@@ -85,10 +85,8 @@ def main():
     args = parser.parse_args()
 
     if args.backend == "cpu":
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
+        os.environ["JAX_PLATFORMS"] = "cpu"   # before the first jax import
     import jax
-    if args.backend == "cpu":
-        jax.config.update("jax_platforms", "cpu")
     import numpy as np
 
     import tpu_dist.dist as dist

@@ -63,9 +63,7 @@ def main():
     args = parser.parse_args()
 
     if args.backend == "cpu":
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
-        import jax
-        jax.config.update("jax_platforms", "cpu")
+        os.environ["JAX_PLATFORMS"] = "cpu"   # before the first jax import
 
     import jax
     import tpu_dist.dist as dist

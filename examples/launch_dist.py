@@ -41,9 +41,7 @@ def main():
     args = parser.parse_args()
 
     if args.backend == "cpu":
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
-        import jax
-        jax.config.update("jax_platforms", "cpu")
+        os.environ["JAX_PLATFORMS"] = "cpu"   # before the first jax import
 
     import tpu_dist.dist as dist
     from tpu_dist import nn, optim

@@ -115,10 +115,7 @@ def _spawn_worker(local_rank, args):
     os.environ.setdefault("MASTER_PORT", "29501")
     os.environ["RANK"] = str(args.nr * args.gpus + local_rank)
     os.environ["WORLD_SIZE"] = str(args.gpus * args.nodes)
-    os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=1"
-    import jax
-    jax.config.update("jax_platforms", "cpu")
     train(args)
 
 
@@ -146,6 +143,9 @@ def main():
                         help="emit the reference's exact breadcrumb strings")
     args = parser.parse_args()
 
+    if args.backend == "cpu":
+        # before the first jax import, here and in every spawned child
+        os.environ["JAX_PLATFORMS"] = "cpu"
     if args.spawn:
         if args.backend != "cpu":
             raise SystemExit("--spawn requires --backend cpu (TPU cores "

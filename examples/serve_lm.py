@@ -69,8 +69,11 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 def build_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser(description=__doc__.split("\n")[0])
-    p.add_argument("--backend", default="cpu",
-                   help="jax platform for the model (cpu|tpu)")
+    p.add_argument("--backend", default=None, choices=["tpu", "cpu"],
+                   help="demand this jax platform for the model (checked "
+                        "after import — the worker refuses to serve from "
+                        "any other); default: whatever JAX resolves, "
+                        "reported in the 'serving on' line")
     p.add_argument("--dim", type=int, default=256)
     p.add_argument("--depth", type=int, default=4)
     p.add_argument("--heads", type=int, default=4)
@@ -432,7 +435,8 @@ def _run_disagg(args, model, params, cache_dtype) -> int:
 
 def main() -> int:
     args = build_parser().parse_args()
-    os.environ.setdefault("JAX_PLATFORMS", args.backend)
+    if args.backend == "cpu":
+        os.environ["JAX_PLATFORMS"] = "cpu"   # before the first jax import
 
     import jax
     import jax.numpy as jnp
@@ -441,6 +445,12 @@ def main() -> int:
     from tpu_dist import resilience, serve
     from tpu_dist import checkpoint as ckpt
     from tpu_dist.models import TransformerLM
+    from tpu_dist.utils import ensure_compile_cache
+
+    # the device gate: a worker started on the chip machine must not end up
+    # serving from the CPU (or the reverse) without having been told to
+    platform = dist.resolve_backend(args.backend)
+    ensure_compile_cache()
 
     if args.tiny:
         args.dim, args.depth, args.heads = 64, 2, 2
@@ -516,8 +526,9 @@ def main() -> int:
     frontend = serve.Frontend(sched, port=args.port, store=store,
                               backend_name=args.backend_name)
     print(f"[serve_lm] rank {rank} serving on {frontend.addr} "
-          f"({args.slots} slots, max_seq_len {args.max_seq_len})",
-          flush=True)
+          f"(platform {platform}, {jax.device_count()} "
+          f"{jax.devices()[0].device_kind} device(s), {args.slots} slots, "
+          f"max_seq_len {args.max_seq_len})", flush=True)
     _write_pid(args, rank)
 
     try:

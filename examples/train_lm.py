@@ -104,9 +104,7 @@ def main():
         if flag not in os.environ.get("XLA_FLAGS", ""):
             os.environ["XLA_FLAGS"] = (
                 os.environ.get("XLA_FLAGS", "") + " " + flag).strip()
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
-        import jax
-        jax.config.update("jax_platforms", "cpu")
+        os.environ["JAX_PLATFORMS"] = "cpu"   # before the first jax import
 
     import jax
     import jax.numpy as jnp
@@ -329,7 +327,9 @@ def main():
                       f"loss: {float(m['loss']):.4f}  (tp={tp})")
 
     if dist.get_rank() == 0:
-        print(f"Training complete in: {datetime.now() - start}")
+        print(f"Training complete in: {datetime.now() - start} "
+              f"(platform {dist.get_backend()}, {dist.get_world_size()} x "
+              f"{jax.devices()[0].device_kind})")
     dist.destroy_process_group()
 
 

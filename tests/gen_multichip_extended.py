@@ -1,33 +1,50 @@
-"""Regenerate MULTICHIP_EXTENDED.json — dryrun_multichip at {8, 16, 32}.
+"""Regenerate MULTICHIP_EXTENDED.json — dryrun_multichip at {8, 16, 32} on
+virtual CPU meshes.
 
 Usage: ``python -m tests.gen_multichip_extended`` from the repo root.
-The driver's own contract records n=8 in MULTICHIP_rN.json; this artifact
-pins the larger-world claims (r4 verdict #6) with timings, reproducible
-via tests/test_dryrun_multichip.py.
+``dryrun_multichip`` runs on the devices its process has, so each world
+size gets its own child with that many CPU devices forced before JAX
+starts; tests/test_dryrun_multichip.py runs the same children.
 """
 
-import importlib.util
 import json
 import os
+import re
+import subprocess
+import sys
 import time
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def main():
-    spec = importlib.util.spec_from_file_location(
-        "__graft_entry__", os.path.join(_REPO, "__graft_entry__.py"))
-    g = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(g)
+def dryrun_in_child(n_devices: int) -> None:
+    """``__graft_entry__.dryrun_multichip(n)`` in a child holding an
+    n-device CPU mesh; raises with the child's output on failure."""
+    rest = re.sub(r"--xla_force_host_platform_device_count=\d+", "",
+                  os.environ.get("XLA_FLAGS", ""))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS=f"{rest} --xla_force_host_platform_device_count="
+                         f"{n_devices}".strip())
+    proc = subprocess.run(
+        [sys.executable, "-c",
+         f"import __graft_entry__ as g; g.dryrun_multichip({n_devices})"],
+        cwd=_REPO, env=env, capture_output=True, text=True, timeout=1800)
+    if proc.returncode != 0:
+        raise RuntimeError(
+            f"dryrun_multichip({n_devices}) child failed "
+            f"(rc={proc.returncode}):\n{proc.stdout[-2000:]}\n"
+            f"{proc.stderr[-4000:]}")
 
+
+def main():
     results = []
     for n in (8, 16, 32):
         t0 = time.time()
         try:
-            g.dryrun_multichip(n)
+            dryrun_in_child(n)
             results.append({"n_devices": n, "ok": True,
                             "wall_s": round(time.time() - t0, 1)})
-        except Exception as e:  # record the failure rather than abort
+        except RuntimeError as e:  # record the failure rather than abort
             results.append({"n_devices": n, "ok": False,
                             "error": repr(e)[:500],
                             "wall_s": round(time.time() - t0, 1)})

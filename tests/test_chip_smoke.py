@@ -1,0 +1,117 @@
+"""chip_smoke.py's phases at toy sizes on the CPU mesh, its refusal to pass
+without a TPU, and the compile-cache placement it relies on.
+
+The script's own path has no CPU branch: these tests call the phase
+functions with tiny sizes and ``backend="cpu"``, and assert what only the
+CPU can promise (token identity with ``generate()``); the chip-only
+assertions (Mosaic kernels in the compiled step, allocator statistics) live
+in ``chip_smoke.main`` and are exercised on the chip.
+"""
+
+import os
+import subprocess
+import sys
+
+import jax
+import pytest
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, _REPO)
+
+import chip_smoke  # noqa: E402
+import tpu_dist.dist as dist  # noqa: E402
+from tpu_dist.utils.compile_cache import ensure_compile_cache  # noqa: E402
+
+TINY_LM = dict(vocab_size=512, dim=64, depth=2, num_heads=2, max_seq_len=128)
+TINY_RUN = dict(model_kw=TINY_LM, per_chip_batch=2, seq_len=128, lr=0.3)
+
+
+@pytest.fixture(autouse=True)
+def _clean_group():
+    if dist.is_initialized():
+        dist.destroy_process_group()
+    yield
+    if dist.is_initialized():
+        dist.destroy_process_group()
+
+
+# -- compile cache placement --------------------------------------------------
+
+@pytest.fixture
+def _restore_cache_config():
+    before = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_cache_dir_from_environment_is_left_to_jax(monkeypatch, tmp_path,
+                                                   _restore_cache_config):
+    jax.config.update("jax_compilation_cache_dir", None)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert ensure_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir is None   # config untouched
+
+
+def test_cache_dir_defaults_to_one_fixed_path_in_the_checkout(
+        monkeypatch, _restore_cache_config):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    first = ensure_compile_cache()
+    assert first == os.path.join(_REPO, ".jax_cache")
+    assert jax.config.jax_compilation_cache_dir == first
+    assert ensure_compile_cache() == first                # twice: same path
+    with open(os.path.join(_REPO, ".gitignore")) as f:
+        assert "/.jax_cache/" in f.read().split()
+
+
+# -- the script refuses to pass off the chip ----------------------------------
+
+def test_script_exits_nonzero_naming_the_platform_without_a_tpu():
+    proc = subprocess.run([sys.executable, "chip_smoke.py"], cwd=_REPO,
+                          env=dict(os.environ, JAX_PLATFORMS="cpu"),
+                          capture_output=True, text=True, timeout=300)
+    assert proc.returncode != 0
+    assert "platform: cpu" in proc.stdout
+    assert "JAX resolved platform 'cpu'" in proc.stdout
+    assert '"ok"' not in proc.stdout                      # no result line
+
+
+def test_mosaic_kernel_names_are_read_from_custom_call_lines():
+    hlo = ('%a = bf16[8] custom-call(%x), custom_call_target='
+           '"tpu_custom_call", metadata={op_name="jit(f)/flash_fwd"}\n'
+           '%b = f32[8] fusion(%y), metadata={op_name="fused_ce_fwd"}\n')
+    assert chip_smoke.mosaic_kernels(
+        hlo, chip_smoke.TRAINER_KERNELS) == {"flash_fwd"}
+
+
+# -- the phases, tiny ---------------------------------------------------------
+
+def test_trainer_and_server_phases_tiny():
+    tr = chip_smoke.phase_trainer("cpu", steps=5, **TINY_RUN)
+    assert len(tr["losses"]) == 5 and tr["losses"][-1] < tr["losses"][0]
+    assert tr["kernels"] == set()        # interpret mode: no Mosaic calls
+    assert len(tr["peak_bytes"]) == 8    # one entry per device of the group
+    assert not dist.is_initialized()     # the phase cleans up after itself
+
+    requests = [(5, 8), (60, 12), (17, 8), (5, 8), (60, 12)]
+    sv = chip_smoke.phase_server(TINY_LM, slots=2, requests=requests)
+    assert sv["identical"] == len(requests)   # the CPU contract
+    assert sv["stats"]["completed"] == len(requests)
+
+
+def test_phase_fails_loudly():
+    with pytest.raises(dist.BackendMismatchError):
+        chip_smoke.phase_convnet("tpu", per_chip_batch=2, steps=2)
+    with pytest.raises(ValueError, match="more requests than slots"):
+        chip_smoke.phase_server(TINY_LM, slots=4, requests=[(5, 4)])
+
+
+@pytest.mark.slow
+def test_kernel_convnet_and_multichip_phases_tiny():
+    chip_smoke.phase_kernels(
+        flash=dict(batch=2, seq=256, heads=2, head_dim=64),
+        ce=dict(rows=64, vocab=1000),
+        moe=dict(tokens=256, dim=128, experts=4, top_k=2))
+    chip_smoke.phase_convnet("cpu", per_chip_batch=8, steps=12)
+    tr = chip_smoke.phase_trainer("cpu", steps=2, **TINY_RUN)
+    chip_smoke.phase_multichip("cpu", dp_first_loss=tr["losses"][0],
+                               **TINY_RUN)

@@ -42,13 +42,48 @@ class TestLifecycle:
 
     def test_backend_aliases(self):
         pg = dist.init_process_group(backend="gloo")  # → cpu
-        assert pg.size() >= 1
+        assert dist.get_backend(pg) == "cpu"
         dist.destroy_process_group()
-        pg = dist.init_process_group(backend="nccl")  # → tpu (runs on forced cpu)
-        assert pg.size() >= 1
-        dist.destroy_process_group()
-        pg = dist.init_process_group(backend="mpi")  # → tpu (ref README:133)
-        assert dist.get_backend(pg) == "tpu"
+        # nccl/xla/mpi → tpu (ref README:133): a demand this CPU mesh
+        # cannot meet, refused by name instead of stamped "tpu"
+        for alias in ("nccl", "mpi"):
+            with pytest.raises(dist.BackendMismatchError):
+                dist.init_process_group(backend=alias)
+            assert not dist.is_initialized()
+
+    def test_explicit_tpu_on_cpu_names_both_platforms(self, monkeypatch):
+        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+        with pytest.raises(dist.BackendMismatchError) as ei:
+            dist.init_process_group(backend="tpu")
+        msg = str(ei.value)
+        assert "'tpu'" in msg and "resolved 'cpu'" in msg
+        assert "JAX_PLATFORMS='cpu'" in msg
+
+    def test_bare_call_reports_what_jax_resolved(self):
+        pg = dist.init_process_group()
+        assert dist.get_backend() == "cpu"
+        assert dist.get_backend(dist.new_group([0, 1])) == "cpu"
+        assert pg.devices[0].platform == "cpu"
+
+    def test_tpu_refused_when_host_chips_are_shared(self, monkeypatch):
+        """One process per host drives all local chips: LOCAL_WORLD_SIZE > 1
+        with a TPU backend is refused — for an explicit ask before JAX is
+        touched, for the default once the platform is known."""
+        import jax
+        monkeypatch.setenv("LOCAL_WORLD_SIZE", "2")
+        with pytest.raises(dist.BackendMismatchError,
+                           match="one process per host"):
+            dist.init_process_group(backend="tpu")
+
+        class _Chip:
+            platform = "tpu"
+
+        monkeypatch.setattr(jax, "devices", lambda *a: [_Chip()])
+        with pytest.raises(dist.BackendMismatchError,
+                           match="LOCAL_WORLD_SIZE=2"):
+            dist.resolve_backend(None)
+        monkeypatch.setenv("LOCAL_WORLD_SIZE", "1")
+        assert dist.resolve_backend("nccl") == "tpu"
 
     def test_unknown_backend_raises(self):
         with pytest.raises(ValueError, match="backend"):

@@ -1,30 +1,18 @@
-"""README §8b perf claims must trace to recorded JSON artifacts.
+"""README §8b accuracy claims must trace to ``ACCURACY.json``.
 
-Round-3 verdict: README perf numbers drifted one round after being fixed
-(732,027 cited while the ratchet held 773,365).  This test makes the tracing
-mechanical, the way tests/test_api_index.py enforces docs/API.md: every
-high-precision numeric claim in README's performance-notes section must
-appear in a LIVING artifact — ``BENCH_EXTENDED.json`` (the best-ever
-ratchet benchmarks/run_all.py maintains) or ``ACCURACY.json``.  Historical
-round snapshots (BENCH_r0N.json) deliberately do NOT count: citing one is
-how numbers go stale.
-
-Rule (documented so failures are actionable): a "claim" is either an integer
-with thousands separators (``143,269``) or a decimal with >=2 fractional
-digits (``0.273``).  Bare small ints (batch sizes, seq lens, "1.5x" speak)
-aren't load-bearing recordings and aren't matched.  An integer claim must
-equal an artifact number rounded to integer; a decimal claim must equal an
-artifact number rounded to the same number of places.
+The device rates §8b used to quote went with their record files in PR 21
+(device numbers live in PERF_LEDGER.jsonl / PERF.md); what the section still
+states as numbers are convergence results, and each must appear in
+``ACCURACY.json``.  A "claim" is a decimal with >= 2 fractional digits
+(``0.7732``); it must equal an artifact number rounded to the same number
+of places.
 """
 
 import json
 import os
 import re
 
-import pytest
-
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-_LIVING_ARTIFACTS = ("BENCH_EXTENDED.json", "ACCURACY.json")
 
 
 def _artifact_numbers():
@@ -44,16 +32,10 @@ def _artifact_numbers():
         elif isinstance(o, str):
             # numbers embedded in note/unit strings still count as recorded
             for m in re.findall(r"-?\d+\.?\d*(?:[eE]-?\d+)?", o):
-                try:
-                    vals.append(float(m))
-                except ValueError:
-                    pass
+                vals.append(float(m))
 
-    for name in _LIVING_ARTIFACTS:
-        path = os.path.join(_REPO, name)
-        assert os.path.exists(path), f"{name} missing — §8b can't be traced"
-        with open(path) as f:
-            walk(json.load(f))
+    with open(os.path.join(_REPO, "ACCURACY.json")) as f:
+        walk(json.load(f))
     return vals
 
 
@@ -64,31 +46,24 @@ def _perf_section():
     return md.split("## 8b.")[1].split("\n## ")[0]
 
 
-def test_section_has_claims():
-    """Guard the extractor itself: §8b must keep yielding a healthy number
-    of claims, else a format change silently turns this file into a no-op."""
+def test_readme_accuracy_numbers_trace_to_artifact():
     sec = _perf_section()
-    ints = re.findall(r"\d{1,3}(?:,\d{3})+", sec)
-    decs = re.findall(r"\d+\.\d{2,}", sec)
-    assert len(ints) >= 8, f"only {len(ints)} comma-int claims found"
-    assert len(decs) >= 2, f"only {len(decs)} decimal claims found"
-
-
-def test_readme_perf_numbers_trace_to_artifacts():
-    sec = _perf_section()
+    claims = set(re.findall(r"\d+\.\d{2,}", sec))
+    # guard the extractor: a format change must not turn this into a no-op
+    assert len(claims) >= 4, f"only {len(claims)} decimal claims found"
     vals = _artifact_numbers()
     untraced = []
-    for s in set(re.findall(r"\d{1,3}(?:,\d{3})+", sec)):
-        n = float(s.replace(",", ""))
-        if not any(abs(round(v) - n) < 0.5 for v in vals):
-            untraced.append(s)
-    for s in set(re.findall(r"\d+\.\d{2,}", sec)):
-        d = float(s)
+    for s in claims:
         places = len(s.split(".")[1])
-        if not any(abs(round(v, places) - d) < 0.5 * 10 ** (-places)
+        if not any(abs(round(v, places) - float(s)) < 0.5 * 10 ** (-places)
                    for v in vals):
             untraced.append(s)
     assert not untraced, (
-        f"README §8b claims with no recording in {_LIVING_ARTIFACTS}: "
-        f"{sorted(untraced)} — re-run the benchmark that produced them "
-        "(benchmarks/run_all.py or accuracy_run.py) or fix the README")
+        f"README §8b claims with no recording in ACCURACY.json: "
+        f"{sorted(untraced)}")
+
+
+def test_readme_quotes_no_device_rate():
+    """No thousands-separated figure (the form every old rate took) is left
+    in §8b: device numbers are in the ledger, or 'not measured'."""
+    assert not re.findall(r"\d{1,3}(?:,\d{3})+", _perf_section())

@@ -2,9 +2,9 @@
 
 Why this exists: the reference keeps the chip fed by throwing host cores at
 augmentation (`num_workers=4, pin_memory=True`, /root/reference/example_mp.py:74-80).
-On a TPU host with few cores that strategy fails — BENCH_EXTENDED.json
-round 2 recorded the host pipeline at 169 img/s against a 9.5k img/s
-ResNet-50 step (57 cores' worth of numpy).  The TPU-native fix is to move
+On a TPU host with few cores that strategy fails — a round-2 lead put the
+host pipeline at 169 img/s per core against a 9.5k img/s ResNet-50 step
+(57 cores' worth of numpy).  The TPU-native fix is to move
 the math to the chip: the host only *slices raw uint8 bytes* (cheap — a
 memcpy per batch) and ships them over PCIe at uint8 width (4x fewer bytes
 than f32); the crop/flip/normalize runs as one jitted XLA program on

@@ -30,7 +30,7 @@ Usage (single host, 8 cores — the ``mp.spawn`` scenario collapsed into one
 process)::
 
     import tpu_dist.dist as dist
-    dist.init_process_group(backend="tpu")
+    dist.init_process_group(backend="tpu")   # raises unless JAX is on a TPU
     dist.get_world_size()   # 8  (devices)
     dist.get_rank()         # 0  (process)
 
@@ -52,6 +52,8 @@ from . import rendezvous as _rdzv
 
 __all__ = [
     "ProcessGroup",
+    "BackendMismatchError",
+    "resolve_backend",
     "init_process_group",
     "destroy_process_group",
     "is_initialized",
@@ -180,7 +182,73 @@ class ProcessGroup:
                 f"axes={dict(zip(self._axis_names, self._mesh.devices.shape))})")
 
 
-def init_process_group(backend: str = "tpu",
+class BackendMismatchError(RuntimeError):
+    """The platform JAX resolved is not the backend that was asked for, or
+    a TPU backend was asked for from a process that shares its host's chips
+    with sibling processes."""
+
+
+def _normalize_backend(backend: Optional[str]) -> Optional[str]:
+    """``None`` (no demand) | ``'tpu'`` | ``'cpu'`` from the torch-style
+    backend strings (/root/reference/README.md:133)."""
+    if backend is None:
+        return None
+    backend = backend.lower()
+    if backend == "gloo":
+        return "cpu"
+    # mpi: the reference name-checks it as an alternative accelerator
+    # backend; on TPU the accelerator data plane is XLA collectives either
+    # way
+    if backend in ("nccl", "xla", "mpi"):
+        return "tpu"
+    if backend not in ("tpu", "cpu"):
+        raise ValueError(f"Unknown backend {backend!r}; use 'tpu' or 'cpu'")
+    return backend
+
+
+def _refuse_shared_chips() -> None:
+    local = int(os.environ.get("LOCAL_WORLD_SIZE", "1") or 1)
+    if local > 1:
+        raise BackendMismatchError(
+            f"TPU backend with LOCAL_WORLD_SIZE={local}: one process per "
+            f"host drives all local chips, and a chip belongs to one "
+            f"process at a time — launch with --nproc_per_node=1 (the "
+            f"in-process device world is dist.get_world_size())")
+
+
+def resolve_backend(backend: Optional[str] = None) -> str:
+    """The device gate: the platform JAX really resolved (``'tpu'``,
+    ``'cpu'``, ...), after checking it against what was asked for.
+
+    An explicit ``backend`` (``'tpu'``/``'cpu'`` or the torch aliases) is a
+    demand: a different resolved platform raises
+    :class:`BackendMismatchError` naming both and ``JAX_PLATFORMS`` — with
+    libtpu installed and no usable chip JAX falls back to the CPU after one
+    log line, and everything keyed on ``jax.default_backend()`` (Pallas
+    interpret mode, attention auto-dispatch) would degrade silently behind
+    it.  ``None`` asks for nothing and reports truthfully.  A TPU platform
+    is refused when the launcher spawned sibling processes on this host
+    (``LOCAL_WORLD_SIZE > 1``); for an explicit TPU ask that check runs
+    BEFORE JAX touches the chip, so the refused process never holds it.
+    """
+    want = _normalize_backend(backend)
+    if want == "tpu":
+        _refuse_shared_chips()
+    import jax
+    have = jax.devices()[0].platform
+    if want is not None and have != want:
+        raise BackendMismatchError(
+            f"backend={backend!r} asks for platform {want!r} but JAX "
+            f"resolved {have!r} (JAX_PLATFORMS="
+            f"{os.environ.get('JAX_PLATFORMS')!r}, devices "
+            f"{jax.devices()[:2]}); fix the environment or ask for the "
+            f"platform that is there")
+    if have == "tpu":
+        _refuse_shared_chips()
+    return have
+
+
+def init_process_group(backend: Optional[str] = None,
                        init_method: Optional[str] = None,
                        world_size: int = -1,
                        rank: int = -1,
@@ -189,10 +257,13 @@ def init_process_group(backend: str = "tpu",
                        mesh_shape: Optional[Sequence[int]] = None) -> ProcessGroup:
     """Bring up the default process group (c10d ``init_process_group`` parity).
 
-    ``backend``: ``'tpu'`` (XLA collectives over ICI/DCN — the NCCL
-    equivalent) or ``'cpu'`` (host-platform devices — the gloo equivalent;
-    requires JAX_PLATFORMS=cpu before first jax import).  The reference's
-    backend strings appear at /root/reference/README.md:133.
+    ``backend``: ``None`` (default) takes whatever platform JAX resolved
+    and reports it truthfully (:func:`get_backend`); ``'tpu'`` (XLA
+    collectives over ICI/DCN — the NCCL equivalent; aliases nccl/xla/mpi)
+    and ``'cpu'`` (host-platform devices — the gloo equivalent; needs
+    ``JAX_PLATFORMS=cpu`` in the environment before the first jax import)
+    are demands checked by :func:`resolve_backend`, which raises
+    :class:`BackendMismatchError` rather than train on the wrong device.
 
     ``init_method``: ``None`` (single process), ``'env://'`` (read
     MASTER_ADDR/MASTER_PORT/WORLD_SIZE/RANK — /root/reference/launch_dist.py:49),
@@ -211,25 +282,17 @@ def init_process_group(backend: str = "tpu",
                 "Default process group already initialized; call "
                 "destroy_process_group() first.")
 
-        backend = backend.lower()
-        if backend in ("gloo",):
-            backend = "cpu"
-        # mpi: the reference name-checks it as an alternative accelerator
-        # backend (/root/reference/README.md:133); on TPU the accelerator
-        # data plane is XLA collectives either way
-        if backend in ("nccl", "xla", "mpi"):
-            backend = "tpu"
-        if backend not in ("tpu", "cpu"):
-            raise ValueError(f"Unknown backend {backend!r}; use 'tpu' or 'cpu'")
+        _normalize_backend(backend)  # an unknown string fails before the join
 
         _rdzv.rendezvous(init_method, world_size=world_size, rank=rank,
                          timeout=timeout)
 
+        resolve_backend(backend)
+        from ..utils.compile_cache import ensure_compile_cache
+        ensure_compile_cache()
         import jax
-        devices = jax.devices()
-        group = ProcessGroup(devices, axis_names=axis_names,
+        group = ProcessGroup(jax.devices(), axis_names=axis_names,
                              mesh_shape=mesh_shape)
-        group._backend = backend
         _DEFAULT_GROUP = group
         return group
 
@@ -266,12 +329,10 @@ def get_rank(group: Optional[ProcessGroup] = None) -> int:
 
 
 def get_backend(group: Optional[ProcessGroup] = None) -> str:
-    """torch ``dist.get_backend`` parity: the group's normalized backend
-    string — ``'tpu'`` (XLA collectives; accepts the aliases nccl/xla/mpi
-    at init) or ``'cpu'`` (accepts gloo).  Subgroups inherit their parent's
-    backend at creation (stamped in :func:`new_group`, so the answer
-    stays right even after the default group is recycled)."""
-    return getattr(_group(group), "_backend", None) or "tpu"
+    """torch ``dist.get_backend`` parity: the platform the group's devices
+    really are (``'tpu'``, ``'cpu'``) — read from the devices, never from
+    the string that was asked for at init."""
+    return _group(group).devices[0].platform
 
 
 def get_num_processes(group: Optional[ProcessGroup] = None) -> int:
@@ -302,10 +363,8 @@ def new_group(ranks: Optional[Sequence[int]] = None,
     if ranks is None:
         ranks = range(default.size())
     devices = [default.devices[r] for r in ranks]
-    group = ProcessGroup(devices, axis_names=axis_names,
-                         mesh_shape=mesh_shape, parent=default)
-    group._backend = getattr(default, "_backend", None)
-    return group
+    return ProcessGroup(devices, axis_names=axis_names,
+                        mesh_shape=mesh_shape, parent=default)
 
 
 def barrier(group: Optional[ProcessGroup] = None) -> None:

@@ -35,7 +35,11 @@ TPU deployment note: on a pod slice run ONE launch per host with
 ``--nproc_per_node=1`` (the process drives all local cores); ``WORLD_SIZE``
 then equals nnodes, and the in-process device world is
 ``dist.get_world_size()`` (cores).  ``--nproc_per_node>1`` is for the CPU
-backend (teaching/testing parity with the reference's one-process-per-GPU).
+backend (teaching/testing parity with the reference's one-process-per-GPU):
+a chip belongs to one process at a time, so ``init_process_group`` refuses
+a TPU backend in a child that sees ``LOCAL_WORLD_SIZE > 1``.  The launcher
+itself never initialises a JAX backend — it must not hold the chip its
+child needs.
 """
 
 from __future__ import annotations
@@ -60,7 +64,8 @@ def build_parser() -> argparse.ArgumentParser:
                     "RANK/LOCAL_RANK/WORLD_SIZE/MASTER_ADDR/MASTER_PORT "
                     "env contract (torch.distributed.launch parity).")
     p.add_argument("--nproc_per_node", type=int, default=1,
-                   help="processes on this node (TPU: keep 1 per host)")
+                   help="processes on this node (TPU: 1 per host — a TPU "
+                        "backend is refused in children when it is more)")
     p.add_argument("--nnodes", type=int, default=1)
     p.add_argument("--node_rank", type=int, default=0)
     p.add_argument("--master_addr", type=str, default="127.0.0.1")

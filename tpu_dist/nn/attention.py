@@ -33,9 +33,9 @@ _IMPL_OVERRIDE: list = []
 # auto-dispatch crossover: below this sequence length the XLA-fused dense
 # path beats the Pallas kernel (tile padding to the 128-lane grid plus
 # kernel launch overhead dominate when the score matrix is small).
-# Measured at the model level on v5e bf16: ViT-B at T=197 trains 1.54x
-# faster dense (921.7 vs 596.8 img/s); GPT-2-small at T=2048 trains with
-# flash 1.63x faster fwd+bwd (BENCH_EXTENDED flash row).
+# Leads from the shared v5e chip of the rounds before PR 1 (not measured
+# on this machine): ViT-B at T=197 trained 1.54x faster dense; GPT-2-small
+# at T=2048 trained 1.63x faster fwd+bwd with flash.
 _FLASH_MIN_SEQ = 1024
 
 

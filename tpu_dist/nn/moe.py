@@ -63,7 +63,7 @@ from jax import lax
 from .module import Module
 from . import init as init_lib
 
-from ..ops._pallas import ceil_to as _ceil_to
+from ..ops._pallas import ceil_to as _ceil_to, sublane_tile
 
 __all__ = ["MoELayer"]
 
@@ -329,8 +329,9 @@ class MoELayer(Module):
         n, d = xt.shape
         kn = k * n
         # row-block size: 512 rows amortizes grid/DMA overhead at LM
-        # shapes; tiny calls (tests, dryrun) shrink to keep M small
-        b = min(512, _ceil_to(max(kn // e, 1), 8))
+        # shapes; tiny calls (tests, dryrun) shrink to keep M small, down
+        # to one sublane tile of the activations' dtype (16 rows of bf16)
+        b = min(512, _ceil_to(max(kn // e, 1), sublane_tile(xt.dtype)))
         m_rows = (-(-kn // b) + e) * b                 # static upper bound
         nb = m_rows // b
 

@@ -4,12 +4,20 @@ from __future__ import annotations
 
 import jax
 
-__all__ = ["use_interpret", "out_struct", "ceil_to"]
+__all__ = ["use_interpret", "out_struct", "ceil_to", "sublane_tile"]
 
 
 def ceil_to(x: int, m: int) -> int:
     """Round ``x`` up to a multiple of ``m`` (tile/lane alignment)."""
     return (x + m - 1) // m * m
+
+
+def sublane_tile(dtype) -> int:
+    """Rows in Mosaic's minimum (sublane x 128-lane) tile for ``dtype``:
+    8 for 4-byte types, 16 for bf16/f16, 32 for 1-byte types — sub-32-bit
+    rows pack along the sublanes, so a row block below this is off the
+    tiling."""
+    return 8 * max(1, 4 // jax.numpy.dtype(dtype).itemsize)
 
 
 def use_interpret() -> bool:
@@ -29,10 +37,6 @@ def use_interpret() -> bool:
 def out_struct(shape, dtype, *operands):
     """ShapeDtypeStruct carrying the union of the operands' varying-mesh-
     axes sets — required for pallas_call outputs traced inside shard_map
-    (e.g. under the DDP wrapper), harmless outside it.  The vma probe is
-    version-sensitive JAX-internals territory; this is the single copy."""
-    try:
-        vma = frozenset().union(*(jax.typeof(x).vma for x in operands))
-    except (AttributeError, TypeError):
-        return jax.ShapeDtypeStruct(shape, dtype)
+    (e.g. under the DDP wrapper), harmless outside it."""
+    vma = frozenset().union(*(jax.typeof(x).vma for x in operands))
     return jax.ShapeDtypeStruct(shape, dtype, vma=vma)

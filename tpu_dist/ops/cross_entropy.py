@@ -8,8 +8,9 @@ VMEM and emits only the per-row loss — one HBM read of the logits forward,
 one read + one write backward.  Matters when V is large (LM heads), not for
 V=10 image classifiers; `nn.CrossEntropyLoss(fused=True)` opts in.
 
-Layout: grid over row blocks of ``TILE_B``; each kernel invocation sees the
-full (padded-to-lane) vocab row.  Forward saves per-row logsumexp; backward
+Layout: grid over row blocks of one sublane tile of the logits' dtype (8
+rows of f32, 16 of bf16); each kernel invocation sees the full
+(padded-to-lane) vocab row.  Forward saves per-row logsumexp; backward
 recomputes softmax from (logits, lse) — no (B, V) residual beyond the
 logits themselves.
 
@@ -25,16 +26,13 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ._pallas import out_struct as _out_struct, use_interpret as _use_interpret
+from ._pallas import (ceil_to as _ceil_to, out_struct as _out_struct,
+                      sublane_tile as _sublane_tile,
+                      use_interpret as _use_interpret)
 
 __all__ = ["fused_cross_entropy"]
 
-_TILE_B = 8  # f32 sublane size; one row block per grid step
 _LANE = 128
-
-
-def _ceil_to(x: int, m: int) -> int:
-    return (x + m - 1) // m * m
 
 
 def _fwd_kernel(logits_ref, labels_ref, nll_ref, lse_ref, *, vocab: int):
@@ -46,16 +44,16 @@ def _fwd_kernel(logits_ref, labels_ref, nll_ref, lse_ref, *, vocab: int):
 
     @pl.when(pl.program_id(0) >= 0)
     def _():
-        logits = logits_ref[:].astype(jnp.float32)       # (TILE_B, Vpad)
+        logits = logits_ref[:].astype(jnp.float32)       # (tile, Vpad)
         cols = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
         valid = cols < vocab
         logits = jnp.where(valid, logits, -jnp.inf)
-        mx = jnp.max(logits, axis=1, keepdims=True)      # (TILE_B, 1)
+        mx = jnp.max(logits, axis=1, keepdims=True)      # (tile, 1)
         shifted = logits - mx
         sumexp = jnp.sum(jnp.where(valid, jnp.exp(shifted), 0.0), axis=1,
                          keepdims=True)
-        lse = mx + jnp.log(sumexp)                       # (TILE_B, 1)
-        onehot = cols == labels_ref[:]                   # (TILE_B, Vpad)
+        lse = mx + jnp.log(sumexp)                       # (tile, 1)
+        onehot = cols == labels_ref[:]                   # (tile, Vpad)
         picked = jnp.sum(jnp.where(onehot, logits, 0.0), axis=1,
                          keepdims=True)
         nll_ref[:] = lse - picked
@@ -78,12 +76,15 @@ def _bwd_kernel(logits_ref, labels_ref, lse_ref, g_ref, dlogits_ref, *,
 
 
 def _pad(logits, labels):
+    """Pad rows to the dtype's sublane tile and the vocab to the lane
+    width; returns ``(logits, labels, rows_padded, vocab_padded, tile)``."""
     b, v = logits.shape
-    bp, vp = _ceil_to(b, _TILE_B), _ceil_to(v, _LANE)
+    tile = _sublane_tile(logits.dtype)
+    bp, vp = _ceil_to(b, tile), _ceil_to(v, _LANE)
     if (bp, vp) != (b, v):
         logits = jnp.pad(logits, ((0, bp - b), (0, vp - v)))
         labels = jnp.pad(labels, (0, bp - b))
-    return logits, labels, bp, vp
+    return logits, labels, bp, vp, tile
 
 
 def _call_fwd(logits, labels):
@@ -91,22 +92,22 @@ def _call_fwd(logits, labels):
     from jax.experimental.pallas import tpu as pltpu
 
     b, v = logits.shape
-    logits_p, labels_p, bp, vp = _pad(logits, labels)
+    logits_p, labels_p, bp, vp, tile = _pad(logits, labels)
     labels2d = labels_p.astype(jnp.int32)[:, None]       # (Bp, 1)
-    grid = (bp // _TILE_B,)
+    grid = (bp // tile,)
     nll, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, vocab=v),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((_TILE_B, vp), lambda i: (i, 0),
+            pl.BlockSpec((tile, vp), lambda i: (i, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((_TILE_B, 1), lambda i: (i, 0),
+            pl.BlockSpec((tile, 1), lambda i: (i, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_specs=[
-            pl.BlockSpec((_TILE_B, 1), lambda i: (i, 0),
+            pl.BlockSpec((tile, 1), lambda i: (i, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((_TILE_B, 1), lambda i: (i, 0),
+            pl.BlockSpec((tile, 1), lambda i: (i, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
@@ -114,6 +115,7 @@ def _call_fwd(logits, labels):
             _out_struct((bp, 1), jnp.float32, logits_p, labels2d),
         ],
         interpret=_use_interpret(),
+        name="fused_ce_fwd",
     )(logits_p, labels2d)
     return nll[:b, 0], lse[:b, 0]
 
@@ -123,29 +125,30 @@ def _call_bwd(logits, labels, lse, g_rows):
     from jax.experimental.pallas import tpu as pltpu
 
     b, v = logits.shape
-    logits_p, labels_p, bp, vp = _pad(logits, labels)
+    logits_p, labels_p, bp, vp, tile = _pad(logits, labels)
     labels2d = labels_p.astype(jnp.int32)[:, None]
     lse2d = jnp.pad(lse, (0, bp - b))[:, None]
     g2d = jnp.pad(g_rows, (0, bp - b))[:, None].astype(jnp.float32)
-    grid = (bp // _TILE_B,)
+    grid = (bp // tile,)
     dlogits = pl.pallas_call(
         functools.partial(_bwd_kernel, vocab=v),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((_TILE_B, vp), lambda i: (i, 0),
+            pl.BlockSpec((tile, vp), lambda i: (i, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((_TILE_B, 1), lambda i: (i, 0),
+            pl.BlockSpec((tile, 1), lambda i: (i, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((_TILE_B, 1), lambda i: (i, 0),
+            pl.BlockSpec((tile, 1), lambda i: (i, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((_TILE_B, 1), lambda i: (i, 0),
+            pl.BlockSpec((tile, 1), lambda i: (i, 0),
                          memory_space=pltpu.VMEM),
         ],
-        out_specs=pl.BlockSpec((_TILE_B, vp), lambda i: (i, 0),
+        out_specs=pl.BlockSpec((tile, vp), lambda i: (i, 0),
                                memory_space=pltpu.VMEM),
         out_shape=_out_struct((bp, vp), logits.dtype, logits_p, labels2d,
                               lse2d, g2d),
         interpret=_use_interpret(),
+        name="fused_ce_bwd",
     )(logits_p, labels2d, lse2d, g2d)
     return dlogits[:b, :v]
 

@@ -206,6 +206,7 @@ def _fwd_call(q, k, v, causal, sm_scale, block_q, block_k):
             pltpu.VMEM((block_q, dp), jnp.float32),      # output accumulator
         ],
         interpret=_use_interpret(),
+        name="flash_fwd",
     )(qp, kp, vp)
     return o[:, :tq, :d], lse[:, :tq]
 
@@ -347,6 +348,7 @@ def _bwd_call(q, k, v, o, lse, do, causal, sm_scale, block_q, block_k,
         out_shape=_out_struct((bh, tqp, dp), q.dtype, qp, kp, vp, dop),
         scratch_shapes=[pltpu.VMEM((block_q, dp), jnp.float32)],
         interpret=_use_interpret(),
+        name="flash_bwd_dq",
     )(qp, kp, vp, dop, lsep, deltap)
 
     # grid transposed: KV tile outer, Q sweep inner, so dk/dv accumulate
@@ -367,6 +369,7 @@ def _bwd_call(q, k, v, o, lse, do, causal, sm_scale, block_q, block_k,
         scratch_shapes=[pltpu.VMEM((block_k, dp), jnp.float32),
                         pltpu.VMEM((block_k, dp), jnp.float32)],
         interpret=_use_interpret(),
+        name="flash_bwd_dkv",
     )(qp, kp, vp, dop, lsep, deltap)
     return dq[:, :tq, :d], dk[:, :tk, :d], dv[:, :tk, :d]
 
@@ -417,9 +420,8 @@ def _split_lse(q, k, v, sm_scale, block_q, block_k):
 
     A single causal call sweeps every tile touching the diagonal with
     full-size blocks, so at seq = 2·block the three executed 1024² tiles
-    are only 2/3 useful (the two diagonal tiles are half masked) — the
-    measured TFLOPs deficit at 2048 vs 8k (BENCH_EXTENDED
-    curve_shape_note).  Split instead:
+    are only 2/3 useful (the two diagonal tiles are half masked).  Split
+    instead:
 
     - **off-diagonal**: tiles STRICTLY below the diagonal band (mode
       ``"offdiag"``) — full blocks, zero masked area, and no per-element

@@ -174,6 +174,7 @@ def gmm(x, w, block_groups, n_live_blocks, *, bias=None, block_rows: int = 512,
         kernel, grid_spec=grid_spec,
         out_shape=_out_struct((m, hp), out_dtype, xp, wp, bp),
         interpret=_use_interpret(),
+        name="gmm",
     )(scalars, xp, wp, bp)
     return out[:, :h]
 
@@ -272,6 +273,7 @@ def tgmm(x, dy, block_groups, n_groups: int, *, block_rows: int = 512,
         out_shape=[_out_struct((n_groups, dp, hp), out_dtype, xp, dyp),
                    _out_struct((n_groups, 1, hp), out_dtype, xp, dyp)],
         interpret=_use_interpret(),
+        name="tgmm",
     )(scalars, xp, dyp)
     dw = dw[:, :d, :h]
     return (dw, db[:, 0, :h]) if with_rowsum else dw

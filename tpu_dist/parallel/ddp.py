@@ -496,8 +496,8 @@ class DistributedDataParallel:
         The steps execute as a ``lax.scan`` on device — semantically
         identical to ``k`` sequential :meth:`train_step` calls (tested),
         but with a single host dispatch and readback.  This is the
-        TPU-idiomatic inner loop: host→device latency (or a slow tunnel)
-        stops mattering when k steps ride one XLA program.
+        TPU-idiomatic inner loop: host dispatch latency stops mattering
+        when k steps ride one XLA program.
 
         Returns ``(new_state, metrics)`` where each metrics leaf is stacked
         per-step, shape ``(k,)`` — log ``metrics["loss"][-1]`` or the mean.

@@ -320,9 +320,10 @@ class SlotEngine:
     def __init__(self, model, params, num_slots: int = 8,
                  max_len: Optional[int] = None, cache_dtype=None,
                  min_bucket: int = 16):
-        import jax
         import jax.numpy as jnp
 
+        from ..utils.compile_cache import ensure_compile_cache
+        ensure_compile_cache()  # before the pool programs compile
         self.model = model
         self.params = params
         self.num_slots = int(num_slots)

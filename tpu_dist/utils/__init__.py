@@ -2,6 +2,7 @@
 tracing/metrics rows are bare prints; these are the structured equivalents)."""
 
 from .backoff import BackoffDeadlineError, retry_call
+from .compile_cache import ensure_compile_cache
 from .logging import MetricLogger, log_event, rank_zero_print
 from .memory import (max_memory_allocated, mem_get_info, memory_allocated,
                      memory_stats, memory_summary)
@@ -11,7 +12,7 @@ from .metrics import (LatencyHistogram, accuracy, collective_counters,
 from .profiler import StepTimer, trace
 
 __all__ = ["rank_zero_print", "MetricLogger", "log_event", "StepTimer",
-           "trace",
+           "trace", "ensure_compile_cache",
            "retry_call", "BackoffDeadlineError",
            "topk_accuracy", "accuracy", "confusion_matrix",
            "record_collective", "collective_counters",

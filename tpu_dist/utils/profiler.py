@@ -37,10 +37,9 @@ class StepTimer:
     """Wall-clock step timing with warmup exclusion and percentile summary.
 
     NOTE on async dispatch: a step's wall time only reflects device time if
-    the loop blocks on the step's output (e.g. reads the loss).  For
-    throughput measurement prefer bench.py's chained-N differencing, which
-    cancels dispatch/readback overhead (important under remote-device
-    tunnels where a sync costs a full RTT).
+    the loop blocks on the step's output (e.g. reads the loss or calls
+    ``block_until_ready``); for throughput, time a scanned chunk of steps
+    (``ddp.train_chunk``) so host dispatch is paid once per chunk.
     """
 
     def __init__(self, warmup: int = 3):
