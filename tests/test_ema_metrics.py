@@ -1,5 +1,4 @@
-"""EMA parameter averaging (vs torch AveragedModel) and eval metrics
-(vs hand/torch references)."""
+"""EMA parameter averaging (vs torch AveragedModel)."""
 
 import jax
 import jax.numpy as jnp
@@ -8,7 +7,6 @@ import pytest
 import torch
 
 from tpu_dist import optim
-from tpu_dist.utils import accuracy, confusion_matrix, topk_accuracy
 
 
 class TestEMA:
@@ -83,43 +81,3 @@ class TestEMA:
 
         s1 = step(state, p)
         assert int(s1["step"]) == 1
-
-
-class TestMetrics:
-    def test_topk_against_torch(self, rng):
-        logits = rng.standard_normal((64, 10)).astype(np.float32)
-        targets = rng.integers(0, 10, 64)
-        a1, a5 = topk_accuracy(jnp.asarray(logits), jnp.asarray(targets),
-                               ks=(1, 5))
-        tl = torch.tensor(logits)
-        tt = torch.tensor(targets)
-        _, pred = tl.topk(5, 1)
-        correct = pred.eq(tt.view(-1, 1))
-        t1 = correct[:, :1].any(1).float().mean().item()
-        t5 = correct.any(1).float().mean().item()
-        assert float(a1) == pytest.approx(t1)
-        assert float(a5) == pytest.approx(t5)
-        assert float(accuracy(jnp.asarray(logits),
-                              jnp.asarray(targets))) == pytest.approx(t1)
-
-    def test_topk_validation(self):
-        with pytest.raises(ValueError, match="k must be"):
-            topk_accuracy(jnp.zeros((4, 3)), jnp.zeros(4, jnp.int32),
-                          ks=(5,))
-        with pytest.raises(ValueError, match="non-empty"):
-            topk_accuracy(jnp.zeros((4, 3)), jnp.zeros(4, jnp.int32), ks=())
-
-    def test_confusion_matrix(self):
-        preds = jnp.asarray([0, 1, 1, 2, 2, 2])
-        tgt = jnp.asarray([0, 1, 2, 2, 2, 0])
-        cm = np.asarray(confusion_matrix(preds, tgt, num_classes=3))
-        want = np.array([[1, 0, 1],
-                         [0, 1, 0],
-                         [0, 1, 2]])
-        np.testing.assert_array_equal(cm, want)
-        assert cm.sum() == 6
-
-    def test_confusion_matrix_drops_out_of_range(self):
-        cm = np.asarray(confusion_matrix(jnp.asarray([0, 7]),
-                                         jnp.asarray([0, 0]), num_classes=2))
-        assert cm.sum() == 1 and cm[0, 0] == 1

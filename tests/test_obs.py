@@ -165,7 +165,7 @@ def test_metrics_shim_reads_obs_stream():
     from tpu_dist.utils import metrics
     metrics.reset_collective_counters()
     obs.record_transport("send", "dataplane", 10, 0.001)
-    metrics.record_collective("send", "dataplane", 20, 0.002)
+    obs.record_transport("send", "dataplane", 20, 0.002)
     c = metrics.collective_counters()
     assert c["send/dataplane"]["calls"] == 2
     assert c["send/dataplane"]["bytes"] == 30

@@ -152,8 +152,8 @@ def test_rmsprop_adagrad_reject_bad_hparams():
 
 
 def test_memory_introspection_smoke():
-    """torch.cuda.memory_* analogues: callable everywhere; on platforms
-    with no allocator stats (CPU tests) they degrade to 0/(0,0) instead
+    """torch.cuda.max_memory_allocated analogue: callable everywhere; on
+    platforms with no allocator stats (CPU tests) it degrades to 0 instead
     of raising."""
     from tpu_dist import utils
 
@@ -161,12 +161,8 @@ def test_memory_introspection_smoke():
     live.block_until_ready()
     stats = utils.memory_stats()
     assert isinstance(stats, dict)
-    allocated = utils.memory_allocated()
     peak = utils.max_memory_allocated()
-    free, total = utils.mem_get_info()
-    assert 0 <= allocated and 0 <= peak
-    assert 0 <= free and (total == 0 or free <= total)
-    assert isinstance(utils.memory_summary(), str)
+    assert 0 <= peak
     if stats:  # a real accelerator: the live buffer must show up
-        assert allocated > 0 or peak > 0
+        assert peak > 0
     del live

@@ -6,7 +6,7 @@ import threading
 import numpy as np
 import pytest
 
-from tpu_dist.utils import LatencyHistogram, MetricLogger, StepTimer
+from tpu_dist.utils import LatencyHistogram, MetricLogger
 
 
 class TestLatencyHistogram:
@@ -106,20 +106,3 @@ class TestMetricLogger:
         log.push(step=1, loss=jnp.asarray(2.0))
         out = log.push(step=2, loss=jnp.asarray(4.0))
         assert out == {"loss": 3.0}
-
-
-class TestStepTimer:
-    def test_warmup_excluded_and_stats(self):
-        t = StepTimer(warmup=2)
-        import time
-        for i in range(6):
-            with t:
-                time.sleep(0.001)
-        assert t.steps == 4
-        assert t.mean() > 0
-        assert t.percentile(50) <= t.percentile(95) or t.steps < 2
-        assert "steps=4" in t.summary()
-
-    def test_empty(self):
-        t = StepTimer()
-        assert t.mean() == 0.0 and t.percentile(50) == 0.0

@@ -36,13 +36,14 @@ def write_slot_rows(cache, rows, slot):
     ``index`` is ignored)."""
     slot = jnp.asarray(slot, jnp.int32)
     out = {}
-    for path, pool in cache.items():
-        row = rows[path]
-        out[path] = {
-            k: jax.lax.dynamic_update_slice(
-                pool[k], row[k].astype(pool[k].dtype),
-                (slot,) + (0,) * (pool[k].ndim - 1))
-            for k in pool}
+    with jax.named_scope("cache_write"):
+        for path, pool in cache.items():
+            row = rows[path]
+            out[path] = {
+                k: jax.lax.dynamic_update_slice(
+                    pool[k], row[k].astype(pool[k].dtype),
+                    (slot,) + (0,) * (pool[k].ndim - 1))
+                for k in pool}
     return out
 
 

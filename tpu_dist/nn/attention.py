@@ -260,72 +260,81 @@ class MultiheadSelfAttention(Module):
         if int8_cache:
             kq, ks = self._quantize_kv(k)
             vq, vs = self._quantize_kv(v)
-        if index.ndim:
-            # per-slot write positions (continuous batching, serve/engine):
-            # index is (B,) — every cache slot appends at its OWN position
-            # and masks to its own prefix.  Rows whose slot is free write
-            # garbage the next prefill fully overwrites (and mask away).
-            b = q.shape[0]
-            rows = jnp.arange(b)[:, None]                     # (B, 1)
-            cols = index[:, None] + jnp.arange(t)[None, :]    # (B, t)
-            if int8_cache:
-                st = dict(st,
-                          k=st["k"].at[rows, cols].set(kq),
-                          v=st["v"].at[rows, cols].set(vq),
-                          k_scale=st["k_scale"].at[rows, cols].set(ks),
-                          v_scale=st["v_scale"].at[rows, cols].set(vs))
+        # scopes: the cache writes and the masked attention over the
+        # whole cache are told apart in a device trace
+        with jax.named_scope("cache_update"):
+            if index.ndim:
+                # per-slot write positions (continuous batching,
+                # serve/engine): index is (B,) — every cache slot appends at
+                # its OWN position and masks to its own prefix.  Rows whose
+                # slot is free write garbage the next prefill fully
+                # overwrites (and mask away).
+                b = q.shape[0]
+                rows = jnp.arange(b)[:, None]                     # (B, 1)
+                cols = index[:, None] + jnp.arange(t)[None, :]    # (B, t)
+                if int8_cache:
+                    st = dict(st,
+                              k=st["k"].at[rows, cols].set(kq),
+                              v=st["v"].at[rows, cols].set(vq),
+                              k_scale=st["k_scale"].at[rows, cols].set(ks),
+                              v_scale=st["v_scale"].at[rows, cols].set(vs))
+                else:
+                    st = dict(st,
+                              k=st["k"].at[rows, cols].set(
+                                  k.astype(st["k"].dtype)),
+                              v=st["v"].at[rows, cols].set(
+                                  v.astype(st["v"].dtype)))
+                ctx.put_state(self._path, dict(st, index=index + t))
+                tmax = st["k"].shape[1]
+                kpos = jnp.arange(tmax)
+                # (B, 1, t, Tmax): per-row causal+unwritten mask, broadcast
+                # over heads
+                mask = (kpos[None, None, :] <= cols[:, :, None])[:, None]
             else:
-                st = dict(st,
-                          k=st["k"].at[rows, cols].set(
-                              k.astype(st["k"].dtype)),
-                          v=st["v"].at[rows, cols].set(
-                              v.astype(st["v"].dtype)))
-            ctx.put_state(self._path, dict(st, index=index + t))
-            tmax = st["k"].shape[1]
-            kpos = jnp.arange(tmax)
-            # (B, 1, t, Tmax): per-row causal+unwritten mask, broadcast
-            # over heads
-            mask = (kpos[None, None, :] <= cols[:, :, None])[:, None]
-        else:
-            if int8_cache:
-                st = dict(
-                    st,
-                    k=jax.lax.dynamic_update_slice(st["k"], kq,
-                                                   (0, index, 0, 0)),
-                    v=jax.lax.dynamic_update_slice(st["v"], vq,
-                                                   (0, index, 0, 0)),
-                    k_scale=jax.lax.dynamic_update_slice(
-                        st["k_scale"], ks, (0, index, 0)),
-                    v_scale=jax.lax.dynamic_update_slice(
-                        st["v_scale"], vs, (0, index, 0)))
-            else:
-                st = dict(
-                    st,
-                    k=jax.lax.dynamic_update_slice(
-                        st["k"], k.astype(st["k"].dtype), (0, index, 0, 0)),
-                    v=jax.lax.dynamic_update_slice(
-                        st["v"], v.astype(st["v"].dtype), (0, index, 0, 0)))
-            ctx.put_state(self._path, dict(st, index=index + t))
-            tmax = st["k"].shape[1]
-            qpos = index + jnp.arange(t)[:, None]           # (t, 1) global
-            kpos = jnp.arange(tmax)[None, :]                # (1, Tmax)
-            mask = kpos <= qpos                             # causal + unwritten
-        if not int8_cache:
-            return scaled_dot_product_attention(
-                q, st["k"].astype(q.dtype), st["v"].astype(q.dtype),
-                mask=mask, impl="dense")
-        # hoisted-scale dense attention over the int8 cache
-        sm = 1.0 / math.sqrt(self.head_dim)
-        s = jnp.einsum("bthd,bshd->bhts", q, st["k"].astype(q.dtype),
-                       preferred_element_type=jnp.float32)
-        s = s * sm * jnp.transpose(st["k_scale"], (0, 2, 1))[:, :, None, :]
-        s = jnp.where(mask if mask.ndim == 4 else mask[None, None],
-                      s, -jnp.inf)
-        p = jax.nn.softmax(s, axis=-1)
-        pv = (p * jnp.transpose(st["v_scale"], (0, 2, 1))[:, :, None, :]
-              ).astype(q.dtype)
-        return jnp.einsum("bhts,bshd->bthd", pv, st["v"].astype(q.dtype),
-                          preferred_element_type=jnp.float32).astype(q.dtype)
+                if int8_cache:
+                    st = dict(
+                        st,
+                        k=jax.lax.dynamic_update_slice(st["k"], kq,
+                                                       (0, index, 0, 0)),
+                        v=jax.lax.dynamic_update_slice(st["v"], vq,
+                                                       (0, index, 0, 0)),
+                        k_scale=jax.lax.dynamic_update_slice(
+                            st["k_scale"], ks, (0, index, 0)),
+                        v_scale=jax.lax.dynamic_update_slice(
+                            st["v_scale"], vs, (0, index, 0)))
+                else:
+                    st = dict(
+                        st,
+                        k=jax.lax.dynamic_update_slice(
+                            st["k"], k.astype(st["k"].dtype),
+                            (0, index, 0, 0)),
+                        v=jax.lax.dynamic_update_slice(
+                            st["v"], v.astype(st["v"].dtype),
+                            (0, index, 0, 0)))
+                ctx.put_state(self._path, dict(st, index=index + t))
+                tmax = st["k"].shape[1]
+                qpos = index + jnp.arange(t)[:, None]       # (t, 1) global
+                kpos = jnp.arange(tmax)[None, :]            # (1, Tmax)
+                mask = kpos <= qpos                   # causal + unwritten
+        with jax.named_scope("attend"):
+            if not int8_cache:
+                return scaled_dot_product_attention(
+                    q, st["k"].astype(q.dtype), st["v"].astype(q.dtype),
+                    mask=mask, impl="dense")
+            # hoisted-scale dense attention over the int8 cache
+            sm = 1.0 / math.sqrt(self.head_dim)
+            s = jnp.einsum("bthd,bshd->bhts", q, st["k"].astype(q.dtype),
+                           preferred_element_type=jnp.float32)
+            s = s * sm * jnp.transpose(
+                st["k_scale"], (0, 2, 1))[:, :, None, :]
+            s = jnp.where(mask if mask.ndim == 4 else mask[None, None],
+                          s, -jnp.inf)
+            p = jax.nn.softmax(s, axis=-1)
+            pv = (p * jnp.transpose(st["v_scale"], (0, 2, 1))[:, :, None, :]
+                  ).astype(q.dtype)
+            return jnp.einsum(
+                "bhts,bshd->bthd", pv, st["v"].astype(q.dtype),
+                preferred_element_type=jnp.float32).astype(q.dtype)
 
     def init_cache(self, batch: int, max_len: int, dtype=jnp.float32):
         """Per-layer KV cache entry (used via TransformerLM.init_cache).

@@ -223,7 +223,12 @@ class Module:
 
     def __call__(self, *args, **kwargs):
         _ctx()  # modules may only be invoked during apply()
-        return self.forward(*args, **kwargs)
+        # the module's own name as a scope, so the scope stack of every
+        # operation reads as the module path (block3/attn, block3/mlp/1);
+        # metadata only, the compiled program does not change
+        name = (self._path or type(self).__name__).rpartition(".")[2]
+        with jax.named_scope(name):
+            return self.forward(*args, **kwargs)
 
     # -- conveniences ----------------------------------------------------------
     def param_count(self, params) -> int:

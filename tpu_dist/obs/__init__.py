@@ -25,18 +25,26 @@ The standing observability surface for the eager/distributed stack
    ``trace_event`` timeline (a track per rank, collectives aligned by
    sequence number) and name the hang: which rank is behind, at which
    collective seq and call-site, and which ranks were already waiting.
+
+Beside them, and sharing nothing with them but a request's id,
+:mod:`.spans` puts the host phases of the compiled path (the decode loop,
+the scheduler's wait, the training step's dispatch) on the profiler's
+clock and into :func:`phase_times` — always on, for time on the chip
+rather than hangs of the host collectives.
 """
 
-from . import hooks, recorder, trace
+from . import hooks, recorder, spans, trace
 from .hooks import (collective_span, fetch_tail, install_from_env, note_path,
                     post_tail, render_tail)
 from .recorder import (FlightRecorder, default_dump_dir, dump_now, dump_path,
                        enabled, get_recorder, obs_key, record_transport,
                        reset, reset_transport_counters, transport_counters)
+from .spans import phase_times, reset_phases, span
 from .trace import diagnose, merge_trace, read_dumps, render_diagnosis
 
 __all__ = [
-    "recorder", "hooks", "trace",
+    "recorder", "hooks", "trace", "spans",
+    "span", "phase_times", "reset_phases",
     "FlightRecorder", "enabled", "get_recorder", "reset", "dump_now",
     "record_transport", "transport_counters", "reset_transport_counters",
     "obs_key", "default_dump_dir", "dump_path",

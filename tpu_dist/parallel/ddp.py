@@ -43,6 +43,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..nn.layers import BatchNorm2d
 from ..nn.module import Module
+from ..obs.spans import span
 
 __all__ = ["TrainState", "DistributedDataParallel", "convert_sync_batchnorm"]
 
@@ -168,6 +169,7 @@ class DistributedDataParallel:
             convert_sync_batchnorm(module, self.axis)
         self._train_step = None
         self._train_chunk = None
+        self._dispatched = 0    # steps handed to the device: spans' step=
         self._train_repeat_cache = {}
         self._eval_step = None
         self._forward = None
@@ -264,10 +266,11 @@ class DistributedDataParallel:
 
                 def loss_local(p):
                     if cdtype is not None:
-                        p = jax.tree.map(
-                            lambda v: v.astype(cdtype)
-                            if jnp.issubdtype(v.dtype, jnp.floating) else v,
-                            p)
+                        with jax.named_scope("cast_params"):
+                            p = jax.tree.map(
+                                lambda v: v.astype(cdtype)
+                                if jnp.issubdtype(v.dtype, jnp.floating)
+                                else v, p)
                     xc = (xb.astype(cdtype)
                           if cdtype is not None and
                           jnp.issubdtype(xb.dtype, jnp.floating) else xb)
@@ -285,7 +288,8 @@ class DistributedDataParallel:
                     else:
                         out = module.apply(p, xc, training=True, rng=key)
                         new_ms = ms
-                    return loss_fn(out, yb), (out, new_ms)
+                    with jax.named_scope("loss"):
+                        return loss_fn(out, yb), (out, new_ms)
 
                 (loss, (out, new_ms)), g = jax.value_and_grad(
                     loss_local, has_aux=True)(p_var)
@@ -327,45 +331,53 @@ class DistributedDataParallel:
             if zero1:
                 # reduce-scatter averaged grads; update 1/n of the flat
                 # parameter vector per device; all-gather updated params
-                flat_g = _flatten_params(local_grads)
-                padded = _ceil_to(flat_g.size, n)
-                flat_g = jnp.pad(flat_g, (0, padded - flat_g.size))
-                if comm_dtype is None:
-                    g_shard = lax.psum_scatter(
-                        flat_g, axis, scatter_dimension=0, tiled=True) / n
-                else:
-                    g_shard = lax.psum_scatter(
-                        (flat_g / n).astype(comm_dtype), axis,
-                        scatter_dimension=0, tiled=True).astype(flat_g.dtype)
-                flat_p = _flatten_params(params)
-                flat_p = jnp.pad(flat_p, (0, padded - flat_p.size))
-                chunk = padded // n
-                me = lax.axis_index(axis)
-                p_shard = lax.dynamic_slice_in_dim(flat_p, me * chunk, chunk)
-                new_shard, new_opt = optimizer.update(
-                    {"flat": g_shard}, opt_state, {"flat": p_shard})
-                # all-gather the updated shards as a psum of offset-placed
-                # contributions: psum of varying inputs yields a VMA-invariant
-                # (replicated) output, which the P() params out_spec needs —
-                # lax.all_gather would leave the value marked varying
-                contrib = jnp.zeros((padded,), new_shard["flat"].dtype)
-                contrib = lax.dynamic_update_slice_in_dim(
-                    contrib, new_shard["flat"], me * chunk, 0)
-                flat_new = lax.psum(contrib, axis)
-                new_params = _unflatten_params(flat_new, params)
+                with jax.named_scope("grad_reduce"):
+                    flat_g = _flatten_params(local_grads)
+                    padded = _ceil_to(flat_g.size, n)
+                    flat_g = jnp.pad(flat_g, (0, padded - flat_g.size))
+                    if comm_dtype is None:
+                        g_shard = lax.psum_scatter(
+                            flat_g, axis, scatter_dimension=0,
+                            tiled=True) / n
+                    else:
+                        g_shard = lax.psum_scatter(
+                            (flat_g / n).astype(comm_dtype), axis,
+                            scatter_dimension=0,
+                            tiled=True).astype(flat_g.dtype)
+                with jax.named_scope("optimizer"):
+                    flat_p = _flatten_params(params)
+                    flat_p = jnp.pad(flat_p, (0, padded - flat_p.size))
+                    chunk = padded // n
+                    me = lax.axis_index(axis)
+                    p_shard = lax.dynamic_slice_in_dim(flat_p, me * chunk,
+                                                       chunk)
+                    new_shard, new_opt = optimizer.update(
+                        {"flat": g_shard}, opt_state, {"flat": p_shard})
+                    # all-gather the updated shards as a psum of
+                    # offset-placed contributions: psum of varying inputs
+                    # yields a VMA-invariant (replicated) output, which the
+                    # P() params out_spec needs — lax.all_gather would leave
+                    # the value marked varying
+                    contrib = jnp.zeros((padded,), new_shard["flat"].dtype)
+                    contrib = lax.dynamic_update_slice_in_dim(
+                        contrib, new_shard["flat"], me * chunk, 0)
+                    flat_new = lax.psum(contrib, axis)
+                    new_params = _unflatten_params(flat_new, params)
             else:
-                if comm_dtype is None:
-                    grads = jax.tree.map(lambda g: lax.pmean(g, axis),
-                                         local_grads)
-                else:
-                    grads = jax.tree.map(
-                        lambda g: lax.psum((g / n).astype(comm_dtype),
-                                           axis).astype(g.dtype)
-                        if jnp.issubdtype(g.dtype, jnp.floating) else
-                        lax.pmean(g, axis),
-                        local_grads)
-                new_params, new_opt = optimizer.update(grads, opt_state,
-                                                       params)
+                with jax.named_scope("grad_reduce"):
+                    if comm_dtype is None:
+                        grads = jax.tree.map(lambda g: lax.pmean(g, axis),
+                                             local_grads)
+                    else:
+                        grads = jax.tree.map(
+                            lambda g: lax.psum((g / n).astype(comm_dtype),
+                                               axis).astype(g.dtype)
+                            if jnp.issubdtype(g.dtype, jnp.floating) else
+                            lax.pmean(g, axis),
+                            local_grads)
+                with jax.named_scope("optimizer"):
+                    new_params, new_opt = optimizer.update(
+                        grads, opt_state, params)
 
             if has_state:
                 # keep replicated-state invariant: average the per-replica
@@ -486,7 +498,10 @@ class DistributedDataParallel:
             raise ValueError("train_step requires optimizer= and loss_fn=")
         if self._train_step is None:
             self._train_step = self._build_train_step(state)
-        return self._train_step(state, x, y)
+        with span("train.dispatch", step=self._dispatched):
+            out = self._train_step(state, x, y)
+        self._dispatched += 1
+        return out
 
     def train_chunk(self, state: TrainState, xs, ys):
         """Run ``xs.shape[0]`` fused train steps in ONE dispatch.
@@ -506,7 +521,11 @@ class DistributedDataParallel:
             raise ValueError("train_chunk requires optimizer= and loss_fn=")
         if self._train_chunk is None:
             self._train_chunk = self._build_train_chunk(state)
-        return self._train_chunk(state, xs, ys)
+        steps = int(xs.shape[0])
+        with span("train.dispatch", step=self._dispatched, steps=steps):
+            out = self._train_chunk(state, xs, ys)
+        self._dispatched += steps
+        return out
 
     def train_repeat(self, state: TrainState, x, y, num_steps: int):
         """``num_steps`` fused steps on the SAME batch in one dispatch.

@@ -41,12 +41,24 @@ from typing import Callable, List, Optional
 
 import numpy as np
 
+from ..obs.spans import phase_times, reset_phases, span
 from ..utils.metrics import LatencyHistogram
 
 __all__ = ["SlotEngine", "Request", "RequestHandle", "ServeError",
            "QueueFullError", "SchedulerDrainingError",
            "SchedulerClosedError", "DeadlineExceededError",
-           "RequestCancelledError", "error_outcome", "sample_tokens"]
+           "RequestCancelledError", "error_outcome", "sample_tokens",
+           "SERVE_PHASES"]
+
+# The serving loop's host phases (tpu_dist.obs.spans), in the order one
+# iteration runs them: ``stats()["phases"]`` reports exactly these and
+# ``reset_stats()`` zeroes them.  ``stage.put`` runs on the staging thread
+# (inside ``prefill.prepare`` only when ``_admit`` finds nothing staged);
+# ``sched.wait`` is the scheduler's, every other one is the loop thread's.
+SERVE_PHASES = ("sweep", "sched.wait", "stage.put",
+                "prefill.prepare", "prefill.dispatch", "prefill.readback",
+                "prefill.emit",
+                "decode.dispatch", "decode.readback", "decode.emit")
 
 
 class ServeError(RuntimeError):
@@ -357,6 +369,7 @@ class SlotEngine:
         self.generated_tokens = 0
         self._occupied_slot_steps = 0
         self._decode_steps = 0
+        self._iterations = 0    # decode iterations ever run: spans' step=
 
         self._build_programs()
 
@@ -374,17 +387,21 @@ class SlotEngine:
 
         def _decode_fn(params, cache, tokens, lengths, temps, keys, steps,
                        sampling):
-            logits, cache = model.decode_step(params, tokens, lengths,
-                                              cache)
-            return sample_tokens(logits, temps, keys, steps,
-                                 sampling), cache
+            with jax.named_scope("decode"):
+                logits, cache = model.decode_step(params, tokens, lengths,
+                                                  cache)
+            with jax.named_scope("sample"):
+                return sample_tokens(logits, temps, keys, steps,
+                                     sampling), cache
 
         def _prefill_fn(params, cache, prompt, length, slot, temp, key,
                         sampling):
-            logits, cache = model.prefill_into_slot(params, prompt, length,
-                                                    slot, cache)
-            tok = sample_tokens(logits[None], temp[None], key[None],
-                                jnp.zeros((1,), jnp.int32), sampling)
+            with jax.named_scope("prefill"):
+                logits, cache = model.prefill_into_slot(params, prompt,
+                                                        length, slot, cache)
+            with jax.named_scope("sample"):
+                tok = sample_tokens(logits[None], temp[None], key[None],
+                                    jnp.zeros((1,), jnp.int32), sampling)
             return tok[0], cache
 
         # the cache is donated (the pool buffer is updated in place instead
@@ -453,9 +470,10 @@ class SlotEngine:
         import jax
 
         bucket = self.bucket_for(len(req.prompt))
-        padded = np.zeros(bucket, np.int32)
-        padded[:len(req.prompt)] = req.prompt
-        req.staged = jax.device_put(padded)
+        with span("stage.put", req=req.id, bucket=bucket):
+            padded = np.zeros(bucket, np.int32)
+            padded[:len(req.prompt)] = req.prompt
+            req.staged = jax.device_put(padded)
         return req.staged
 
     # -- the two pool operations --------------------------------------------
@@ -495,64 +513,75 @@ class SlotEngine:
     def _admit(self, req: Request, slot: int) -> int:
         """The unconditional admission half: prefill + slot bookkeeping
         (every refusal already ruled out by :meth:`_admission_slot`)."""
-        req.t_admit = _now()
-        self.hist_queue.observe(req.t_admit - req.t_submit)
-        staged = req.staged if req.staged is not None else self.stage(req)
-
         import jax
-        key = np.asarray(
-            jax.random.key_data(jax.random.key(req.seed)), np.uint32)
-        tok_dev, self.cache = self._prefill(
-            self.params, self.cache, staged,
-            np.int32(len(req.prompt)), np.int32(slot),
-            np.float32(req.temperature), key, req.temperature > 0)
-        tok = int(tok_dev)
+
+        ids = {"req": req.id, "slot": slot}
+        req.t_admit = _now()
+        with span("prefill.prepare", **ids):
+            self.hist_queue.observe(req.t_admit - req.t_submit)
+            staged = (req.staged if req.staged is not None
+                      else self.stage(req))
+            key = np.asarray(
+                jax.random.key_data(jax.random.key(req.seed)), np.uint32)
+        with span("prefill.dispatch", bucket=int(staged.shape[0]), **ids):
+            tok_dev, self.cache = self._prefill(
+                self.params, self.cache, staged,
+                np.int32(len(req.prompt)), np.int32(slot),
+                np.float32(req.temperature), key, req.temperature > 0)
+        with span("prefill.readback", **ids):
+            tok = int(tok_dev)
         t_pf = _now()
         self.hist_prefill.observe(t_pf - req.t_admit)
 
-        self.lengths[slot] = len(req.prompt)
-        self.tokens[slot] = tok
-        self.temps[slot] = req.temperature
-        self.keys[slot] = key
-        self.steps[slot] = 1
-        self.active[slot] = True
-        self.slot_req[slot] = req
-        self._obs_admit(req, slot, t_pf)
+        with span("prefill.emit", **ids):
+            self.lengths[slot] = len(req.prompt)
+            self.tokens[slot] = tok
+            self.temps[slot] = req.temperature
+            self.keys[slot] = key
+            self.steps[slot] = 1
+            self.active[slot] = True
+            self.slot_req[slot] = req
+            self._obs_admit(req, slot, t_pf)
 
-        req.emit(tok)
-        self.hist_ttft.observe(_now() - req.t_submit)
-        self.generated_tokens += 1
-        self._maybe_finish(slot, tok)
+            req.emit(tok)
+            self.hist_ttft.observe(_now() - req.t_submit)
+            self.generated_tokens += 1
+            self._maybe_finish(slot, tok)
         return slot
 
     def step(self) -> int:
         """One decode iteration over the pool; returns tokens emitted."""
         if not self.active.any():
             return 0
-        t0 = _now()
-        nxt_dev, self.cache = self._decode(
-            self.params, self.cache, self.tokens, self.lengths,
-            self.temps, self.keys, self.steps,
-            bool(np.any(self.temps > 0)))
-        nxt = np.asarray(nxt_dev)
-        dt = _now() - t0
+        self._iterations += 1
         n_active = int(self.active.sum())
+        ids = {"step": self._iterations, "active": n_active}
+        t0 = _now()
+        with span("decode.dispatch", **ids):
+            nxt_dev, self.cache = self._decode(
+                self.params, self.cache, self.tokens, self.lengths,
+                self.temps, self.keys, self.steps,
+                bool(np.any(self.temps > 0)))
+        with span("decode.readback", **ids):
+            nxt = np.asarray(nxt_dev)
+        dt = _now() - t0
         self._decode_steps += 1
         self._occupied_slot_steps += n_active
         self.hist_token.observe(dt)
 
         emitted = 0
-        for slot in np.flatnonzero(self.active):
-            slot = int(slot)
-            req = self.slot_req[slot]
-            tok = int(nxt[slot])
-            self.lengths[slot] += 1
-            self.steps[slot] += 1
-            self.tokens[slot] = tok
-            req.emit(tok)
-            self.generated_tokens += 1
-            emitted += 1
-            self._maybe_finish(slot, tok)
+        with span("decode.emit", **ids):
+            for slot in np.flatnonzero(self.active):
+                slot = int(slot)
+                req = self.slot_req[slot]
+                tok = int(nxt[slot])
+                self.lengths[slot] += 1
+                self.steps[slot] += 1
+                self.tokens[slot] = tok
+                req.emit(tok)
+                self.generated_tokens += 1
+                emitted += 1
+                self._maybe_finish(slot, tok)
         return emitted
 
     # -- completion / failure ------------------------------------------------
@@ -593,11 +622,12 @@ class SlotEngine:
         decoding to ``max_new_tokens`` for nobody.  The request terminates
         with the named error and its obs span closes ``error:Cancelled`` /
         ``error:DeadlineExceededError``.  Returns the slots freed."""
-        expired = self._sweep_candidates()
-        if expired:
-            self._pre_free([slot for slot, _ in expired])
-        for slot, exc in expired:
-            self.fail_slot(slot, exc)
+        with span("sweep", step=self._iterations + 1):
+            expired = self._sweep_candidates()
+            if expired:
+                self._pre_free([slot for slot, _ in expired])
+            for slot, exc in expired:
+                self.fail_slot(slot, exc)
         return len(expired)
 
     def _sweep_candidates(self) -> List[tuple]:
@@ -681,7 +711,9 @@ class SlotEngine:
 
     def reset_stats(self) -> None:
         """Zero the histograms/counters (benchmarks: exclude warmup
-        compiles from the measured window).  Slot state is untouched."""
+        compiles from the measured window), the serving loop's phases
+        (:data:`SERVE_PHASES`, process-wide) among them.  Slot state is
+        untouched."""
         self.hist_queue = LatencyHistogram()
         self.hist_prefill = LatencyHistogram()
         self.hist_ttft = LatencyHistogram()
@@ -691,6 +723,7 @@ class SlotEngine:
         self.generated_tokens = 0
         self._occupied_slot_steps = 0
         self._decode_steps = 0
+        reset_phases(SERVE_PHASES)
 
     def stats(self) -> dict:
         return {
@@ -703,4 +736,5 @@ class SlotEngine:
             "ttft": self.hist_ttft.summary(),
             "decode_step": self.hist_token.summary(),
             "e2e": self.hist_e2e.summary(),
+            "phases": phase_times(SERVE_PHASES),
         }

@@ -29,6 +29,7 @@ import threading
 import time
 from typing import Callable, Optional
 
+from ..obs.spans import span
 from .engine import (DeadlineExceededError, QueueFullError, Request,
                      RequestCancelledError, RequestHandle,
                      SchedulerClosedError, SchedulerDrainingError,
@@ -400,7 +401,8 @@ class Scheduler:
                 else:
                     with self._idle_cv:
                         self._idle_cv.notify_all()
-                    time.sleep(0.01)
+                    with span("sched.wait", why="drain"):
+                        time.sleep(0.01)
                 continue
             # -- the iteration boundary: cancelled / past-deadline slots
             # free HERE, before admission sees the free-slot count — a
@@ -435,12 +437,15 @@ class Scheduler:
                     break
             elif held:
                 # inside the coalescing window: short bounded nap
-                time.sleep(min(self.batch_window / 4, 0.002))
+                with span("sched.wait", why="coalesce"):
+                    time.sleep(min(self.batch_window / 4, 0.002))
             else:
                 with self._idle_cv:
                     self._idle_cv.notify_all()
                 try:
-                    held.append(self._staged.get(timeout=0.05))
+                    with span("sched.wait", why="idle"):
+                        arrived = self._staged.get(timeout=0.05)
+                    held.append(arrived)
                     window_start = _now()
                 except queue.Empty:
                     pass

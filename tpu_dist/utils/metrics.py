@@ -1,33 +1,27 @@
-"""Evaluation metrics — jit-friendly counterparts of the torch recipes —
-plus per-collective transport counters.
+"""Streaming latency percentiles, and the per-collective transport
+counters.
 
-The reference computes accuracy host-side per batch
-(`/root/reference/mpspawn_dist.py:125-131`: argmax + eq + sum).  These
-helpers keep the computation in the XLA graph (device reductions, one
-scalar out) and add the standard top-k form.
+``LatencyHistogram`` is the one percentile engine of the serving layer and
+of the host phases (:mod:`tpu_dist.obs.spans`).
 
 The collective counters aggregate bytes/latency per (op, transport) for the
 eager host collectives, so a training job can answer "how much gradient
 traffic rode the p2p data plane vs. the store, and at what rate?" without a
-profiler.  Since the ``tpu_dist.obs`` flight recorder landed, the counters
-live in :mod:`tpu_dist.obs.recorder` — the collectives record into ONE
-ingestion point (``record_transport``) that feeds both the aggregates and
-the armed event stream, so the counters and the flight recorder can never
-disagree.  The three functions below are kept as the stable public API.
+profiler.  They live in :mod:`tpu_dist.obs.recorder` — the collectives
+record into ONE ingestion point (``record_transport``) that feeds both the
+aggregates and the armed event stream, so the counters and the flight
+recorder can never disagree.  The two functions below are the operator's
+read side (docs/collectives.md).
 """
 
 from __future__ import annotations
 
 import math
 import threading
-from typing import Dict, Optional, Sequence, Tuple
+from typing import Dict, Optional
 
-import jax
-import jax.numpy as jnp
-
-__all__ = ["topk_accuracy", "accuracy", "confusion_matrix",
-           "record_collective", "collective_counters",
-           "reset_collective_counters", "LatencyHistogram"]
+__all__ = ["collective_counters", "reset_collective_counters",
+           "LatencyHistogram"]
 
 
 class LatencyHistogram:
@@ -140,17 +134,6 @@ class LatencyHistogram:
 # -- host-collective transport counters (shims over tpu_dist.obs) -------------
 
 
-def record_collective(op: str, transport: str, nbytes: int,
-                      seconds: float) -> None:
-    """Account one eager collective: ``op`` (all_reduce, send, ...) over
-    ``transport`` ('dataplane' | 'store' | 'mesh') moving ``nbytes`` of
-    array payload in ``seconds`` of wall time.  Shim over
-    :func:`tpu_dist.obs.recorder.record_transport` — the flight recorder's
-    ingestion point."""
-    from ..obs import recorder as _obs
-    _obs.record_transport(op, transport, nbytes, seconds)
-
-
 def collective_counters(reset: bool = False) -> Dict[str, Dict[str, float]]:
     """Snapshot of the per-``op/transport`` counters, each entry
     ``{calls, bytes, seconds, mb_per_s}``.  ``reset=True`` atomically
@@ -163,43 +146,3 @@ def collective_counters(reset: bool = False) -> Dict[str, Dict[str, float]]:
 def reset_collective_counters() -> None:
     from ..obs import recorder as _obs
     _obs.reset_transport_counters()
-
-
-def topk_accuracy(logits, targets, ks: Sequence[int] = (1, 5)):
-    """Fraction of rows whose target is within the top-k logits, for each
-    ``k`` — the torchvision ``accuracy(output, target, topk=(1, 5))``
-    recipe, jit-friendly (one lax.top_k, shared across ks).
-
-    ``logits``: (..., C); ``targets``: (...) int.  Returns a tuple of
-    scalars in [0, 1], one per k, in the order given.
-    """
-    ks = tuple(int(k) for k in ks)
-    c = logits.shape[-1]
-    if not ks or any(k < 1 or k > c for k in ks):
-        raise ValueError(f"every k must be in [1, {c}] and ks non-empty, "
-                         f"got {ks}")
-    flat = logits.reshape(-1, c)
-    tgt = targets.reshape(-1)
-    _, top = jax.lax.top_k(flat, max(ks))          # (N, max_k)
-    hit = top == tgt[:, None]                      # (N, max_k) bool
-    return tuple(hit[:, :k].any(axis=1).mean() for k in ks)
-
-
-def accuracy(logits, targets) -> jax.Array:
-    """Top-1 accuracy as a scalar in [0, 1]."""
-    return (logits.reshape(-1, logits.shape[-1]).argmax(-1)
-            == targets.reshape(-1)).mean()
-
-
-def confusion_matrix(predictions, targets, num_classes: int) -> jax.Array:
-    """(num_classes, num_classes) count matrix, rows = true class, cols =
-    predicted (sklearn orientation).  Scatter-add on device; out-of-range
-    entries are dropped (not clamped into a real class)."""
-    preds = jnp.asarray(predictions).reshape(-1)
-    tgt = jnp.asarray(targets).reshape(-1)
-    valid = ((preds >= 0) & (preds < num_classes)
-             & (tgt >= 0) & (tgt < num_classes))
-    idx = tgt * num_classes + preds
-    counts = jnp.zeros(num_classes * num_classes, jnp.int32).at[
-        jnp.where(valid, idx, 0)].add(valid.astype(jnp.int32))
-    return counts.reshape(num_classes, num_classes)
