@@ -1,0 +1,155 @@
+"""The K/V slot pool is stored the way the decode step uses it.
+
+The mechanism's counter (ISSUE 24): the two pool programs are compiled HERE,
+on the CPU box, for a DESCRIBED v5e at the serving cell's shapes (width 1600,
+25 heads of 64, 32 slots x 1024, cache donated), and the optimized HLO must
+hold no whole-pool ``copy`` — the relayout that cost 139 ms of every 178 ms
+decode step while the pool was stored ``(B, Tmax, H, D)`` and written by a
+native scatter (PERF.md, PR 24).  XLA's choice between a scatter that wants
+its own layout and an in-place update is a heuristic; a later jaxlib or an
+innocent edit can bring the copies back with every CPU test still green.
+
+A compile is not a chip run and says nothing about time.  Where no v5e
+topology can be described the compile cases skip, they never fail.  The
+topology is described inside a fixture and only in this file (one process at
+a time may load libtpu; see the on-chip-measurement guide, section 2).
+"""
+
+import math
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from tpu_dist import nn
+from tpu_dist.models import TransformerLM
+
+SLOTS, MAX_LEN, DIM, HEADS, DEPTH = 32, 1024, 1600, 25, 2
+# The cell's vocabulary is 50257; it is cut here because at that size the
+# compiler relayouts the token table for the embedding gather (a 161 MB
+# temporary of its own, PERF.md section 7), which would drown the signal
+# this file exists for.  The K/V path does not see the vocabulary.
+VOCAB = 2048
+POOL_ELEMENTS = SLOTS * MAX_LEN * DIM
+# Temporaries: the decode step held 272 MB with the relayout copies (two
+# layers); one padded pool copy alone is 256 MiB.  The 1024-token prefill
+# keeps its 25 x 1024 x 1024 scores, 100 MiB in float32 with the int8 cache
+# (on the parent too), so its limit sits above that and below a pool copy.
+TEMP_LIMIT = {"decode_step": 64 << 20, "prefill_into_slot": 128 << 20}
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """A compile for a described chip cannot be read back from the
+    persistent cache without a chip: keep these out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def _shapes(tree, sharding):
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree)
+
+
+def _compile(program, cache_dtype, sharding):
+    """The optimized executable of ``decode_step`` or ``prefill_into_slot``
+    over a donated pool, bf16 parameters, for the described chip."""
+    model = TransformerLM(VOCAB, dim=DIM, depth=DEPTH, num_heads=HEADS,
+                          max_seq_len=MAX_LEN)
+    params = _shapes(jax.eval_shape(
+        lambda: jax.tree_util.tree_map(lambda a: a.astype(jnp.bfloat16),
+                                       model.init(jax.random.key(0)))),
+        sharding)
+    cache = _shapes(jax.eval_shape(
+        lambda: model.init_slot_cache(SLOTS, MAX_LEN, cache_dtype)),
+        sharding)
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=sharding)
+
+    if program == "decode_step":
+        fn = jax.jit(lambda p, c, tok, lens: model.decode_step(p, tok, lens,
+                                                               c),
+                     donate_argnums=1)
+        return fn.lower(params, cache, ints(SLOTS), ints(SLOTS)).compile()
+    fn = jax.jit(lambda p, c, prompt, n, slot: model.prefill_into_slot(
+        p, prompt, n, slot, c), donate_argnums=1)
+    return fn.lower(params, cache, ints(MAX_LEN), ints(), ints()).compile()
+
+
+def _pool_sized_copies(hlo_text):
+    """``copy`` instructions, in any computation of the module, whose result
+    has at least the K/V pool's element count."""
+    found = []
+    for line in hlo_text.splitlines():
+        m = re.search(r"= \w+\[([\d,]+)\]\S* copy\(", line)
+        if m and math.prod(int(d) for d in m.group(1).split(",")
+                           ) >= POOL_ELEMENTS:
+            found.append(line.strip()[:160])
+    return found
+
+
+@pytest.mark.parametrize("cache_dtype", [jnp.bfloat16, jnp.int8],
+                         ids=["bf16", "int8"])
+@pytest.mark.parametrize("program", ["decode_step", "prefill_into_slot"])
+def test_pool_program_has_no_whole_pool_copy(one_chip, no_compile_cache,
+                                             program, cache_dtype):
+    compiled = _compile(program, cache_dtype, one_chip)
+    copies = _pool_sized_copies(compiled.as_text())
+    assert not copies, (
+        f"{program} relayouts the K/V pool again ({len(copies)} whole-pool "
+        f"copies): the stored layout or the write form no longer lets XLA "
+        f"update the donated pool in place\n" + "\n".join(copies[:4]))
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < TEMP_LIMIT[program], (
+        f"{program} holds {temp / 2**20:.0f} MiB of temporaries (limit "
+        f"{TEMP_LIMIT[program] >> 20} MiB; one padded pool copy is 256 MiB)")
+
+
+def test_copy_counter_sees_a_relayout():
+    """The counter itself: it finds a pool-sized copy in HLO text shaped
+    like the parent's, and ignores a weight-sized one."""
+    text = "\n".join([
+        "  %copy.26 = bf16[32,1024,25,64]{3,2,1,0:T(8,128)(2,1)} "
+        "copy(%c__block0_attn____k__.1), sharding={replicated}",
+        "  %copy.22 = bf16[1600,4800]{0,1:T(8,128)(2,1)S(1)} "
+        "copy(%p__block0_attn____qkv_weight__.1)",
+        "  %fusion.2 = bf16[32,25,64,1024]{3,2,1,0} fusion(%copy.26)"])
+    assert len(_pool_sized_copies(text)) == 1
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.int8],
+                         ids=["float32", "bfloat16", "int8"])
+def test_time_is_the_last_axis_of_every_cache_leaf(dtype):
+    """One rule for every leaf, and the helpers host code slices by."""
+    model = TransformerLM(64, dim=32, depth=1, num_heads=4, max_seq_len=16)
+    (entry,) = model.init_slot_cache(3, 16, dtype).values()
+    assert entry["k"].shape == entry["v"].shape == (3, 4, 8, 16)
+    if dtype == jnp.int8:
+        assert entry["k_scale"].shape == entry["v_scale"].shape == (3, 4, 16)
+    for leaf in entry.values():
+        assert leaf.shape[nn.cache_time_axis(leaf)] == 16
+        host = np.arange(leaf.size).reshape(leaf.shape)
+        cut = nn.cache_time_slice(host, 2, 7)
+        assert cut.shape == leaf.shape[:-1] + (5,)
+        np.testing.assert_array_equal(cut, host[..., 2:7])

@@ -113,6 +113,72 @@ class TestSlotParity:
         np.testing.assert_array_equal(np.asarray(logits),
                                       np.asarray(ref_logits)[0, -1])
 
+    @pytest.mark.parametrize("dtype,tol", [
+        (jnp.float32, 1e-5), (jnp.bfloat16, 0.05), (jnp.int8, 0.05)],
+        ids=["float32", "bfloat16", "int8"])
+    def test_slot_cache_logits_match_uncached_forward(self, lm, dtype, tol):
+        # the stored layout (B, H, D, Tmax) and its in-place write: every
+        # way the pool is written — whole-prompt prefill into a slot, slot
+        # decode at UNEQUAL per-slot positions, and a t > 1 vector-index
+        # write — must reproduce the uncached forward's logits position by
+        # position (float32 at test_transformer's 1e-5; the rounded caches
+        # at the int8 cache's 0.05)
+        model, params = lm
+        rng = np.random.default_rng(5)
+        seqs = rng.integers(0, 97, (3, 20)).astype(np.int32)
+        full = np.asarray(model.apply(params, jnp.asarray(seqs)))
+        rows = np.arange(3)
+
+        def close(got, want, what):
+            np.testing.assert_allclose(np.asarray(got), want, atol=tol,
+                                       rtol=tol, err_msg=what)
+
+        lengths = np.array([4, 9, 6], np.int32)
+        cache = model.init_slot_cache(3, 32, dtype)
+        for slot, n in enumerate(lengths):
+            padded = np.zeros(16, np.int32)
+            padded[:n] = seqs[slot, :n]
+            logits, cache = model.prefill_into_slot(params, padded, n, slot,
+                                                    cache)
+            close(logits, full[slot, n - 1], f"prefill into slot {slot}")
+        for step in range(3):
+            logits, cache = model.decode_step(params, seqs[rows, lengths],
+                                              lengths, cache)
+            close(logits, full[rows, lengths], f"slot decode step {step}")
+            lengths = lengths + 1
+        # t = 4 new tokens per slot, each slot at its own position
+        t = 4
+        toks = np.stack([seqs[b, n:n + t] for b, n in enumerate(lengths)])
+        state = {path: dict(entry, index=jnp.asarray(lengths))
+                 for path, entry in cache.items()}
+        logits, state = model.apply(params, jnp.asarray(toks),
+                                    pos_offset=jnp.asarray(lengths),
+                                    state=state)
+        for b, n in enumerate(lengths):
+            close(logits[b], full[b, n:n + t], f"t={t} write, slot {b}")
+        # ... and the next decode step attends over what that write stored
+        lengths = lengths + t
+        cache = {path: {k: v for k, v in entry.items() if k != "index"}
+                 for path, entry in state.items()}
+        logits, _ = model.decode_step(params, seqs[rows, lengths], lengths,
+                                      cache)
+        close(logits, full[rows, lengths], "decode after the t>1 write")
+
+    @pytest.mark.parametrize("cache_dtype", [None, jnp.bfloat16, jnp.int8],
+                             ids=["float32", "bfloat16", "int8"])
+    def test_generate_equals_uncached_greedy(self, lm, cache_dtype):
+        # generate() through the cache picks, token for token, what
+        # re-running the whole prefix without a cache picks
+        model, params = lm
+        prompt = np.random.default_rng(6).integers(0, 97, (2, 6))
+        out = model.generate(params, jnp.asarray(prompt), 8,
+                             cache_dtype=cache_dtype)
+        seq = jnp.asarray(prompt)
+        for _ in range(8):
+            nxt = model.apply(params, seq)[:, -1].argmax(-1)
+            seq = jnp.concatenate([seq, nxt[:, None]], axis=1)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(seq))
+
     def test_engine_int8_cache_matches_generate(self, lm):
         # the quantized-cache decode path has its own per-slot write logic
         # (k_scale/v_scale rows) — same parity contract

@@ -60,10 +60,11 @@ def store():
 
 def _mk_rows(T, layers=2, heads=2, hd=4, seed=0):
     """A per-layer batch-1 KV row tree, float32 — the host-side shape
-    ``TransformerLM.prefill_rows`` hands the transfer layer."""
+    ``TransformerLM.prefill_rows`` hands the transfer layer: time LAST
+    (``nn.cache_time_axis``)."""
     rng = np.random.default_rng(seed)
     return {f"blocks/{j}": {k: rng.standard_normal(
-        (1, T, heads, hd)).astype(np.float32) for k in ("k", "v")}
+        (1, heads, hd, T)).astype(np.float32) for k in ("k", "v")}
         for j in range(layers)}
 
 
@@ -73,10 +74,10 @@ def _mk_int8_rows(T, layers=2, heads=2, hd=4, seed=0):
     what ``prefill_rows(..., dtype=jnp.int8)`` produces."""
     rng = np.random.default_rng(seed)
     return {f"blocks/{j}": {
-        "k": rng.integers(-127, 128, (1, T, heads, hd)).astype(np.int8),
-        "v": rng.integers(-127, 128, (1, T, heads, hd)).astype(np.int8),
-        "k_scale": rng.random((1, T, heads)).astype(np.float32),
-        "v_scale": rng.random((1, T, heads)).astype(np.float32)}
+        "k": rng.integers(-127, 128, (1, heads, hd, T)).astype(np.int8),
+        "v": rng.integers(-127, 128, (1, heads, hd, T)).astype(np.int8),
+        "k_scale": rng.random((1, heads, T)).astype(np.float32),
+        "v_scale": rng.random((1, heads, T)).astype(np.float32)}
         for j in range(layers)}
 
 
@@ -117,7 +118,7 @@ class TestKVTransfer:
                 for k in ("k", "v"):
                     # only the TRUE length columns travel, bit-exact
                     np.testing.assert_array_equal(
-                        got["rows"][path][k], rows[path][k][:, :10])
+                        got["rows"][path][k], rows[path][k][..., :10])
             assert kv1.fetched_bytes == got["bytes"] > 0
         finally:
             dp0.close(), dp1.close()
@@ -140,7 +141,7 @@ class TestKVTransfer:
                     assert np.max(np.abs(a - b)) < 0.1
                     assert not np.array_equal(a, b)
             # ~4x fewer payload bytes than the exact wire would ship
-            exact = sum(r[k][:, :16].nbytes for r in rows.values()
+            exact = sum(r[k][..., :16].nbytes for r in rows.values()
                         for k in r)
             assert kv1.fetched_bytes < exact / 2
         finally:
@@ -200,7 +201,7 @@ class TestKVTransfer:
             for path in rows:
                 for k in ("k", "v", "k_scale", "v_scale"):
                     np.testing.assert_array_equal(
-                        got["rows"][path][k], rows[path][k][:, :10])
+                        got["rows"][path][k], rows[path][k][..., :10])
         finally:
             dp0.close(), dp1.close()
 
@@ -268,7 +269,7 @@ class TestPrefixCache:
         for path in rows:
             for k in ("k", "v"):
                 np.testing.assert_array_equal(got[path][k],
-                                              rows[path][k][:, :12])
+                                              rows[path][k][..., :12])
         # longer prompt sharing the prefix: the whole 16 cached tokens
         hit, got = pc.match(np.concatenate([prompt, [7, 8, 9]]))
         assert hit == 16
@@ -340,7 +341,7 @@ class TestPrefixCache:
         # the reloaded level-1 block, bitwise
         assert hit == 4 and pc2.paged_in == 1
         np.testing.assert_array_equal(got["blocks/0"]["k"],
-                                      rows["blocks/0"]["k"][:, :4])
+                                      rows["blocks/0"]["k"][..., :4])
         # a different block size re-keys every chain: stale spill ignored
         pc3 = PrefixCache(block_tokens=8, spill_dir=str(tmp_path))
         assert len(pc3._entries) == 0

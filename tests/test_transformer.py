@@ -121,23 +121,29 @@ class TestGenerate:
                               max_seq_len=64, **kw)
         return model, model.init(jax.random.key(0))
 
-    def test_cached_decode_matches_full_forward(self):
+    @pytest.mark.parametrize("dtype,tol", [
+        (jnp.float32, 1e-5), (jnp.bfloat16, 0.05), (jnp.int8, 0.05)],
+        ids=["float32", "bfloat16", "int8"])
+    def test_cached_decode_matches_full_forward(self, dtype, tol):
         """Teacher-forced decode through the KV cache must reproduce the
-        dense forward's logits position by position (the decode oracle)."""
+        dense forward's logits position by position (the decode oracle),
+        exactly for a float32 cache and within rounding for the others."""
         model, params = self._model()
         toks = _tokens(b=2, t=16)
         full = model.apply(params, toks)                     # (B, 16, V)
 
-        cache = model.init_cache(batch=2, max_len=16)
+        cache = model.init_cache(batch=2, max_len=16, dtype=dtype)
+        # time is the last axis of the stored K/V: (B, H, D, Tmax)
+        assert cache[next(iter(cache))]["k"].shape == (2, 4, 8, 16)
         pre, cache = model.apply(params, toks[:, :5], state=cache)
         np.testing.assert_allclose(np.asarray(pre), np.asarray(full[:, :5]),
-                                   atol=1e-5, rtol=1e-5)
+                                   atol=tol, rtol=tol)
         for i in range(5, 16):
             step, cache = model.apply(params, toks[:, i:i + 1],
                                       pos_offset=i, state=cache)
             np.testing.assert_allclose(
                 np.asarray(step[:, 0]), np.asarray(full[:, i]),
-                atol=1e-5, rtol=1e-5, err_msg=f"position {i}")
+                atol=tol, rtol=tol, err_msg=f"position {i}")
 
     def test_generate_greedy_is_deterministic(self):
         model, params = self._model()

@@ -31,9 +31,12 @@ def write_slot_rows(cache, rows, slot):
     land *transferred* KV rows in a decode rank's pool through the exact
     same scatter the unified engine uses (the two paths cannot drift).
 
-    ``rows`` carries one ``{"k": (1, Tmax, ...), ...}`` entry per layer
-    path; only keys present in the pool entry are written (a row's extra
-    ``index`` is ignored)."""
+    ``rows`` carries one ``{"k": (1, H, D, T), ...}`` entry per layer
+    path, time last like the pool's ``(B, H, D, Tmax)`` (``T <= Tmax``: a
+    bucket's worth of columns lands at column 0); only keys present in the
+    pool entry are written (a row's extra ``index`` is ignored).  The
+    update is on the slot axis alone, so it is in place in the donated
+    pool whatever the stored layout."""
     slot = jnp.asarray(slot, jnp.int32)
     out = {}
     with jax.named_scope("cache_write"):
@@ -209,7 +212,10 @@ class TransformerLM(nn.Module):
                    dtype=jnp.float32):
         """KV-cache state pytree for :meth:`generate` — one
         ``{"k", "v", "index"}`` entry per attention layer, keyed by module
-        path, threaded through ``apply(state=...)`` like any mutable state."""
+        path, threaded through ``apply(state=...)`` like any mutable state.
+        ``k``/``v`` are ``(B, H, D, Tmax)``, time last: the layout the TPU
+        compiler keeps the pool in, written and read in place
+        (:meth:`nn.MultiheadSelfAttention.init_cache`)."""
         if self.sequence_axis is not None:
             raise ValueError("KV-cache decode runs on gathered sequences; "
                              "build the model without sequence_axis for "
@@ -229,7 +235,8 @@ class TransformerLM(nn.Module):
     def init_slot_cache(self, slots: int, max_len: Optional[int] = None,
                         dtype=jnp.float32):
         """KV-cache pool for slot-based continuous-batching decode: the
-        :meth:`init_cache` layout WITHOUT the per-layer scalar write index
+        :meth:`init_cache` layout (``k``/``v`` ``(slots, H, D, max_len)``,
+        time last) WITHOUT the per-layer scalar write index
         — each call to :meth:`decode_step` supplies every slot's position
         as the ``lengths`` vector instead, so the host-side engine
         (:class:`tpu_dist.serve.SlotEngine`) holds the single source of
@@ -277,8 +284,8 @@ class TransformerLM(nn.Module):
         prompt length = one compiled program; bucket prompt lengths to
         bound retraces."""
         entry = next(iter(cache.values()))
-        max_len, dtype = entry["k"].shape[1], entry["k"].dtype
-        pre = self.init_cache(1, max_len, dtype)
+        k = entry["k"]
+        pre = self.init_cache(1, k.shape[nn.cache_time_axis(k)], k.dtype)
         logits, st = self.apply(params, jnp.asarray(prompt)[None, :],
                                 state=pre)
         new_cache = write_slot_rows(cache, st, slot)
@@ -303,8 +310,10 @@ class TransformerLM(nn.Module):
         ``prefix_len`` (learned table via ``pos_offset``, rope via the
         cache write index) and the suffix K/V appends at
         ``[prefix_len, prefix_len + S)``.  Returns ``(last-real-token
-        logits (vocab,), rows)`` where ``rows`` are full-width
-        ``(1, max_len)`` per-layer entries (no ``index``).  With no
+        logits (vocab,), rows)`` where ``rows`` are full-width per-layer
+        entries (no ``index``), ``k``/``v`` of shape ``(1, H, D, max_len)``
+        — the pool's own order, time last, so :func:`write_slot_rows`
+        lands them without a transpose.  With no
         prefix this is bitwise-identical to the forward inside
         :meth:`prefill_into_slot` (same apply, same padding discipline);
         one padded suffix length = one compiled program."""

@@ -26,7 +26,8 @@ from .module import Module
 from . import init as I
 
 __all__ = ["scaled_dot_product_attention", "MultiheadSelfAttention",
-           "attention_impl", "rotary_embed"]
+           "attention_impl", "rotary_embed", "cache_time_axis",
+           "cache_time_slice"]
 
 _IMPL_OVERRIDE: list = []
 
@@ -124,6 +125,45 @@ def rotary_embed(x, positions, theta: float = 10000.0):
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                           axis=-1)
     return out.astype(x.dtype)
+
+
+def cache_time_axis(leaf) -> int:
+    """The time axis of a K/V cache leaf — the LAST one, for ``k``/``v``
+    ``(B, H, D, Tmax)`` and the int8 scales ``(B, H, Tmax)`` alike
+    (:meth:`MultiheadSelfAttention.init_cache` says why).  Host code that
+    cuts, joins or pads cache rows by position (serve/prefix, kvtransfer,
+    disagg) asks here instead of assuming an axis."""
+    return leaf.ndim - 1
+
+
+def cache_time_slice(leaf, lo, hi):
+    """Columns ``[lo, hi)`` of a cache leaf along its time axis (a view,
+    clipped to the leaf's extent like any slice)."""
+    idx = [slice(None)] * leaf.ndim
+    idx[cache_time_axis(leaf)] = slice(lo, hi)
+    return leaf[tuple(idx)]
+
+
+def _write_columns(pool, new, index):
+    """Per-slot column write: ``new`` (B, ..., t) lands in columns
+    ``[index[b], index[b] + t)`` of each row b of ``pool`` (B, ..., Tmax),
+    time last; a column past ``Tmax`` is dropped.
+
+    Written as a select over the pool, not as a scatter: XLA fuses the
+    select into the attention's q.K / P.V reduction as one multi-output
+    pass that reads the pool once and rewrites the donated buffer in
+    place, where a native scatter wants the pool in a layout of its own
+    (a 2.56x padded whole-pool copy there and back, each step).  On the
+    chip (PERF.md, PR 24) this beat the ``vmap``-ed
+    ``dynamic_update_slice``, which XLA expands into a per-slot loop of
+    partial-tile writes.  ``t`` selects are unrolled: t is 1 in slot
+    decode and small in a multi-token append; whole prompts take the
+    scalar-index branch of :meth:`MultiheadSelfAttention._decode`."""
+    kpos = jnp.arange(pool.shape[-1])
+    start = index.reshape((-1,) + (1,) * (pool.ndim - 1))
+    for j in range(new.shape[-1]):
+        pool = jnp.where(kpos == start + j, new[..., j:j + 1], pool)
+    return pool
 
 
 class MultiheadSelfAttention(Module):
@@ -240,10 +280,11 @@ class MultiheadSelfAttention(Module):
     def _decode(self, ctx, q, k, v):
         """Cached attention step.  q/k/v: (B, t, H, D) with t the number of
         new positions (t>1 = prefill, t=1 = one decode step).  The cache is
-        state ``{"k": (B, Tmax, H, D), "v": ..., "index": ()}``; new keys
-        land at [index, index+t) and queries see cache positions <= their
-        own global position (cache slots past the index are masked, so the
-        zeros there never contribute).
+        state ``{"k": (B, H, D, Tmax), "v": ..., "index": ()}`` — time is
+        the LAST axis of every leaf (see :meth:`init_cache` for why); new
+        keys land at columns [index, index+t) and queries see cache
+        positions <= their own global position (cache columns past the
+        index are masked, so the zeros there never contribute).
 
         With an int8 cache (``init_cache(dtype=jnp.int8)``) K/V are stored
         quantized with per-(token, head) symmetric scales and the scales are
@@ -257,9 +298,13 @@ class MultiheadSelfAttention(Module):
         index = jnp.asarray(st["index"])
         t = q.shape[1]
         int8_cache = st["k"].dtype == jnp.int8
+        new = {"k": k, "v": v}
         if int8_cache:
-            kq, ks = self._quantize_kv(k)
-            vq, vs = self._quantize_kv(v)
+            new["k"], new["k_scale"] = self._quantize_kv(k)
+            new["v"], new["v_scale"] = self._quantize_kv(v)
+        # (B, t, ...) -> (B, ..., t): the stored order, time last
+        new = {key: jnp.moveaxis(val, 1, -1).astype(st[key].dtype)
+               for key, val in new.items()}
         # scopes: the cache writes and the masked attention over the
         # whole cache are told apart in a device trace
         with jax.named_scope("cache_update"):
@@ -269,86 +314,61 @@ class MultiheadSelfAttention(Module):
                 # its OWN position and masks to its own prefix.  Rows whose
                 # slot is free write garbage the next prefill fully
                 # overwrites (and mask away).
-                b = q.shape[0]
-                rows = jnp.arange(b)[:, None]                     # (B, 1)
+                st = dict(st, **{
+                    key: _write_columns(st[key], val, index)
+                    for key, val in new.items()})
                 cols = index[:, None] + jnp.arange(t)[None, :]    # (B, t)
-                if int8_cache:
-                    st = dict(st,
-                              k=st["k"].at[rows, cols].set(kq),
-                              v=st["v"].at[rows, cols].set(vq),
-                              k_scale=st["k_scale"].at[rows, cols].set(ks),
-                              v_scale=st["v_scale"].at[rows, cols].set(vs))
-                else:
-                    st = dict(st,
-                              k=st["k"].at[rows, cols].set(
-                                  k.astype(st["k"].dtype)),
-                              v=st["v"].at[rows, cols].set(
-                                  v.astype(st["v"].dtype)))
-                ctx.put_state(self._path, dict(st, index=index + t))
-                tmax = st["k"].shape[1]
-                kpos = jnp.arange(tmax)
                 # (B, 1, t, Tmax): per-row causal+unwritten mask, broadcast
                 # over heads
-                mask = (kpos[None, None, :] <= cols[:, :, None])[:, None]
+                mask = (jnp.arange(st["k"].shape[-1])[None, None, :]
+                        <= cols[:, :, None])[:, None]
             else:
-                if int8_cache:
-                    st = dict(
-                        st,
-                        k=jax.lax.dynamic_update_slice(st["k"], kq,
-                                                       (0, index, 0, 0)),
-                        v=jax.lax.dynamic_update_slice(st["v"], vq,
-                                                       (0, index, 0, 0)),
-                        k_scale=jax.lax.dynamic_update_slice(
-                            st["k_scale"], ks, (0, index, 0)),
-                        v_scale=jax.lax.dynamic_update_slice(
-                            st["v_scale"], vs, (0, index, 0)))
-                else:
-                    st = dict(
-                        st,
-                        k=jax.lax.dynamic_update_slice(
-                            st["k"], k.astype(st["k"].dtype),
-                            (0, index, 0, 0)),
-                        v=jax.lax.dynamic_update_slice(
-                            st["v"], v.astype(st["v"].dtype),
-                            (0, index, 0, 0)))
-                ctx.put_state(self._path, dict(st, index=index + t))
-                tmax = st["k"].shape[1]
+                st = dict(st, **{
+                    key: jax.lax.dynamic_update_slice(
+                        st[key], val, (0,) * (val.ndim - 1) + (index,))
+                    for key, val in new.items()})
                 qpos = index + jnp.arange(t)[:, None]       # (t, 1) global
-                kpos = jnp.arange(tmax)[None, :]            # (1, Tmax)
-                mask = kpos <= qpos                   # causal + unwritten
+                kpos = jnp.arange(st["k"].shape[-1])[None, :]  # (1, Tmax)
+                mask = (kpos <= qpos)[None, None]     # causal + unwritten
+            ctx.put_state(self._path, dict(st, index=index + t))
         with jax.named_scope("attend"):
-            if not int8_cache:
-                return scaled_dot_product_attention(
-                    q, st["k"].astype(q.dtype), st["v"].astype(q.dtype),
-                    mask=mask, impl="dense")
-            # hoisted-scale dense attention over the int8 cache
-            sm = 1.0 / math.sqrt(self.head_dim)
-            s = jnp.einsum("bthd,bshd->bhts", q, st["k"].astype(q.dtype),
-                           preferred_element_type=jnp.float32)
-            s = s * sm * jnp.transpose(
-                st["k_scale"], (0, 2, 1))[:, :, None, :]
-            s = jnp.where(mask if mask.ndim == 4 else mask[None, None],
-                          s, -jnp.inf)
-            p = jax.nn.softmax(s, axis=-1)
-            pv = (p * jnp.transpose(st["v_scale"], (0, 2, 1))[:, :, None, :]
-                  ).astype(q.dtype)
-            return jnp.einsum(
-                "bhts,bshd->bthd", pv, st["v"].astype(q.dtype),
-                preferred_element_type=jnp.float32).astype(q.dtype)
+            # the contractions read the pool where it lies, (B, H, D, Tmax):
+            # for t == 1 they are multiply-reduce fusions over the stored
+            # bytes, for prefill K is already K^T for the MXU.  Numerics are
+            # scaled_dot_product_attention(impl="dense")'s: operands, scores
+            # and softmax in the compute dtype; the int8 cache keeps float32
+            # scores and its scales hoisted out of both matmuls.
+            acc = jnp.float32 if int8_cache else None
+            s = jnp.einsum("bthd,bhds->bhts", q, st["k"].astype(q.dtype),
+                           preferred_element_type=acc
+                           ) / math.sqrt(self.head_dim)
+            if int8_cache:
+                s = s * st["k_scale"][:, :, None, :]
+            w = jax.nn.softmax(jnp.where(mask, s, -jnp.inf), axis=-1)
+            if int8_cache:
+                w = (w * st["v_scale"][:, :, None, :]).astype(q.dtype)
+            return jnp.einsum("bhts,bhds->bthd", w, st["v"].astype(q.dtype),
+                              preferred_element_type=acc).astype(q.dtype)
 
     def init_cache(self, batch: int, max_len: int, dtype=jnp.float32):
-        """Per-layer KV cache entry (used via TransformerLM.init_cache).
-        ``dtype=jnp.int8`` allocates the quantized cache layout: int8 K/V
-        plus float32 per-(token, head) scales (see :meth:`_decode`)."""
-        cache = {"k": jnp.zeros((batch, max_len, self.num_heads,
-                                 self.head_dim), dtype),
-                 "v": jnp.zeros((batch, max_len, self.num_heads,
-                                 self.head_dim), dtype),
+        """Per-layer KV cache entry (used via TransformerLM.init_cache):
+        ``k``, ``v`` of shape ``(B, H, D, Tmax)``, time LAST.  That is the
+        layout the TPU compiler keeps such a pool in at rest whatever its
+        logical shape (``Tmax`` in the 128 lanes, ``D`` in the sublanes:
+        unpadded for bf16 whenever ``D % 16 == 0`` and ``Tmax % 128 == 0``,
+        any head count); storing it so lets the decode program write the
+        donated pool in place and the attention read it where it lies,
+        with no whole-pool relayout copy (tests/test_decode_layout.py).
+        ``dtype=jnp.int8`` allocates the quantized cache: int8 K/V plus
+        float32 per-(token, head) scales ``(B, H, Tmax)`` (see
+        :meth:`_decode`)."""
+        shape = (batch, self.num_heads, self.head_dim, max_len)
+        cache = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),
                  "index": jnp.zeros((), jnp.int32)}
         if jnp.dtype(dtype) == jnp.int8:
-            cache["k_scale"] = jnp.zeros((batch, max_len, self.num_heads),
+            cache["k_scale"] = jnp.zeros((batch, self.num_heads, max_len),
                                          jnp.float32)
-            cache["v_scale"] = jnp.zeros((batch, max_len, self.num_heads),
+            cache["v_scale"] = jnp.zeros((batch, self.num_heads, max_len),
                                          jnp.float32)
         return cache
 

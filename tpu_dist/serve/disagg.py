@@ -51,6 +51,7 @@ from typing import Callable, Dict, Optional
 
 import numpy as np
 
+from ..nn.attention import cache_time_axis
 from .engine import Request, ServeError, SlotEngine, sample_tokens
 from .kvtransfer import KVTransfer, KVTransferError
 from .scheduler import Scheduler
@@ -72,6 +73,20 @@ def kv_channel(decode_role_rank: int) -> str:
 
 def _now() -> float:
     return time.perf_counter()
+
+
+def _pad_time(rows, total: int):
+    """Host cache rows ``{path: {key: leaf}}`` zero-padded along each
+    leaf's time axis to ``total`` columns (a bucket, or the whole
+    ``max_len``): the fixed shape one compiled program takes."""
+    def pad(arr):
+        width = [(0, 0)] * arr.ndim
+        ax = cache_time_axis(arr)
+        width[ax] = (0, total - arr.shape[ax])
+        return np.pad(arr, width)
+
+    return {path: {k: pad(arr) for k, arr in entry.items()}
+            for path, entry in rows.items()}
 
 
 def kv_timeout_default() -> float:
@@ -344,14 +359,8 @@ class DisaggSlotEngine(SlotEngine):
                 f"{arrival['length']} tokens but the prompt has "
                 f"{len(req.prompt)} — descriptor/transfer drift")
         bucket = self.bucket_for(arrival["length"])
-        padded = {}
-        for path, entry in arrival["rows"].items():
-            padded[path] = {}
-            for k, arr in entry.items():
-                full = np.zeros((1, bucket) + arr.shape[2:], arr.dtype)
-                full[:, :arrival["length"]] = arr
-                padded[path][k] = full
-        arrival["rows"] = jax.device_put(padded)
+        arrival["rows"] = jax.device_put(
+            _pad_time(arrival["rows"], bucket))
         req.staged = arrival
         return req.staged
 
@@ -578,17 +587,9 @@ class PrefillWorker:
             sb = self._bucket_for(L - hit, self.max_len - hit)
             padded = np.zeros(sb, np.int32)
             padded[:L - hit] = tokens[hit:]
-            pre_full = {}
-            for path, entry in pre_rows.items():
-                pre_full[path] = {}
-                for k, arr in entry.items():
-                    full = np.zeros((1, self.max_len) + arr.shape[2:],
-                                    arr.dtype)
-                    full[:, :hit] = arr
-                    pre_full[path][k] = full
             tok_dev, rows = self._pf_pre(self.params, padded, np.int32(L),
-                                         pre_full, np.int32(hit), temp,
-                                         key, sampling)
+                                         _pad_time(pre_rows, self.max_len),
+                                         np.int32(hit), temp, key, sampling)
         else:
             b = self._bucket_for(L, self.max_len)
             padded = np.zeros(b, np.int32)

@@ -21,8 +21,9 @@ reshard engine's fragment tags):
   prefill program) so the decode rank starts decoding with zero extra
   round-trips.
 - ``kv/{rid}/{j}.{key}`` — layer ``j``'s ``key`` rows (``k``/``v``),
-  shape ``(1, length, heads, head_dim)``, in deterministic (sorted
-  path, sorted key) order on both sides.
+  shape ``(1, heads, head_dim, length)`` — the cache's own order, time
+  last (``nn.cache_time_axis``) — in deterministic (sorted path, sorted
+  key) order on both sides.
 
 ``wire="int8_blockN"`` opts each FLOAT fragment into the block-quantized
 int8 wire from the collectives layer (PR 8): ~3.9x fewer bytes, but
@@ -49,6 +50,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ..nn.attention import cache_time_axis, cache_time_slice
 from .engine import ServeError
 
 __all__ = ["KVTransfer", "KVTransferError", "kv_template"]
@@ -63,19 +65,26 @@ class KVTransferError(ServeError):
 
 
 def kv_template(cache_or_rows) -> Dict[str, Dict[str, Tuple[tuple, np.dtype]]]:
-    """``{layer_path: {key: (trailing_shape, dtype)}}`` from a slot-cache
-    pool or a batch-1 row tree — the shape contract both transfer
-    endpoints derive from their OWN model, so a fragment that arrives
-    with drifted geometry is a named error, not a silent reshape."""
+    """``{layer_path: {key: (per_token_shape, dtype)}}`` from a slot-cache
+    pool or a batch-1 row tree (a leaf's shape less its batch and time
+    axes) — the shape contract both transfer endpoints derive from their
+    OWN model, so a fragment that arrives with drifted geometry is a
+    named error, not a silent reshape."""
     out: Dict[str, Dict[str, Tuple[tuple, np.dtype]]] = {}
     for path, entry in cache_or_rows.items():
         out[path] = {}
         for key, arr in entry.items():
             if key == "index":
                 continue
-            shape = tuple(int(d) for d in arr.shape[2:])
+            shape = tuple(int(d) for d in arr.shape[1:cache_time_axis(arr)])
             out[path][key] = (shape, np.dtype(arr.dtype))
     return out
+
+
+def _fragment_shape(per_token_shape: tuple, length: int) -> tuple:
+    """Shape of one wire fragment: batch 1, the template's per-token
+    shape, ``length`` columns — time last, as the cache stores it."""
+    return (1,) + per_token_shape + (length,)
 
 
 class KVTransfer:
@@ -144,12 +153,12 @@ class KVTransfer:
         frags = []
         for path, key in self._frames:
             shape, dtype = self.template[path][key]
-            arr = np.asarray(rows[path][key])[:, :length]
-            if arr.shape[2:] != shape or arr.shape[0] != 1:
+            arr = cache_time_slice(np.asarray(rows[path][key]), 0, length)
+            if arr.shape != _fragment_shape(shape, length):
                 raise KVTransferError(
                     f"kv send {rid}: layer {path!r}[{key}] rows have shape "
-                    f"{arr.shape}, template expects (1, {length}, "
-                    f"{', '.join(map(str, shape))}) — the two endpoints' "
+                    f"{arr.shape}, template expects "
+                    f"{_fragment_shape(shape, length)} — the two endpoints' "
                     f"models disagree")
             frags.append(np.ascontiguousarray(arr, dtype))
         meta = np.asarray([length, int(first_tok), int(prefix_hit),
@@ -225,7 +234,7 @@ class KVTransfer:
                 got = got.dequantize(np.float32).astype(dtype, copy=False)
             else:
                 nbytes += int(np.asarray(got).nbytes)
-            arr = np.asarray(got).reshape((1, length) + shape)
+            arr = np.asarray(got).reshape(_fragment_shape(shape, length))
             rows.setdefault(path, {})[key] = arr
         self.fetched_bytes += nbytes
         return {"rows": rows, "length": length, "first_tok": first_tok,

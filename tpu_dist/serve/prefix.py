@@ -41,6 +41,8 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ..nn.attention import cache_time_axis, cache_time_slice
+
 __all__ = ["PrefixCache"]
 
 
@@ -68,8 +70,9 @@ class PrefixCache:
     """Content-verified, byte-capped, spill-backed KV prefix cache.
 
     Thread-safe (one lock; prefill workers share an instance).  ``rows``
-    trees everywhere are host numpy ``{layer_path: {"k"/"v": (1, T,
-    ...)}}`` — the cache never touches a device."""
+    trees everywhere are host numpy ``{layer_path: {"k"/"v": (1, ...,
+    T)}}``, time last (``nn.cache_time_axis``) — the cache never touches
+    a device."""
 
     def __init__(self, block_tokens: int = 16,
                  capacity_bytes: int = 64 << 20,
@@ -107,7 +110,7 @@ class PrefixCache:
 
     def match(self, tokens) -> Tuple[int, Optional[dict]]:
         """Longest cached-and-verified prefix of ``tokens``: ``(hit_len,
-        rows)`` with ``rows`` the concatenated ``(1, hit_len, ...)``
+        rows)`` with ``rows`` the concatenated ``(1, ..., hit_len)``
         per-layer tree, or ``(0, None)``.  Capped at ``len(tokens) - 1``
         so a suffix always remains to prefill."""
         tokens = np.asarray(tokens, np.int32).reshape(-1)
@@ -139,8 +142,8 @@ class PrefixCache:
             for path in chain[0].rows:
                 rows[path] = {
                     k: np.concatenate([e.rows[path][k] for e in chain],
-                                      axis=1)
-                    for k in chain[0].rows[path]}
+                                      axis=cache_time_axis(leaf))
+                    for k, leaf in chain[0].rows[path].items()}
             self.hits += 1
             self.tokens_saved += hit_len
             # enforce AFTER assembling the hit: paging in must not page
@@ -153,7 +156,7 @@ class PrefixCache:
     def insert(self, tokens, rows, length: int) -> int:
         """Cache every complete block of ``tokens[:length]`` whose chain
         level is not already present, slicing its rows out of ``rows``
-        (full prefill output, ``(1, >=length, ...)`` per layer).  Returns
+        (full prefill output, ``(1, ..., >=length)`` per layer).  Returns
         the number of new levels cached."""
         tokens = np.asarray(tokens, np.int32).reshape(-1)[:int(length)]
         levels = len(tokens) // self.block
@@ -173,8 +176,8 @@ class PrefixCache:
                 lo, hi = (j - 1) * self.block, j * self.block
                 block_rows = {
                     path: {k: np.ascontiguousarray(
-                        np.asarray(rows[path][k])[:, lo:hi])
-                        for k in rows[path] if k != "index"}
+                        cache_time_slice(np.asarray(leaf), lo, hi))
+                        for k, leaf in rows[path].items() if k != "index"}
                     for path in rows}
                 nbytes = sum(a.nbytes for e in block_rows.values()
                              for a in e.values())

@@ -62,6 +62,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ..nn.attention import cache_time_axis
 from .engine import Request, ServeError, SlotEngine, sample_tokens
 
 __all__ = ["ShardedLM", "ShardedDecoder", "ShardedSlotEngine",
@@ -592,9 +593,9 @@ class ShardedDecoder:
         segment pipeline at batch 1 with a fresh per-layer cache row,
         then each layer's rows are written into this shard's pool slice."""
         jnp = self._jnp
-        entry0 = next(iter(cache.values()))
-        max_len, dtype = entry0["k"].shape[1], entry0["k"].dtype
-        fresh = self.slm.init_slot_cache(1, max_len, dtype)
+        k0 = next(iter(cache.values()))["k"]
+        fresh = self.slm.init_slot_cache(1, k0.shape[cache_time_axis(k0)],
+                                         k0.dtype)
         zero = jnp.zeros((), jnp.int32)
         rows = {}
         p0 = self._layer_paths[0]
