@@ -153,3 +153,71 @@ def test_time_is_the_last_axis_of_every_cache_leaf(dtype):
         cut = nn.cache_time_slice(host, 2, 7)
         assert cut.shape == leaf.shape[:-1] + (5,)
         np.testing.assert_array_equal(cut, host[..., 2:7])
+
+
+# -- a routed model's pool programs (ISSUE 25) --------------------------------
+
+@pytest.fixture
+def mosaic_gmm(monkeypatch):
+    """``ops/gmm.py`` asks ``jax.default_backend()`` whether to interpret its
+    kernels, and sees the CPU here: steer it to the Mosaic lowering, in the
+    test (``tpu_dist.ops.gmm`` the attribute is the function, so the module
+    comes from ``sys.modules``)."""
+    import sys
+    import tpu_dist.ops.gmm  # noqa: F401
+    monkeypatch.setattr(sys.modules["tpu_dist.ops.gmm"], "_use_interpret",
+                        lambda: False)
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_into_slot"])
+def test_olmoe_pool_program_compiles_with_its_grouped_matmuls(
+        one_chip, no_compile_cache, mosaic_gmm, program):
+    """OLMoE's block at the published widths (2048, 16 heads of 128, 64
+    gated experts of 1024, 8 a token; one layer, vocabulary cut), 32 slots x
+    1024, with the routed-row counters merged into the pool: the chip's
+    compiler takes the grouped-matmul kernels at 16 rows a block (decode: 4
+    rows an expert) and at 128 (a 1024-token prefill), three calls a layer
+    named by their routed rows, and copies neither the pool nor an expert
+    tensor (interpreted, the kernels would copy every one: 256 MiB each)."""
+    experts, width, top_k = 64, 1024, 8
+    model = TransformerLM(VOCAB, dim=2048, depth=1, num_heads=16,
+                          max_seq_len=MAX_LEN, norm="rmsnorm", rope=True,
+                          norm_eps=1e-5, attn_bias=False, qk_norm=True,
+                          num_experts=experts, moe_top_k=top_k,
+                          moe_hidden=width, moe_gated=True,
+                          moe_normalize_gates=False, moe_dispatch="dropless")
+    params = _shapes(jax.eval_shape(
+        lambda: jax.tree_util.tree_map(lambda a: a.astype(jnp.bfloat16),
+                                       model.init(jax.random.key(0)))),
+        one_chip)
+    pool = _shapes(jax.eval_shape(
+        lambda: dict(model.init_slot_cache(SLOTS, MAX_LEN, jnp.bfloat16),
+                     **model.init_moe_counters())), one_chip)
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    if program == "decode_step":
+        fn = jax.jit(lambda p, c, tok, lens: model.decode_step(p, tok, lens,
+                                                               c),
+                     donate_argnums=1)
+        compiled = fn.lower(params, pool, ints(SLOTS), ints(SLOTS)).compile()
+        routed = SLOTS * top_k
+    else:
+        fn = jax.jit(lambda p, c, prompt, n, slot: model.prefill_into_slot(
+            p, prompt, n, slot, c), donate_argnums=1)
+        compiled = fn.lower(params, pool, ints(MAX_LEN), ints(),
+                            ints()).compile()
+        routed = MAX_LEN * top_k
+    text = compiled.as_text()
+    calls = re.findall(r"%(gmm_r\d+)[.\d]* = [^\n]*custom_call_target="
+                       r"\"tpu_custom_call\"", text)
+    assert calls == [f"gmm_r{routed}"] * 3, calls
+    big = min(SLOTS * MAX_LEN * 2048, experts * 2048 * width)
+    copies = []
+    for line in text.splitlines():
+        m = re.search(r"= \w+\[([\d,]+)\]\S* copy\(", line)
+        if m and math.prod(int(d) for d in m.group(1).split(",")) >= big:
+            copies.append(line.strip()[:160])
+    assert not copies, copies
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20

@@ -396,7 +396,15 @@ def _flaky_worker(i, path):
     with open(os.path.join(path, f"gen{gen}_rank{i}"), "w") as f:
         f.write("x")
     if gen == 0 and i == 1:
-        sys.exit(5)  # generation 0 always fails; generation 1 succeeds
+        # generation 0 always fails; generation 1 succeeds.  The supervisor
+        # kills the world on the first failure, so fail only once rank 0 has
+        # written its file: on a loaded box rank 0 may start seconds later
+        # (the test below lists all four files)
+        deadline = time.monotonic() + 60
+        while (not os.path.exists(os.path.join(path, "gen0_rank0"))
+               and time.monotonic() < deadline):
+            time.sleep(0.01)
+        sys.exit(5)
 
 
 class TestSpawnSupervisor:

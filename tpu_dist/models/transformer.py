@@ -36,11 +36,16 @@ def write_slot_rows(cache, rows, slot):
     bucket's worth of columns lands at column 0); only keys present in the
     pool entry are written (a row's extra ``index`` is ignored).  The
     update is on the slot axis alone, so it is in place in the donated
-    pool whatever the stored layout."""
+    pool whatever the stored layout.  An entry of ``cache`` without K/V
+    (the routed-row counters, :meth:`TransformerLM.init_moe_counters`)
+    has no slots: it is taken whole from ``rows`` where they carry it."""
     slot = jnp.asarray(slot, jnp.int32)
     out = {}
     with jax.named_scope("cache_write"):
         for path, pool in cache.items():
+            if "k" not in pool:
+                out[path] = rows.get(path, pool)
+                continue
             row = rows[path]
             out[path] = {
                 k: jax.lax.dynamic_update_slice(
@@ -50,12 +55,13 @@ def write_slot_rows(cache, rows, slot):
     return out
 
 
-def _norm_cls(norm: str):
-    if norm == "layernorm":
-        return nn.LayerNorm
-    if norm == "rmsnorm":
-        return nn.RMSNorm
-    raise ValueError(f"Unknown norm {norm!r} (layernorm|rmsnorm)")
+def _make_norm(norm: str, dim: int, eps: Optional[float] = None):
+    """``eps=None`` keeps the norm class's own default (LayerNorm 1e-5,
+    RMSNorm 1e-6); ViT passes 1e-6 for torchvision parity, OLMoE 1e-5."""
+    if norm not in ("layernorm", "rmsnorm"):
+        raise ValueError(f"Unknown norm {norm!r} (layernorm|rmsnorm)")
+    cls = nn.LayerNorm if norm == "layernorm" else nn.RMSNorm
+    return cls(dim) if eps is None else cls(dim, eps=eps)
 
 
 class TransformerBlock(nn.Module):
@@ -63,19 +69,18 @@ class TransformerBlock(nn.Module):
                  sequence_axis: Optional[str] = None, mode: str = "ring",
                  mlp: Optional[nn.Module] = None, norm: str = "layernorm",
                  rope: bool = False, rope_theta: float = 10000.0,
-                 norm_eps: Optional[float] = None):
+                 norm_eps: Optional[float] = None, attn_bias: bool = True,
+                 qk_norm: bool = False):
         super().__init__()
-        norm_cls = _norm_cls(norm)
-        # norm_eps=None keeps each norm class's own default (LayerNorm
-        # 1e-5, RMSNorm 1e-6); ViT passes 1e-6 for torchvision parity
-        mk_norm = (norm_cls if norm_eps is None
-                   else lambda d: norm_cls(d, eps=norm_eps))
-        self.ln1 = mk_norm(dim)
-        self.attn = nn.MultiheadSelfAttention(dim, num_heads, causal=causal,
-                                              sequence_axis=sequence_axis,
-                                              mode=mode, rope=rope,
-                                              rope_theta=rope_theta)
-        self.ln2 = mk_norm(dim)
+        self.ln1 = _make_norm(norm, dim, norm_eps)
+        # qk_norm: an RMSNorm over the whole q and k projections, with the
+        # block's own eps (OLMoE)
+        self.attn = nn.MultiheadSelfAttention(
+            dim, num_heads, bias=attn_bias, causal=causal,
+            sequence_axis=sequence_axis, mode=mode, rope=rope,
+            rope_theta=rope_theta, qk_norm=qk_norm,
+            qk_norm_eps=1e-6 if norm_eps is None else norm_eps)
+        self.ln2 = _make_norm(norm, dim, norm_eps)
         # mlp override: e.g. an nn.MoELayer for mixture-of-experts blocks
         self.mlp = mlp if mlp is not None else nn.Sequential(
             nn.Linear(dim, 4 * dim), nn.GELU(), nn.Linear(4 * dim, dim))
@@ -103,15 +108,29 @@ class TransformerLM(nn.Module):
                  mode: str = "ring", remat: bool = False,
                  num_experts: int = 0, moe_top_k: int = 2,
                  moe_every: int = 1, moe_capacity_factor: float = 1.25,
-                 moe_dispatch: str = "einsum",
+                 moe_dispatch: str = "einsum", moe_hidden: int = 0,
+                 moe_gated: bool = False, moe_normalize_gates: bool = True,
                  norm: str = "layernorm", rope: bool = False,
-                 rope_theta: float = 10000.0):
+                 rope_theta: float = 10000.0,
+                 norm_eps: Optional[float] = None, attn_bias: bool = True,
+                 qk_norm: bool = False):
         """``num_experts > 0`` makes every ``moe_every``-th block's MLP a
         routed :class:`~tpu_dist.nn.MoELayer` (expert-parallel under
         :data:`~tpu_dist.parallel.MOE_EP_RULES`); aux load-balance losses
         surface in the model state, see nn/moe.py.  ``moe_dispatch=
         "gather"`` selects the index-map dispatch (cheaper off the GSPMD
-        'expert' axis — see nn/moe.py).
+        'expert' axis — see nn/moe.py), ``"dropless"`` the grouped-matmul
+        one, which drops no row and is the one to serve with.
+        ``moe_hidden`` is one expert's width (0 = ``4 * dim``),
+        ``moe_gated`` makes the expert ``down(silu(gate(x)) * up(x))``
+        without biases, ``moe_normalize_gates=False`` uses the top-k router
+        probabilities as they are.
+
+        ``norm_eps`` sets every norm's eps (None keeps each class's own),
+        ``attn_bias=False`` drops the attention projections' biases,
+        ``qk_norm`` normalises the whole q and k projections before the
+        head split (nn/attention.py).  With the LLaMA-family recipe below
+        these spell OLMoE (chipbench/configs/olmoe-1b-7b-serve.json).
 
         ``norm="rmsnorm"`` + ``rope=True`` gives the LLaMA-family recipe:
         RMS normalization and rotary position embeddings instead of the
@@ -130,10 +149,13 @@ class TransformerLM(nn.Module):
             setattr(self, f"block{i}", TransformerBlock(
                 dim, num_heads, causal=causal,
                 sequence_axis=sequence_axis, mode=mode, norm=norm,
-                rope=rope, rope_theta=rope_theta,
-                mlp=nn.MoELayer(dim, num_experts, top_k=moe_top_k,
+                rope=rope, rope_theta=rope_theta, norm_eps=norm_eps,
+                attn_bias=attn_bias, qk_norm=qk_norm,
+                mlp=nn.MoELayer(dim, num_experts, hidden=moe_hidden,
+                                top_k=moe_top_k,
                                 capacity_factor=moe_capacity_factor,
-                                dispatch=moe_dispatch)
+                                normalize_gates=moe_normalize_gates,
+                                dispatch=moe_dispatch, gated=moe_gated)
                 if moe else None))
         self.depth = depth
         self.causal = causal
@@ -144,7 +166,7 @@ class TransformerLM(nn.Module):
         # (per-layer residual-boundary policy, like torch's
         # checkpoint_sequential over blocks)
         self.remat = remat
-        self.ln_f = _norm_cls(norm)(dim)
+        self.ln_f = _make_norm(norm, dim, norm_eps)
         self.head = nn.Linear(dim, vocab_size)
 
     def embed_tokens(self, idx, pos_offset=None):
@@ -245,6 +267,27 @@ class TransformerLM(nn.Module):
                 for path, entry in
                 self.init_cache(slots, max_len, dtype).items()}
 
+    def init_moe_counters(self):
+        """Routed-row counters for serving a model with expert layers, one
+        entry per :class:`~tpu_dist.nn.MoELayer` keyed by its path (empty
+        for a dense model): ``rows`` (E,) routed rows per expert that
+        belong to a request, ``pad_rows`` routed rows that belong to none
+        (free slots in :meth:`decode_step`, bucket padding in
+        :meth:`prefill_into_slot`), ``calls`` of the layer, and
+        ``experts_hit``, the experts with a request's row summed over
+        calls.  Merged into the ``cache`` given to those two methods, the
+        entries come back in the returned cache with the call's rows added
+        — on the device, nothing is read back (tpu_dist.serve.SlotEngine
+        keeps them beside its pool).  int32: a reader takes differences
+        modulo 2**32."""
+        self._assign_paths()
+        z = lambda *shape: jnp.zeros(shape, jnp.int32)
+        return {mlp._path: {"rows": z(mlp.num_experts), "pad_rows": z(),
+                            "calls": z(), "experts_hit": z()}
+                for mlp in (getattr(self, f"block{i}").mlp
+                            for i in range(self.depth))
+                if isinstance(mlp, nn.MoELayer)}
+
     def decode_step(self, params, tokens, lengths, cache):
         """ONE decode iteration over a slot pool: feed each slot's current
         last token, get each slot's next-token logits.
@@ -258,15 +301,20 @@ class TransformerLM(nn.Module):
         their cache writes land in rows the next prefill overwrites.
         The math per row is exactly :meth:`generate`'s decode scan — the
         scan *uses* this method — so slot decode and offline generation
-        cannot drift."""
+        cannot drift.  Counter entries in ``cache``
+        (:meth:`init_moe_counters`) take a slot of length 0 as free."""
         lengths = jnp.asarray(lengths, jnp.int32)
-        state = {path: dict(entry, index=lengths)
+        # K/V entries get their write index, counter entries the mask of
+        # rows that are a request's
+        valid = (lengths > 0)[:, None]
+        state = {path: (dict(entry, index=lengths) if "k" in entry
+                        else dict(entry, valid=valid))
                  for path, entry in cache.items()}
         tokens = jnp.asarray(tokens)[:, None]
         logits, state = self.apply(params, tokens, pos_offset=lengths,
                                    state=state)
         new_cache = {path: {k: v for k, v in state[path].items()
-                            if k != "index"}
+                            if k not in ("index", "valid")}
                      for path in cache}
         return logits[:, -1], new_cache
 
@@ -283,11 +331,16 @@ class TransformerLM(nn.Module):
         request's first generated token from those logits.  One padded
         prompt length = one compiled program; bucket prompt lengths to
         bound retraces."""
-        entry = next(iter(cache.values()))
-        k = entry["k"]
+        k = next(e["k"] for e in cache.values() if "k" in e)
+        prompt = jnp.asarray(prompt)
         pre = self.init_cache(1, k.shape[nn.cache_time_axis(k)], k.dtype)
-        logits, st = self.apply(params, jnp.asarray(prompt)[None, :],
-                                state=pre)
+        # counter entries ride along: the rows past ``length`` are padding
+        valid = (jnp.arange(prompt.shape[0]) < length)[None, :]
+        pre.update({p: dict(e, valid=valid) for p, e in cache.items()
+                    if "k" not in e})
+        logits, st = self.apply(params, prompt[None, :], state=pre)
+        st = {p: {k: v for k, v in e.items() if k != "valid"}
+              for p, e in st.items()}
         new_cache = write_slot_rows(cache, st, slot)
         return jax.lax.dynamic_index_in_dim(
             logits[0], jnp.asarray(length, jnp.int32) - 1, axis=0,

@@ -179,7 +179,8 @@ class MultiheadSelfAttention(Module):
     def __init__(self, embed_dim: int, num_heads: int, bias: bool = True,
                  causal: bool = False, sequence_axis: Optional[str] = None,
                  mode: str = "ring", attn_impl: Optional[str] = None,
-                 rope: bool = False, rope_theta: float = 10000.0):
+                 rope: bool = False, rope_theta: float = 10000.0,
+                 qk_norm: bool = False, qk_norm_eps: float = 1e-6):
         super().__init__()
         if embed_dim % num_heads:
             raise ValueError(f"embed_dim {embed_dim} not divisible by "
@@ -199,6 +200,11 @@ class MultiheadSelfAttention(Module):
         self.attn_impl = attn_impl  # None=auto | "dense" | "flash"
         self.rope = rope
         self.rope_theta = rope_theta
+        # OLMoE's QK-norm: an RMSNorm with its own weight over the WHOLE
+        # q and k projections (all heads together), before the head split
+        # and before rope
+        self.qk_norm = qk_norm
+        self.qk_norm_eps = qk_norm_eps
 
     def create_params(self, key):
         k1, k2 = jax.random.split(key)
@@ -209,6 +215,9 @@ class MultiheadSelfAttention(Module):
         if self.bias:
             p["qkv_bias"] = jnp.zeros((3 * self.embed_dim,))
             p["out_bias"] = jnp.zeros((self.embed_dim,))
+        if self.qk_norm:
+            p["q_norm_weight"] = jnp.ones((self.embed_dim,))
+            p["k_norm_weight"] = jnp.ones((self.embed_dim,))
         return p
 
     def _qkv_proj(self, p, x):
@@ -226,9 +235,13 @@ class MultiheadSelfAttention(Module):
         ctx = _ctx()
         p = ctx.get_params(self._path)
         b, t, _ = x.shape
-        qkv = self._qkv_proj(p, x)
-        qkv = qkv.reshape(b, t, 3, self.num_heads, self.head_dim)
+        qkv = self._qkv_proj(p, x).reshape(b, t, 3, self.embed_dim)
         q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+        if self.qk_norm:
+            q = F.rms_norm(q, p["q_norm_weight"], self.qk_norm_eps)
+            k = F.rms_norm(k, p["k_norm_weight"], self.qk_norm_eps)
+        q, k, v = (a.reshape(b, t, self.num_heads, self.head_dim)
+                   for a in (q, k, v))
         if self.rope:
             # absolute positions of THESE tokens: the cache write index
             # during decode, the shard offset under sequence parallelism,

@@ -17,7 +17,7 @@ from jax import lax
 __all__ = [
     "conv2d", "max_pool2d", "avg_pool2d", "relu", "linear", "dropout",
     "log_softmax", "softmax", "cross_entropy", "one_hot", "flatten",
-    "batch_norm",
+    "batch_norm", "rms_norm",
 ]
 
 _IntOr2 = Union[int, Tuple[int, int]]
@@ -181,3 +181,13 @@ def batch_norm(x, mean, var, weight=None, bias=None, eps: float = 1e-5):
     if bias is not None:
         y = y + bias
     return y
+
+
+def rms_norm(x, weight=None, eps: float = 1e-6, axes=(-1,)):
+    """``x / rms(x)`` over ``axes`` (statistics in float32, result in
+    ``x.dtype``), times ``weight`` if given — Zhang & Sennrich 2019."""
+    xf = x.astype(jnp.float32)
+    y = (xf * lax.rsqrt(jnp.mean(jnp.square(xf), axes, keepdims=True) + eps)
+         ).astype(x.dtype)
+    # cast the weight, not the product: keep the promised output dtype
+    return y if weight is None else y * weight.astype(x.dtype)

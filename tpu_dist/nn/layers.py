@@ -334,14 +334,9 @@ class RMSNorm(Module):
 
     def forward(self, x):
         axes = tuple(range(x.ndim - len(self.normalized_shape), x.ndim))
-        xf = x.astype(jnp.float32)
-        y = xf * lax.rsqrt(jnp.mean(jnp.square(xf), axes, keepdims=True)
-                           + self.eps)
-        y = y.astype(x.dtype)
-        if self.elementwise_affine:
-            w = _ctx().get_params(self._path)["weight"]
-            y = y * w.astype(x.dtype)  # keep the promised output dtype
-        return y
+        w = (_ctx().get_params(self._path)["weight"]
+             if self.elementwise_affine else None)
+        return F.rms_norm(x, w, self.eps, axes)
 
     def __repr__(self):
         return f"RMSNorm({self.normalized_shape})"

@@ -15,8 +15,9 @@ TPU-first design — routing as dense einsums, not gather/scatter:
   position assignment gives every (choice, token) a slot at its chosen
   expert; tokens beyond an expert's capacity ``C = ceil(k*N/E *
   capacity_factor)`` are dropped — their combine weights are zero, so they
-  pass through the surrounding residual unchanged.  Two interchangeable
-  dispatch realizations (``dispatch=``), numerically identical outputs:
+  pass through the surrounding residual unchanged.  Three
+  dispatch realizations (``dispatch=``), numerically identical while
+  nothing is dropped:
 
   * ``"einsum"`` — dense **dispatch/combine tensors** ``(N, E, C)`` built
     from one-hots: static shapes, no data-dependent indexing, and the XLA
@@ -36,14 +37,32 @@ TPU-first design — routing as dense einsums, not gather/scatter:
     ~5 ms at 16k rows on v5e), each expert run over its exact contiguous
     segment by the grouped-matmul kernels (ops/gmm.py), segments padded
     only to the row-block size.  No capacity, no drops, and the output
-    never depends on batch composition.  Measured honestly (quiet-chip
-    interleaved A/B at GPT-2-small MoE shapes): ~0.6x the capacity path
-    forward / 0.8x fwd+bwd — XLA's dense batched einsum over the padded
-    (E, C, d) tensor runs at near-peak MXU rate and beats the
-    finer-grained grouped kernels despite doing 1.25x the FLOPs, so
-    ``dropless`` is the EXACTNESS option (serving, drop-sensitive
-    training), not a throughput one, at these shapes.
+    never depends on batch composition: the path to SERVE with, where a
+    capacity cumsum over free slots and bucket padding would make one
+    client's tokens depend on who shares the pool.  On the chip at OLMoE's
+    shapes (64 experts of 2048 x 1024, 8 a token; PERF.md, PR 25) the
+    grouped matmuls are bound by reading the experts' weights, not by the
+    MXU: 1.5 ms a layer for a 1024-token prefill (128 rows an expert) and
+    1.1 ms for a 32-slot decode step (4 rows an expert) against the
+    0.98 ms that reading 806 MB takes; the row gathers around them
+    (``dispatch``, ``combine``) add a quarter of that in prefill.  Against
+    the capacity paths in training it has no measurement on this machine:
+    the only record is a lead from before PR 1 at dim 768 with 8 experts
+    (~0.6x the capacity path forward, 0.8x forward and backward, XLA's
+    dense batched einsum over the padded (E, C, d) tensor beating the
+    finer-grained kernels despite 1.25x the FLOPs).
 
+- ``gated=True`` makes each expert ``down(silu(gate(x)) * up(x))`` without
+  biases (``w1`` gate, ``w3`` up, ``w2`` down, the LLaMA-family names) in
+  place of the two-matrix GELU MLP; every dispatch computes either.
+- The router's logits are accumulated, softened and ranked in float32
+  whatever the activations' type (top-k among 64 near-equal probabilities
+  is not a bfloat16 decision); ``normalize_gates=False`` uses the selected
+  probabilities as they are (OLMoE's ``norm_topk_prob: false``).
+- Inside the layer ``jax.named_scope``s ``route``, ``dispatch``,
+  ``experts`` and ``combine`` split a device trace (``python3 -m
+  chipbench.scope_reduce``), and while serving the layer counts its routed
+  rows per expert on the device (:meth:`MoELayer._count_rows`).
 - The Switch **load-balancing auxiliary loss** ``E * sum_e f_e * p_e``
   (fraction of tokens routed to e times mean router probability of e) is
   published through the module-state mechanism (``state["aux_loss"]``):
@@ -81,11 +100,25 @@ __all__ = ["MoELayer"]
 # slot->choice inverse-map build (~0.1 ms at 32k tokens on v5e).  Integer
 # index arguments take no gradient (None cotangents).
 
+def _rows_or_zero(rows, index):
+    """``rows[index]`` with zeros where ``index == len(rows)``, the sentinel
+    of an empty slot or a dropped choice.  Appending the zero row copies the
+    SOURCE, masking the gather's result is a pass over the RESULT: take the
+    smaller.  On the chip at a 1024-token OLMoE prefill (PERF.md, PR 25)
+    the dispatch (1,024 rows gathered into 16,384) took 0.23 ms padded and
+    0.33 ms masked, and the combine's padded copy of its 16,384 x 2048
+    source was a 0.09 ms operation of its own."""
+    if rows.shape[0] < index.size:
+        pad = jnp.concatenate(
+            [rows, jnp.zeros((1,) + rows.shape[1:], rows.dtype)])
+        return pad[index]
+    return jnp.take(rows, index, axis=0, mode="fill", fill_value=0)
+
+
 @jax.custom_vjp
 def _dispatch_rows(xt, token_for_slot, slot):
     """xt (N, d) -> xs_flat (E*C, d): row token_for_slot[s], zeros if == N."""
-    pad = jnp.concatenate([xt, jnp.zeros((1, xt.shape[1]), xt.dtype)])
-    return pad[token_for_slot]
+    return _rows_or_zero(xt, token_for_slot)
 
 
 def _dispatch_rows_fwd(xt, token_for_slot, slot):
@@ -93,11 +126,9 @@ def _dispatch_rows_fwd(xt, token_for_slot, slot):
 
 
 def _dispatch_rows_bwd(slot, g):
-    # grad_xt[i] = sum_j grad_xs[slot[j, i]]; dropped choices point at the
-    # appended zero row (slot == E*C)
-    g_pad = jnp.concatenate([g, jnp.zeros((1, g.shape[1]), g.dtype)])
-    gx = g_pad[slot.reshape(-1)].reshape(*slot.shape, g.shape[1])
-    return gx.sum(0), None, None
+    # grad_xt[i] = sum_j grad_xs[slot[j, i]]; dropped choices point past
+    # the last row (slot == E*C) and read zeros
+    return _rows_or_zero(g, slot).sum(0), None, None
 
 
 _dispatch_rows.defvjp(_dispatch_rows_fwd, _dispatch_rows_bwd)
@@ -111,9 +142,7 @@ def _combine_rows(out_flat, w, choice_for_slot, slot):
     (choice-major) index occupying each slot, k*N if empty — only the
     backward pass needs it, to invert the gy and w lookups as gathers.
     """
-    pad = jnp.concatenate([out_flat,
-                           jnp.zeros((1, out_flat.shape[1]), out_flat.dtype)])
-    g = pad[slot.reshape(-1)].reshape(*slot.shape, out_flat.shape[1])
+    g = _rows_or_zero(out_flat, slot)
     return (g * w[:, :, None].astype(g.dtype)).sum(0)
 
 
@@ -125,18 +154,15 @@ def _combine_rows_fwd(out_flat, w, choice_for_slot, slot):
 def _combine_rows_bwd(res, gy):
     out_flat, w, choice_for_slot, slot = res
     k, n = slot.shape
-    d = out_flat.shape[1]
-    # grad_out[s] = w[choice(s)] * gy[token(s)]; empty slots hit the padded
-    # zero rows of both lookups (choice_for_slot == k*n -> token == n)
+    # grad_out[s] = w[choice(s)] * gy[token(s)]; empty slots read zeros in
+    # both lookups (choice_for_slot == k*n -> token == n)
     token_for_slot = jnp.where(choice_for_slot == k * n, n,
                                choice_for_slot % jnp.int32(n))
-    gy_pad = jnp.concatenate([gy, jnp.zeros((1, d), gy.dtype)])
-    w_flat = jnp.concatenate([w.reshape(-1), jnp.zeros((1,), w.dtype)])
-    w_at_slot = w_flat[choice_for_slot]
-    g_out = w_at_slot[:, None].astype(gy.dtype) * gy_pad[token_for_slot]
-    # grad_w[j, i] = dot(gy[i], out_pad[slot[j, i]])
-    out_pad = jnp.concatenate([out_flat, jnp.zeros((1, d), out_flat.dtype)])
-    g_rows = out_pad[slot.reshape(-1)].reshape(k, n, d)
+    w_at_slot = _rows_or_zero(w.reshape(-1), choice_for_slot)
+    g_out = (w_at_slot[:, None].astype(gy.dtype)
+             * _rows_or_zero(gy, token_for_slot))
+    # grad_w[j, i] = dot(gy[i], out_flat[slot[j, i]])
+    g_rows = _rows_or_zero(out_flat, slot)
     g_w = (g_rows * gy[None, :, :].astype(g_rows.dtype)).sum(-1)
     return g_out, g_w.astype(w.dtype), None, None
 
@@ -168,14 +194,17 @@ class MoELayer(Module):
             single-device / shard_map execution), or ``"dropless"``
             (sort-by-expert + grouped-matmul kernels, ops/gmm.py: no
             capacity, no drops, batch-composition-independent outputs —
-            the EXACTNESS option; measured ~0.6-0.8x the capacity
-            path's speed at GPT-2-small shapes, see module docstring;
+            the one to serve with, see module docstring;
             ``capacity_factor`` is ignored).
+        gated: each expert is ``down(silu(gate(x)) * up(x))`` without
+            biases, ``hidden`` wide (parameters ``w1``, ``w3``, ``w2``),
+            instead of ``w2(gelu(w1 x + b1)) + b2``.
     """
 
     def __init__(self, dim: int, num_experts: int, hidden: int = 0,
                  top_k: int = 2, capacity_factor: float = 1.25,
-                 normalize_gates: bool = True, dispatch: str = "einsum"):
+                 normalize_gates: bool = True, dispatch: str = "einsum",
+                 gated: bool = False):
         super().__init__()
         if num_experts < 2:
             raise ValueError(f"num_experts must be >= 2, got {num_experts}")
@@ -191,6 +220,7 @@ class MoELayer(Module):
         self.capacity_factor = capacity_factor
         self.normalize_gates = normalize_gates
         self.dispatch = dispatch
+        self.gated = gated
 
     def create_params(self, key):
         kr, k1, k2 = jax.random.split(key, 3)
@@ -203,6 +233,16 @@ class MoELayer(Module):
             bound = math.sqrt(6.0 / fan_in)
             return init_lib.uniform(k, shape, -bound, bound)
 
+        if self.gated:
+            # w1 = gate, w3 = up, w2 = down (the LLaMA-family names); no
+            # biases, as every published gated expert has none
+            return {
+                "router": init_lib.kaiming_uniform(kr, (d, e)),
+                "w1": expert_uniform(k1, (e, d, h), d),
+                "w3": expert_uniform(jax.random.fold_in(key, 3), (e, d, h),
+                                     d),
+                "w2": expert_uniform(k2, (e, h, d), h),
+            }
         return {
             "router": init_lib.kaiming_uniform(kr, (d, e)),
             "w1": expert_uniform(k1, (e, d, h), d),
@@ -223,30 +263,41 @@ class MoELayer(Module):
 
     def forward(self, x):
         from .module import _ctx
-        p = _ctx().get_params(self._path)
+        ctx = _ctx()
+        p = ctx.get_params(self._path)
         e, k = self.num_experts, self.top_k
         lead, d = x.shape[:-1], x.shape[-1]
         xt = x.reshape(-1, d)
         n = xt.shape[0]
         c = self._capacity(n)
 
-        probs = jax.nn.softmax(xt @ p["router"], axis=-1)        # (N, E)
-        gate_vals, gate_idx = lax.top_k(probs, k)                # (N, k)
-        if self.normalize_gates and k > 1:
-            gate_vals = gate_vals / jnp.maximum(
-                gate_vals.sum(-1, keepdims=True), 1e-9)
+        with jax.named_scope("route"):
+            # router logits accumulate and soften in float32 whatever the
+            # activations' type: with 64 experts a bfloat16 softmax puts
+            # near-ties among the top-k in the wrong order
+            probs = jax.nn.softmax(
+                jnp.dot(xt, p["router"],
+                        preferred_element_type=jnp.float32), axis=-1)
+            gate_vals, gate_idx = lax.top_k(probs, k)            # (N, k)
+            if self.normalize_gates and k > 1:
+                gate_vals = gate_vals / jnp.maximum(
+                    gate_vals.sum(-1, keepdims=True), 1e-9)
+            gate_vals = gate_vals.astype(xt.dtype)
 
-
-        # slot assignment: flatten the k choices in priority order (all
-        # first choices, then all second choices, ...) and cumsum the
-        # one-hots — each (choice, token) gets its arrival index at the
-        # chosen expert; indices >= capacity are dropped.  Bookkeeping runs
-        # in int32 no matter what xt's dtype is: a bf16 cumsum rounds
-        # positions past 256 and mis-slots tokens.
-        oh_i = jax.nn.one_hot(gate_idx.T, e, dtype=jnp.int32)    # (k, N, E)
-        flat = oh_i.reshape(k * n, e)
-        pos = (jnp.cumsum(flat, axis=0) - flat)                  # (k*N, E)
-        pos = (pos * flat).sum(-1).reshape(k, n)                 # (k, N)
+            # slot assignment: flatten the k choices in priority order (all
+            # first choices, then all second choices, ...) and cumsum the
+            # one-hots — each (choice, token) gets its arrival index at the
+            # chosen expert; indices >= capacity are dropped.  Bookkeeping
+            # runs in int32 no matter what xt's dtype is: a bf16 cumsum
+            # rounds positions past 256 and mis-slots tokens.
+            oh_i = jax.nn.one_hot(gate_idx.T, e, dtype=jnp.int32)  # (k,N,E)
+            flat = oh_i.reshape(k * n, e)
+            pos = (jnp.cumsum(flat, axis=0) - flat)              # (k*N, E)
+            pos = (pos * flat).sum(-1).reshape(k, n)             # (k, N)
+            # serving counts its routed rows; training publishes the
+            # load-balancing loss (the state entry holds one or the other)
+            if not self._count_rows(ctx, oh_i):
+                self._put_switch_aux(xt, probs.astype(xt.dtype), gate_idx)
 
         if self.dispatch == "dropless":
             # pos IS each row's stable within-expert rank — the same
@@ -256,46 +307,84 @@ class MoELayer(Module):
             counts = oh_i.sum((0, 1))                            # (E,)
             y = self._forward_dropless(p, xt, gate_vals, gate_idx, pos,
                                        counts)
-            self._put_switch_aux(xt, probs, gate_idx)
             return y.reshape(*lead, d)
 
         keep = (pos < c).astype(xt.dtype)                        # (k, N)
 
-        if self.dispatch == "gather":
-            # forward map: (choice, token) -> flat slot e*C + pos (trash
-            # slot E*C for dropped); inverse map via one int32 scatter
-            slot = jnp.where(keep > 0,
-                             gate_idx.T.astype(jnp.int32) * c + pos,
-                             e * c)                              # (k, N)
-            choice_for_slot = (
-                jnp.full((e * c + 1,), k * n, jnp.int32)
-                .at[slot.reshape(-1)]
-                .set(jnp.arange(k * n, dtype=jnp.int32), mode="drop")[:-1])
-            token_for_slot = jnp.where(choice_for_slot == k * n, n,
-                                       choice_for_slot % jnp.int32(n))
-            xs = _dispatch_rows(xt, token_for_slot, slot).reshape(e, c, d)
-            combine_t = None
-        else:
-            slot_oh = jax.nn.one_hot(pos, c, dtype=xt.dtype)     # (k, N, C)
-            oh = oh_i.astype(xt.dtype)
-            # (k, N, E, C) collapsed over k → dispatch/combine (N, E, C)
-            dispatch_t = jnp.einsum("kne,knc,kn->nec", oh, slot_oh, keep)
-            combine_t = jnp.einsum("kne,knc,kn->nec", oh, slot_oh,
-                                   keep * gate_vals.T)
-            xs = jnp.einsum("nec,nd->ecd", dispatch_t, xt)
-        hdn = jax.nn.gelu(jnp.einsum("ecd,edh->ech", xs, p["w1"])
-                          + p["b1"][:, None, :])
-        out = jnp.einsum("ech,ehd->ecd", hdn, p["w2"]) + p["b2"][:, None, :]
+        with jax.named_scope("dispatch"):
+            if self.dispatch == "gather":
+                # forward map: (choice, token) -> flat slot e*C + pos (trash
+                # slot E*C for dropped); inverse map via one int32 scatter
+                slot = jnp.where(keep > 0,
+                                 gate_idx.T.astype(jnp.int32) * c + pos,
+                                 e * c)                          # (k, N)
+                choice_for_slot = (
+                    jnp.full((e * c + 1,), k * n, jnp.int32)
+                    .at[slot.reshape(-1)]
+                    .set(jnp.arange(k * n, dtype=jnp.int32),
+                         mode="drop")[:-1])
+                token_for_slot = jnp.where(choice_for_slot == k * n, n,
+                                           choice_for_slot % jnp.int32(n))
+                xs = _dispatch_rows(xt, token_for_slot, slot).reshape(e, c, d)
+                combine_t = None
+            else:
+                slot_oh = jax.nn.one_hot(pos, c, dtype=xt.dtype)  # (k, N, C)
+                oh = oh_i.astype(xt.dtype)
+                # (k, N, E, C) collapsed over k → dispatch/combine (N, E, C)
+                dispatch_t = jnp.einsum("kne,knc,kn->nec", oh, slot_oh, keep)
+                combine_t = jnp.einsum("kne,knc,kn->nec", oh, slot_oh,
+                                       keep * gate_vals.T)
+                xs = jnp.einsum("nec,nd->ecd", dispatch_t, xt)
+        def linear(rows, w, bias):              # (E, C, in) -> (E, C, out)
+            y = jnp.einsum("eci,eio->eco", rows, w)
+            return y if bias is None else y + bias[:, None, :]
+
+        out = self._experts(p, xs, linear)
         # dropped tokens have all-zero combine rows → output 0; the
         # surrounding residual connection passes them through unchanged
-        if self.dispatch == "gather":
-            y = _combine_rows(out.reshape(e * c, d), keep * gate_vals.T,
-                              choice_for_slot, slot)
-        else:
-            y = jnp.einsum("nec,ecd->nd", combine_t, out)
-
-        self._put_switch_aux(xt, probs, gate_idx)
+        with jax.named_scope("combine"):
+            if self.dispatch == "gather":
+                y = _combine_rows(out.reshape(e * c, d), keep * gate_vals.T,
+                                  choice_for_slot, slot)
+            else:
+                y = jnp.einsum("nec,ecd->nd", combine_t, out)
         return y.reshape(*lead, d)
+
+    def _experts(self, p, xs, linear):
+        """The expert FFN over rows already in expert order, ``linear(rows,
+        w, bias)`` being the per-expert matmul of the dispatch at hand:
+        ``w2(silu(w1 x) * w3 x)`` gated, ``w2 gelu(w1 x + b1) + b2`` not."""
+        with jax.named_scope("experts"):
+            if self.gated:
+                hdn = (jax.nn.silu(linear(xs, p["w1"], None))
+                       * linear(xs, p["w3"], None))
+                return linear(hdn, p["w2"], None)
+            return linear(jax.nn.gelu(linear(xs, p["w1"], p["b1"])),
+                          p["w2"], p["b2"])
+
+    def _count_rows(self, ctx, oh_i) -> bool:
+        """Serving counters (``TransformerLM.init_moe_counters``): when
+        this layer's state entry carries them, add this call's routed rows
+        per expert to it, on the device.  ``valid`` (the rows that belong
+        to a request; the entry's, set by ``decode_step`` /
+        ``prefill_into_slot``) keeps the rows of free slots and of bucket
+        padding apart: they are routed and cost work, but are nobody's.
+        True when counted (the training-time aux loss is then not
+        published: the entry holds counters and nothing else)."""
+        st = ctx.state.get(self._path) if ctx.state else None
+        if st is None or "rows" not in st:
+            return False
+        k, n, _ = oh_i.shape
+        valid = st["valid"].reshape(n)
+        rows = (oh_i.sum(0) * valid[:, None].astype(jnp.int32)).sum(0)
+        n_valid = valid.sum().astype(jnp.int32)
+        ctx.put_state(self._path, {
+            "rows": st["rows"] + rows,
+            "pad_rows": st["pad_rows"] + k * (n - n_valid),
+            "calls": st["calls"] + 1,
+            "experts_hit": st["experts_hit"] + (rows > 0).sum().astype(
+                jnp.int32)})
+        return True
 
     def _put_switch_aux(self, xt, probs, gate_idx):
         # Switch load-balance loss on first-choice assignments
@@ -335,39 +424,45 @@ class MoELayer(Module):
         m_rows = (-(-kn // b) + e) * b                 # static upper bound
         nb = m_rows // b
 
-        # destination row per (choice, token): its expert's block-aligned
-        # segment start + its arrival rank there (``rank`` is the routing
-        # cumsum from forward() — a stable counting sort, no argsort)
-        padded = ((counts + b - 1) // b) * b
-        pad_start = jnp.cumsum(padded) - padded                 # (E,)
-        slot = (pad_start[gate_idx.T] + rank).astype(jnp.int32)  # (k, N)
-        pos = slot.reshape(-1)                                   # (k*N,)
+        with jax.named_scope("dispatch"):
+            # destination row per (choice, token): its expert's
+            # block-aligned segment start + its arrival rank there
+            # (``rank`` is the routing cumsum from forward() — a stable
+            # counting sort, no argsort)
+            padded = ((counts + b - 1) // b) * b
+            pad_start = jnp.cumsum(padded) - padded              # (E,)
+            slot = (pad_start[gate_idx.T] + rank).astype(jnp.int32)  # (k,N)
+            pos = slot.reshape(-1)                               # (k*N,)
 
-        # the two inverse maps the gather VJPs need; pad rows point at
-        # the sentinels (token n = zero row, choice k*n = dropped)
-        flat_choice = jnp.arange(kn, dtype=jnp.int32)
-        token_for_row = (jnp.full((m_rows,), n, jnp.int32)
-                         .at[pos].set(flat_choice % n))
-        choice_for_row = (jnp.full((m_rows,), kn, jnp.int32)
-                          .at[pos].set(flat_choice))
+            # the two inverse maps the gather VJPs need; pad rows point at
+            # the sentinels (token n = zero row, choice k*n = dropped)
+            flat_choice = jnp.arange(kn, dtype=jnp.int32)
+            token_for_row = (jnp.full((m_rows,), n, jnp.int32)
+                             .at[pos].set(flat_choice % n))
+            choice_for_row = (jnp.full((m_rows,), kn, jnp.int32)
+                              .at[pos].set(flat_choice))
 
-        cum_padded = jnp.cumsum(padded)
-        n_live = (cum_padded[-1] // b).astype(jnp.int32)
-        # block -> expert map; overallocation-tail blocks get clamped to
-        # E-1 (tgmm needs them to extend the final segment with zero rows)
-        bg = jnp.searchsorted(cum_padded,
-                              jnp.arange(nb, dtype=jnp.int32) * b,
-                              side="right")
-        bg = jnp.minimum(bg, e - 1).astype(jnp.int32)
-        present = counts > 0
+            cum_padded = jnp.cumsum(padded)
+            n_live = (cum_padded[-1] // b).astype(jnp.int32)
+            # block -> expert map; overallocation-tail blocks get clamped
+            # to E-1 (tgmm needs them to extend the final segment with
+            # zero rows)
+            bg = jnp.searchsorted(cum_padded,
+                                  jnp.arange(nb, dtype=jnp.int32) * b,
+                                  side="right")
+            bg = jnp.minimum(bg, e - 1).astype(jnp.int32)
+            present = counts > 0
+            xs = _dispatch_rows(xt, token_for_row, slot)        # (M, d)
+        # the kernels are named by the routed rows of the call, so a device
+        # trace tells a 1024-token prefill's calls (gmm_r8192) from a
+        # 32-slot decode step's (gmm_r256)
+        def linear(rows, w, bias):
+            return grouped_linear(rows, w, bias, bg, n_live, present, b, 512,
+                                  f"gmm_r{kn}")
 
-        xs = _dispatch_rows(xt, token_for_row, slot)            # (M, d)
-        hdn_lin = grouped_linear(xs, p["w1"], p["b1"], bg, n_live, present,
-                                 b, 512)
-        hdn = jax.nn.gelu(hdn_lin)
-        out = grouped_linear(hdn, p["w2"], p["b2"], bg, n_live, present,
-                             b, 512)
-        return _combine_rows(out, gate_vals.T, choice_for_row, slot)
+        out = self._experts(p, xs, linear)
+        with jax.named_scope("combine"):
+            return _combine_rows(out, gate_vals.T, choice_for_row, slot)
 
     def _put_aux(self, aux) -> None:
         from .module import current_context

@@ -72,7 +72,8 @@ def _fit_blocks(block_rows: int, block_h: int, dp: int, itemsize: int,
 
 
 def gmm(x, w, block_groups, n_live_blocks, *, bias=None, block_rows: int = 512,
-        block_h: int = 512, out_dtype=None, activation=None):
+        block_h: int = 512, out_dtype=None, activation=None,
+        name: str = "gmm"):
     """Block-diagonal grouped matmul: ``out[i*B:(i+1)*B] = x[i*B:(i+1)*B]
     @ w[block_groups[i]] (+ bias[block_groups[i]])``.
 
@@ -89,6 +90,9 @@ def gmm(x, w, block_groups, n_live_blocks, *, bias=None, block_rows: int = 512,
         activation: optional elementwise fn applied in-kernel on the f32
             accumulator (e.g. ``jax.nn.gelu``) — saves a full (M, H) HBM
             round-trip vs applying it outside.
+        name: the ``pallas_call``'s name, which a device trace shows as
+            the operation's; must hold ``gmm`` (chipbench finds the
+            kernels by it).
     Returns:
         (M, H) in ``out_dtype`` (default ``x.dtype``).
     """
@@ -174,7 +178,7 @@ def gmm(x, w, block_groups, n_live_blocks, *, bias=None, block_rows: int = 512,
         kernel, grid_spec=grid_spec,
         out_shape=_out_struct((m, hp), out_dtype, xp, wp, bp),
         interpret=_use_interpret(),
-        name="gmm",
+        name=name,
     )(scalars, xp, wp, bp)
     return out[:, :h]
 
@@ -283,38 +287,44 @@ def tgmm(x, dy, block_groups, n_groups: int, *, block_rows: int = 512,
 # differentiable wrapper
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
 def grouped_linear(x, w, bias, block_groups, n_live_blocks, group_present,
-                   block_rows=512, block_h=512):
+                   block_rows=512, block_h=512, name="gmm"):
     """Differentiable grouped linear: ``gmm(x, w, ...) + bias[group]`` with
     the three backward passes expressed as grouped matmuls over the same
     block map (dx via gmm against w^T, dw/db via tgmm) — no scatters.
+    ``bias`` may be None (a gated expert has none).
 
     ``group_present`` (E,) bool marks groups with at least one routed row:
     tgmm never visits an absent group, leaving its dw/db tiles unwritten
     (garbage), so the backward zero-masks them here.  Rows must be sorted
     by group with block-aligned segments and ZERO padding rows — pad rows
     then contribute nothing to any of the three grads (their x and dy are
-    both zero).  Integer/bool args take no gradient."""
+    both zero).  Integer/bool args take no gradient.  ``name`` names the
+    forward kernel (see :func:`gmm`)."""
     return gmm(x, w, block_groups, n_live_blocks, bias=bias,
-               block_rows=block_rows, block_h=block_h)
+               block_rows=block_rows, block_h=block_h, name=name)
 
 
 def _gl_fwd(x, w, bias, block_groups, n_live_blocks, group_present,
-            block_rows, block_h):
+            block_rows, block_h, name):
     out = gmm(x, w, block_groups, n_live_blocks, bias=bias,
-              block_rows=block_rows, block_h=block_h)
-    return out, (x, w, block_groups, n_live_blocks, group_present)
+              block_rows=block_rows, block_h=block_h, name=name)
+    return out, (x, w, bias, block_groups, n_live_blocks, group_present)
 
 
-def _gl_bwd(block_rows, block_h, res, dy):
-    x, w, block_groups, n_live_blocks, group_present = res
+def _gl_bwd(block_rows, block_h, name, res, dy):
+    x, w, bias, block_groups, n_live_blocks, group_present = res
     e, d, h = w.shape
     dx = gmm(dy, jnp.swapaxes(w, 1, 2), block_groups, n_live_blocks,
              block_rows=block_rows, block_h=block_h, out_dtype=x.dtype)
+    db = None
     if d <= h:
-        dw, db = tgmm(x, dy, block_groups, e, block_rows=block_rows,
-                      block_h=block_h, with_rowsum=True, out_dtype=w.dtype)
+        dw = tgmm(x, dy, block_groups, e, block_rows=block_rows,
+                  block_h=block_h, with_rowsum=bias is not None,
+                  out_dtype=w.dtype)
+        if bias is not None:
+            dw, db = dw
     else:
         # x wider than dy (e.g. the down-projection w2): tgmm's (D, bh)
         # f32 accumulator scales with the X side, so compute the
@@ -323,15 +333,17 @@ def _gl_bwd(block_rows, block_h, res, dy):
         dw = jnp.swapaxes(
             tgmm(dy, x, block_groups, e, block_rows=block_rows,
                  block_h=block_h, out_dtype=w.dtype), 1, 2)
-        # bias grad = per-group row sums of dy: one elementwise pass
-        # (block partial sums, then a tiny scatter-add over blocks; dead
-        # tail blocks carry zero dy rows and contribute nothing)
-        nb = dy.shape[0] // block_rows
-        blk = dy.astype(jnp.float32).reshape(nb, block_rows, h).sum(1)
-        db = (jnp.zeros((e, h), jnp.float32).at[block_groups].add(blk)
-              .astype(w.dtype))
+        if bias is not None:
+            # bias grad = per-group row sums of dy: one elementwise pass
+            # (block partial sums, then a tiny scatter-add over blocks;
+            # dead tail blocks carry zero dy rows and contribute nothing)
+            nb = dy.shape[0] // block_rows
+            blk = dy.astype(jnp.float32).reshape(nb, block_rows, h).sum(1)
+            db = (jnp.zeros((e, h), jnp.float32).at[block_groups].add(blk)
+                  .astype(w.dtype))
     dw = jnp.where(group_present[:, None, None], dw, 0)
-    db = jnp.where(group_present[:, None], db, 0)
+    if db is not None:
+        db = jnp.where(group_present[:, None], db, 0)
     return dx, dw, db, None, None, None
 
 

@@ -71,8 +71,9 @@ class PartitionRules:
 TRANSFORMER_TP_RULES = PartitionRules(_rules.partition_pairs())
 
 # Expert parallelism over an 'expert' mesh axis: every stacked MoE leaf
-# (w1/b1/w2/b2, leading dim = num_experts; see nn/moe.py) shards its expert
-# axis; the router and everything else replicate.  The dispatch/combine
+# (w1/b1/w2/b2, a gated expert's w3; leading dim = num_experts; see
+# nn/moe.py) shards its expert axis; the router and everything else
+# replicate.  The dispatch/combine
 # einsums then partition over 'expert' and XLA inserts the token
 # all-to-alls the GShard paper wires by hand.
 MOE_EP_RULES = PartitionRules(_rules.partition_pairs({"expert": "expert"}))

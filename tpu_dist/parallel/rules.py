@@ -140,7 +140,7 @@ TRANSFORMER_LAYOUTS: Tuple[Tuple[str, str, LeafLayout], ...] = (
     (r"tok", r"weight", LeafLayout((("vocab",), ("embed",)))),
     (r"pos", r"weight", LeafLayout((("seq",), ("embed",)))),
     # MoE expert banks (nn.moe): leading dim is the expert bank
-    (r"block\d+\.mlp", r"[wb][12]", LeafLayout((("expert",),))),
+    (r"block\d+\.mlp", r"[wb][123]", LeafLayout((("expert",),))),
 )
 
 

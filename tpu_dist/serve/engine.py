@@ -319,6 +319,26 @@ def _bucket_lengths(max_prompt: int, min_bucket: int = 16) -> List[int]:
     return out
 
 
+class _CountedProgram:
+    """A jitted pool program ``f(params, cache, counters, *args) -> (out,
+    cache, counters)`` called as ``f(params, cache, *args) -> (out, cache)``:
+    the counter set ``sets[phase]`` is threaded through every call and
+    replaced by what comes back.  ``lower`` takes the same short signature
+    (tests and scripts inspect the program through it)."""
+
+    def __init__(self, program, sets: dict, phase: str):
+        self.program, self.sets, self.phase = program, sets, phase
+
+    def __call__(self, params, cache, *args):
+        out, cache, self.sets[self.phase] = self.program(
+            params, cache, self.sets[self.phase], *args)
+        return out, cache
+
+    def lower(self, params, cache, *args):
+        return self.program.lower(params, cache, self.sets[self.phase],
+                                  *args)
+
+
 class SlotEngine:
     """Fixed pool of ``num_slots`` KV-cache slots with per-slot lengths.
 
@@ -370,6 +390,11 @@ class SlotEngine:
         self._occupied_slot_steps = 0
         self._decode_steps = 0
         self._iterations = 0    # decode iterations ever run: spans' step=
+        # routed-row counters per pool program (_build_programs fills them
+        # for a model with expert layers) and their reading at the last
+        # reset_stats()
+        self._moe: dict = {}
+        self._moe_base: dict = {}
 
         self._build_programs()
 
@@ -384,34 +409,48 @@ class SlotEngine:
         import jax.numpy as jnp
 
         model = self.model
+        # routed-row counters of a model with expert layers ({} without):
+        # one set per pool program, on the device, beside the pool.  Each
+        # program takes its set merged into the cache it hands the model
+        # and returns it with the call's rows added; nothing is read back
+        # until stats().  NOT donated: stats() and reset_stats() may read
+        # a set from another thread while the loop thread steps.
+        fresh = getattr(model, "init_moe_counters", dict)
+        self._moe = {"prefill": fresh(), "decode": fresh()}
 
-        def _decode_fn(params, cache, tokens, lengths, temps, keys, steps,
-                       sampling):
+        def split(merged, moe):
+            return ({p: e for p, e in merged.items() if p not in moe},
+                    {p: merged[p] for p in moe})
+
+        def _decode_fn(params, cache, moe, tokens, lengths, temps, keys,
+                       steps, sampling):
             with jax.named_scope("decode"):
-                logits, cache = model.decode_step(params, tokens, lengths,
-                                                  cache)
+                logits, merged = model.decode_step(params, tokens, lengths,
+                                                   dict(cache, **moe))
             with jax.named_scope("sample"):
-                return sample_tokens(logits, temps, keys, steps,
-                                     sampling), cache
+                return (sample_tokens(logits, temps, keys, steps, sampling),
+                        *split(merged, moe))
 
-        def _prefill_fn(params, cache, prompt, length, slot, temp, key,
+        def _prefill_fn(params, cache, moe, prompt, length, slot, temp, key,
                         sampling):
             with jax.named_scope("prefill"):
-                logits, cache = model.prefill_into_slot(params, prompt,
-                                                        length, slot, cache)
+                logits, merged = model.prefill_into_slot(
+                    params, prompt, length, slot, dict(cache, **moe))
             with jax.named_scope("sample"):
                 tok = sample_tokens(logits[None], temp[None], key[None],
                                     jnp.zeros((1,), jnp.int32), sampling)
-            return tok[0], cache
+            return (tok[0], *split(merged, moe))
 
         # the cache is donated (the pool buffer is updated in place instead
         # of copied every token); ``sampling`` is STATIC — jit caches by
         # shape, so whether any slot samples must key the program cache,
         # not be read from host state at trace time
-        self._decode = jax.jit(_decode_fn, donate_argnums=(1,),
-                               static_argnums=(7,))
-        self._prefill = jax.jit(_prefill_fn, donate_argnums=(1,),
-                                static_argnums=(7,))
+        decode = jax.jit(_decode_fn, donate_argnums=(1,), static_argnums=(8,))
+        prefill = jax.jit(_prefill_fn, donate_argnums=(1,),
+                          static_argnums=(8,))
+
+        self._decode = _CountedProgram(decode, self._moe, "decode")
+        self._prefill = _CountedProgram(prefill, self._moe, "prefill")
 
     # -- sampling (traced) ---------------------------------------------------
 
@@ -724,9 +763,52 @@ class SlotEngine:
         self._occupied_slot_steps = 0
         self._decode_steps = 0
         reset_phases(SERVE_PHASES)
+        # the device counters are never zeroed (a step in flight would
+        # carry the old count on): stats() reports them past this reading
+        self._moe_base = self._moe_read()
+
+    def _moe_read(self) -> dict:
+        """The routed-row counters per pool program, summed over layers, as
+        numpy int64 (one fetch per program; {} for a model with no expert
+        layer or an engine that keeps none)."""
+        import jax
+
+        out = {}
+        for phase, sets in dict(self._moe).items():
+            if sets:
+                layers = jax.device_get(list(sets.values()))
+                out[phase] = {k: sum(np.asarray(l[k], np.int64)
+                                     for l in layers) for k in layers[0]}
+        return out
+
+    def _moe_stats(self) -> Optional[dict]:
+        """``stats()["moe"]``: routed rows since ``reset_stats()``, per
+        expert (a request's rows only, summed over layers and both pool
+        programs) and per program — ``rows`` a request's, ``pad_rows`` those
+        of free slots (decode) and bucket padding (prefill), ``calls`` of an
+        expert layer, ``experts_hit`` summed over calls."""
+        now = self._moe_read()
+        if not now:
+            return None
+        base = self._moe_base
+        # int32 on the device: differences are right modulo 2**32
+        since = {phase: {k: (v - base.get(phase, {}).get(k, 0)) % (1 << 32)
+                         for k, v in c.items()} for phase, c in now.items()}
+        per_expert = sum(c["rows"] for c in since.values())
+        by_phase = {phase: {"rows": int(c["rows"].sum()),
+                            "pad_rows": int(c["pad_rows"]),
+                            "calls": int(c["calls"]),
+                            "experts_hit": int(c["experts_hit"])}
+                    for phase, c in since.items()}
+        total = lambda k: sum(c[k] for c in by_phase.values())
+        return {"rows_per_expert": [int(r) for r in per_expert],
+                "rows": total("rows"), "pad_rows": total("pad_rows"),
+                "calls": total("calls"), "by_phase": by_phase}
 
     def stats(self) -> dict:
+        moe = self._moe_stats()
         return {
+            **({"moe": moe} if moe else {}),
             "completed": self.completed,
             "generated_tokens": self.generated_tokens,
             "decode_steps": self._decode_steps,
