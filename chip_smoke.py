@@ -5,8 +5,9 @@ at the full width of the repo's GPT-2-small-shaped LM (12 x 768 x 12 heads,
 vocab 32768, T = 2048), every phase fatal:
 
   device    platform must be ``tpu``; versions, compile cache, store backend
-  kernels   the three Pallas kernels, forward and backward, lowered by Mosaic
-            at their full-width users' shapes, against plain ``jnp``
+  kernels   the Pallas kernels (flash attention, fused CE and the grouped
+            matmuls forward and backward, slot-decode attention), lowered by
+            Mosaic at their full-width users' shapes, against plain ``jnp``
   convnet   the source paper's ConvNet through ``init_process_group`` +
             ``DistributedDataParallel.train_step``
   trainer   the LM through the same DDP over ALL local devices, bf16, fused
@@ -28,6 +29,7 @@ CPU branch.
 from __future__ import annotations
 
 import json
+import math
 import os
 import sys
 import time
@@ -273,11 +275,64 @@ def check_dropless_moe(tokens: int, dim: int, experts: int,
                      BF16_TOL)
 
 
-def phase_kernels(flash: dict, ce: dict, moe: dict) -> dict:
+def check_decode_attention(slots: int, heads: int, head_dim: int,
+                           max_len: int) -> None:
+    """The slot-decode kernel on a bf16 K/V pool ``(slots, heads, head_dim,
+    max_len)`` with ragged lengths (free slots, lane and block edges, a
+    full row), against the float32 jnp composition of the same step: the
+    output of every busy slot, and the pools bit for bit (the new column
+    in, nothing else touched)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from tpu_dist.ops.decode_attention import decode_attention
+
+    keys = jax.random.split(jax.random.key(6), 5)
+    q, kn, vn = (jax.random.normal(k, (slots, heads, head_dim), jnp.bfloat16)
+                 for k in keys[:3])
+    kp, vp = (jax.random.normal(k, (slots, heads, head_dim, max_len),
+                                jnp.bfloat16) for k in keys[3:])
+    edges = [0, 1, 127, 128, 129, 255, 256, 257, max_len - 1, max_len, 0,
+             max_len // 2 + 3]
+    lens = jnp.asarray([edges[i % len(edges)] for i in range(slots)],
+                       jnp.int32)
+
+    def reference(q, kn, vn, kp, vp, lens):
+        hi = jax.lax.Precision.HIGHEST
+        at = jnp.arange(max_len) == lens[:, None, None, None]
+        kp = jnp.where(at, kn[..., None], kp)
+        vp = jnp.where(at, vn[..., None], vp)
+        s = jnp.einsum("bhd,bhdt->bht", q.astype(jnp.float32),
+                       kp.astype(jnp.float32), precision=hi
+                       ) / math.sqrt(head_dim)
+        seen = jnp.arange(max_len) <= lens[:, None, None]
+        w = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
+        return jnp.einsum("bht,bhdt->bhd", w, vp.astype(jnp.float32),
+                          precision=hi), kp, vp
+
+    out, k2, v2 = jax.jit(decode_attention)(q, kn, vn, kp, vp, lens)
+    want, k_ref, v_ref = jax.jit(reference)(q, kn, vn, kp, vp, lens)
+    busy = np.asarray(lens) > 0
+    _check_close("decode attention out", np.asarray(out, np.float32)[busy],
+                 np.asarray(want)[busy], BF16_TOL)
+    for name, got, ref, before in (("K", k2, k_ref, kp), ("V", v2, v_ref, vp)):
+        got, ref, before = (np.asarray(a, np.float32)
+                            for a in (got, ref, before))
+        if not (np.array_equal(got[busy], ref[busy])
+                and np.array_equal(got[~busy], before[~busy])):
+            raise AssertionError(f"decode attention: the {name} pool is not "
+                                 f"the input with the new columns in")
+    _say(f"  decode attention pools: new columns in, nothing else touched "
+         f"({int(busy.sum())} busy of {slots} slots)")
+
+
+def phase_kernels(flash: dict, ce: dict, moe: dict, decode: dict) -> dict:
     t0 = time.perf_counter()
     check_flash(**flash)
     check_fused_ce(**ce)
     check_dropless_moe(**moe)
+    check_decode_attention(**decode)
     return {"seconds": time.perf_counter() - t0}
 
 
@@ -616,7 +671,8 @@ def main() -> int:
     r = phase_kernels(
         flash=dict(batch=8, seq=2048, heads=12, head_dim=64),    # B*H = 96
         ce=dict(rows=8 * 2048, vocab=32768),
-        moe=dict(tokens=8 * 2048, dim=768, experts=8, top_k=2))
+        moe=dict(tokens=8 * 2048, dim=768, experts=8, top_k=2),
+        decode=dict(slots=32, heads=25, head_dim=64, max_len=1024))
     _say(f"phase kernels passed ({r['seconds']:.1f} s with compilation)")
 
     _say("phase convnet")

@@ -97,16 +97,22 @@ def _compile(program, cache_dtype, sharding):
     return fn.lower(params, cache, ints(MAX_LEN), ints(), ints()).compile()
 
 
-def _pool_sized_copies(hlo_text):
-    """``copy`` instructions, in any computation of the module, whose result
-    has at least the K/V pool's element count."""
+def _pool_sized_results(hlo_text, ops):
+    """Instructions of the named kinds, in any computation of the module,
+    with a result (or a member of a tuple result) of at least the K/V
+    pool's element count."""
     found = []
     for line in hlo_text.splitlines():
-        m = re.search(r"= \w+\[([\d,]+)\]\S* copy\(", line)
-        if m and math.prod(int(d) for d in m.group(1).split(",")
-                           ) >= POOL_ELEMENTS:
+        m = re.search(r"= (.*?) (%s)\(" % "|".join(ops), line)
+        if m and any(math.prod(int(d) for d in dims.split(",")
+                               ) >= POOL_ELEMENTS
+                     for dims in re.findall(r"\w+\[([\d,]+)\]", m.group(1))):
             found.append(line.strip()[:160])
     return found
+
+
+def _pool_sized_copies(hlo_text):
+    return _pool_sized_results(hlo_text, ("copy",))
 
 
 @pytest.mark.parametrize("cache_dtype", [jnp.bfloat16, jnp.int8],
@@ -221,3 +227,61 @@ def test_olmoe_pool_program_compiles_with_its_grouped_matmuls(
             copies.append(line.strip()[:160])
     assert not copies, copies
     assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
+
+
+# -- the slot-decode kernel in the decode program (ISSUE 26) ------------------
+
+@pytest.fixture
+def mosaic_decode_attention(monkeypatch):
+    """As ``mosaic_gmm``: steer ``ops/decode_attention.py`` to the Mosaic
+    lowering although ``jax.default_backend()`` says CPU here."""
+    from tpu_dist.ops import decode_attention
+    monkeypatch.setattr(decode_attention, "_use_interpret", lambda: False)
+
+
+@pytest.mark.parametrize("heads, dim", [(25, 1600), (16, 2048), (5, 320)],
+                         ids=["gpt2xl-25x64", "olmoe-16x128", "shard-5x64"])
+def test_decode_step_on_the_kernel_writes_no_pool_sized_result(
+        one_chip, no_compile_cache, mosaic_decode_attention, heads, dim):
+    """``decode_step`` with the slot-decode kernel forced, compiled for the
+    described chip at the serving cells' head shapes (and one shard's of
+    ``serve/sharded.py``): one ``decode_attention`` call a layer, whose
+    aliased pools are the ONLY pool-sized results: no fusion and no copy
+    reads a whole pool tensor to write one (the dense branch's two
+    multi-output fusions a layer did, PERF.md section 5), and temporaries
+    stay under the decode limit."""
+    model = TransformerLM(VOCAB, dim=dim, depth=DEPTH, num_heads=heads,
+                          max_seq_len=MAX_LEN)
+    params = _shapes(jax.eval_shape(
+        lambda: jax.tree_util.tree_map(lambda a: a.astype(jnp.bfloat16),
+                                       model.init(jax.random.key(0)))),
+        one_chip)
+    cache = _shapes(jax.eval_shape(
+        lambda: model.init_slot_cache(SLOTS, MAX_LEN, jnp.bfloat16)),
+        one_chip)
+    ints = jax.ShapeDtypeStruct((SLOTS,), jnp.int32, sharding=one_chip)
+    with nn.attention_impl("flash"):
+        compiled = jax.jit(
+            lambda p, c, tok, lens: model.decode_step(p, tok, lens, c),
+            donate_argnums=1).lower(params, cache, ints, ints).compile()
+    text = compiled.as_text()
+    calls = re.findall(r"%(decode_attention)[.\d]* = [^\n]*"
+                       r"custom_call_target=\"tpu_custom_call\"", text)
+    assert len(calls) == DEPTH, calls
+    big = _pool_sized_results(text, ("fusion", "copy"))
+    assert not big, "\n".join(big[:4])
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < TEMP_LIMIT["decode_step"], temp
+
+
+def test_result_counter_sees_the_dense_branchs_fusions():
+    """The counter itself: the dense branch's multi-output fusion (PERF.md
+    section 5) counts, a weight-sized one and the kernel's call do not."""
+    text = "\n".join([
+        "  %multiply_reduce_fusion = (bf16[32,25,1024]{2,1,0}, "
+        "bf16[32,25,64,1024]{3,2,1,0}) fusion(%p.1, %p.2), kind=kLoop",
+        "  %fusion.7 = bf16[1600,4800]{1,0} fusion(%p.3), kind=kLoop",
+        "  %decode_attention.1 = (bf16[32,64,25]{2,1,0}, "
+        "bf16[32,25,64,1024]{3,2,1,0}, bf16[32,25,64,1024]{3,2,1,0}) "
+        "custom-call(%a, %b), custom_call_target=\"tpu_custom_call\""])
+    assert len(_pool_sized_results(text, ("fusion", "copy"))) == 1

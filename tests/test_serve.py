@@ -250,6 +250,62 @@ class TestSlotParity:
             engine.validate(4, 0)
 
 
+class TestDecodeAttnCounter:
+    """``stats()["decode_attn"]`` (ISSUE 26): the K/V time blocks the busy
+    slots held, by host arithmetic on the lengths at every decode
+    dispatch — what tpu_dist.ops.decode_attention reads on a TPU, and what
+    it WOULD read here, where the decode program is built dense."""
+
+    @pytest.fixture(scope="class")
+    def long_lm(self):
+        model = TransformerLM(vocab_size=97, dim=32, depth=1, num_heads=4,
+                              max_seq_len=512)
+        return model, model.init(jax.random.key(0))
+
+    @staticmethod
+    def _requests():
+        # lengths at the three decode dispatches: (5, 254), (6, 255),
+        # (7, 256); a slot reads ceil((len + 1) / 256) blocks of 256
+        rng = np.random.default_rng(0)
+        return [serve.Request(rng.integers(0, 97, n).astype(np.int32), 4)
+                for n in (5, 254)]
+
+    WANT = {"kv_blocks_read": 2 + 2 + 3, "kv_blocks_pool": 3 * 4 * 2,
+            "steps": 3, "block": 256, "kernel": False}
+
+    def test_counts_what_the_lengths_say_and_reset_zeroes(self, long_lm):
+        model, params = long_lm
+        engine = serve.SlotEngine(model, params, num_slots=4)
+        assert engine.stats()["decode_attn"] == {
+            "kv_blocks_read": 0, "kv_blocks_pool": 0, "steps": 0,
+            "block": 256, "kernel": False}
+        for r in self._requests():
+            engine.admit(r)
+        while not engine.idle():
+            engine.step()
+        assert engine.stats()["decode_attn"] == self.WANT
+        engine.reset_stats()
+        zero = engine.stats()["decode_attn"]
+        assert (zero["kv_blocks_read"], zero["kv_blocks_pool"],
+                zero["steps"]) == (0, 0, 0)
+
+    def test_is_on_the_wire_stats_frame(self, long_lm):
+        model, params = long_lm
+        engine = serve.SlotEngine(model, params, num_slots=4)
+        sched = serve.Scheduler(engine, batch_window=0.002)
+        fe = serve.Frontend(sched, port=0)
+        cli = serve.ServeClient("127.0.0.1", fe.port, connect_retry=10)
+        try:
+            cli.generate(list(range(1, 6)), max_new_tokens=4, timeout=120.0)
+            got = cli.stats()["decode_attn"]
+        finally:
+            cli.close()
+            fe.close()
+            sched.close()
+        assert got == {"kv_blocks_read": 3, "kv_blocks_pool": 3 * 4 * 2,
+                       "steps": 3, "block": 256, "kernel": False}
+
+
 class TestScheduler:
     def test_coalesced_admission_and_completion(self, lm):
         model, params = lm

@@ -4,7 +4,7 @@ SURVEY.md §1)."""
 from . import functional, init
 from .attention import (MultiheadSelfAttention, attention_impl,
                         cache_time_axis, cache_time_slice, rotary_embed,
-                        scaled_dot_product_attention)
+                        scaled_dot_product_attention, slot_decode_kernel)
 from .layers import (AdaptiveAvgPool2d, AvgPool2d, BatchNorm2d, Conv2d,
                      Dropout, Embedding, Flatten, GELU, Identity, LayerNorm,
                      Linear, MaxPool2d, ReLU, RMSNorm)
@@ -22,7 +22,7 @@ __all__ = [
     "Embedding", "LayerNorm", "RMSNorm", "GELU",
     "MultiheadSelfAttention", "scaled_dot_product_attention",
     "attention_impl", "MoELayer", "rotary_embed", "cache_time_axis",
-    "cache_time_slice",
+    "cache_time_slice", "slot_decode_kernel",
     "CrossEntropyLoss",
     "QuantEmbedding", "QuantLinear", "QuantMultiheadSelfAttention",
     "quantize_linear_weights",

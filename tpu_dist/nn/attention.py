@@ -27,7 +27,7 @@ from . import init as I
 
 __all__ = ["scaled_dot_product_attention", "MultiheadSelfAttention",
            "attention_impl", "rotary_embed", "cache_time_axis",
-           "cache_time_slice"]
+           "cache_time_slice", "slot_decode_kernel"]
 
 _IMPL_OVERRIDE: list = []
 
@@ -142,6 +142,23 @@ def cache_time_slice(leaf, lo, hi):
     idx = [slice(None)] * leaf.ndim
     idx[cache_time_axis(leaf)] = slice(lo, hi)
     return leaf[tuple(idx)]
+
+
+def slot_decode_kernel(pool) -> bool:
+    """Whether a slot-decode step (a vector ``index``, one new position a
+    slot) over the K/V pool leaf ``pool`` takes the Pallas kernel
+    (tpu_dist.ops.decode_attention) or the dense branch of
+    :meth:`MultiheadSelfAttention._decode`.  Chosen as
+    :func:`scaled_dot_product_attention` chooses flash: by what can be
+    observed — a float pool whose ``D`` fills whole sublane tiles and whose
+    ``Tmax`` fills whole lanes, on a TPU backend (interpreted, the kernel
+    would make every served token cost seconds) — with the trace-scoped
+    :func:`attention_impl` (``"flash"`` / ``"dense"``) as the override.
+    The engine asks here which branch its decode program was built on."""
+    from ..ops.decode_attention import decode_attention_ok
+    impl = (_IMPL_OVERRIDE[-1] if _IMPL_OVERRIDE
+            else "flash" if jax.default_backend() == "tpu" else "dense")
+    return impl == "flash" and decode_attention_ok(pool)
 
 
 def _write_columns(pool, new, index):
@@ -306,7 +323,16 @@ class MultiheadSelfAttention(Module):
         matmul — so the big cache tensors cross HBM as int8 and are
         converted in the MXU tile load, never materialized dequantized.
         Long-context decode reads the cache, not the weights; halving its
-        bytes halves the bandwidth bill where it dominates."""
+        bytes halves the bandwidth bill where it dominates.
+
+        A slot-decode step (``index`` a vector, ``t == 1``) over a float
+        pool takes ONE Pallas call where :func:`slot_decode_kernel` says so
+        (tpu_dist.ops.decode_attention: it reads only each slot's resident
+        blocks and writes only the block of the new column; a slot of
+        length 0 is free there: untouched, output zero).  Prefill, a
+        multi-token append, every CPU run and the int8 cache (its hoisted
+        scales would be a different kernel; no benchmark cell runs it) stay
+        on the dense branch below."""
         st = ctx.get_state(self._path)
         index = jnp.asarray(st["index"])
         t = q.shape[1]
@@ -318,6 +344,15 @@ class MultiheadSelfAttention(Module):
         # (B, t, ...) -> (B, ..., t): the stored order, time last
         new = {key: jnp.moveaxis(val, 1, -1).astype(st[key].dtype)
                for key, val in new.items()}
+        if index.ndim == 1 and t == 1 and slot_decode_kernel(st["k"]):
+            from ..ops.decode_attention import decode_attention
+            with jax.named_scope("attend"):
+                out, k_pool, v_pool = decode_attention(
+                    q[:, 0], new["k"][..., 0], new["v"][..., 0], st["k"],
+                    st["v"], index)
+            ctx.put_state(self._path, dict(st, k=k_pool, v=v_pool,
+                                           index=index + 1))
+            return out[:, None]
         # scopes: the cache writes and the masked attention over the
         # whole cache are told apart in a device trace
         with jax.named_scope("cache_update"):

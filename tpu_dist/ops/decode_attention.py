@@ -1,0 +1,301 @@
+"""Slot-decode attention — a Pallas TPU kernel over the resident K/V only.
+
+One decode step of continuous batching attends ONE new position per slot
+over a K/V pool stored ``(B, H, D, Tmax)``, time last
+(:meth:`tpu_dist.nn.MultiheadSelfAttention.init_cache`).  The dense form
+(``nn/attention.py`` ``_decode``) selects the new column into the whole
+pool and reduces over all of it, so every layer of every step reads and
+rewrites ``2 x B x H x D x Tmax`` elements whatever the slots hold.  This
+kernel makes the step's traffic follow the occupancy:
+
+- the slots' lengths arrive as scalar-prefetch operands and become a work
+  list, one entry per (busy slot, time block) with the blocks ``[0,
+  ceil((len + 1) / block))`` of that slot.  ONE kernel invocation loops
+  over the list, bounded by its length, and copies each entry's K and V
+  block HBM -> VMEM by hand through a ring of three buffers (two copies in
+  flight while one block is computed on): a free slot (length 0) and the
+  blocks past a slot's last cost no HBM read and no loop trip;
+- scores, the softmax statistics and the weighted values are accumulated
+  in float32, lane by lane (each of the 128 lanes keeps its own running
+  maximum, sum and weighted values; one cross-lane combine per slot), on
+  the VPU: with one query row per head there is nothing for the MXU (a
+  form that used it timed the same, PERF.md PR 26: the copies set the
+  pace);
+- the new K and V column is written IN PLACE through
+  ``input_output_aliases``: the only thing written back per slot and
+  tensor is the ``(H, D, 128)`` slab that holds column ``len``.  A column
+  at ``Tmax`` is dropped, as ``_write_columns`` drops it.
+
+A free slot is not visited: its pool row is untouched and its output row
+is zero.  Float pools only; the int8 cache's hoisted scales are a
+different kernel and stay on the dense branch.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+
+from ._pallas import (out_struct as _out_struct, sublane_tile,
+                      use_interpret as _use_interpret)
+
+__all__ = ["decode_attention", "decode_attention_ok", "kv_blocks"]
+
+_LANE = 128
+_NEG = -1e30   # finite: a lane that has seen no column yet stays NaN-free
+_RING = 3      # K/V block buffers: two copies in flight, one computed on
+# Time columns a block holds.  Timed on the chip (PERF.md, PR 26): 128
+# doubles the trips, 512 and 1024 read up to a quarter more of a row than
+# the slot holds.
+BLOCK_K = 256
+
+
+def _block_k(tmax: int) -> int:
+    return BLOCK_K if tmax % BLOCK_K == 0 else _LANE
+
+
+def decode_attention_ok(pool) -> bool:
+    """Whether the kernel takes this K/V pool leaf: a float one whose ``D``
+    fills whole sublane tiles and whose ``Tmax`` fills whole lanes."""
+    return (jnp.issubdtype(pool.dtype, jnp.floating)
+            and pool.shape[-2] % sublane_tile(pool.dtype) == 0
+            and pool.shape[-1] % _LANE == 0)
+
+
+def kv_blocks(lengths, tmax: int):
+    """``(blocks read, blocks in the pool, block)`` of one decode step:
+    a busy slot reads ``ceil((len + 1) / block)`` time blocks, clipped to
+    the row; a free one (length 0) none.  Host arithmetic on a numpy
+    vector — the engine's counter (``SlotEngine.stats()["decode_attn"]``)
+    and the kernel's own work list (:func:`_work_list`) count alike."""
+    tk = _block_k(tmax)
+    n = _blocks_per_slot(lengths, tmax, tk)
+    return int(n.sum()), len(lengths) * -(-tmax // tk), tk
+
+
+def _blocks_per_slot(lengths, tmax, tk):
+    """``ceil((len + 1) / tk)`` clipped to the row, 0 for a free slot;
+    numpy and jax vectors alike."""
+    return (lengths > 0) * ((lengths + tk) // tk).clip(max=-(-tmax // tk))
+
+
+def _work_list(lengths, tmax, tk):
+    """The scalar-prefetch vector and ``G = B * Tmax / tk``, the most
+    entries a list can hold: ``slot[G]`` and ``block[G]`` of each entry
+    (busy slots in order, a slot's blocks in order; entries past the
+    ``total`` are never read), then the ``B`` lengths, then ``total``."""
+    b = lengths.shape[0]
+    g = b * (tmax // tk)
+    n = _blocks_per_slot(lengths, tmax, tk).astype(jnp.int32)
+    ends = jnp.cumsum(n)
+    step = jnp.arange(g, dtype=jnp.int32)
+    # the slot whose blocks end past this entry (one compare, no search loop)
+    slot = jnp.minimum(jnp.sum(ends[None, :] <= step[:, None], axis=1,
+                               dtype=jnp.int32), b - 1)
+    blk = step - (ends - n)[slot]
+    return jnp.concatenate([slot, blk, lengths, ends[-1:]]), g
+
+
+def _kernel(s_ref, x_ref, k_hbm, v_hbm, o_ref, ko_hbm, vo_hbm,
+            kbuf, vbuf, wk, wv, rsem, wsem, xb_ref, m_ref, l_ref, acc_ref,
+            *, g, tk, tmax, scale):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    slots, _, d, heads = x_ref.shape
+    total = s_ref[2 * g + slots]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, _LANE), 2)
+    f32 = jnp.float32
+    o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)     # free slots' rows
+
+    def reads(i):
+        """The K and V copies of entry ``i`` into its ring buffer."""
+        buf = i % _RING
+        cols = pl.ds(pl.multiple_of(s_ref[g + i] * tk, tk), tk)
+        return [pltpu.make_async_copy(hbm.at[s_ref[i], :, :, cols],
+                                      ring.at[buf], rsem.at[j, buf])
+                for j, (hbm, ring) in enumerate(((k_hbm, kbuf),
+                                                 (v_hbm, vbuf)))]
+
+    def writes(slot, col):
+        """The copies of the new column's slabs back into the pools."""
+        cols = pl.ds(pl.multiple_of(col, _LANE), _LANE)
+        return [pltpu.make_async_copy(w, hbm.at[slot, :, :, cols],
+                                      wsem.at[j])
+                for j, (w, hbm) in enumerate(((wk, ko_hbm), (wv, vo_hbm)))]
+
+    for j in range(_RING - 1):
+        @pl.when(j < total)
+        def _():
+            for copy in reads(j):
+                copy.start()
+
+    def entry(i, writing):
+        slot, blk = s_ref[i], s_ref[g + i]
+        ln = s_ref[2 * g + slot]
+        for copy in reads(i):
+            # tpudlint: disable=TD004  # a DMA semaphore inside the kernel, no peer
+            copy.wait()
+
+        @pl.when(i + _RING - 1 < total)
+        def _():
+            for copy in reads(i + _RING - 1):
+                copy.start()
+
+        k_ref, v_ref = kbuf.at[i % _RING], vbuf.at[i % _RING]
+
+        @pl.when(blk == 0)
+        def _():
+            # a slot's first block: q and the new K/V column, each (D, H)
+            # with the heads in the lanes, spread to (H, D, 128)
+            # lane-replicated; the new column opens the softmax (lane 0)
+            x = x_ref[slot].astype(f32)
+            for h in range(heads):
+                for c in range(3):
+                    xb_ref[c, h] = jnp.broadcast_to(x[c][:, h:h + 1],
+                                                    (d, _LANE))
+            has_new = (ln < tmax) & (lane == 0)
+            s_new = jnp.sum(xb_ref[0] * xb_ref[1], axis=1,
+                            keepdims=True) * scale
+            m_ref[...] = jnp.where(has_new, s_new, _NEG)
+            l_ref[...] = jnp.broadcast_to(jnp.where(has_new, 1.0, 0.0),
+                                          l_ref.shape)
+            acc_ref[...] = jnp.where(has_new, xb_ref[2], 0.0)
+
+        @pl.when(blk * tk < ln)
+        def _():
+            q = xb_ref[0]
+            for c in range(tk // _LANE):
+                cols = slice(c * _LANE, (c + 1) * _LANE)
+                s = jnp.sum(q * k_ref[:, :, cols].astype(f32), axis=1,
+                            keepdims=True) * scale             # (H, 1, 128)
+                seen = blk * tk + c * _LANE + lane < ln
+                m_prev = m_ref[...]
+                m_new = jnp.maximum(m_prev, jnp.where(seen, s, _NEG))
+                alpha = jnp.exp(m_prev - m_new)
+                p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
+                m_ref[...] = m_new
+                l_ref[...] = alpha * l_ref[...] + p
+                acc_ref[...] = (alpha * acc_ref[...]
+                                + p * v_ref[:, :, cols].astype(f32))
+
+        # the slot's last block holds column ``len`` (a column at Tmax:
+        # none, nothing to write)
+        is_last = blk == jnp.minimum((ln + tk) // tk, tmax // tk) - 1
+        has_col = ln < tmax
+
+        @pl.when(is_last)
+        def _():
+            @pl.when(writing == 1)
+            def _():                      # the slabs are free again
+                for copy in writes(0, 0):
+                    # tpudlint: disable=TD004  # a DMA semaphore, no peer
+                    copy.wait()
+
+            @pl.when(has_col)
+            def _():
+                at = ln - blk * tk
+                cols = pl.ds(pl.multiple_of(at // _LANE * _LANE, _LANE),
+                             _LANE)
+                here = lane == at % _LANE
+                wk[...] = jnp.where(here, xb_ref[1], k_ref[:, :, cols].astype(
+                    f32)).astype(wk.dtype)
+                wv[...] = jnp.where(here, xb_ref[2], v_ref[:, :, cols].astype(
+                    f32)).astype(wv.dtype)
+                for copy in writes(slot, ln // _LANE * _LANE):
+                    copy.start()
+
+            # combine the 128 lanes' partial softmaxes
+            m = m_ref[...]
+            w = jnp.exp(m - jnp.max(m, axis=2, keepdims=True))
+            denom = jnp.sum(l_ref[...] * w, axis=2, keepdims=True)
+            o = jnp.sum(acc_ref[...] * w, axis=2, keepdims=True) / denom
+            head = jax.lax.broadcasted_iota(jnp.int32, (d, heads), 1)
+            out = jnp.zeros((d, heads), f32)
+            for h in range(heads):
+                out = jnp.where(head == h, o[h], out)          # (D, H)
+            o_ref[slot] = out.astype(o_ref.dtype)
+
+        return jnp.where(is_last, has_col.astype(jnp.int32), writing)
+
+    writing = jax.lax.fori_loop(0, total, entry, jnp.int32(0))
+
+    @pl.when(writing == 1)
+    def _():
+        for copy in writes(0, 0):
+            # tpudlint: disable=TD004  # a DMA semaphore, no peer
+            copy.wait()
+
+
+def decode_attention(q, k_new, v_new, k_pool, v_pool, lengths):
+    """One new position per slot against a time-last K/V pool.
+
+    ``q``, ``k_new``, ``v_new``: ``(B, H, D)``, this step's query and the
+    column to append; ``k_pool``, ``v_pool``: ``(B, H, D, Tmax)``;
+    ``lengths``: ``(B,)`` int, the positions resident in each slot = the
+    column the new one lands in.  Returns ``(out (B, H, D) in q.dtype,
+    k_pool, v_pool)`` with the column written (the pools are aliased to
+    the results: donate them).  Slot ``b`` attends columns ``<= len[b]``,
+    its own new one included, exactly as the dense branch masks; a slot of
+    length 0 is FREE (``TransformerLM.decode_step``'s convention): not
+    read, not written, output zero."""
+    return _call(q, k_new, v_new, k_pool, v_pool, lengths,
+                 interpret=_use_interpret())
+
+
+# jitted so that a model's layers share ONE trace and ONE Mosaic lowering:
+# unjitted, 48 layers cost 15 s of lowering at every process start
+@functools.partial(jax.jit, static_argnames="interpret")
+def _call(q, k_new, v_new, k_pool, v_pool, lengths, *, interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, heads, d, tmax = k_pool.shape
+    tk = _block_k(tmax)
+    scalars, g = _work_list(jnp.asarray(lengths, jnp.int32), tmax, tk)
+    # q and the new column as (B, 3, D, H): D in the sublanes as the pool
+    # has it, heads in the lanes
+    cdt = jnp.promote_types(q.dtype, k_pool.dtype)
+    x = jnp.stack([q.astype(cdt), k_new.astype(k_pool.dtype).astype(cdt),
+                   v_new.astype(v_pool.dtype).astype(cdt)], axis=1)
+    x = jnp.swapaxes(x, 2, 3)
+
+    def whole(*shape):
+        return pl.BlockSpec(shape, lambda i, s: (0,) * len(shape))
+
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    small = (heads, d, _LANE)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(1,),
+        in_specs=[whole(*x.shape), in_hbm, in_hbm],
+        out_specs=[whole(b, d, heads), in_hbm, in_hbm],
+        scratch_shapes=[pltpu.VMEM((_RING, heads, d, tk), k_pool.dtype),
+                        pltpu.VMEM((_RING, heads, d, tk), v_pool.dtype),
+                        pltpu.VMEM(small, k_pool.dtype),
+                        pltpu.VMEM(small, v_pool.dtype),
+                        pltpu.SemaphoreType.DMA((2, _RING)),
+                        pltpu.SemaphoreType.DMA((2,)),
+                        pltpu.VMEM((3,) + small, jnp.float32),
+                        pltpu.VMEM((heads, 1, _LANE), jnp.float32),
+                        pltpu.VMEM((heads, 1, _LANE), jnp.float32),
+                        pltpu.VMEM(small, jnp.float32)])
+    out, k_pool, v_pool = pl.pallas_call(
+        functools.partial(_kernel, g=g, tk=tk, tmax=tmax,
+                          scale=1.0 / math.sqrt(d)),
+        grid_spec=grid_spec,
+        out_shape=[_out_struct((b, d, heads), q.dtype, x, k_pool, v_pool),
+                   _out_struct(k_pool.shape, k_pool.dtype, x, k_pool, v_pool),
+                   _out_struct(v_pool.shape, v_pool.dtype, x, k_pool,
+                               v_pool)],
+        # operands count the scalar-prefetch vector: 2 and 3 are the pools
+        input_output_aliases={2: 1, 3: 2},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        interpret=interpret,
+        name="decode_attention",
+    )(scalars, x, k_pool, v_pool)
+    return jnp.swapaxes(out, 1, 2), k_pool, v_pool

@@ -41,7 +41,9 @@ from typing import Callable, List, Optional
 
 import numpy as np
 
+from ..nn.attention import slot_decode_kernel
 from ..obs.spans import phase_times, reset_phases, span
+from ..ops.decode_attention import kv_blocks
 from ..utils.metrics import LatencyHistogram
 
 __all__ = ["SlotEngine", "Request", "RequestHandle", "ServeError",
@@ -395,6 +397,14 @@ class SlotEngine:
         # reset_stats()
         self._moe: dict = {}
         self._moe_base: dict = {}
+        # decode attention: K/V time blocks the busy slots held, summed
+        # over decode iterations (host arithmetic on self.lengths), and
+        # whether a decode step over this pool takes the Pallas kernel,
+        # asked where MultiheadSelfAttention._decode asks
+        self._kv_blocks_read = 0
+        self._attn_kernel = all(slot_decode_kernel(entry["k"])
+                                for entry in self.cache.values()
+                                if "k" in entry)
 
         self._build_programs()
 
@@ -597,6 +607,7 @@ class SlotEngine:
         ids = {"step": self._iterations, "active": n_active}
         t0 = _now()
         with span("decode.dispatch", **ids):
+            self._kv_blocks_read += kv_blocks(self.lengths, self.max_len)[0]
             nxt_dev, self.cache = self._decode(
                 self.params, self.cache, self.tokens, self.lengths,
                 self.temps, self.keys, self.steps,
@@ -762,6 +773,7 @@ class SlotEngine:
         self.generated_tokens = 0
         self._occupied_slot_steps = 0
         self._decode_steps = 0
+        self._kv_blocks_read = 0
         reset_phases(SERVE_PHASES)
         # the device counters are never zeroed (a step in flight would
         # carry the old count on): stats() reports them past this reading
@@ -805,10 +817,27 @@ class SlotEngine:
                 "rows": total("rows"), "pad_rows": total("pad_rows"),
                 "calls": total("calls"), "by_phase": by_phase}
 
+    def _decode_attn_stats(self) -> dict:
+        """``stats()["decode_attn"]``: how far the decode step's K/V traffic
+        follows the occupancy.  ``kv_blocks_read``: time blocks of
+        ``block`` columns the busy slots held, ``ceil((len + 1) / block)``
+        each, summed over the ``steps`` decode iterations since
+        ``reset_stats()``; ``kv_blocks_pool``: the blocks of the whole
+        pool over the same iterations.  With ``kernel`` true the decode
+        program reads the former (tpu_dist.ops.decode_attention); with it
+        false, on the dense branch, it reads the latter and the former is
+        what the kernel WOULD read."""
+        _, per_step, block = kv_blocks(self.lengths, self.max_len)
+        return {"kv_blocks_read": int(self._kv_blocks_read),
+                "kv_blocks_pool": self._decode_steps * per_step,
+                "steps": self._decode_steps, "block": block,
+                "kernel": self._attn_kernel}
+
     def stats(self) -> dict:
         moe = self._moe_stats()
         return {
             **({"moe": moe} if moe else {}),
+            "decode_attn": self._decode_attn_stats(),
             "completed": self.completed,
             "generated_tokens": self.generated_tokens,
             "decode_steps": self._decode_steps,
