@@ -26,7 +26,8 @@ the dp×tp cell must cut wire ≥1.3× AND not lose steps/s; both land in
 2. host-vs-pjit parity — the eager tp=2 engine's logits are BITWISE
    equal to the compiled mesh program under the SAME rule table.
 
-``run()`` is the BENCH_EXTENDED ladder entry (benchmarks/run_all.py).
+``run()`` is the full recording: both cells and the ratio, into
+``BENCH_MESH.json``.
 """
 
 from __future__ import annotations
@@ -167,7 +168,7 @@ def run_cell(tp: int, steps: int = 5):
 
 
 def run():
-    """BENCH_EXTENDED ladder entry: both cells + the headline ratio."""
+    """Both cells + the headline ratio, written to BENCH_MESH.json."""
     pure = run_cell(tp=1)
     mesh = run_cell(tp=2)
     wire_ratio = pure["wire_bytes_per_step"] / \

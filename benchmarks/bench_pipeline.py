@@ -24,8 +24,7 @@ Output: one BENCH JSON row per cell to stdout + ``BENCH_PIPELINE.json``::
 ``--smoke`` is the tier-1 parity gate (tests/test_pipeline_host.py): one
 tiny cell per schedule plus the serial oracle, asserting GPipe == 1F1B
 == serial loss-bitwise AND 1F1B's stage-0 stash peak strictly below
-GPipe's; ``run()`` is the BENCH_EXTENDED ladder entry
-(benchmarks/run_all.py).
+GPipe's.
 """
 
 from __future__ import annotations
@@ -233,22 +232,6 @@ def _full_rows(steps: int, batch: int):
             rows.append(row)
             print(json.dumps(row), flush=True)
     return rows
-
-
-def run():
-    """BENCH_EXTENDED ladder entry: headline = best tokens/s across the
-    (schedule, M) grid, with the bubble table attached."""
-    rows = _full_rows(steps=4, batch=16)
-    best = max(rows, key=lambda r: r["value"])
-    return {"metric": "pipeline_host_tokens_per_sec",
-            "value": best["value"], "unit": "tokens/s",
-            "schedule": best["schedule"],
-            "microbatches": best["microbatches"],
-            "bubble_table": [
-                {k: r[k] for k in ("schedule", "microbatches",
-                                   "bubble_theoretical", "bubble_measured",
-                                   "value")}
-                for r in rows]}
 
 
 def main(argv=None) -> int:

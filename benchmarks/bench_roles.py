@@ -22,8 +22,7 @@ Output: one BENCH JSON row per cell to stdout + ``BENCH_ROLES.json``::
 
 ``--smoke`` runs two small cells per path with a payload-equality
 cross-check and no spawned graph — wired as a tier-1 gate
-(tests/test_roles.py); ``run()`` is the BENCH_EXTENDED ladder entry
-(benchmarks/run_all.py).
+(tests/test_roles.py).
 """
 
 from __future__ import annotations
@@ -163,22 +162,6 @@ def _bench_e2e(actors: int, steps: int):
             "value": round(learner["steps_per_sec"], 2),
             "unit": "steps/s",
             "dp_msgs": learner["traj_stats"]["dp_msgs"]}
-
-
-def run():
-    """BENCH_EXTENDED ladder entry (benchmarks/run_all.py): the channel
-    cells plus a small spawned e2e; headline = best dataplane MB/s."""
-    rows = _bench_channels(smoke=False)
-    rows.append(_bench_e2e(actors=2, steps=60))
-    best = next(r for r in rows
-                if r["metric"] == "roles_channel_dp_best_mb_s")
-    e2e = rows[-1]
-    out = {"metric": "roles_channel_dp_best_mb_s",
-           "value": best["value"], "unit": "MB/s",
-           "payload_bytes": best["payload_bytes"]}
-    if "value" in e2e:
-        out["actor_learner_steps_per_sec"] = e2e["value"]
-    return out
 
 
 def main(argv=None) -> int:

@@ -139,7 +139,7 @@ def main():
             args.image_size,
             dtype=jnp.float32 if args.no_bf16 else jnp.bfloat16)
     # prefetch 3: three staged batches keep a slow host-to-device link
-    # busy at negligible HBM cost (benchmarks/imagenet_e2e.py uses the same)
+    # busy at negligible HBM cost
     loader = DeviceLoader(
         DataLoader(ds, batch_size=world_batch // dist.get_num_processes(),
                    sampler=sampler, drop_last=True,

@@ -154,9 +154,9 @@ def test_time_is_the_last_axis_of_every_cache_leaf(dtype):
     if dtype == jnp.int8:
         assert entry["k_scale"].shape == entry["v_scale"].shape == (3, 4, 16)
     for leaf in entry.values():
-        assert leaf.shape[nn.cache_time_axis(leaf)] == 16
+        assert leaf.shape[nn.cache.time_axis(leaf)] == 16
         host = np.arange(leaf.size).reshape(leaf.shape)
-        cut = nn.cache_time_slice(host, 2, 7)
+        cut = nn.cache.time_slice(host, 2, 7)
         assert cut.shape == leaf.shape[:-1] + (5,)
         np.testing.assert_array_equal(cut, host[..., 2:7])
 
@@ -180,7 +180,7 @@ def test_olmoe_pool_program_compiles_with_its_grouped_matmuls(
         one_chip, no_compile_cache, mosaic_gmm, program):
     """OLMoE's block at the published widths (2048, 16 heads of 128, 64
     gated experts of 1024, 8 a token; one layer, vocabulary cut), 32 slots x
-    1024, with the routed-row counters merged into the pool: the chip's
+    1024, with the routed-row counters beside the pool: the chip's
     compiler takes the grouped-matmul kernels at 16 rows a block (decode: 4
     rows an expert) and at 128 (a 1024-token prefill), three calls a layer
     named by their routed rows, and copies neither the pool nor an expert
@@ -197,22 +197,24 @@ def test_olmoe_pool_program_compiles_with_its_grouped_matmuls(
                                        model.init(jax.random.key(0)))),
         one_chip)
     pool = _shapes(jax.eval_shape(
-        lambda: dict(model.init_slot_cache(SLOTS, MAX_LEN, jnp.bfloat16),
-                     **model.init_moe_counters())), one_chip)
+        lambda: model.init_slot_cache(SLOTS, MAX_LEN, jnp.bfloat16)),
+        one_chip)
+    counters = _shapes(jax.eval_shape(model.init_moe_counters), one_chip)
 
     def ints(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
 
     if program == "decode_step":
-        fn = jax.jit(lambda p, c, tok, lens: model.decode_step(p, tok, lens,
-                                                               c),
-                     donate_argnums=1)
-        compiled = fn.lower(params, pool, ints(SLOTS), ints(SLOTS)).compile()
+        fn = jax.jit(lambda p, c, n, tok, lens: model.decode_step(
+            p, tok, lens, c, n), donate_argnums=1)
+        compiled = fn.lower(params, pool, counters, ints(SLOTS),
+                            ints(SLOTS)).compile()
         routed = SLOTS * top_k
     else:
-        fn = jax.jit(lambda p, c, prompt, n, slot: model.prefill_into_slot(
-            p, prompt, n, slot, c), donate_argnums=1)
-        compiled = fn.lower(params, pool, ints(MAX_LEN), ints(),
+        fn = jax.jit(lambda p, c, n, prompt, length, slot:
+                     model.prefill_into_slot(p, prompt, length, slot, c, n),
+                     donate_argnums=1)
+        compiled = fn.lower(params, pool, counters, ints(MAX_LEN), ints(),
                             ints()).compile()
         routed = MAX_LEN * top_k
     text = compiled.as_text()

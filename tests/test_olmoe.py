@@ -101,8 +101,8 @@ def test_forward_logits_match_the_reference(program):
 
 
 def _pool(model, slots=4, max_len=128):
-    return dict(model.init_slot_cache(slots, max_len),
-                **model.init_moe_counters())
+    """The slot pool and, beside it, the routed-row counters."""
+    return model.init_slot_cache(slots, max_len), model.init_moe_counters()
 
 
 def _serve_one(model, params, prompt, n_new, slot, pool, bucket, others=None):
@@ -113,15 +113,15 @@ def _serve_one(model, params, prompt, n_new, slot, pool, bucket, others=None):
     padded[:len(prompt)] = prompt
     prefill = jax.jit(model.prefill_into_slot)
     decode = jax.jit(model.decode_step)
-    row, pool = prefill(params, padded, len(prompt), slot, pool)
+    row, *pool = prefill(params, padded, len(prompt), slot, *pool)
     rows, toks = [np.asarray(row)], [int(np.argmax(row))]
-    slots = next(v["k"].shape[0] for v in pool.values() if "k" in v)
+    slots = len(jax.tree.leaves(pool[0])[0])
     tokens, lengths = np.zeros(slots, np.int32), np.zeros(slots, np.int32)
     for s, (tok, length) in (others or {}).items():
         tokens[s], lengths[s] = tok, length
     tokens[slot], lengths[slot] = toks[0], len(prompt)
     for _ in range(n_new - 1):
-        logits, pool = decode(params, tokens, lengths, pool)
+        logits, *pool = decode(params, tokens, lengths, *pool)
         nxt = np.asarray(jnp.argmax(logits, -1))
         rows.append(np.asarray(logits[slot]))
         toks.append(int(nxt[slot]))
@@ -179,8 +179,8 @@ def test_logits_do_not_depend_on_the_bucket(program):
     np.testing.assert_allclose(a, b, rtol=0, atol=1e-6)
     # routed rows: 40 real x 2 experts; padding 24 x 2 and 88 x 2 (one
     # prefill), then 3 free slots x 2 in each of 3 decode steps
-    for pool, pad in ((pa, 24), (pb, 88)):
-        c = pool["block1.mlp"]
+    for (_, counters), pad in ((pa, 24), (pb, 88)):
+        c = counters["block1.mlp"]
         assert int(c["rows"].sum()) == 40 * 2 + 3 * 2
         assert int(c["pad_rows"]) == pad * 2 + 3 * 3 * 2
         assert int(c["calls"]) == 4

@@ -21,7 +21,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from tpu_dist import serve
+from tpu_dist import nn, serve
 from tpu_dist.models import TransformerLM
 
 pytestmark = pytest.mark.serve
@@ -106,7 +106,7 @@ class TestSlotParity:
         cache = model.init_slot_cache(2, 64)
         padded = np.zeros(16, np.int32)
         padded[:5] = prompt
-        logits, _ = model.prefill_into_slot(params, padded, 5, 1, cache)
+        logits, *_ = model.prefill_into_slot(params, padded, 5, 1, cache)
         ref_cache = model.init_cache(1, 64)
         ref_logits, _ = model.apply(params, jnp.asarray(prompt)[None, :],
                                     state=ref_cache)
@@ -138,19 +138,18 @@ class TestSlotParity:
         for slot, n in enumerate(lengths):
             padded = np.zeros(16, np.int32)
             padded[:n] = seqs[slot, :n]
-            logits, cache = model.prefill_into_slot(params, padded, n, slot,
-                                                    cache)
+            logits, cache, _ = model.prefill_into_slot(params, padded, n,
+                                                       slot, cache)
             close(logits, full[slot, n - 1], f"prefill into slot {slot}")
         for step in range(3):
-            logits, cache = model.decode_step(params, seqs[rows, lengths],
-                                              lengths, cache)
+            logits, cache, _ = model.decode_step(
+                params, seqs[rows, lengths], lengths, cache)
             close(logits, full[rows, lengths], f"slot decode step {step}")
             lengths = lengths + 1
         # t = 4 new tokens per slot, each slot at its own position
         t = 4
         toks = np.stack([seqs[b, n:n + t] for b, n in enumerate(lengths)])
-        state = {path: dict(entry, index=jnp.asarray(lengths))
-                 for path, entry in cache.items()}
+        state = nn.cache.call_state(cache, jnp.asarray(lengths))
         logits, state = model.apply(params, jnp.asarray(toks),
                                     pos_offset=jnp.asarray(lengths),
                                     state=state)
@@ -158,10 +157,9 @@ class TestSlotParity:
             close(logits[b], full[b, n:n + t], f"t={t} write, slot {b}")
         # ... and the next decode step attends over what that write stored
         lengths = lengths + t
-        cache = {path: {k: v for k, v in entry.items() if k != "index"}
-                 for path, entry in state.items()}
-        logits, _ = model.decode_step(params, seqs[rows, lengths], lengths,
-                                      cache)
+        cache, _ = nn.cache.split_state(state)
+        logits, *_ = model.decode_step(params, seqs[rows, lengths], lengths,
+                                       cache)
         close(logits, full[rows, lengths], "decode after the t>1 write")
 
     @pytest.mark.parametrize("cache_dtype", [None, jnp.bfloat16, jnp.int8],

@@ -61,7 +61,7 @@ def store():
 def _mk_rows(T, layers=2, heads=2, hd=4, seed=0):
     """A per-layer batch-1 KV row tree, float32 — the host-side shape
     ``TransformerLM.prefill_rows`` hands the transfer layer: time LAST
-    (``nn.cache_time_axis``)."""
+    (``nn.cache.time_axis``)."""
     rng = np.random.default_rng(seed)
     return {f"blocks/{j}": {k: rng.standard_normal(
         (1, heads, hd, T)).astype(np.float32) for k in ("k", "v")}

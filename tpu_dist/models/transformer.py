@@ -20,39 +20,7 @@ import jax.numpy as jnp
 from .. import nn
 from ..nn.module import current_context, run_capturing_state
 
-__all__ = ["TransformerLM", "TransformerBlock", "write_slot_rows"]
-
-
-def write_slot_rows(cache, rows, slot):
-    """Scatter ONE request's per-layer batch-1 cache rows into slot
-    ``slot`` of a slot-cache pool, leaving every other slot untouched —
-    the write half of :meth:`TransformerLM.prefill_into_slot`, factored
-    out so the disaggregated-serving path (tpu_dist/serve/disagg.py) can
-    land *transferred* KV rows in a decode rank's pool through the exact
-    same scatter the unified engine uses (the two paths cannot drift).
-
-    ``rows`` carries one ``{"k": (1, H, D, T), ...}`` entry per layer
-    path, time last like the pool's ``(B, H, D, Tmax)`` (``T <= Tmax``: a
-    bucket's worth of columns lands at column 0); only keys present in the
-    pool entry are written (a row's extra ``index`` is ignored).  The
-    update is on the slot axis alone, so it is in place in the donated
-    pool whatever the stored layout.  An entry of ``cache`` without K/V
-    (the routed-row counters, :meth:`TransformerLM.init_moe_counters`)
-    has no slots: it is taken whole from ``rows`` where they carry it."""
-    slot = jnp.asarray(slot, jnp.int32)
-    out = {}
-    with jax.named_scope("cache_write"):
-        for path, pool in cache.items():
-            if "k" not in pool:
-                out[path] = rows.get(path, pool)
-                continue
-            row = rows[path]
-            out[path] = {
-                k: jax.lax.dynamic_update_slice(
-                    pool[k], row[k].astype(pool[k].dtype),
-                    (slot,) + (0,) * (pool[k].ndim - 1))
-                for k in pool}
-    return out
+__all__ = ["TransformerLM", "TransformerBlock"]
 
 
 def _make_norm(norm: str, dim: int, eps: Optional[float] = None):
@@ -232,12 +200,28 @@ class TransformerLM(nn.Module):
 
     def init_cache(self, batch: int, max_len: Optional[int] = None,
                    dtype=jnp.float32):
-        """KV-cache state pytree for :meth:`generate` — one
-        ``{"k", "v", "index"}`` entry per attention layer, keyed by module
-        path, threaded through ``apply(state=...)`` like any mutable state.
-        ``k``/``v`` are ``(B, H, D, Tmax)``, time last: the layout the TPU
-        compiler keeps the pool in, written and read in place
-        (:meth:`nn.MultiheadSelfAttention.init_cache`)."""
+        """The state :meth:`generate` starts from, for a caller that drives
+        ``apply(state=...)`` itself: :meth:`init_slot_cache` addressed at
+        position 0 (``nn.cache.call_state``).  Every such call returns the
+        state advanced by its tokens, ready for the next."""
+        return nn.cache.call_state(
+            self.init_slot_cache(batch, max_len, dtype),
+            jnp.zeros((), jnp.int32))
+
+    # -- slot-pool decode (continuous batching; tpu_dist.serve) ------------
+
+    def init_slot_cache(self, slots: int, max_len: Optional[int] = None,
+                        dtype=jnp.float32):
+        """KV-cache pool for slot-based continuous-batching decode, in the
+        format nn/cache.py owns: per attention layer, keyed by module path,
+        what the layer keeps per slot (``k``/``v`` ``(slots, H, D,
+        max_len)``, time last: the layout the TPU compiler keeps the pool
+        in, written and read in place;
+        :meth:`nn.MultiheadSelfAttention.init_cache`).  No write position
+        is stored: each call to :meth:`decode_step` supplies every slot's
+        as the ``lengths`` vector, so the host-side engine
+        (:class:`tpu_dist.serve.SlotEngine`) holds the single source of
+        truth for slot occupancy."""
         if self.sequence_axis is not None:
             raise ValueError("KV-cache decode runs on gathered sequences; "
                              "build the model without sequence_axis for "
@@ -248,47 +232,25 @@ class TransformerLM(nn.Module):
                              "tokens and cannot be decoded incrementally")
         max_len = self.max_seq_len if max_len is None else max_len
         self._assign_paths()
-        return {attn._path: attn.init_cache(batch, max_len, dtype)
+        return {attn._path: attn.init_cache(slots, max_len, dtype)
                 for attn in (getattr(self, f"block{i}").attn
                              for i in range(self.depth))}
-
-    # -- slot-pool decode (continuous batching; tpu_dist.serve) ------------
-
-    def init_slot_cache(self, slots: int, max_len: Optional[int] = None,
-                        dtype=jnp.float32):
-        """KV-cache pool for slot-based continuous-batching decode: the
-        :meth:`init_cache` layout (``k``/``v`` ``(slots, H, D, max_len)``,
-        time last) WITHOUT the per-layer scalar write index
-        — each call to :meth:`decode_step` supplies every slot's position
-        as the ``lengths`` vector instead, so the host-side engine
-        (:class:`tpu_dist.serve.SlotEngine`) holds the single source of
-        truth for slot occupancy."""
-        return {path: {k: v for k, v in entry.items() if k != "index"}
-                for path, entry in
-                self.init_cache(slots, max_len, dtype).items()}
 
     def init_moe_counters(self):
         """Routed-row counters for serving a model with expert layers, one
         entry per :class:`~tpu_dist.nn.MoELayer` keyed by its path (empty
-        for a dense model): ``rows`` (E,) routed rows per expert that
-        belong to a request, ``pad_rows`` routed rows that belong to none
-        (free slots in :meth:`decode_step`, bucket padding in
-        :meth:`prefill_into_slot`), ``calls`` of the layer, and
-        ``experts_hit``, the experts with a request's row summed over
-        calls.  Merged into the ``cache`` given to those two methods, the
-        entries come back in the returned cache with the call's rows added
-        — on the device, nothing is read back (tpu_dist.serve.SlotEngine
-        keeps them beside its pool).  int32: a reader takes differences
-        modulo 2**32."""
+        for a dense model; :meth:`nn.MoELayer.init_counters` names the
+        leaves).  Given to :meth:`decode_step` / :meth:`prefill_into_slot`
+        BESIDE the cache, they come back with the call's rows added — on
+        the device, nothing is read back (tpu_dist.serve.SlotEngine keeps a
+        set per pool program)."""
         self._assign_paths()
-        z = lambda *shape: jnp.zeros(shape, jnp.int32)
-        return {mlp._path: {"rows": z(mlp.num_experts), "pad_rows": z(),
-                            "calls": z(), "experts_hit": z()}
+        return {mlp._path: mlp.init_counters()
                 for mlp in (getattr(self, f"block{i}").mlp
                             for i in range(self.depth))
                 if isinstance(mlp, nn.MoELayer)}
 
-    def decode_step(self, params, tokens, lengths, cache):
+    def decode_step(self, params, tokens, lengths, cache, counters=None):
         """ONE decode iteration over a slot pool: feed each slot's current
         last token, get each slot's next-token logits.
 
@@ -296,95 +258,81 @@ class TransformerLM(nn.Module):
         prompt's last token right after prefill).  ``lengths``: (B,) int —
         tokens already resident in each slot's cache row, i.e. the write
         position.  ``cache``: from :meth:`init_slot_cache` /
-        :meth:`prefill_into_slot`.  Returns ``(logits (B, vocab),
-        new_cache)``.  Free slots decode garbage rows the caller masks;
-        their cache writes land in rows the next prefill overwrites.
-        The math per row is exactly :meth:`generate`'s decode scan — the
-        scan *uses* this method — so slot decode and offline generation
-        cannot drift.  Counter entries in ``cache``
-        (:meth:`init_moe_counters`) take a slot of length 0 as free."""
+        :meth:`prefill_into_slot`.  ``counters``: from
+        :meth:`init_moe_counters`, or None; they take a slot of length 0 as
+        free.  Returns ``(logits (B, vocab), new_cache, new_counters)``.
+        Free slots decode garbage rows the caller masks; their cache writes
+        land in rows the next prefill overwrites.  The math per row is
+        exactly :meth:`generate`'s decode scan — the scan *uses* this
+        method — so slot decode and offline generation cannot drift."""
         lengths = jnp.asarray(lengths, jnp.int32)
-        # K/V entries get their write index, counter entries the mask of
-        # rows that are a request's
-        valid = (lengths > 0)[:, None]
-        state = {path: (dict(entry, index=lengths) if "k" in entry
-                        else dict(entry, valid=valid))
-                 for path, entry in cache.items()}
+        state = nn.cache.call_state(cache, lengths, counters,
+                                    valid=(lengths > 0)[:, None])
         tokens = jnp.asarray(tokens)[:, None]
         logits, state = self.apply(params, tokens, pos_offset=lengths,
                                    state=state)
-        new_cache = {path: {k: v for k, v in state[path].items()
-                            if k not in ("index", "valid")}
-                     for path in cache}
-        return logits[:, -1], new_cache
+        return (logits[:, -1], *nn.cache.split_state(state, counters))
 
-    def prefill_into_slot(self, params, prompt, length, slot, cache):
+    def prefill_into_slot(self, params, prompt, length, slot, cache,
+                          counters=None):
         """Prefill ONE request into slot ``slot`` of a slot-cache pool
         while other slots' rows are untouched — the admission half of
-        continuous batching.
+        continuous batching: :meth:`prefill_rows` at the pool's own extent,
+        then ``nn.cache.write_slot_rows``.
 
         ``prompt``: (P,) int tokens, padded past ``length`` with any valid
         token id (padding K/V lands at positions ``>= length``, which
         every later decode step either masks out or overwrites before
         attending).  ``length``: true token count (traced OK).  Returns
-        ``(last-real-token logits (vocab,), new_cache)`` — sample the
-        request's first generated token from those logits.  One padded
-        prompt length = one compiled program; bucket prompt lengths to
-        bound retraces."""
-        k = next(e["k"] for e in cache.values() if "k" in e)
-        prompt = jnp.asarray(prompt)
-        pre = self.init_cache(1, k.shape[nn.cache_time_axis(k)], k.dtype)
-        # counter entries ride along: the rows past ``length`` are padding
-        valid = (jnp.arange(prompt.shape[0]) < length)[None, :]
-        pre.update({p: dict(e, valid=valid) for p, e in cache.items()
-                    if "k" not in e})
-        logits, st = self.apply(params, prompt[None, :], state=pre)
-        st = {p: {k: v for k, v in e.items() if k != "valid"}
-              for p, e in st.items()}
-        new_cache = write_slot_rows(cache, st, slot)
-        return jax.lax.dynamic_index_in_dim(
-            logits[0], jnp.asarray(length, jnp.int32) - 1, axis=0,
-            keepdims=False), new_cache
+        ``(last-real-token logits (vocab,), new_cache, new_counters)`` —
+        sample the request's first generated token from those logits.  One
+        padded prompt length = one compiled program; bucket prompt lengths
+        to bound retraces."""
+        logits, rows, counters = self.prefill_rows(
+            params, prompt, length, *nn.cache.extent(cache),
+            counters=counters)
+        return logits, nn.cache.write_slot_rows(cache, rows, slot), counters
 
     def prefill_rows(self, params, prompt, length, max_len,
-                     dtype=jnp.float32, prefix_rows=None, prefix_len=0):
+                     dtype=jnp.float32, prefix_rows=None, prefix_len=0,
+                     counters=None):
         """Prefill ONE request into fresh batch-1 cache rows with NO slot
-        pool in sight — the disaggregated-prefill primitive: a prefill
-        rank computes these rows and ships them to a decode rank, where
-        :func:`write_slot_rows` lands them in a free slot.
+        pool in sight — the forward of :meth:`prefill_into_slot`, and the
+        disaggregated-prefill primitive: a prefill rank computes these rows
+        and ships them to a decode rank, where ``nn.cache.write_slot_rows``
+        lands them in a free slot.
 
         ``prompt``: (S,) int suffix tokens, padded past the true suffix
-        length with any valid id (padding K/V lands at positions
-        ``>= length`` and is masked/overwritten exactly as in
-        :meth:`prefill_into_slot`).  ``length``: TOTAL true token count
+        length with any valid id.  ``length``: TOTAL true token count
         including any cached prefix.  With ``prefix_rows`` (batch-1 rows
         holding the first ``prefix_len`` tokens' K/V — a prefix-cache
         hit), only the suffix runs the forward: positions start at
         ``prefix_len`` (learned table via ``pos_offset``, rope via the
         cache write index) and the suffix K/V appends at
-        ``[prefix_len, prefix_len + S)``.  Returns ``(last-real-token
-        logits (vocab,), rows)`` where ``rows`` are full-width per-layer
-        entries (no ``index``), ``k``/``v`` of shape ``(1, H, D, max_len)``
-        — the pool's own order, time last, so :func:`write_slot_rows`
-        lands them without a transpose.  With no
-        prefix this is bitwise-identical to the forward inside
-        :meth:`prefill_into_slot` (same apply, same padding discipline);
-        one padded suffix length = one compiled program."""
-        length = jnp.asarray(length, jnp.int32)
-        plen = jnp.asarray(prefix_len, jnp.int32)
+        ``[prefix_len, prefix_len + S)``.  ``counters``
+        (:meth:`init_moe_counters`) take the rows past the true length as
+        padding.  Returns ``(last-real-token logits (vocab,), rows,
+        new_counters)`` where ``rows`` are full-width per-layer entries,
+        ``k``/``v`` of shape ``(1, H, D, max_len)`` — the pool's own order,
+        time last, so the slot write lands them without a transpose.  One
+        padded suffix length = one compiled program."""
+        prompt = jnp.asarray(prompt)
+        real = jnp.asarray(length, jnp.int32)   # the prompt's true tokens
         if prefix_rows is None:
-            pre = self.init_cache(1, max_len, dtype)
-            logits, st = self.apply(params, jnp.asarray(prompt)[None, :],
-                                    state=pre)
+            rows = self.init_slot_cache(1, max_len, dtype)
+            start, offset = jnp.zeros((), jnp.int32), None
         else:
-            pre = {path: dict(entry, index=plen)
-                   for path, entry in prefix_rows.items()}
-            logits, st = self.apply(params, jnp.asarray(prompt)[None, :],
-                                    pos_offset=plen, state=pre)
-        rows = {path: {k: v for k, v in st[path].items() if k != "index"}
-                for path in st}
-        return jax.lax.dynamic_index_in_dim(
-            logits[0], length - plen - 1, axis=0, keepdims=False), rows
+            rows = prefix_rows
+            start = offset = jnp.asarray(prefix_len, jnp.int32)
+            real = real - start
+        state = nn.cache.call_state(
+            rows, start, counters,
+            valid=(jnp.arange(prompt.shape[0]) < real)[None, :])
+        logits, state = self.apply(params, prompt[None, :],
+                                   pos_offset=offset, state=state)
+        return (jax.lax.dynamic_index_in_dim(logits[0], real - 1, axis=0,
+                                             keepdims=False),
+                *nn.cache.split_state(state, counters))
 
     def generate(self, params, prompt, max_new_tokens: int,
                  temperature: float = 0.0, rng=None, cache_dtype=None,
@@ -441,20 +389,20 @@ class TransformerLM(nn.Module):
                 logits = jnp.where(logits < thresh, -jnp.inf, logits)
             return jax.random.categorical(key, logits, axis=-1)
 
-        cache = self.init_cache(b, total, cache_dtype or jnp.float32)
-        logits, cache = self.apply(params, prompt, state=cache)
+        logits, state = self.apply(
+            params, prompt,
+            state=self.init_cache(b, total, cache_dtype or jnp.float32))
         key0 = rng if rng is not None else jax.random.key(0)
         first = sample(logits[:, -1], jax.random.fold_in(key0, 0))
         # the decode loop runs on the slot-pool primitive (decode_step):
         # lengths = tp + i for every row, so offline generation and the
         # serving engine's continuous-batching decode share ONE code path
-        slot_cache = {path: {k: v for k, v in entry.items() if k != "index"}
-                      for path, entry in cache.items()}
+        slot_cache, _ = nn.cache.split_state(state)
 
         def step(carry, i):
             tok, cache = carry
             lengths = jnp.full((b,), tp, jnp.int32) + i
-            logits, cache = self.decode_step(params, tok, lengths, cache)
+            logits, cache, _ = self.decode_step(params, tok, lengths, cache)
             nxt = sample(logits, jax.random.fold_in(key0, i + 1))
             return (nxt, cache), tok
 

@@ -1,9 +1,8 @@
 """tpu_dist.nn — functional module system + layers (L2 of the layer map,
 SURVEY.md §1)."""
 
-from . import functional, init
-from .attention import (MultiheadSelfAttention, attention_impl,
-                        cache_time_axis, cache_time_slice, rotary_embed,
+from . import cache, functional, init
+from .attention import (MultiheadSelfAttention, attention_impl, rotary_embed,
                         scaled_dot_product_attention, slot_decode_kernel)
 from .layers import (AdaptiveAvgPool2d, AvgPool2d, BatchNorm2d, Conv2d,
                      Dropout, Embedding, Flatten, GELU, Identity, LayerNorm,
@@ -16,13 +15,12 @@ from .quant import (QuantEmbedding, QuantLinear,
 
 __all__ = [
     "Module", "Remat", "Sequential", "run_capturing_state",
-    "functional", "init",
+    "cache", "functional", "init",
     "Linear", "Conv2d", "MaxPool2d", "AvgPool2d", "AdaptiveAvgPool2d",
     "ReLU", "Flatten", "Dropout", "BatchNorm2d", "Identity",
     "Embedding", "LayerNorm", "RMSNorm", "GELU",
     "MultiheadSelfAttention", "scaled_dot_product_attention",
-    "attention_impl", "MoELayer", "rotary_embed", "cache_time_axis",
-    "cache_time_slice", "slot_decode_kernel",
+    "attention_impl", "MoELayer", "rotary_embed", "slot_decode_kernel",
     "CrossEntropyLoss",
     "QuantEmbedding", "QuantLinear", "QuantMultiheadSelfAttention",
     "quantize_linear_weights",

@@ -26,8 +26,7 @@ from .module import Module
 from . import init as I
 
 __all__ = ["scaled_dot_product_attention", "MultiheadSelfAttention",
-           "attention_impl", "rotary_embed", "cache_time_axis",
-           "cache_time_slice", "slot_decode_kernel"]
+           "attention_impl", "rotary_embed", "slot_decode_kernel"]
 
 _IMPL_OVERRIDE: list = []
 
@@ -127,26 +126,9 @@ def rotary_embed(x, positions, theta: float = 10000.0):
     return out.astype(x.dtype)
 
 
-def cache_time_axis(leaf) -> int:
-    """The time axis of a K/V cache leaf — the LAST one, for ``k``/``v``
-    ``(B, H, D, Tmax)`` and the int8 scales ``(B, H, Tmax)`` alike
-    (:meth:`MultiheadSelfAttention.init_cache` says why).  Host code that
-    cuts, joins or pads cache rows by position (serve/prefix, kvtransfer,
-    disagg) asks here instead of assuming an axis."""
-    return leaf.ndim - 1
-
-
-def cache_time_slice(leaf, lo, hi):
-    """Columns ``[lo, hi)`` of a cache leaf along its time axis (a view,
-    clipped to the leaf's extent like any slice)."""
-    idx = [slice(None)] * leaf.ndim
-    idx[cache_time_axis(leaf)] = slice(lo, hi)
-    return leaf[tuple(idx)]
-
-
-def slot_decode_kernel(pool) -> bool:
+def slot_decode_kernel(entry) -> bool:
     """Whether a slot-decode step (a vector ``index``, one new position a
-    slot) over the K/V pool leaf ``pool`` takes the Pallas kernel
+    slot) over one layer's pool ``entry`` takes the Pallas kernel
     (tpu_dist.ops.decode_attention) or the dense branch of
     :meth:`MultiheadSelfAttention._decode`.  Chosen as
     :func:`scaled_dot_product_attention` chooses flash: by what can be
@@ -158,7 +140,7 @@ def slot_decode_kernel(pool) -> bool:
     from ..ops.decode_attention import decode_attention_ok
     impl = (_IMPL_OVERRIDE[-1] if _IMPL_OVERRIDE
             else "flash" if jax.default_backend() == "tpu" else "dense")
-    return impl == "flash" and decode_attention_ok(pool)
+    return impl == "flash" and decode_attention_ok(entry["k"])
 
 
 def _write_columns(pool, new, index):
@@ -310,8 +292,9 @@ class MultiheadSelfAttention(Module):
     def _decode(self, ctx, q, k, v):
         """Cached attention step.  q/k/v: (B, t, H, D) with t the number of
         new positions (t>1 = prefill, t=1 = one decode step).  The cache is
-        state ``{"k": (B, H, D, Tmax), "v": ..., "index": ()}`` — time is
-        the LAST axis of every leaf (see :meth:`init_cache` for why); new
+        this layer's entry of the call's state (nn/cache.py): the resident
+        ``{"k": (B, H, D, Tmax), "v": ...}`` plus the write ``index`` — time
+        is the LAST axis of every leaf (see :meth:`init_cache` for why); new
         keys land at columns [index, index+t) and queries see cache
         positions <= their own global position (cache columns past the
         index are masked, so the zeros there never contribute).
@@ -344,7 +327,7 @@ class MultiheadSelfAttention(Module):
         # (B, t, ...) -> (B, ..., t): the stored order, time last
         new = {key: jnp.moveaxis(val, 1, -1).astype(st[key].dtype)
                for key, val in new.items()}
-        if index.ndim == 1 and t == 1 and slot_decode_kernel(st["k"]):
+        if index.ndim == 1 and t == 1 and slot_decode_kernel(st):
             from ..ops.decode_attention import decode_attention
             with jax.named_scope("attend"):
                 out, k_pool, v_pool = decode_attention(
@@ -399,8 +382,9 @@ class MultiheadSelfAttention(Module):
                               preferred_element_type=acc).astype(q.dtype)
 
     def init_cache(self, batch: int, max_len: int, dtype=jnp.float32):
-        """Per-layer KV cache entry (used via TransformerLM.init_cache):
-        ``k``, ``v`` of shape ``(B, H, D, Tmax)``, time LAST.  That is the
+        """What this layer keeps per slot (one entry of a nn/cache.py tree,
+        via TransformerLM.init_slot_cache): ``k``, ``v`` of shape
+        ``(B, H, D, Tmax)``, time LAST.  That is the
         layout the TPU compiler keeps such a pool in at rest whatever its
         logical shape (``Tmax`` in the 128 lanes, ``D`` in the sublanes:
         unpadded for bf16 whenever ``D % 16 == 0`` and ``Tmax % 128 == 0``,
@@ -411,8 +395,7 @@ class MultiheadSelfAttention(Module):
         float32 per-(token, head) scales ``(B, H, Tmax)`` (see
         :meth:`_decode`)."""
         shape = (batch, self.num_heads, self.head_dim, max_len)
-        cache = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),
-                 "index": jnp.zeros((), jnp.int32)}
+        cache = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
         if jnp.dtype(dtype) == jnp.int8:
             cache["k_scale"] = jnp.zeros((batch, self.num_heads, max_len),
                                          jnp.float32)

@@ -362,15 +362,27 @@ class MoELayer(Module):
             return linear(jax.nn.gelu(linear(xs, p["w1"], p["b1"])),
                           p["w2"], p["b2"])
 
+    def init_counters(self):
+        """Routed-row counters for serving, this layer's entry of the
+        counter tree (``TransformerLM.init_moe_counters``): ``rows`` (E,)
+        routed rows per expert that belong to a request, ``pad_rows`` routed
+        rows that belong to none (free slots in a decode step, bucket padding
+        in a prefill), ``calls`` of the layer, and ``experts_hit``, the
+        experts with a request's row summed over calls.  int32: a reader
+        takes differences modulo 2**32."""
+        z = lambda *shape: jnp.zeros(shape, jnp.int32)
+        return {"rows": z(self.num_experts), "pad_rows": z(), "calls": z(),
+                "experts_hit": z()}
+
     def _count_rows(self, ctx, oh_i) -> bool:
-        """Serving counters (``TransformerLM.init_moe_counters``): when
-        this layer's state entry carries them, add this call's routed rows
-        per expert to it, on the device.  ``valid`` (the rows that belong
-        to a request; the entry's, set by ``decode_step`` /
-        ``prefill_into_slot``) keeps the rows of free slots and of bucket
-        padding apart: they are routed and cost work, but are nobody's.
-        True when counted (the training-time aux loss is then not
-        published: the entry holds counters and nothing else)."""
+        """When this layer's state entry carries the counters
+        (:meth:`init_counters`), add this call's routed rows per expert to
+        it, on the device.  ``valid`` (the rows that belong to a request;
+        put into the entry by ``nn.cache.call_state``) keeps the rows of
+        free slots and of bucket padding apart: they are routed and cost
+        work, but are nobody's.  True when counted (the training-time aux
+        loss is then not published: the entry holds counters and nothing
+        else)."""
         st = ctx.state.get(self._path) if ctx.state else None
         if st is None or "rows" not in st:
             return False

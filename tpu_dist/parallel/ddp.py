@@ -533,7 +533,7 @@ class DistributedDataParallel:
         Like :meth:`train_chunk` but the batch is scan-invariant, so no
         ``(k, batch, ...)`` input is materialized — the per-step rng still
         advances (the step counter seeds dropout keys).  Uses: throughput
-        measurement (benchmarks/timing.py) and overfit-one-batch debugging.
+        measurement and overfit-one-batch debugging.
         Returns ``(new_state, metrics)`` with per-step ``(k,)`` leaves.
         """
         if self.optimizer is None or self.loss_fn is None:

@@ -51,7 +51,7 @@ from typing import Callable, Dict, Optional
 
 import numpy as np
 
-from ..nn.attention import cache_time_axis
+from ..nn import cache as kvcache
 from .engine import Request, ServeError, SlotEngine, sample_tokens
 from .kvtransfer import KVTransfer, KVTransferError
 from .scheduler import Scheduler
@@ -73,20 +73,6 @@ def kv_channel(decode_role_rank: int) -> str:
 
 def _now() -> float:
     return time.perf_counter()
-
-
-def _pad_time(rows, total: int):
-    """Host cache rows ``{path: {key: leaf}}`` zero-padded along each
-    leaf's time axis to ``total`` columns (a bucket, or the whole
-    ``max_len``): the fixed shape one compiled program takes."""
-    def pad(arr):
-        width = [(0, 0)] * arr.ndim
-        ax = cache_time_axis(arr)
-        width[ax] = (0, total - arr.shape[ax])
-        return np.pad(arr, width)
-
-    return {path: {k: pad(arr) for k, arr in entry.items()}
-            for path, entry in rows.items()}
 
 
 def kv_timeout_default() -> float:
@@ -166,7 +152,7 @@ class DisaggSlotEngine(SlotEngine):
         # int8 slot caches work end-to-end: the prefill worker runs its
         # forward with the same cache dtype, so the transferred rows
         # carry the int8 k/v AND their f32 per-(token, head) scales as
-        # ordinary fragments (kv_template lists every non-index key) —
+        # ordinary fragments (kv_template lists every resident leaf) —
         # staging pads and write_slot_rows scatters them like any other
         # row.  Both endpoints must agree on the dtype (the template's
         # geometry check names a mismatch).
@@ -206,11 +192,8 @@ class DisaggSlotEngine(SlotEngine):
 
     def _build_inject(self) -> None:
         import jax
-        from ..models.transformer import write_slot_rows
 
-        self._inject = jax.jit(
-            lambda cache, rows, slot: write_slot_rows(cache, rows, slot),
-            donate_argnums=(0,))
+        self._inject = jax.jit(kvcache.write_slot_rows, donate_argnums=(0,))
 
     @property
     def fatal_error(self):
@@ -360,7 +343,7 @@ class DisaggSlotEngine(SlotEngine):
                 f"{len(req.prompt)} — descriptor/transfer drift")
         bucket = self.bucket_for(arrival["length"])
         arrival["rows"] = jax.device_put(
-            _pad_time(arrival["rows"], bucket))
+            kvcache.pad_time(arrival["rows"], bucket))
         req.staged = arrival
         return req.staged
 
@@ -539,18 +522,18 @@ class PrefillWorker:
         dtype_ = self.dtype
 
         def _pf_fn(params, prompt, length, temp, key, sampling):
-            row, rows = model_.prefill_rows(params, prompt, length,
-                                            max_len_, dtype=dtype_)
+            row, rows, _ = model_.prefill_rows(params, prompt, length,
+                                               max_len_, dtype=dtype_)
             tok = sample_tokens(row[None], temp[None], key[None],
                                 jnp.zeros((1,), jnp.int32), sampling)
             return tok[0], rows
 
         def _pf_pre_fn(params, prompt, length, pre, plen, temp, key,
                        sampling):
-            row, rows = model_.prefill_rows(params, prompt, length,
-                                            max_len_, dtype=dtype_,
-                                            prefix_rows=pre,
-                                            prefix_len=plen)
+            row, rows, _ = model_.prefill_rows(params, prompt, length,
+                                               max_len_, dtype=dtype_,
+                                               prefix_rows=pre,
+                                               prefix_len=plen)
             tok = sample_tokens(row[None], temp[None], key[None],
                                 jnp.zeros((1,), jnp.int32), sampling)
             return tok[0], rows
@@ -587,9 +570,10 @@ class PrefillWorker:
             sb = self._bucket_for(L - hit, self.max_len - hit)
             padded = np.zeros(sb, np.int32)
             padded[:L - hit] = tokens[hit:]
-            tok_dev, rows = self._pf_pre(self.params, padded, np.int32(L),
-                                         _pad_time(pre_rows, self.max_len),
-                                         np.int32(hit), temp, key, sampling)
+            tok_dev, rows = self._pf_pre(
+                self.params, padded, np.int32(L),
+                kvcache.pad_time(pre_rows, self.max_len), np.int32(hit),
+                temp, key, sampling)
         else:
             b = self._bucket_for(L, self.max_len)
             padded = np.zeros(b, np.int32)

@@ -321,26 +321,6 @@ def _bucket_lengths(max_prompt: int, min_bucket: int = 16) -> List[int]:
     return out
 
 
-class _CountedProgram:
-    """A jitted pool program ``f(params, cache, counters, *args) -> (out,
-    cache, counters)`` called as ``f(params, cache, *args) -> (out, cache)``:
-    the counter set ``sets[phase]`` is threaded through every call and
-    replaced by what comes back.  ``lower`` takes the same short signature
-    (tests and scripts inspect the program through it)."""
-
-    def __init__(self, program, sets: dict, phase: str):
-        self.program, self.sets, self.phase = program, sets, phase
-
-    def __call__(self, params, cache, *args):
-        out, cache, self.sets[self.phase] = self.program(
-            params, cache, self.sets[self.phase], *args)
-        return out, cache
-
-    def lower(self, params, cache, *args):
-        return self.program.lower(params, cache, self.sets[self.phase],
-                                  *args)
-
-
 class SlotEngine:
     """Fixed pool of ``num_slots`` KV-cache slots with per-slot lengths.
 
@@ -395,16 +375,15 @@ class SlotEngine:
         # routed-row counters per pool program (_build_programs fills them
         # for a model with expert layers) and their reading at the last
         # reset_stats()
-        self._moe: dict = {}
+        self._moe: dict = {"prefill": {}, "decode": {}}
         self._moe_base: dict = {}
         # decode attention: K/V time blocks the busy slots held, summed
         # over decode iterations (host arithmetic on self.lengths), and
         # whether a decode step over this pool takes the Pallas kernel,
         # asked where MultiheadSelfAttention._decode asks
         self._kv_blocks_read = 0
-        self._attn_kernel = all(slot_decode_kernel(entry["k"])
-                                for entry in self.cache.values()
-                                if "k" in entry)
+        self._attn_kernel = all(map(slot_decode_kernel,
+                                    self.cache.values()))
 
         self._build_programs()
 
@@ -421,52 +400,40 @@ class SlotEngine:
         model = self.model
         # routed-row counters of a model with expert layers ({} without):
         # one set per pool program, on the device, beside the pool.  Each
-        # program takes its set merged into the cache it hands the model
-        # and returns it with the call's rows added; nothing is read back
-        # until stats().  NOT donated: stats() and reset_stats() may read
-        # a set from another thread while the loop thread steps.
+        # program takes its set next to the cache and returns it with the
+        # call's rows added; nothing is read back until stats().  NOT
+        # donated: stats() and reset_stats() may read a set from another
+        # thread while the loop thread steps.
         fresh = getattr(model, "init_moe_counters", dict)
         self._moe = {"prefill": fresh(), "decode": fresh()}
-
-        def split(merged, moe):
-            return ({p: e for p, e in merged.items() if p not in moe},
-                    {p: merged[p] for p in moe})
 
         def _decode_fn(params, cache, moe, tokens, lengths, temps, keys,
                        steps, sampling):
             with jax.named_scope("decode"):
-                logits, merged = model.decode_step(params, tokens, lengths,
-                                                   dict(cache, **moe))
+                logits, cache, moe = model.decode_step(params, tokens,
+                                                       lengths, cache, moe)
             with jax.named_scope("sample"):
                 return (sample_tokens(logits, temps, keys, steps, sampling),
-                        *split(merged, moe))
+                        cache, moe)
 
         def _prefill_fn(params, cache, moe, prompt, length, slot, temp, key,
                         sampling):
             with jax.named_scope("prefill"):
-                logits, merged = model.prefill_into_slot(
-                    params, prompt, length, slot, dict(cache, **moe))
+                logits, cache, moe = model.prefill_into_slot(
+                    params, prompt, length, slot, cache, moe)
             with jax.named_scope("sample"):
                 tok = sample_tokens(logits[None], temp[None], key[None],
                                     jnp.zeros((1,), jnp.int32), sampling)
-            return (tok[0], *split(merged, moe))
+            return tok[0], cache, moe
 
         # the cache is donated (the pool buffer is updated in place instead
         # of copied every token); ``sampling`` is STATIC — jit caches by
         # shape, so whether any slot samples must key the program cache,
         # not be read from host state at trace time
-        decode = jax.jit(_decode_fn, donate_argnums=(1,), static_argnums=(8,))
-        prefill = jax.jit(_prefill_fn, donate_argnums=(1,),
-                          static_argnums=(8,))
-
-        self._decode = _CountedProgram(decode, self._moe, "decode")
-        self._prefill = _CountedProgram(prefill, self._moe, "prefill")
-
-    # -- sampling (traced) ---------------------------------------------------
-
-    def _sample(self, logits, temps, keys, steps, sampling: bool):
-        """Back-compat shim over the module-level :func:`sample_tokens`."""
-        return sample_tokens(logits, temps, keys, steps, sampling)
+        self._decode = jax.jit(_decode_fn, donate_argnums=(1,),
+                               static_argnums=(8,))
+        self._prefill = jax.jit(_prefill_fn, donate_argnums=(1,),
+                                static_argnums=(8,))
 
     # -- introspection -------------------------------------------------------
 
@@ -573,8 +540,8 @@ class SlotEngine:
             key = np.asarray(
                 jax.random.key_data(jax.random.key(req.seed)), np.uint32)
         with span("prefill.dispatch", bucket=int(staged.shape[0]), **ids):
-            tok_dev, self.cache = self._prefill(
-                self.params, self.cache, staged,
+            tok_dev, self.cache, self._moe["prefill"] = self._prefill(
+                self.params, self.cache, self._moe["prefill"], staged,
                 np.int32(len(req.prompt)), np.int32(slot),
                 np.float32(req.temperature), key, req.temperature > 0)
         with span("prefill.readback", **ids):
@@ -608,9 +575,9 @@ class SlotEngine:
         t0 = _now()
         with span("decode.dispatch", **ids):
             self._kv_blocks_read += kv_blocks(self.lengths, self.max_len)[0]
-            nxt_dev, self.cache = self._decode(
-                self.params, self.cache, self.tokens, self.lengths,
-                self.temps, self.keys, self.steps,
+            nxt_dev, self.cache, self._moe["decode"] = self._decode(
+                self.params, self.cache, self._moe["decode"], self.tokens,
+                self.lengths, self.temps, self.keys, self.steps,
                 bool(np.any(self.temps > 0)))
         with span("decode.readback", **ids):
             nxt = np.asarray(nxt_dev)

@@ -22,7 +22,7 @@ reshard engine's fragment tags):
   round-trips.
 - ``kv/{rid}/{j}.{key}`` — layer ``j``'s ``key`` rows (``k``/``v``),
   shape ``(1, heads, head_dim, length)`` — the cache's own order, time
-  last (``nn.cache_time_axis``) — in deterministic (sorted path, sorted
+  last (``nn.cache.time_axis``) — in deterministic (sorted path, sorted
   key) order on both sides.
 
 ``wire="int8_blockN"`` opts each FLOAT fragment into the block-quantized
@@ -50,7 +50,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from ..nn.attention import cache_time_axis, cache_time_slice
+from ..nn import cache as kvcache
 from .engine import ServeError
 
 __all__ = ["KVTransfer", "KVTransferError", "kv_template"]
@@ -64,21 +64,11 @@ class KVTransferError(ServeError):
     side can retry the prefill by name or fail the handle."""
 
 
-def kv_template(cache_or_rows) -> Dict[str, Dict[str, Tuple[tuple, np.dtype]]]:
-    """``{layer_path: {key: (per_token_shape, dtype)}}`` from a slot-cache
-    pool or a batch-1 row tree (a leaf's shape less its batch and time
-    axes) — the shape contract both transfer endpoints derive from their
-    OWN model, so a fragment that arrives with drifted geometry is a
-    named error, not a silent reshape."""
-    out: Dict[str, Dict[str, Tuple[tuple, np.dtype]]] = {}
-    for path, entry in cache_or_rows.items():
-        out[path] = {}
-        for key, arr in entry.items():
-            if key == "index":
-                continue
-            shape = tuple(int(d) for d in arr.shape[1:cache_time_axis(arr)])
-            out[path][key] = (shape, np.dtype(arr.dtype))
-    return out
+# ``{layer_path: {key: (per_token_shape, dtype)}}`` from a slot-cache pool or
+# a batch-1 row tree: the shape contract both transfer endpoints derive from
+# their OWN model, so a fragment that arrives with drifted geometry is a
+# named error, not a silent reshape
+kv_template = kvcache.token_template
 
 
 def _fragment_shape(per_token_shape: tuple, length: int) -> tuple:
@@ -153,7 +143,7 @@ class KVTransfer:
         frags = []
         for path, key in self._frames:
             shape, dtype = self.template[path][key]
-            arr = cache_time_slice(np.asarray(rows[path][key]), 0, length)
+            arr = kvcache.time_slice(np.asarray(rows[path][key]), 0, length)
             if arr.shape != _fragment_shape(shape, length):
                 raise KVTransferError(
                     f"kv send {rid}: layer {path!r}[{key}] rows have shape "

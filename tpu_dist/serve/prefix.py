@@ -41,7 +41,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from ..nn.attention import cache_time_axis, cache_time_slice
+from ..nn import cache as kvcache
 
 __all__ = ["PrefixCache"]
 
@@ -71,7 +71,7 @@ class PrefixCache:
 
     Thread-safe (one lock; prefill workers share an instance).  ``rows``
     trees everywhere are host numpy ``{layer_path: {"k"/"v": (1, ...,
-    T)}}``, time last (``nn.cache_time_axis``) — the cache never touches
+    T)}}``, time last (``nn.cache.time_axis``) — the cache never touches
     a device."""
 
     def __init__(self, block_tokens: int = 16,
@@ -138,12 +138,7 @@ class PrefixCache:
                     self._page_in(ent)
                 ent.last_use = self._clock
             hit_len = chain[-1].level * self.block
-            rows: Dict[str, Dict[str, np.ndarray]] = {}
-            for path in chain[0].rows:
-                rows[path] = {
-                    k: np.concatenate([e.rows[path][k] for e in chain],
-                                      axis=cache_time_axis(leaf))
-                    for k, leaf in chain[0].rows[path].items()}
+            rows = kvcache.join_time([e.rows for e in chain])
             self.hits += 1
             self.tokens_saved += hit_len
             # enforce AFTER assembling the hit: paging in must not page
@@ -176,9 +171,9 @@ class PrefixCache:
                 lo, hi = (j - 1) * self.block, j * self.block
                 block_rows = {
                     path: {k: np.ascontiguousarray(
-                        cache_time_slice(np.asarray(leaf), lo, hi))
-                        for k, leaf in rows[path].items() if k != "index"}
-                    for path in rows}
+                        kvcache.time_slice(np.asarray(leaf), lo, hi))
+                        for k, leaf in entry.items()}
+                    for path, entry in rows.items()}
                 nbytes = sum(a.nbytes for e in block_rows.values()
                              for a in e.values())
                 ent = _Entry(key, j, prefix.copy(), block_rows, nbytes)

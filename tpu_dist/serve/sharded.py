@@ -62,7 +62,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from ..nn.attention import cache_time_axis
+from ..nn import cache as kvcache
 from .engine import Request, ServeError, SlotEngine, sample_tokens
 
 __all__ = ["ShardedLM", "ShardedDecoder", "ShardedSlotEngine",
@@ -450,23 +450,19 @@ class ShardedDecoder:
             # decode, scalar 0 during prefill — it is BOTH the position
             # offset and the cache write index)
             path = self._layer_paths[0]
-            st = {path: dict(entry, index=index)}
-            (x, part), st2 = slm.apply(p, toks, index, state=st,
-                                       op="embed_attn")
-            new_entry = {k: v for k, v in st2[path].items()
-                         if k != "index"}
-            return x, part, new_entry
+            (x, part), st = slm.apply(
+                p, toks, index, op="embed_attn",
+                state=kvcache.call_state({path: entry}, index))
+            return x, part, kvcache.split_state(st)[0][path]
 
         def _mk_attn(i):
             path = self._layer_paths[i]
 
             def f(p, x, add, entry, index):
-                st = {path: dict(entry, index=index)}
-                (x2, part), st2 = slm.apply(p, x, add, state=st,
-                                            op="attn", layer=i)
-                new_entry = {k: v for k, v in st2[path].items()
-                             if k != "index"}
-                return x2, part, new_entry
+                (x2, part), st = slm.apply(
+                    p, x, add, op="attn", layer=i,
+                    state=kvcache.call_state({path: entry}, index))
+                return x2, part, kvcache.split_state(st)[0][path]
             return jax.jit(f, donate_argnums=(3,))
 
         def _mk_mlp(i):
@@ -488,27 +484,13 @@ class ShardedDecoder:
                                 jnp.zeros((1,), jnp.int32), sampling)
             return tok[0]
 
-        def _write_slot(pool, rows, slot):
-            # one request's per-layer cache rows land in slot `slot` of
-            # the pool — prefill_into_slot's dynamic_update_slice, over
-            # this shard's head slice only
-            slot = jnp.asarray(slot, jnp.int32)
-            out = {}
-            for path, entry in pool.items():
-                row = rows[path]
-                out[path] = {
-                    k: jax.lax.dynamic_update_slice(
-                        entry[k], row[k].astype(entry[k].dtype),
-                        (slot,) + (0,) * (entry[k].ndim - 1))
-                    for k in entry}
-            return out
-
         self._embed_attn0 = jax.jit(_embed_attn0, donate_argnums=(3,))
         self._attn = [_mk_attn(i) for i in range(self.depth)]
         self._mlp = [_mk_mlp(i) for i in range(self.depth)]
         self._head_dec = jax.jit(_head_decode, static_argnums=(6,))
         self._head_pre = jax.jit(_head_prefill, static_argnums=(6,))
-        self._write = jax.jit(_write_slot, donate_argnums=(0,))
+        # prefill_into_slot's slot write, over this shard's head slice only
+        self._write = jax.jit(kvcache.write_slot_rows, donate_argnums=(0,))
 
     # -- the cross-shard combine --------------------------------------------
 
@@ -593,9 +575,7 @@ class ShardedDecoder:
         segment pipeline at batch 1 with a fresh per-layer cache row,
         then each layer's rows are written into this shard's pool slice."""
         jnp = self._jnp
-        k0 = next(iter(cache.values()))["k"]
-        fresh = self.slm.init_slot_cache(1, k0.shape[cache_time_axis(k0)],
-                                         k0.dtype)
+        fresh = self.slm.init_slot_cache(1, *kvcache.extent(cache))
         zero = jnp.zeros((), jnp.int32)
         rows = {}
         p0 = self._layer_paths[0]
@@ -657,15 +637,13 @@ class ShardedSlotEngine(SlotEngine):
     def _build_programs(self) -> None:
         dec = self.decoder
 
-        def _decode(params, cache, tokens, lengths, temps, keys, steps,
-                    sampling):
-            return dec.decode_pool(params, cache, tokens, lengths, temps,
-                                   keys, steps, sampling)
+        # the engine's argument order; a sharded dense model keeps no
+        # routed-row counters, the (empty) set passes through
+        def _decode(params, cache, counters, *args):
+            return (*dec.decode_pool(params, cache, *args), counters)
 
-        def _prefill(params, cache, prompt, length, slot, temp, key,
-                     sampling):
-            return dec.prefill_pool(params, cache, prompt, length, slot,
-                                    temp, key, sampling)
+        def _prefill(params, cache, counters, *args):
+            return (*dec.prefill_pool(params, cache, *args), counters)
 
         self._decode = _decode
         self._prefill = _prefill
