@@ -219,8 +219,8 @@ def test_engine_programs_take_counters_beside_the_pool_they_donate(kind):
     assert _names(engine.cache) == {"k", "v"}
     assert bool(engine._moe["decode"]) == (kind == "routed")
     lowered = engine._decode.lower(
-        engine.params, engine.cache, engine._moe["decode"], engine.tokens,
-        engine.lengths, engine.temps, engine.keys, engine.steps, False)
+        engine.params, engine.cache, engine._moe["decode"], engine._slots,
+        engine.active, False)
     _, cache_info, counter_info, *_ = lowered.args_info[0]
     assert all(a.donated for a in jax.tree.leaves(cache_info))
     assert not any(a.donated for a in jax.tree.leaves(counter_info))

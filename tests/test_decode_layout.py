@@ -25,6 +25,7 @@ import pytest
 
 from tpu_dist import nn
 from tpu_dist.models import TransformerLM
+from tpu_dist.serve.engine import pool_programs
 
 SLOTS, MAX_LEN, DIM, HEADS, DEPTH = 32, 1024, 1600, 25, 2
 # The cell's vocabulary is 50257; it is cut here because at that size the
@@ -84,17 +85,27 @@ def _compile(program, cache_dtype, sharding):
         lambda: model.init_slot_cache(SLOTS, MAX_LEN, cache_dtype)),
         sharding)
 
-    def ints(*shape):
-        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=sharding)
+    return _lower(model, program, params, cache, {}, sharding).compile()
 
+
+def _lower(model, program, params, pool, counters, sharding):
+    """``serve.engine.pool_programs(model)`` — the program AS SERVED, the
+    slot state and the live mask beside the donated pool (ISSUE 29) —
+    lowered for the described chip."""
+    def arr(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    ints = lambda *shape: arr(jnp.int32, *shape)
+    slots = {"tokens": ints(SLOTS), "lengths": ints(SLOTS),
+             "steps": ints(SLOTS), "temps": arr(jnp.float32, SLOTS),
+             "keys": arr(jnp.uint32, SLOTS, 2)}
+    decode, prefill = pool_programs(model)
     if program == "decode_step":
-        fn = jax.jit(lambda p, c, tok, lens: model.decode_step(p, tok, lens,
-                                                               c),
-                     donate_argnums=1)
-        return fn.lower(params, cache, ints(SLOTS), ints(SLOTS)).compile()
-    fn = jax.jit(lambda p, c, prompt, n, slot: model.prefill_into_slot(
-        p, prompt, n, slot, c), donate_argnums=1)
-    return fn.lower(params, cache, ints(MAX_LEN), ints(), ints()).compile()
+        return jax.jit(decode, donate_argnums=1, static_argnums=5).lower(
+            params, pool, counters, slots, arr(jnp.bool_, SLOTS), False)
+    return jax.jit(prefill, donate_argnums=1, static_argnums=9).lower(
+        params, pool, counters, slots, ints(MAX_LEN), ints(), ints(),
+        arr(jnp.float32), arr(jnp.uint32, 2), False)
 
 
 def _pool_sized_results(hlo_text, ops):
@@ -201,22 +212,9 @@ def test_olmoe_pool_program_compiles_with_its_grouped_matmuls(
         one_chip)
     counters = _shapes(jax.eval_shape(model.init_moe_counters), one_chip)
 
-    def ints(*shape):
-        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
-
-    if program == "decode_step":
-        fn = jax.jit(lambda p, c, n, tok, lens: model.decode_step(
-            p, tok, lens, c, n), donate_argnums=1)
-        compiled = fn.lower(params, pool, counters, ints(SLOTS),
-                            ints(SLOTS)).compile()
-        routed = SLOTS * top_k
-    else:
-        fn = jax.jit(lambda p, c, n, prompt, length, slot:
-                     model.prefill_into_slot(p, prompt, length, slot, c, n),
-                     donate_argnums=1)
-        compiled = fn.lower(params, pool, counters, ints(MAX_LEN), ints(),
-                            ints()).compile()
-        routed = MAX_LEN * top_k
+    compiled = _lower(model, program, params, pool, counters,
+                      one_chip).compile()
+    routed = (SLOTS if program == "decode_step" else MAX_LEN) * top_k
     text = compiled.as_text()
     calls = re.findall(r"%(gmm_r\d+)[.\d]* = [^\n]*custom_call_target="
                        r"\"tpu_custom_call\"", text)
@@ -261,11 +259,9 @@ def test_decode_step_on_the_kernel_writes_no_pool_sized_result(
     cache = _shapes(jax.eval_shape(
         lambda: model.init_slot_cache(SLOTS, MAX_LEN, jnp.bfloat16)),
         one_chip)
-    ints = jax.ShapeDtypeStruct((SLOTS,), jnp.int32, sharding=one_chip)
     with nn.attention_impl("flash"):
-        compiled = jax.jit(
-            lambda p, c, tok, lens: model.decode_step(p, tok, lens, c),
-            donate_argnums=1).lower(params, cache, ints, ints).compile()
+        compiled = _lower(model, "decode_step", params, cache, {},
+                          one_chip).compile()
     text = compiled.as_text()
     calls = re.findall(r"%(decode_attention)[.\d]* = [^\n]*"
                        r"custom_call_target=\"tpu_custom_call\"", text)

@@ -349,8 +349,7 @@ class TestScopes:
         engine = serve.SlotEngine(model, params, num_slots=2)
         decode = _scopes(engine._decode.lower(
             engine.params, engine.cache, engine._moe["decode"],
-            engine.tokens, engine.lengths, engine.temps, engine.keys,
-            engine.steps, False))
+            engine._slots, engine.active, False))
         has = lambda names, part: any(part in n for n in names)
         for part in ("decode/block0/attn/cache_update/",
                      "decode/block0/attn/attend/", "decode/block1/mlp/1/",
@@ -358,8 +357,8 @@ class TestScopes:
             assert has(decode, part), part
         prefill = _scopes(engine._prefill.lower(
             engine.params, engine.cache, engine._moe["prefill"],
-            np.zeros(16, np.int32), np.int32(5), np.int32(0), np.float32(0),
-            np.zeros(2, np.uint32), False))
+            engine._slots, np.zeros(16, np.int32), np.int32(5), np.int32(0),
+            np.float32(0), np.zeros(2, np.uint32), False))
         for part in ("prefill/block0/attn/cache_update/",
                      "prefill/block0/attn/attend/", "prefill/cache_write/",
                      "/sample"):

@@ -383,7 +383,7 @@ class TestScheduler:
             def boom():
                 raise RuntimeError("device died")
 
-            engine.step = boom
+            engine.launch_step = boom
             for h in (inflight, queued):
                 with pytest.raises(serve.SchedulerClosedError,
                                    match="device died"):

@@ -52,7 +52,8 @@ from typing import Callable, Dict, Optional
 import numpy as np
 
 from ..nn import cache as kvcache
-from .engine import Request, ServeError, SlotEngine, sample_tokens
+from .engine import (Request, ServeError, SlotEngine, sample_tokens,
+                     seed_key, set_row)
 from .kvtransfer import KVTransfer, KVTransferError
 from .scheduler import Scheduler
 
@@ -350,8 +351,6 @@ class DisaggSlotEngine(SlotEngine):
     # -- admission: inject instead of prefill ---------------------------------
 
     def _admit(self, req: Request, slot: int) -> int:
-        import jax
-
         arrival = req.staged
         if not isinstance(arrival, dict) or "rows" not in arrival:
             raise DisaggError(f"request {req.id} reached disagg admission "
@@ -359,11 +358,13 @@ class DisaggSlotEngine(SlotEngine):
         req.t_admit = _now()
         self.hist_queue.observe(req.t_admit - req.t_submit)
 
-        key = np.asarray(
-            jax.random.key_data(jax.random.key(req.seed)), np.uint32)
+        key = seed_key(req.seed)
         self.cache = self._inject(self.cache, arrival["rows"],
                                   np.int32(slot))
         tok = int(arrival["first_tok"])
+        # no prefill program runs here to set the slot's row on the device
+        self._slots = set_row(self._slots, slot, tok, len(req.prompt),
+                              req.temperature, key)
         t_pf = _now()
         # phase split: `prefill` is the REMOTE compute (shipped in the
         # meta frame), `transfer` the dispatch->arrival wall time
@@ -379,13 +380,8 @@ class DisaggSlotEngine(SlotEngine):
         else:
             self.prefix_misses += 1
 
-        self.lengths[slot] = len(req.prompt)
+        self._occupy(req, slot, key)
         self.tokens[slot] = tok
-        self.temps[slot] = req.temperature
-        self.keys[slot] = key
-        self.steps[slot] = 1
-        self.active[slot] = True
-        self.slot_req[slot] = req
         self._obs_admit(req, slot, t_pf)
         self._obs_transfer(req, arrival, xfer)
 

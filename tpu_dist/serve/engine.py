@@ -24,8 +24,18 @@ Two compiled programs drive the pool (tpu_dist/models/transformer.py):
   token-identical to offline generation (the ``--smoke`` gate pins it).
 
 The engine is deliberately single-threaded (the scheduler's loop thread
-drives ``admit``/``step``); everything thread-sensitive (handles,
-queues) lives in :mod:`tpu_dist.serve.scheduler`.
+drives it); everything thread-sensitive (handles, queues) lives in
+:mod:`tpu_dist.serve.scheduler`.
+
+Each pool operation has two halves.  The LAUNCH half (``launch_admit`` /
+``launch_step``) enqueues the program and returns: what the next program
+needs of a slot (last token, length, step count, temperature, key) is an
+argument and a result of both programs and stays on the device, so step
+n + 1 can be launched while step n's tokens are still unread.  The
+COLLECT half (``collect``) waits for the oldest program in flight — the
+one place the loop thread blocks on the device — emits its tokens and does
+its bookkeeping.  ``admit`` / ``step`` are launch immediately followed by
+collect; the scheduler's loop launches one program ahead (``settle``).
 
 Per-request observability: when the flight recorder is armed
 (``TPU_DIST_OBS=1``) every request opens a ``serve`` span at submit and
@@ -36,6 +46,7 @@ working on — not just "the rank is busy".
 
 from __future__ import annotations
 
+import collections
 import time
 from typing import Callable, List, Optional
 
@@ -321,6 +332,97 @@ def _bucket_lengths(max_prompt: int, min_bucket: int = 16) -> List[int]:
     return out
 
 
+def seed_key(seed: int) -> np.ndarray:
+    """``jax.random.key_data(jax.random.key(seed))`` of the default
+    (threefry) generator, computed on the host: the words of the seed, the
+    high one kept only where 64-bit integers are enabled.  A request's
+    sampling key never costs the loop thread a device round trip."""
+    import jax
+
+    high = (seed >> 32) & 0xFFFFFFFF if jax.config.jax_enable_x64 else 0
+    return np.array([high, seed & 0xFFFFFFFF], np.uint32)
+
+
+# -- slot state on the device -------------------------------------------------
+# What the next pool program needs of every slot: {"tokens", "lengths",
+# "steps", "temps", "keys"}, one row a slot.  An argument and a result of
+# both programs, beside the cache and the routed-row counters.
+
+def masked_rows(slots: dict, live):
+    """``(tokens, lengths, temps)`` as a decode step feeds them to the
+    model: a row the step does not carry reads as a free slot (token 0,
+    length 0, greedy), whatever its device row still holds."""
+    import jax.numpy as jnp
+
+    return (jnp.where(live, slots["tokens"], 0),
+            jnp.where(live, slots["lengths"], 0),
+            jnp.where(live, slots["temps"], 0.0))
+
+
+def advance_rows(slots: dict, live, nxt) -> dict:
+    """The rows a decode step carried, after it: the sampled token, one
+    more resident position, one more step of the sampling schedule."""
+    import jax.numpy as jnp
+
+    one = live.astype(jnp.int32)
+    return dict(slots, tokens=jnp.where(live, nxt, slots["tokens"]),
+                lengths=slots["lengths"] + one, steps=slots["steps"] + one)
+
+
+def set_row(slots: dict, slot, token, length, temp, key) -> dict:
+    """One slot's row as an admission leaves it: first token sampled,
+    ``length`` positions resident, step 1 of the sampling schedule."""
+    put = lambda name, value: slots[name].at[slot].set(value)
+    return {"tokens": put("tokens", token), "lengths": put("lengths", length),
+            "steps": put("steps", 1), "temps": put("temps", temp),
+            "keys": put("keys", key)}
+
+
+def pool_programs(model):
+    """The two pool programs of ``model``, unjitted: ``decode(params, cache,
+    moe, slots, live, sampling)`` and ``prefill(params, cache, moe, slots,
+    prompt, length, slot, temp, key, sampling)``.  Each returns its sampled
+    token(s), then the cache, the counters and the slot state it was
+    given, updated."""
+    import jax
+    import jax.numpy as jnp
+
+    def decode(params, cache, moe, slots, live, sampling):
+        tokens, lengths, temps = masked_rows(slots, live)
+        with jax.named_scope("decode"):
+            logits, cache, moe = model.decode_step(params, tokens, lengths,
+                                                   cache, moe)
+        with jax.named_scope("sample"):
+            nxt = sample_tokens(logits, temps, slots["keys"], slots["steps"],
+                                sampling)
+            return nxt, cache, moe, advance_rows(slots, live, nxt)
+
+    def prefill(params, cache, moe, slots, prompt, length, slot, temp, key,
+                sampling):
+        with jax.named_scope("prefill"):
+            logits, cache, moe = model.prefill_into_slot(
+                params, prompt, length, slot, cache, moe)
+        with jax.named_scope("sample"):
+            tok = sample_tokens(logits[None], temp[None], key[None],
+                                jnp.zeros((1,), jnp.int32), sampling)[0]
+            return tok, cache, moe, set_row(slots, slot, tok, length, temp,
+                                            key)
+
+    return decode, prefill
+
+
+class _Flight:
+    """One pool program launched and not yet collected: its sampled
+    token(s) still on the device, the slots it carried and whose request
+    each row was when it was launched."""
+
+    __slots__ = ("kind", "out", "slots", "reqs", "t_launch", "ids")
+
+    def __init__(self, kind, out, slots, reqs, t_launch, ids):
+        self.kind, self.out, self.slots, self.reqs = kind, out, slots, reqs
+        self.t_launch, self.ids = t_launch, ids
+
+
 class SlotEngine:
     """Fixed pool of ``num_slots`` KV-cache slots with per-slot lengths.
 
@@ -328,12 +430,15 @@ class SlotEngine:
     prefills a free slot between decode iterations, ``step()`` decodes
     every active slot one token.  EOS and per-request ``max_new_tokens``
     free slots immediately — the freed slot is admissible on the very next
-    iteration.
+    iteration.  Both are a launch half followed by :meth:`collect`; a
+    caller that launches ahead (``launch_step`` / ``launch_admit``, then
+    :meth:`settle`) gets every request the same tokens in the same order.
     """
 
     def __init__(self, model, params, num_slots: int = 8,
                  max_len: Optional[int] = None, cache_dtype=None,
                  min_bucket: int = 16):
+        import jax
         import jax.numpy as jnp
 
         from ..utils.compile_cache import ensure_compile_cache
@@ -352,7 +457,9 @@ class SlotEngine:
         self.cache = model.init_slot_cache(self.num_slots, self.max_len,
                                            self.cache_dtype)
 
-        # host-side slot table — THE source of truth for occupancy
+        # host-side slot table — THE source of truth for occupancy, and the
+        # host's mirror of the device's slot state: lengths / steps / temps /
+        # keys as of the last LAUNCH, tokens as of the last COLLECTION
         self.lengths = np.zeros(self.num_slots, np.int32)
         self.tokens = np.zeros(self.num_slots, np.int32)
         self.temps = np.zeros(self.num_slots, np.float32)
@@ -360,6 +467,24 @@ class SlotEngine:
         self.steps = np.ones(self.num_slots, np.int32)
         self.active = np.zeros(self.num_slots, bool)
         self.slot_req: List[Optional[Request]] = [None] * self.num_slots
+        # tokens of each slot's request no program has been launched for
+        # yet: a request that ends by max_new_tokens leaves the steps
+        # launched ahead of its last token's collection
+        self._left = np.zeros(self.num_slots, np.int32)
+        # (copies: the CPU backend may keep the host buffer it is handed)
+        self._slots = jax.device_put(
+            {"tokens": self.tokens.copy(), "lengths": self.lengths.copy(),
+             "steps": self.steps.copy(), "temps": self.temps.copy(),
+             "keys": self.keys.copy()})
+        # programs launched and not yet collected, oldest first; the live
+        # mask of the last decode launch, uploaded again only when it changes
+        self._flight: collections.deque = collections.deque()
+        self._live = (None, None)
+        self._t_collected = 0.0
+        # decode steps ever collected (reset_stats leaves it): the
+        # scheduler's progress feed
+        self.steps_done = 0
+        self._pipeline = self._fresh_pipeline()
 
         # latency split (shared streaming histograms, utils.metrics)
         self.hist_queue = LatencyHistogram()
@@ -395,7 +520,6 @@ class SlotEngine:
         other line of slot bookkeeping is shared, so the two engines
         cannot drift on admission/finish semantics."""
         import jax
-        import jax.numpy as jnp
 
         model = self.model
         # routed-row counters of a model with expert layers ({} without):
@@ -407,33 +531,15 @@ class SlotEngine:
         fresh = getattr(model, "init_moe_counters", dict)
         self._moe = {"prefill": fresh(), "decode": fresh()}
 
-        def _decode_fn(params, cache, moe, tokens, lengths, temps, keys,
-                       steps, sampling):
-            with jax.named_scope("decode"):
-                logits, cache, moe = model.decode_step(params, tokens,
-                                                       lengths, cache, moe)
-            with jax.named_scope("sample"):
-                return (sample_tokens(logits, temps, keys, steps, sampling),
-                        cache, moe)
-
-        def _prefill_fn(params, cache, moe, prompt, length, slot, temp, key,
-                        sampling):
-            with jax.named_scope("prefill"):
-                logits, cache, moe = model.prefill_into_slot(
-                    params, prompt, length, slot, cache, moe)
-            with jax.named_scope("sample"):
-                tok = sample_tokens(logits[None], temp[None], key[None],
-                                    jnp.zeros((1,), jnp.int32), sampling)
-            return tok[0], cache, moe
-
         # the cache is donated (the pool buffer is updated in place instead
         # of copied every token); ``sampling`` is STATIC — jit caches by
         # shape, so whether any slot samples must key the program cache,
         # not be read from host state at trace time
-        self._decode = jax.jit(_decode_fn, donate_argnums=(1,),
-                               static_argnums=(8,))
-        self._prefill = jax.jit(_prefill_fn, donate_argnums=(1,),
-                                static_argnums=(8,))
+        decode, prefill = pool_programs(model)
+        self._decode = jax.jit(decode, donate_argnums=(1,),
+                               static_argnums=(5,))
+        self._prefill = jax.jit(prefill, donate_argnums=(1,),
+                                static_argnums=(9,))
 
     # -- introspection -------------------------------------------------------
 
@@ -452,7 +558,8 @@ class SlotEngine:
         return int(self.active.sum())
 
     def idle(self) -> bool:
-        return not self.active.any()
+        """No slot occupied and no program in flight."""
+        return not self.active.any() and not self._flight
 
     def occupancy(self) -> float:
         """Mean fraction of slots busy per decode step."""
@@ -500,6 +607,21 @@ class SlotEngine:
         free (callers check :meth:`free_slots` first).  Cancelled or
         past-deadline requests are refused by name BEFORE the prefill —
         shedding stale load instead of spending a compiled program on it."""
+        slot = self.launch_admit(req)
+        self.collect_all()
+        return slot
+
+    def step(self) -> int:
+        """One decode iteration over the pool; returns tokens emitted."""
+        self.launch_step()
+        return self.collect_all()
+
+    # -- launch: enqueue a program, return without its result ---------------
+
+    def launch_admit(self, req: Request) -> int:
+        """The launch half of :meth:`admit`: the refusals, the slot choice
+        and the prefill's launch.  The slot is occupied from here on; its
+        first token is emitted by the :meth:`collect` of this program."""
         slot = self._admission_slot(req)
         self._pre_admit(req, slot)
         return self._admit(req, slot)
@@ -527,78 +649,149 @@ class SlotEngine:
         the sharded engine's plan broadcast point."""
 
     def _admit(self, req: Request, slot: int) -> int:
-        """The unconditional admission half: prefill + slot bookkeeping
-        (every refusal already ruled out by :meth:`_admission_slot`)."""
-        import jax
-
+        """The unconditional admission half: the prefill's launch and the
+        slot's occupation (every refusal already ruled out by
+        :meth:`_admission_slot`).  Uploads the request's own scalars and
+        nothing of the other slots."""
         ids = {"req": req.id, "slot": slot}
         req.t_admit = _now()
         with span("prefill.prepare", **ids):
             self.hist_queue.observe(req.t_admit - req.t_submit)
             staged = (req.staged if req.staged is not None
                       else self.stage(req))
-            key = np.asarray(
-                jax.random.key_data(jax.random.key(req.seed)), np.uint32)
+            key = seed_key(req.seed)
         with span("prefill.dispatch", bucket=int(staged.shape[0]), **ids):
-            tok_dev, self.cache, self._moe["prefill"] = self._prefill(
-                self.params, self.cache, self._moe["prefill"], staged,
-                np.int32(len(req.prompt)), np.int32(slot),
-                np.float32(req.temperature), key, req.temperature > 0)
-        with span("prefill.readback", **ids):
-            tok = int(tok_dev)
-        t_pf = _now()
-        self.hist_prefill.observe(t_pf - req.t_admit)
-
-        with span("prefill.emit", **ids):
-            self.lengths[slot] = len(req.prompt)
-            self.tokens[slot] = tok
-            self.temps[slot] = req.temperature
-            self.keys[slot] = key
-            self.steps[slot] = 1
-            self.active[slot] = True
-            self.slot_req[slot] = req
-            self._obs_admit(req, slot, t_pf)
-
-            req.emit(tok)
-            self.hist_ttft.observe(_now() - req.t_submit)
-            self.generated_tokens += 1
-            self._maybe_finish(slot, tok)
+            tok_dev, self.cache, self._moe["prefill"], self._slots = \
+                self._prefill(
+                    self.params, self.cache, self._moe["prefill"],
+                    self._slots, staged, np.int32(len(req.prompt)),
+                    np.int32(slot), np.float32(req.temperature), key,
+                    req.temperature > 0)
+            self._occupy(req, slot, key)
+        self._launched(_Flight("prefill", tok_dev, [slot], [req],
+                               req.t_admit, ids))
         return slot
 
-    def step(self) -> int:
-        """One decode iteration over the pool; returns tokens emitted."""
-        if not self.active.any():
-            return 0
+    def _occupy(self, req: Request, slot: int, key) -> None:
+        """The host's rows of a slot whose admission was launched; its
+        first token follows at collection."""
+        self.lengths[slot] = len(req.prompt)
+        self.temps[slot] = req.temperature
+        self.keys[slot] = key
+        self.steps[slot] = 1
+        self.active[slot] = True
+        self.slot_req[slot] = req
+        self._left[slot] = req.max_new_tokens - 1
+
+    def launch_step(self) -> bool:
+        """The launch half of :meth:`step`: one decode iteration over the
+        rows whose requests still have a token to come that no program in
+        flight computes already.  False when there is none (nothing was
+        launched).  Uploads the live mask when it changed, nothing else."""
+        import jax
+
+        live = self.active & (self._left > 0)
+        if not live.any():
+            return False
+        self._pre_step()
         self._iterations += 1
-        n_active = int(self.active.sum())
-        ids = {"step": self._iterations, "active": n_active}
+        rows = np.flatnonzero(live)
+        ids = {"step": self._iterations, "active": len(rows)}
         t0 = _now()
         with span("decode.dispatch", **ids):
-            self._kv_blocks_read += kv_blocks(self.lengths, self.max_len)[0]
-            nxt_dev, self.cache, self._moe["decode"] = self._decode(
-                self.params, self.cache, self._moe["decode"], self.tokens,
-                self.lengths, self.temps, self.keys, self.steps,
-                bool(np.any(self.temps > 0)))
-        with span("decode.readback", **ids):
-            nxt = np.asarray(nxt_dev)
-        dt = _now() - t0
-        self._decode_steps += 1
-        self._occupied_slot_steps += n_active
-        self.hist_token.observe(dt)
+            self._kv_blocks_read += kv_blocks(
+                np.where(live, self.lengths, 0), self.max_len)[0]
+            if not np.array_equal(live, self._live[0]):
+                self._live = (live, jax.device_put(live))
+            nxt_dev, self.cache, self._moe["decode"], self._slots = \
+                self._decode(self.params, self.cache, self._moe["decode"],
+                             self._slots, self._live[1],
+                             bool(np.any(self.temps[rows] > 0)))
+            self.lengths[rows] += 1
+            self.steps[rows] += 1
+            self._left[rows] -= 1
+        self._launched(_Flight("decode", nxt_dev, rows,
+                               [self.slot_req[s] for s in rows], t0, ids))
+        return True
 
+    def _pre_step(self) -> None:
+        """Hook before a decode step's launch, once it is certain — the
+        sharded engine's plan broadcast point."""
+
+    def _launched(self, flight: _Flight) -> None:
+        count = self._pipeline
+        count["launches"][flight.kind] += 1
+        if self._flight:
+            count["launched_ahead"][flight.kind] += 1
+        self._flight.append(flight)
+
+    # -- collect: the one wait on the device, then the host's share ----------
+
+    def settle(self) -> int:
+        """Collect every program in flight but the newest: what the loop
+        thread calls after a launch, so one program is always enqueued
+        behind the one it waits for.  An engine whose launch must stay
+        glued to its collection (a plan already on the wire) makes this
+        :meth:`collect_all`.  Returns tokens emitted."""
+        return self._collect_to(1)
+
+    def collect_all(self) -> int:
+        """Collect every program in flight; returns tokens emitted."""
+        return self._collect_to(0)
+
+    def _collect_to(self, keep: int) -> int:
         emitted = 0
-        with span("decode.emit", **ids):
-            for slot in np.flatnonzero(self.active):
-                slot = int(slot)
-                req = self.slot_req[slot]
-                tok = int(nxt[slot])
-                self.lengths[slot] += 1
-                self.steps[slot] += 1
+        while len(self._flight) > keep:
+            emitted += self.collect()
+        return emitted
+
+    def _readback(self, out) -> np.ndarray:
+        """THE place the loop thread waits for the device."""
+        return np.asarray(out)
+
+    def collect(self) -> int:
+        """Wait for the oldest program in flight, emit its tokens and do
+        its bookkeeping; returns tokens emitted (0 with nothing in flight).
+        A row whose request ended since the launch (EOS, cancel, deadline)
+        is dropped: its token is never emitted."""
+        if not self._flight:
+            return 0
+        flight = self._flight.popleft()
+        prefill = flight.kind == "prefill"
+        with span(flight.kind + ".readback", **flight.ids):
+            out = self._readback(flight.out)
+        # charged collection to collection (from its own launch where that
+        # is later: an idle pool), so the two histograms split the loop's
+        # busy time and count nothing twice
+        now = _now()
+        dt = now - max(self._t_collected, flight.t_launch)
+        self._t_collected = now
+        if prefill:
+            self.hist_prefill.observe(dt)
+        else:
+            self._decode_steps += 1
+            self.steps_done += 1
+            self.hist_token.observe(dt)
+        tokens = out.reshape(-1) if prefill else out[flight.slots]
+        emitted = wasted = 0
+        with span(flight.kind + ".emit", **flight.ids):
+            for slot, req, tok in zip(flight.slots, flight.reqs, tokens):
+                slot, tok = int(slot), int(tok)
+                if self.slot_req[slot] is not req:   # ended since the launch
+                    wasted += 1
+                    continue
                 self.tokens[slot] = tok
+                if prefill:
+                    self._obs_admit(req, slot, now)
                 req.emit(tok)
+                if prefill:
+                    self.hist_ttft.observe(_now() - req.t_submit)
                 self.generated_tokens += 1
                 emitted += 1
                 self._maybe_finish(slot, tok)
+        if not prefill:
+            self._occupied_slot_steps += emitted
+            self._pipeline["wasted_rows"] += wasted
         return emitted
 
     # -- completion / failure ------------------------------------------------
@@ -628,15 +821,20 @@ class SlotEngine:
             req.fail(exc)
 
     def fail_all(self, exc: BaseException) -> None:
+        """Shutdown: every occupied slot fails with ``exc``, and what is
+        still in flight is dropped uncollected."""
         for slot in np.flatnonzero(self.active):
             self.fail_slot(int(slot), exc)
+        self._flight.clear()
 
     def sweep_expired(self) -> int:
         """Free slots whose requests were cancelled (client disconnect /
         explicit cancel) or ran past their ``deadline_ms`` — called by the
         scheduler loop at EVERY iteration boundary, so a cancelled request
         stops occupying a slot after at most one decode step instead of
-        decoding to ``max_new_tokens`` for nobody.  The request terminates
+        decoding to ``max_new_tokens`` for nobody (a step launched ahead of
+        the boundary still carries its row: two steps at most, the second's
+        token dropped at collection).  The request terminates
         with the named error and its obs span closes ``error:Cancelled`` /
         ``error:DeadlineExceededError``.  Returns the slots freed."""
         with span("sweep", step=self._iterations + 1):
@@ -682,6 +880,7 @@ class SlotEngine:
         self.tokens[slot] = 0
         self.temps[slot] = 0.0
         self.slot_req[slot] = None
+        self._left[slot] = 0
 
     # -- per-request obs spans ----------------------------------------------
 
@@ -741,10 +940,21 @@ class SlotEngine:
         self._occupied_slot_steps = 0
         self._decode_steps = 0
         self._kv_blocks_read = 0
+        self._pipeline = self._fresh_pipeline()
         reset_phases(SERVE_PHASES)
         # the device counters are never zeroed (a step in flight would
         # carry the old count on): stats() reports them past this reading
         self._moe_base = self._moe_read()
+
+    @staticmethod
+    def _fresh_pipeline() -> dict:
+        """``stats()["pipeline"]``: programs launched, those launched while
+        an earlier one's result was still uncollected, and the rows decode
+        steps carried for requests that had already ended.  Host
+        arithmetic, no device read."""
+        kinds = lambda: {"decode": 0, "prefill": 0}
+        return {"launches": kinds(), "launched_ahead": kinds(),
+                "wasted_rows": 0}
 
     def _moe_read(self) -> dict:
         """The routed-row counters per pool program, summed over layers, as
@@ -805,6 +1015,8 @@ class SlotEngine:
         return {
             **({"moe": moe} if moe else {}),
             "decode_attn": self._decode_attn_stats(),
+            "pipeline": {k: dict(v) if isinstance(v, dict) else v
+                         for k, v in self._pipeline.items()},
             "completed": self.completed,
             "generated_tokens": self.generated_tokens,
             "decode_steps": self._decode_steps,

@@ -5,7 +5,10 @@ bucket-pads and device-stages each prompt (the ``DeviceLoader`` discipline:
 input prep overlaps the decode loop instead of stalling it); the *loop*
 thread drives the :class:`~tpu_dist.serve.engine.SlotEngine` — admit
 staged requests into free slots between decode iterations, then run one
-``decode_step`` over the pool.
+``decode_step`` over the pool.  It launches each program BEFORE it collects
+the previous one, so the chip always has its next program enqueued while
+the host reads back, sends token frames and keeps its books
+(``_run_loop``).
 
 Admission coalescing: when the engine is IDLE and a request arrives, the
 loop holds admission for up to ``batch_window`` seconds so closely-spaced
@@ -321,9 +324,22 @@ class Scheduler:
                 if count:
                     q.task_done()
 
-    def _admit(self, req: Request) -> None:
+    def _fail_fatal(self, exc: BaseException) -> bool:
+        """A dead engine (device error mid-decode, donated cache
+        invalidated, a shard peer gone) strands every request: record the
+        cause, stop the scheduler, and fail everything BY NAME in the loop's
+        epilogue — a zombie loop accepting submits it can never serve is
+        the one shape this layer forbids.  Returns False (the loop's
+        "stop" answer)."""
+        self._fatal = exc
+        self._stop.set()
+        return False
+
+    def _admit(self, req: Request) -> bool:
+        """Launch one admission ahead of the program in flight, then collect
+        what came before it; False = fatal engine death (stop set)."""
         try:
-            self.engine.admit(req)
+            self.engine.launch_admit(req)
         except Exception as e:   # a bad request must not kill the loop
             self.engine._obs_end(req, error_outcome(e))
             req.fail(e)
@@ -333,11 +349,12 @@ class Scheduler:
                 # (a sharded leader whose admit plan was broadcast before
                 # its prefill died): shut down with the cause — the loop
                 # epilogue fails everything by name, exactly like a
-                # fatal step()
-                self._fatal = fatal
-                self._stop.set()
+                # fatal step
+                return self._fail_fatal(fatal)
+            return True
         finally:
             self._staged.task_done()
+        return self._collect(self.engine.settle)
 
     def _sweep_once(self) -> bool:
         """One expiry sweep; False = fatal engine death (stop set).  The
@@ -345,39 +362,52 @@ class Scheduler:
         leader broadcasts its free plan AND its idle-liveness probe here,
         so a dead follower's ``PeerGoneError`` surfaces at the iteration
         boundary; it must take the same cause-naming shutdown as a fatal
-        ``step()``, not kill the loop thread silently."""
+        step, not kill the loop thread silently."""
         try:
             self.engine.sweep_expired()
         except Exception as e:
-            self._fatal = e
-            self._stop.set()
-            return False
+            return self._fail_fatal(e)
         return True
 
     def _step_once(self) -> bool:
-        """One decode iteration; False = fatal engine death (stop set)."""
+        """Launch the next decode step ahead of the program in flight and
+        collect what came before it — or, with no row left to launch,
+        collect everything.  False = fatal engine death (stop set)."""
         try:
-            self.engine.step()
+            launched = self.engine.launch_step()
         except Exception as e:
-            # a dead engine (device error mid-decode, donated cache
-            # invalidated) strands every request: record the cause, stop
-            # the scheduler, and fail everything BY NAME in the epilogue —
-            # a zombie loop accepting submits it can never serve is the
-            # one shape this layer forbids
-            self._fatal = e
-            self._stop.set()
-            return False
-        self._steps += 1
-        if self.step_hook is not None:
-            try:
-                self.step_hook(self._steps)
-            except Exception:
-                pass
+            return self._fail_fatal(e)
+        return self._collect(self.engine.settle if launched
+                             else self.engine.collect_all)
+
+    def _collect(self, collect: Callable[[], int]) -> bool:
+        """Run one of the engine's collecting calls, then count the decode
+        steps it finished; False = fatal engine death (stop set)."""
+        try:
+            collect()
+        except Exception as e:
+            return self._fail_fatal(e)
+        while self._steps < self.engine.steps_done:
+            self._steps += 1
+            if self.step_hook is not None:
+                try:
+                    self.step_hook(self._steps)
+                except Exception:
+                    pass
         with self._idle_cv:
             self._idle_cv.notify_all()
         return True
 
     def _run_loop(self) -> None:
+        # One program is kept enqueued behind the one the thread waits for:
+        # each iteration sweeps, decides admissions from the engine's host
+        # mirror, LAUNCHES the next program (a held request's prefill, else
+        # the next decode step) and only then COLLECTS the one launched
+        # before it (engine.settle) — the host reads back step n and sends
+        # its tokens while the chip runs step n + 1.  What the mirror
+        # cannot know yet arrives one program late: a request that ended by
+        # EOS, cancel or deadline has one more row in the step in flight,
+        # whose token is dropped at collection.
         held = []            # staged requests inside the coalescing window
         window_start = None
         while not self._stop.is_set():
@@ -407,32 +437,42 @@ class Scheduler:
             # -- the iteration boundary: cancelled / past-deadline slots
             # free HERE, before admission sees the free-slot count — a
             # disconnected client's request stops costing decode steps
-            # after at most one iteration
+            # after at most two iterations (the step launched ahead of
+            # this boundary still carries its row)
             if not self._sweep_once():
                 break
-            # -- pull staged arrivals (never beyond the free slots) ----------
-            while len(held) < self.engine.free_slots():
-                try:
-                    held.append(self._staged.get_nowait())
-                except queue.Empty:
-                    break
-            if held and window_start is None:
-                window_start = _now()
-            busy = not self.engine.idle()
-            window_over = window_start is not None and (
-                _now() - window_start >= self.batch_window
-                or len(held) >= self.engine.free_slots())
             # -- admission, between decode iterations ------------------------
             # a busy pool admits immediately (the iteration boundary IS the
             # batching point); an idle pool holds the first prefill for up
-            # to batch_window so closely-spaced arrivals group up
-            if held and (busy or window_over or self.batch_window <= 0):
-                for req in held:
-                    self._admit(req)
-                held, window_start = [], None
-                busy = not self.engine.idle()
+            # to batch_window so closely-spaced arrivals group up.  Each
+            # admission's collection may free slots: staged arrivals are
+            # pulled again for them, a pool's worth at most per iteration
+            admitted = 0
+            while not self._stop.is_set():
+                # pull staged arrivals (never beyond the free slots)
+                while len(held) < self.engine.free_slots():
+                    try:
+                        held.append(self._staged.get_nowait())
+                    except queue.Empty:
+                        break
+                if held and window_start is None:
+                    window_start = _now()
+                window_over = window_start is not None and (
+                    _now() - window_start >= self.batch_window
+                    or len(held) >= self.engine.free_slots())
+                go = (not self.engine.idle() or window_over
+                      or self.batch_window <= 0)
+                if not (held and go) or admitted >= self.engine.num_slots:
+                    break
+                admitted += 1
+                if not self._admit(held.pop(0)):
+                    break
+            if self._stop.is_set():
+                break
+            if not held:
+                window_start = None
             # -- one decode iteration over the pool --------------------------
-            if busy:
+            if not self.engine.idle():
                 if not self._step_once():
                     break
             elif held:

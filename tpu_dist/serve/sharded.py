@@ -63,7 +63,8 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from ..nn import cache as kvcache
-from .engine import Request, ServeError, SlotEngine, sample_tokens
+from .engine import (Request, ServeError, SlotEngine, advance_rows,
+                     masked_rows, sample_tokens, set_row)
 
 __all__ = ["ShardedLM", "ShardedDecoder", "ShardedSlotEngine",
            "ShardFollower", "ShardedParams", "ShardConfigError",
@@ -637,16 +638,29 @@ class ShardedSlotEngine(SlotEngine):
     def _build_programs(self) -> None:
         dec = self.decoder
 
-        # the engine's argument order; a sharded dense model keeps no
-        # routed-row counters, the (empty) set passes through
-        def _decode(params, cache, counters, *args):
-            return (*dec.decode_pool(params, cache, *args), counters)
+        # the engine's argument order and slot state (engine.pool_programs);
+        # a sharded dense model keeps no routed-row counters, the (empty)
+        # set passes through
+        def _decode(params, cache, counters, slots, live, sampling):
+            tokens, lengths, temps = masked_rows(slots, live)
+            nxt, cache = dec.decode_pool(params, cache, tokens, lengths,
+                                         temps, slots["keys"],
+                                         slots["steps"], sampling)
+            return nxt, cache, counters, advance_rows(slots, live, nxt)
 
-        def _prefill(params, cache, counters, *args):
-            return (*dec.prefill_pool(params, cache, *args), counters)
+        def _prefill(params, cache, counters, slots, prompt, length, slot,
+                     temp, key, sampling):
+            tok, cache = dec.prefill_pool(params, cache, prompt, length,
+                                          slot, temp, key, sampling)
+            return tok, cache, counters, set_row(slots, slot, tok, length,
+                                                 temp, key)
 
         self._decode = _decode
         self._prefill = _prefill
+
+    # the plan of a program is on the wire before the program runs: a
+    # launch stays glued to its collection, nothing is ever in flight
+    settle = SlotEngine.collect_all
 
     # -- plan broadcast -------------------------------------------------------
 
@@ -717,11 +731,12 @@ class ShardedSlotEngine(SlotEngine):
             self._poisoned = e
             raise
 
-    def step(self) -> int:
+    def launch_step(self) -> bool:
         self._check_lockstep()
-        if self.active.any():
-            self._bcast({"op": "step"})
-        return super().step()
+        return super().launch_step()
+
+    def _pre_step(self) -> None:
+        self._bcast({"op": "step"})
 
     def _pre_free(self, slots: List[int]) -> None:
         self._bcast({"op": "free", "slots": [int(s) for s in slots]})
