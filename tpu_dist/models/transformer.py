@@ -25,11 +25,15 @@ __all__ = ["TransformerLM", "TransformerBlock"]
 
 def _make_norm(norm: str, dim: int, eps: Optional[float] = None):
     """``eps=None`` keeps the norm class's own default (LayerNorm 1e-5,
-    RMSNorm 1e-6); ViT passes 1e-6 for torchvision parity, OLMoE 1e-5."""
-    if norm not in ("layernorm", "rmsnorm"):
-        raise ValueError(f"Unknown norm {norm!r} (layernorm|rmsnorm)")
-    cls = nn.LayerNorm if norm == "layernorm" else nn.RMSNorm
-    return cls(dim) if eps is None else cls(dim, eps=eps)
+    RMSNorm 1e-6); ViT passes 1e-6 for torchvision parity, OLMoE 1e-5.
+    ``"rmsnorm_zc"`` is the zero-centred RMSNorm, ``x_hat * (1 + w)``."""
+    if norm not in ("layernorm", "rmsnorm", "rmsnorm_zc"):
+        raise ValueError(f"Unknown norm {norm!r} "
+                         f"(layernorm|rmsnorm|rmsnorm_zc)")
+    kw = {} if eps is None else {"eps": eps}
+    if norm == "layernorm":
+        return nn.LayerNorm(dim, **kw)
+    return nn.RMSNorm(dim, zero_centered=norm == "rmsnorm_zc", **kw)
 
 
 class TransformerBlock(nn.Module):
@@ -38,12 +42,17 @@ class TransformerBlock(nn.Module):
                  mlp: Optional[nn.Module] = None, norm: str = "layernorm",
                  rope: bool = False, rope_theta: float = 10000.0,
                  norm_eps: Optional[float] = None, attn_bias: bool = True,
-                 qk_norm: bool = False):
+                 qk_norm: bool = False, mixer: Optional[nn.Module] = None):
+        """``mixer`` overrides the token mixer (the ``attn`` submodule)
+        with a module built by the caller, as ``mlp`` overrides the MLP:
+        e.g. an :class:`nn.GatedDeltaNet`, or an attention layer spelled
+        beyond this constructor's flags.  A mixer that serves from a slot
+        cache has ``init_cache(batch, max_len, dtype)``."""
         super().__init__()
         self.ln1 = _make_norm(norm, dim, norm_eps)
         # qk_norm: an RMSNorm over the whole q and k projections, with the
         # block's own eps (OLMoE)
-        self.attn = nn.MultiheadSelfAttention(
+        self.attn = mixer if mixer is not None else nn.MultiheadSelfAttention(
             dim, num_heads, bias=attn_bias, causal=causal,
             sequence_axis=sequence_axis, mode=mode, rope=rope,
             rope_theta=rope_theta, qk_norm=qk_norm,
@@ -107,14 +116,11 @@ class TransformerLM(nn.Module):
         super().__init__()
         if num_experts > 0 and moe_every < 1:
             raise ValueError(f"moe_every must be >= 1, got {moe_every}")
-        self.vocab_size = vocab_size
-        self.max_seq_len = max_seq_len
         self.num_experts = num_experts
-        self.tok = nn.Embedding(vocab_size, dim)
-        self.pos = None if rope else nn.Embedding(max_seq_len, dim)
-        for i in range(depth):
+
+        def block(i):
             moe = (num_experts > 0 and i % moe_every == moe_every - 1)
-            setattr(self, f"block{i}", TransformerBlock(
+            return TransformerBlock(
                 dim, num_heads, causal=causal,
                 sequence_axis=sequence_axis, mode=mode, norm=norm,
                 rope=rope, rope_theta=rope_theta, norm_eps=norm_eps,
@@ -124,8 +130,30 @@ class TransformerLM(nn.Module):
                                 capacity_factor=moe_capacity_factor,
                                 normalize_gates=moe_normalize_gates,
                                 dispatch=moe_dispatch, gated=moe_gated)
-                if moe else None))
-        self.depth = depth
+                if moe else None)
+
+        self._assemble(vocab_size, dim, max_seq_len,
+                       [block(i) for i in range(depth)],
+                       ln_f=_make_norm(norm, dim, norm_eps),
+                       head=nn.Linear(dim, vocab_size), learned_pos=not rope,
+                       causal=causal, sequence_axis=sequence_axis,
+                       remat=remat)
+
+    def _assemble(self, vocab_size: int, dim: int, max_seq_len: int, blocks,
+                  ln_f, head, learned_pos: bool, causal: bool = True,
+                  sequence_axis: Optional[str] = None, remat: bool = False):
+        """Register the model's parts.  A model spelled beyond
+        ``__init__``'s flags (models/qwen3_next.py) builds its own blocks,
+        each a :class:`TransformerBlock` whose ``attn`` is any token mixer,
+        and shares everything below: embedding, forward, the slot cache,
+        the pool programs' two methods and :meth:`generate`."""
+        self.vocab_size = vocab_size
+        self.max_seq_len = max_seq_len
+        self.tok = nn.Embedding(vocab_size, dim)
+        self.pos = nn.Embedding(max_seq_len, dim) if learned_pos else None
+        for i, blk in enumerate(blocks):
+            setattr(self, f"block{i}", blk)
+        self.depth = len(blocks)
         self.causal = causal
         self.sequence_axis = sequence_axis
         # remat=True wraps each block in jax.checkpoint: activations inside
@@ -134,8 +162,12 @@ class TransformerLM(nn.Module):
         # (per-layer residual-boundary policy, like torch's
         # checkpoint_sequential over blocks)
         self.remat = remat
-        self.ln_f = _make_norm(norm, dim, norm_eps)
-        self.head = nn.Linear(dim, vocab_size)
+        self.ln_f = ln_f
+        self.head = head
+
+    def _mixers(self):
+        """Each block's token mixer, in order."""
+        return [getattr(self, f"block{i}").attn for i in range(self.depth)]
 
     def embed_tokens(self, idx, pos_offset=None):
         """Token (+ learned positional) embeddings for ``idx`` (B, T) —
@@ -193,8 +225,7 @@ class TransformerLM(nn.Module):
         ctx = current_context()
         if ctx is None or not ctx.state:
             return False
-        return any(getattr(self, f"block{i}").attn._path in ctx.state
-                   for i in range(self.depth))
+        return any(mixer._path in ctx.state for mixer in self._mixers())
 
     # -- autoregressive inference ------------------------------------------
 
@@ -212,12 +243,13 @@ class TransformerLM(nn.Module):
 
     def init_slot_cache(self, slots: int, max_len: Optional[int] = None,
                         dtype=jnp.float32):
-        """KV-cache pool for slot-based continuous-batching decode, in the
-        format nn/cache.py owns: per attention layer, keyed by module path,
-        what the layer keeps per slot (``k``/``v`` ``(slots, H, D,
-        max_len)``, time last: the layout the TPU compiler keeps the pool
-        in, written and read in place;
-        :meth:`nn.MultiheadSelfAttention.init_cache`).  No write position
+        """Cache pool for slot-based continuous-batching decode, in the
+        format nn/cache.py owns: per token mixer, keyed by module path,
+        what the layer keeps per slot: an attention layer's ``k``/``v``
+        ``(slots, Hkv, D, max_len)``, time last (the layout the TPU
+        compiler keeps the pool in, written and read in place;
+        :meth:`nn.MultiheadSelfAttention.init_cache`), a recurrent layer's
+        whole state (:meth:`nn.GatedDeltaNet.init_cache`).  No write position
         is stored: each call to :meth:`decode_step` supplies every slot's
         as the ``lengths`` vector, so the host-side engine
         (:class:`tpu_dist.serve.SlotEngine`) holds the single source of
@@ -232,9 +264,16 @@ class TransformerLM(nn.Module):
                              "tokens and cannot be decoded incrementally")
         max_len = self.max_seq_len if max_len is None else max_len
         self._assign_paths()
-        return {attn._path: attn.init_cache(slots, max_len, dtype)
-                for attn in (getattr(self, f"block{i}").attn
-                             for i in range(self.depth))}
+        return {mixer._path: mixer.init_cache(slots, max_len, dtype)
+                for mixer in self._mixers()}
+
+    def slot_decode_kernel(self, cache) -> bool:
+        """Whether a decode step over the pool ``cache`` takes the Pallas
+        decode-attention kernel in EVERY attention layer (each layer's own
+        answer, :meth:`nn.MultiheadSelfAttention.takes_slot_kernel`)."""
+        return all(mixer.takes_slot_kernel(cache[mixer._path])
+                   for mixer in self._mixers()
+                   if isinstance(mixer, nn.MultiheadSelfAttention))
 
     def init_moe_counters(self):
         """Routed-row counters for serving a model with expert layers, one

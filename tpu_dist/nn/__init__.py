@@ -3,7 +3,8 @@ SURVEY.md §1)."""
 
 from . import cache, functional, init
 from .attention import (MultiheadSelfAttention, attention_impl, rotary_embed,
-                        scaled_dot_product_attention, slot_decode_kernel)
+                        scaled_dot_product_attention)
+from .deltanet import GatedDeltaNet
 from .layers import (AdaptiveAvgPool2d, AvgPool2d, BatchNorm2d, Conv2d,
                      Dropout, Embedding, Flatten, GELU, Identity, LayerNorm,
                      Linear, MaxPool2d, ReLU, RMSNorm)
@@ -20,7 +21,7 @@ __all__ = [
     "ReLU", "Flatten", "Dropout", "BatchNorm2d", "Identity",
     "Embedding", "LayerNorm", "RMSNorm", "GELU",
     "MultiheadSelfAttention", "scaled_dot_product_attention",
-    "attention_impl", "MoELayer", "rotary_embed", "slot_decode_kernel",
+    "attention_impl", "GatedDeltaNet", "MoELayer", "rotary_embed",
     "CrossEntropyLoss",
     "QuantEmbedding", "QuantLinear", "QuantMultiheadSelfAttention",
     "quantize_linear_weights",

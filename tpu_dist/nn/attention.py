@@ -20,13 +20,14 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from . import functional as F
 from .module import Module
 from . import init as I
 
 __all__ = ["scaled_dot_product_attention", "MultiheadSelfAttention",
-           "attention_impl", "rotary_embed", "slot_decode_kernel"]
+           "attention_impl", "rotary_embed"]
 
 _IMPL_OVERRIDE: list = []
 
@@ -58,7 +59,10 @@ def attention_impl(impl: str):
 def scaled_dot_product_attention(q, k, v, causal: bool = False,
                                  mask: Optional[jax.Array] = None,
                                  impl: Optional[str] = None):
-    """Attention.  ``q,k,v``: (..., T, H, D) → (..., T, H, D).
+    """Attention.  ``q,k,v``: (..., T, H, D) → (..., T, H, D).  ``k`` and
+    ``v`` may hold fewer heads than ``q`` (grouped queries: K/V head ``j``
+    serves query heads ``[j * G, (j + 1) * G)``); they are then repeated
+    for the computation, which is the dense one.
 
     ``mask``: broadcastable to (..., H, Tq, Tk), True = keep.
 
@@ -71,6 +75,9 @@ def scaled_dot_product_attention(q, k, v, causal: bool = False,
     constant); dense elsewhere (the kernel runs interpreted off-TPU —
     correct but slower than XLA's fused dense path).
     """
+    if k.shape[-2] != q.shape[-2]:
+        group = q.shape[-2] // k.shape[-2]
+        k, v = (jnp.repeat(a, group, axis=-2) for a in (k, v))
     if impl in (None, "auto"):
         if _IMPL_OVERRIDE:
             impl = _IMPL_OVERRIDE[-1]
@@ -102,12 +109,19 @@ def scaled_dot_product_attention(q, k, v, causal: bool = False,
     return jnp.einsum("...hqk,...khd->...qhd", w, v)
 
 
-def rotary_embed(x, positions, theta: float = 10000.0):
+def rotary_embed(x, positions, theta: float = 10000.0, rotary_dim=None):
     """Rotate ``x`` (..., T, H, D) by per-position angles — RoPE (Su et al.,
     arXiv:2104.09864), rotate-half convention.  ``positions``: (T,) int
     absolute positions; attention scores then depend only on relative
     distance, so no learned position table is needed and contexts
-    extrapolate.  Angles computed in f32, result cast back to x.dtype."""
+    extrapolate.  Angles computed in f32, result cast back to x.dtype.
+    ``rotary_dim`` < D rotates the first ``rotary_dim`` dims of each head
+    as a head of that size and leaves the others untouched (a partial
+    rotary factor)."""
+    if rotary_dim is not None and rotary_dim < x.shape[-1]:
+        return jnp.concatenate(
+            [rotary_embed(x[..., :rotary_dim], positions, theta),
+             x[..., rotary_dim:]], axis=-1)
     d = x.shape[-1]
     half = d // 2
     freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) * 2.0 / d)
@@ -124,23 +138,6 @@ def rotary_embed(x, positions, theta: float = 10000.0):
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                           axis=-1)
     return out.astype(x.dtype)
-
-
-def slot_decode_kernel(entry) -> bool:
-    """Whether a slot-decode step (a vector ``index``, one new position a
-    slot) over one layer's pool ``entry`` takes the Pallas kernel
-    (tpu_dist.ops.decode_attention) or the dense branch of
-    :meth:`MultiheadSelfAttention._decode`.  Chosen as
-    :func:`scaled_dot_product_attention` chooses flash: by what can be
-    observed — a float pool whose ``D`` fills whole sublane tiles and whose
-    ``Tmax`` fills whole lanes, on a TPU backend (interpreted, the kernel
-    would make every served token cost seconds) — with the trace-scoped
-    :func:`attention_impl` (``"flash"`` / ``"dense"``) as the override.
-    The engine asks here which branch its decode program was built on."""
-    from ..ops.decode_attention import decode_attention_ok
-    impl = (_IMPL_OVERRIDE[-1] if _IMPL_OVERRIDE
-            else "flash" if jax.default_backend() == "tpu" else "dense")
-    return impl == "flash" and decode_attention_ok(entry["k"])
 
 
 def _write_columns(pool, new, index):
@@ -173,25 +170,54 @@ class MultiheadSelfAttention(Module):
     attention — ``mode='ring'`` rotates KV blocks around the ring
     (ring attention), ``mode='ulysses'`` redistributes heads via all-to-all.
     Results equal the dense computation (tested in tests/test_ring_attention.py).
+
+    ``num_kv_heads`` < ``num_heads`` shares each K/V head among a group of
+    query heads (the cache then holds ``num_kv_heads`` heads), ``head_dim``
+    sets a head's size apart from ``embed_dim // num_heads``,
+    ``rotary_dim`` rotates only the first dims of each head, ``qk_norm=
+    "head"`` normalises q and k over EACH head's dims with one zero-centred
+    weight of ``head_dim`` shared by the heads (``True``: over the whole
+    projection, OLMoE's), and ``gated`` multiplies the attention's output
+    by the sigmoid of a second, query-sized projection of the input before
+    the output projection (Qwen3-Next's full-attention layer).
     """
 
     def __init__(self, embed_dim: int, num_heads: int, bias: bool = True,
                  causal: bool = False, sequence_axis: Optional[str] = None,
                  mode: str = "ring", attn_impl: Optional[str] = None,
                  rope: bool = False, rope_theta: float = 10000.0,
-                 qk_norm: bool = False, qk_norm_eps: float = 1e-6):
+                 qk_norm=False, qk_norm_eps: float = 1e-6,
+                 num_kv_heads: Optional[int] = None,
+                 head_dim: Optional[int] = None,
+                 rotary_dim: Optional[int] = None, gated: bool = False):
         super().__init__()
-        if embed_dim % num_heads:
+        if head_dim is None and embed_dim % num_heads:
             raise ValueError(f"embed_dim {embed_dim} not divisible by "
                              f"num_heads {num_heads}")
+        head_dim = head_dim or embed_dim // num_heads
+        num_kv_heads = num_kv_heads or num_heads
+        if num_heads % num_kv_heads:
+            raise ValueError(f"num_heads {num_heads} not divisible by "
+                             f"num_kv_heads {num_kv_heads}")
         if mode not in ("ring", "ulysses"):
             raise ValueError(f"Unknown sequence-parallel mode {mode!r}")
-        if rope and (embed_dim // num_heads) % 2:
+        if rope and (rotary_dim or head_dim) % 2:
             raise ValueError(f"rotary embeddings need an even head_dim, "
-                             f"got {embed_dim // num_heads}")
+                             f"got {rotary_dim or head_dim}")
+        if qk_norm not in (False, True, "head"):
+            raise ValueError(f"qk_norm must be False, True or 'head', got "
+                             f"{qk_norm!r}")
+        if sequence_axis is not None and num_kv_heads != num_heads:
+            raise ValueError("sequence-parallel attention has no "
+                             "grouped-query form")
         self.embed_dim = embed_dim
         self.num_heads = num_heads
-        self.head_dim = embed_dim // num_heads
+        self.num_kv_heads = num_kv_heads
+        self.head_dim = head_dim
+        self.q_dim = num_heads * head_dim
+        self.kv_dim = num_kv_heads * head_dim
+        self.rotary_dim = rotary_dim
+        self.gated = gated
         self.bias = bias
         self.causal = causal
         self.sequence_axis = sequence_axis
@@ -199,24 +225,30 @@ class MultiheadSelfAttention(Module):
         self.attn_impl = attn_impl  # None=auto | "dense" | "flash"
         self.rope = rope
         self.rope_theta = rope_theta
-        # OLMoE's QK-norm: an RMSNorm with its own weight over the WHOLE
-        # q and k projections (all heads together), before the head split
-        # and before rope
+        # True, OLMoE's QK-norm: an RMSNorm with its own weight over the
+        # WHOLE q and k projections (all heads together), before the head
+        # split and before rope.  "head", Qwen3-Next's: over each head's
+        # dims after the split, zero-centred (x_hat * (1 + w))
         self.qk_norm = qk_norm
         self.qk_norm_eps = qk_norm_eps
 
     def create_params(self, key):
         k1, k2 = jax.random.split(key)
+        # one fused matrix, split [q | gate | k | v] (gate only when gated)
+        fused = self.q_dim * (2 if self.gated else 1) + 2 * self.kv_dim
         p = {"qkv_weight": I.torch_default_uniform(
-                 k1, (self.embed_dim, 3 * self.embed_dim), self.embed_dim),
+                 k1, (self.embed_dim, fused), self.embed_dim),
              "out_weight": I.torch_default_uniform(
-                 k2, (self.embed_dim, self.embed_dim), self.embed_dim)}
+                 k2, (self.q_dim, self.embed_dim), self.q_dim)}
         if self.bias:
-            p["qkv_bias"] = jnp.zeros((3 * self.embed_dim,))
+            p["qkv_bias"] = jnp.zeros((fused,))
             p["out_bias"] = jnp.zeros((self.embed_dim,))
-        if self.qk_norm:
-            p["q_norm_weight"] = jnp.ones((self.embed_dim,))
-            p["k_norm_weight"] = jnp.ones((self.embed_dim,))
+        if self.qk_norm == "head":
+            p["q_norm_weight"] = jnp.zeros((self.head_dim,))
+            p["k_norm_weight"] = jnp.zeros((self.head_dim,))
+        elif self.qk_norm:
+            p["q_norm_weight"] = jnp.ones((self.q_dim,))
+            p["k_norm_weight"] = jnp.ones((self.kv_dim,))
         return p
 
     def _qkv_proj(self, p, x):
@@ -234,13 +266,26 @@ class MultiheadSelfAttention(Module):
         ctx = _ctx()
         p = ctx.get_params(self._path)
         b, t, _ = x.shape
-        qkv = self._qkv_proj(p, x).reshape(b, t, 3, self.embed_dim)
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-        if self.qk_norm:
+        gate = None
+        if self.gated or self.kv_dim != self.q_dim:
+            # [q | gate | k | v], the gate only when gated
+            sizes = ([self.q_dim] * (2 if self.gated else 1)
+                     + [self.kv_dim, self.kv_dim])
+            q, *gate, k, v = jnp.split(self._qkv_proj(p, x),
+                                       np.cumsum(sizes)[:-1], axis=-1)
+            gate = gate[0] if gate else None
+        else:
+            qkv = self._qkv_proj(p, x).reshape(b, t, 3, self.embed_dim)
+            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+        if self.qk_norm is True:
             q = F.rms_norm(q, p["q_norm_weight"], self.qk_norm_eps)
             k = F.rms_norm(k, p["k_norm_weight"], self.qk_norm_eps)
-        q, k, v = (a.reshape(b, t, self.num_heads, self.head_dim)
-                   for a in (q, k, v))
+        q = q.reshape(b, t, self.num_heads, self.head_dim)
+        k, v = (a.reshape(b, t, self.num_kv_heads, self.head_dim)
+                for a in (k, v))
+        if self.qk_norm == "head":
+            q = F.rms_norm(q, 1.0 + p["q_norm_weight"], self.qk_norm_eps)
+            k = F.rms_norm(k, 1.0 + p["k_norm_weight"], self.qk_norm_eps)
         if self.rope:
             # absolute positions of THESE tokens: the cache write index
             # during decode, the shard offset under sequence parallelism,
@@ -257,8 +302,8 @@ class MultiheadSelfAttention(Module):
             # vector offset = per-slot decode positions: (B,) -> (B, t)
             pos = (off[..., None] + jnp.arange(t) if off.ndim
                    else offset + jnp.arange(t))
-            q = rotary_embed(q, pos, self.rope_theta)
-            k = rotary_embed(k, pos, self.rope_theta)
+            q = rotary_embed(q, pos, self.rope_theta, self.rotary_dim)
+            k = rotary_embed(k, pos, self.rope_theta, self.rotary_dim)
         if ctx.state is not None and self._path in ctx.state:
             # autoregressive decode: a KV cache was allocated for this layer
             # (TransformerLM.init_cache) — append this call's K/V at the
@@ -274,7 +319,9 @@ class MultiheadSelfAttention(Module):
         else:
             out = scaled_dot_product_attention(q, k, v, causal=self.causal,
                                                impl=self.attn_impl)
-        out = out.reshape(b, t, self.embed_dim)
+        out = out.reshape(b, t, self.q_dim)
+        if gate is not None:
+            out = out * jax.nn.sigmoid(gate)
         return self._out_proj(p, out)
 
     @staticmethod
@@ -309,7 +356,7 @@ class MultiheadSelfAttention(Module):
         bytes halves the bandwidth bill where it dominates.
 
         A slot-decode step (``index`` a vector, ``t == 1``) over a float
-        pool takes ONE Pallas call where :func:`slot_decode_kernel` says so
+        pool takes ONE Pallas call where :meth:`takes_slot_kernel` says so
         (tpu_dist.ops.decode_attention: it reads only each slot's resident
         blocks and writes only the block of the new column; a slot of
         length 0 is free there: untouched, output zero).  Prefill, a
@@ -327,7 +374,8 @@ class MultiheadSelfAttention(Module):
         # (B, t, ...) -> (B, ..., t): the stored order, time last
         new = {key: jnp.moveaxis(val, 1, -1).astype(st[key].dtype)
                for key, val in new.items()}
-        if index.ndim == 1 and t == 1 and slot_decode_kernel(st):
+        group = self.num_heads // self.num_kv_heads
+        if index.ndim == 1 and t == 1 and self.takes_slot_kernel(st):
             from ..ops.decode_attention import decode_attention
             with jax.named_scope("attend"):
                 out, k_pool, v_pool = decode_attention(
@@ -370,6 +418,18 @@ class MultiheadSelfAttention(Module):
             # and softmax in the compute dtype; the int8 cache keeps float32
             # scores and its scales hoisted out of both matmuls.
             acc = jnp.float32 if int8_cache else None
+            if group > 1:
+                # K/V head j serves query heads [j * G, (j + 1) * G): the
+                # G x t query rows of a group are one K/V head's batch
+                b, heads = q.shape[0], self.num_kv_heads
+                qg = q.reshape(b, t, heads, group, self.head_dim)
+                s = jnp.einsum("btkgd,bkds->bkgts", qg,
+                               st["k"].astype(q.dtype)
+                               ) / math.sqrt(self.head_dim)
+                w = jax.nn.softmax(jnp.where(mask[:, :, None], s, -jnp.inf),
+                                   axis=-1)
+                return jnp.einsum("bkgts,bkds->btkgd", w,
+                                  st["v"].astype(q.dtype)).reshape(q.shape)
             s = jnp.einsum("bthd,bhds->bhts", q, st["k"].astype(q.dtype),
                            preferred_element_type=acc
                            ) / math.sqrt(self.head_dim)
@@ -381,10 +441,29 @@ class MultiheadSelfAttention(Module):
             return jnp.einsum("bhts,bhds->bthd", w, st["v"].astype(q.dtype),
                               preferred_element_type=acc).astype(q.dtype)
 
+    def takes_slot_kernel(self, entry) -> bool:
+        """Whether a slot-decode step (a vector ``index``, one new position
+        a slot) of THIS layer over its pool ``entry`` takes the Pallas
+        kernel (tpu_dist.ops.decode_attention) or the dense branch of
+        :meth:`_decode`.  Chosen as :func:`scaled_dot_product_attention`
+        chooses flash: by what can be observed — one query row a K/V head,
+        which is the kernel's form (grouped queries stay dense: PERF.md,
+        PR 30), a float pool whose ``D`` fills whole sublane tiles and
+        whose ``Tmax`` fills whole lanes, on a TPU backend (interpreted,
+        the kernel would make every served token cost seconds) — with the
+        trace-scoped :func:`attention_impl` (``"flash"`` / ``"dense"``) as
+        the override.  The engine asks the model, which asks here, which
+        branch its decode program was built on."""
+        from ..ops.decode_attention import decode_attention_ok
+        impl = (_IMPL_OVERRIDE[-1] if _IMPL_OVERRIDE
+                else "flash" if jax.default_backend() == "tpu" else "dense")
+        return (self.num_kv_heads == self.num_heads and impl == "flash"
+                and decode_attention_ok(entry["k"]))
+
     def init_cache(self, batch: int, max_len: int, dtype=jnp.float32):
         """What this layer keeps per slot (one entry of a nn/cache.py tree,
         via TransformerLM.init_slot_cache): ``k``, ``v`` of shape
-        ``(B, H, D, Tmax)``, time LAST.  That is the
+        ``(B, Hkv, D, Tmax)``, time LAST.  That is the
         layout the TPU compiler keeps such a pool in at rest whatever its
         logical shape (``Tmax`` in the 128 lanes, ``D`` in the sublanes:
         unpadded for bf16 whenever ``D % 16 == 0`` and ``Tmax % 128 == 0``,
@@ -394,7 +473,10 @@ class MultiheadSelfAttention(Module):
         ``dtype=jnp.int8`` allocates the quantized cache: int8 K/V plus
         float32 per-(token, head) scales ``(B, H, Tmax)`` (see
         :meth:`_decode`)."""
-        shape = (batch, self.num_heads, self.head_dim, max_len)
+        if jnp.dtype(dtype) == jnp.int8 and self.num_kv_heads != \
+                self.num_heads:
+            raise ValueError("the int8 cache has no grouped-query form")
+        shape = (batch, self.num_kv_heads, self.head_dim, max_len)
         cache = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
         if jnp.dtype(dtype) == jnp.int8:
             cache["k_scale"] = jnp.zeros((batch, self.num_heads, max_len),

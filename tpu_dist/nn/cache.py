@@ -2,20 +2,35 @@
 addresses it, and how rows are written, cut and joined along time.
 
 A cache (a pool of slots, or one request's batch-1 *rows*) is a tree
-``{layer path: {name: leaf}}`` of RESIDENT leaves only: ``k``/``v``
-``(B, H, D, Tmax)`` and, for the int8 cache, ``k_scale``/``v_scale``
-``(B, H, Tmax)`` (:meth:`MultiheadSelfAttention.init_cache` builds them and
-says why time is last).  What a single call adds travels beside it and is
-put in and taken out HERE and nowhere else:
+``{layer path: {name: leaf}}`` of RESIDENT leaves only, of two kinds told
+apart by name (:func:`is_timed`):
+
+- TIME-INDEXED leaves, one column a position: ``k``/``v`` ``(B, Hkv, D,
+  Tmax)`` and, for the int8 cache, ``k_scale``/``v_scale`` ``(B, H, Tmax)``
+  (:meth:`MultiheadSelfAttention.init_cache` builds them and says why time
+  is last);
+- a slot's WHOLE STATE, with no time axis: any other name, such as
+  :class:`GatedDeltaNet`'s ``state`` ``(B, Hv, Dk, Dv)`` float32 and its
+  convolution tail ``conv`` (:meth:`GatedDeltaNet.init_cache`).  A layer
+  replaces such a leaf entire at every call; it cannot be cut, padded or
+  joined along time, and the functions below that do so pass it through
+  or refuse it, each as its docstring says.
+
+What a single call adds travels beside it and is put in and taken out HERE
+and nowhere else:
 
 - ``index``, the call's write position, read by
   :meth:`MultiheadSelfAttention._decode` from its own entry: a scalar when
   every row writes at one position (a whole prompt), a ``(B,)`` vector for a
   slot step where each row stands at its own;
+- ``valid``, the call's mask of positions that are a request's (``(B, t)``
+  or None for all): a recurrent layer leaves its state as it was over the
+  others (bucket padding, a free slot's row), which causal attention need
+  not care about;
 - the routed-row counters of the expert layers
   (:meth:`MoELayer.init_counters`), a tree of their own keyed by the
-  ``MoELayer`` paths, with ``valid``, the call's mask of rows that are a
-  request's, read by :meth:`MoELayer._count_rows`.
+  ``MoELayer`` paths, with the same ``valid``, read by
+  :meth:`MoELayer._count_rows`.
 
 Three groups of functions: the layout (time is the last axis; pad, cut, join
 and describe a tree along it), the call (:func:`call_state` /
@@ -30,21 +45,72 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["time_axis", "time_slice", "extent", "pad_time", "join_time",
-           "token_template", "call_state", "split_state", "write_slot_rows"]
+__all__ = ["is_timed", "state_leaves", "require_timed", "kv_entries",
+           "slot_bytes", "time_axis", "time_slice", "extent", "pad_time",
+           "join_time", "token_template", "call_state", "split_state",
+           "write_slot_rows"]
+
+_TIMED = frozenset({"k", "v", "k_scale", "v_scale"})
 
 
 # -- the layout ---------------------------------------------------------------
 
+def is_timed(name: str) -> bool:
+    """Whether the leaf of this name is indexed by time (one column a
+    position) or is a slot's whole state."""
+    return name in _TIMED
+
+
+def state_leaves(cache) -> list:
+    """``["path.name", ...]`` of the leaves that are a slot's whole state
+    (empty for a model of attention layers alone)."""
+    return [f"{path}.{name}" for path, entry in cache.items()
+            for name in entry if not is_timed(name)]
+
+
+def require_timed(cache, who: str) -> None:
+    """Refuse, by the leaf's name, a cache that ``who`` cannot move: the
+    host-side movers (serve/prefix, kvtransfer, disagg, sharded) cut, join
+    and ship rows ALONG TIME, and a state leaf has no time axis.  Reusing
+    or shipping it takes a snapshot of the state at the cut, which they do
+    not have yet."""
+    found = state_leaves(cache)
+    if found:
+        raise NotImplementedError(
+            f"{who} moves cache rows along time, and leaf {found[0]!r} is a "
+            f"slot's whole state with no time axis ({len(found)} such "
+            f"leaves); it needs state snapshots, which it does not have")
+
+
+def kv_entries(cache) -> list:
+    """The entries that hold a K/V pool (the attention layers')."""
+    return [entry for entry in cache.values() if "k" in entry]
+
+
+def slot_bytes(cache) -> tuple:
+    """``(bytes of whole state a slot holds, bytes a resident position
+    holds)`` over all layers: what a decode step must read AND write of a
+    busy slot whatever its length, and what it reads per position held."""
+    state = per_pos = 0
+    for entry in cache.values():
+        for name, leaf in entry.items():
+            row = leaf.dtype.itemsize * int(np.prod(leaf.shape[1:]))
+            if is_timed(name):
+                per_pos += row // leaf.shape[time_axis(leaf)]
+            else:
+                state += row
+    return state, per_pos
+
+
 def time_axis(leaf) -> int:
-    """The time axis of a resident leaf: the LAST one, for ``k``/``v`` and
-    the int8 scales alike."""
+    """The time axis of a time-indexed leaf: the LAST one, for ``k``/``v``
+    and the int8 scales alike.  (A state leaf has none.)"""
     return leaf.ndim - 1
 
 
 def time_slice(leaf, lo, hi):
-    """Columns ``[lo, hi)`` of a leaf along its time axis (a view, clipped
-    to the leaf's extent like any slice)."""
+    """Columns ``[lo, hi)`` of a time-indexed leaf along its time axis (a
+    view, clipped to the leaf's extent like any slice)."""
     idx = [slice(None)] * leaf.ndim
     idx[time_axis(leaf)] = slice(lo, hi)
     return leaf[tuple(idx)]
@@ -53,19 +119,27 @@ def time_slice(leaf, lo, hi):
 def extent(cache):
     """``(max_len, dtype)`` of a pool or row tree: the positions a slot
     holds and the type its K/V are stored in, what
-    ``init_slot_cache(batch, max_len, dtype)`` was given."""
-    k = next(iter(cache.values()))["k"]
+    ``init_slot_cache(batch, max_len, dtype)`` was given.  Read from the
+    first K/V pool; state leaves keep types of their own."""
+    entries = kv_entries(cache)
+    if not entries:
+        raise ValueError("extent() reads a K/V pool, and this cache holds "
+                         f"none (leaves: {state_leaves(cache)})")
+    k = entries[0]["k"]
     return k.shape[time_axis(k)], k.dtype
 
 
-def _map_leaves(fn, tree):
-    return {path: {name: fn(leaf) for name, leaf in entry.items()}
+def _map_leaves(fn, tree, state=lambda leaf: leaf):
+    """``fn`` over the time-indexed leaves, ``state`` over the others."""
+    return {path: {name: (fn if is_timed(name) else state)(leaf)
+                   for name, leaf in entry.items()}
             for path, entry in tree.items()}
 
 
 def pad_time(rows, total: int):
     """Host rows zero-padded along time to ``total`` columns (a bucket, or
-    the whole ``max_len``): the fixed shape one compiled program takes."""
+    the whole ``max_len``): the fixed shape one compiled program takes.  A
+    state leaf has one shape whatever the bucket and passes through."""
     def pad(leaf):
         width = [(0, 0)] * leaf.ndim
         width[time_axis(leaf)] = (0, total - leaf.shape[time_axis(leaf)])
@@ -76,7 +150,10 @@ def pad_time(rows, total: int):
 
 def join_time(chain):
     """One host row tree from a chain of them, joined along time in order
-    (a prefix-cache hit's blocks)."""
+    (a prefix-cache hit's blocks).  Time-indexed leaves only: the state
+    after a chain is not a join of its blocks' states
+    (:func:`require_timed`)."""
+    require_timed(chain[0], "join_time")
     return {path: {name: np.concatenate([rows[path][name] for rows in chain],
                                         axis=time_axis(leaf))
                    for name, leaf in entry.items()}
@@ -84,21 +161,25 @@ def join_time(chain):
 
 
 def token_template(cache):
-    """``{path: {name: (per-token shape, dtype)}}``: each leaf's shape less
-    its batch and time axes.  Two endpoints that move rows derive it from
-    their own model and compare."""
+    """``{path: {name: (per-token shape, dtype)}}``: each time-indexed
+    leaf's shape less its batch and time axes; a state leaf's whole shape
+    less its batch axis (it is per slot, not per token).  Two endpoints
+    that move rows derive it from their own model and compare."""
+    describe = lambda leaf, shape: (tuple(int(d) for d in shape),
+                                    np.dtype(leaf.dtype))
     return _map_leaves(
-        lambda leaf: (tuple(int(d) for d in leaf.shape[1:time_axis(leaf)]),
-                      np.dtype(leaf.dtype)), cache)
+        lambda leaf: describe(leaf, leaf.shape[1:time_axis(leaf)]), cache,
+        state=lambda leaf: describe(leaf, leaf.shape[1:]))
 
 
 # -- the call -----------------------------------------------------------------
 
 def call_state(cache, index, counters=None, valid=None):
     """The state a forward pass reads (``apply(state=...)``): every entry
-    of ``cache`` with this call's write position ``index``, and every entry
-    of ``counters`` with the call's request mask ``valid``."""
-    state = {path: dict(entry, index=index) for path, entry in cache.items()}
+    of ``cache`` with this call's write position ``index`` and its request
+    mask ``valid``, and every entry of ``counters`` with ``valid``."""
+    state = {path: dict(entry, index=index, valid=valid)
+             for path, entry in cache.items()}
     state.update({path: dict(entry, valid=valid)
                   for path, entry in (counters or {}).items()})
     return state
@@ -106,13 +187,14 @@ def call_state(cache, index, counters=None, valid=None):
 
 def split_state(state, counters=None):
     """``(cache, counters)`` out of the state a forward pass returned: the
-    entries the call addressed, less their advanced ``index``, and the
-    entries at the paths of ``counters``.  Anything else a layer published
-    (a training-time aux loss) is dropped."""
-    strip = lambda entry, name: {k: v for k, v in entry.items() if k != name}
-    return ({path: strip(entry, "index") for path, entry in state.items()
+    entries the call addressed, less their advanced ``index`` and the
+    ``valid`` mask, and the entries at the paths of ``counters``.  Anything
+    else a layer published (a training-time aux loss) is dropped."""
+    strip = lambda entry: {k: v for k, v in entry.items()
+                           if k not in ("index", "valid")}
+    return ({path: strip(entry) for path, entry in state.items()
              if "index" in entry},
-            {path: strip(state[path], "valid") for path in counters or {}})
+            {path: strip(state[path]) for path in counters or {}})
 
 
 # -- the slot write -----------------------------------------------------------
@@ -122,8 +204,9 @@ def write_slot_rows(cache, rows, slot):
     ``cache``, every other slot untouched: the one way rows land in a pool,
     whether the engine prefilled them or a prefill rank sent them
     (serve/disagg.py).  ``rows`` may hold fewer columns than the pool (a
-    bucket's worth lands at column 0).  The update is on the slot axis
-    alone, so it is in place in a donated pool."""
+    bucket's worth lands at column 0); a state leaf lands entire, so
+    nothing of the slot's last request is left.  The update is on the slot
+    axis alone, so it is in place in a donated pool."""
     slot = jnp.asarray(slot, jnp.int32)
     with jax.named_scope("cache_write"):
         return {path: {

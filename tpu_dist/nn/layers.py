@@ -316,26 +316,33 @@ class LayerNorm(Module):
 class RMSNorm(Module):
     """Root-mean-square normalization (torch ``nn.RMSNorm`` parity;
     Zhang & Sennrich, arXiv:1910.07467) — no mean subtraction, no bias,
-    the LLaMA-family default.  Statistics in f32, result in x.dtype."""
+    the LLaMA-family default.  Statistics in f32, result in x.dtype.
+    ``zero_centered`` scales by ``1 + weight`` with the weight initialised
+    to zero (Qwen3-Next's norm), the same function at initialisation."""
 
     def __init__(self, normalized_shape, eps: float = 1e-6,
-                 elementwise_affine: bool = True):
+                 elementwise_affine: bool = True,
+                 zero_centered: bool = False):
         super().__init__()
         if isinstance(normalized_shape, int):
             normalized_shape = (normalized_shape,)
         self.normalized_shape = tuple(normalized_shape)
         self.eps = eps
         self.elementwise_affine = elementwise_affine
+        self.zero_centered = zero_centered
 
     def create_params(self, key):
         if not self.elementwise_affine:
             return None
-        return {"weight": jnp.ones(self.normalized_shape)}
+        fill = jnp.zeros if self.zero_centered else jnp.ones
+        return {"weight": fill(self.normalized_shape)}
 
     def forward(self, x):
         axes = tuple(range(x.ndim - len(self.normalized_shape), x.ndim))
         w = (_ctx().get_params(self._path)["weight"]
              if self.elementwise_affine else None)
+        if w is not None and self.zero_centered:
+            w = 1.0 + w
         return F.rms_norm(x, w, self.eps, axes)
 
     def __repr__(self):

@@ -59,6 +59,15 @@ TPU-first design — routing as dense einsums, not gather/scatter:
   whatever the activations' type (top-k among 64 near-equal probabilities
   is not a bfloat16 decision); ``normalize_gates=False`` uses the selected
   probabilities as they are (OLMoE's ``norm_topk_prob: false``).
+- ``experts_held`` / ``expert_offset`` give the layer ONE CHIP'S SHARE of
+  an expert-parallel deployment: the router keeps its ``num_experts``
+  outputs and its ``top_k`` a token (renormalised over all of them), the
+  layer holds the weights of experts ``[offset, offset + held)`` and adds
+  up the picks that fall on those; a pick of an absent expert is given no
+  row and costs no matmul.  The shares of all chips add up to the whole
+  layer (tests/test_qwen3_next.py); nothing here stands in for the absent
+  chips or their exchange.  ``shared_hidden`` adds a shared expert under
+  its own sigmoid gate, computed for every token, here (scope ``shared``).
 - Inside the layer ``jax.named_scope``s ``route``, ``dispatch``,
   ``experts`` and ``combine`` split a device trace (``python3 -m
   chipbench.scope_reduce``), and while serving the layer counts its routed
@@ -73,6 +82,7 @@ TPU-first design — routing as dense einsums, not gather/scatter:
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -199,12 +209,20 @@ class MoELayer(Module):
         gated: each expert is ``down(silu(gate(x)) * up(x))`` without
             biases, ``hidden`` wide (parameters ``w1``, ``w3``, ``w2``),
             instead of ``w2(gelu(w1 x + b1)) + b2``.
+        shared_hidden: width of a shared gated expert added to every
+            token's output under ``sigmoid(x w_s)`` (parameters
+            ``shared_w1``, ``shared_w3``, ``shared_w2``, ``shared_gate``);
+            0 = none.
+        experts_held / expert_offset: the experts whose weights this
+            layer holds, ``[offset, offset + held)`` of the router's
+            ``num_experts`` (0 = all); dropless dispatch only.
     """
 
     def __init__(self, dim: int, num_experts: int, hidden: int = 0,
                  top_k: int = 2, capacity_factor: float = 1.25,
                  normalize_gates: bool = True, dispatch: str = "einsum",
-                 gated: bool = False):
+                 gated: bool = False, shared_hidden: int = 0,
+                 experts_held: int = 0, expert_offset: int = 0):
         super().__init__()
         if num_experts < 2:
             raise ValueError(f"num_experts must be >= 2, got {num_experts}")
@@ -221,15 +239,48 @@ class MoELayer(Module):
         self.normalize_gates = normalize_gates
         self.dispatch = dispatch
         self.gated = gated
+        self.shared_hidden = shared_hidden
+        self.experts_held = experts_held or num_experts
+        self.expert_offset = expert_offset
+        if self.experts_held != num_experts:
+            if dispatch != "dropless":
+                raise ValueError("a share of the experts is computed by the "
+                                 "dropless dispatch alone")
+            if not (0 <= expert_offset
+                    and expert_offset + self.experts_held <= num_experts):
+                raise ValueError(
+                    f"experts [{expert_offset}, {expert_offset} + "
+                    f"{self.experts_held}) are not among {num_experts}")
+
+    def _shared_params(self, key):
+        if not self.shared_hidden:
+            return {}
+        ks = jax.random.split(jax.random.fold_in(key, 7), 4)
+        d, h = self.dim, self.shared_hidden
+        return {"shared_w1": init_lib.torch_default_uniform(ks[0], (d, h), d),
+                "shared_w3": init_lib.torch_default_uniform(ks[1], (d, h), d),
+                "shared_w2": init_lib.torch_default_uniform(ks[2], (h, d), h),
+                "shared_gate": init_lib.torch_default_uniform(ks[3], (d, 1),
+                                                              d)}
 
     def create_params(self, key):
         kr, k1, k2 = jax.random.split(key, 3)
-        e, d, h = self.num_experts, self.dim, self.hidden
+        # the router spans all experts, the weights those held here
+        n_out, e, d, h = (self.num_experts, self.experts_held, self.dim,
+                          self.hidden)
 
         def expert_uniform(k, shape, fan_in):
             # kaiming_uniform per expert: stacked (E, in, out) weights get
             # the same bound a (in, out) Linear would (init.calculate_fan
-            # only knows 2-D/4-D shapes)
+            # only knows 2-D/4-D shapes).  Beside a shared expert the
+            # routed ones are drawn as IT is, U(+-1/sqrt(fan_in)), so that
+            # one routed expert weighs what the shared one does (published
+            # code draws both alike); at the kaiming bound a routed expert's
+            # output is 6 ** 1.5 = 14.7 times the shared one's and the
+            # swap of a token's least pick moves its logits more than the
+            # arithmetic's precision does (PERF.md, PR 30)
+            if self.shared_hidden:
+                return init_lib.torch_default_uniform(k, shape, fan_in)
             bound = math.sqrt(6.0 / fan_in)
             return init_lib.uniform(k, shape, -bound, bound)
 
@@ -237,18 +288,20 @@ class MoELayer(Module):
             # w1 = gate, w3 = up, w2 = down (the LLaMA-family names); no
             # biases, as every published gated expert has none
             return {
-                "router": init_lib.kaiming_uniform(kr, (d, e)),
+                "router": init_lib.kaiming_uniform(kr, (d, n_out)),
                 "w1": expert_uniform(k1, (e, d, h), d),
                 "w3": expert_uniform(jax.random.fold_in(key, 3), (e, d, h),
                                      d),
                 "w2": expert_uniform(k2, (e, h, d), h),
+                **self._shared_params(key),
             }
         return {
-            "router": init_lib.kaiming_uniform(kr, (d, e)),
+            "router": init_lib.kaiming_uniform(kr, (d, n_out)),
             "w1": expert_uniform(k1, (e, d, h), d),
             "b1": jnp.zeros((e, h)),
             "w2": expert_uniform(k2, (e, h, d), h),
             "b2": jnp.zeros((e, d)),
+            **self._shared_params(key),
         }
 
     def create_state(self):
@@ -265,10 +318,22 @@ class MoELayer(Module):
         from .module import _ctx
         ctx = _ctx()
         p = ctx.get_params(self._path)
-        e, k = self.num_experts, self.top_k
         lead, d = x.shape[:-1], x.shape[-1]
         xt = x.reshape(-1, d)
-        n = xt.shape[0]
+        y = self._routed(ctx, p, xt)
+        if self.shared_hidden:
+            with jax.named_scope("shared"):
+                hdn = (jax.nn.silu(xt @ p["shared_w1"])
+                       * (xt @ p["shared_w3"]))
+                y = y + (jax.nn.sigmoid(xt @ p["shared_gate"])
+                         * (hdn @ p["shared_w2"]))
+        return y.reshape(*lead, d)
+
+    def _routed(self, ctx, p, xt):
+        """The routed experts' part of the output for rows ``xt`` (N, d):
+        the picks that fall on the experts held here."""
+        e, k = self.experts_held, self.top_k
+        n, d = xt.shape
         c = self._capacity(n)
 
         with jax.named_scope("route"):
@@ -290,13 +355,23 @@ class MoELayer(Module):
             # chosen expert; indices >= capacity are dropped.  Bookkeeping
             # runs in int32 no matter what xt's dtype is: a bf16 cumsum
             # rounds positions past 256 and mis-slots tokens.
-            oh_i = jax.nn.one_hot(gate_idx.T, e, dtype=jnp.int32)  # (k,N,E)
+            # Experts are numbered from the first one HELD: a pick of an
+            # absent expert (index < 0 or >= e) has an all-zero one-hot
+            # row, so it takes no position and counts for no expert.
+            local_idx = gate_idx - self.expert_offset
+            oh_i = jax.nn.one_hot(local_idx.T, e, dtype=jnp.int32)  # (k,N,E)
             flat = oh_i.reshape(k * n, e)
             pos = (jnp.cumsum(flat, axis=0) - flat)              # (k*N, E)
             pos = (pos * flat).sum(-1).reshape(k, n)             # (k, N)
+            counts = oh_i.sum((0, 1))                            # (E,)
+            if self.dispatch == "dropless":
+                b = self._block_rows(k * n, xt.dtype)
+                computed = (((counts + b - 1) // b) * b).sum()
+            else:
+                computed = e * c
             # serving counts its routed rows; training publishes the
             # load-balancing loss (the state entry holds one or the other)
-            if not self._count_rows(ctx, oh_i):
+            if not self._count_rows(ctx, gate_idx, oh_i, computed):
                 self._put_switch_aux(xt, probs.astype(xt.dtype), gate_idx)
 
         if self.dispatch == "dropless":
@@ -304,10 +379,9 @@ class MoELayer(Module):
             # cumsum doubles as a counting sort, so no argsort is needed
             # (measured ~5 ms for a 16k-row argsort on v5e, dwarfing the
             # expert matmuls themselves)
-            counts = oh_i.sum((0, 1))                            # (E,)
-            y = self._forward_dropless(p, xt, gate_vals, gate_idx, pos,
-                                       counts)
-            return y.reshape(*lead, d)
+            held = (local_idx >= 0) & (local_idx < e)            # (N, k)
+            return self._forward_dropless(p, xt, gate_vals, local_idx, held,
+                                          pos, counts, b)
 
         keep = (pos < c).astype(xt.dtype)                        # (k, N)
 
@@ -348,7 +422,7 @@ class MoELayer(Module):
                                   choice_for_slot, slot)
             else:
                 y = jnp.einsum("nec,ecd->nd", combine_t, out)
-        return y.reshape(*lead, d)
+        return y
 
     def _experts(self, p, xs, linear):
         """The expert FFN over rows already in expert order, ``linear(rows,
@@ -365,36 +439,50 @@ class MoELayer(Module):
     def init_counters(self):
         """Routed-row counters for serving, this layer's entry of the
         counter tree (``TransformerLM.init_moe_counters``): ``rows`` (E,)
-        routed rows per expert that belong to a request, ``pad_rows`` routed
-        rows that belong to none (free slots in a decode step, bucket padding
-        in a prefill), ``calls`` of the layer, and ``experts_hit``, the
-        experts with a request's row summed over calls.  int32: a reader
-        takes differences modulo 2**32."""
+        picks per ROUTER expert that belong to a request (held or not),
+        ``held_rows`` those of them that fell on an expert held here (all,
+        for a layer that holds every expert), ``pad_rows`` picks that
+        belong to no request (free slots in a decode step, bucket padding in
+        a prefill), ``computed_rows`` the rows the expert matmuls ran over
+        (every held pick, a request's or not, with each expert's segment
+        rounded up to the row block), ``calls`` of the layer, and
+        ``experts_hit``, the held experts with a request's row summed over
+        calls.  int32: a reader takes differences modulo 2**32."""
         z = lambda *shape: jnp.zeros(shape, jnp.int32)
-        return {"rows": z(self.num_experts), "pad_rows": z(), "calls": z(),
+        return {"rows": z(self.num_experts), "held_rows": z(),
+                "pad_rows": z(), "computed_rows": z(), "calls": z(),
                 "experts_hit": z()}
 
-    def _count_rows(self, ctx, oh_i) -> bool:
+    def _count_rows(self, ctx, gate_idx, oh_i, computed) -> bool:
         """When this layer's state entry carries the counters
-        (:meth:`init_counters`), add this call's routed rows per expert to
-        it, on the device.  ``valid`` (the rows that belong to a request;
-        put into the entry by ``nn.cache.call_state``) keeps the rows of
-        free slots and of bucket padding apart: they are routed and cost
-        work, but are nobody's.  True when counted (the training-time aux
-        loss is then not published: the entry holds counters and nothing
-        else)."""
+        (:meth:`init_counters`), add this call's routed rows to it, on the
+        device.  ``valid`` (the rows that belong to a request; put into the
+        entry by ``nn.cache.call_state``) keeps the rows of free slots and
+        of bucket padding apart: they are routed and cost work, but are
+        nobody's.  ``oh_i`` is the one-hot of the picks over the experts
+        held.  True when counted (the training-time aux loss is then not
+        published: the entry holds counters and nothing else)."""
         st = ctx.state.get(self._path) if ctx.state else None
         if st is None or "rows" not in st:
             return False
         k, n, _ = oh_i.shape
-        valid = st["valid"].reshape(n)
-        rows = (oh_i.sum(0) * valid[:, None].astype(jnp.int32)).sum(0)
-        n_valid = valid.sum().astype(jnp.int32)
+        valid = st["valid"].reshape(n).astype(jnp.int32)
+        held = (oh_i.sum(0) * valid[:, None]).sum(0)             # (held,)
+        if self.experts_held == self.num_experts:
+            rows = held
+        else:
+            rows = (jax.nn.one_hot(gate_idx, self.num_experts,
+                                   dtype=jnp.int32).sum(1)
+                    * valid[:, None]).sum(0)
+        n_valid = valid.sum()
         ctx.put_state(self._path, {
             "rows": st["rows"] + rows,
+            "held_rows": st["held_rows"] + held.sum(),
             "pad_rows": st["pad_rows"] + k * (n - n_valid),
+            "computed_rows": st["computed_rows"]
+            + jnp.asarray(computed, jnp.int32),
             "calls": st["calls"] + 1,
-            "experts_hit": st["experts_hit"] + (rows > 0).sum().astype(
+            "experts_hit": st["experts_hit"] + (held > 0).sum().astype(
                 jnp.int32)})
         return True
 
@@ -405,10 +493,23 @@ class MoELayer(Module):
                         axis=0)
         self._put_aux(e * jnp.sum(frac * probs.mean(0)))
 
-    def _forward_dropless(self, p, xt, gate_vals, gate_idx, rank, counts):
+    def _block_rows(self, kn: int, dtype) -> int:
+        """The dropless path's row-block size for a call of ``kn`` picks:
+        512 rows amortizes grid/DMA overhead at LM shapes; tiny calls
+        (tests, dryrun, a decode step) shrink to the rows an expert expects,
+        down to one sublane tile of the activations' dtype (16 rows of
+        bf16)."""
+        return min(512, _ceil_to(max(kn // self.num_experts, 1),
+                                 sublane_tile(dtype)))
+
+    def _forward_dropless(self, p, xt, gate_vals, gate_idx, held, rank,
+                          counts, b):
         """Dropless expert compute: sort the (choice, token) rows by
         expert and run each expert over its exact segment with the
         grouped-matmul kernels (ops/gmm.py) — MegaBlocks-style.
+        ``gate_idx`` (N, k) numbers the experts from the first one held,
+        ``held`` marks the picks that fall on one, ``counts`` (E,) and
+        ``rank`` (k, N) are the counting sort's, ``b`` the row block.
 
         No capacity, no drops: every routed row is processed, and the only
         padding is each segment's round-up to the row-block size (average
@@ -423,38 +524,66 @@ class MoELayer(Module):
         passes are gathers, never a data scatter); the per-expert FFN
         matmuls and all three of their backward passes are grouped
         matmuls over the same block→expert map (ops.gmm.grouped_linear).
-        """
-        from ..ops.gmm import grouped_linear
 
-        e, k = self.num_experts, self.top_k
+        A layer that holds a share of the experts expects a share of the
+        picks, but MAY be sent all of them.  The row buffer is sized for
+        twice the expected share and the call falls back, under
+        ``lax.cond``, to the buffer that holds every pick when the rows do
+        not fit: nothing is dropped, and the usual call does not pay the
+        gathers, zero writes and grid steps of rows that hardly ever
+        come.  On the chip, one layer that holds 64 of 512 experts over
+        4,096 rows takes 2.78 ms under the ``cond`` and 5.20 ms with the
+        worst-size buffer alone, 29 ms of a 178 ms prefill over 12 layers;
+        over 96 rows 0.59 against 0.61 ms (PERF.md, PR 30).
+        tests/test_qwen3_next.py sends every pick to held experts and
+        takes the fallback.
+        """
+        e, k = self.experts_held, self.top_k
         n, d = xt.shape
         kn = k * n
-        # row-block size: 512 rows amortizes grid/DMA overhead at LM
-        # shapes; tiny calls (tests, dryrun) shrink to keep M small, down
-        # to one sublane tile of the activations' dtype (16 rows of bf16)
-        b = min(512, _ceil_to(max(kn // e, 1), sublane_tile(xt.dtype)))
-        m_rows = (-(-kn // b) + e) * b                 # static upper bound
+        padded = ((counts + b - 1) // b) * b
+        cum_padded = jnp.cumsum(padded)
+        worst = (-(-kn // b) + e) * b            # every pick on a held expert
+        usual = (-(-2 * kn * e // (self.num_experts * b)) + e) * b
+        rows = functools.partial(
+            self._dropless_rows, p, xt, gate_vals, gate_idx, held, rank,
+            counts, padded, cum_padded, b)
+        if usual >= worst:
+            return rows(worst)
+        return lax.cond(cum_padded[-1] <= usual, lambda: rows(usual),
+                        lambda: rows(worst))
+
+    def _dropless_rows(self, p, xt, gate_vals, gate_idx, held, rank, counts,
+                       padded, cum_padded, b, m_rows):
+        """:meth:`_forward_dropless` over a row buffer of ``m_rows`` (a
+        static bound on the block-aligned rows of the call)."""
+        from ..ops.gmm import grouped_linear
+
+        e, k = self.experts_held, self.top_k
+        n, d = xt.shape
+        kn = k * n
         nb = m_rows // b
 
         with jax.named_scope("dispatch"):
             # destination row per (choice, token): its expert's
             # block-aligned segment start + its arrival rank there
             # (``rank`` is the routing cumsum from forward() — a stable
-            # counting sort, no argsort)
-            padded = ((counts + b - 1) // b) * b
-            pad_start = jnp.cumsum(padded) - padded              # (E,)
-            slot = (pad_start[gate_idx.T] + rank).astype(jnp.int32)  # (k,N)
+            # counting sort, no argsort); a pick of an absent expert
+            # points past the last row and is given none
+            pad_start = cum_padded - padded                      # (E,)
+            slot = jnp.where(
+                held.T, pad_start[jnp.clip(gate_idx.T, 0, e - 1)] + rank,
+                m_rows).astype(jnp.int32)                        # (k, N)
             pos = slot.reshape(-1)                               # (k*N,)
 
             # the two inverse maps the gather VJPs need; pad rows point at
             # the sentinels (token n = zero row, choice k*n = dropped)
             flat_choice = jnp.arange(kn, dtype=jnp.int32)
             token_for_row = (jnp.full((m_rows,), n, jnp.int32)
-                             .at[pos].set(flat_choice % n))
+                             .at[pos].set(flat_choice % n, mode="drop"))
             choice_for_row = (jnp.full((m_rows,), kn, jnp.int32)
-                              .at[pos].set(flat_choice))
+                              .at[pos].set(flat_choice, mode="drop"))
 
-            cum_padded = jnp.cumsum(padded)
             n_live = (cum_padded[-1] // b).astype(jnp.int32)
             # block -> expert map; overallocation-tail blocks get clamped
             # to E-1 (tgmm needs them to extend the final segment with
@@ -465,7 +594,7 @@ class MoELayer(Module):
             bg = jnp.minimum(bg, e - 1).astype(jnp.int32)
             present = counts > 0
             xs = _dispatch_rows(xt, token_for_row, slot)        # (M, d)
-        # the kernels are named by the routed rows of the call, so a device
+        # the kernels are named by the picks of the call, so a device
         # trace tells a 1024-token prefill's calls (gmm_r8192) from a
         # 32-slot decode step's (gmm_r256)
         def linear(rows, w, bias):

@@ -160,6 +160,8 @@ class DisaggSlotEngine(SlotEngine):
         super().__init__(model, params, num_slots=num_slots,
                          max_len=max_len, cache_dtype=cache_dtype,
                          min_bucket=min_bucket)
+        # arrivals are bucket-padded along time and prefix hits joined
+        kvcache.require_timed(self.cache, "DisaggSlotEngine")
         self.kv = kv
         self.rank = int(rank if rank is not None else kv.dp.rank)
         self.role_rank = int(role_rank)

@@ -52,7 +52,7 @@ from typing import Callable, List, Optional
 
 import numpy as np
 
-from ..nn.attention import slot_decode_kernel
+from ..nn import cache as kvcache
 from ..obs.spans import phase_times, reset_phases, span
 from ..ops.decode_attention import kv_blocks
 from ..utils.metrics import LatencyHistogram
@@ -507,8 +507,13 @@ class SlotEngine:
         # whether a decode step over this pool takes the Pallas kernel,
         # asked where MultiheadSelfAttention._decode asks
         self._kv_blocks_read = 0
-        self._attn_kernel = all(map(slot_decode_kernel,
-                                    self.cache.values()))
+        self._attn_kernel = model.slot_decode_kernel(self.cache)
+        # the two kinds of cache a decode step touches for its busy slots
+        # (nn/cache.py): a slot's whole state, read and written once a step
+        # whatever its length, and the K/V columns it holds; bytes summed
+        # over decode iterations, host arithmetic like the blocks above
+        self._slot_bytes = kvcache.slot_bytes(self.cache)
+        self._state_bytes = self._kv_bytes = 0
 
         self._build_programs()
 
@@ -701,6 +706,10 @@ class SlotEngine:
         with span("decode.dispatch", **ids):
             self._kv_blocks_read += kv_blocks(
                 np.where(live, self.lengths, 0), self.max_len)[0]
+            state, per_pos = self._slot_bytes
+            self._state_bytes += 2 * state * len(rows)
+            self._kv_bytes += per_pos * int(self.lengths[rows].sum()
+                                            + len(rows))
             if not np.array_equal(live, self._live[0]):
                 self._live = (live, jax.device_put(live))
             nxt_dev, self.cache, self._moe["decode"], self._slots = \
@@ -940,6 +949,7 @@ class SlotEngine:
         self._occupied_slot_steps = 0
         self._decode_steps = 0
         self._kv_blocks_read = 0
+        self._state_bytes = self._kv_bytes = 0
         self._pipeline = self._fresh_pipeline()
         reset_phases(SERVE_PHASES)
         # the device counters are never zeroed (a step in flight would
@@ -972,10 +982,14 @@ class SlotEngine:
 
     def _moe_stats(self) -> Optional[dict]:
         """``stats()["moe"]``: routed rows since ``reset_stats()``, per
-        expert (a request's rows only, summed over layers and both pool
-        programs) and per program — ``rows`` a request's, ``pad_rows`` those
-        of free slots (decode) and bucket padding (prefill), ``calls`` of an
-        expert layer, ``experts_hit`` summed over calls."""
+        router expert (a request's picks only, summed over layers and both
+        pool programs) and per program — ``rows`` a request's picks,
+        ``held_rows`` those on an expert the model holds and ``absent_rows``
+        the others (a model that holds a share of its experts), ``pad_rows``
+        the picks of free slots (decode) and bucket padding (prefill),
+        ``computed_rows`` the rows the expert matmuls ran over, ``calls`` of
+        an expert layer, ``experts_hit`` summed over calls
+        (``MoELayer.init_counters``)."""
         now = self._moe_read()
         if not now:
             return None
@@ -984,15 +998,16 @@ class SlotEngine:
         since = {phase: {k: (v - base.get(phase, {}).get(k, 0)) % (1 << 32)
                          for k, v in c.items()} for phase, c in now.items()}
         per_expert = sum(c["rows"] for c in since.values())
-        by_phase = {phase: {"rows": int(c["rows"].sum()),
-                            "pad_rows": int(c["pad_rows"]),
-                            "calls": int(c["calls"]),
-                            "experts_hit": int(c["experts_hit"])}
+        by_phase = {phase: {k: int(v.sum()) for k, v in c.items()}
                     for phase, c in since.items()}
+        for c in by_phase.values():
+            c["absent_rows"] = c["rows"] - c["held_rows"]
         total = lambda k: sum(c[k] for c in by_phase.values())
         return {"rows_per_expert": [int(r) for r in per_expert],
-                "rows": total("rows"), "pad_rows": total("pad_rows"),
-                "calls": total("calls"), "by_phase": by_phase}
+                **{k: total(k) for k in ("rows", "held_rows", "absent_rows",
+                                         "pad_rows", "computed_rows",
+                                         "calls")},
+                "by_phase": by_phase}
 
     def _decode_attn_stats(self) -> dict:
         """``stats()["decode_attn"]``: how far the decode step's K/V traffic
@@ -1011,10 +1026,17 @@ class SlotEngine:
                 "kernel": self._attn_kernel}
 
     def stats(self) -> dict:
+        """Everything since ``reset_stats()``.  ``"state"``: bytes of the
+        two kinds of cache the decode steps had to touch for their busy
+        slots, summed over steps: ``state_bytes`` (whole state, read and
+        written) and ``kv_bytes`` (the K/V columns held, the new one
+        included)."""
         moe = self._moe_stats()
         return {
             **({"moe": moe} if moe else {}),
             "decode_attn": self._decode_attn_stats(),
+            "state": {"state_bytes": int(self._state_bytes),
+                      "kv_bytes": int(self._kv_bytes)},
             "pipeline": {k: dict(v) if isinstance(v, dict) else v
                          for k, v in self._pipeline.items()},
             "completed": self.completed,

@@ -90,6 +90,8 @@ class KVTransfer:
     def __init__(self, dp, template, wire=None):
         from ..collectives.quant import parse_scheme
 
+        # fragments are the first ``length`` columns of every leaf
+        kvcache.require_timed(template, "KVTransfer")
         self.dp = dp
         self.template = {path: dict(entry)
                          for path, entry in template.items()}

@@ -153,6 +153,9 @@ class PrefixCache:
         level is not already present, slicing its rows out of ``rows``
         (full prefill output, ``(1, ..., >=length)`` per layer).  Returns
         the number of new levels cached."""
+        # a block is a cut along time; a recurrent layer's state at the cut
+        # would be a snapshot, which nobody took
+        kvcache.require_timed(rows, "PrefixCache.insert")
         tokens = np.asarray(tokens, np.int32).reshape(-1)[:int(length)]
         levels = len(tokens) // self.block
         added = 0

@@ -178,6 +178,10 @@ def _model_dims(model) -> dict:
     """Shardable hyperparameters read off a built ``TransformerLM`` —
     raising :class:`ShardConfigError` for shapes this layout cannot
     split."""
+    import jax
+    # the per-block segments address one K/V pool entry a block
+    kvcache.require_timed(jax.eval_shape(
+        lambda: model.init_slot_cache(1, 8)), "sharded serving")
     if getattr(model, "num_experts", 0):
         raise ShardConfigError(
             "sharded serving covers dense MLP blocks; MoE blocks are "
