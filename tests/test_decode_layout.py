@@ -25,13 +25,12 @@ import pytest
 
 from tpu_dist import nn
 from tpu_dist.models import TransformerLM
-from tpu_dist.serve.engine import pool_programs
+from tpu_dist.serve.engine import gathered_tables, pool_programs, row_major
 
 SLOTS, MAX_LEN, DIM, HEADS, DEPTH = 32, 1024, 1600, 25, 2
-# The cell's vocabulary is 50257; it is cut here because at that size the
-# compiler relayouts the token table for the embedding gather (a 161 MB
-# temporary of its own, PERF.md section 7), which would drown the signal
-# this file exists for.  The K/V path does not see the vocabulary.
+# The cell's vocabulary is 50257; the K/V path does not see it, and the older
+# cases keep it cut for their speed.  The cases at the end of the file
+# (ISSUE 31) compile the whole table, placed as the engine places it.
 VOCAB = 2048
 POOL_ELEMENTS = SLOTS * MAX_LEN * DIM
 # Temporaries: the decode step held 272 MB with the relayout copies (two
@@ -72,15 +71,20 @@ def _shapes(tree, sharding):
         tree)
 
 
+def _param_shapes(model, sharding):
+    """``model``'s parameters as served, bf16, as shapes on ``sharding``."""
+    return _shapes(jax.eval_shape(
+        lambda: jax.tree_util.tree_map(lambda a: a.astype(jnp.bfloat16),
+                                       model.init(jax.random.key(0)))),
+        sharding)
+
+
 def _compile(program, cache_dtype, sharding):
     """The optimized executable of ``decode_step`` or ``prefill_into_slot``
     over a donated pool, bf16 parameters, for the described chip."""
     model = TransformerLM(VOCAB, dim=DIM, depth=DEPTH, num_heads=HEADS,
                           max_seq_len=MAX_LEN)
-    params = _shapes(jax.eval_shape(
-        lambda: jax.tree_util.tree_map(lambda a: a.astype(jnp.bfloat16),
-                                       model.init(jax.random.key(0)))),
-        sharding)
+    params = _param_shapes(model, sharding)
     cache = _shapes(jax.eval_shape(
         lambda: model.init_slot_cache(SLOTS, MAX_LEN, cache_dtype)),
         sharding)
@@ -108,15 +112,14 @@ def _lower(model, program, params, pool, counters, sharding):
         arr(jnp.float32), arr(jnp.uint32, 2), False)
 
 
-def _pool_sized_results(hlo_text, ops):
+def _pool_sized_results(hlo_text, ops, elements=POOL_ELEMENTS):
     """Instructions of the named kinds, in any computation of the module,
-    with a result (or a member of a tuple result) of at least the K/V
-    pool's element count."""
+    with a result (or a member of a tuple result) of at least ``elements``
+    elements: the K/V pool's count, unless another is given."""
     found = []
     for line in hlo_text.splitlines():
         m = re.search(r"= (.*?) (%s)\(" % "|".join(ops), line)
-        if m and any(math.prod(int(d) for d in dims.split(",")
-                               ) >= POOL_ELEMENTS
+        if m and any(math.prod(int(d) for d in dims.split(",")) >= elements
                      for dims in re.findall(r"\w+\[([\d,]+)\]", m.group(1))):
             found.append(line.strip()[:160])
     return found
@@ -203,10 +206,7 @@ def test_olmoe_pool_program_compiles_with_its_grouped_matmuls(
                           num_experts=experts, moe_top_k=top_k,
                           moe_hidden=width, moe_gated=True,
                           moe_normalize_gates=False, moe_dispatch="dropless")
-    params = _shapes(jax.eval_shape(
-        lambda: jax.tree_util.tree_map(lambda a: a.astype(jnp.bfloat16),
-                                       model.init(jax.random.key(0)))),
-        one_chip)
+    params = _param_shapes(model, one_chip)
     pool = _shapes(jax.eval_shape(
         lambda: model.init_slot_cache(SLOTS, MAX_LEN, jnp.bfloat16)),
         one_chip)
@@ -252,10 +252,7 @@ def test_decode_step_on_the_kernel_writes_no_pool_sized_result(
     stay under the decode limit."""
     model = TransformerLM(VOCAB, dim=dim, depth=DEPTH, num_heads=heads,
                           max_seq_len=MAX_LEN)
-    params = _shapes(jax.eval_shape(
-        lambda: jax.tree_util.tree_map(lambda a: a.astype(jnp.bfloat16),
-                                       model.init(jax.random.key(0)))),
-        one_chip)
+    params = _param_shapes(model, one_chip)
     cache = _shapes(jax.eval_shape(
         lambda: model.init_slot_cache(SLOTS, MAX_LEN, jnp.bfloat16)),
         one_chip)
@@ -283,3 +280,63 @@ def test_result_counter_sees_the_dense_branchs_fusions():
         "bf16[32,25,64,1024]{3,2,1,0}, bf16[32,25,64,1024]{3,2,1,0}) "
         "custom-call(%a, %b), custom_call_target=\"tpu_custom_call\""])
     assert len(_pool_sized_results(text, ("fusion", "copy"))) == 1
+
+
+# -- the gathered tables lie as the programs read them (ISSUE 31) -------------
+
+def _placed_shapes(model, sharding):
+    """bf16 parameter shapes of ``model`` for the described chip, each
+    gathered table in the format ``serve.engine.place_params`` gives it."""
+    params = _param_shapes(model, sharding)
+    for path, name in gathered_tables(model):
+        leaf = params[path][name]
+        params[path][name] = jax.ShapeDtypeStruct(
+            leaf.shape, leaf.dtype, sharding=row_major(sharding))
+    return params
+
+
+@pytest.mark.parametrize("vocab, dim, heads",
+                         [(50257, 1600, 25), (50304, 2048, 16)],
+                         ids=["gpt2xl-50257x1600", "olmoe-50304x2048"])
+@pytest.mark.parametrize("program", ["decode_step", "prefill_into_slot"])
+def test_pool_program_reads_the_token_table_where_it_lies(
+        one_chip, no_compile_cache, mosaic_decode_attention, program,
+        vocab, dim, heads):
+    """The pool programs as served (the slot kernel taken) over the WHOLE
+    vocabulary, the parameters in the formats the engine's helper gives
+    them: the entry parameter of ``tok.weight`` is row-major and no copy
+    writes a table-sized result.  In the device's default format, which
+    for ``bf16[50257, 1600]`` is vocabulary-minor (1600 is 12.5 tiles of
+    128 lanes), both programs opened with a 160 MB copy of the table:
+    0.51 ms of every 5.09 ms decode step (PERF.md, PR 31)."""
+    model = TransformerLM(vocab, dim=dim, depth=DEPTH, num_heads=heads,
+                          max_seq_len=MAX_LEN)
+    params = _placed_shapes(model, one_chip)
+    cache = _shapes(jax.eval_shape(
+        lambda: model.init_slot_cache(SLOTS, MAX_LEN, jnp.bfloat16)),
+        one_chip)
+    with nn.attention_impl("flash"):
+        compiled = _lower(model, program, params, cache, {},
+                          one_chip).compile()
+    table = compiled.input_formats[0][0]["tok"]["weight"]
+    assert table.layout.major_to_minor == (0, 1), table
+    copies = _pool_sized_results(compiled.as_text(), ("copy",), vocab * dim)
+    assert not copies, (
+        f"{program} copies the token table again at every call\n"
+        + "\n".join(copies[:4]))
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < TEMP_LIMIT[program], (
+        f"{program} holds {temp / 2**20:.0f} MiB of temporaries (limit "
+        f"{TEMP_LIMIT[program] >> 20} MiB; the table is 153 MiB)")
+
+
+def test_copy_counter_sees_a_table_sized_copy():
+    """The counter itself, at the table's size: it finds a copy shaped
+    like the parent's and ignores the compiler's prefetch of a weight."""
+    text = "\n".join([
+        "  %copy.23 = bf16[50257,1600]{1,0:T(8,128)(2,1)} "
+        "copy(%p__tok____weight__.1), sharding={replicated}",
+        "  %copy.22 = bf16[1600,4800]{0,1:T(8,128)(2,1)S(1)} "
+        "copy(%p__block0_attn____qkv_weight__.1)",
+        "  %gather = bf16[32,1600]{1,0} gather(%copy.23, %tokens)"])
+    assert len(_pool_sized_results(text, ("copy",), 50257 * 1600)) == 1

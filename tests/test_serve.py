@@ -44,9 +44,10 @@ def _gen_ref(model, params, prompt, n, **kw):
 
 
 def _run_engine(model, params, reqs, slots=4, cache_dtype=None,
-                interleave=True):
+                interleave=True, **request_kw):
     """Drive the raw engine: admit mixed-length requests (interleaved with
-    decoding when ``interleave``) and return each request's tokens."""
+    decoding when ``interleave``) and return each request's tokens.
+    ``request_kw`` (``temperature``, ``seed``) goes to every request."""
     engine = serve.SlotEngine(model, params, num_slots=slots,
                               cache_dtype=cache_dtype)
     outs = {}
@@ -55,7 +56,8 @@ def _run_engine(model, params, reqs, slots=4, cache_dtype=None,
     def on_token(req, tok):
         outs.setdefault(req.id, []).append(tok)
 
-    pending = [serve.Request(p, n, on_token=on_token) for p, n in reqs]
+    pending = [serve.Request(p, n, on_token=on_token, **request_kw)
+               for p, n in reqs]
     for r in pending:
         order.append(r.id)
     while pending or not engine.idle():
@@ -302,6 +304,123 @@ class TestDecodeAttnCounter:
             sched.close()
         assert got == {"kv_blocks_read": 3, "kv_blocks_pool": 3 * 4 * 2,
                        "steps": 3, "block": 256, "kernel": False}
+
+
+class TestParamsPlacement:
+    """``serve.engine.place_params`` (ISSUE 31): the engine holds a
+    gathered table row-major, whatever format the caller made it in, and
+    every other leaf as it came.  The CPU's default IS row-major, so an
+    engine here holds the caller's own tree; a caller-made column-major
+    table exercises the placement itself (the CPU backend can lay a
+    matrix either way, it only has no tiles)."""
+
+    ZERO = {"placed_leaves": 0, "placed_bytes": 0, "leaves": 30}
+
+    @staticmethod
+    def _column_major(params, paths):
+        from jax.experimental.layout import Format, Layout
+        out = {path: dict(leaves) for path, leaves in params.items()}
+        for path in paths:
+            w = params[path]["weight"]
+            out[path]["weight"] = jax.device_put(
+                w, Format(Layout(major_to_minor=(1, 0)), w.sharding))
+        return out
+
+    @staticmethod
+    def _requests():
+        rng = np.random.default_rng(31)
+        return [(rng.integers(0, 97, n).astype(np.int32), m)
+                for n, m in ((3, 6), (17, 4), (9, 8), (30, 5))]
+
+    def test_is_the_identity_where_the_default_suits(self, lm):
+        from tpu_dist.serve.engine import gathered_tables, place_params
+        model, params = lm
+        assert gathered_tables(model) == [("tok", "weight"),
+                                          ("pos", "weight")]
+        placed, took = place_params(model, params)
+        assert placed is params and took == self.ZERO
+        engine = serve.SlotEngine(model, params, num_slots=2)
+        assert engine.params is params
+
+    def test_counter_survives_reset_and_rides_the_wire(self, lm):
+        model, params = lm
+        engine = serve.SlotEngine(model, params, num_slots=4)
+        assert engine.stats()["params"] == self.ZERO
+        sched = serve.Scheduler(engine, batch_window=0.002)
+        fe = serve.Frontend(sched, port=0)
+        cli = serve.ServeClient("127.0.0.1", fe.port, connect_retry=10)
+        try:
+            cli.generate(list(range(1, 6)), max_new_tokens=4, timeout=120.0)
+            got = cli.stats()["params"]
+        finally:
+            cli.close()
+            fe.close()
+            sched.close()
+        assert got == self.ZERO
+        engine.reset_stats()
+        assert engine.stats()["params"] == self.ZERO
+
+    @pytest.mark.parametrize("paths", [("tok",), ("pos",), ("tok", "pos")],
+                             ids=["tok", "pos", "both"])
+    def test_places_a_table_that_lies_otherwise(self, lm, paths):
+        model, params = lm
+        made = self._column_major(params, paths)
+        engine = serve.SlotEngine(model, made, num_slots=3)
+        nbytes = sum(params[p]["weight"].nbytes for p in paths)
+        assert engine.stats()["params"] == {
+            "placed_leaves": len(paths), "placed_bytes": nbytes,
+            "leaves": 30}
+        engine.reset_stats()
+        assert engine.stats()["params"]["placed_leaves"] == len(paths)
+        for path, leaves in engine.params.items():
+            for name, leaf in leaves.items():
+                if path in paths and name == "weight":
+                    assert leaf.format.layout.major_to_minor == (0, 1)
+                    np.testing.assert_array_equal(leaf, params[path][name])
+                else:       # the caller's own array, not a copy
+                    assert leaf is made[path][name]
+        # the caller's tree is as it was made
+        assert made[paths[0]]["weight"].format.layout.major_to_minor == (1, 0)
+
+    @pytest.mark.parametrize("placed", [False, True],
+                             ids=["callers-tree", "placed-table"])
+    def test_each_pool_program_compiles_once(self, lm, placed):
+        """A placed table is committed to its device, and so is every
+        result of a program that takes it: the pool, the slot rows and the
+        counters start committed beside it, or the second call of each
+        program would compile again (on the chip: 16 s inside the window)."""
+        model, params = lm
+        tree = self._column_major(params, ("tok",)) if placed else params
+        engine = serve.SlotEngine(model, tree, num_slots=2)
+        assert all(leaf.committed == placed
+                   for leaf in jax.tree_util.tree_leaves(
+                       (engine.cache, engine._slots)))
+        for _ in range(3):      # one bucket, three admissions, their steps
+            engine.admit(serve.Request(np.arange(1, 6, dtype=np.int32), 3))
+            while not engine.idle():
+                engine.step()
+        assert engine._prefill._cache_size() == 1
+        assert engine._decode._cache_size() == 1
+
+    @pytest.mark.parametrize("temperature", [0.0, 0.8],
+                             ids=["greedy", "sampled"])
+    def test_serves_the_same_tokens_bitwise(self, lm, temperature):
+        """Fixed seeds: the engine over the caller's tree, as
+        ``self.params = params`` served it before, and the engine over a
+        tree it had to place, emit the same tokens; greedy ones are
+        ``generate()``'s."""
+        model, params = lm
+        kw = dict(slots=3, temperature=temperature, seed=1031)
+        before, engine = _run_engine(model, params, self._requests(), **kw)
+        assert engine.params is params
+        after, engine = _run_engine(
+            model, self._column_major(params, ("tok", "pos")),
+            self._requests(), **kw)
+        assert engine.stats()["params"]["placed_leaves"] == 2
+        assert after == before
+        if not temperature:
+            for (p, n), got in zip(self._requests(), before):
+                assert got == _gen_ref(model, params, p, n)
 
 
 class TestScheduler:

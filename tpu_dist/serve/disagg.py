@@ -52,8 +52,8 @@ from typing import Callable, Dict, Optional
 import numpy as np
 
 from ..nn import cache as kvcache
-from .engine import (Request, ServeError, SlotEngine, sample_tokens,
-                     seed_key, set_row)
+from .engine import (Request, ServeError, SlotEngine, place_params,
+                     sample_tokens, seed_key, set_row)
 from .kvtransfer import KVTransfer, KVTransferError
 from .scheduler import Scheduler
 
@@ -500,7 +500,7 @@ class PrefillWorker:
         from .engine import _bucket_lengths
 
         self.model = model
-        self.params = params
+        self.params, _ = place_params(model, params)
         self.kv = kv
         self.claim_ch = claim_ch
         self.env_chans = dict(env_chans)

@@ -378,6 +378,70 @@ def set_row(slots: dict, slot, token, length, temp, key) -> dict:
             "keys": put("keys", key)}
 
 
+# -- where the parameters lie -------------------------------------------------
+# A program reads a parameter in the format its operation wants and, handed
+# another, converts it at EVERY call.  Only a gathered table can differ: the
+# device's default format of a tall table whose width is no multiple of the
+# 128 lanes (GPT-2 XL's bf16[50257, 1600]) is vocabulary-minor, and a row
+# gather of it opened both pool programs with a copy of the whole table.
+
+def gathered_tables(model) -> list:
+    """``(path, name)`` of every parameter the programs of ``model`` gather
+    rows from: the weight of each ``nn.Embedding`` (``tok``, ``pos``)."""
+    from ..nn import Embedding
+
+    return [(path, "weight") for path, module in model.named_modules()
+            if isinstance(module, Embedding)]
+
+
+def row_major(sharding):
+    """The format a gathered table is read in: a row contiguous."""
+    from jax.experimental.layout import Format, Layout
+
+    return Format(Layout(major_to_minor=(0, 1)), sharding)
+
+
+def place_params(model, params):
+    """``params`` as the programs of ``model`` read them, and what that
+    took: ``{"placed_leaves", "placed_bytes", "leaves"}``.  A gathered table
+    (:func:`gathered_tables`) that is on a device in another format than
+    :func:`row_major` is placed again, once, and ``jax.jit`` follows a
+    committed argument's format; every other leaf is the caller's array.
+    Where the default format is row-major already (a width that is a
+    multiple of 128; every CPU run) the tree is returned as it came."""
+    import jax
+
+    moved = {}
+    for path, name in gathered_tables(model):
+        leaf = params.get(path, {}).get(name)
+        layout = leaf.format.layout if isinstance(leaf, jax.Array) else None
+        if layout is not None and layout.major_to_minor != (0, 1):
+            moved[path, name] = jax.device_put(leaf, row_major(leaf.sharding))
+    if moved:
+        params = {path: {name: moved.get((path, name), leaf)
+                         for name, leaf in leaves.items()}
+                  for path, leaves in params.items()}
+    return params, {
+        "placed_leaves": len(moved),
+        "placed_bytes": sum(int(leaf.nbytes) for leaf in moved.values()),
+        "leaves": len(jax.tree_util.tree_leaves(params))}
+
+
+def beside(params, state):
+    """``state`` committed where the committed leaves of ``params`` are, if
+    they share one sharding; as it came if none is committed.  A program's
+    results are committed as soon as one argument is (a placed table is: a
+    format names its sharding), and the pool, the slot rows and the
+    counters are results fed back as arguments: starting uncommitted they
+    would compile each program a second time at its second call, inside a
+    measured window.  No copy: the buffers are where they were."""
+    import jax
+
+    homes = {leaf.sharding for leaf in jax.tree_util.tree_leaves(params)
+             if isinstance(leaf, jax.Array) and leaf.committed}
+    return jax.device_put(state, homes.pop()) if len(homes) == 1 else state
+
+
 def pool_programs(model):
     """The two pool programs of ``model``, unjitted: ``decode(params, cache,
     moe, slots, live, sampling)`` and ``prefill(params, cache, moe, slots,
@@ -444,7 +508,9 @@ class SlotEngine:
         from ..utils.compile_cache import ensure_compile_cache
         ensure_compile_cache()  # before the pool programs compile
         self.model = model
-        self.params = params
+        # placed once, in the format the pool programs read (host facts for
+        # stats(), fixed here)
+        self.params, self._placed = place_params(model, params)
         self.num_slots = int(num_slots)
         self.max_len = int(max_len if max_len is not None
                            else model.max_seq_len)
@@ -454,8 +520,8 @@ class SlotEngine:
         self.cache_dtype = cache_dtype or jnp.float32
         self.buckets = _bucket_lengths(self.max_len, min_bucket)
         self._jnp = jnp
-        self.cache = model.init_slot_cache(self.num_slots, self.max_len,
-                                           self.cache_dtype)
+        self.cache = beside(self.params, model.init_slot_cache(
+            self.num_slots, self.max_len, self.cache_dtype))
 
         # host-side slot table — THE source of truth for occupancy, and the
         # host's mirror of the device's slot state: lengths / steps / temps /
@@ -472,10 +538,10 @@ class SlotEngine:
         # launched ahead of its last token's collection
         self._left = np.zeros(self.num_slots, np.int32)
         # (copies: the CPU backend may keep the host buffer it is handed)
-        self._slots = jax.device_put(
+        self._slots = beside(self.params, jax.device_put(
             {"tokens": self.tokens.copy(), "lengths": self.lengths.copy(),
              "steps": self.steps.copy(), "temps": self.temps.copy(),
-             "keys": self.keys.copy()})
+             "keys": self.keys.copy()}))
         # programs launched and not yet collected, oldest first; the live
         # mask of the last decode launch, uploaded again only when it changes
         self._flight: collections.deque = collections.deque()
@@ -534,7 +600,8 @@ class SlotEngine:
         # donated: stats() and reset_stats() may read a set from another
         # thread while the loop thread steps.
         fresh = getattr(model, "init_moe_counters", dict)
-        self._moe = {"prefill": fresh(), "decode": fresh()}
+        self._moe = beside(self.params,
+                           {"prefill": fresh(), "decode": fresh()})
 
         # the cache is donated (the pool buffer is updated in place instead
         # of copied every token); ``sampling`` is STATIC — jit caches by
@@ -1030,7 +1097,8 @@ class SlotEngine:
         two kinds of cache the decode steps had to touch for their busy
         slots, summed over steps: ``state_bytes`` (whole state, read and
         written) and ``kv_bytes`` (the K/V columns held, the new one
-        included)."""
+        included).  ``"params"``: what :func:`place_params` did at
+        construction; ``reset_stats()`` leaves it."""
         moe = self._moe_stats()
         return {
             **({"moe": moe} if moe else {}),
@@ -1039,6 +1107,7 @@ class SlotEngine:
                       "kv_bytes": int(self._kv_bytes)},
             "pipeline": {k: dict(v) if isinstance(v, dict) else v
                          for k, v in self._pipeline.items()},
+            "params": dict(self._placed),
             "completed": self.completed,
             "generated_tokens": self.generated_tokens,
             "decode_steps": self._decode_steps,

@@ -63,8 +63,8 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from ..nn import cache as kvcache
-from .engine import (Request, ServeError, SlotEngine, advance_rows,
-                     masked_rows, sample_tokens, set_row)
+from .engine import (Request, ServeError, SlotEngine, advance_rows, beside,
+                     masked_rows, place_params, sample_tokens, set_row)
 
 __all__ = ["ShardedLM", "ShardedDecoder", "ShardedSlotEngine",
            "ShardFollower", "ShardedParams", "ShardConfigError",
@@ -431,7 +431,9 @@ class ShardedDecoder:
                 f"ShardedLM coordinates ({self.slm.shard_rank}, "
                 f"{self.slm.shard_world}) disagree with the decoder's "
                 f"({shard_rank}, {shard_world})")
-        self.params = params
+        # every shard, the followers too, holds its tree as its programs
+        # read it (engine.place_params)
+        self.params, self.placed = place_params(self.slm, params)
         self.dp = dp
         self.rank = int(shard_rank)
         self.world = int(shard_world)
@@ -547,7 +549,8 @@ class ShardedDecoder:
     # -- pool operations (SlotEngine program signatures) ----------------------
 
     def init_slot_cache(self, slots: int, max_len: int, dtype):
-        return self.slm.init_slot_cache(slots, max_len, dtype)
+        return beside(self.params,
+                      self.slm.init_slot_cache(slots, max_len, dtype))
 
     def decode_pool(self, params, cache, tokens, lengths, temps, keys,
                     steps, sampling: bool):
@@ -638,6 +641,7 @@ class ShardedSlotEngine(SlotEngine):
         super().__init__(decoder.slm, decoder.params, num_slots=num_slots,
                          max_len=max_len, cache_dtype=cache_dtype,
                          min_bucket=min_bucket)
+        self._placed = decoder.placed   # its placement, not the no-op above
 
     def _build_programs(self) -> None:
         dec = self.decoder
