@@ -6,7 +6,8 @@ vocab 32768, T = 2048), every phase fatal:
 
   device    platform must be ``tpu``; versions, compile cache, store backend
   kernels   the Pallas kernels (flash attention, fused CE and the grouped
-            matmuls forward and backward, slot-decode attention), lowered by
+            matmuls forward and backward, slot-decode attention by head
+            and over a latent), lowered by
             Mosaic at their full-width users' shapes, against plain ``jnp``
   convnet   the source paper's ConvNet through ``init_process_group`` +
             ``DistributedDataParallel.train_step``
@@ -327,12 +328,65 @@ def check_decode_attention(slots: int, heads: int, head_dim: int,
          f"({int(busy.sum())} busy of {slots} slots)")
 
 
-def phase_kernels(flash: dict, ce: dict, moe: dict, decode: dict) -> dict:
+def check_latent_decode_attention(slots: int, heads: int, latent: int,
+                                  values: int, max_len: int) -> None:
+    """The latent form of the slot-decode kernel on a bf16 pool ``(slots,
+    latent, max_len)`` with the same ragged lengths, ``heads`` query rows a
+    slot, the values the first ``values`` rows of each column, against the
+    float32 jnp composition of the same step."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from tpu_dist.ops.decode_attention import latent_decode_attention
+
+    keys = jax.random.split(jax.random.key(8), 3)
+    # queries at the size a softmax scale of latent ** -0.5 expects
+    q = jax.random.normal(keys[0], (slots, heads, latent), jnp.bfloat16)
+    new = jax.random.normal(keys[1], (slots, latent), jnp.bfloat16)
+    pool = jax.random.normal(keys[2], (slots, latent, max_len), jnp.bfloat16)
+    edges = [0, 1, 127, 128, 129, 255, 256, 257, max_len - 1, max_len, 0,
+             max_len // 2 + 3]
+    lens = jnp.asarray([edges[i % len(edges)] for i in range(slots)],
+                       jnp.int32)
+    scale = latent ** -0.5
+
+    def reference(q, new, pool, lens):
+        hi = jax.lax.Precision.HIGHEST
+        pool = jnp.where(jnp.arange(max_len) == lens[:, None, None],
+                         new[..., None], pool)
+        wide = pool.astype(jnp.float32)
+        s = jnp.einsum("bhc,bct->bht", q.astype(jnp.float32), wide,
+                       precision=hi) * scale
+        seen = jnp.arange(max_len) <= lens[:, None, None]
+        w = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
+        return jnp.einsum("bht,bct->bhc", w, wide[:, :values],
+                          precision=hi), pool
+
+    out, got = jax.jit(lambda *a: latent_decode_attention(
+        *a, value_dim=values, scale=scale))(q, new, pool, lens)
+    want, ref = jax.jit(reference)(q, new, pool, lens)
+    busy = np.asarray(lens) > 0
+    _check_close("latent decode attention out",
+                 np.asarray(out, np.float32)[busy], np.asarray(want)[busy],
+                 BF16_TOL)
+    got, ref, before = (np.asarray(a, np.float32) for a in (got, ref, pool))
+    if not (np.array_equal(got[busy], ref[busy])
+            and np.array_equal(got[~busy], before[~busy])):
+        raise AssertionError("latent decode attention: the pool is not the "
+                             "input with the new columns in")
+    _say(f"  latent decode attention pool: new columns in, nothing else "
+         f"touched ({int(busy.sum())} busy of {slots} slots)")
+
+
+def phase_kernels(flash: dict, ce: dict, moe: dict, decode: dict,
+                  latent: dict) -> dict:
     t0 = time.perf_counter()
     check_flash(**flash)
     check_fused_ce(**ce)
     check_dropless_moe(**moe)
     check_decode_attention(**decode)
+    check_latent_decode_attention(**latent)
     return {"seconds": time.perf_counter() - t0}
 
 
@@ -672,7 +726,9 @@ def main() -> int:
         flash=dict(batch=8, seq=2048, heads=12, head_dim=64),    # B*H = 96
         ce=dict(rows=8 * 2048, vocab=32768),
         moe=dict(tokens=8 * 2048, dim=768, experts=8, top_k=2),
-        decode=dict(slots=32, heads=25, head_dim=64, max_len=1024))
+        decode=dict(slots=32, heads=25, head_dim=64, max_len=1024),
+        latent=dict(slots=32, heads=64, latent=576, values=512,
+                    max_len=1024))
     _say(f"phase kernels passed ({r['seconds']:.1f} s with compilation)")
 
     _say("phase convnet")

@@ -340,3 +340,77 @@ def test_copy_counter_sees_a_table_sized_copy():
         "copy(%p__block0_attn____qkv_weight__.1)",
         "  %gather = bf16[32,1600]{1,0} gather(%copy.23, %tokens)"])
     assert len(_pool_sized_results(text, ("copy",), 50257 * 1600)) == 1
+
+
+# -- a latent pool's decode kernel (ISSUE 32) ---------------------------------
+
+def test_latent_decode_kernel_compiles_at_the_cells_shapes(
+        one_chip, no_compile_cache, mosaic_decode_attention):
+    """``latent_decode_attention`` alone, for the described chip, at Kimi
+    K2's serving shapes: 128 slots of 4,096 positions of a 576-wide
+    bfloat16 latent, 64 query rows a slot, values the first 512 rows.  The
+    chip's compiler takes the 576-deep and the transposed matmul, the
+    aliased pool is not copied, and nothing pool-sized is a temporary."""
+    from tpu_dist.ops.decode_attention import latent_decode_attention
+    b, h, c, r, tmax = 128, 64, 576, 512, 4096
+    arr = lambda dtype, *shape: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one_chip)
+    compiled = jax.jit(
+        lambda q, new, pool, lengths: latent_decode_attention(
+            q, new, pool, lengths, value_dim=r, scale=0.13),
+        donate_argnums=2).lower(
+        arr(jnp.bfloat16, b, h, c), arr(jnp.bfloat16, b, c),
+        arr(jnp.bfloat16, b, c, tmax), arr(jnp.int32, b)).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%latent_decode_attention[.\d]* = [^\n]*"
+                          r"custom_call_target=\"tpu_custom_call\"",
+                          text)) == 1
+    assert not _pool_sized_results(text, ("fusion", "copy"), b * c * tmax)
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
+
+
+def test_kimi_k2_decode_step_on_the_kernel_writes_no_pool_sized_result(
+        one_chip, no_compile_cache, mosaic_gmm, mosaic_decode_attention):
+    """The decode program of the DeepSeek-V3 block at the published widths
+    (7168, 64 heads over a 512 + 64 latent, a dense layer of 18,432 and an
+    expert layer that holds 12 of 384 experts of 2,048; two layers,
+    vocabulary cut), 32 slots x 1024: one ``latent_decode_attention`` call
+    a layer whose aliased pool is the ONLY pool-sized result (the dense
+    branch selects the new column into the whole pool: a pool-sized fusion
+    a layer), the grouped matmuls as Mosaic calls, and no expert tensor
+    copied."""
+    from tpu_dist.models import KimiK2LM
+    model = KimiK2LM(
+        VOCAB, dim=7168, depth=2, num_heads=64, q_lora_rank=1536,
+        kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128, dense_hidden=18432, num_experts=384, moe_top_k=8,
+        moe_hidden=2048, routed_scaling_factor=2.827, experts_held=12,
+        rope_scaling_factor=32, rope_scaling_beta_fast=1,
+        rope_scaling_mscale_all_dim=1, max_seq_len=MAX_LEN)
+    params = _param_shapes(model, one_chip)
+    pool = _shapes(jax.eval_shape(
+        lambda: model.init_slot_cache(SLOTS, MAX_LEN, jnp.bfloat16)),
+        one_chip)
+    counters = _shapes(jax.eval_shape(model.init_moe_counters), one_chip)
+
+    def pool_results():
+        """Fusions and copies with a result of the pool's own shape (the
+        weights here are larger than this small pool: not by size)."""
+        text = _lower(model, "decode_step", params, pool, counters,
+                      one_chip).compile().as_text()
+        shape = re.escape(f"bf16[{SLOTS},576,{MAX_LEN}]")
+        return text, [line.strip()[:160] for line in text.splitlines()
+                      if re.search(r"= [^=]*%s[^=]* (fusion|copy)\(" % shape,
+                                   line)]
+
+    with nn.attention_impl("flash"):
+        text, found = pool_results()
+    calls = re.findall(r"%(latent_decode_attention|gmm_r\d+)[.\d]* = [^\n]*"
+                       r"custom_call_target=\"tpu_custom_call\"", text)
+    # (the expert layer's usual-size and worst-size branches: 3 calls each)
+    assert sorted(calls) == sorted(["latent_decode_attention"] * 2
+                                   + [f"gmm_r{SLOTS * 8}"] * 6), calls
+    assert not found, found
+    with nn.attention_impl("dense"):
+        _, dense = pool_results()
+    assert len(dense) >= 2, dense

@@ -572,6 +572,65 @@ class TestSocketLayer:
         finally:
             cli.close()
 
+    def test_a_steps_token_frames_leave_as_one_write(self, stack, lm):
+        # a decode step emits a token frame a busy slot: the connection
+        # holds them until the engine has emitted them all and writes
+        # once; a done frame takes what is pending with it, so each
+        # request's frames keep their order
+        from tpu_dist.serve.frontend import (_HELLO, _MAGIC, _VERSION,
+                                             read_frame, send_frame)
+        model, params = lm
+        _, _, fe = stack
+        ours, theirs = socket.socketpair()
+
+        class Counted:
+            writes = 0
+
+            def __getattr__(self, name):
+                return getattr(theirs, name)
+
+            def sendall(self, data):
+                Counted.writes += 1
+                return theirs.sendall(data)
+
+            def sendmsg(self, parts):
+                Counted.writes += 1
+                return theirs.sendmsg(parts)
+
+        t = threading.Thread(target=fe._serve_conn, args=(Counted(),),
+                             daemon=True)
+        t.start()
+        try:
+            ours.sendall(_HELLO.pack(_MAGIC, _VERSION))
+            assert len(ours.recv(_HELLO.size)) == _HELLO.size
+            hello_writes = Counted.writes
+            prompts = [np.arange(3 + i, dtype=np.int32) for i in range(4)]
+            for i, pr in enumerate(prompts):
+                send_frame(ours, {"type": "submit", "id": i,
+                                  "prompt": pr.tolist(),
+                                  "max_new_tokens": 6})
+            got, done, frames = {i: [] for i in range(4)}, {}, 0
+            ours.settimeout(120.0)
+            while len(done) < 4:
+                f = read_frame(ours)
+                frames += 1
+                if f["type"] == "token":
+                    assert f["id"] not in done
+                    got[f["id"]].append(f["t"])
+                else:
+                    assert f["type"] == "done" and f["n"] == 6
+                    done[f["id"]] = f["reason"]
+            for i, pr in enumerate(prompts):
+                assert got[i] == _gen_ref(model, params, pr, 6)
+            assert frames == 28
+            # 4 first tokens, then at most 5 + 3 staggered steps
+            assert Counted.writes - hello_writes <= 12
+            assert fe.scheduler.engine._flushers     # hooked while connected
+        finally:
+            ours.close()
+            t.join(30.0)
+        assert not t.is_alive() and not fe.scheduler.engine._flushers
+
     def test_invalid_request_error_frame(self, stack):
         _, _, fe = stack
         cli = serve.ServeClient("127.0.0.1", fe.port, connect_retry=10)

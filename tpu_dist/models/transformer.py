@@ -268,12 +268,13 @@ class TransformerLM(nn.Module):
                 for mixer in self._mixers()}
 
     def slot_decode_kernel(self, cache) -> bool:
-        """Whether a decode step over the pool ``cache`` takes the Pallas
-        decode-attention kernel in EVERY attention layer (each layer's own
-        answer, :meth:`nn.MultiheadSelfAttention.takes_slot_kernel`)."""
+        """Whether a decode step over the pool ``cache`` takes a Pallas
+        decode-attention kernel in EVERY layer that keeps a time-indexed
+        pool, by head or latent (``nn.cache.pool_leaf``; each layer's own
+        answer, its ``takes_slot_kernel``)."""
         return all(mixer.takes_slot_kernel(cache[mixer._path])
                    for mixer in self._mixers()
-                   if isinstance(mixer, nn.MultiheadSelfAttention))
+                   if nn.cache.pool_leaf(cache[mixer._path]) is not None)
 
     def init_moe_counters(self):
         """Routed-row counters for serving a model with expert layers, one
@@ -359,7 +360,10 @@ class TransformerLM(nn.Module):
         real = jnp.asarray(length, jnp.int32)   # the prompt's true tokens
         if prefix_rows is None:
             rows = self.init_slot_cache(1, max_len, dtype)
-            start, offset = jnp.zeros((), jnp.int32), None
+            # a whole prompt from position 0, known while tracing: a layer
+            # may stop at the columns such a call can see
+            # (nn.MultiheadLatentAttention.forward)
+            start, offset = 0, None
         else:
             rows = prefix_rows
             start = offset = jnp.asarray(prefix_len, jnp.int32)

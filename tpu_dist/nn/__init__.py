@@ -3,12 +3,14 @@ SURVEY.md §1)."""
 
 from . import cache, functional, init
 from .attention import (MultiheadSelfAttention, attention_impl, rotary_embed,
-                        scaled_dot_product_attention)
+                        scaled_dot_product_attention, yarn_inv_freq,
+                        yarn_mscale)
 from .deltanet import GatedDeltaNet
 from .layers import (AdaptiveAvgPool2d, AvgPool2d, BatchNorm2d, Conv2d,
-                     Dropout, Embedding, Flatten, GELU, Identity, LayerNorm,
-                     Linear, MaxPool2d, ReLU, RMSNorm)
+                     Dropout, Embedding, Flatten, GELU, GatedMLP, Identity,
+                     LayerNorm, Linear, MaxPool2d, ReLU, RMSNorm)
 from .loss import CrossEntropyLoss
+from .mla import MultiheadLatentAttention
 from .moe import MoELayer
 from .module import Module, Remat, Sequential, run_capturing_state
 from .quant import (QuantEmbedding, QuantLinear,
@@ -19,9 +21,10 @@ __all__ = [
     "cache", "functional", "init",
     "Linear", "Conv2d", "MaxPool2d", "AvgPool2d", "AdaptiveAvgPool2d",
     "ReLU", "Flatten", "Dropout", "BatchNorm2d", "Identity",
-    "Embedding", "LayerNorm", "RMSNorm", "GELU",
-    "MultiheadSelfAttention", "scaled_dot_product_attention",
-    "attention_impl", "GatedDeltaNet", "MoELayer", "rotary_embed",
+    "Embedding", "LayerNorm", "RMSNorm", "GELU", "GatedMLP",
+    "MultiheadSelfAttention", "MultiheadLatentAttention",
+    "scaled_dot_product_attention", "attention_impl", "GatedDeltaNet",
+    "MoELayer", "rotary_embed", "yarn_inv_freq", "yarn_mscale",
     "CrossEntropyLoss",
     "QuantEmbedding", "QuantLinear", "QuantMultiheadSelfAttention",
     "quantize_linear_weights",

@@ -27,7 +27,7 @@ from .module import Module
 from . import init as I
 
 __all__ = ["scaled_dot_product_attention", "MultiheadSelfAttention",
-           "attention_impl", "rotary_embed"]
+           "attention_impl", "rotary_embed", "yarn_inv_freq", "yarn_mscale"]
 
 _IMPL_OVERRIDE: list = []
 
@@ -54,6 +54,16 @@ def attention_impl(impl: str):
         yield
     finally:
         _IMPL_OVERRIDE.pop()
+
+
+def slot_kernel_wanted() -> bool:
+    """Whether a slot-decode step should take its Pallas kernel where the
+    pool suits one: on a TPU backend (interpreted, a kernel would make
+    every served token cost seconds), with the trace-scoped
+    :func:`attention_impl` (``"flash"`` / ``"dense"``) as the override."""
+    impl = (_IMPL_OVERRIDE[-1] if _IMPL_OVERRIDE
+            else "flash" if jax.default_backend() == "tpu" else "dense")
+    return impl == "flash"
 
 
 def scaled_dot_product_attention(q, k, v, causal: bool = False,
@@ -109,7 +119,44 @@ def scaled_dot_product_attention(q, k, v, causal: bool = False,
     return jnp.einsum("...hqk,...khd->...qhd", w, v)
 
 
-def rotary_embed(x, positions, theta: float = 10000.0, rotary_dim=None):
+def yarn_inv_freq(dim: int, theta: float, factor: float,
+                  original_max_position: int, beta_fast: float = 32.0,
+                  beta_slow: float = 1.0) -> np.ndarray:
+    """YaRN's blended rotary frequencies (Peng et al., arXiv:2309.00071),
+    ``dim // 2`` float32 values for :func:`rotary_embed`'s ``inv_freq``, as
+    the published DeepSeek-V3 code computes them
+    (``DeepseekV3YarnRotaryEmbedding``): pair ``i`` keeps its own frequency
+    ``theta^(-2i/dim)`` where it turns more than ``beta_fast`` times within
+    the original context, takes ``1/factor`` of it (interpolated
+    positions) where it turns fewer than ``beta_slow`` times, and a linear
+    blend between: ``low`` / ``high`` are the floor / ceiling of the pair
+    indices at which it turns exactly ``beta_fast`` / ``beta_slow`` times,
+    and the ramp over ``[low, high]`` widens a range whose ends coincide
+    by 0.001, as ``yarn_linear_ramp_mask`` does.  Host arithmetic on the
+    configuration's scalars: a constant of the program."""
+    def turns_at(rotations):
+        return (dim * math.log(original_max_position
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(turns_at(beta_fast)), 0)
+    high = min(math.ceil(turns_at(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    pair = np.arange(dim // 2, dtype=np.float32)
+    own = 1.0 / theta ** (2.0 * pair / dim)
+    keep = 1.0 - np.clip((pair - low) / (high - low), 0.0, 1.0)
+    return (own / factor * (1.0 - keep) + own * keep).astype(np.float32)
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature for a context stretched ``factor``
+    times: ``0.1 * mscale * ln(factor) + 1`` (1 for no stretch)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rotary_embed(x, positions, theta: float = 10000.0, rotary_dim=None,
+                 inv_freq=None):
     """Rotate ``x`` (..., T, H, D) by per-position angles — RoPE (Su et al.,
     arXiv:2104.09864), rotate-half convention.  ``positions``: (T,) int
     absolute positions; attention scores then depend only on relative
@@ -117,14 +164,17 @@ def rotary_embed(x, positions, theta: float = 10000.0, rotary_dim=None):
     extrapolate.  Angles computed in f32, result cast back to x.dtype.
     ``rotary_dim`` < D rotates the first ``rotary_dim`` dims of each head
     as a head of that size and leaves the others untouched (a partial
-    rotary factor)."""
+    rotary factor).  ``inv_freq`` (D // 2,) gives the pairs' frequencies
+    in place of ``theta``'s geometric ones (:func:`yarn_inv_freq`)."""
     if rotary_dim is not None and rotary_dim < x.shape[-1]:
         return jnp.concatenate(
-            [rotary_embed(x[..., :rotary_dim], positions, theta),
+            [rotary_embed(x[..., :rotary_dim], positions, theta,
+                          inv_freq=inv_freq),
              x[..., rotary_dim:]], axis=-1)
     d = x.shape[-1]
     half = d // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) * 2.0 / d)
+    freqs = (theta ** (-jnp.arange(0, half, dtype=jnp.float32) * 2.0 / d)
+             if inv_freq is None else jnp.asarray(inv_freq, jnp.float32))
     # positions may be (T,) — shared across the batch — or carry leading
     # batch dims, e.g. (B, T) during per-slot continuous-batching decode
     # where every cache slot sits at its own position
@@ -231,6 +281,13 @@ class MultiheadSelfAttention(Module):
         # dims after the split, zero-centred (x_hat * (1 + w))
         self.qk_norm = qk_norm
         self.qk_norm_eps = qk_norm_eps
+
+    @property
+    def attend_flops_per_position(self) -> int:
+        """Operations one resident position costs one new query of this
+        layer: ``q . k`` and ``p . v`` over ``head_dim``, every query
+        head."""
+        return 2 * self.num_heads * 2 * self.head_dim
 
     def create_params(self, key):
         k1, k2 = jax.random.split(key)
@@ -455,9 +512,7 @@ class MultiheadSelfAttention(Module):
         the override.  The engine asks the model, which asks here, which
         branch its decode program was built on."""
         from ..ops.decode_attention import decode_attention_ok
-        impl = (_IMPL_OVERRIDE[-1] if _IMPL_OVERRIDE
-                else "flash" if jax.default_backend() == "tpu" else "dense")
-        return (self.num_kv_heads == self.num_heads and impl == "flash"
+        return (self.num_kv_heads == self.num_heads and slot_kernel_wanted()
                 and decode_attention_ok(entry["k"]))
 
     def init_cache(self, batch: int, max_len: int, dtype=jnp.float32):

@@ -2,13 +2,18 @@
 addresses it, and how rows are written, cut and joined along time.
 
 A cache (a pool of slots, or one request's batch-1 *rows*) is a tree
-``{layer path: {name: leaf}}`` of RESIDENT leaves only, of two kinds told
-apart by name (:func:`is_timed`):
+``{layer path: {name: leaf}}`` of RESIDENT leaves only, of three kinds told
+apart by name (:func:`is_timed`, :func:`pool_leaf`):
 
-- TIME-INDEXED leaves, one column a position: ``k``/``v`` ``(B, Hkv, D,
-  Tmax)`` and, for the int8 cache, ``k_scale``/``v_scale`` ``(B, H, Tmax)``
-  (:meth:`MultiheadSelfAttention.init_cache` builds them and says why time
-  is last);
+- TIME-INDEXED leaves BY HEAD, one column a position: ``k``/``v`` ``(B,
+  Hkv, D, Tmax)`` and, for the int8 cache, ``k_scale``/``v_scale`` ``(B, H,
+  Tmax)`` (:meth:`MultiheadSelfAttention.init_cache` builds them and says
+  why time is last);
+- a TIME-INDEXED leaf WITHOUT a head axis: ``latent`` ``(B, C, Tmax)``, the
+  normalised latent and the roped shared key that every head of a latent
+  attention layer reads (:meth:`MultiheadLatentAttention.init_cache`).
+  Time is its last axis too, so every function below that cuts, pads,
+  joins or describes a tree along time serves it as it serves ``k``;
 - a slot's WHOLE STATE, with no time axis: any other name, such as
   :class:`GatedDeltaNet`'s ``state`` ``(B, Hv, Dk, Dv)`` float32 and its
   convolution tail ``conv`` (:meth:`GatedDeltaNet.init_cache`).  A layer
@@ -45,12 +50,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["is_timed", "state_leaves", "require_timed", "kv_entries",
-           "slot_bytes", "time_axis", "time_slice", "extent", "pad_time",
+__all__ = ["is_timed", "state_leaves", "require_timed", "require_heads",
+           "pool_leaf",
+           "kv_entries", "slot_bytes", "time_axis", "time_slice", "extent", "pad_time",
            "join_time", "token_template", "call_state", "split_state",
            "write_slot_rows"]
 
-_TIMED = frozenset({"k", "v", "k_scale", "v_scale"})
+# a pool's values, by head or headless, and the int8 pool's scales beside them
+_VALUES = ("k", "v", "latent")
+_TIMED = frozenset(_VALUES + ("k_scale", "v_scale"))
 
 
 # -- the layout ---------------------------------------------------------------
@@ -82,9 +90,33 @@ def require_timed(cache, who: str) -> None:
             f"leaves); it needs state snapshots, which it does not have")
 
 
+def require_heads(cache, who: str) -> None:
+    """Refuse, by the leaf's name, a cache that ``who`` divides BY HEAD
+    (tensor-parallel serving gives each shard its heads' K/V): a
+    ``latent`` leaf is one column a position for all heads and has no
+    head axis to divide."""
+    found = [f"{path}.{name}" for path, entry in cache.items()
+             for name in entry if name == "latent"]
+    if found:
+        raise NotImplementedError(
+            f"{who} divides the cache by head, and leaf {found[0]!r} is a "
+            f"latent every head reads, with no head axis ({len(found)} "
+            f"such leaves)")
+
+
+def pool_leaf(entry):
+    """The first time-indexed leaf of a layer's ``entry`` that holds the
+    pool's values (``k`` of a K/V pool, ``latent`` of a latent one; not an
+    int8 pool's scales): the leaf whose extent, type and tiling say what
+    the pool is.  None for an entry of whole state alone."""
+    return next((entry[name] for name in _VALUES if name in entry), None)
+
+
 def kv_entries(cache) -> list:
-    """The entries that hold a K/V pool (the attention layers')."""
-    return [entry for entry in cache.values() if "k" in entry]
+    """The entries that hold a time-indexed pool of either kind (the
+    attention layers')."""
+    return [entry for entry in cache.values()
+            if pool_leaf(entry) is not None]
 
 
 def slot_bytes(cache) -> tuple:
@@ -103,8 +135,9 @@ def slot_bytes(cache) -> tuple:
 
 
 def time_axis(leaf) -> int:
-    """The time axis of a time-indexed leaf: the LAST one, for ``k``/``v``
-    and the int8 scales alike.  (A state leaf has none.)"""
+    """The time axis of a time-indexed leaf: the LAST one, for ``k``/``v``,
+    the int8 scales and a headless ``latent`` alike.  (A state leaf has
+    none.)"""
     return leaf.ndim - 1
 
 
@@ -118,15 +151,16 @@ def time_slice(leaf, lo, hi):
 
 def extent(cache):
     """``(max_len, dtype)`` of a pool or row tree: the positions a slot
-    holds and the type its K/V are stored in, what
+    holds and the type its pool is stored in, what
     ``init_slot_cache(batch, max_len, dtype)`` was given.  Read from the
-    first K/V pool; state leaves keep types of their own."""
+    first time-indexed pool, of either kind; state leaves keep types of
+    their own."""
     entries = kv_entries(cache)
     if not entries:
-        raise ValueError("extent() reads a K/V pool, and this cache holds "
-                         f"none (leaves: {state_leaves(cache)})")
-    k = entries[0]["k"]
-    return k.shape[time_axis(k)], k.dtype
+        raise ValueError("extent() reads a time-indexed pool, and this "
+                         f"cache holds none (leaves: {state_leaves(cache)})")
+    leaf = pool_leaf(entries[0])
+    return leaf.shape[time_axis(leaf)], leaf.dtype
 
 
 def _map_leaves(fn, tree, state=lambda leaf: leaf):

@@ -22,7 +22,7 @@ from .module import Module, _ctx
 __all__ = [
     "Linear", "Conv2d", "MaxPool2d", "AvgPool2d", "AdaptiveAvgPool2d",
     "ReLU", "Flatten", "Dropout", "BatchNorm2d", "Identity",
-    "Embedding", "LayerNorm", "GELU",
+    "Embedding", "LayerNorm", "GELU", "GatedMLP",
 ]
 
 _IntOr2 = Union[int, Tuple[int, int]]
@@ -357,3 +357,17 @@ class GELU(Module):
 
     def __repr__(self):
         return "GELU()"
+
+
+class GatedMLP(Module):
+    """SiLU-gated feed-forward without biases, ``down(silu(gate(x)) *
+    up(x))`` (SwiGLU; the LLaMA-family MLP), ``hidden`` wide."""
+
+    def __init__(self, dim: int, hidden: int):
+        super().__init__()
+        self.gate = Linear(dim, hidden, bias=False)
+        self.up = Linear(dim, hidden, bias=False)
+        self.down = Linear(hidden, dim, bias=False)
+
+    def forward(self, x):
+        return self.down(jax.nn.silu(self.gate(x)) * self.up(x))

@@ -55,10 +55,17 @@ TPU-first design — routing as dense einsums, not gather/scatter:
 - ``gated=True`` makes each expert ``down(silu(gate(x)) * up(x))`` without
   biases (``w1`` gate, ``w3`` up, ``w2`` down, the LLaMA-family names) in
   place of the two-matrix GELU MLP; every dispatch computes either.
-- The router's logits are accumulated, softened and ranked in float32
+- The router's logits are accumulated, scored and ranked in float32
   whatever the activations' type (top-k among 64 near-equal probabilities
-  is not a bfloat16 decision); ``normalize_gates=False`` uses the selected
-  probabilities as they are (OLMoE's ``norm_topk_prob: false``).
+  is not a bfloat16 decision).  Two scoring forms: ``scoring="softmax"``
+  over all experts (OLMoE, Qwen3-Next), and ``scoring="sigmoid"`` of each
+  logit by itself (DeepSeek-V3, Kimi K2), which ``selection_bias`` pairs
+  with a learned per-expert ``router_bias`` added to the scores for the
+  SELECTION alone (``topk_method: noaux_tc``): the k picks are the largest
+  ``score + bias``, their weights the unbiased scores.
+  ``normalize_gates=False`` uses the selected scores as they are (OLMoE's
+  ``norm_topk_prob: false``); ``routed_scale`` multiplies the weights
+  after that (``routed_scaling_factor``).
 - ``experts_held`` / ``expert_offset`` give the layer ONE CHIP'S SHARE of
   an expert-parallel deployment: the router keeps its ``num_experts``
   outputs and its ``top_k`` a token (renormalised over all of them), the
@@ -66,8 +73,9 @@ TPU-first design — routing as dense einsums, not gather/scatter:
   up the picks that fall on those; a pick of an absent expert is given no
   row and costs no matmul.  The shares of all chips add up to the whole
   layer (tests/test_qwen3_next.py); nothing here stands in for the absent
-  chips or their exchange.  ``shared_hidden`` adds a shared expert under
-  its own sigmoid gate, computed for every token, here (scope ``shared``).
+  chips or their exchange.  ``shared_hidden`` adds a shared expert,
+  computed for every token, here (scope ``shared``); its sigmoid gate is
+  optional (``shared_gate``: Qwen3-Next's has one, Kimi K2's none).
 - Inside the layer ``jax.named_scope``s ``route``, ``dispatch``,
   ``experts`` and ``combine`` split a device trace (``python3 -m
   chipbench.scope_reduce``), and while serving the layer counts its routed
@@ -210,9 +218,16 @@ class MoELayer(Module):
             biases, ``hidden`` wide (parameters ``w1``, ``w3``, ``w2``),
             instead of ``w2(gelu(w1 x + b1)) + b2``.
         shared_hidden: width of a shared gated expert added to every
-            token's output under ``sigmoid(x w_s)`` (parameters
-            ``shared_w1``, ``shared_w3``, ``shared_w2``, ``shared_gate``);
-            0 = none.
+            token's output (parameters ``shared_w1``, ``shared_w3``,
+            ``shared_w2``); 0 = none.
+        shared_gate: the shared expert's output is multiplied by
+            ``sigmoid(x w_s)`` (parameter ``shared_gate``); off adds it
+            as it is.
+        scoring: ``"softmax"`` over the router's logits or ``"sigmoid"``
+            of each (module docstring).
+        selection_bias: a per-expert ``router_bias`` is added to the
+            scores to choose the ``top_k`` and left out of their weights.
+        routed_scale: factor on the (normalised) weights of the picks.
         experts_held / expert_offset: the experts whose weights this
             layer holds, ``[offset, offset + held)`` of the router's
             ``num_experts`` (0 = all); dropless dispatch only.
@@ -222,7 +237,9 @@ class MoELayer(Module):
                  top_k: int = 2, capacity_factor: float = 1.25,
                  normalize_gates: bool = True, dispatch: str = "einsum",
                  gated: bool = False, shared_hidden: int = 0,
-                 experts_held: int = 0, expert_offset: int = 0):
+                 experts_held: int = 0, expert_offset: int = 0,
+                 shared_gate: bool = True, scoring: str = "softmax",
+                 selection_bias: bool = False, routed_scale: float = 1.0):
         super().__init__()
         if num_experts < 2:
             raise ValueError(f"num_experts must be >= 2, got {num_experts}")
@@ -231,6 +248,9 @@ class MoELayer(Module):
         if dispatch not in ("einsum", "gather", "dropless"):
             raise ValueError(f"dispatch must be 'einsum', 'gather', or "
                              f"'dropless', got {dispatch!r}")
+        if scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"scoring must be 'softmax' or 'sigmoid', got "
+                             f"{scoring!r}")
         self.dim = dim
         self.num_experts = num_experts
         self.hidden = hidden or 4 * dim
@@ -240,6 +260,10 @@ class MoELayer(Module):
         self.dispatch = dispatch
         self.gated = gated
         self.shared_hidden = shared_hidden
+        self.shared_gate = shared_gate
+        self.scoring = scoring
+        self.selection_bias = selection_bias
+        self.routed_scale = routed_scale
         self.experts_held = experts_held or num_experts
         self.expert_offset = expert_offset
         if self.experts_held != num_experts:
@@ -257,17 +281,38 @@ class MoELayer(Module):
             return {}
         ks = jax.random.split(jax.random.fold_in(key, 7), 4)
         d, h = self.dim, self.shared_hidden
-        return {"shared_w1": init_lib.torch_default_uniform(ks[0], (d, h), d),
-                "shared_w3": init_lib.torch_default_uniform(ks[1], (d, h), d),
-                "shared_w2": init_lib.torch_default_uniform(ks[2], (h, d), h),
-                "shared_gate": init_lib.torch_default_uniform(ks[3], (d, 1),
-                                                              d)}
+        p = {"shared_w1": init_lib.torch_default_uniform(ks[0], (d, h), d),
+             "shared_w3": init_lib.torch_default_uniform(ks[1], (d, h), d),
+             "shared_w2": init_lib.torch_default_uniform(ks[2], (h, d), h)}
+        if self.shared_gate:
+            p["shared_gate"] = init_lib.torch_default_uniform(ks[3], (d, 1),
+                                                              d)
+        return p
+
+    def _router_params(self, key):
+        """The router over ALL experts and, with ``selection_bias``, its
+        per-expert bias.  Published code starts the bias at zero and loads
+        the trained value, which exists to BALANCE the experts' load; drawn
+        from the seed here, U(+-0.01): a unit-RMS input of width 7,168
+        through this router gives a token's eight largest of 384 sigmoid
+        scores 0.947-0.983, the eighth and ninth 0.004 apart, and this bias
+        then swaps one of the eight picks for two tokens in three and never
+        more than three, so that it changes the selection, a bias that
+        leaked into the weights shows, and the load stays near the
+        router's own (U(+-0.05) made the busiest expert's load 2.8 times
+        the mean and a chip's held experts reached 8 to 10 of 12 a step by
+        the seed: PERF.md, PR 32)."""
+        p = {"router": init_lib.kaiming_uniform(
+            key, (self.dim, self.num_experts))}
+        if self.selection_bias:
+            p["router_bias"] = init_lib.uniform(
+                jax.random.fold_in(key, 11), (self.num_experts,), -0.01, 0.01)
+        return p
 
     def create_params(self, key):
         kr, k1, k2 = jax.random.split(key, 3)
         # the router spans all experts, the weights those held here
-        n_out, e, d, h = (self.num_experts, self.experts_held, self.dim,
-                          self.hidden)
+        e, d, h = self.experts_held, self.dim, self.hidden
 
         def expert_uniform(k, shape, fan_in):
             # kaiming_uniform per expert: stacked (E, in, out) weights get
@@ -288,7 +333,7 @@ class MoELayer(Module):
             # w1 = gate, w3 = up, w2 = down (the LLaMA-family names); no
             # biases, as every published gated expert has none
             return {
-                "router": init_lib.kaiming_uniform(kr, (d, n_out)),
+                **self._router_params(kr),
                 "w1": expert_uniform(k1, (e, d, h), d),
                 "w3": expert_uniform(jax.random.fold_in(key, 3), (e, d, h),
                                      d),
@@ -296,7 +341,7 @@ class MoELayer(Module):
                 **self._shared_params(key),
             }
         return {
-            "router": init_lib.kaiming_uniform(kr, (d, n_out)),
+            **self._router_params(kr),
             "w1": expert_uniform(k1, (e, d, h), d),
             "b1": jnp.zeros((e, h)),
             "w2": expert_uniform(k2, (e, h, d), h),
@@ -323,10 +368,11 @@ class MoELayer(Module):
         y = self._routed(ctx, p, xt)
         if self.shared_hidden:
             with jax.named_scope("shared"):
-                hdn = (jax.nn.silu(xt @ p["shared_w1"])
-                       * (xt @ p["shared_w3"]))
-                y = y + (jax.nn.sigmoid(xt @ p["shared_gate"])
-                         * (hdn @ p["shared_w2"]))
+                out = (jax.nn.silu(xt @ p["shared_w1"])
+                       * (xt @ p["shared_w3"])) @ p["shared_w2"]
+                if self.shared_gate:
+                    out = jax.nn.sigmoid(xt @ p["shared_gate"]) * out
+                y = y + out
         return y.reshape(*lead, d)
 
     def _routed(self, ctx, p, xt):
@@ -337,16 +383,25 @@ class MoELayer(Module):
         c = self._capacity(n)
 
         with jax.named_scope("route"):
-            # router logits accumulate and soften in float32 whatever the
-            # activations' type: with 64 experts a bfloat16 softmax puts
+            # router logits accumulate and are scored in float32 whatever
+            # the activations' type: with 64 experts a bfloat16 softmax puts
             # near-ties among the top-k in the wrong order
-            probs = jax.nn.softmax(
-                jnp.dot(xt, p["router"],
-                        preferred_element_type=jnp.float32), axis=-1)
-            gate_vals, gate_idx = lax.top_k(probs, k)            # (N, k)
+            logits = jnp.dot(xt, p["router"],
+                             preferred_element_type=jnp.float32)
+            probs = (jax.nn.sigmoid(logits) if self.scoring == "sigmoid"
+                     else jax.nn.softmax(logits, axis=-1))
+            if self.selection_bias:
+                # the bias chooses, the unbiased scores weigh
+                _, gate_idx = lax.top_k(
+                    probs + p["router_bias"].astype(jnp.float32), k)
+                gate_vals = jnp.take_along_axis(probs, gate_idx, axis=-1)
+            else:
+                gate_vals, gate_idx = lax.top_k(probs, k)        # (N, k)
             if self.normalize_gates and k > 1:
                 gate_vals = gate_vals / jnp.maximum(
                     gate_vals.sum(-1, keepdims=True), 1e-9)
+            if self.routed_scale != 1.0:
+                gate_vals = gate_vals * self.routed_scale
             gate_vals = gate_vals.astype(xt.dtype)
 
             # slot assignment: flatten the k choices in priority order (all
@@ -536,7 +591,10 @@ class MoELayer(Module):
         worst-size buffer alone, 29 ms of a 178 ms prefill over 12 layers;
         over 96 rows 0.59 against 0.61 ms (PERF.md, PR 30).
         tests/test_qwen3_next.py sends every pick to held experts and
-        takes the fallback.
+        takes the fallback.  Where the share is small (12 of 384: the
+        buffer of every pick is ten times the usual one) buffers of four
+        times the rows stand between the two, so that one expert most of a
+        prompt picks costs a layer 1.4 ms and not 4.5 (PERF.md, PR 32).
         """
         e, k = self.experts_held, self.top_k
         n, d = xt.shape
@@ -548,10 +606,17 @@ class MoELayer(Module):
         rows = functools.partial(
             self._dropless_rows, p, xt, gate_vals, gate_idx, held, rank,
             counts, padded, cum_padded, b)
-        if usual >= worst:
-            return rows(worst)
-        return lax.cond(cum_padded[-1] <= usual, lambda: rows(usual),
-                        lambda: rows(worst))
+        sizes = [usual]
+        while 8 * sizes[-1] <= worst:
+            sizes.append(4 * sizes[-1])
+
+        def smallest(sizes):
+            if not sizes or sizes[0] >= worst:
+                return rows(worst)
+            return lax.cond(cum_padded[-1] <= sizes[0],
+                            lambda: rows(sizes[0]),
+                            lambda: smallest(sizes[1:]))
+        return smallest(sizes)
 
     def _dropless_rows(self, p, xt, gate_vals, gate_idx, held, rank, counts,
                        padded, cum_padded, b, m_rows):

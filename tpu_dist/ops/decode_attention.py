@@ -29,6 +29,16 @@ kernel makes the step's traffic follow the occupancy:
 A free slot is not visited: its pool row is untouched and its output row
 is zero.  Float pools only; the int8 cache's hoisted scales are a
 different kernel and stay on the dense branch.
+
+A second form, :func:`latent_decode_attention`, serves a LATENT pool
+``(B, C, Tmax)`` with no head axis (tpu_dist/nn/mla.py, the absorbed
+path): every head's query row against the SAME columns, the values the
+first ``value_dim`` rows of those columns.  Same work list, same ring of
+hand-made copies, same in-place write of the slab that holds the new
+column; with ``H`` query rows a block the scores and the weighted values
+are two MXU matmuls a block, ``(H, C) x (C, block)`` and ``(H, block) x
+(block, value_dim)``, and the softmax statistics are one ``(H, 1)`` column
+each.
 """
 
 from __future__ import annotations
@@ -42,7 +52,8 @@ import jax.numpy as jnp
 from ._pallas import (out_struct as _out_struct, sublane_tile,
                       use_interpret as _use_interpret)
 
-__all__ = ["decode_attention", "decode_attention_ok", "kv_blocks"]
+__all__ = ["decode_attention", "decode_attention_ok",
+           "latent_decode_attention", "kv_blocks"]
 
 _LANE = 128
 _NEG = -1e30   # finite: a lane that has seen no column yet stays NaN-free
@@ -53,8 +64,22 @@ _RING = 3      # K/V block buffers: two copies in flight, one computed on
 BLOCK_K = 256
 
 
-def _block_k(tmax: int) -> int:
-    return BLOCK_K if tmax % BLOCK_K == 0 else _LANE
+def _wait(copy) -> None:
+    """Block on a copy's DMA semaphore, inside a kernel: there is no peer
+    that could die and no deadline to pass."""
+    # tpudlint: disable=TD004  # a DMA semaphore inside a kernel, no peer
+    copy.wait()
+
+
+# The latent form's block: 576 numbers a column make a 256-column copy
+# 295 KB, too small to hide a copy's start-up.  Timed on the chip (PERF.md,
+# PR 32; 128 slots of ~1,950 columns, one layer): 128 columns 1.19 ms, 256
+# 0.78, 512 0.58 (kept), 1,024 0.61; a ring of 4 buffers changed nothing.
+LATENT_BLOCK_K = 512
+
+
+def _block_k(tmax: int, block: int = BLOCK_K) -> int:
+    return block if tmax % block == 0 else _LANE
 
 
 def decode_attention_ok(pool) -> bool:
@@ -70,7 +95,9 @@ def kv_blocks(lengths, tmax: int):
     a busy slot reads ``ceil((len + 1) / block)`` time blocks, clipped to
     the row; a free one (length 0) none.  Host arithmetic on a numpy
     vector — the engine's counter (``SlotEngine.stats()["decode_attn"]``)
-    and the kernel's own work list (:func:`_work_list`) count alike."""
+    and the kernel's own work list (:func:`_work_list`) count alike.  (The
+    latent form copies blocks of ``LATENT_BLOCK_K`` columns: at most one
+    of these blocks more a slot than counted here.)"""
     tk = _block_k(tmax)
     n = _blocks_per_slot(lengths, tmax, tk)
     return int(n.sum()), len(lengths) * -(-tmax // tk), tk
@@ -137,8 +164,7 @@ def _kernel(s_ref, x_ref, k_hbm, v_hbm, o_ref, ko_hbm, vo_hbm,
         slot, blk = s_ref[i], s_ref[g + i]
         ln = s_ref[2 * g + slot]
         for copy in reads(i):
-            # tpudlint: disable=TD004  # a DMA semaphore inside the kernel, no peer
-            copy.wait()
+            _wait(copy)
 
         @pl.when(i + _RING - 1 < total)
         def _():
@@ -192,8 +218,7 @@ def _kernel(s_ref, x_ref, k_hbm, v_hbm, o_ref, ko_hbm, vo_hbm,
             @pl.when(writing == 1)
             def _():                      # the slabs are free again
                 for copy in writes(0, 0):
-                    # tpudlint: disable=TD004  # a DMA semaphore, no peer
-                    copy.wait()
+                    _wait(copy)
 
             @pl.when(has_col)
             def _():
@@ -226,8 +251,7 @@ def _kernel(s_ref, x_ref, k_hbm, v_hbm, o_ref, ko_hbm, vo_hbm,
     @pl.when(writing == 1)
     def _():
         for copy in writes(0, 0):
-            # tpudlint: disable=TD004  # a DMA semaphore, no peer
-            copy.wait()
+            _wait(copy)
 
 
 def decode_attention(q, k_new, v_new, k_pool, v_pool, lengths):
@@ -299,3 +323,170 @@ def _call(q, k_new, v_new, k_pool, v_pool, lengths, *, interpret):
         name="decode_attention",
     )(scalars, x, k_pool, v_pool)
     return jnp.swapaxes(out, 1, 2), k_pool, v_pool
+
+
+# -- the latent form ----------------------------------------------------------
+
+def _latent_kernel(s_ref, q_ref, new_ref, pool_hbm, o_ref, out_hbm,
+                   buf, slab, rsem, wsem, m_ref, l_ref, acc_ref,
+                   *, g, tk, tmax, r, scale):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    slots = q_ref.shape[0]
+    total = s_ref[2 * g + slots]
+    f32 = jnp.float32
+    o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)     # free slots' rows
+
+    def read(i):
+        """The copy of entry ``i``'s block of columns into its buffer."""
+        cols = pl.ds(pl.multiple_of(s_ref[g + i] * tk, tk), tk)
+        return pltpu.make_async_copy(pool_hbm.at[s_ref[i], :, cols],
+                                     buf.at[i % _RING], rsem.at[i % _RING])
+
+    def write(slot, col):
+        """The copy of the new column's slab back into the pool."""
+        cols = pl.ds(pl.multiple_of(col, _LANE), _LANE)
+        return pltpu.make_async_copy(slab, out_hbm.at[slot, :, cols],
+                                     wsem.at[0])
+
+    for j in range(_RING - 1):
+        @pl.when(j < total)
+        def _():
+            read(j).start()
+
+    def entry(i, writing):
+        slot, blk = s_ref[i], s_ref[g + i]
+        ln = s_ref[2 * g + slot]
+        _wait(read(i))
+
+        @pl.when(i + _RING - 1 < total)
+        def _():
+            read(i + _RING - 1).start()
+
+        block = buf.at[i % _RING]
+
+        @pl.when(blk == 0)
+        def _():
+            m_ref[...] = jnp.full(m_ref.shape, _NEG, f32)
+            l_ref[...] = jnp.zeros(l_ref.shape, f32)
+            acc_ref[...] = jnp.zeros(acc_ref.shape, f32)
+
+        # the slot's last block holds column ``len`` (a column at Tmax:
+        # none, nothing to write): the new column joins the block in VMEM,
+        # is attended as one of its columns, and its slab goes back
+        is_last = blk == jnp.minimum((ln + tk) // tk, tmax // tk) - 1
+        has_col = ln < tmax
+
+        @pl.when(is_last & (writing == 1))
+        def _():                          # the slab is free again
+            _wait(write(0, 0))
+
+        @pl.when(is_last & has_col)
+        def _():
+            at = ln - blk * tk
+            cols = pl.ds(pl.multiple_of(at // _LANE * _LANE, _LANE), _LANE)
+            # this slot's column out of (C, B), B in the lanes, spread over
+            # 128 lanes: one small matmul with a one-hot of the slot
+            pick = (jax.lax.broadcasted_iota(jnp.int32, (new_ref.shape[1],
+                                                         _LANE), 0)
+                    == slot).astype(new_ref.dtype)
+            col = jnp.dot(new_ref[...], pick, preferred_element_type=f32)
+            lane = jax.lax.broadcasted_iota(jnp.int32, (1, _LANE), 1)
+            slab[...] = jnp.where(lane == at % _LANE, col,
+                                  block[:, cols].astype(f32)
+                                  ).astype(slab.dtype)
+            block[:, cols] = slab[...]
+            write(slot, ln // _LANE * _LANE).start()
+
+        q = q_ref[slot]                                       # (H, C)
+        s = jnp.dot(q, block[...], preferred_element_type=f32) * scale
+        col_id = blk * tk + jax.lax.broadcasted_iota(jnp.int32, (1, tk), 1)
+        seen = col_id < ln + has_col.astype(jnp.int32)
+        s = jnp.where(seen, s, _NEG)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.where(seen, jnp.exp(s - m_new), 0.0)          # (H, tk)
+        m_ref[...] = m_new
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
+            p.astype(block.dtype), block[:r, :], (((1,), (1,)), ((), ())),
+            preferred_element_type=f32)                       # (H, r)
+
+        @pl.when(is_last)
+        def _():
+            o_ref[slot] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+        return jnp.where(is_last, has_col.astype(jnp.int32), writing)
+
+    writing = jax.lax.fori_loop(0, total, entry, jnp.int32(0))
+
+    @pl.when(writing == 1)
+    def _():
+        _wait(write(0, 0))
+
+
+def latent_decode_attention(q, new, pool, lengths, *, value_dim: int,
+                            scale: float):
+    """One new position per slot against a time-last LATENT pool.
+
+    ``q``: ``(B, H, C)``, every head's query row over the latent's ``C``
+    numbers; ``new``: ``(B, C)``, the column to append; ``pool``: ``(B, C,
+    Tmax)``; ``lengths``: ``(B,)`` int, the positions resident in each
+    slot = the column the new one lands in.  Scores are ``scale * q .
+    column``; the values are the first ``value_dim`` numbers of each
+    column.  Returns ``(out (B, H, value_dim) in q.dtype, pool)`` with the
+    column written (the pool is aliased to the result: donate it).  Slot
+    ``b`` attends columns ``<= len[b]``, its own new one included; a slot
+    of length 0 is FREE: not read, not written, output zero."""
+    return _latent_call(q, new, pool, lengths, value_dim=value_dim,
+                        scale=float(scale), interpret=_use_interpret())
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("value_dim", "scale", "interpret"))
+def _latent_call(q, new, pool, lengths, *, value_dim, scale, interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, c, tmax = pool.shape
+    heads = q.shape[1]
+    tk = _block_k(tmax, LATENT_BLOCK_K)
+    scalars, g = _work_list(jnp.asarray(lengths, jnp.int32), tmax, tk)
+    q = q.astype(pool.dtype)
+    # the new columns as (C, B'): C in the sublanes as the pool has it, the
+    # slots in the lanes, padded to whole lane tiles
+    new_t = jnp.pad(new.astype(pool.dtype).T, ((0, 0), (0, -b % _LANE)))
+
+    def whole(*shape):
+        return pl.BlockSpec(shape, lambda i, s: (0,) * len(shape))
+
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(1,),
+        in_specs=[whole(*q.shape), whole(*new_t.shape), in_hbm],
+        out_specs=[whole(b, heads, value_dim), in_hbm],
+        scratch_shapes=[pltpu.VMEM((_RING, c, tk), pool.dtype),
+                        pltpu.VMEM((c, _LANE), pool.dtype),
+                        pltpu.SemaphoreType.DMA((_RING,)),
+                        pltpu.SemaphoreType.DMA((1,)),
+                        pltpu.VMEM((heads, 1), jnp.float32),
+                        pltpu.VMEM((heads, 1), jnp.float32),
+                        pltpu.VMEM((heads, value_dim), jnp.float32)])
+    out, pool = pl.pallas_call(
+        functools.partial(_latent_kernel, g=g, tk=tk, tmax=tmax,
+                          r=value_dim, scale=scale),
+        grid_spec=grid_spec,
+        out_shape=[_out_struct((b, heads, value_dim), q.dtype, q, new_t,
+                               pool),
+                   _out_struct(pool.shape, pool.dtype, q, new_t, pool)],
+        # operands count the scalar-prefetch vector: 3 is the pool
+        input_output_aliases={3: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=100 * 1024 * 1024),
+        interpret=interpret,
+        name="latent_decode_attention",
+    )(scalars, q, new_t, pool)
+    return out, pool

@@ -391,6 +391,7 @@ class DisaggSlotEngine(SlotEngine):
         self.hist_ttft.observe(_now() - req.t_submit)
         self.generated_tokens += 1
         self._maybe_finish(slot, tok)
+        self._flush()
         return slot
 
     def _obs_transfer(self, req: Request, arrival: dict,

@@ -427,6 +427,38 @@ def place_params(model, params):
         "leaves": len(jax.tree_util.tree_leaves(params))}
 
 
+def decode_need_facts(model, params) -> dict:
+    """What ONE decode step needs of ``params``, from their shapes alone:
+    ``fixed_bytes`` / ``fixed_params``, every leaf a step reads once
+    whatever it routes (all but the gathered tables, of which it reads one
+    row a slot, and the routed experts' stacked weights); ``experts``,
+    ``{MoELayer path: (bytes, parameters) of ONE expert}``, read only where
+    a row reaches it; ``attend_flops``, the operations one resident
+    position costs the attention layers, summed over them (each layer's
+    ``attend_flops_per_position``; a layer of whole state has none).  Host
+    facts for ``stats()["decode_need"]``, fixed at construction."""
+    from ..nn import MoELayer
+
+    tables = set(gathered_tables(model))
+    experts, routed = {}, set()
+    attend_flops = 0
+    for path, module in model.named_modules():
+        attend_flops += getattr(module, "attend_flops_per_position", 0)
+        if isinstance(module, MoELayer) and path in params:
+            own = {n: a for n, a in params[path].items()
+                   if n in ("w1", "w2", "w3", "b1", "b2")}
+            routed.update((path, n) for n in own)
+            held = module.experts_held
+            experts[path] = (sum(int(a.nbytes) for a in own.values()) // held,
+                             sum(int(a.size) for a in own.values()) // held)
+    fixed = [a for path, leaves in params.items()
+             for n, a in leaves.items()
+             if (path, n) not in tables and (path, n) not in routed]
+    return {"fixed_bytes": sum(int(a.nbytes) for a in fixed),
+            "fixed_params": sum(int(a.size) for a in fixed),
+            "experts": experts, "attend_flops": int(attend_flops)}
+
+
 def beside(params, state):
     """``state`` committed where the committed leaves of ``params`` are, if
     they share one sharding; as it came if none is committed.  A program's
@@ -545,6 +577,7 @@ class SlotEngine:
         # programs launched and not yet collected, oldest first; the live
         # mask of the last decode launch, uploaded again only when it changes
         self._flight: collections.deque = collections.deque()
+        self._flushers: list = []     # add_flusher
         self._live = (None, None)
         self._t_collected = 0.0
         # decode steps ever collected (reset_stats leaves it): the
@@ -580,6 +613,10 @@ class SlotEngine:
         # over decode iterations, host arithmetic like the blocks above
         self._slot_bytes = kvcache.slot_bytes(self.cache)
         self._state_bytes = self._kv_bytes = 0
+        # what a decode step needs of the parameters (host facts), and the
+        # busy rows and resident positions summed over decode iterations
+        self._need = decode_need_facts(model, self.params)
+        self._need_rows = self._need_positions = 0
 
         self._build_programs()
 
@@ -632,6 +669,22 @@ class SlotEngine:
     def idle(self) -> bool:
         """No slot occupied and no program in flight."""
         return not self.active.any() and not self._flight
+
+    def add_flusher(self, flush: Callable[[], None]) -> Callable[[], None]:
+        """Call ``flush`` each time a program's tokens have all been emitted
+        (inside its ``*.emit`` phase): how a frontend connection sends a
+        decode step's token frames as one write.  Returns the call that
+        takes it out again."""
+        self._flushers.append(flush)
+
+        def remove() -> None:
+            if flush in self._flushers:
+                self._flushers.remove(flush)
+        return remove
+
+    def _flush(self) -> None:
+        for flush in tuple(self._flushers):
+            flush()
 
     def occupancy(self) -> float:
         """Mean fraction of slots busy per decode step."""
@@ -775,8 +828,10 @@ class SlotEngine:
                 np.where(live, self.lengths, 0), self.max_len)[0]
             state, per_pos = self._slot_bytes
             self._state_bytes += 2 * state * len(rows)
-            self._kv_bytes += per_pos * int(self.lengths[rows].sum()
-                                            + len(rows))
+            positions = int(self.lengths[rows].sum()) + len(rows)
+            self._kv_bytes += per_pos * positions
+            self._need_rows += len(rows)
+            self._need_positions += positions
             if not np.array_equal(live, self._live[0]):
                 self._live = (live, jax.device_put(live))
             nxt_dev, self.cache, self._moe["decode"], self._slots = \
@@ -865,6 +920,7 @@ class SlotEngine:
                 self.generated_tokens += 1
                 emitted += 1
                 self._maybe_finish(slot, tok)
+            self._flush()
         if not prefill:
             self._occupied_slot_steps += emitted
             self._pipeline["wasted_rows"] += wasted
@@ -1017,6 +1073,7 @@ class SlotEngine:
         self._decode_steps = 0
         self._kv_blocks_read = 0
         self._state_bytes = self._kv_bytes = 0
+        self._need_rows = self._need_positions = 0
         self._pipeline = self._fresh_pipeline()
         reset_phases(SERVE_PHASES)
         # the device counters are never zeroed (a step in flight would
@@ -1034,20 +1091,30 @@ class SlotEngine:
                 "wasted_rows": 0}
 
     def _moe_read(self) -> dict:
-        """The routed-row counters per pool program, summed over layers, as
-        numpy int64 (one fetch per program; {} for a model with no expert
-        layer or an engine that keeps none)."""
+        """The routed-row counters per pool program, each stacked over the
+        expert layers in the order of their paths, as numpy int64 (one
+        fetch per program; {} for a model with no expert layer or an engine
+        that keeps none)."""
         import jax
 
         out = {}
         for phase, sets in dict(self._moe).items():
             if sets:
-                layers = jax.device_get(list(sets.values()))
-                out[phase] = {k: sum(np.asarray(l[k], np.int64)
-                                     for l in layers) for k in layers[0]}
+                layers = jax.device_get([sets[path] for path in sorted(sets)])
+                out[phase] = {k: np.stack([np.asarray(l[k], np.int64)
+                                           for l in layers])
+                              for k in layers[0]}
         return out
 
-    def _moe_stats(self) -> Optional[dict]:
+    def _moe_since(self) -> dict:
+        """The counters of :meth:`_moe_read` since ``reset_stats()``."""
+        base = self._moe_base
+        # int32 on the device: differences are right modulo 2**32
+        return {phase: {k: (v - base.get(phase, {}).get(k, 0)) % (1 << 32)
+                        for k, v in c.items()}
+                for phase, c in self._moe_read().items()}
+
+    def _moe_stats(self, since: dict) -> Optional[dict]:
         """``stats()["moe"]``: routed rows since ``reset_stats()``, per
         router expert (a request's picks only, summed over layers and both
         pool programs) and per program — ``rows`` a request's picks,
@@ -1057,14 +1124,9 @@ class SlotEngine:
         ``computed_rows`` the rows the expert matmuls ran over, ``calls`` of
         an expert layer, ``experts_hit`` summed over calls
         (``MoELayer.init_counters``)."""
-        now = self._moe_read()
-        if not now:
+        if not since:
             return None
-        base = self._moe_base
-        # int32 on the device: differences are right modulo 2**32
-        since = {phase: {k: (v - base.get(phase, {}).get(k, 0)) % (1 << 32)
-                         for k, v in c.items()} for phase, c in now.items()}
-        per_expert = sum(c["rows"] for c in since.values())
+        per_expert = sum(c["rows"].sum(0) for c in since.values())
         by_phase = {phase: {k: int(v.sum()) for k, v in c.items()}
                     for phase, c in since.items()}
         for c in by_phase.values():
@@ -1075,6 +1137,38 @@ class SlotEngine:
                                          "pad_rows", "computed_rows",
                                          "calls")},
                 "by_phase": by_phase}
+
+    def _decode_need_stats(self, since: dict) -> dict:
+        """``stats()["decode_need"]``: what the decode steps since
+        ``reset_stats()`` NEEDED of the chip by the mathematics, summed over
+        steps.  ``weight_bytes``: every parameter a step reads once (all
+        but the gathered tables and the routed experts) plus one expert's
+        weights for each held expert a request's row reached
+        (``experts_hit``); ``cache_bytes``: the resident columns of the
+        busy slots, the one written included, and twice their whole state;
+        ``flops``: 2 x the parameters a row uses (the fixed ones, and an
+        expert's for each pick on a held expert) + the attention's
+        operations a resident position.  Rows of free slots, the kernels'
+        padding and a dense branch's reading of the whole pool are nobody's
+        need.  ``steps`` are those launched, ``rows`` their busy slots and
+        ``positions`` the columns those held, the one written included;
+        host arithmetic but for the expert counters ``stats()["moe"]``
+        reads anyway."""
+        need, steps = self._need, self._pipeline["launches"]["decode"]
+        routed = since.get("decode", {})
+        per_expert = [need["experts"][path]
+                      for path in sorted(need["experts"])] if routed else []
+        hit = sum(int(n) * nbytes for n, (nbytes, _) in
+                  zip(routed.get("experts_hit", ()), per_expert))
+        picks = sum(int(n) * size for n, (_, size) in
+                    zip(routed.get("held_rows", ()), per_expert))
+        return {"steps": steps, "rows": int(self._need_rows),
+                "positions": int(self._need_positions),
+                "weight_bytes": steps * need["fixed_bytes"] + hit,
+                "cache_bytes": int(self._kv_bytes + self._state_bytes),
+                "flops": 2 * (need["fixed_params"] * int(self._need_rows)
+                              + picks)
+                + need["attend_flops"] * int(self._need_positions)}
 
     def _decode_attn_stats(self) -> dict:
         """``stats()["decode_attn"]``: how far the decode step's K/V traffic
@@ -1097,12 +1191,15 @@ class SlotEngine:
         two kinds of cache the decode steps had to touch for their busy
         slots, summed over steps: ``state_bytes`` (whole state, read and
         written) and ``kv_bytes`` (the K/V columns held, the new one
-        included).  ``"params"``: what :func:`place_params` did at
-        construction; ``reset_stats()`` leaves it."""
-        moe = self._moe_stats()
+        included).  ``"decode_need"``: :meth:`_decode_need_stats`.
+        ``"params"``: what :func:`place_params` did at construction;
+        ``reset_stats()`` leaves it."""
+        since = self._moe_since()
+        moe = self._moe_stats(since)
         return {
             **({"moe": moe} if moe else {}),
             "decode_attn": self._decode_attn_stats(),
+            "decode_need": self._decode_need_stats(since),
             "state": {"state_bytes": int(self._state_bytes),
                       "kv_bytes": int(self._kv_bytes)},
             "pipeline": {k: dict(v) if isinstance(v, dict) else v

@@ -45,10 +45,11 @@ import socket
 import struct
 import threading
 import time
-from typing import Dict, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
-from ..collectives.transport import (FrameCorruptError, _recv_exact,
-                                     _sendv, _tune_socket, frame_checksum)
+from ..collectives.transport import (FrameCorruptError, _net_chaos,
+                                     _recv_exact, _sendv, _tune_socket,
+                                     frame_checksum)
 from .scheduler import Scheduler
 
 __all__ = ["Frontend", "Gateway", "BACKEND_KEY", "GATEWAY_KEY",
@@ -77,7 +78,6 @@ def _net_serve_fault(sock, payload: bytes) -> bytes:
     truncate/reset writes must not interleave with a concurrent writer's
     frame."""
     import time as _time
-    from ..collectives.transport import _net_chaos
     nc = _net_chaos()  # THE shared sys.modules+env-guarded probe
     if nc is None:
         return payload
@@ -174,15 +174,20 @@ def send_frame(sock, obj: dict, lock: Optional[threading.Lock] = None) -> None:
     shared connection (token frames for different requests interleave) —
     fault injection runs under it too, so an injected truncate/reset
     cannot interleave raw bytes into another writer's in-flight frame."""
-    payload = json.dumps(obj).encode()
-    # checksum BEFORE fault injection: netchaos `corrupt` simulates bit
-    # flips on the wire, which is what the receiver must catch
-    header = _U32.pack(len(payload)) + _U32.pack(frame_checksum((payload,)))
+    header, payload = _encode_frame(obj)
     if lock is None:
         _send_frame_faulted(sock, header, payload)
     else:
         with lock:
             _send_frame_faulted(sock, header, payload)
+
+
+def _encode_frame(obj: dict) -> Tuple[bytes, bytes]:
+    payload = json.dumps(obj).encode()
+    # checksum BEFORE fault injection: netchaos `corrupt` simulates bit
+    # flips on the wire, which is what the receiver must catch
+    return (_U32.pack(len(payload)) + _U32.pack(frame_checksum((payload,))),
+            payload)
 
 
 def _send_frame_faulted(sock, header: bytes, payload: bytes) -> None:
@@ -345,17 +350,39 @@ class Frontend(_Listener):
         handles: Dict[object, object] = {}  # rid -> RequestHandle: the
         # submit handles stay owned (TD007) — errors also travel on them
 
+        # token frames of one collection (a decode step emits one per busy
+        # slot) leave as ONE write once the engine has emitted them all
+        # (SlotEngine.add_flusher): a syscall a frame kept the loop thread
+        # on the socket for a third of a 128-slot step.  Any other frame
+        # takes what is pending with it, so a request's frames keep their
+        # order.
+        pending: List[bytes] = []
+
+        def _write(frame: Tuple[bytes, bytes] = ()) -> None:
+            with send_mu:
+                parts = pending + list(frame)
+                pending.clear()
+                if not (parts and alive[0]):
+                    return
+                try:
+                    _send_frame_faulted(conn, b"".join(parts[:-1]), parts[-1])
+                except (OSError, ConnectionError):
+                    alive[0] = False   # client gone: stop pushing its frames
+
         def _send(obj: dict) -> None:
-            if not alive[0]:
-                return
-            try:
-                send_frame(conn, obj, lock=send_mu)
-            except (OSError, ConnectionError):
-                alive[0] = False   # client gone: stop pushing its frames
+            _write(_encode_frame(obj))
+
+        def _send_token(obj: dict) -> None:
+            if _net_chaos() is not None:   # faults are injected a frame
+                return _send(obj)
+            with send_mu:
+                pending.extend(_encode_frame(obj))
+
+        unhook = self.scheduler.engine.add_flusher(_write)
 
         def _callbacks(rid):
             def on_token(req, t):
-                _send({"type": "token", "id": rid, "t": t})
+                _send_token({"type": "token", "id": rid, "t": t})
 
             def on_done(req, reason):
                 handles.pop(rid, None)
@@ -418,6 +445,7 @@ class Frontend(_Listener):
             pass
         finally:
             alive[0] = False
+            unhook()
             # client gone: cancel everything it still had in flight — the
             # engine frees the slots at the next iteration boundary and
             # each request's obs span closes outcome=error:Cancelled,

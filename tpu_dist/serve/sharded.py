@@ -180,8 +180,9 @@ def _model_dims(model) -> dict:
     split."""
     import jax
     # the per-block segments address one K/V pool entry a block
-    kvcache.require_timed(jax.eval_shape(
-        lambda: model.init_slot_cache(1, 8)), "sharded serving")
+    pool = jax.eval_shape(lambda: model.init_slot_cache(1, 8))
+    kvcache.require_timed(pool, "sharded serving")
+    kvcache.require_heads(pool, "sharded serving")
     if getattr(model, "num_experts", 0):
         raise ShardConfigError(
             "sharded serving covers dense MLP blocks; MoE blocks are "
