@@ -5,16 +5,22 @@ JAX_PLATFORMS=cpu): same kernel code as the TPU path, checked for forward
 and gradient equality against tpu_dist.nn.attention's dense math.
 """
 
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from tpu_dist.nn.attention import scaled_dot_product_attention
-from tpu_dist.ops import flash_attention
+from tpu_dist.ops import flash_attention, flash_attention_with_lse
 
-# compile-heavy file: excluded from the fast tier (`pytest -m "not slow"`)
-pytestmark = pytest.mark.slow
+# ``tpu_dist.ops.flash_attention`` the attribute is the function
+fa = importlib.import_module("tpu_dist.ops.flash_attention")
+
+# the older, compile-heavy cases stay out of the fast tier (`pytest -m "not
+# slow"`); the sub-tile cases below them are tier-1
+slow = pytest.mark.slow
 
 
 def _rand_qkv(rng, b, tq, tk, h, d, dtype=jnp.float32):
@@ -24,6 +30,7 @@ def _rand_qkv(rng, b, tq, tk, h, d, dtype=jnp.float32):
     return q, k, v
 
 
+@slow
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("shape", [
     (2, 128, 128, 2, 64),     # exact tiles
@@ -42,6 +49,7 @@ def test_forward_matches_dense(rng, causal, shape):
     np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
 
 
+@slow
 def test_forward_bf16(rng):
     q, k, v = _rand_qkv(rng, 2, 256, 256, 2, 64, jnp.bfloat16)
     out = flash_attention(q, k, v, causal=True, block_q=128, block_k=128)
@@ -53,6 +61,7 @@ def test_forward_bf16(rng):
                                rtol=3e-2)
 
 
+@slow
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("shape", [
     (1, 128, 128, 2, 32),
@@ -79,66 +88,7 @@ def test_grads_match_dense(rng, causal, shape):
                                    err_msg=f"d{name}")
 
 
-@pytest.mark.parametrize("shape", [
-    (2, 256, 2, 64),    # 2 bands of 128 (the shape the split targets)
-    (1, 512, 2, 32),    # 4 bands
-])
-def test_split_causal_matches_dense(rng, shape):
-    """The diagonal/off-diagonal split (ops/flash_attention._split_lse) —
-    an opt-in variant (split_diag=True; default stays the single causal
-    call, which quiet-window A/B measured faster)."""
-    b, t, h, d = shape
-    q, k, v = _rand_qkv(rng, b, t, t, h, d)
-    out = flash_attention(q, k, v, causal=True, block_q=128, block_k=128,
-                          split_diag=True)
-    ref = scaled_dot_product_attention(q, k, v, causal=True)
-    np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
-
-    cot = jnp.asarray(rng.standard_normal((b, t, h, d)), jnp.float32)
-
-    def loss(q, k, v, split):
-        return jnp.vdot(flash_attention(q, k, v, causal=True, block_q=128,
-                                        block_k=128, split_diag=split), cot)
-
-    g_split = jax.grad(lambda *a: loss(*a, True), argnums=(0, 1, 2))(q, k, v)
-    g_dense = jax.grad(
-        lambda q, k, v: jnp.vdot(
-            scaled_dot_product_attention(q, k, v, causal=True), cot),
-        argnums=(0, 1, 2))(q, k, v)
-    for gs, gd, name in zip(g_split, g_dense, "qkv"):
-        np.testing.assert_allclose(gs, gd, atol=5e-4, rtol=5e-4,
-                                   err_msg=f"d{name}")
-
-
-def test_split_lse_and_cotangent_match_single(rng):
-    """flash_attention_with_lse parity between the split and single-call
-    paths, including the lse COTANGENT (the ring-attention merge
-    differentiates through lse, so the split must route it into the
-    softmax-jacobian correction identically)."""
-    from tpu_dist.ops import flash_attention_with_lse
-
-    q, k, v = _rand_qkv(rng, 1, 256, 256, 2, 32)
-
-    def loss(q, k, v, split):
-        o, lse = flash_attention_with_lse(q, k, v, causal=True, block_q=128,
-                                          block_k=128, split_diag=split)
-        return (o ** 2).sum() + 0.01 * (lse ** 2).sum()
-
-    (o_s, lse_s) = flash_attention_with_lse(q, k, v, causal=True,
-                                            block_q=128, block_k=128,
-                                            split_diag=True)
-    (o_1, lse_1) = flash_attention_with_lse(q, k, v, causal=True,
-                                            block_q=128, block_k=128,
-                                            split_diag=False)
-    np.testing.assert_allclose(o_s, o_1, atol=2e-5, rtol=2e-5)
-    np.testing.assert_allclose(lse_s, lse_1, atol=2e-5, rtol=2e-5)
-    g_s = jax.grad(lambda *a: loss(*a, True), argnums=(0, 1, 2))(q, k, v)
-    g_1 = jax.grad(lambda *a: loss(*a, False), argnums=(0, 1, 2))(q, k, v)
-    for a, b_, name in zip(g_s, g_1, "qkv"):
-        np.testing.assert_allclose(a, b_, atol=5e-4, rtol=5e-4,
-                                   err_msg=f"d{name}")
-
-
+@slow
 def test_jit_and_leading_batch_dims(rng):
     # extra leading dims + under jit (the TransformerLM call pattern)
     q = jnp.asarray(rng.standard_normal((2, 3, 64, 2, 32)), jnp.float32)
@@ -151,6 +101,7 @@ def test_jit_and_leading_batch_dims(rng):
     np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
 
 
+@slow
 def test_sdpa_impl_flash_dispatch(rng):
     q, k, v = _rand_qkv(rng, 1, 64, 64, 2, 32)
     out = scaled_dot_product_attention(q, k, v, causal=True, impl="flash")
@@ -161,16 +112,22 @@ def test_sdpa_impl_flash_dispatch(rng):
         scaled_dot_product_attention(q, k, v, mask=mask, impl="flash")
 
 
-def test_flash_under_shard_map(rng, eight_devices):
+@slow
+@pytest.mark.parametrize("t, block", [
+    (64, 1024),     # one grid tile a head
+    (256, 128),     # 2 x 2 grid tiles: a grid step picks its kind
+])
+def test_flash_under_shard_map(rng, eight_devices, t, block):
     # the DDP-wrapper path: pallas_call traced inside shard_map requires
     # vma-annotated out_shapes (regression test for the _out_struct fix)
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     mesh = jax.make_mesh((8,), ("data",))
-    q, k, v = _rand_qkv(rng, 16, 64, 64, 2, 32)
+    q, k, v = _rand_qkv(rng, 16, t, t, 2, 32)
 
     def local_loss(q, k, v):
-        o = flash_attention(q, k, v, causal=True)
+        o = flash_attention(q, k, v, causal=True, block_q=block,
+                            block_k=block)
         return jax.lax.pmean(jnp.sum(o ** 2), "data")
 
     loss_fn = jax.jit(jax.shard_map(
@@ -190,6 +147,7 @@ def test_flash_under_shard_map(rng, eight_devices):
                                atol=1e-4, rtol=1e-4)
 
 
+@slow
 def test_broadcast_kv_rejected(rng):
     # numpy-broadcast batch dims (shared KV) would silently misalign the
     # (B*H, T, D) flatten — must raise, and auto-dispatch must go dense
@@ -200,3 +158,263 @@ def test_broadcast_kv_rejected(rng):
     # dense path still supports it (and auto never routes this to flash)
     out = scaled_dot_product_attention(q, kv, kv, causal=True)
     assert out.shape == q.shape
+
+
+# ---------------------------------------------------------------------------
+# sub-tiles inside the grid step (tier-1)
+# ---------------------------------------------------------------------------
+
+def _tol(dtype):
+    # bf16 operands against the float32 dense composition: the forward bound
+    # test_forward_bf16 uses; gradients of a unit-normal cotangent sum ~T
+    # bf16 products
+    return (dict(atol=2e-5, rtol=2e-5), dict(atol=5e-4, rtol=5e-4)) \
+        if dtype == jnp.float32 else \
+        (dict(atol=3e-2, rtol=3e-2), dict(atol=8e-2, rtol=8e-2))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("shape", [
+    (1024, 1024, 64),   # the training cells' class: bf16 is ONE 1024 block of
+                        # 4 x 4 sub-tiles, f32 2 x 2 blocks of 512 (a
+                        # diagonal kind and a below-the-diagonal kind)
+    (1000, 1000, 64),   # padding ends inside the last sub-tile
+    (320, 320, 64),     # a 384 block walks in 128 sub-tiles
+    (100, 100, 48),     # T below one sub-tile, ragged D
+    (96, 160, 32),      # tq != tk
+    (640, 384, 64),     # tq > tk: the last rows see every key
+], ids=lambda s: "x".join(map(str, s)))
+def test_subtiled_causal_matches_dense(rng, shape, dtype):
+    """Forward and all three gradients of the sub-tiled causal path at the
+    default 1024 blocks against the dense composition in float32."""
+    tq, tk, d = shape
+    q, k, v = _rand_qkv(rng, 1, tq, tk, 2, d, dtype)
+    cot = jnp.asarray(rng.standard_normal((1, tq, 2, d)), jnp.float32)
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    fwd, bwd = _tol(dtype)
+
+    out = flash_attention(q, k, v, causal=True)
+    assert out.dtype == dtype
+    ref = scaled_dot_product_attention(*f32, causal=True)
+    np.testing.assert_allclose(out.astype(np.float32), ref, **fwd)
+
+    g_flash = jax.grad(lambda *a: jnp.vdot(
+        flash_attention(*a, causal=True).astype(jnp.float32), cot),
+        argnums=(0, 1, 2))(q, k, v)
+    g_dense = jax.grad(lambda *a: jnp.vdot(
+        scaled_dot_product_attention(*a, causal=True), cot),
+        argnums=(0, 1, 2))(*f32)
+    for gf, gd, name in zip(g_flash, g_dense, "qkv"):
+        np.testing.assert_allclose(gf.astype(np.float32), gd, **bwd,
+                                   err_msg=f"d{name}")
+
+
+def _dense_with_lse(q, k, v, causal, block):
+    """(out, lse) of (B, T, H, D) inputs in plain jnp, float32, under the
+    kernel's three-valued ``causal`` and its -1e30 convention for a row with
+    no visible key."""
+    tq, tk, d = q.shape[1], k.shape[1], q.shape[-1]
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(d)
+    qpos, kpos = jnp.arange(tq)[:, None], jnp.arange(tk)[None, :]
+    if causal is True:
+        mask = kpos <= qpos
+    elif causal == "offdiag":
+        mask = kpos // block < qpos // block
+    else:
+        mask = jnp.ones((tq, tk), bool)
+    s = jnp.where(mask, s, -jnp.inf)
+    seen = mask.any(axis=1)[None, None, :, None]
+    lse = jax.nn.logsumexp(jnp.where(seen, s, 0.0), axis=-1, keepdims=True)
+    p = jnp.where(seen, jnp.exp(s - lse), 0.0)
+    out = jnp.einsum("bhqk,bkhd->bqhd", p, v)
+    lse = jnp.where(seen, lse, -1e30)[..., 0]
+    return out, jnp.swapaxes(lse, 1, 2)                 # lse (B, Tq, H)
+
+
+def _lse_loss(o, lse):
+    # the lse cotangent is live; rows with no visible key (lse -1e30) are
+    # left out of it, as ring attention's merge weight leaves them out
+    return (o ** 2).sum() + 0.01 * (jnp.where(lse > -1e29, lse, 0.0) ** 2).sum()
+
+
+@pytest.mark.parametrize("shape", [(1024, 1024), (900, 700)],
+                         ids=lambda s: "x".join(map(str, s)))
+@pytest.mark.parametrize("causal", [False, "offdiag", True], ids=str)
+def test_lse_modes_match_dense(rng, causal, shape):
+    """out AND lse AND the gradients with a live lse cotangent, for the three
+    values of ``causal``, over 2 x 2 grid tiles of 2 x 2 sub-tiles."""
+    tq, tk = shape
+    q, k, v = _rand_qkv(rng, 1, tq, tk, 2, 32)
+    kw = dict(causal=causal, block_q=512, block_k=512)
+    o, lse = flash_attention_with_lse(q, k, v, **kw)
+    ro, rlse = _dense_with_lse(q, k, v, causal, 512)
+    np.testing.assert_allclose(o, ro, atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(lse, rlse, atol=2e-5, rtol=2e-5)
+    g = jax.grad(lambda *a: _lse_loss(*flash_attention_with_lse(*a, **kw)),
+                 argnums=(0, 1, 2))(q, k, v)
+    rg = jax.grad(lambda *a: _lse_loss(*_dense_with_lse(*a, causal, 512)),
+                  argnums=(0, 1, 2))(q, k, v)
+    for a, b, name in zip(g, rg, "qkv"):
+        np.testing.assert_allclose(a, b, atol=5e-4, rtol=5e-4,
+                                   err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_ring_merge_of_subtiled_blocks(rng, dtype):
+    """Ring attention's arithmetic on one device: a query shard attends its
+    own K/V block causally and an earlier block in full, and the two
+    (out, lse) pairs merge by the blockwise identity; value and gradients
+    (which reach both calls' lse cotangents) against dense causal attention
+    over the whole sequence."""
+    t = 512
+    q, k, v = _rand_qkv(rng, 1, 2 * t, 2 * t, 2, 64, dtype)
+    fwd, bwd = _tol(dtype)
+
+    def merged(q, k, v):
+        q2 = q[:, t:]
+        oa, la = flash_attention_with_lse(q2, k[:, :t], v[:, :t],
+                                          causal=False)
+        ob, lb = flash_attention_with_lse(q2, k[:, t:], v[:, t:],
+                                          causal=True)
+        m = jnp.maximum(la, lb)
+        wa, wb = jnp.exp(la - m)[..., None], jnp.exp(lb - m)[..., None]
+        return (oa.astype(jnp.float32) * wa
+                + ob.astype(jnp.float32) * wb) / (wa + wb)
+
+    def dense(q, k, v):
+        return scaled_dot_product_attention(q, k, v, causal=True)[:, t:]
+
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    np.testing.assert_allclose(merged(q, k, v), dense(*f32), **fwd)
+    cot = jnp.asarray(rng.standard_normal((1, t, 2, 64)), jnp.float32)
+    g = jax.grad(lambda *a: jnp.vdot(merged(*a), cot), (0, 1, 2))(q, k, v)
+    rg = jax.grad(lambda *a: jnp.vdot(dense(*a), cot), (0, 1, 2))(*f32)
+    for a, b, name in zip(g, rg, "qkv"):
+        np.testing.assert_allclose(a.astype(np.float32), b, **bwd,
+                                   err_msg=f"d{name}")
+
+
+def test_tile_plan_at_the_training_shape():
+    plan = fa.tile_plan(1024, 1024, True)
+    sub = plan["sub_q"]
+    assert plan["sub_k"] == sub == fa._SUB
+    n = 1024 // sub
+    assert plan["total"] == n * n
+    assert plan["executed"] == n * (n + 1) // 2     # 10 of 16 at 256
+    assert plan["masked"] == n                      # the diagonal's alone
+    # visible pairs 1024 * 1025 / 2, so a hair under (n + 1) / n = 1.25
+    assert plan["executed"] / plan["needed"] == pytest.approx(
+        (n + 1) / n, rel=2e-3)
+    full = fa.tile_plan(1024, 1024, False)
+    assert full["executed"] == full["total"] and full["masked"] == 0
+    assert full["executed"] / full["needed"] == 1.0
+
+
+def _case_id(case):
+    return "-".join(str(getattr(x, "__name__", x)) for x in case)
+
+
+_PLANS = [
+    # tq, tk, causal, block_q, block_k, dtype
+    (1024, 1024, True, 1024, 1024, jnp.bfloat16),
+    (1024, 1024, True, 1024, 1024, jnp.float32),    # 2 x 2 blocks of 512
+    (2048, 2048, True, 1024, 1024, jnp.bfloat16),
+    (2048, 2048, "offdiag", 1024, 1024, jnp.bfloat16),
+    (1000, 1000, True, 1024, 1024, jnp.bfloat16),
+    (1000, 1000, False, 1024, 1024, jnp.bfloat16),
+    (1100, 1100, True, 1024, 1024, jnp.bfloat16),
+    (320, 320, True, 1024, 1024, jnp.bfloat16),
+    (100, 100, True, 1024, 1024, jnp.bfloat16),
+    (96, 160, True, 1024, 1024, jnp.bfloat16),
+    (640, 384, True, 1024, 1024, jnp.bfloat16),
+    (900, 700, "offdiag", 512, 512, jnp.bfloat16),
+    (1536, 1536, True, 512, 256, jnp.bfloat16),     # blocks that differ
+    (1536, 1536, True, 256, 1024, jnp.bfloat16),
+]
+
+
+@pytest.mark.parametrize("case", _PLANS, ids=_case_id)
+def test_tile_plan_counts_what_the_mask_leaves(case):
+    """``tile_plan`` against the mask itself: inside the grid tiles that are
+    live, a sub-tile is executed iff some (query, key) pair of it is visible,
+    and masks iff some pair of it (padding included) is not."""
+    tq, tk, causal, bq, bk, dtype = case
+    plan = fa.tile_plan(tq, tk, causal, bq, bk, dtype)
+    bq, bk = fa._clamp_blocks(dtype, tq, tk, bq, bk)
+    sq, sk = plan["sub_q"], plan["sub_k"]
+    assert bq % sq == 0 and bk % sk == 0
+    tqp, tkp = -(-tq // bq) * bq, -(-tk // bk) * bk
+    qpos, kpos = np.arange(tqp)[:, None], np.arange(tkp)[None, :]
+    vis = np.broadcast_to(kpos < tk, (tqp, tkp)).copy()   # q padding computes
+    if causal is True:
+        vis &= kpos <= qpos
+        live = kpos // bk * bk <= qpos // bq * bq + bq - 1
+    elif causal == "offdiag":
+        live = kpos // bk * bk + bk <= qpos // bq * bq
+    else:
+        live = np.ones((tqp, tkp), bool)
+    vis &= live
+    tiles = vis.reshape(tqp // sq, sq, tkp // sk, sk)
+    some, every = tiles.any(axis=(1, 3)), tiles.all(axis=(1, 3))
+    assert plan["executed"] == some.sum()
+    assert plan["masked"] == (some & ~every).sum()
+    assert plan["total"] == some.size
+    needed = vis[:tq].sum() / (sq * sk)
+    assert plan["needed"] == pytest.approx(needed)
+
+
+@pytest.mark.parametrize("case", [
+    (1024, 1024, True, jnp.bfloat16),   # one grid tile, one kind
+    (1024, 1024, True, jnp.float32),    # 2 x 2 grid tiles of two kinds
+    (1000, 1000, False, jnp.bfloat16),
+    (640, 384, True, jnp.bfloat16),
+], ids=_case_id)
+def test_kernels_execute_the_plan(rng, monkeypatch, case):
+    """Count, in interpret mode, the sub-tiles whose scores each pass really
+    computes: every piece of scores goes through ``_scores_t`` (a run of
+    adjacent sub-tiles as one matmul: counted by its area), every piece that
+    masks through ``_visible``.  Forward, dQ and dK/dV each run
+    ``tile_plan``'s ``executed`` a head, ``masked`` of them with mask math."""
+    tq, tk, causal, dtype = case
+    heads = 2
+    plan = fa.tile_plan(tq, tk, causal, dtype=dtype)
+    area = plan["sub_q"] * plan["sub_k"]
+    counts = {"scores": 0, "masked": 0}
+
+    def counting(name, fn, shape_of):
+        def wrapped(*a, **kw):
+            rows, cols = shape_of(*a)
+            assert rows * cols % area == 0
+            jax.debug.callback(lambda: counts.__setitem__(
+                name, counts[name] + rows * cols // area))
+            return fn(*a, **kw)
+        return wrapped
+
+    # the calls are jitted: drop the traces other tests left (they would run
+    # without the counters) and, after, the ones made here
+    def drop_traces():
+        fa._fwd_call.clear_cache()
+        fa._bwd_call.clear_cache()
+
+    drop_traces()
+    monkeypatch.setattr(fa, "_scores_t", counting(
+        "scores", fa._scores_t, lambda k, q, _: (k.shape[0], q.shape[0])))
+    monkeypatch.setattr(fa, "_visible", counting(
+        "masked", fa._visible, lambda shape, *_, **__: shape))
+    q, k, v = _rand_qkv(rng, 1, tq, tk, heads, 64, dtype)
+    try:
+        out, vjp = jax.vjp(lambda *a: flash_attention(*a, causal=causal),
+                           q, k, v)
+        jax.block_until_ready(out)
+        jax.effects_barrier()
+        assert counts == {"scores": heads * plan["executed"],
+                          "masked": heads * plan["masked"]}
+        jax.block_until_ready(vjp(jnp.ones_like(out)))
+        jax.effects_barrier()
+        # forward once, then dQ and dK/dV
+        assert counts == {"scores": 3 * heads * plan["executed"],
+                          "masked": 3 * heads * plan["masked"]}
+    finally:
+        drop_traces()

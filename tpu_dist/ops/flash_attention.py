@@ -5,21 +5,52 @@ materializes the (Tq, Tk) score matrix in HBM; fine for the reference's
 image workloads, quadratic-memory death for long sequences.  This kernel is
 the single-device half of the long-context story (the cross-device half is
 tpu_dist.parallel.ring_attention, which rotates KV blocks over ICI with the
-same online-softmax recurrence): Q/K/V tiles stream HBM -> VMEM, scores for
-one (block_q, block_k) tile live only in VMEM/registers, and the softmax is
-accumulated online (flash recurrence), so memory is O(T) instead of O(T^2).
+same online-softmax recurrence): Q/K/V tiles stream HBM -> VMEM, scores
+live only in VMEM/registers, and the softmax is accumulated online (flash
+recurrence), so memory is O(T) instead of O(T^2).
+
+Two sizes of tile.  A GRID STEP holds one (block_q, block_k) tile of a
+head: its Q, K and V blocks are what one DMA brings into VMEM, and the
+1024 defaults are large so that few steps and few DMAs carry a sequence.
+Inside a grid step the kernels walk the tile in SUB-TILES of
+(_SUB x _SUB) scores, and a sub-tile is the unit that is computed, masked
+or skipped:
+
+- a sub-tile wholly above the causal diagonal, or wholly inside K's
+  padding, is never computed: no MXU pass, no exp, no mask;
+- a sub-tile wholly below the diagonal and inside the real keys takes no
+  mask math (no iotas, compare, select);
+- only a sub-tile that the diagonal or the padding's edge crosses masks.
+
+So causal attention at T = 1024 is one grid step a head and executes 10
+of its 16 sub-tiles of 256 (``tile_plan``).  All three kernels walk a tile
+the same way: by ROW of sub-tiles (``_SUB`` queries), each row against the
+keys ``_k_range`` leaves it — its unmasked sub-tiles first, as one piece
+of scores, then those that mask — and on TRANSPOSED scores (keys x
+queries): a row's q (and dO) stay in the MXU while its keys stream past,
+every per-query statistic (running max and sum, lse, delta) is a row
+(1, _SUB) that broadcasts down the keys, and the softmax's reductions run
+down the keys too, vreg on vreg, not across lanes.  A pass first makes
+every row's score matmuls, then every row's elementwise work, then every
+row's output matmuls: the order the chip's scheduler overlaps best.
+
+Which sub-tiles run is plain integer arithmetic on a tile's position.
+When a kernel is traced it lists the few ways a live tile of its grid can
+lie against the diagonal and K's end (``_by_kind``: the diagonal's tile, a
+tile below it, the one K's padding ends in) and unrolls one body a kind
+with static slices; a grid step computes its own ranges as scalars and
+runs the body they equal.
 
 Layout (kernel-internal): (BH, T, D) with a (BH, nq, nk) grid; the KV index
 is innermost so the f32 accumulators (m, l, acc) persist in VMEM scratch
 across a Q row's KV sweep and the output tile is written back to HBM once.
-Forward saves per-row logsumexp; backward recomputes score tiles from
+Forward saves per-row logsumexp; backward recomputes scores from
 (q, k, lse) flash-style — two kernels, one accumulating dQ over the KV
 sweep, one accumulating dK/dV over the Q sweep (grid transposed so the
 accumulators stay resident).  Residuals are just (q, k, v, o, lse): no
 (Tq, Tk) tensor is ever materialized, forward or backward.
 
-Causal masking is applied per-tile from global positions; tiles entirely
-above the diagonal are predicated off with ``pl.when`` (no MXU work, the
+Grid tiles entirely above the diagonal match no kind and run nothing (the
 grid still sweeps them).  Runs on TPU via Mosaic; everywhere else (CPU
 tests) through ``interpret=True`` — same kernel, same numerics (tests
 compare forward and grads against the dense composition).
@@ -40,19 +71,21 @@ import jax.numpy as jnp
 from ._pallas import (ceil_to as _ceil_to, out_struct as _out_struct,
                       use_interpret as _use_interpret)
 
-__all__ = ["flash_attention", "flash_attention_with_lse"]
+__all__ = ["flash_attention", "flash_attention_with_lse", "tile_plan"]
 
 _LANE = 128
 _D_ALIGN = 64  # head_dim alignment: 64 halves K/V DMA for d=64 vs padding to 128
 _NEG_INF = -1e30  # finite: keeps max/correction arithmetic NaN-free when a
                   # whole tile is masked (same sentinel as ring_attention)
+_SUB = 256  # edge of a sub-tile; timed on the chip against 128 and 512
+            # (PERF.md section 6, PR 33)
 
 
 def _clamp_blocks(dtype, tq, tk, block_q, block_k):
-    """Tile sizes that fit VMEM: the 1024 defaults are tuned for bf16; with
-    f32 inputs the tile intermediates double and the dK/dV kernel's
-    (block_q, block_k) f32 score/prob/ds tiles blow the ~16 MB VMEM budget
-    at 1024² (observed: 16.17M > 16M on v5e) — halve for 4-byte dtypes."""
+    """Grid-step tile sizes that fit VMEM: the 1024 defaults are for bf16;
+    for 4-byte inputs they are halved (the dK/dV kernel passed the ~16 MB
+    VMEM budget at 1024^2 when it held whole-tile f32 intermediates; not
+    timed again since the intermediates are sub-tiles)."""
     if jnp.dtype(dtype).itemsize >= 4:
         block_q = min(block_q, 512)
         block_k = min(block_k, 512)
@@ -60,38 +93,41 @@ def _clamp_blocks(dtype, tq, tk, block_q, block_k):
             min(block_k, _ceil_to(tk, _LANE)))
 
 
+def _sub_edge(block):
+    """Edge of the sub-tiles a grid step of ``block`` rows (or columns) is
+    walked in: the largest divisor of the block that _SUB allows, or the
+    whole block where that would leave the 128-lane tiling."""
+    sub = math.gcd(block, _SUB)
+    return sub if sub % _LANE == 0 else block
+
+
 # ---------------------------------------------------------------------------
-# forward
+# which sub-tiles run: integer arithmetic on Python ints or kernel scalars
 # ---------------------------------------------------------------------------
 
-def _masked_scores(q, k, sm_scale, tk, causal, q_lo, k_lo):
-    """(block_q, block_k) score tile on the MXU (f32 accumulation), with
-    out-of-range and above-diagonal entries set to _NEG_INF.  The single
-    source of the score/mask convention shared by the forward and both
-    backward kernels.
-
-    ``causal`` is three-valued: ``True`` masks above the diagonal,
-    ``False`` doesn't, and ``"offdiag"`` also doesn't — its tiles sit
-    strictly below the diagonal band by the grid predicate, so per-element
-    causal mask math (two iotas + compare + select per tile) is skipped
-    entirely; only the K padding range check remains."""
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    s = s * sm_scale
-    kpos = k_lo + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    mask = kpos < tk
+def _k_range(causal, q_lo, sq, k_lo, sk, n_sk, tk):
+    """``(n_full, n_live)`` for the q sub-tile of rows [q_lo, q_lo + sq)
+    against the ``n_sk`` k sub-tiles of ``sk`` columns that start at
+    ``k_lo``: sub-tiles [0, n_full) hold visible scores only and take no
+    mask, [n_full, n_live) are crossed by the diagonal or by the end of the
+    real keys and mask, [n_live, n_sk) hold nothing visible and are never
+    computed.  ``causal`` as in _visible; positions are Python ints
+    (``tile_plan``, ``_by_kind``) or scalars of a kernel."""
+    ints = isinstance(q_lo, int) and isinstance(k_lo, int)
+    lo, clip = (min, lambda x: min(max(x, 0), n_sk * sk)) if ints else (
+        jnp.minimum, lambda x: jnp.clip(x, 0, n_sk * sk))
+    full = live = tk - k_lo              # columns before K's padding
     if causal is True:
-        qpos = q_lo + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        mask = mask & (kpos <= qpos)
-    return jnp.where(mask, s, _NEG_INF), mask
+        full = lo(full, q_lo + 1 - k_lo)     # ... that all rows see
+        live = lo(live, q_lo + sq - k_lo)    # ... that the last row sees
+    return clip(full) // sk, (clip(live) + sk - 1) // sk
 
 
 def _tile_live(causal, q_lo, k_lo, block_q, block_k):
     """Grid predicate: does tile (q_lo, k_lo) contribute any unmasked
     entries?  ``True`` = tiles intersecting or below the diagonal;
-    ``"offdiag"`` = tiles STRICTLY below the diagonal band (the masked
-    diagonal tiles are handled by a separate finer-tiled causal call —
-    see _split_lse); ``False`` = all tiles."""
+    ``"offdiag"`` = tiles STRICTLY below the diagonal band; ``False`` = all
+    tiles."""
     if causal is True:
         return k_lo <= q_lo + block_q - 1
     if causal == "offdiag":
@@ -99,22 +135,179 @@ def _tile_live(causal, q_lo, k_lo, block_q, block_k):
     return k_lo >= 0  # trivially true (kernel body must sit under pl.when)
 
 
-def _tile_probs(q_ref, k_ref, lse_ref, sm_scale, tk, causal, q_lo, k_lo):
-    """Recompute the softmax probabilities of one tile from (q, k, lse) —
-    the flash-backward recurrence shared by the dQ and dK/dV kernels."""
-    s, mask = _masked_scores(q_ref[0], k_ref[0], sm_scale, tk, causal,
-                             q_lo, k_lo)
-    p = jnp.exp(s - lse_ref[0])                             # (bq, bk) f32
-    return jnp.where(mask, p, 0.0)
+def _plan(causal, tk, block_q, block_k):
+    """``(sq, sk, k_ranges)``: the sub-tile's edges in a grid tile of these
+    blocks, and the tile at ``(q_lo, k_lo)``'s _k_range a q sub-tile."""
+    sq, sk = _sub_edge(block_q), _sub_edge(block_k)
+
+    def k_ranges(q_lo, k_lo):
+        return tuple(_k_range(causal, q_lo + r, sq, k_lo, sk, block_k // sk,
+                              tk) for r in range(0, block_q, sq))
+    return sq, sk, k_ranges
 
 
-def _make_fwd_kernel(sm_scale, tk, block_q, block_k, causal):
+def _live_tiles(causal, nq, nk, block_q, block_k):
+    return [(q_lo, k_lo) for q_lo in range(0, nq * block_q, block_q)
+            for k_lo in range(0, nk * block_k, block_k)
+            if _tile_live(causal, q_lo, k_lo, block_q, block_k)]
+
+
+def tile_plan(tq, tk, causal, block_q: int = 1024, block_k: int = 1024,
+              dtype=jnp.bfloat16) -> dict:
+    """What one head's attention call executes, from shapes alone, by the
+    arithmetic the kernels run on (_tile_live, _k_range).
+
+    ``executed``: sub-tiles a pass computes; ``masked``: those of them that
+    take mask math; ``total``: sub-tiles of the padded (Tq, Tk) grid;
+    ``needed``: the visible (query, key) pairs in units of one sub-tile's
+    area, what the mathematics asks for; ``sub_q`` / ``sub_k``: a sub-tile's
+    edges.  ``executed / needed`` is 1.0 when nothing masked or padded is
+    computed: 10 sub-tiles of 256 for 8.008 needed, 1.249, at causal
+    T = 1024."""
+    if not isinstance(causal, str):
+        causal = bool(causal)
+    block_q, block_k = _clamp_blocks(dtype, tq, tk, block_q, block_k)
+    sq, sk, k_ranges = _plan(causal, tk, block_q, block_k)
+    nq, nk = -(-tq // block_q), -(-tk // block_k)
+    ranges = [r for tile in _live_tiles(causal, nq, nk, block_q, block_k)
+              for r in k_ranges(*tile)]
+    if causal is True:
+        pairs = sum(min(q + 1, tk) for q in range(tq))
+    elif causal == "offdiag":
+        pairs = sum(min(q // block_q * block_q // block_k * block_k, tk)
+                    for q in range(tq))
+    else:
+        pairs = tq * tk
+    return {"executed": sum(live for _, live in ranges),
+            "masked": sum(live - full for full, live in ranges),
+            "total": nq * (block_q // sq) * nk * (block_k // sk),
+            "needed": pairs / (sq * sk), "sub_q": sq, "sub_k": sk}
+
+
+# ---------------------------------------------------------------------------
+# what the three kernels share
+# ---------------------------------------------------------------------------
+
+def _scores_t(k, q, sm_scale):
+    """Scaled scores of a q sub-tile against a run of keys, TRANSPOSED:
+    (keys, queries), on the MXU with f32 accumulation."""
+    s = jax.lax.dot_general(k, q, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    return s * sm_scale
+
+
+def _visible(shape, causal, tk, q_lo, k_lo):
+    """Mask of a (keys, queries) piece of scores whose first query is
+    ``q_lo`` and first key ``k_lo`` (scalars of the kernel).  The single
+    source of the mask convention shared by the forward and both backward
+    kernels.
+
+    ``causal`` is three-valued: ``True`` masks above the diagonal,
+    ``False`` doesn't, and ``"offdiag"`` also doesn't — its tiles sit
+    strictly below the diagonal band by the grid predicate; only the K
+    padding range check remains."""
+    key = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    mask = key < tk - k_lo
+    if causal is True:
+        query = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        mask = mask & (key - query <= q_lo - k_lo)
+    return mask
+
+
+def _sub_tiles(kind, sq, sk):
+    """``(rows, first query, full, live)`` of each q sub-tile of a kind
+    that executes anything: its queries as a slice of the block, and the
+    keys it runs as counts from the block's first — [0, full) unmasked,
+    [full, live) masked."""
+    return [(slice(i * sq, (i + 1) * sq), i * sq, n_full * sk, n_live * sk)
+            for i, (n_full, n_live) in enumerate(kind) if n_live]
+
+
+def _key_pieces(x, full, live):
+    """A sub-tile's (keys, ...) array as ``(piece, first key, masked)``: the
+    run of sub-tiles that take no mask, then the run that does."""
+    return [(x[a:b], a, masked)
+            for a, b, masked in ((0, full, False), (full, live, True))
+            if b > a]
+
+
+def _join(parts):
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
+
+
+def _bwd_pieces(refs, tiles, sm_scale, causal, tk, q_lo, k_lo):
+    """``(p^T, ds^T)`` in the inputs' dtype, (keys, queries), of each q
+    sub-tile in ``tiles``: the part of the backward pass that dQ and dK/dV
+    both need.  ds = p * (dp - delta); q/k/v/do stay in their input dtype:
+    bf16 inputs run bf16 MXU passes with f32 accumulation."""
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs
+    made = [(_scores_t(k_ref[0, :live, :], q_ref[0, rows, :], sm_scale),
+             jax.lax.dot_general(v_ref[0, :live, :], do_ref[0, rows, :],
+                                 (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32))
+            for rows, _, _, live in tiles]
+    out = []
+    for (rows, r0, full, live), (s, dp) in zip(tiles, made):
+        ps, dss = [], []
+        for x, c0, masked in _key_pieces(s, full, live):
+            # the flash-backward recurrence: probabilities recomputed from
+            # the scores and the queries' lse
+            p = jnp.exp(x - lse_ref[0, :, rows])
+            if masked:
+                p = jnp.where(_visible(p.shape, causal, tk, q_lo + r0,
+                                       k_lo + c0), p, 0.0)
+            ps.append(p.astype(q_ref.dtype))
+            dss.append((p * (dp[c0:c0 + x.shape[0]] - delta_ref[0, :, rows])
+                        ).astype(q_ref.dtype))
+        out.append((_join(ps), _join(dss)))
+    return out
+
+
+def _by_kind(causal, tk, block_q, block_k, nq, nk):
+    """``(sq, sk, run)`` for a kernel over this grid.  When the kernel is
+    traced, the distinct ``k_ranges`` of the grid's live tiles are listed:
+    every KIND of tile, every way one can lie against the diagonal and K's
+    end.  ``run(q_lo, k_lo, body)``, in a grid step whose tile starts at
+    those scalars, runs ``body(kind)`` for the kind the step's own ranges
+    equal, and nothing for a tile that is not live: each body's slices are
+    static, its sweep unrolled, and it sits under ``pl.when`` (see
+    _use_interpret for why it must either way)."""
     from jax.experimental import pallas as pl
 
+    sq, sk, k_ranges = _plan(causal, tk, block_q, block_k)
+    kinds = list(dict.fromkeys(
+        k_ranges(*tile)
+        for tile in _live_tiles(causal, nq, nk, block_q, block_k)))
+
+    def run(q_lo, k_lo, body):
+        ranges = k_ranges(q_lo, k_lo)
+        live = _tile_live(causal, q_lo, k_lo, block_q, block_k)
+        for kind in kinds:
+            hit = live
+            for (a, b), (ka, kb) in zip(ranges, kind):
+                hit = hit & (a == ka) & (b == kb)
+            pl.when(hit)(functools.partial(body, kind))
+    return sq, sk, run
+
+
+def _col(x):
+    """A row (1, n) of per-query statistics as a column (n, 1)."""
+    return jnp.transpose(jnp.broadcast_to(x, (_LANE, x.shape[1])))[:, 0:1]
+
+
+# ---------------------------------------------------------------------------
+# forward
+# ---------------------------------------------------------------------------
+
+def _make_fwd_kernel(sm_scale, tk, block_q, block_k, causal, nq, nk):
+    from jax.experimental import pallas as pl
+
+    sq, sk, run = _by_kind(causal, tk, block_q, block_k, nq, nk)
+
     def kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr):
-        qi = pl.program_id(1)
         ki = pl.program_id(2)
-        nk = pl.num_programs(2)
+        q_lo = pl.program_id(1) * block_q
+        k_lo = ki * block_k
 
         @pl.when(ki == 0)
         def _init():
@@ -122,50 +315,65 @@ def _make_fwd_kernel(sm_scale, tk, block_q, block_k, causal):
             l_scr[:] = jnp.zeros(l_scr.shape, jnp.float32)
             acc_scr[:] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-        q_lo = qi * block_q
-        k_lo = ki * block_k
+        def body(kind):
+            tiles = _sub_tiles(kind, sq, sk)
+            scores = [_scores_t(k_ref[0, :live, :], q_ref[0, rows, :],
+                                sm_scale) for rows, _, _, live in tiles]
+            probs = []
+            for (rows, r0, full, live), s in zip(tiles, scores):
+                # one online-softmax step a q sub-tile, statistics as rows
+                pieces = []
+                for x, c0, masked in _key_pieces(s, full, live):
+                    mask = None
+                    if masked:
+                        mask = _visible(x.shape, causal, tk, q_lo + r0,
+                                        k_lo + c0)
+                        x = jnp.where(mask, x, _NEG_INF)
+                    pieces.append((x, mask))
+                m_prev = m_scr[:, rows]
+                m_new = m_prev
+                for x, _ in pieces:
+                    m_new = jnp.maximum(m_new,
+                                        jnp.max(x, axis=0, keepdims=True))
+                alpha = jnp.exp(m_prev - m_new)
+                l_new = alpha * l_scr[:, rows]
+                ps = []
+                for x, mask in pieces:
+                    p = jnp.exp(x - m_new)
+                    if mask is not None:
+                        # fully-masked queries: x == m_new == _NEG_INF gives
+                        # exp(0) = 1; zero them so they contribute nothing
+                        p = jnp.where(mask, p, 0.0)
+                    l_new = l_new + jnp.sum(p, axis=0, keepdims=True)
+                    ps.append(p.astype(v_ref.dtype))
+                m_scr[:, rows] = m_new
+                l_scr[:, rows] = l_new
+                probs.append((_join(ps), alpha))
+            for (rows, _, _, live), (p, alpha) in zip(tiles, probs):
+                pv = jax.lax.dot_general(p, v_ref[0, :live, :],
+                                         (((0,), (0,)), ((), ())),
+                                         preferred_element_type=jnp.float32)
+                if nk > 1:      # an earlier k tile's part, at the new max
+                    pv = pv + acc_scr[rows, :] * _col(alpha)
+                acc_scr[rows, :] = pv
 
-        def body():
-            s, mask = _masked_scores(q_ref[0], k_ref[0], sm_scale, tk,
-                                     causal, q_lo, k_lo)
-            m_prev = m_scr[:, 0:1]
-            l_prev = l_scr[:, 0:1]
-            m_cur = jnp.max(s, axis=1, keepdims=True)
-            m_new = jnp.maximum(m_prev, m_cur)
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new)
-            # fully-masked rows: s == m_new == _NEG_INF gives exp(0) = 1;
-            # zero them so they contribute nothing
-            p = jnp.where(mask, p, 0.0)
-            l_scr[:] = jnp.broadcast_to(
-                alpha * l_prev + jnp.sum(p, axis=1, keepdims=True),
-                l_scr.shape)
-            v = v_ref[0]
-            pv = jax.lax.dot_general(p.astype(v.dtype), v,
-                                     (((1,), (0,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-            acc_scr[:] = acc_scr[:] * alpha + pv
-            m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-
-        # tiles contributing nothing (above the diagonal / diagonal band)
-        # are predicated off; non-causal uses a trivially-true predicate
-        # (see _use_interpret for why the body must be under pl.when
-        # either way)
-        @pl.when(_tile_live(causal, q_lo, k_lo, block_q, block_k))
-        def _():
-            body()
+        run(q_lo, k_lo, body)
 
         @pl.when(ki == nk - 1)
         def _fin():
-            m = m_scr[:, 0:1]
-            l = l_scr[:, 0:1]
+            l = l_scr[:]
             l_safe = jnp.where(l == 0.0, 1.0, l)
-            o_ref[0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
-            lse_ref[0] = m + jnp.log(l_safe)
+            o_ref[0] = (acc_scr[:] / _col(l_safe)).astype(o_ref.dtype)
+            lse_ref[0] = m_scr[:] + jnp.log(l_safe)
 
     return kernel
 
 
+# jitted so that a model's layers share ONE trace and one lowered function
+# of the call: the kernels' bodies are unrolled, and tracing and lowering them
+# afresh at each of 24 layers' call sites cost a training process 50 s of
+# set-up on every start
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
 def _fwd_call(q, k, v, causal, sm_scale, block_q, block_k):
     """q: (BH, Tq, D); k, v: (BH, Tk, D) -> (o, lse) with lse (BH, Tq, 1)."""
     from jax.experimental import pallas as pl
@@ -178,10 +386,10 @@ def _fwd_call(q, k, v, causal, sm_scale, block_q, block_k):
     qp = jnp.pad(q, ((0, 0), (0, tqp - tq), (0, dp - d)))
     kp = jnp.pad(k, ((0, 0), (0, tkp - tk), (0, dp - d)))
     vp = jnp.pad(v, ((0, 0), (0, tkp - tk), (0, dp - d)))
-    grid = (bh, tqp // block_q, tkp // block_k)
+    nq, nk = tqp // block_q, tkp // block_k
     o, lse = pl.pallas_call(
-        _make_fwd_kernel(sm_scale, tk, block_q, block_k, causal),
-        grid=grid,
+        _make_fwd_kernel(sm_scale, tk, block_q, block_k, causal, nq, nk),
+        grid=(bh, nq, nk),
         in_specs=[
             pl.BlockSpec((1, block_q, dp), lambda b, i, j: (b, i, 0),
                          memory_space=pltpu.VMEM),
@@ -193,62 +401,55 @@ def _fwd_call(q, k, v, causal, sm_scale, block_q, block_k):
         out_specs=[
             pl.BlockSpec((1, block_q, dp), lambda b, i, j: (b, i, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0),
+            pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i),
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
             _out_struct((bh, tqp, dp), q.dtype, qp, kp, vp),
-            _out_struct((bh, tqp, 1), jnp.float32, qp, kp, vp),
+            _out_struct((bh, 1, tqp), jnp.float32, qp, kp, vp),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, _LANE), jnp.float32),   # running max m
-            pltpu.VMEM((block_q, _LANE), jnp.float32),   # running sum l
+            pltpu.VMEM((1, block_q), jnp.float32),       # running max m
+            pltpu.VMEM((1, block_q), jnp.float32),       # running sum l
             pltpu.VMEM((block_q, dp), jnp.float32),      # output accumulator
         ],
         interpret=_use_interpret(),
         name="flash_fwd",
     )(qp, kp, vp)
-    return o[:, :tq, :d], lse[:, :tq]
+    return o[:, :tq, :d], lse[:, 0, :tq, None]
 
 
 # ---------------------------------------------------------------------------
 # backward
 # ---------------------------------------------------------------------------
 
-def _make_dq_kernel(sm_scale, tk, block_q, block_k, causal):
+def _make_dq_kernel(sm_scale, tk, block_q, block_k, causal, nq, nk):
     from jax.experimental import pallas as pl
+
+    sq, sk, run = _by_kind(causal, tk, block_q, block_k, nq, nk)
 
     def kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                acc_scr):
-        qi = pl.program_id(1)
         ki = pl.program_id(2)
-        nk = pl.num_programs(2)
+        q_lo = pl.program_id(1) * block_q
+        k_lo = ki * block_k
 
         @pl.when(ki == 0)
         def _init():
             acc_scr[:] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-        q_lo = qi * block_q
-        k_lo = ki * block_k
+        def body(kind):
+            tiles = _sub_tiles(kind, sq, sk)
+            pieces = _bwd_pieces(
+                (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), tiles,
+                sm_scale, causal, tk, q_lo, k_lo)
+            for (rows, _, _, live), (_, ds) in zip(tiles, pieces):
+                acc_scr[rows, :] = acc_scr[rows, :] + (
+                    sm_scale * jax.lax.dot_general(
+                        ds, k_ref[0, :live, :], (((0,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32))
 
-        def body():
-            # q/k/v/do stay in their input dtype: bf16 inputs run bf16 MXU
-            # passes with f32 accumulation (preferred_element_type)
-            k = k_ref[0]
-            v = v_ref[0]
-            do = do_ref[0]
-            p = _tile_probs(q_ref, k_ref, lse_ref, sm_scale, tk, causal,
-                            q_lo, k_lo)
-            dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-            ds = (p * (dp - delta_ref[0])).astype(k.dtype)  # (bq, bk)
-            acc_scr[:] = acc_scr[:] + sm_scale * jax.lax.dot_general(
-                ds, k, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-
-        @pl.when(_tile_live(causal, q_lo, k_lo, block_q, block_k))
-        def _():
-            body()
+        run(q_lo, k_lo, body)
 
         @pl.when(ki == nk - 1)
         def _fin():
@@ -257,43 +458,39 @@ def _make_dq_kernel(sm_scale, tk, block_q, block_k, causal):
     return kernel
 
 
-def _make_dkv_kernel(sm_scale, tk, block_q, block_k, causal):
+def _make_dkv_kernel(sm_scale, tk, block_q, block_k, causal, nq, nk):
     from jax.experimental import pallas as pl
+
+    sq, sk, run = _by_kind(causal, tk, block_q, block_k, nq, nk)
 
     def kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                dk_ref, dv_ref, dk_scr, dv_scr):
-        ki = pl.program_id(1)
         qi = pl.program_id(2)
-        nq = pl.num_programs(2)
+        k_lo = pl.program_id(1) * block_k
+        q_lo = qi * block_q
 
         @pl.when(qi == 0)
         def _init():
             dk_scr[:] = jnp.zeros(dk_scr.shape, jnp.float32)
             dv_scr[:] = jnp.zeros(dv_scr.shape, jnp.float32)
 
-        q_lo = qi * block_q
-        k_lo = ki * block_k
+        def body(kind):
+            tiles = _sub_tiles(kind, sq, sk)
+            pieces = _bwd_pieces(
+                (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), tiles,
+                sm_scale, causal, tk, q_lo, k_lo)
+            for (rows, _, _, live), (p, ds) in zip(tiles, pieces):
+                # padded q rows contribute nothing: their do and delta are
+                # zero
+                dv_scr[:live, :] = dv_scr[:live, :] + jax.lax.dot_general(
+                    p, do_ref[0, rows, :], (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                dk_scr[:live, :] = dk_scr[:live, :] + (
+                    sm_scale * jax.lax.dot_general(
+                        ds, q_ref[0, rows, :], (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32))
 
-        def body():
-            q = q_ref[0]
-            v = v_ref[0]
-            do = do_ref[0]
-            p = _tile_probs(q_ref, k_ref, lse_ref, sm_scale, tk, causal,
-                            q_lo, k_lo)
-            # padded q rows contribute nothing: their do and delta are zero
-            dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
-                p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-            ds = (p * (dp - delta_ref[0])).astype(q.dtype)
-            dk_scr[:] = dk_scr[:] + sm_scale * jax.lax.dot_general(
-                ds, q, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-
-        @pl.when(_tile_live(causal, q_lo, k_lo, block_q, block_k))
-        def _():
-            body()
+        run(q_lo, k_lo, body)
 
         @pl.when(qi == nq - 1)
         def _fin():
@@ -303,8 +500,9 @@ def _make_dkv_kernel(sm_scale, tk, block_q, block_k, causal):
     return kernel
 
 
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9))
 def _bwd_call(q, k, v, o, lse, do, causal, sm_scale, block_q, block_k,
-              dlse=None, delta=None):
+              dlse=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -312,37 +510,37 @@ def _bwd_call(q, k, v, o, lse, do, causal, sm_scale, block_q, block_k,
     tk = k.shape[1]
     block_q, block_k = _clamp_blocks(q.dtype, tq, tk, block_q, block_k)
     tqp, tkp, dp = _ceil_to(tq, block_q), _ceil_to(tk, block_k), _ceil_to(d, _D_ALIGN)
+    nq, nk = tqp // block_q, tkp // block_k
 
     # delta_i = rowsum(dO_i * O_i) — the softmax-jacobian correction term;
     # cheap elementwise jnp, fused by XLA around the kernels.  When the
     # caller differentiates through lse too (ring-attention merge), its
     # cotangent enters the same place with opposite sign:
     # dL/ds_ij = p_ij * (dp_ij - delta_i + dlse_i), so fold it into delta.
-    # The split-causal backward passes a precomputed ``delta`` so its two
-    # region calls share one rowsum pass.
-    if delta is None:
-        delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                        axis=-1, keepdims=True)              # (BH, Tq, 1)
-        if dlse is not None:
-            delta = delta - dlse.astype(jnp.float32)
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                    axis=-1, keepdims=True)              # (BH, Tq, 1)
+    if dlse is not None:
+        delta = delta - dlse.astype(jnp.float32)
 
     qp = jnp.pad(q, ((0, 0), (0, tqp - tq), (0, dp - d)))
     kp = jnp.pad(k, ((0, 0), (0, tkp - tk), (0, dp - d)))
     vp = jnp.pad(v, ((0, 0), (0, tkp - tk), (0, dp - d)))
     dop = jnp.pad(do, ((0, 0), (0, tqp - tq), (0, dp - d)))
-    lsep = jnp.pad(lse, ((0, 0), (0, tqp - tq), (0, 0)))
-    deltap = jnp.pad(delta, ((0, 0), (0, tqp - tq), (0, 0)))
+    # per-query statistics as rows (BH, 1, Tq): the same bytes
+    pad_row = ((0, 0), (0, 0), (0, tqp - tq))
+    lsep = jnp.pad(lse.reshape(bh, 1, tq), pad_row)
+    deltap = jnp.pad(delta.reshape(bh, 1, tq), pad_row)
 
     q_spec = pl.BlockSpec((1, block_q, dp), lambda b, i, j: (b, i, 0),
                           memory_space=pltpu.VMEM)
     kv_spec_dq = pl.BlockSpec((1, block_k, dp), lambda b, i, j: (b, j, 0),
                               memory_space=pltpu.VMEM)
-    row_spec = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0),
+    row_spec = pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i),
                             memory_space=pltpu.VMEM)
 
     dq = pl.pallas_call(
-        _make_dq_kernel(sm_scale, tk, block_q, block_k, causal),
-        grid=(bh, tqp // block_q, tkp // block_k),
+        _make_dq_kernel(sm_scale, tk, block_q, block_k, causal, nq, nk),
+        grid=(bh, nq, nk),
         in_specs=[q_spec, kv_spec_dq, kv_spec_dq, q_spec, row_spec, row_spec],
         out_specs=q_spec,
         out_shape=_out_struct((bh, tqp, dp), q.dtype, qp, kp, vp, dop),
@@ -356,11 +554,11 @@ def _bwd_call(q, k, v, o, lse, do, causal, sm_scale, block_q, block_k,
                             memory_space=pltpu.VMEM)
     kv_spec_t = pl.BlockSpec((1, block_k, dp), lambda b, j, i: (b, j, 0),
                              memory_space=pltpu.VMEM)
-    row_spec_t = pl.BlockSpec((1, block_q, 1), lambda b, j, i: (b, i, 0),
+    row_spec_t = pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, i),
                               memory_space=pltpu.VMEM)
     dk, dv = pl.pallas_call(
-        _make_dkv_kernel(sm_scale, tk, block_q, block_k, causal),
-        grid=(bh, tkp // block_k, tqp // block_q),
+        _make_dkv_kernel(sm_scale, tk, block_q, block_k, causal, nq, nk),
+        grid=(bh, nk, nq),
         in_specs=[q_spec_t, kv_spec_t, kv_spec_t, q_spec_t, row_spec_t,
                   row_spec_t],
         out_specs=[kv_spec_t, kv_spec_t],
@@ -400,116 +598,8 @@ def _flash_lse_bwd(causal, sm_scale, block_q, block_k, res, cts):
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 
 
-def _merge_lse(o_a, lse_a, o_b, lse_b):
-    """Exact blockwise-attention merge of two partial results over disjoint
-    KV sets (the identity from flash_attention_with_lse's docstring), in
-    f32.  Plain jnp: autodiff routes the cotangents into both partials'
-    custom VJPs (including dlse), exactly like ring_attention's merge."""
-    m = jnp.maximum(lse_a, lse_b)
-    w_a = jnp.exp(lse_a - m)
-    w_b = jnp.exp(lse_b - m)
-    den = w_a + w_b
-    o = (o_a.astype(jnp.float32) * w_a + o_b.astype(jnp.float32) * w_b) / den
-    return o.astype(o_a.dtype), m + jnp.log(den)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _split_lse(q, k, v, sm_scale, block_q, block_k):
-    """Causal flash attention as two kernel calls per pass whose executed
-    tile area ≈ the useful (unmasked) score area.
-
-    A single causal call sweeps every tile touching the diagonal with
-    full-size blocks, so at seq = 2·block the three executed 1024² tiles
-    are only 2/3 useful (the two diagonal tiles are half masked).  Split
-    instead:
-
-    - **off-diagonal**: tiles STRICTLY below the diagonal band (mode
-      ``"offdiag"``) — full blocks, zero masked area, and no per-element
-      causal mask math at all;
-    - **diagonal band**: each q block attends causally within its own
-      band, which is exactly a BATCHED causal attention over
-      (BH·n_bands, block_q) sequences — the same kernel at half-size
-      blocks, so the masked waste per band shrinks from block²/2 to
-      block²/4 (minus the skipped above-diagonal sub-tile);
-
-    merged with the exact blockwise-lse identity.  Executed-area ratio vs
-    the single call: (n² + n/2) / (n² + n) per n = T/block — a 1/6 area
-    cut at n=2, vanishing as n grows (the 8k curve point was already
-    ~90% useful).  Measured on the v5e the area cut does NOT convert to
-    time on a quiet chip: at 2048 the single call is bound by grid-step
-    overhead (~1.9 us/step), and the split triples the step count, so it
-    only wins under heavy chip contention (1.7-2.5x there, 0.3-0.5x
-    quiet) — hence opt-in, see flash_attention_with_lse.
-
-    The custom VJP is at THIS level, not composed from two _flash_lse
-    VJPs: the backward recomputes p = exp(s - lse) from the MERGED lse in
-    both regions (the standard flash recurrence is oblivious to how the
-    forward was tiled), so the residuals are exactly the single-call ones
-    (q, k, v, o, lse) — composing custom-VJP calls through the merge
-    instead saves two extra partial (o, lse) pairs and differentiates the
-    elementwise merge, which measured as a complete wash at 2048.
-
-    Inputs are the kernel-internal (BH, T, D) layout; requires tq == tk
-    and block_q | tq (the dispatch condition in
-    flash_attention_with_lse)."""
-    return _split_fwd_impl(q, k, v, sm_scale, block_q, block_k)
-
-
-def _to_bands(x, n_bands, band):
-    bh = x.shape[0]
-    return x.reshape(bh * n_bands, band, x.shape[-1])
-
-
-def _split_fwd_impl(q, k, v, sm_scale, block_q, block_k):
-    bh, tq, d = q.shape
-    n_bands = tq // block_q
-    o_diag, lse_diag = _fwd_call(
-        _to_bands(q, n_bands, block_q), _to_bands(k, n_bands, block_q),
-        _to_bands(v, n_bands, block_q), True, sm_scale,
-        block_q // 2, block_q // 2)
-    o_off, lse_off = _fwd_call(q, k, v, "offdiag", sm_scale,
-                               block_q, block_k)
-    return _merge_lse(o_off, lse_off, o_diag.reshape(bh, tq, d),
-                      lse_diag.reshape(bh, tq, 1))
-
-
-def _split_fwd(q, k, v, sm_scale, block_q, block_k):
-    o, lse = _split_fwd_impl(q, k, v, sm_scale, block_q, block_k)
-    return (o, lse), (q, k, v, o, lse)
-
-
-def _split_bwd(sm_scale, block_q, block_k, res, cts):
-    q, k, v, o, lse = res
-    do, dlse = cts
-    bh, tq, d = q.shape
-    n_bands = tq // block_q
-    # one shared softmax-jacobian correction (see _bwd_call): both region
-    # calls recompute p from the same merged lse, so they share delta too
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1, keepdims=True)
-    if dlse is not None:
-        delta = delta - dlse.astype(jnp.float32)
-    dq_off, dk_off, dv_off = _bwd_call(
-        q, k, v, o, lse, do, "offdiag", sm_scale, block_q, block_k,
-        delta=delta)
-
-    def bands(x):
-        return _to_bands(x, n_bands, block_q)
-
-    dq_d, dk_d, dv_d = _bwd_call(
-        bands(q), bands(k), bands(v), bands(o), bands(lse), bands(do),
-        True, sm_scale, block_q // 2, block_q // 2, delta=bands(delta))
-    return (dq_off + dq_d.reshape(bh, tq, d),
-            dk_off + dk_d.reshape(bh, tq, d),
-            dv_off + dv_d.reshape(bh, tq, d))
-
-
-_split_lse.defvjp(_split_fwd, _split_bwd)
-
-
 def flash_attention_with_lse(q, k, v, causal: bool = False, sm_scale=None,
-                             block_q: int = 1024, block_k: int = 1024,
-                             split_diag=None):
+                             block_q: int = 1024, block_k: int = 1024):
     """Flash attention returning ``(out, lse)``.
 
     ``out``: (..., Tq, H, D) like :func:`flash_attention`; ``lse``:
@@ -524,6 +614,13 @@ def flash_attention_with_lse(q, k, v, causal: bool = False, sm_scale=None,
     Differentiable in both outputs (the lse cotangent folds into the
     softmax-jacobian correction).  Rows with no visible keys get lse ≈ -1e30
     and out 0 — the merge weight exp(lse - m) then vanishes exactly.
+
+    ``causal``: ``True`` masks above the diagonal and executes only the
+    sub-tiles at or below it; ``False`` and ``"offdiag"`` (every key of the
+    grid tiles strictly below the diagonal band of ``block_q`` rows, none
+    of the others) execute every sub-tile that holds a real key, and mask
+    only where K's padding begins.  Grid steps and sub-tiles: the module's
+    docstring and :func:`tile_plan`.
     """
     if q.ndim < 3:
         raise ValueError(f"expected (..., T, H, D), got {q.shape}")
@@ -548,60 +645,32 @@ def flash_attention_with_lse(q, k, v, causal: bool = False, sm_scale=None,
         x = x.reshape(-1, t, h, d)
         return jnp.swapaxes(x, 1, 2).reshape(-1, t, d)
 
-    # ``split_diag`` is OPT-IN (default off).  The two-call split
-    # (_split_lse) makes executed tile area ≈ useful area, and interleaved
-    # A/B under heavy chip contention measured it 1.7-2.5x faster at seq
-    # 2048 — but on a QUIET chip the same A/B inverts (0.3-0.5x): at 2048
-    # the single call is grid-overhead-bound, not area-bound (128 grid
-    # steps at ~1.9 us vs the split's ~384 across its finer-tiled calls),
-    # and 1024^2 single-call already runs at the same per-executed-area
-    # rate as 8k there (142 TF fwd reported / (4/3) accounting inflation
-    # ~= 107 effective ~= the 8k row).  Quiet windows are what the
-    # best-ever ratchet keeps, so the split stays a documented variant
-    # (exact numerics, tests/test_flash_attention.py), not the default.
-    bq_eff, bk_eff = _clamp_blocks(q.dtype, tq, tk, block_q, block_k)
-    if split_diag is None:
-        split_diag = False
-    elif split_diag:
-        # explicit opt-in: the split hardcodes causal self-attention
-        # semantics, so reject configurations it would silently get wrong
-        if causal is not True or tq != tk or tq % bq_eff:
-            raise ValueError(
-                "split_diag=True requires causal=True self-attention "
-                f"(tq == tk) with block_q dividing tq; got causal={causal}, "
-                f"tq={tq}, tk={tk}, effective block_q={bq_eff}")
-        # the off-diagonal predicate (k_lo + block_k <= q_lo) skips key
-        # columns outright if k tiles are coarser than the q banding —
-        # square tiles are the only layout the split supports
-        bk_eff = bq_eff
-    if split_diag:
-        o3, lse3 = _split_lse(to3(q, tq), to3(k, tk), to3(v, tk),
-                              float(sm_scale), bq_eff, bk_eff)
-    else:
-        o3, lse3 = _flash_lse(to3(q, tq), to3(k, tk), to3(v, tk), causal,
-                              float(sm_scale), int(block_q), int(block_k))
+    o3, lse3 = _flash_lse(to3(q, tq), to3(k, tk), to3(v, tk), causal,
+                          float(sm_scale), int(block_q), int(block_k))
     o = jnp.swapaxes(o3.reshape(-1, h, tq, d), 1, 2).reshape(*lead, tq, h, d)
     lse = jnp.swapaxes(lse3.reshape(-1, h, tq), 1, 2)       # (B, Tq, H)
     return o, lse.reshape(*lead, tq, h)
 
 
 def flash_attention(q, k, v, causal: bool = False, sm_scale=None,
-                    block_q: int = 1024, block_k: int = 1024,
-                    split_diag=None):
+                    block_q: int = 1024, block_k: int = 1024):
     """Flash attention.  ``q``: (..., Tq, H, D); ``k, v``: (..., Tk, H, D).
 
     Drop-in for :func:`tpu_dist.nn.attention.scaled_dot_product_attention`
     (mask=None); differentiable; O(T) memory.  ``block_q``/``block_k`` are
-    VMEM tile sizes (auto-clamped for short sequences).  The 1024 defaults
-    are from an on-chip sweep at (4, 8192, 8, 64) bf16 causal: large tiles
-    amortize grid/DMA overhead and win ~2.5x over 128 tiles for training
-    (fwd+bwd); measured vs jax.experimental.pallas.ops.tpu.flash_attention
-    at the same shape this kernel is ~2x (fwd) / ~4x (fwd+bwd) faster.
+    the sizes of a GRID STEP's tile, what one DMA brings into VMEM
+    (auto-clamped for short sequences, halved for 4-byte inputs).  They
+    stay at 1024 because a grid step and its DMAs cost the same whatever
+    the tile holds: a sequence of 1024 is one step a head.  What is
+    computed is decided a level below, by SUB-TILE (``_SUB`` squared scores,
+    a constant timed on the chip): a causal call never computes a sub-tile
+    above the diagonal, takes no mask math in one wholly below it, and
+    masks only those the diagonal crosses, forward and in both backward
+    kernels; :func:`tile_plan` counts them from shapes.
 
     Same computation as :func:`flash_attention_with_lse` with the lse
     discarded (its cotangent is then zero, so the backward is identical).
     """
     return flash_attention_with_lse(q, k, v, causal=causal,
                                     sm_scale=sm_scale, block_q=block_q,
-                                    block_k=block_k,
-                                    split_diag=split_diag)[0]
+                                    block_k=block_k)[0]
