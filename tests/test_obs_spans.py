@@ -287,6 +287,440 @@ class TestServingPhases:
         assert inside >= 0.7 * extent, (inside, extent)
 
 
+# -- the loop thread's clock ---------------------------------------------------
+
+KINDS = ("prefill", "decode", "idle")
+WAITS = ("t.readback", "t.wait")
+
+
+@pytest.fixture
+def clock():
+    """A clock this test's thread owns; the thread is disowned afterwards,
+    so the spans of later tests pay the one lookup again."""
+    c = obs.LoopClock("test loop", KINDS, WAITS, sleep="t.wait")
+    c.tick("idle")
+    yield c
+    obs.spans._local.clock = None
+
+
+def _spin(seconds):
+    end = time.perf_counter() + seconds
+    n = 0
+    while time.perf_counter() < end:
+        n += 1
+    return n
+
+
+class TestLoopClock:
+    def test_the_books_balance_and_nested_spans_count_once(self, clock):
+        for i in range(3):
+            with obs.span("t.outer"):
+                with obs.span("t.inner"):
+                    time.sleep(0.003)
+                with obs.span("t.inner"):
+                    pass
+            with obs.span("t.readback"):
+                time.sleep(0.002)
+            time.sleep(0.002)                 # under no span: unnamed
+            clock.tick("decode", step=i + 1)
+        got = clock.stats()
+        assert got["iterations"] == {"prefill": 0, "decode": 3, "idle": 0}
+        assert got["covered_s"] + got["unnamed_s"] == pytest.approx(
+            got["wall_s"], rel=1e-12)
+        assert got["unnamed_s"] >= 3 * 0.002
+        rec = got["longest"]["decode"][0]
+        # t.inner lies inside t.outer: in by_phase under its own name, in
+        # the iteration's covered time once
+        assert rec["by_phase"]["t.outer"] >= rec["by_phase"]["t.inner"] > 0
+        covered = rec["by_phase"]["t.outer"] + rec["by_phase"]["t.readback"]
+        assert rec["wall"] - rec["unnamed"] == pytest.approx(covered)
+        assert got["wait_s"] == pytest.approx(sum(
+            r["by_phase"]["t.readback"] for r in got["longest"]["decode"]))
+        assert all(r["wait"] == r["by_phase"]["t.readback"]
+                   for r in got["longest"]["decode"])
+
+    def test_a_span_on_another_thread_adds_nothing(self, clock):
+        def other():
+            with obs.span("t.elsewhere"):
+                time.sleep(0.01)
+        t = threading.Thread(target=other)
+        with obs.span("t.mine"):
+            t.start()
+            t.join()
+        clock.tick("decode")
+        rec, = clock.stats()["longest"]["decode"]
+        assert set(rec["by_phase"]) == {"t.mine"}
+        assert clock.stats()["covered_s"] == rec["by_phase"]["t.mine"]
+
+    def test_a_sleep_in_a_phase_is_time_off_the_cpu(self, clock):
+        with obs.span("t.emit"):
+            time.sleep(0.05)
+        clock.tick("decode", step=7)
+        rec, = clock.stats()["longest"]["decode"]
+        assert rec["phase"] == "t.emit" and rec["step"] == 7
+        assert rec["wall"] >= 0.05
+        # compared, not gated on microseconds: the thread stood off the CPU
+        assert rec["offcpu"] > 0.6 * rec["wall"] > rec["cpu"]
+        assert rec["offcpu"] <= rec["wall"] and rec["wait"] == 0.0
+
+    def test_a_busy_loop_in_a_phase_is_cpu(self, clock):
+        # six workers wide the OS may take the CPU away for most of one
+        # spin: the best of a few is compared, no microsecond is gated
+        for _ in range(5):
+            with obs.span("t.emit"):
+                _spin(0.05)
+            clock.tick("decode")
+        kept = clock.stats()["longest"]["decode"]
+        assert {r["phase"] for r in kept} == {"t.emit"}
+        assert any(r["cpu"] > r["offcpu"] for r in kept)
+        assert all(r["cpu"] <= r["wall"] * 1.05 + 0.005 for r in kept)
+
+    def test_a_designed_wait_is_neither(self, clock):
+        with obs.span("t.readback"):
+            time.sleep(0.03)
+        with obs.span("t.wait"):
+            time.sleep(0.02)
+        clock.tick("idle")
+        got = clock.stats()
+        rec, = got["longest"]["idle"]
+        assert rec["wait"] >= 0.05 and rec["offcpu"] < 0.02
+        # the histogram and the ranking leave the loop's own sleep out
+        assert got["iteration"]["idle"]["max"] == pytest.approx(
+            rec["wall"] - rec["by_phase"]["t.wait"])
+        assert rec["phase"] == "t.readback"
+
+    def test_what_the_other_threads_burn_is_counted_beside(self, clock):
+        stop = threading.Event()
+        burner = threading.Thread(target=lambda: [_spin(0.01) for _ in
+                                                  iter(stop.is_set, True)])
+        burner.start()
+        try:
+            with obs.span("t.emit"):
+                time.sleep(0.08)
+        finally:
+            stop.set()
+            burner.join()
+        clock.tick("decode")
+        rec, = clock.stats()["longest"]["decode"]
+        assert rec["cpu_others"] > rec["cpu"]
+        assert clock.stats()["cpu_others_s"] == rec["cpu_others"]
+
+    def test_a_collection_is_counted_and_is_a_span_with_its_gen(
+            self, clock, tmp_path):
+        import gc
+        before = clock.stats()
+        with _profile(tmp_path):
+            with obs.span("t.emit"):
+                junk = [[i] for i in range(20000)]
+                gc.collect()
+            clock.tick("decode")
+        got = clock.stats()
+        assert got["gc_s"] > before["gc_s"]
+        # (the junk may set a full collection off by itself before ours)
+        assert got["gc_collections"][2] >= before["gc_collections"][2] + 1
+        rec = got["longest"]["decode"][0]
+        assert 0 < rec["gc"] <= rec["by_phase"]["t.emit"]
+        gens = [f["gen"] for name, f, *_ in _annotations(tmp_path)
+                if name == "gc"]
+        assert 2 in gens
+        del junk
+
+    def test_the_collector_is_watched_once(self, clock):
+        import gc
+        obs.LoopClock("another", KINDS, WAITS, sleep="t.wait")
+        assert gc.callbacks.count(obs.spans._on_gc) == 1
+
+    def test_the_heap_keeps_the_longest_eight_longest_first(self, clock):
+        naps = [0.001 * k for k in (3, 9, 1, 12, 5, 7, 2, 11, 4, 10, 6, 8)]
+        for i, nap in enumerate(naps):
+            with obs.span("t.emit"):
+                time.sleep(nap)
+            clock.tick("decode", step=i)
+        got = clock.stats()
+        kept = got["longest"]["decode"]
+        assert len(kept) == obs.spans.KEPT == 8
+        walls = [r["wall"] for r in kept]
+        assert walls == sorted(walls, reverse=True)
+        # the eight longest naps, whatever the scheduler added to each
+        order = sorted(range(len(naps)), key=naps.__getitem__, reverse=True)
+        assert {r["step"] for r in kept[:4]} <= set(order[:8])
+        assert got["iterations"]["decode"] == len(naps)
+        assert got["iteration"]["decode"]["count"] == len(naps)
+        assert got["longest"]["prefill"] == got["longest"]["idle"] == []
+        assert all(0 <= r["at"] <= got["wall_s"] for r in kept)
+
+    def test_an_iteration_open_across_a_reset_is_dropped(self, clock):
+        with obs.span("t.emit"):
+            time.sleep(0.02)
+        clock.reset()                    # mid-iteration, as a harness does
+        with obs.span("t.emit"):
+            pass
+        clock.tick("decode")
+        got = clock.stats()
+        assert got["wall_s"] == 0.0 and got["iterations"]["decode"] == 0
+        assert got["longest"]["decode"] == []
+        with obs.span("t.emit"):
+            pass
+        clock.tick("decode")
+        got = clock.stats()
+        assert got["iterations"]["decode"] == 1 and 0 < got["wall_s"] < 0.02
+
+    def test_a_tick_from_another_thread_takes_the_clock_over(self, clock):
+        took, stale = threading.Event(), threading.Event()
+
+        def loop():
+            clock.tick("decode")         # takes over: the open one is dropped
+            took.set()
+            assert stale.wait(30.0)
+            with obs.span("t.emit"):
+                time.sleep(0.005)
+            clock.tick("decode")
+        with obs.span("t.mine"):         # the old owner's: dropped
+            pass
+        t = threading.Thread(target=loop)
+        t.start()
+        assert took.wait(30.0)
+        with obs.span("t.stale"):        # no longer the owner: adds nothing
+            pass
+        assert obs.spans._local.clock is None
+        stale.set()
+        t.join(30.0)
+        assert not t.is_alive()
+        got = clock.stats()
+        assert got["iterations"]["decode"] == 1
+        rec, = got["longest"]["decode"]
+        assert set(rec["by_phase"]) == {"t.emit"}
+
+    def test_two_stalls_a_second_apart_print_one_line(self, clock, capfd):
+        for step in (41, 42):
+            with obs.span("t.emit"):
+                time.sleep(obs.spans.STALL_S + 0.05)
+            clock.tick("decode", step=step)
+        err = capfd.readouterr().err
+        lines = [l for l in err.splitlines() if "test loop stalled" in l]
+        assert len(lines) == 1, err
+        line = lines[0]
+        assert line.startswith("[tpu_dist] test loop stalled 0.")
+        assert " at step 41 in t.emit: cpu " in line
+        for part in ("off-cpu 0.", "gc ", "others' cpu "):
+            assert part in line
+        assert line.endswith(" involuntary switches") \
+            == obs.spans._counts_switches()
+        # both are kept, whatever was printed
+        assert [r["step"] for r in clock.stats()["longest"]["decode"]] \
+            in ([41, 42], [42, 41])
+
+    def test_a_platform_without_switch_counts_pays_and_says_nothing(
+            self, monkeypatch, capfd):
+        monkeypatch.setattr(obs.spans, "_counts_switches", lambda: False)
+        c = obs.LoopClock("bare loop", KINDS, WAITS, sleep="t.wait")
+        try:
+            c.tick("idle")
+            with obs.span("t.emit"):
+                time.sleep(obs.spans.STALL_S + 0.05)
+            c.tick("decode", step=3)
+        finally:
+            obs.spans._local.clock = None
+        rec, = c.stats()["longest"]["decode"]
+        assert not {"switches", "voluntary_switches", "major_faults"} \
+            & set(rec)
+        line, = [l for l in capfd.readouterr().err.splitlines()
+                 if "bare loop stalled" in l]
+        assert line.endswith(" s") and "switches" not in line
+
+    def test_the_sums_are_clamped_as_sums(self, clock):
+        # a wait that burns CPU (more CPU than wall less the waits) makes
+        # one iteration's difference negative: the record clamps it, the
+        # window's sum is taken over the sums
+        with obs.span("t.readback"):
+            _spin(0.03)
+        clock.tick("decode")
+        with obs.span("t.emit"):
+            time.sleep(0.03)
+        clock.tick("decode")
+        got = clock.stats()
+        assert got["offcpu_s"] == pytest.approx(max(
+            0.0, got["wall_s"] - got["wait_s"] - got["cpu_s"]))
+        assert got["offcpu_s"] <= sum(r["offcpu"]
+                                      for r in got["longest"]["decode"])
+
+    def test_a_thread_with_no_clock_is_left_alone(self):
+        seen = []
+        def work():
+            with obs.span("t.free"):
+                seen.append(obs.spans._local.clock)
+        t = threading.Thread(target=work)
+        t.start()
+        t.join()
+        assert seen == [None]
+
+
+def test_the_cost_benchmark_measures_a_span_and_a_tick():
+    """benchmarks/bench_loop_clock.py at a small count: the three numbers
+    exist and are positive; what they are is a chip host's to say."""
+    from benchmarks import bench_loop_clock
+    obs.spans._local.clock = None
+    got = bench_loop_clock.measure(2000)
+    assert got["n"] == 2000
+    assert all(got[k] > 0 for k in ("span_ns", "span_on_clock_ns",
+                                    "tick_ns"))
+    assert obs.spans._local.clock is None    # measured on its own thread
+
+
+class TestServingLoopClock:
+    def test_stats_loop_counts_iterations_by_what_they_launched(self, lm):
+        model, params = lm
+        engine = serve.SlotEngine(model, params, num_slots=2)
+        try:
+            engine.sweep_expired()           # this thread owns the clock
+            engine.reset_stats()
+            engine.sweep_expired()           # dropped: open across the reset
+            engine.admit(serve.Request(np.arange(4, dtype=np.int32), 4))
+            engine.step()                    # a prefill names the iteration
+            engine.sweep_expired()
+            engine.step()
+            engine.sweep_expired()
+            engine.step()
+            engine.sweep_expired()
+            engine.sweep_expired()           # nothing launched: idle
+            loop = engine.stats()["loop"]
+        finally:
+            obs.spans._local.clock = None
+        assert loop["iterations"] == {"prefill": 1, "decode": 2, "idle": 1}
+        assert loop["covered_s"] + loop["unnamed_s"] == pytest.approx(
+            loop["wall_s"], rel=1e-12)
+        assert set(loop) == {
+            "iterations", "wall_s", "covered_s", "unnamed_s", "wait_s",
+            "cpu_s", "cpu_others_s", "offcpu_s", "gc_s", "gc_collections",
+            "iteration", "longest"}
+        pre, = loop["longest"]["prefill"]
+        assert {"prefill.dispatch", "prefill.readback", "decode.dispatch",
+                "sweep", "stage.put"} <= set(pre["by_phase"])
+        # stage.put ran inline, inside prefill.prepare: counted once
+        top = sum(v for k, v in pre["by_phase"].items() if k != "stage.put")
+        assert pre["wall"] - pre["unnamed"] == pytest.approx(top)
+        assert [r["step"] for r in loop["longest"]["decode"]] in ([2, 3],
+                                                                  [3, 2])
+        assert loop["wait_s"] == pytest.approx(sum(
+            r["by_phase"].get(n, 0.0) for k in KINDS
+            for r in loop["longest"][k]
+            for n in serve.engine.LOOP_WAITS))
+
+    def test_a_pass_that_admits_many_is_an_iteration_a_prefill(self, lm):
+        model, params = lm
+        engine = serve.SlotEngine(model, params, num_slots=4)
+        try:
+            engine.sweep_expired()
+            # the scheduler's admission loop: a pool's worth between sweeps
+            for n in (4, 6, 9):
+                engine.launch_admit(serve.Request(
+                    np.arange(n, dtype=np.int32), 3))
+                engine.settle()
+            engine.launch_step()
+            engine.settle()
+            engine.sweep_expired()
+            loop = engine.stats()["loop"]
+        finally:
+            obs.spans._local.clock = None
+        assert loop["iterations"] == {"prefill": 3, "decode": 0, "idle": 0}
+        for rec in loop["longest"]["prefill"]:
+            assert rec["by_phase"]["prefill.dispatch"] > 0
+        # the last one carries the pass's decode step; the first its sweep
+        assert sum("decode.dispatch" in r["by_phase"]
+                   for r in loop["longest"]["prefill"]) == 1
+        assert sum("sweep" in r["by_phase"]
+                   for r in loop["longest"]["prefill"]) == 1
+
+    def test_reset_stats_zeroes_the_loop_and_leaves_params(self, lm):
+        model, params = lm
+        engine = serve.SlotEngine(model, params, num_slots=2)
+        try:
+            engine.sweep_expired()
+            engine.admit(serve.Request(np.arange(4, dtype=np.int32), 3))
+            engine.sweep_expired()
+            before = engine.stats()
+            assert before["loop"]["iterations"]["prefill"] == 1
+            engine.reset_stats()
+            after = engine.stats()
+        finally:
+            obs.spans._local.clock = None
+        assert after["params"] == before["params"]
+        loop = after["loop"]
+        assert set(loop["iterations"].values()) == {0}
+        assert loop["wall_s"] == loop["gc_s"] == loop["offcpu_s"] == 0.0
+        assert loop["gc_collections"] == [0, 0, 0]
+        assert all(v == [] for v in loop["longest"].values())
+        assert all(h["count"] == 0 for h in loop["iteration"].values())
+
+    def test_two_engines_keep_their_own_books(self, lm):
+        model, params = lm
+        a = serve.SlotEngine(model, params, num_slots=2)
+        b = serve.SlotEngine(model, params, num_slots=2)
+        done = []
+
+        def drive(engine, steps):
+            engine.sweep_expired()
+            engine.admit(serve.Request(np.arange(5, dtype=np.int32),
+                                       steps + 1))
+            engine.sweep_expired()
+            while not engine.idle():
+                engine.step()
+                engine.sweep_expired()
+            done.append(engine)
+        threads = [threading.Thread(target=drive, args=(e, n))
+                   for e, n in ((a, 3), (b, 6))]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(120.0)
+        assert len(done) == 2
+        assert a.stats()["loop"]["iterations"]["decode"] == 3
+        assert b.stats()["loop"]["iterations"]["decode"] == 6
+
+    def test_the_wire_stats_frame_carries_the_loop_as_json(self, lm):
+        import json
+        model, params = lm
+        engine = serve.SlotEngine(model, params, num_slots=4)
+        sched = serve.Scheduler(engine, batch_window=0.002)
+        fe = serve.Frontend(sched, port=0)
+        cli = serve.ServeClient("127.0.0.1", fe.port, connect_retry=10)
+        try:
+            cli.generate(list(range(1, 9)), max_new_tokens=3, timeout=120.0)
+            time.sleep(0.05)
+            engine.reset_stats()
+            for n in (6, 11):
+                cli.generate(list(range(1, n)), max_new_tokens=5,
+                             timeout=120.0)
+            time.sleep(0.12)
+            stats = cli.stats()
+            local = engine.stats()["loop"]
+        finally:
+            cli.close()
+            fe.close()
+            sched.close()
+        loop = stats["loop"]
+        assert json.loads(json.dumps(local)) == local
+        assert loop["iterations"]["prefill"] == 2
+        assert loop["iterations"]["decode"] >= 4
+        assert loop["iterations"]["idle"] >= 1
+        assert loop["covered_s"] + loop["unnamed_s"] == pytest.approx(
+            loop["wall_s"], rel=1e-9)
+        # the loop thread's whole time since the reset, but for the
+        # iteration open when the frame was read
+        assert 0 < loop["wall_s"] and loop["unnamed_s"] < 0.5 * loop["wall_s"]
+        assert loop["iteration"]["decode"]["count"] \
+            == loop["iterations"]["decode"]
+        # an idle iteration is its sleep: the histogram leaves it out
+        assert loop["iteration"]["idle"]["p50"] < 0.05
+        for kind, kept in loop["longest"].items():
+            assert len(kept) <= 8
+            for r in kept:
+                assert set(r) - {"switches", "voluntary_switches",
+                                 "major_faults"} == {
+                    "step", "at", "wall", "phase", "by_phase", "unnamed",
+                    "wait", "cpu", "cpu_others", "offcpu", "gc"}
+
+
 # -- scopes on the compiled path -----------------------------------------------
 
 def _ddp(compute_dtype=jnp.bfloat16):

@@ -30,7 +30,9 @@ Beside them, and sharing nothing with them but a request's id,
 :mod:`.spans` puts the host phases of the compiled path (the decode loop,
 the scheduler's wait, the training step's dispatch) on the profiler's
 clock and into :func:`phase_times` — always on, for time on the chip
-rather than hangs of the host collectives.
+rather than hangs of the host collectives.  A :class:`LoopClock` closes the
+books on every iteration of one loop thread over those spans: by phase,
+CPU, garbage collection (``td/gc``) and time off the CPU.
 """
 
 from . import hooks, recorder, spans, trace
@@ -39,12 +41,12 @@ from .hooks import (collective_span, fetch_tail, install_from_env, note_path,
 from .recorder import (FlightRecorder, default_dump_dir, dump_now, dump_path,
                        enabled, get_recorder, obs_key, record_transport,
                        reset, reset_transport_counters, transport_counters)
-from .spans import phase_times, reset_phases, span
+from .spans import LoopClock, phase_times, reset_phases, span
 from .trace import diagnose, merge_trace, read_dumps, render_diagnosis
 
 __all__ = [
     "recorder", "hooks", "trace", "spans",
-    "span", "phase_times", "reset_phases",
+    "span", "phase_times", "reset_phases", "LoopClock",
     "FlightRecorder", "enabled", "get_recorder", "reset", "dump_now",
     "record_transport", "transport_counters", "reset_transport_counters",
     "obs_key", "default_dump_dir", "dump_path",

@@ -53,7 +53,7 @@ from typing import Callable, List, Optional
 import numpy as np
 
 from ..nn import cache as kvcache
-from ..obs.spans import phase_times, reset_phases, span
+from ..obs.spans import LoopClock, phase_times, reset_phases, span
 from ..ops.decode_attention import kv_blocks
 from ..utils.metrics import LatencyHistogram
 
@@ -61,7 +61,7 @@ __all__ = ["SlotEngine", "Request", "RequestHandle", "ServeError",
            "QueueFullError", "SchedulerDrainingError",
            "SchedulerClosedError", "DeadlineExceededError",
            "RequestCancelledError", "error_outcome", "sample_tokens",
-           "SERVE_PHASES"]
+           "SERVE_PHASES", "LOOP_KINDS", "LOOP_WAITS"]
 
 # The serving loop's host phases (tpu_dist.obs.spans), in the order one
 # iteration runs them: ``stats()["phases"]`` reports exactly these and
@@ -72,6 +72,11 @@ SERVE_PHASES = ("sweep", "sched.wait", "stage.put",
                 "prefill.prepare", "prefill.dispatch", "prefill.readback",
                 "prefill.emit",
                 "decode.dispatch", "decode.readback", "decode.emit")
+# What the loop clock (``stats()["loop"]``) calls an iteration, by what it
+# launched, and the phases in which the loop thread waits by design: for
+# the device, and (the scheduler's sleep) for work.
+LOOP_KINDS = ("prefill", "decode", "idle")
+LOOP_WAITS = ("prefill.readback", "decode.readback", "sched.wait")
 
 
 class ServeError(RuntimeError):
@@ -596,6 +601,12 @@ class SlotEngine:
         self._occupied_slot_steps = 0
         self._decode_steps = 0
         self._iterations = 0    # decode iterations ever run: spans' step=
+        # the loop thread's clock: ticked at every iteration boundary
+        # (sweep_expired), it closes the iteration that just ended as the
+        # kind _launched() noted
+        self._loop = LoopClock("serve loop", LOOP_KINDS, LOOP_WAITS,
+                               sleep="sched.wait")
+        self._loop_kind = "idle"
         # routed-row counters per pool program (_build_programs fills them
         # for a model with expert layers) and their reading at the last
         # reset_stats()
@@ -748,6 +759,11 @@ class SlotEngine:
         and the prefill's launch.  The slot is occupied from here on; its
         first token is emitted by the :meth:`collect` of this program."""
         slot = self._admission_slot(req)
+        if self._loop_kind == "prefill":
+            # the scheduler admits up to a pool's worth between two sweeps
+            # (a pool's first filling is ONE pass of seconds): an iteration
+            # of the loop clock holds one prefill
+            self._tick()
         self._pre_admit(req, slot)
         return self._admit(req, slot)
 
@@ -855,6 +871,8 @@ class SlotEngine:
         if self._flight:
             count["launched_ahead"][flight.kind] += 1
         self._flight.append(flight)
+        if self._loop_kind != "prefill":    # a prefill names the iteration
+            self._loop_kind = flight.kind
 
     # -- collect: the one wait on the device, then the host's share ----------
 
@@ -968,7 +986,13 @@ class SlotEngine:
         the boundary still carries its row: two steps at most, the second's
         token dropped at collection).  The request terminates
         with the named error and its obs span closes ``error:Cancelled`` /
-        ``error:DeadlineExceededError``.  Returns the slots freed."""
+        ``error:DeadlineExceededError``.  Returns the slots freed.
+
+        The boundary is also where the loop clock ticks, before ``sweep``
+        opens: the calling thread's iteration that just ended is closed
+        into ``stats()["loop"]`` (:meth:`launch_admit` ticks too, at an
+        admission that follows another inside one pass)."""
+        self._tick()
         with span("sweep", step=self._iterations + 1):
             expired = self._sweep_candidates()
             if expired:
@@ -976,6 +1000,11 @@ class SlotEngine:
             for slot, exc in expired:
                 self.fail_slot(slot, exc)
         return len(expired)
+
+    def _tick(self) -> None:
+        """Close the loop clock's iteration as what it launched."""
+        self._loop.tick(self._loop_kind, self._iterations)
+        self._loop_kind = "idle"
 
     def _sweep_candidates(self) -> List[tuple]:
         """``(slot, named_error)`` for every active slot whose request was
@@ -1060,8 +1089,9 @@ class SlotEngine:
     def reset_stats(self) -> None:
         """Zero the histograms/counters (benchmarks: exclude warmup
         compiles from the measured window), the serving loop's phases
-        (:data:`SERVE_PHASES`, process-wide) among them.  Slot state is
-        untouched."""
+        (:data:`SERVE_PHASES`, process-wide) and the loop clock among them
+        (an iteration open across the call is dropped, not split).  Slot
+        state is untouched."""
         self.hist_queue = LatencyHistogram()
         self.hist_prefill = LatencyHistogram()
         self.hist_ttft = LatencyHistogram()
@@ -1076,6 +1106,7 @@ class SlotEngine:
         self._need_rows = self._need_positions = 0
         self._pipeline = self._fresh_pipeline()
         reset_phases(SERVE_PHASES)
+        self._loop.reset()
         # the device counters are never zeroed (a step in flight would
         # carry the old count on): stats() reports them past this reading
         self._moe_base = self._moe_read()
@@ -1193,7 +1224,11 @@ class SlotEngine:
         written) and ``kv_bytes`` (the K/V columns held, the new one
         included).  ``"decode_need"``: :meth:`_decode_need_stats`.
         ``"params"``: what :func:`place_params` did at construction;
-        ``reset_stats()`` leaves it."""
+        ``reset_stats()`` leaves it.  ``"loop"``: the loop thread's clock
+        (:meth:`tpu_dist.obs.spans.LoopClock.stats`): every iteration the
+        thread that calls :meth:`sweep_expired` closed, by phase, CPU,
+        garbage collection and time off the CPU, and the longest ones
+        whole."""
         since = self._moe_since()
         moe = self._moe_stats(since)
         return {
@@ -1215,4 +1250,5 @@ class SlotEngine:
             "decode_step": self.hist_token.summary(),
             "e2e": self.hist_e2e.summary(),
             "phases": phase_times(SERVE_PHASES),
+            "loop": self._loop.stats(),
         }

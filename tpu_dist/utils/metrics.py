@@ -67,13 +67,22 @@ class LatencyHistogram:
 
     def observe(self, seconds: float) -> None:
         """Record one latency sample (negative values clamp to 0)."""
-        v = max(0.0, float(seconds))
-        i = min(self._index(v), self._nbuckets - 1)
+        v = float(seconds)
+        # every span on the compiled path ends here: _index() inline
+        if v >= self.min_value:
+            i = 1 + int(math.log(v / self.min_value) / self._log1p)
+            if i >= self._nbuckets:
+                i = self._nbuckets - 1
+        else:
+            i = 0
+            if not v > 0.0:
+                v = 0.0
         with self._mu:
             self._counts[i] += 1
             self._n += 1
             self._sum += v
-            self._max = max(self._max, v)
+            if v > self._max:
+                self._max = v
 
     def merge(self, other: "LatencyHistogram") -> None:
         """Fold ``other``'s counts into this histogram (must share the
