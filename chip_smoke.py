@@ -395,8 +395,9 @@ def phase_kernels(flash: dict, ce: dict, moe: dict, decode: dict,
 # ---------------------------------------------------------------------------
 
 def _assert_spans_group(pg, x, state) -> None:
-    """Every device of the group holds a shard of the batch and a replica of
-    the params."""
+    """Every device of the group holds a shard of the batch and, of each
+    parameter leaf, a replica or (a leaf the sharded update divides) its
+    1/world."""
     import jax
 
     want = set(pg.devices)
@@ -405,11 +406,10 @@ def _assert_spans_group(pg, x, state) -> None:
     if set(shards) != want or any(sh[0] != rows for sh in shards.values()):
         raise AssertionError(f"batch shards {shards} do not cover {want} "
                              f"with {rows} rows each")
-    leaf = jax.tree.leaves(state.params)[0]
-    if not (leaf.sharding.is_fully_replicated
-            and set(leaf.sharding.device_set) == want):
-        raise AssertionError(f"params not replicated over the group: "
-                             f"{leaf.sharding}")
+    for leaf in jax.tree.leaves(state.params):
+        if set(leaf.sharding.device_set) != want:
+            raise AssertionError(f"params not spread over the group: "
+                                 f"{leaf.sharding}")
 
 
 def _train(ddp, state, batches, put) -> tuple:

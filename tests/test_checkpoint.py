@@ -123,8 +123,9 @@ def test_sharded_restore(tmp_path, pg):
 
 def test_zero1_sharded_opt_state_roundtrip(tmp_path, pg):
     """VERDICT r1 weak #5: a shard_optimizer=True (ZeRO-1) TrainState — whose
-    opt_state is P(axis)-sharded flat vectors — must save, restore with its
-    placement (via state_shardings), and resume training identically."""
+    opt_state holds its large leaf 1/world a device along that leaf's
+    shard_axis — must save, restore with its placement (via
+    state_shardings), and resume training identically."""
     from jax.sharding import PartitionSpec as P
 
     ddp = DDP(ConvNet(), optimizer=optim.SGD(lr=0.1, momentum=0.9),
@@ -136,9 +137,10 @@ def test_zero1_sharded_opt_state_roundtrip(tmp_path, pg):
     y = rng.integers(0, 10, 16)
     state, _ = ddp.train_step(state, x, y)
 
-    # sanity: the opt_state really is sharded over the data axis
-    opt_leaf = jax.tree.leaves(state.opt_state)[0]
-    assert opt_leaf.sharding.spec == P(pg.axis_name)
+    # sanity: the opt_state really is sharded over the data axis, along
+    # the third axis of ConvNet's one leaf past ddp.SHARD_MIN_ELEMENTS
+    big = lambda st: st.opt_state["momentum"]["conv3"]["weight"]
+    assert big(state).sharding.spec == P(None, None, pg.axis_name)
 
     checkpoint.save(str(tmp_path), state, step=1)
     restored = checkpoint.restore(str(tmp_path), state,
@@ -148,10 +150,11 @@ def test_zero1_sharded_opt_state_roundtrip(tmp_path, pg):
     jax.tree.map(lambda a, b: np.testing.assert_array_equal(
         np.asarray(a), np.asarray(b)), state, restored)
     # ...and the ZeRO-1 placement survived the round trip
-    r_leaf = jax.tree.leaves(restored.opt_state)[0]
-    assert r_leaf.sharding.spec == P(pg.axis_name)
+    assert big(restored).sharding.spec == P(None, None, pg.axis_name)
     p_leaf = jax.tree.leaves(restored.params)[0]
-    assert p_leaf.sharding.spec == P()
+    assert p_leaf.sharding.spec == P()         # a bias: stays replicated
+    assert (restored.params["conv3"]["weight"].sharding.spec
+            == P(None, None, pg.axis_name))
 
     # resume: both continue to the same numbers
     _, m_a = ddp.train_step(state, x, y)

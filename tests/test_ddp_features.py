@@ -1,5 +1,7 @@
-"""DDP wrapper extensions: grad accumulation, ZeRO-1 optimizer sharding,
-mixed precision — each checked against the plain DDP step's numerics."""
+"""DDP wrapper extensions: grad accumulation, the sharded weight update
+(ZeRO-1), mixed precision — each checked against the numerics of the plain
+DDP step with whole updates (``shard_optimizer=False``: over a group the
+default shards ConvNet's one large leaf, ``conv3.weight``)."""
 
 import jax
 import jax.numpy as jnp
@@ -37,16 +39,21 @@ def _mk(pg, **kw):
                loss_fn=nn.CrossEntropyLoss(), group=pg, donate=False, **kw)
 
 
+def _plain(pg, **kw):
+    """The oracle: every update whole and replicated."""
+    return _mk(pg, shard_optimizer=False, **kw)
+
+
 class TestGradAccumulation:
     def test_accum_matches_plain(self, pg):
         """k microbatches of B/k == one batch of B (same grads for
         mean-reduced loss)."""
         x, y = _batch(64)
-        plain = _mk(pg)
+        plain = _plain(pg)
         s0 = plain.init(seed=0)
         s1, m1 = plain.train_step(s0, x, y)
 
-        accum = _mk(pg, accum_steps=4)
+        accum = _plain(pg, accum_steps=4)
         a0 = accum.init(seed=0)
         a1, m2 = accum.train_step(a0, x, y)
 
@@ -65,7 +72,7 @@ class TestGradAccumulation:
 class TestZero1:
     def test_matches_plain_over_steps(self, pg):
         x, y = _batch(64)
-        plain = _mk(pg)
+        plain = _plain(pg)
         z1 = _mk(pg, shard_optimizer=True)
         sp, sz = plain.init(seed=0), z1.init(seed=0)
         for _ in range(3):
@@ -80,15 +87,20 @@ class TestZero1:
     def test_opt_state_is_sharded(self, pg):
         z1 = _mk(pg, shard_optimizer=True)
         s = z1.init(seed=0)
-        mom = s.opt_state["momentum"]["flat"]
-        assert mom.sharding.spec == P(pg.axis_name)
-        # each device holds 1/8 of the (padded) flat vector
-        assert mom.sharding.shard_shape(mom.shape)[0] == mom.shape[0] // 8
+        # the moments are shaped as their parameters; the one leaf past
+        # ddp.SHARD_MIN_ELEMENTS, (3, 3, 64, 128), is held 1/8 a device
+        # along the first axis 8 divides (the parameter itself too), the
+        # rest replicate
+        mom = s.opt_state["momentum"]["conv3"]["weight"]
+        assert mom.sharding.spec == P(None, None, pg.axis_name)
+        assert mom.sharding.shard_shape(mom.shape) == (3, 3, 64 // 8, 128)
+        assert s.params["conv3"]["weight"].sharding.spec == mom.sharding.spec
+        assert s.opt_state["momentum"]["fc1"]["weight"].sharding.spec == P()
         # stays sharded after a step
         x, y = _batch(16)
         s2, _ = z1.train_step(s, x, y)
-        assert s2.opt_state["momentum"]["flat"].sharding.spec == \
-            P(pg.axis_name)
+        assert s2.opt_state["momentum"]["conv3"]["weight"].sharding.spec \
+            == P(None, None, pg.axis_name)
 
     def test_zero1_scalar_opt_state_leaves(self, pg):
         """Optimizers with scalar step counters (AdamW, scheduled-lr SGD)
@@ -99,7 +111,7 @@ class TestZero1:
                               momentum=0.9)):
             plain = DDP(ConvNet(), optimizer=opt,
                         loss_fn=nn.CrossEntropyLoss(), group=pg,
-                        donate=False)
+                        donate=False, shard_optimizer=False)
             z1 = DDP(ConvNet(), optimizer=opt,
                      loss_fn=nn.CrossEntropyLoss(), group=pg, donate=False,
                      shard_optimizer=True)
@@ -115,7 +127,7 @@ class TestZero1:
 
     def test_zero1_with_accum(self, pg):
         x, y = _batch(64)
-        plain = _mk(pg)
+        plain = _plain(pg)
         combo = _mk(pg, shard_optimizer=True, accum_steps=2)
         sp, sc = plain.init(seed=0), combo.init(seed=0)
         sp, _ = plain.train_step(sp, x, y)

@@ -6,13 +6,15 @@ lower each parallel train step on the 8-device mesh, compile it, and
 assert the expected collective ops appear in the optimized HLO the
 expected number of times:
 
-  - plain DDP      -> all-reduces only, and few of them (XLA's
-                      all-reduce combiner fuses the per-leaf psums;
-                      metrics may ride a separate reduce)
-  - ZeRO-1         -> exactly one reduce-scatter for grads and one
-                      all-reduce that rebuilds the updated flat params
-                      (the psum-of-contributions all-gather), plus the
-                      metrics reduce
+  - whole update   -> all-reduces only, and few of them (XLA's
+    (shard_optimizer  all-reduce combiner fuses the per-leaf psums;
+     =False)          metrics may ride a separate reduce)
+  - DDP by default -> the sharded weight update: a divided leaf's
+    (and =True)       parameter is made whole by all-gather and its
+                      gradient rides reduce-scatter, byte for byte; what
+                      is left to all-reduce is the leaves that stay whole
+                      and the metrics.  Asserted on BYTES: how many
+                      operations carry them is XLA's combiner's choice
   - pipeline (PP)  -> collective-permute for the stage-boundary shifts
   - GSPMD TP       -> all-reduces for row-parallel matmul partial sums
 
@@ -68,12 +70,41 @@ def collective_counts(hlo_text: str) -> dict:
     return out
 
 
-def lowered_counts(ddp, x, y):
+_ITEMSIZE = {"f32": 4, "bf16": 2, "f16": 2, "s32": 4, "u32": 4, "pred": 1}
+
+
+def collective_bytes(hlo_text: str) -> dict:
+    """Bytes each kind of collective RETURNS on one device, summed over
+    its instances (a combined operation returns a tuple: every member
+    counts).  For the synchronous forms the CPU mesh compiles to."""
+    out = dict.fromkeys(COLLECTIVES, 0)
+    for result, op in re.findall(
+            rf"= (\([^)]*\)|\S+) ({'|'.join(COLLECTIVES)})\(", hlo_text):
+        for dtype, dims in re.findall(r"([a-z]+\d*)\[([\d,]*)\]", result):
+            size = _ITEMSIZE[dtype]
+            for d in filter(None, dims.split(",")):
+                size *= int(d)
+            out[op] += size
+    return out
+
+
+def compiled_text(ddp, x, y):
     st = ddp.init(seed=0)
     if ddp._train_step is None:
         ddp._train_step = ddp._build_train_step(st)
-    hlo = ddp._train_step.lower(st, x, y).compile().as_text()
-    return collective_counts(hlo)
+    return ddp._train_step.lower(st, x, y).compile().as_text()
+
+
+def lowered_counts(ddp, x, y):
+    return collective_counts(compiled_text(ddp, x, y))
+
+
+# ConvNet's eight leaves under the sharded update: conv3.weight
+# (3, 3, 64, 128) passes ddp.SHARD_MIN_ELEMENTS and is divided along its
+# third axis; the other seven stay whole
+SHARDED_ELEMENTS = 3 * 3 * 64 * 128
+WHOLE_ELEMENTS = 32 + 800 + 64 + 18432 + 128 + 10 + 20480
+METRIC_BYTES = 8            # the loss (f32) and the correct count (s32)
 
 
 def _batch():
@@ -81,59 +112,88 @@ def _batch():
             jnp.zeros((64,), jnp.int32))
 
 
+def _ddp(pg, **kw):
+    return DDP(ConvNet(), optimizer=optim.SGD(lr=0.1),
+               loss_fn=nn.CrossEntropyLoss(), group=pg, donate=False, **kw)
+
+
 class TestDDPFusedAllReduce:
-    def test_plain_ddp_single_digit_allreduces_no_other_collectives(self, pg):
-        """The whole step compiles to a handful of all-reduces (combiner-
-        fused grads + metrics), NOT one per parameter leaf (ConvNet has 8
-        leaves; unfused lowering emits 10 all_reduce in StableHLO)."""
+    def test_whole_update_single_digit_allreduces_no_other_collectives(
+            self, pg):
+        """With whole updates the step compiles to a handful of
+        all-reduces (combiner-fused grads + metrics), NOT one per
+        parameter leaf (ConvNet has 8 leaves; unfused lowering emits 10
+        all_reduce in StableHLO)."""
         x, y = _batch()
-        ddp = DDP(ConvNet(), optimizer=optim.SGD(lr=0.1),
-                  loss_fn=nn.CrossEntropyLoss(), group=pg, donate=False)
-        c = lowered_counts(ddp, x, y)
+        c = lowered_counts(_ddp(pg, shard_optimizer=False), x, y)
         assert c["all-reduce"] >= 1
         assert c["all-reduce"] <= 4, c
         assert c["reduce-scatter"] == 0, c
         assert c["all-gather"] == 0, c
         assert c["collective-permute"] == 0, c
 
+    def test_default_scatters_gradients_and_gathers_parameters(self, pg):
+        """The default path over a group: the divided leaf's parameter is
+        made whole by all-gather, its gradient rides reduce-scatter (a
+        device receives its 1/n), and NO parameter-sized all-reduce is
+        left: what all-reduce carries is the whole leaves and the metrics,
+        to the byte."""
+        x, y = _batch()
+        text = compiled_text(_ddp(pg), x, y)
+        b, c = collective_bytes(text), collective_counts(text)
+        assert b["reduce-scatter"] == 4 * SHARDED_ELEMENTS // pg.size(), b
+        assert b["all-gather"] == 4 * SHARDED_ELEMENTS, b
+        assert b["all-reduce"] == 4 * WHOLE_ELEMENTS + METRIC_BYTES, b
+        assert c["all-reduce"] <= 4, c
+        assert c["collective-permute"] == 0 and c["all-to-all"] == 0, c
+
     def test_comm_dtype_keeps_fusion(self, pg):
         """bf16 comm-hook compression must not explode the collective
-        count (the cast happens around ONE fused reduce)."""
+        count (the cast happens around ONE fused reduce).  The lowered
+        reduce-scatter carries bf16 (the CPU compiler widens 16-bit
+        collectives again, so bytes are read before it); the parameters
+        come back in float32."""
         x, y = _batch()
-        ddp = DDP(ConvNet(), optimizer=optim.SGD(lr=0.1),
-                  loss_fn=nn.CrossEntropyLoss(), group=pg, donate=False,
-                  comm_dtype=jnp.bfloat16)
-        c = lowered_counts(ddp, x, y)
+        ddp = _ddp(pg, comm_dtype=jnp.bfloat16)
+        text = compiled_text(ddp, x, y)
+        b, c = collective_bytes(text), collective_counts(text)
         assert 1 <= c["all-reduce"] <= 4, c
-        assert c["reduce-scatter"] == 0, c
+        assert c["reduce-scatter"] >= 1, c
+        assert b["all-gather"] == 4 * SHARDED_ELEMENTS, b
+        lowered = ddp._train_step.lower(ddp.init(seed=0), x, y).as_text()
+        shard = f"3x3x{64 // pg.size()}x128"
+        assert re.search(rf"xbf16>\) -> tensor<{shard}xbf16>", lowered)
+        assert not re.search(rf"xf32>\) -> tensor<{shard}xf32>", lowered)
 
     def test_accum_reduces_once_not_per_microbatch(self, pg):
         """no_sync semantics, mechanically: 4 microbatches must NOT emit
-        4x the collectives — the reduce happens once, after the scan."""
+        4x the collectives — the reduce happens once, after the scan:
+        the same bytes as without accumulation."""
         x, y = _batch()
-        plain = DDP(ConvNet(), optimizer=optim.SGD(lr=0.1),
-                    loss_fn=nn.CrossEntropyLoss(), group=pg, donate=False)
-        accum = DDP(ConvNet(), optimizer=optim.SGD(lr=0.1),
-                    loss_fn=nn.CrossEntropyLoss(), group=pg, donate=False,
-                    accum_steps=4)
-        cp = lowered_counts(plain, x, y)
-        ca = lowered_counts(accum, x, y)
+        tp = compiled_text(_ddp(pg), x, y)
+        ta = compiled_text(_ddp(pg, accum_steps=4), x, y)
+        cp, ca = collective_counts(tp), collective_counts(ta)
         assert ca["all-reduce"] <= cp["all-reduce"] + 1, (cp, ca)
+        assert ca["reduce-scatter"] <= cp["reduce-scatter"], (cp, ca)
+        assert collective_bytes(ta) == collective_bytes(tp)
 
 
 class TestZeRO1Collectives:
     def test_reduce_scatter_plus_param_rebuild(self, pg):
-        """ZeRO-1: grads ride ONE reduce-scatter; the updated param shards
-        are rebuilt with ONE all-reduce (psum of offset contributions) or
-        all-gather, plus at most the metrics reduce."""
+        """``shard_optimizer=True`` is the per-leaf path: grads of the
+        divided leaf ride reduce-scatter and the held shards are made
+        whole by an all-gather that IS an all-gather (not a psum of
+        zero-padded contributions): no all-reduce carries a sharded
+        leaf's bytes in either direction."""
         x, y = _batch()
-        ddp = DDP(ConvNet(), optimizer=optim.SGD(lr=0.1),
-                  loss_fn=nn.CrossEntropyLoss(), group=pg, donate=False,
-                  shard_optimizer=True)
-        c = lowered_counts(ddp, x, y)
-        assert c["reduce-scatter"] == 1, c
-        # param rebuild + metrics; grads must NOT ride all-reduce
-        assert 1 <= c["all-reduce"] + c["all-gather"] <= 3, c
+        text = compiled_text(_ddp(pg, shard_optimizer=True), x, y)
+        b, c = collective_bytes(text), collective_counts(text)
+        assert c["reduce-scatter"] >= 1 and c["all-gather"] >= 1, c
+        assert b["reduce-scatter"] == 4 * SHARDED_ELEMENTS // pg.size(), b
+        assert b["all-gather"] == 4 * SHARDED_ELEMENTS, b
+        # the whole leaves + metrics; grads must NOT ride all-reduce
+        assert b["all-reduce"] == 4 * WHOLE_ELEMENTS + METRIC_BYTES, b
+        assert b == collective_bytes(compiled_text(_ddp(pg), x, y))
 
 
 class TestPipelineCollectives:

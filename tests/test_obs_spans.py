@@ -778,6 +778,25 @@ class TestScopes:
         assert fields == [{"step": 1}, {"step": 2}, {"step": 3, "steps": 3}]
         assert ddp._dispatched == 6
 
+    def test_first_train_dispatch_span_carries_the_update_plan(self,
+                                                               tmp_path):
+        """What the weight update shards rides the first dispatch's span,
+        once; ``update_plan()`` gives the same facts to the host."""
+        ddp = _ddp()
+        state = ddp.init(seed=0)
+        x, y = _batch()
+        with _profile(tmp_path):
+            for _ in range(2):
+                state, m = ddp.train_step(state, x, y)
+            jax.block_until_ready(m["loss"])
+        first, second = [f for name, f, *_ in _annotations(tmp_path)
+                         if name == "train.dispatch"]
+        plan = ddp.update_plan()
+        assert first == dict(plan, step=0) and second == {"step": 1}
+        assert plan["world"] == len(jax.devices())
+        assert plan["sharded_leaves"] + plan["whole_leaves"] == len(
+            jax.tree.leaves(state.params))
+
     def test_serving_programs_name_their_scopes(self, lm):
         model, params = lm
         engine = serve.SlotEngine(model, params, num_slots=2)

@@ -22,9 +22,10 @@ Sharded state is handled on both sides:
   gather locally via ``np.asarray``.
 - **restore**: pass ``sharding=`` to re-place leaves;
   :meth:`tpu_dist.parallel.DistributedDataParallel.state_shardings` builds
-  the matching pytree for a TrainState (replicated params, ZeRO-1-sharded
-  opt_state) so a ``shard_optimizer=True`` state round-trips with its
-  P(axis) placement intact.
+  the matching pytree for a TrainState (params and opt_state sharded leaf
+  by leaf where the weight update is: the default over a group of more
+  than one) so such a state round-trips with its placement intact; a
+  state saved whole restores under it the same way.
 
 Works on any pytree of arrays — :class:`tpu_dist.parallel.TrainState`
 included (its PRNG key is stored as key *data*, a plain uint32 array).

@@ -23,6 +23,14 @@ all-reduce both correct and free:
   so XLA overlaps it with remaining backward compute on ICI — the same
   overlap DDP's Reducer implements by hand with buckets and streams.
 
+Over a group of more than one the weight update is sharded leaf by leaf
+(Xu et al., arXiv:2004.13336): a replica HOLDS 1/world of each divided
+parameter leaf and of its optimizer state along :func:`shard_axis`; a step
+all-gathers the parameters for the forward and backward passes,
+reduce-scatters each gradient leaf and updates the 1/world it holds — the
+all-reduce's bytes, 1/world of the update
+(``shard_optimizer``, :meth:`DistributedDataParallel.update_plan`).
+
 BatchNorm semantics (SURVEY.md §2b #16): batch statistics stay **per-replica**
 (DDP parity — torch DDP does not sync BN).  Running-stat *updates* are
 pmean-ed across replicas to keep the state replicated; this is a documented,
@@ -33,6 +41,7 @@ layers to cross-replica batch stats (torch SyncBatchNorm parity).
 
 from __future__ import annotations
 
+import math
 from functools import partial
 from typing import Any, NamedTuple, Optional
 
@@ -40,18 +49,33 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
+# jax 0.9.0 keeps the varying -> invariant all-gather out of ``jax.lax``;
+# under ``shard_map`` it type-checks as replicated and lowers to ONE
+# all-gather (``lax.all_gather`` leaves its result marked varying)
+from jax._src.lax.parallel import all_gather_invariant
 
 from ..nn.layers import BatchNorm2d
 from ..nn.module import Module
 from ..obs.spans import span
 
-__all__ = ["TrainState", "DistributedDataParallel", "convert_sync_batchnorm"]
+__all__ = ["TrainState", "DistributedDataParallel", "convert_sync_batchnorm",
+           "shard_axis"]
+
+# A leaf under this many elements keeps the whole update on every replica:
+# a quarter of a LayerNorm vector or a bias is not worth a reduce-scatter
+# and an all-gather of its own.  GPT-2 medium's 195 vectors (1,024-50,257
+# elements, 0.09% of the model) lie below it, its 99 matrices (>= 2**20)
+# above; PERF.md section 3 counts both sides.
+SHARD_MIN_ELEMENTS = 1 << 16
 
 
 class TrainState(NamedTuple):
     """Training state threaded through the jitted step.  Replicated over the
-    group — except ``opt_state`` under ``shard_optimizer=True`` (ZeRO-1),
-    which is sharded 1/world per device as a flat vector."""
+    group — except, where the weight update is sharded (a group of more than
+    one by default), the divided leaves of ``params`` and of ``opt_state``:
+    each is held 1/world per device along :func:`shard_axis`.  They are
+    ordinary global arrays all the same: reading one on the host, or handing
+    it to a function that wants it replicated, gathers it."""
     params: Any
     model_state: Any      # BN running stats etc.; {} for stateless nets
     opt_state: Any
@@ -63,28 +87,26 @@ def _ceil_to(x: int, m: int) -> int:
     return (x + m - 1) // m * m
 
 
-def _zero1_spec(leaf, axis: str) -> P:
-    """ZeRO-1 opt-state placement rule: rank>=1 leaves shard 1/world over
-    the data axis; scalar leaves (schedule/Adam step counters) replicate.
+def shard_axis(shape, n: int) -> Optional[int]:
+    """The axis along which a group of ``n`` divides a parameter leaf of
+    this shape for the sharded weight update: the first one ``n`` divides.
+    None where the leaf stays whole: a group of one, no such axis (GPT-2's
+    ``[50257]`` head bias), or under :data:`SHARD_MIN_ELEMENTS`.  A pure
+    function of ``(shape, n)``: a moment has its parameter's shape, so the
+    parameter, its gradient and its optimizer state agree on the axis."""
+    if n <= 1 or math.prod(shape) < SHARD_MIN_ELEMENTS:
+        return None
+    return next((d for d, s in enumerate(shape) if s % n == 0), None)
+
+
+def _zero1_spec(leaf, axis: str, n: int) -> P:
+    """Placement of a parameter or optimizer-state leaf under the sharded
+    update: held 1/world along its :func:`shard_axis`; scalar leaves
+    (schedule/Adam step counters) and the leaves that stay whole replicate.
     Single source of truth for state_shardings() and the train-step
     in/out_specs — they must agree or restore-time placement breaks."""
-    return P(axis) if getattr(leaf, "ndim", 0) >= 1 else P()
-
-
-def _flatten_params(tree):
-    """Concatenate all leaves, raveled, in tree-flatten order."""
-    leaves = jax.tree.leaves(tree)
-    return jnp.concatenate([l.ravel() for l in leaves])
-
-
-def _unflatten_params(flat, template):
-    """Inverse of :func:`_flatten_params` (padding tail ignored)."""
-    leaves, treedef = jax.tree_util.tree_flatten(template)
-    out, off = [], 0
-    for l in leaves:
-        out.append(flat[off:off + l.size].reshape(l.shape).astype(l.dtype))
-        off += l.size
-    return jax.tree_util.tree_unflatten(treedef, out)
+    d = shard_axis(getattr(leaf, "shape", ()), n)
+    return P() if d is None else P(*([None] * d), axis)
 
 
 def convert_sync_batchnorm(module: Module, axis_name: str) -> Module:
@@ -120,9 +142,9 @@ class DistributedDataParallel:
     def __init__(self, module: Module, optimizer=None, loss_fn=None,
                  group=None, sync_batchnorm: bool = False,
                  donate: bool = True, compute_dtype=None,
-                 accum_steps: int = 1, shard_optimizer: bool = False,
-                 comm_dtype=None):
-        """Options beyond torch-DDP parity (all default off):
+                 accum_steps: int = 1,
+                 shard_optimizer: Optional[bool] = None, comm_dtype=None):
+        """Options beyond torch-DDP parity:
 
         ``compute_dtype``: run forward/backward in this dtype (bf16 for the
         MXU) while parameters, gradients and optimizer state stay float32
@@ -133,12 +155,30 @@ class DistributedDataParallel:
         comms pattern of torch DDP's ``no_sync`` accumulation, compiled as a
         ``lax.scan``.
 
-        ``shard_optimizer``: ZeRO-1 / cross-replica weight-update sharding
-        (Xu et al., arXiv:2004.13336 — the XLA data-parallel paper): the
-        gradient all-reduce splits into reduce-scatter + all-gather around
-        an optimizer update that each replica performs on only 1/world of
-        the (flattened) parameters, so optimizer state is sharded 1/world
-        per device.  Numerics identical to the dense path (tested).
+        ``shard_optimizer``: cross-replica sharding of the weight update
+        (ZeRO-1; Xu et al., arXiv:2004.13336 — the XLA data-parallel
+        paper), leaf by leaf.  A replica holds 1/world of each divided
+        parameter leaf and of its optimizer state along the leaf's
+        :func:`shard_axis`; a step all-gathers the parameters for its
+        forward and backward passes, reduce-scatters each gradient leaf,
+        and updates the 1/world it holds — the all-reduce's bytes on the
+        wire, a 1/world of the update's work and of the parameters' and
+        moments' memory at rest.  (The gather opens the step rather than
+        closing it: a gathered parameter that is a program's RESULT costs a
+        copy of the whole leaf on the TPU, twice with donation; one that
+        feeds the cast to ``compute_dtype`` costs nothing more.)  Leaves
+        with no axis the group size divides, or under
+        :data:`SHARD_MIN_ELEMENTS`, stay replicated and keep the all-reduce
+        and the whole update.  ``None`` (the default) decides by group
+        size: sharded when the group has more than one member; ``False``
+        keeps every leaf replicated and every update whole (the oracle the
+        sharded path is tested against); ``True`` is the same as ``None``
+        (a group of one has nothing to shard).  Numerics identical to the
+        whole update (tested): every optimizer in :mod:`tpu_dist.optim` is
+        elementwise per leaf plus scalar counters.  :meth:`update_plan`
+        says what was sharded; :meth:`eval_step` and :meth:`forward` take
+        the held parameters as they are (one gather a call); restore a
+        checkpoint through :meth:`state_shardings`.
 
         ``comm_dtype``: compress the gradient all-reduce to this dtype
         (torch DDP *comm hook* parity — ``fp16_compress_hook`` /
@@ -148,7 +188,8 @@ class DistributedDataParallel:
         and cast back to the gradient's dtype before the optimizer update.
         Halves ICI/DCN bytes per step with 16-bit dtypes; composes with
         ``accum_steps`` (compression happens once, at sync time, like the
-        torch hook) and ZeRO-1 (the reduce-scatter runs compressed).
+        torch hook) and the sharded update (the reduce-scatter runs
+        compressed).
         """
         if group is None:
             from .. import dist as _dist
@@ -164,12 +205,15 @@ class DistributedDataParallel:
         self.compute_dtype = compute_dtype
         self.accum_steps = accum_steps
         self.shard_optimizer = shard_optimizer
+        # the group a leaf is divided over: 1 = every update whole
+        self._shard_n = group.size() if shard_optimizer is not False else 1
         self.comm_dtype = comm_dtype
         if sync_batchnorm:
             convert_sync_batchnorm(module, self.axis)
         self._train_step = None
         self._train_chunk = None
         self._dispatched = 0    # steps handed to the device: spans' step=
+        self._plan = None       # update_plan(), once parameter shapes are seen
         self._train_repeat_cache = {}
         self._eval_step = None
         self._forward = None
@@ -188,52 +232,59 @@ class DistributedDataParallel:
         key = rng if rng is not None else jax.random.key(seed)
         params = self.module.init(key)
         model_state = self.module.init_state()
-        if self.optimizer is None:
-            opt_state = {}
-        elif self.shard_optimizer:
-            # ZeRO-1: optimizer state lives on the flattened-and-padded
-            # parameter vector, sharded 1/world per device
-            n = self.group.size()
-            flat = _flatten_params(params)
-            padded = _ceil_to(flat.size, n)
-            opt_state = self.optimizer.init({"flat": jnp.zeros(padded)})
-        else:
-            opt_state = self.optimizer.init(params)
+        opt_state = ({} if self.optimizer is None
+                     else self.optimizer.init(params))
         state = TrainState(params, model_state, opt_state,
                            jnp.zeros((), jnp.int32),
                            jax.random.key_data(jax.random.fold_in(key, 0x5eed)))
         # commit onto the mesh so donation reuses buffers; the layout policy
-        # (replicated everywhere, ZeRO-1-sharded opt_state) lives in
-        # state_shardings so checkpoints restore to exactly this placement
+        # (replicated, but params and opt_state per leaf by shard_axis) lives
+        # in state_shardings so checkpoints restore to exactly this placement
         return jax.tree.map(jax.device_put, state, self.state_shardings(state))
 
     def state_shardings(self, state: TrainState) -> TrainState:
         """Pytree of :class:`NamedSharding` mirroring ``state``'s layout:
-        everything replicated except ZeRO-1-sharded ``opt_state``
-        (``P(axis)``).  Feed to ``tpu_dist.checkpoint.restore(sharding=...)``
-        so a restored TrainState lands with its original placement."""
-        repl = NamedSharding(self.group.mesh, P())
-        shardings = jax.tree.map(lambda _: repl, state)
-        if self.shard_optimizer and self.optimizer is not None:
-            shardings = shardings._replace(
-                opt_state=jax.tree.map(
-                    lambda l: NamedSharding(self.group.mesh,
-                                            _zero1_spec(l, self.axis)),
-                    state.opt_state))
-        return shardings
+        everything replicated except the ``params`` and ``opt_state`` leaves
+        a sharded update divides, each along its :func:`shard_axis`.  Feed to
+        ``tpu_dist.checkpoint.restore(sharding=...)`` so a restored
+        TrainState lands with its original placement."""
+        mesh = self.group.mesh
+        return jax.tree.map(lambda spec: NamedSharding(mesh, spec),
+                            self._state_pspecs(state))
+
+    def update_plan(self) -> dict:
+        """What the weight update shards, host facts fixed at build:
+        ``world`` (the group's size), ``sharded_leaves`` /
+        ``sharded_elements`` (parameter leaves held and updated 1/world a
+        replica along their :func:`shard_axis`, and their elements),
+        ``whole_leaves`` / ``whole_elements`` (updated whole on every
+        replica).  Also on the first ``td/train.dispatch`` span."""
+        if self._plan is None:
+            self._plan = self._plan_of(
+                jax.eval_shape(self.module.init, jax.random.key(0)))
+        return dict(self._plan)
+
+    def _plan_of(self, params) -> dict:
+        plan = {"world": self.group.size(), "sharded_leaves": 0,
+                "whole_leaves": 0, "sharded_elements": 0, "whole_elements": 0}
+        for leaf in jax.tree.leaves(params):
+            kind = ("whole" if shard_axis(leaf.shape, self._shard_n) is None
+                    else "sharded")
+            plan[kind + "_leaves"] += 1
+            plan[kind + "_elements"] += int(leaf.size)
+        return plan
 
     # -- compiled steps --------------------------------------------------------
-    def _state_pspec(self, template: TrainState) -> TrainState:
-        """PartitionSpec pytree for TrainState: replicated, except ZeRO-1
-        opt_state sharded over the data axis (must agree with
-        :meth:`state_shardings`)."""
-        if self.shard_optimizer:
-            opt_spec = jax.tree.map(lambda l: _zero1_spec(l, self.axis),
-                                    template.opt_state)
-        else:
-            opt_spec = P()
-        return TrainState(params=P(), model_state=P(), opt_state=opt_spec,
-                          step=P(), rng=P())
+    def _state_pspecs(self, template: TrainState) -> TrainState:
+        """PartitionSpec pytree for TrainState, a spec a leaf: replicated,
+        except a sharded update's params and opt_state (:func:`_zero1_spec`).
+        The one place that says so: :meth:`state_shardings` and the train
+        step's in/out_specs both read it."""
+        held = lambda tree: jax.tree.map(
+            lambda l: _zero1_spec(l, self.axis, self._shard_n), tree)
+        return jax.tree.map(lambda _: P(), template)._replace(
+            params=held(template.params),
+            opt_state=held(template.opt_state))
 
     def _make_local_step(self, template: TrainState):
         module, loss_fn, optimizer, axis = (self.module, self.loss_fn,
@@ -242,12 +293,46 @@ class DistributedDataParallel:
         accum = self.accum_steps
         cdtype = self.compute_dtype
         comm_dtype = self.comm_dtype
-        zero1 = self.shard_optimizer
         n = self.group.size()
+        # the axis each parameter leaf is divided along (None: stays whole),
+        # in tree-flatten order
+        dims = [shard_axis(leaf.shape, self._shard_n)
+                for leaf in jax.tree.leaves(template.params)]
+        self._plan = self._plan_of(template.params)
+
+        def reduce_grad(g, d):
+            """The mean gradient over the group: whole by all-reduce, or
+            this replica's 1/n along ``d`` by reduce-scatter.  Comm-hook
+            compression (torch DDP fp16/bf16_compress_hook semantics):
+            divide by world size BEFORE the cast so the compressed-dtype
+            sum cannot overflow (fp16 max 65504), move comm_dtype bytes on
+            the wire, and decompress to the gradient's dtype after the
+            reduce — accumulation and the optimizer update stay in the
+            uncompressed dtype."""
+            if d is None:
+                total = partial(lax.psum, axis_name=axis)
+            else:
+                total = partial(lax.psum_scatter, axis_name=axis,
+                                scatter_dimension=d, tiled=True)
+            if comm_dtype is None or not jnp.issubdtype(g.dtype,
+                                                        jnp.floating):
+                return lax.pmean(g, axis) if d is None else total(g) / n
+            return total((g / n).astype(comm_dtype)).astype(g.dtype)
+
+        treedef = jax.tree.structure(template.params)
+        on_leaves = lambda f, tree: jax.tree_util.tree_unflatten(
+            treedef, [f(v, d) for v, d in
+                      zip(treedef.flatten_up_to(tree), dims)])
 
         def local_step(state: TrainState, x, y):
-            params, mstate, opt_state, step, rng_data = state
+            held, mstate, opt_state, step, rng_data = state
             base_key = jax.random.wrap_key_data(rng_data)
+            # a divided leaf arrives as this replica's 1/n: make it whole
+            # (and replicated) for the forward and backward passes
+            with jax.named_scope("param_gather"):
+                params = on_leaves(
+                    lambda p, d: p if d is None else all_gather_invariant(
+                        p, axis, axis=d, tiled=True), held)
 
             # Microbatch gradient: params are made device-varying (pvary) so
             # jax.grad yields LOCAL gradients with no implicit collective —
@@ -322,62 +407,17 @@ class DistributedDataParallel:
                 loss = lax.pmean(loss_sum, axis)
                 correct = lax.psum(correct_sum, axis)
 
-            # comm-hook compression (torch DDP fp16/bf16_compress_hook
-            # semantics): divide by world size BEFORE the cast so the
-            # compressed-dtype sum cannot overflow (fp16 max 65504), move
-            # comm_dtype bytes on the wire, and decompress to the original
-            # grad dtype after the reduce — accumulation and the optimizer
-            # update stay in the uncompressed dtype
-            if zero1:
-                # reduce-scatter averaged grads; update 1/n of the flat
-                # parameter vector per device; all-gather updated params
-                with jax.named_scope("grad_reduce"):
-                    flat_g = _flatten_params(local_grads)
-                    padded = _ceil_to(flat_g.size, n)
-                    flat_g = jnp.pad(flat_g, (0, padded - flat_g.size))
-                    if comm_dtype is None:
-                        g_shard = lax.psum_scatter(
-                            flat_g, axis, scatter_dimension=0,
-                            tiled=True) / n
-                    else:
-                        g_shard = lax.psum_scatter(
-                            (flat_g / n).astype(comm_dtype), axis,
-                            scatter_dimension=0,
-                            tiled=True).astype(flat_g.dtype)
-                with jax.named_scope("optimizer"):
-                    flat_p = _flatten_params(params)
-                    flat_p = jnp.pad(flat_p, (0, padded - flat_p.size))
-                    chunk = padded // n
-                    me = lax.axis_index(axis)
-                    p_shard = lax.dynamic_slice_in_dim(flat_p, me * chunk,
-                                                       chunk)
-                    new_shard, new_opt = optimizer.update(
-                        {"flat": g_shard}, opt_state, {"flat": p_shard})
-                    # all-gather the updated shards as a psum of
-                    # offset-placed contributions: psum of varying inputs
-                    # yields a VMA-invariant (replicated) output, which the
-                    # P() params out_spec needs — lax.all_gather would leave
-                    # the value marked varying
-                    contrib = jnp.zeros((padded,), new_shard["flat"].dtype)
-                    contrib = lax.dynamic_update_slice_in_dim(
-                        contrib, new_shard["flat"], me * chunk, 0)
-                    flat_new = lax.psum(contrib, axis)
-                    new_params = _unflatten_params(flat_new, params)
-            else:
-                with jax.named_scope("grad_reduce"):
-                    if comm_dtype is None:
-                        grads = jax.tree.map(lambda g: lax.pmean(g, axis),
-                                             local_grads)
-                    else:
-                        grads = jax.tree.map(
-                            lambda g: lax.psum((g / n).astype(comm_dtype),
-                                               axis).astype(g.dtype)
-                            if jnp.issubdtype(g.dtype, jnp.floating) else
-                            lax.pmean(g, axis),
-                            local_grads)
-                with jax.named_scope("optimizer"):
-                    new_params, new_opt = optimizer.update(
-                        grads, opt_state, params)
+            # Cross-replica sharding of the weight update, leaf by leaf: a
+            # replica receives 1/n of each divided leaf's mean gradient and
+            # updates the 1/n of the parameter it holds with its 1/n of the
+            # moments; none of the three leaves it, and the next step's
+            # gather is what makes the parameter whole again.  A leaf that
+            # stays whole takes the all-reduce and the whole update, as
+            # every leaf does at n == 1.
+            with jax.named_scope("grad_reduce"):
+                grads = on_leaves(reduce_grad, local_grads)
+            with jax.named_scope("optimizer"):
+                new_params, new_opt = optimizer.update(grads, opt_state, held)
 
             if has_state:
                 # keep replicated-state invariant: average the per-replica
@@ -390,7 +430,7 @@ class DistributedDataParallel:
         return local_step
 
     def _build_train_step(self, template: TrainState):
-        state_spec = self._state_pspec(template)
+        state_spec = self._state_pspecs(template)
         fn = jax.shard_map(self._make_local_step(template),
                            mesh=self.group.mesh,
                            in_specs=(state_spec, P(self.axis), P(self.axis)),
@@ -405,7 +445,7 @@ class DistributedDataParallel:
                 return local_step(st, xy[0], xy[1])
             return lax.scan(body, state, (xs, ys))
 
-        state_spec = self._state_pspec(template)
+        state_spec = self._state_pspecs(template)
         fn = jax.shard_map(local_chunk, mesh=self.group.mesh,
                            in_specs=(state_spec, P(None, self.axis),
                                      P(None, self.axis)),
@@ -420,7 +460,7 @@ class DistributedDataParallel:
                 return local_step(st, x, y)
             return lax.scan(body, state, None, length=num_steps)
 
-        state_spec = self._state_pspec(template)
+        state_spec = self._state_pspecs(template)
         fn = jax.shard_map(local_repeat, mesh=self.group.mesh,
                            in_specs=(state_spec, P(self.axis), P(self.axis)),
                            out_specs=(state_spec, P()))
@@ -432,8 +472,9 @@ class DistributedDataParallel:
         ignore = getattr(loss_fn, "ignore_index", None)
 
         # takes only (params, model_state): feeding the whole TrainState
-        # would re-lay-out ZeRO-1-sharded opt_state to replicated (an
-        # all-gather of optimizer moments) on every eval batch
+        # would re-lay-out a sharded opt_state to replicated (an
+        # all-gather of optimizer moments) on every eval batch.  Held
+        # parameters are gathered by the P() in_spec, once a call
         def local_eval(params, mstate, x, y, n_valid):
             out = module.apply(params, x,
                                **({"state": mstate} if has_state else {}))
@@ -498,7 +539,9 @@ class DistributedDataParallel:
             raise ValueError("train_step requires optimizer= and loss_fn=")
         if self._train_step is None:
             self._train_step = self._build_train_step(state)
-        with span("train.dispatch", step=self._dispatched):
+        # the first dispatch carries what the update shards
+        plan = self._plan if self._dispatched == 0 else {}
+        with span("train.dispatch", step=self._dispatched, **plan):
             out = self._train_step(state, x, y)
         self._dispatched += 1
         return out
@@ -522,7 +565,9 @@ class DistributedDataParallel:
         if self._train_chunk is None:
             self._train_chunk = self._build_train_chunk(state)
         steps = int(xs.shape[0])
-        with span("train.dispatch", step=self._dispatched, steps=steps):
+        plan = self._plan if self._dispatched == 0 else {}
+        with span("train.dispatch", step=self._dispatched, steps=steps,
+                  **plan):
             out = self._train_chunk(state, xs, ys)
         self._dispatched += steps
         return out
