@@ -816,30 +816,86 @@ def _served_logits(model, seed=0):
     ).hexdigest()
 
 
-@pytest.mark.parametrize("name,digest", [
-    ("olmoe",
-     "bd18a4f2fe4c9a24d1da8437edc23077feaa12c293f2020bbbf8354d65627f88"),
-    ("qwen3next",
-     "af678d18815f000f52c4c80c3cd7cc746f2bc2cd70b8a1435e89f5c5efa0744b")])
-def test_the_routed_models_serve_the_parents_logits_bit_for_bit(name, digest):
-    """The router's second scoring form, its selection bias and the shared
-    expert's optional gate change nothing for the models that do not ask
-    for them: the digests are the PARENT commit's (ed68261), taken on this
-    CPU by the same function."""
+def _small_model(name):
+    if name == "gpt2":
+        return TransformerLM(vocab_size=211, dim=64, depth=2, num_heads=4,
+                             max_seq_len=128)
+    if name == "kimik2":
+        return _model(SHARE)
     if name == "olmoe":
-        model = TransformerLM(
+        return TransformerLM(
             vocab_size=211, dim=64, depth=2, num_heads=4, max_seq_len=128,
             num_experts=8, moe_top_k=2, moe_hidden=32,
             moe_normalize_gates=False, norm_eps=1e-5, rope_theta=10000,
             norm="rmsnorm", rope=True, qk_norm=True, attn_bias=False,
             moe_gated=True, moe_dispatch="dropless")
-    else:
-        model = Qwen3NextLM(
-            vocab_size=211, dim=64, depth=4, num_heads=4, num_kv_heads=1,
-            head_dim=32, full_attention_interval=4,
-            partial_rotary_factor=0.25, rope_theta=10000000, norm_eps=1e-6,
-            linear_key_heads=2, linear_value_heads=4, linear_key_dim=16,
-            linear_value_dim=16, linear_conv_kernel=4, num_experts=16,
-            experts_held=4, expert_offset=4, moe_top_k=4, moe_hidden=32,
-            shared_hidden=32, moe_normalize_gates=True, max_seq_len=256)
-    assert _served_logits(model) == digest
+    return Qwen3NextLM(
+        vocab_size=211, dim=64, depth=4, num_heads=4, num_kv_heads=1,
+        head_dim=32, full_attention_interval=4,
+        partial_rotary_factor=0.25, rope_theta=10000000, norm_eps=1e-6,
+        linear_key_heads=2, linear_value_heads=4, linear_key_dim=16,
+        linear_value_dim=16, linear_conv_kernel=4, num_experts=16,
+        experts_held=4, expert_offset=4, moe_top_k=4, moe_hidden=32,
+        shared_hidden=32, moe_normalize_gates=True, max_seq_len=256)
+
+
+@pytest.mark.parametrize("name,digest", [
+    ("olmoe",
+     "bd18a4f2fe4c9a24d1da8437edc23077feaa12c293f2020bbbf8354d65627f88"),
+    ("qwen3next",
+     "af678d18815f000f52c4c80c3cd7cc746f2bc2cd70b8a1435e89f5c5efa0744b"),
+    ("gpt2",
+     "c0e1b8382010846436091e694dc7c7c47621d6425b829d37ea187409f3c63edb"),
+    ("kimik2",
+     "c943e4ead157c2619aef51facacc0ede33cccffe7563ebeb38a1411eae8f0560")])
+def test_the_routed_models_serve_the_parents_logits_bit_for_bit(name, digest):
+    """The router's second scoring form, its selection bias and the shared
+    expert's optional gate (PR 32), and the way a sublayer's output joins
+    the residual (PR 38: ``TransformerBlock(residual=...)``,
+    ``TransformerLM.forward``'s open and close), change nothing for the
+    models that do not ask for them: the digests are the PARENT commits'
+    (olmoe and qwen3next ed68261 and again 102ef64, gpt2 and kimik2
+    102ef64), taken on this CPU by the same function."""
+    assert _served_logits(_small_model(name)) == digest
+
+
+def _trained(steps=2, seed=0):
+    """``steps`` ``train_step``s of a small GPT-2 through
+    ``DistributedDataParallel`` on one device (AdamW, float32): the bytes of
+    every loss and of the updated parameters, hashed."""
+    import tpu_dist.dist as dist
+    from tpu_dist import optim
+    from tpu_dist.parallel import DistributedDataParallel
+
+    if dist.is_initialized():
+        dist.destroy_process_group()
+    pg = dist.init_process_group()
+    try:
+        ddp = DistributedDataParallel(
+            TransformerLM(vocab_size=211, dim=64, depth=2, num_heads=4,
+                          max_seq_len=32),
+            optimizer=optim.AdamW(lr=3e-4), loss_fn=nn.CrossEntropyLoss(),
+            group=dist.new_group(ranks=[0]))
+        state = ddp.init(seed=seed)
+        rng = np.random.default_rng(seed)
+        out = []
+        for _ in range(steps):
+            x = jnp.asarray(rng.integers(0, 211, (4, 32)), jnp.int32)
+            y = jnp.asarray(rng.integers(0, 211, (4, 32)), jnp.int32)
+            state, metrics = ddp.train_step(state, x, y)
+            out.append(np.asarray(metrics["loss"], np.float32))
+        params = jax.device_get(state.params)
+        out += [np.asarray(params[path][name], np.float32)
+                for path in sorted(params) for name in sorted(params[path])]
+        return hashlib.sha256(b"".join(
+            np.ascontiguousarray(a).tobytes() for a in out)).hexdigest()
+    finally:
+        dist.destroy_process_group()
+
+
+def test_gpt2_trains_to_the_parents_loss_and_parameters_bit_for_bit():
+    """``TransformerBlock.forward`` and ``TransformerLM.forward`` are the
+    training cells' too: two steps' losses and parameters are the PARENT
+    commit's (102ef64), taken on this CPU by the same function."""
+    assert _trained() == (
+        "5a9859aed9571a3bc9302be85bbe9e4703cda745688ec64c1a1662658280099b")

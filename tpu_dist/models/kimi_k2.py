@@ -61,7 +61,12 @@ class KimiK2LM(TransformerLM):
                  rope_scaling_beta_slow: float = 1.0,
                  rope_scaling_mscale: float = 1.0,
                  rope_scaling_mscale_all_dim: float = 0.0,
-                 norm_eps: float = 1e-6, max_seq_len: int = 131072):
+                 norm_eps: float = 1e-6, max_seq_len: int = 131072,
+                 residual=None):
+        """``residual``: how a sublayer's output joins the residual, handed
+        to every :class:`TransformerBlock` (None is ``x + f(x)``, the
+        published Kimi K2; models/xing4.py builds on this constructor with
+        a hyper-connection)."""
         nn.Module.__init__(self)
         if n_group != 1 or topk_group != 1:
             raise NotImplementedError(
@@ -111,7 +116,7 @@ class KimiK2LM(TransformerLM):
                 qk_rope_head_dim, v_head_dim, rope_theta=rope_theta,
                 rope_inv_freq=inv_freq, softmax_scale=scale,
                 norm_eps=norm_eps),
-            mlp=mlp(kind)) for kind in self.layer_kinds]
+            mlp=mlp(kind), residual=residual) for kind in self.layer_kinds]
         self._assemble(vocab_size, dim, max_seq_len, blocks,
                        ln_f=_make_norm("rmsnorm", dim, norm_eps),
                        head=nn.Linear(dim, vocab_size, bias=False),

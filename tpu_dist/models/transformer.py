@@ -42,12 +42,20 @@ class TransformerBlock(nn.Module):
                  mlp: Optional[nn.Module] = None, norm: str = "layernorm",
                  rope: bool = False, rope_theta: float = 10000.0,
                  norm_eps: Optional[float] = None, attn_bias: bool = True,
-                 qk_norm: bool = False, mixer: Optional[nn.Module] = None):
+                 qk_norm: bool = False, mixer: Optional[nn.Module] = None,
+                 residual=None):
         """``mixer`` overrides the token mixer (the ``attn`` submodule)
         with a module built by the caller, as ``mlp`` overrides the MLP:
         e.g. an :class:`nn.GatedDeltaNet`, or an attention layer spelled
         beyond this constructor's flags.  A mixer that serves from a slot
-        cache has ``init_cache(batch, max_len, dtype)``."""
+        cache has ``init_cache(batch, max_len, dtype)``.
+
+        ``residual`` is how a sublayer's output joins the residual: None is
+        ``x + f(x)``; a callable builds the joining module of ONE sublayer
+        (called twice: ``hc_attn`` and ``hc_mlp``, each with parameters of
+        its own), e.g. ``lambda: nn.HyperConnection(dim, 4)``, and the block
+        then takes and returns that module's ``streams`` streams, a tuple of
+        ``(B, T, dim)`` arrays (:attr:`streams`)."""
         super().__init__()
         self.ln1 = _make_norm(norm, dim, norm_eps)
         # qk_norm: an RMSNorm over the whole q and k projections, with the
@@ -61,11 +69,26 @@ class TransformerBlock(nn.Module):
         # mlp override: e.g. an nn.MoELayer for mixture-of-experts blocks
         self.mlp = mlp if mlp is not None else nn.Sequential(
             nn.Linear(dim, 4 * dim), nn.GELU(), nn.Linear(4 * dim, dim))
+        # registered only where asked for: a module more moves every later
+        # module's initialisation key
+        self.hc_attn = self.hc_mlp = None
+        if residual is not None:
+            self.hc_attn, self.hc_mlp = residual(), residual()
+
+    @property
+    def streams(self) -> int:
+        """Residual streams the block takes and returns (1: one array)."""
+        return 1 if self.hc_attn is None else self.hc_attn.streams
 
     def forward(self, x):
-        x = x + self.attn(self.ln1(x))
-        x = x + self.mlp(self.ln2(x))
-        return x
+        if self.hc_attn is None:
+            x = x + self.attn(self.ln1(x))
+            x = x + self.mlp(self.ln2(x))
+            return x
+        u, coeff = self.hc_attn(x)
+        x = self.hc_attn.post(x, self.attn(self.ln1(u)), coeff)
+        u, coeff = self.hc_mlp(x)
+        return self.hc_mlp.post(x, self.mlp(self.ln2(u)), coeff)
 
 
 class TransformerLM(nn.Module):
@@ -154,6 +177,10 @@ class TransformerLM(nn.Module):
         for i, blk in enumerate(blocks):
             setattr(self, f"block{i}", blk)
         self.depth = len(blocks)
+        #: residual streams between the blocks (the blocks' own answer):
+        #: ``forward`` opens them after the embedding and closes them before
+        #: ``ln_f``; 1 is the plain residual, one array and neither step
+        self.streams = blocks[0].streams if blocks else 1
         self.causal = causal
         self.sequence_axis = sequence_axis
         # remat=True wraps each block in jax.checkpoint: activations inside
@@ -199,6 +226,8 @@ class TransformerLM(nn.Module):
         # out of the jax.checkpoint sub-trace (and inference keeps no
         # activations anyway)
         use_remat = self.remat and not self._decoding()
+        if self.streams > 1:
+            x = nn.open_streams(x, self.streams)    # no operation, no scope
         for i in range(self.depth):
             block = getattr(self, f"block{i}")
             if use_remat:
@@ -216,7 +245,21 @@ class TransformerLM(nn.Module):
                     ctx.put_state(path, val)
             else:
                 x = block(x)
+        if self.streams > 1:
+            x = nn.close_streams(x)
         return self.head(self.ln_f(x))
+
+    def residual_numbers_per_row(self) -> int:
+        """Numbers of the compute type ONE row (a prompt token, a busy slot)
+        must move through the residual mixes of all sublayers: each block's
+        joining modules' ``numbers_per_row``; 0 for the plain residual,
+        whose add rides in the sublayer's own output.  A host fact for
+        ``SlotEngine.stats()["residual"]``."""
+        return sum(hc.numbers_per_row
+                   for i in range(self.depth)
+                   for hc in (getattr(self, f"block{i}").hc_attn,
+                              getattr(self, f"block{i}").hc_mlp)
+                   if hc is not None)
 
     def _decoding(self) -> bool:
         """True when the current apply() carries a KV cache for this model's

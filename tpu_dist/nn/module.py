@@ -221,13 +221,18 @@ class Module:
             f"{type(self).__name__} does not define forward()."
         )
 
+    def scope(self):
+        """The module's own name as a ``jax.named_scope``, so the scope
+        stack of every operation reads as the module path (block3/attn,
+        block3/mlp/1); metadata only, the compiled program does not
+        change.  ``__call__`` opens it around ``forward``; a module with a
+        second entry point opens it there (``nn.HyperConnection.post``)."""
+        return jax.named_scope(
+            (self._path or type(self).__name__).rpartition(".")[2])
+
     def __call__(self, *args, **kwargs):
         _ctx()  # modules may only be invoked during apply()
-        # the module's own name as a scope, so the scope stack of every
-        # operation reads as the module path (block3/attn, block3/mlp/1);
-        # metadata only, the compiled program does not change
-        name = (self._path or type(self).__name__).rpartition(".")[2]
-        with jax.named_scope(name):
+        with self.scope():
             return self.forward(*args, **kwargs)
 
     # -- conveniences ----------------------------------------------------------

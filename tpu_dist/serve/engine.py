@@ -464,6 +464,21 @@ def decode_need_facts(model, params) -> dict:
             "experts": experts, "attend_flops": int(attend_flops)}
 
 
+def residual_facts(model, params) -> dict:
+    """What the residual between ``model``'s sublayers is made of, from the
+    model's own answers: ``streams`` it keeps a token, ``sublayers`` that
+    read and write them, and ``row_bytes``, what ONE row must move through
+    the mixes of all of them in the type the embedding is served in
+    (``residual_numbers_per_row``: 0 for ``x + f(x)``, whose add rides in
+    the sublayer's own output).  Host facts for ``stats()["residual"]``,
+    fixed at construction."""
+    tables = [params[path][name] for path, name in gathered_tables(model)
+              if name in params.get(path, {})]
+    itemsize = tables[0].dtype.itemsize if tables else 4
+    return {"streams": model.streams, "sublayers": 2 * model.depth,
+            "row_bytes": model.residual_numbers_per_row() * itemsize}
+
+
 def beside(params, state):
     """``state`` committed where the committed leaves of ``params`` are, if
     they share one sharding; as it came if none is committed.  A program's
@@ -628,6 +643,10 @@ class SlotEngine:
         # busy rows and resident positions summed over decode iterations
         self._need = decode_need_facts(model, self.params)
         self._need_rows = self._need_positions = 0
+        # what the residual mixes must move a row (the model's answer: 0
+        # for x + f(x)), and the rows each pool program carried
+        self._residual = residual_facts(model, self.params)
+        self._residual_rows = self._fresh_residual_rows()
 
         self._build_programs()
 
@@ -809,6 +828,7 @@ class SlotEngine:
                     np.int32(slot), np.float32(req.temperature), key,
                     req.temperature > 0)
             self._occupy(req, slot, key)
+            self._residual_rows["prefill"] += len(req.prompt)
         self._launched(_Flight("prefill", tok_dev, [slot], [req],
                                req.t_admit, ids))
         return slot
@@ -848,6 +868,7 @@ class SlotEngine:
             self._kv_bytes += per_pos * positions
             self._need_rows += len(rows)
             self._need_positions += positions
+            self._residual_rows["decode"] += len(rows)
             if not np.array_equal(live, self._live[0]):
                 self._live = (live, jax.device_put(live))
             nxt_dev, self.cache, self._moe["decode"], self._slots = \
@@ -1104,6 +1125,7 @@ class SlotEngine:
         self._kv_blocks_read = 0
         self._state_bytes = self._kv_bytes = 0
         self._need_rows = self._need_positions = 0
+        self._residual_rows = self._fresh_residual_rows()
         self._pipeline = self._fresh_pipeline()
         reset_phases(SERVE_PHASES)
         self._loop.reset()
@@ -1120,6 +1142,25 @@ class SlotEngine:
         kinds = lambda: {"decode": 0, "prefill": 0}
         return {"launches": kinds(), "launched_ahead": kinds(),
                 "wasted_rows": 0}
+
+    @staticmethod
+    def _fresh_residual_rows() -> dict:
+        return {"prefill": 0, "decode": 0}
+
+    def _residual_stats(self) -> dict:
+        """``stats()["residual"]``: the model's ``streams`` and
+        ``sublayers`` (:func:`residual_facts`), and by pool program since
+        ``reset_stats()`` the ``rows`` it carried for requests (a prefill's
+        true prompt tokens, a decode step's busy slots) and ``bytes``, what
+        those rows had to move through the residual mixes of all
+        sublayers (read the streams and write the sublayer's input; read
+        them and its output and write them back).  Host arithmetic inside
+        ``prefill.dispatch`` / ``decode.dispatch``; 0 bytes for a model
+        whose residual is ``x + f(x)``."""
+        facts = self._residual
+        return {"streams": facts["streams"], "sublayers": facts["sublayers"],
+                **{kind: {"rows": rows, "bytes": rows * facts["row_bytes"]}
+                   for kind, rows in self._residual_rows.items()}}
 
     def _moe_read(self) -> dict:
         """The routed-row counters per pool program, each stacked over the
@@ -1223,6 +1264,7 @@ class SlotEngine:
         slots, summed over steps: ``state_bytes`` (whole state, read and
         written) and ``kv_bytes`` (the K/V columns held, the new one
         included).  ``"decode_need"``: :meth:`_decode_need_stats`.
+        ``"residual"``: :meth:`_residual_stats`.
         ``"params"``: what :func:`place_params` did at construction;
         ``reset_stats()`` leaves it.  ``"loop"``: the loop thread's clock
         (:meth:`tpu_dist.obs.spans.LoopClock.stats`): every iteration the
@@ -1235,6 +1277,7 @@ class SlotEngine:
             **({"moe": moe} if moe else {}),
             "decode_attn": self._decode_attn_stats(),
             "decode_need": self._decode_need_stats(since),
+            "residual": self._residual_stats(),
             "state": {"state_bytes": int(self._state_bytes),
                       "kv_bytes": int(self._kv_bytes)},
             "pipeline": {k: dict(v) if isinstance(v, dict) else v
