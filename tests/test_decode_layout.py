@@ -369,6 +369,25 @@ def test_latent_decode_kernel_compiles_at_the_cells_shapes(
     assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
 
 
+def _kimi_k2_two_layers(sharding):
+    """The DeepSeek-V3 block at the published widths, two layers (one dense,
+    one that holds 12 of 384 experts), vocabulary cut: ``(model, params,
+    pool, counters)`` as shapes on ``sharding``, 32 slots x 1024."""
+    from tpu_dist.models import KimiK2LM
+    model = KimiK2LM(
+        VOCAB, dim=7168, depth=2, num_heads=64, q_lora_rank=1536,
+        kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128, dense_hidden=18432, num_experts=384, moe_top_k=8,
+        moe_hidden=2048, routed_scaling_factor=2.827, experts_held=12,
+        rope_scaling_factor=32, rope_scaling_beta_fast=1,
+        rope_scaling_mscale_all_dim=1, max_seq_len=MAX_LEN)
+    pool = _shapes(jax.eval_shape(
+        lambda: model.init_slot_cache(SLOTS, MAX_LEN, jnp.bfloat16)),
+        sharding)
+    counters = _shapes(jax.eval_shape(model.init_moe_counters), sharding)
+    return model, _param_shapes(model, sharding), pool, counters
+
+
 def test_kimi_k2_decode_step_on_the_kernel_writes_no_pool_sized_result(
         one_chip, no_compile_cache, mosaic_gmm, mosaic_decode_attention):
     """The decode program of the DeepSeek-V3 block at the published widths
@@ -379,19 +398,7 @@ def test_kimi_k2_decode_step_on_the_kernel_writes_no_pool_sized_result(
     branch selects the new column into the whole pool: a pool-sized fusion
     a layer), the grouped matmuls as Mosaic calls, and no expert tensor
     copied."""
-    from tpu_dist.models import KimiK2LM
-    model = KimiK2LM(
-        VOCAB, dim=7168, depth=2, num_heads=64, q_lora_rank=1536,
-        kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
-        v_head_dim=128, dense_hidden=18432, num_experts=384, moe_top_k=8,
-        moe_hidden=2048, routed_scaling_factor=2.827, experts_held=12,
-        rope_scaling_factor=32, rope_scaling_beta_fast=1,
-        rope_scaling_mscale_all_dim=1, max_seq_len=MAX_LEN)
-    params = _param_shapes(model, one_chip)
-    pool = _shapes(jax.eval_shape(
-        lambda: model.init_slot_cache(SLOTS, MAX_LEN, jnp.bfloat16)),
-        one_chip)
-    counters = _shapes(jax.eval_shape(model.init_moe_counters), one_chip)
+    model, params, pool, counters = _kimi_k2_two_layers(one_chip)
 
     def pool_results():
         """Fusions and copies with a result of the pool's own shape (the
@@ -414,3 +421,46 @@ def test_kimi_k2_decode_step_on_the_kernel_writes_no_pool_sized_result(
     with nn.attention_impl("dense"):
         _, dense = pool_results()
     assert len(dense) >= 2, dense
+
+
+# -- a latent layer's whole-prompt prefill on the flash forward kernel
+#    (ISSUE 39) ----------------------------------------------------------------
+
+@pytest.fixture
+def mosaic_flash(monkeypatch):
+    """``ops/flash_attention.py`` the same (``mosaic_gmm``)."""
+    import sys
+    import tpu_dist.ops.flash_attention  # noqa: F401
+    fa = sys.modules["tpu_dist.ops.flash_attention"]
+    monkeypatch.setattr(fa, "_use_interpret", lambda: False)
+    fa._fwd_call.clear_cache()
+    yield
+    fa._fwd_call.clear_cache()      # leave no Mosaic-lowered trace behind
+
+
+def test_kimi_k2_prefill_on_the_kernel_holds_no_score_tensor(
+        one_chip, no_compile_cache, mosaic_gmm, mosaic_flash):
+    """The prefill program of the same two layers at a 1,024 bucket: on the
+    kernel branch the chip's compiler takes one ``flash_fwd`` call a layer
+    at heads of 192 / 128 and the optimized program produces no array of
+    shape ``[64, 1024, 1024]`` (the dense branch's holds the bfloat16
+    scores, 128 MiB a layer, and 58 MiB of temporaries more: 228.8 against
+    170.8 MiB)."""
+    model, params, pool, counters = _kimi_k2_two_layers(one_chip)
+    scores = re.compile(r"\w+\[64,%d,%d\]" % (MAX_LEN, MAX_LEN))
+    seen = {}
+    for impl in ("flash", "dense"):
+        with nn.attention_impl(impl):
+            compiled = _lower(model, "prefill_into_slot", params, pool,
+                              counters, one_chip).compile()
+        text = compiled.as_text()
+        seen[impl] = (
+            len(re.findall(r"%flash_fwd[.\d]* = [^\n]*"
+                           r"custom_call_target=\"tpu_custom_call\"", text)),
+            sorted(set(scores.findall(text))),
+            compiled.memory_analysis().temp_size_in_bytes)
+    calls, score_shapes, temp = seen["flash"]
+    assert calls == 2 and not score_shapes, (calls, score_shapes)
+    calls, score_shapes, dense_temp = seen["dense"]
+    assert calls == 0 and "bf16[64,1024,1024]" in score_shapes
+    assert dense_temp - temp >= 48 << 20, (dense_temp, temp)

@@ -418,3 +418,112 @@ def test_kernels_execute_the_plan(rng, monkeypatch, case):
                           "masked": 3 * heads * plan["masked"]}
     finally:
         drop_traces()
+
+
+# -- a value width of its own (ISSUE 39) --------------------------------------
+
+def _dense_heads_first(q, k, v, sm_scale):
+    """The dense composition over (H, T, D) operands, causal, in float32."""
+    q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
+    s = jnp.einsum("htd,hsd->hts", q, k) * sm_scale
+    t = s.shape[-1]
+    s = jnp.where(jnp.arange(t)[None, :] <= jnp.arange(t)[:, None], s,
+                  -jnp.inf)
+    return jnp.einsum("hts,hsd->htd", jax.nn.softmax(s, axis=-1), v)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("t", [1024, 1536, 2048])   # one grid step, K's
+@pytest.mark.parametrize("d, dv", [(64, 64), (192, 128), (128, 64)])
+def test_forward_with_a_value_width_of_its_own(rng, d, dv, t, dtype):
+    """The forward kernel takes ``dv`` from v: a latent layer's 192 / 128,
+    a v narrower than a lane tile, and today's ``dv == d``, at an explicit
+    scale that is not ``d ** -0.5``, over one grid step, K's padding (1536
+    in blocks of 1024) and several tiles; heads first, the kernel's own
+    order, and through the (T, H, D) wrapper."""
+    scale = 0.1447
+    q = jnp.asarray(rng.standard_normal((2, t, d)), dtype)
+    k = jnp.asarray(rng.standard_normal((2, t, d)), dtype)
+    v = jnp.asarray(rng.standard_normal((2, t, dv)), dtype)
+    out = fa.flash_attention_heads_first(q, k, v, causal=True, sm_scale=scale)
+    assert out.shape == (2, t, dv) and out.dtype == dtype
+    tol = (dict(atol=2e-5, rtol=2e-5) if dtype == jnp.float32
+           else dict(atol=3e-2, rtol=3e-2))
+    np.testing.assert_allclose(out.astype(np.float32),
+                               _dense_heads_first(q, k, v, scale), **tol)
+    swap = lambda a: jnp.swapaxes(a, 0, 1)
+    np.testing.assert_array_equal(
+        swap(flash_attention(swap(q), swap(k), swap(v), causal=True,
+                             sm_scale=scale)), out)
+
+
+def test_tile_plan_at_the_latent_buckets():
+    """The sub-tiles a head executes at the two serving buckets, which the
+    value width does not enter: 136 of 256 for 128.03 needed at 4,096 (six
+    whole grid tiles of 16 and four diagonal ones of 10), 36 for 32.02 at
+    2,048."""
+    for t, executed, needed in ((4096, 136, 128.03), (2048, 36, 32.02)):
+        plan = fa.tile_plan(t, t, True)
+        assert plan["executed"] == executed and plan["sub_q"] == 256
+        assert plan["needed"] == pytest.approx(needed, abs=5e-3)
+
+
+def test_differentiating_a_value_width_of_its_own_says_why_not(rng):
+    q = jnp.asarray(rng.standard_normal((1, 128, 1, 192)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((1, 128, 1, 128)), jnp.float32)
+    loss = lambda q, k, v: flash_attention(q, k, v, causal=True).sum()
+    with pytest.raises(NotImplementedError, match="forward kernel only"):
+        jax.grad(loss, (0, 1, 2))(q, q, v)
+    # the same call forward is fine, and dv == d still differentiates
+    assert flash_attention(q, q, v, causal=True).shape == (1, 128, 1, 128)
+    jax.grad(loss, (0, 1, 2))(q, q, q)
+    with pytest.raises(ValueError, match="v's head size alone may differ"):
+        flash_attention(q, v, v)
+
+
+def _mosaic_kernel(monkeypatch, *operands):
+    """The Mosaic module ``_fwd_call`` lowers to for a TPU (no chip, no
+    compile), as text without source locations."""
+    import jax._src.tpu_custom_call as tcc
+    seen, inner = [], tcc._lower_mosaic_module_to_asm
+    monkeypatch.setattr(fa, "_use_interpret", lambda: False)
+    monkeypatch.setattr(
+        tcc, "_lower_mosaic_module_to_asm", lambda module, **kw: (
+            seen.append(module.operation.get_asm(enable_debug_info=False)),
+            inner(module, **kw))[1])
+    fa._fwd_call.trace(*operands, True, 0.125, 1024, 1024).lower(
+        lowering_platforms=("tpu",))
+    (text,) = seen
+    return text
+
+
+def _vmem(shape, dtype):
+    return (f"memref<{'x'.join(map(str, shape))}x{dtype}, "
+            f"#tpu.memory_space<vmem>>")
+
+
+def test_the_training_call_lowers_to_the_blocks_it_always_had(monkeypatch):
+    """``dv == d`` at the training cells' shape (128 heads of 64 over 1,024
+    positions): q, k, v and the output in blocks of (1, 1024, 64), the
+    statistics as rows, a (1024, 64) accumulator, one grid step a head.
+    (The module's text, locations aside, is the parent commit's character
+    for character: PERF.md section 6, PR 39.)  And at 192 / 128 only V's
+    block, the accumulator and the output take the value width."""
+    sds = lambda bh, t, w: jax.ShapeDtypeStruct((bh, t, w), jnp.bfloat16)
+    text = _mosaic_kernel(monkeypatch, *[sds(128, 1024, 64)] * 3)
+    main = next(l for l in text.splitlines() if "func.func @main" in l)
+    block, row = _vmem((1, 1024, 64), "bf16"), _vmem((1, 1024), "f32")
+    assert main.count(block) == 4
+    assert main.count(row) == 2 and _vmem((1, 1, 1024), "f32") in main
+    assert main.count(_vmem((1024, 64), "f32")) == 1
+    assert "iteration_bounds = array<i64: 128, 1, 1>" in main
+    fa._fwd_call.clear_cache()
+    text = _mosaic_kernel(monkeypatch, sds(32, 4096, 192), sds(32, 4096, 192),
+                          sds(32, 4096, 128))
+    main = next(l for l in text.splitlines() if "func.func @main" in l)
+    assert main.count(_vmem((1, 1024, 192), "bf16")) == 2
+    assert main.count(_vmem((1, 1024, 128), "bf16")) == 2
+    assert main.count(_vmem((1024, 128), "f32")) == 1
+    assert "iteration_bounds = array<i64: 32, 4, 4>" in main
+    fa._fwd_call.clear_cache()      # leave no TPU-lowered trace behind

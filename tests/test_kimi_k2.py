@@ -257,6 +257,143 @@ def test_a_whole_prompt_rebuilds_keys_for_its_bucket_alone(served):
     assert "16x128x" not in text
 
 
+# -- a whole prompt's attention through the flash forward kernel (ISSUE 39) ----
+
+#: heads of 192 / 128 as published, everything else reduced; a 1,024 bucket
+WIDE = dict(CFG, qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+            num_attention_heads=2, num_hidden_layers=2,
+            max_position_embeddings=1024)
+
+
+@pytest.fixture(scope="module")
+def wide():
+    """The model at WIDE, a prompt of 900 in a 1,024 bucket, the float32
+    reference's logits over it, and ``prefill_rows`` under either
+    ``attention_impl`` (the kernel interpreted)."""
+    model = _model(WIDE)
+    params = _perturbed(model.init(jax.random.key(0)))
+    prompt = np.asarray(jax.random.randint(jax.random.key(1), (1024,), 0,
+                                           211), np.int32)
+    want = np.asarray(REF.forward(WIDE, REF.stack_params(WIDE, params),
+                                  prompt[None, :900])[0])
+    got = {}
+    for impl in ("dense", "flash"):
+        with nn.attention_impl(impl):
+            logits, rows, _ = jax.jit(lambda p, x: model.prefill_rows(
+                p, x, 900, 1024))(params, prompt)
+        got[impl] = np.asarray(logits), jax.tree.map(np.asarray, rows)
+    return model, params, prompt, want, got
+
+
+def test_a_whole_prompt_on_the_kernel_is_the_dense_branchs(wide):
+    _, _, _, want, got = wide
+    np.testing.assert_allclose(got["flash"][0], want[899], atol=ATOL)
+    np.testing.assert_allclose(got["flash"][0], got["dense"][0], atol=ATOL)
+    for path, entry in got["dense"][1].items():
+        np.testing.assert_allclose(got["flash"][1][path]["latent"],
+                                   entry["latent"], atol=ATOL)
+
+
+def test_when_a_latent_layers_prefill_takes_the_kernel(wide, monkeypatch):
+    model, params, prompt, _, _ = wide
+    attn = model.block0.attn
+    assert not attn.takes_prefill_kernel(1024, 0)      # the CPU's default
+    with nn.attention_impl("flash"):
+        assert attn.takes_prefill_kernel(1024, 0)
+        assert attn.takes_prefill_kernel(4096, 0)
+        assert not attn.takes_prefill_kernel(512, 0)   # a short bucket
+        # a prefix hit's suffix: the position is traced, keys outnumber
+        # queries
+        assert not attn.takes_prefill_kernel(1024, jnp.int32(0))
+        assert not attn.takes_prefill_kernel(1024, 16)
+        assert not _model().block0.attn.takes_prefill_kernel(1024, 0)  # 16/8
+    with nn.attention_impl("dense"):
+        assert not attn.takes_prefill_kernel(1024, 0)
+    # which branch a call takes, by what it shows
+    taken = []
+    for name in ("_expanded", "_expanded_flash"):
+        inner = getattr(nn.MultiheadLatentAttention, name)
+        monkeypatch.setattr(
+            nn.MultiheadLatentAttention, name,
+            lambda self, *a, _n=name, _f=inner: (taken.append(_n),
+                                                 _f(self, *a))[1])
+    with nn.attention_impl("flash"):
+        jax.eval_shape(lambda: model.apply(params, prompt[None]))
+        assert set(taken) == {"_expanded"}      # the plain forward: no cache
+        taken.clear()
+        rows = model.init_slot_cache(1, 2048)
+        jax.eval_shape(lambda: model.prefill_rows(
+            params, prompt, 1040, 2048, prefix_rows=rows, prefix_len=16))
+        assert set(taken) == {"_expanded"}      # a prefix hit's suffix
+        taken.clear()
+        jax.eval_shape(lambda: model.prefill_rows(params, prompt, 900, 1024))
+        assert set(taken) == {"_expanded_flash"}
+        taken.clear()
+        jax.eval_shape(lambda: model.prefill_rows(params, prompt[:512], 500,
+                                                  1024))
+        assert set(taken) == {"_expanded"}
+
+
+def _squares(jaxpr, t) -> list:
+    """Shapes of every array an equation of ``jaxpr`` produces, sub-programs
+    (a jitted call, a kernel's body) included, whose last two axes are both
+    ``t`` long or longer."""
+    found = []
+    for eqn in jaxpr.eqns:
+        found += [v.aval.shape for v in eqn.outvars
+                  if len(getattr(v.aval, "shape", ())) >= 2
+                  and min(v.aval.shape[-2:]) >= t]
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _squares(sub, t)
+    return found
+
+
+def test_the_kernel_branchs_program_holds_no_score_tensor(wide):
+    """Not only absent from one trace: no equation of the prefill program
+    produces a ``t x t`` array on the kernel (neither scores nor mask); the
+    dense branch's produces ``heads x t x t``."""
+    model, params, prompt, _, _ = wide
+    seen = {}
+    for impl in ("dense", "flash"):
+        with nn.attention_impl(impl):
+            seen[impl] = _squares(jax.make_jaxpr(
+                lambda p, x: model.prefill_rows(p, x, 900, 1024))(
+                    params, prompt).jaxpr, 1024)
+    assert (1, WIDE["num_attention_heads"], 1024, 1024) in seen["dense"]
+    assert seen["flash"] == []
+
+
+def test_the_engine_serves_the_same_tokens_on_either_branch(wide):
+    model, params, prompt, want, _ = wide
+    served = {}
+    for impl in ("dense", "flash"):
+        with nn.attention_impl(impl):
+            eng = serve.SlotEngine(model, params, num_slots=2, max_len=1024,
+                                   min_bucket=1024)
+            got = []
+            eng.admit(serve.Request(prompt[:900], max_new_tokens=3,
+                                    on_token=lambda r, t: got.append(t)))
+            while eng.active.any():
+                eng.step()
+            served[impl] = got, eng.stats()["prefill_attn"]
+            eng.reset_stats()
+            assert set(eng.stats()["prefill_attn"].values()) == {0}
+    assert served["flash"][0] == served["dense"][0]
+    assert served["flash"][0][0] == int(want[899].argmax())
+    assert len(served["flash"][0]) == 3
+    # 2 heads x 2 layers, 900 true tokens in a bucket of 1,024; what the
+    # kernel executes is tile_plan's own count (float32: grid tiles of 512)
+    from tpu_dist.ops.flash_attention import tile_plan
+    plan = tile_plan(1024, 1024, True, dtype=jnp.float32)
+    needed = 4 * (900 * 901 // 2)
+    assert served["flash"][1] == {
+        "prefills": 1, "kernel_prefills": 1, "pairs_needed": needed,
+        "pairs_executed": 4 * plan["executed"] * 256 * 256}
+    assert served["dense"][1] == {
+        "prefills": 1, "kernel_prefills": 0, "pairs_needed": needed,
+        "pairs_executed": 4 * 1024 * 1024}
+
+
 # -- the decode kernel against the dense branch -------------------------------
 
 TMAX = 1024

@@ -677,6 +677,31 @@ class TestServingLoopClock:
         assert a.stats()["loop"]["iterations"]["decode"] == 3
         assert b.stats()["loop"]["iterations"]["decode"] == 6
 
+    def test_prefill_attn_counts_reset_and_ride_the_wire(self, lm):
+        """``stats()["prefill_attn"]`` for a model without a latent layer:
+        its prefills counted, none on the kernel, no pairs (the counts of a
+        latent model on either branch: tests/test_kimi_k2.py)."""
+        model, params = lm
+        engine = serve.SlotEngine(model, params, num_slots=4)
+        sched = serve.Scheduler(engine, batch_window=0.002)
+        fe = serve.Frontend(sched, port=0)
+        cli = serve.ServeClient("127.0.0.1", fe.port, connect_retry=10)
+        zero = {"prefills": 0, "kernel_prefills": 0, "pairs_needed": 0,
+                "pairs_executed": 0}
+        try:
+            assert cli.stats()["prefill_attn"] == zero
+            for n in (6, 11, 30):
+                cli.generate(list(range(1, n)), max_new_tokens=2,
+                             timeout=120.0)
+            assert cli.stats()["prefill_attn"] == dict(zero, prefills=3)
+            assert engine.stats()["prefill_attn"] == dict(zero, prefills=3)
+            engine.reset_stats()
+            assert cli.stats()["prefill_attn"] == zero
+        finally:
+            cli.close()
+            fe.close()
+            sched.close()
+
     def test_the_wire_stats_frame_carries_the_loop_as_json(self, lm):
         import json
         model, params = lm

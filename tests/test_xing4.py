@@ -547,6 +547,106 @@ def test_a_plain_residual_counts_rows_and_no_bytes():
     assert got["prefill"]["bytes"] == got["decode"]["bytes"] == 0
 
 
+# -- a whole prompt's attention through the flash forward kernel (ISSUE 39) ------
+
+#: heads of 192 / 128 as published inside the four streams; a 1,024 bucket
+WIDE = dict(CFG, qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+            num_attention_heads=2, max_position_embeddings=1024)
+
+
+@pytest.fixture(scope="module")
+def wide():
+    """The model at WIDE, a prompt of 900 in a 1,024 bucket, the float32
+    reference's logits at its last token, and ``prefill_rows`` under either
+    ``attention_impl`` (the kernel interpreted)."""
+    model = _model(WIDE)
+    params = _perturbed(model.init(jax.random.key(0)))
+    prompt = np.asarray(jax.random.randint(jax.random.key(1), (1024,), 0,
+                                           211), np.int32)
+    want = np.asarray(jax.jit(lambda p, t: REF.forward(
+        WIDE, REF.stack_params(WIDE, p), t))(params, prompt[None, :900])[0])
+    # a function of its own a branch: a trace is cached by the function
+    # traced, and knows nothing of the attention_impl it was made under
+    prefill = lambda: lambda p, x: model.prefill_rows(p, x, 900, 1024)
+    got = {}
+    for impl in ("dense", "flash"):
+        with nn.attention_impl(impl):
+            logits, rows, _ = jax.jit(prefill())(params, prompt)
+        got[impl] = np.asarray(logits), jax.tree.map(np.asarray, rows)
+    return model, params, prompt, want, got, prefill
+
+
+def test_a_whole_prompt_on_the_kernel_is_the_dense_branchs(wide):
+    _, _, _, want, got, _ = wide
+    np.testing.assert_allclose(got["flash"][0], want[899], atol=ATOL)
+    np.testing.assert_allclose(got["flash"][0], got["dense"][0], atol=ATOL)
+    for path, entry in got["dense"][1].items():
+        assert set(entry) == {"latent"}
+        np.testing.assert_allclose(got["flash"][1][path]["latent"],
+                                   entry["latent"], atol=ATOL)
+
+
+def test_which_prefill_takes_the_kernel_and_what_its_program_holds(wide):
+    """Decided by the call (a whole prompt from 0, 1,024 or more, heads of
+    whole 64s, ``attention_impl``), whatever residual surrounds the layer;
+    and on the kernel no equation of the prefill program, its sub-programs
+    included, produces a ``t x t`` array: the scores are gone from the
+    program, not only from one trace."""
+    model, params, prompt, _, _, prefill = wide
+    attn = model.block1.attn
+    assert not attn.takes_prefill_kernel(1024, 0)       # the CPU's default
+    assert model.prefill_attention_facts(1024)["kernel"] is False
+    with nn.attention_impl("flash"):
+        assert attn.takes_prefill_kernel(1024, 0)
+        assert not attn.takes_prefill_kernel(512, 0)
+        assert not attn.takes_prefill_kernel(1024, jnp.int32(0))
+        assert not _model().block1.attn.takes_prefill_kernel(1024, 0)
+        assert model.prefill_attention_facts(1024) == {
+            "kernel": True, "heads": 4, "pairs_executed": 4 * 10 * 256 * 256}
+        assert model.prefill_attention_facts(512) == {
+            "kernel": False, "heads": 4, "pairs_executed": 4 * 512 * 512}
+
+    def squares(jaxpr):
+        """Shapes of every array an equation produces, sub-programs
+        included, whose last two axes are both a bucket long."""
+        found = []
+        for eqn in jaxpr.eqns:
+            found += [v.aval.shape for v in eqn.outvars
+                      if len(getattr(v.aval, "shape", ())) >= 2
+                      and min(v.aval.shape[-2:]) >= 1024]
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                found += squares(sub)
+        return found
+
+    seen = {}
+    for impl in ("dense", "flash"):
+        with nn.attention_impl(impl):
+            seen[impl] = squares(jax.make_jaxpr(prefill())(params,
+                                                           prompt).jaxpr)
+    assert (1, 2, 1024, 1024) in seen["dense"]
+    assert seen["flash"] == []
+
+
+def test_the_engine_serves_the_same_tokens_on_either_branch(wide):
+    model, params, prompt, want, _, _ = wide
+    served = {}
+    for impl in ("dense", "flash"):
+        with nn.attention_impl(impl):
+            eng = serve.SlotEngine(model, params, num_slots=2, max_len=1024,
+                                   min_bucket=1024)
+            got = []
+            eng.admit(serve.Request(prompt[:900], max_new_tokens=3,
+                                    on_token=lambda r, t: got.append(t)))
+            while eng.active.any():
+                eng.step()
+            served[impl] = got, eng.stats()["prefill_attn"]
+    assert served["flash"][0] == served["dense"][0]
+    assert served["flash"][0][0] == int(want[899].argmax())
+    assert len(served["flash"][0]) == 3
+    assert served["flash"][1]["kernel_prefills"] == 1
+    assert served["dense"][1]["kernel_prefills"] == 0
+
+
 # -- what is cached and moved: the latent, never a stream ------------------------
 
 @pytest.fixture(scope="module", params=["kimik2", "xing4"])

@@ -319,6 +319,25 @@ class TransformerLM(nn.Module):
                    for mixer in self._mixers()
                    if nn.cache.pool_leaf(cache[mixer._path]) is not None)
 
+    def prefill_attention_facts(self, bucket: int, dtype=jnp.bfloat16) -> dict:
+        """What the attention of a whole-prompt prefill of ``bucket``
+        positions is built on, in the layers that say (a latent layer's
+        ``takes_prefill_kernel`` / ``prefill_pairs_executed``; asked under
+        the ``attention_impl`` the program is traced under): ``kernel``,
+        whether every such layer takes the causal flash forward kernel;
+        ``heads``, query heads summed over those layers; ``pairs_executed``,
+        the (query, key) pairs they execute, all heads.  A model with no
+        such layer answers ``False, 0, 0``.  Host facts for
+        ``SlotEngine.stats()["prefill_attn"]``."""
+        layers = [m for m in self._mixers()
+                  if hasattr(m, "takes_prefill_kernel")]
+        return {"kernel": bool(layers) and all(
+                    m.takes_prefill_kernel(bucket, 0) for m in layers),
+                "heads": sum(m.num_heads for m in layers),
+                "pairs_executed": sum(
+                    m.num_heads * m.prefill_pairs_executed(bucket, dtype)
+                    for m in layers)}
+
     def init_moe_counters(self):
         """Routed-row counters for serving a model with expert layers, one
         entry per :class:`~tpu_dist.nn.MoELayer` keyed by its path (empty

@@ -31,7 +31,16 @@ Two paths compute the same function of that latent and must agree
 
 Which path a call takes follows from the call: a vector ``index`` (one
 write position a slot: ``TransformerLM.decode_step``) takes the absorbed
-path, anything else the expanded one.  The absorbed path's attention over
+path, anything else the expanded one.  The expanded path of a WHOLE PROMPT
+from position 0 (``index`` the Python int 0: ``TransformerLM.prefill_rows``)
+computes its attention with the causal flash forward kernel where
+:meth:`takes_prefill_kernel` says so (tpu_dist.ops.flash_attention, heads
+of ``nope + rope`` for q and k and ``v`` for the values; operands built
+heads first, ``out_proj`` contracting the result where it lies): no ``H x t
+x t`` scores pass through HBM.  The plain differentiable forward, a
+prefix-cache hit's suffix, a multi-token append, every CPU run and buckets
+under 1,024 stay on two einsums around a materialised softmax
+(:meth:`_expanded`).  The absorbed path's attention over
 a slot pool is one Pallas call where :meth:`takes_slot_kernel` says so
 (tpu_dist.ops.decode_attention.latent_decode_attention: it reads only each
 slot's resident blocks and writes only the slab of the new column), and a
@@ -47,7 +56,8 @@ import jax.numpy as jnp
 from . import cache as kvcache
 from . import functional as F
 from . import init as I
-from .attention import _write_columns, rotary_embed, slot_kernel_wanted
+from .attention import (_FLASH_MIN_SEQ, _write_columns, rotary_embed,
+                        slot_kernel_wanted)
 from .module import Module
 
 __all__ = ["MultiheadLatentAttention"]
@@ -138,6 +148,44 @@ class MultiheadLatentAttention(Module):
         return (slot_kernel_wanted() and decode_attention_ok(pool)
                 and self.kv_lora_rank % sublane_tile(pool.dtype) == 0)
 
+    def takes_prefill_kernel(self, t, index) -> bool:
+        """Whether a call of ``t`` new positions at write position
+        ``index`` into a cache computes its EXPANDED attention with the
+        causal flash forward kernel (tpu_dist.ops.flash_attention: scores
+        in float32 inside the kernel, only the sub-tiles at or below the
+        diagonal executed, nothing of ``H x t x t`` in HBM) or with two
+        einsums around a materialised softmax.  Chosen, as
+        :meth:`takes_slot_kernel` chooses, by what the call shows: a whole
+        prompt from position 0 known while tracing (``index`` the Python
+        int 0: ``TransformerLM.prefill_rows``), so that the mask is the
+        plain causal one over the call's own ``t`` columns; ``t`` at least
+        ``_FLASH_MIN_SEQ`` (below it the scores are small and XLA's fused
+        dense path is the faster: the constant's own note); the q / k and
+        the v head sizes whole multiples of the kernel's ``_D_ALIGN``; and
+        :func:`slot_kernel_wanted` (a TPU backend; ``attention_impl``
+        overrides).  Everything else stays dense: the plain differentiable
+        forward (no cache; the kernel's backward takes one head size), a
+        prefix-cache hit's suffix (a traced ``index``, keys longer than
+        queries), a multi-token append, every CPU run, short buckets."""
+        from ..ops.flash_attention import _D_ALIGN
+        return (isinstance(index, int) and index == 0
+                and t >= _FLASH_MIN_SEQ and slot_kernel_wanted()
+                and (self.nope + self.rope) % _D_ALIGN == 0
+                and self.v_dim % _D_ALIGN == 0)
+
+    def prefill_pairs_executed(self, t, dtype=jnp.bfloat16) -> int:
+        """(query, key) pairs ONE head of this layer executes for a whole
+        prompt of ``t`` positions from position 0, of the ``t (t + 1) / 2``
+        the mathematics needs: the kernel's executed sub-tiles
+        (``tile_plan``, which counts by the kernels' own arithmetic) where
+        :meth:`takes_prefill_kernel`, the whole square on the dense
+        branch."""
+        if not self.takes_prefill_kernel(t, 0):
+            return t * t
+        from ..ops.flash_attention import tile_plan
+        plan = tile_plan(t, t, True, dtype=dtype)
+        return plan["executed"] * plan["sub_q"] * plan["sub_k"]
+
     # -- the two paths over a latent ------------------------------------------
 
     def _w_kvb(self, p, dtype):
@@ -168,6 +216,32 @@ class MultiheadLatentAttention(Module):
         with jax.named_scope("attend"):
             w = self._softmax(jnp.einsum("bthd,bshd->bhts", q, k), mask)
             return jnp.einsum("bhts,bshd->bthd", w, kv[..., self.nope:])
+
+    def _expanded_flash(self, p, q_nope, q_pe, latent):
+        """:meth:`_expanded` for a whole prompt from position 0
+        (:meth:`takes_prefill_kernel`): the same keys and values, causal,
+        through the flash forward kernel.  Its operands are PRODUCED heads
+        first, the kernel's own order: ``k_nope`` and ``v`` each by an
+        einsum whose result is ``(B, H, S, d)``, the roped shared key
+        broadcast into place once, the queries turned inside the fusion
+        that ropes and joins them.  Returns ``(B, H, t, v)``, heads first
+        too: :meth:`forward`'s ``out_proj`` contracts ``(h, d)`` where they
+        lie."""
+        from ..ops.flash_attention import flash_attention_heads_first
+        r = self.kv_lora_rank
+        dtype = q_nope.dtype
+        with jax.named_scope("expand"):
+            lat, w_kvb = latent[:, :r].astype(dtype), self._w_kvb(p, dtype)
+            k_nope = jnp.einsum("bcs,chd->bhsd", lat, w_kvb[..., :self.nope])
+            v = jnp.einsum("bcs,chd->bhsd", lat, w_kvb[..., self.nope:])
+            k_pe = jnp.broadcast_to(
+                jnp.swapaxes(latent[:, r:], 1, 2).astype(dtype)[:, None],
+                k_nope.shape[:3] + (self.rope,))
+            k = jnp.concatenate([k_nope, k_pe], axis=-1)
+            q = jnp.swapaxes(jnp.concatenate([q_nope, q_pe], axis=-1), 1, 2)
+        with jax.named_scope("attend"):
+            return flash_attention_heads_first(
+                q, k, v, causal=True, sm_scale=self.softmax_scale)
 
     def _absorbed(self, p, q_nope, q_pe, attend):
         """The same function with ``W_UK`` folded into the query and
@@ -227,6 +301,7 @@ class MultiheadLatentAttention(Module):
                                 inv_freq=self.rope_inv_freq)[..., 0, :]
             # (B, t, C) -> (B, C, t): the stored order, time last
             new = jnp.swapaxes(jnp.concatenate([c_kv, k_pe], axis=-1), 1, 2)
+        heads_first = False
         if st is None:
             out = self._expanded(p, q_nope, q_pe, new,
                                  steps[None, :] <= steps[:, None])
@@ -258,6 +333,18 @@ class MultiheadLatentAttention(Module):
                 else:
                     pool = jax.lax.dynamic_update_slice(pool, new,
                                                         (0, 0, index))
+                ctx.put_state(self._path, dict(st, latent=pool,
+                                               index=index + t))
+            if vector:
+                out = self._absorbed(p, q_nope, q_pe, lambda q:
+                                     self._attend_latent(q, latent, mask))
+            elif self.takes_prefill_kernel(t, index):
+                # the call's own columns ARE the visible ones, in the
+                # stored type: the causal kernel over them, heads first
+                heads_first = True
+                out = self._expanded_flash(p, q_nope, q_pe, new)
+            else:
+                with jax.named_scope("cache_update"):
                     # the columns this call can see: all the rows hold,
                     # unless the position is known while tracing (a whole
                     # prompt from 0: TransformerLM.prefill_rows), when the
@@ -266,14 +353,11 @@ class MultiheadLatentAttention(Module):
                             else tmax)
                     latent = kvcache.time_slice(pool, 0, seen)
                     mask = jnp.arange(seen)[None, :] <= pos[:, None]
-                ctx.put_state(self._path, dict(st, latent=pool,
-                                               index=index + t))
-            if vector:
-                out = self._absorbed(p, q_nope, q_pe, lambda q:
-                                     self._attend_latent(q, latent, mask))
-            else:
                 out = self._expanded(p, q_nope, q_pe, latent, mask)
         with jax.named_scope("out_proj"):
+            if heads_first:     # (B, H, t, v): contract (h, d) where they lie
+                return jnp.einsum("bhtd,hdo->bto", out,
+                                  p["out_weight"].reshape(h, self.v_dim, -1))
             return F.linear(out.reshape(b, t, h * self.v_dim),
                             p["out_weight"])
 

@@ -50,6 +50,17 @@ sweep, one accumulating dK/dV over the Q sweep (grid transposed so the
 accumulators stay resident).  Residuals are just (q, k, v, o, lse): no
 (Tq, Tk) tensor is ever materialized, forward or backward.
 
+The FORWARD kernel takes a value width of its own: q and k share one head
+size D, v may have another (a latent layer's expanded heads are 192 for q
+and k, 128 for v), and only V's block, the accumulator and the output are
+Dv wide; the sub-tiles, the statistics and ``tile_plan`` do not see it, and
+with Dv == D the call lowers to the program it always was.  The two
+backward kernels take ONE head size: differentiating a call with Dv != D
+raises ``NotImplementedError``.  Its caller is a latent layer's
+whole-prompt prefill (tpu_dist.nn.mla, through
+:func:`flash_attention_heads_first`, which takes and returns the kernel's
+own (..., H, T, D) order).
+
 Grid tiles entirely above the diagonal match no kind and run nothing (the
 grid still sweeps them).  Runs on TPU via Mosaic; everywhere else (CPU
 tests) through ``interpret=True`` — same kernel, same numerics (tests
@@ -71,7 +82,8 @@ import jax.numpy as jnp
 from ._pallas import (ceil_to as _ceil_to, out_struct as _out_struct,
                       use_interpret as _use_interpret)
 
-__all__ = ["flash_attention", "flash_attention_with_lse", "tile_plan"]
+__all__ = ["flash_attention", "flash_attention_with_lse",
+           "flash_attention_heads_first", "tile_plan"]
 
 _LANE = 128
 _D_ALIGN = 64  # head_dim alignment: 64 halves K/V DMA for d=64 vs padding to 128
@@ -375,17 +387,21 @@ def _make_fwd_kernel(sm_scale, tk, block_q, block_k, causal, nq, nk):
 # set-up on every start
 @functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
 def _fwd_call(q, k, v, causal, sm_scale, block_q, block_k):
-    """q: (BH, Tq, D); k, v: (BH, Tk, D) -> (o, lse) with lse (BH, Tq, 1)."""
+    """q: (BH, Tq, D); k: (BH, Tk, D); v: (BH, Tk, Dv) -> (o, lse) with o
+    (BH, Tq, Dv) and lse (BH, Tq, 1).  The value width is v's own: only V's
+    block, the accumulator and the output are as wide as Dv, and where
+    Dv == D the call lowers to the program it always was."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, tq, d = q.shape
-    tk = k.shape[1]
+    tk, dv = k.shape[1], v.shape[2]
     block_q, block_k = _clamp_blocks(q.dtype, tq, tk, block_q, block_k)
     tqp, tkp, dp = _ceil_to(tq, block_q), _ceil_to(tk, block_k), _ceil_to(d, _D_ALIGN)
+    dvp = _ceil_to(dv, _D_ALIGN)
     qp = jnp.pad(q, ((0, 0), (0, tqp - tq), (0, dp - d)))
     kp = jnp.pad(k, ((0, 0), (0, tkp - tk), (0, dp - d)))
-    vp = jnp.pad(v, ((0, 0), (0, tkp - tk), (0, dp - d)))
+    vp = jnp.pad(v, ((0, 0), (0, tkp - tk), (0, dvp - dv)))
     nq, nk = tqp // block_q, tkp // block_k
     o, lse = pl.pallas_call(
         _make_fwd_kernel(sm_scale, tk, block_q, block_k, causal, nq, nk),
@@ -395,28 +411,28 @@ def _fwd_call(q, k, v, causal, sm_scale, block_q, block_k):
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, block_k, dp), lambda b, i, j: (b, j, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, dp), lambda b, i, j: (b, j, 0),
+            pl.BlockSpec((1, block_k, dvp), lambda b, i, j: (b, j, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, dp), lambda b, i, j: (b, i, 0),
+            pl.BlockSpec((1, block_q, dvp), lambda b, i, j: (b, i, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i),
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
-            _out_struct((bh, tqp, dp), q.dtype, qp, kp, vp),
+            _out_struct((bh, tqp, dvp), q.dtype, qp, kp, vp),
             _out_struct((bh, 1, tqp), jnp.float32, qp, kp, vp),
         ],
         scratch_shapes=[
             pltpu.VMEM((1, block_q), jnp.float32),       # running max m
             pltpu.VMEM((1, block_q), jnp.float32),       # running sum l
-            pltpu.VMEM((block_q, dp), jnp.float32),      # output accumulator
+            pltpu.VMEM((block_q, dvp), jnp.float32),     # output accumulator
         ],
         interpret=_use_interpret(),
         name="flash_fwd",
     )(qp, kp, vp)
-    return o[:, :tq, :d], lse[:, 0, :tq, None]
+    return o[:, :tq, :dv], lse[:, 0, :tq, None]
 
 
 # ---------------------------------------------------------------------------
@@ -584,6 +600,13 @@ def _flash_lse(q, k, v, causal, sm_scale, block_q, block_k):
 
 
 def _flash_lse_fwd(q, k, v, causal, sm_scale, block_q, block_k):
+    if v.shape[-1] != q.shape[-1]:
+        raise NotImplementedError(
+            f"flash attention with a value width of its own (q/k heads of "
+            f"{q.shape[-1]}, v heads of {v.shape[-1]}) has a forward kernel "
+            f"only: the two backward kernels take one head size.  "
+            f"Differentiate the dense composition (impl='dense'), or pad v "
+            f"to the q/k width")
     o, lse = _fwd_call(q, k, v, causal, sm_scale, block_q, block_k)
     return (o, lse), (q, k, v, o, lse)
 
@@ -598,11 +621,39 @@ def _flash_lse_bwd(causal, sm_scale, block_q, block_k, res, cts):
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 
 
+def _checked(q, k, v, causal, sm_scale, t_axis):
+    """The wrappers' shared checks: ``(h, tq, tk, d, dv, causal, sm_scale)``
+    of operands whose time axis is ``t_axis`` (-3: ``(..., T, H, D)``; -2:
+    ``(..., H, T, D)``)."""
+    if q.ndim < 3:
+        order = "(..., T, H, D)" if t_axis == -3 else "(..., H, T, D)"
+        raise ValueError(f"expected {order}, got {q.shape}")
+    h_axis = -5 - t_axis
+    h, tq, d = q.shape[h_axis], q.shape[t_axis], q.shape[-1]
+    tk, dv = k.shape[t_axis], v.shape[-1]
+    if not (q.shape[:-3] == k.shape[:-3] == v.shape[:-3]
+            and k.shape[h_axis] == v.shape[h_axis] == h
+            and k.shape[-1] == d and v.shape[t_axis] == tk):
+        # no numpy-broadcast batch semantics here: the (B*H, T, D) flatten
+        # would silently misalign batches — use impl='dense' for shared KV
+        raise ValueError(
+            f"flash_attention needs identical batch/head dims for q, k, v "
+            f"(v's head size alone may differ); "
+            f"got q={q.shape}, k={k.shape}, v={v.shape}")
+    if sm_scale is None:
+        sm_scale = 1.0 / math.sqrt(d)
+    if not isinstance(causal, str):
+        # normalize truthy values (np.bool_, 1) to the literal bool the
+        # kernels' three-valued dispatch (`causal is True`) relies on
+        causal = bool(causal)
+    return h, tq, tk, d, dv, causal, float(sm_scale)
+
+
 def flash_attention_with_lse(q, k, v, causal: bool = False, sm_scale=None,
                              block_q: int = 1024, block_k: int = 1024):
     """Flash attention returning ``(out, lse)``.
 
-    ``out``: (..., Tq, H, D) like :func:`flash_attention`; ``lse``:
+    ``out``: (..., Tq, H, Dv) like :func:`flash_attention`; ``lse``:
     (..., Tq, H) float32 per-row logsumexp of the scaled scores.  Partial
     results ``(out_a, lse_a), (out_b, lse_b)`` over disjoint KV blocks merge
     exactly (the blockwise-attention identity used by
@@ -621,40 +672,50 @@ def flash_attention_with_lse(q, k, v, causal: bool = False, sm_scale=None,
     of the others) execute every sub-tile that holds a real key, and mask
     only where K's padding begins.  Grid steps and sub-tiles: the module's
     docstring and :func:`tile_plan`.
+
+    ``v``'s head size may differ from ``q``'s and ``k``'s (a latent layer's
+    192 / 128): the FORWARD kernel takes the value width from ``v``; the
+    backward kernels do not, and differentiating such a call raises
+    ``NotImplementedError``.
     """
-    if q.ndim < 3:
-        raise ValueError(f"expected (..., T, H, D), got {q.shape}")
-    *lead, tq, h, d = q.shape
-    tk = k.shape[-3]
-    if not (q.shape[:-3] == k.shape[:-3] == v.shape[:-3]
-            and k.shape[-2:] == v.shape[-2:] == (h, d)
-            and v.shape[-3] == tk):
-        # no numpy-broadcast batch semantics here: the (B*H, T, D) flatten
-        # would silently misalign batches — use impl='dense' for shared KV
-        raise ValueError(
-            f"flash_attention needs identical batch/head dims for q, k, v; "
-            f"got q={q.shape}, k={k.shape}, v={v.shape}")
-    if sm_scale is None:
-        sm_scale = 1.0 / math.sqrt(d)
-    if not isinstance(causal, str):
-        # normalize truthy values (np.bool_, 1) to the literal bool the
-        # kernels' three-valued dispatch (`causal is True`) relies on
-        causal = bool(causal)
+    h, tq, tk, d, dv, causal, sm_scale = _checked(q, k, v, causal, sm_scale,
+                                                  -3)
+    lead = q.shape[:-3]
 
-    def to3(x, t):
-        x = x.reshape(-1, t, h, d)
-        return jnp.swapaxes(x, 1, 2).reshape(-1, t, d)
+    def to3(x, t, width):
+        x = x.reshape(-1, t, h, width)
+        return jnp.swapaxes(x, 1, 2).reshape(-1, t, width)
 
-    o3, lse3 = _flash_lse(to3(q, tq), to3(k, tk), to3(v, tk), causal,
-                          float(sm_scale), int(block_q), int(block_k))
-    o = jnp.swapaxes(o3.reshape(-1, h, tq, d), 1, 2).reshape(*lead, tq, h, d)
+    o3, lse3 = _flash_lse(to3(q, tq, d), to3(k, tk, d), to3(v, tk, dv),
+                          causal, sm_scale, int(block_q), int(block_k))
+    o = jnp.swapaxes(o3.reshape(-1, h, tq, dv), 1, 2).reshape(*lead, tq, h, dv)
     lse = jnp.swapaxes(lse3.reshape(-1, h, tq), 1, 2)       # (B, Tq, H)
     return o, lse.reshape(*lead, tq, h)
 
 
+def flash_attention_heads_first(q, k, v, causal: bool = False, sm_scale=None,
+                                block_q: int = 1024, block_k: int = 1024):
+    """:func:`flash_attention` over operands in the kernel's OWN order:
+    ``q`` (..., H, Tq, D), ``k`` (..., H, Tk, D), ``v`` (..., H, Tk, Dv) ->
+    (..., H, Tq, Dv), with no transpose on either side of the call (the
+    reshapes to ``(B H, T, D)`` move nothing).  For a caller that can
+    produce its heads first and consume them so, as a latent layer's
+    prefill does (tpu_dist.nn.mla): it rebuilds keys and values by an
+    einsum whose result order is its own choice, and its output projection
+    contracts ``(h, d)`` wherever they lie."""
+    h, tq, tk, d, dv, causal, sm_scale = _checked(q, k, v, causal, sm_scale,
+                                                  -2)
+    o3, _ = _flash_lse(q.reshape(-1, tq, d), k.reshape(-1, tk, d),
+                       v.reshape(-1, tk, dv), causal, sm_scale,
+                       int(block_q), int(block_k))
+    return o3.reshape(*q.shape[:-1], dv)
+
+
 def flash_attention(q, k, v, causal: bool = False, sm_scale=None,
                     block_q: int = 1024, block_k: int = 1024):
-    """Flash attention.  ``q``: (..., Tq, H, D); ``k, v``: (..., Tk, H, D).
+    """Flash attention.  ``q``: (..., Tq, H, D); ``k``: (..., Tk, H, D);
+    ``v``: (..., Tk, H, Dv), Dv = D but for a forward-only call
+    (:func:`flash_attention_with_lse`).
 
     Drop-in for :func:`tpu_dist.nn.attention.scaled_dot_product_attention`
     (mask=None); differentiable; O(T) memory.  ``block_q``/``block_k`` are

@@ -464,6 +464,15 @@ def decode_need_facts(model, params) -> dict:
             "experts": experts, "attend_flops": int(attend_flops)}
 
 
+def served_dtype(model, params):
+    """The type ``params`` serve ``model`` in: the embedding table's (what
+    every activation of the two pool programs follows), float32 for a model
+    that gathers from none."""
+    tables = [params[path][name] for path, name in gathered_tables(model)
+              if name in params.get(path, {})]
+    return tables[0].dtype if tables else np.dtype(np.float32)
+
+
 def residual_facts(model, params) -> dict:
     """What the residual between ``model``'s sublayers is made of, from the
     model's own answers: ``streams`` it keeps a token, ``sublayers`` that
@@ -472,11 +481,9 @@ def residual_facts(model, params) -> dict:
     (``residual_numbers_per_row``: 0 for ``x + f(x)``, whose add rides in
     the sublayer's own output).  Host facts for ``stats()["residual"]``,
     fixed at construction."""
-    tables = [params[path][name] for path, name in gathered_tables(model)
-              if name in params.get(path, {})]
-    itemsize = tables[0].dtype.itemsize if tables else 4
     return {"streams": model.streams, "sublayers": 2 * model.depth,
-            "row_bytes": model.residual_numbers_per_row() * itemsize}
+            "row_bytes": (model.residual_numbers_per_row()
+                          * served_dtype(model, params).itemsize)}
 
 
 def beside(params, state):
@@ -647,6 +654,12 @@ class SlotEngine:
         # for x + f(x)), and the rows each pool program carried
         self._residual = residual_facts(model, self.params)
         self._residual_rows = self._fresh_residual_rows()
+        # what each bucket's prefill program builds its attention on (the
+        # model's answer, asked when the bucket's program is first
+        # launched, under whatever attention_impl it is traced under), and
+        # the prefills counted by it
+        self._prefill_attn_facts: dict = {}
+        self._prefill_attn = self._fresh_prefill_attn()
 
         self._build_programs()
 
@@ -821,6 +834,7 @@ class SlotEngine:
                       else self.stage(req))
             key = seed_key(req.seed)
         with span("prefill.dispatch", bucket=int(staged.shape[0]), **ids):
+            self._count_prefill_attn(int(staged.shape[0]), len(req.prompt))
             tok_dev, self.cache, self._moe["prefill"], self._slots = \
                 self._prefill(
                     self.params, self.cache, self._moe["prefill"],
@@ -1126,6 +1140,7 @@ class SlotEngine:
         self._state_bytes = self._kv_bytes = 0
         self._need_rows = self._need_positions = 0
         self._residual_rows = self._fresh_residual_rows()
+        self._prefill_attn = self._fresh_prefill_attn()
         self._pipeline = self._fresh_pipeline()
         reset_phases(SERVE_PHASES)
         self._loop.reset()
@@ -1161,6 +1176,32 @@ class SlotEngine:
         return {"streams": facts["streams"], "sublayers": facts["sublayers"],
                 **{kind: {"rows": rows, "bytes": rows * facts["row_bytes"]}
                    for kind, rows in self._residual_rows.items()}}
+
+    @staticmethod
+    def _fresh_prefill_attn() -> dict:
+        return {"prefills": 0, "kernel_prefills": 0, "pairs_needed": 0,
+                "pairs_executed": 0}
+
+    def _count_prefill_attn(self, bucket: int, n: int) -> None:
+        """``stats()["prefill_attn"]``: one whole-prompt prefill of ``n``
+        true tokens in a program of ``bucket`` positions.  ``prefills``;
+        ``kernel_prefills``, those whose program's attention is the causal
+        flash forward kernel (``model.prefill_attention_facts``: a model
+        without a layer that says reports 0); ``pairs_needed``, ``n (n +
+        1) / 2`` (query, key) pairs a head a layer of those; and
+        ``pairs_executed``, what the program executed for them:
+        ``tile_plan``'s sub-tiles on the kernel, ``bucket ** 2`` dense.
+        Host arithmetic inside ``prefill.dispatch``."""
+        facts = self._prefill_attn_facts.get(bucket)
+        if facts is None:
+            facts = self._prefill_attn_facts[bucket] = \
+                self.model.prefill_attention_facts(
+                    bucket, served_dtype(self.model, self.params))
+        count = self._prefill_attn
+        count["prefills"] += 1
+        count["kernel_prefills"] += int(facts["kernel"])
+        count["pairs_needed"] += facts["heads"] * (n * (n + 1) // 2)
+        count["pairs_executed"] += facts["pairs_executed"]
 
     def _moe_read(self) -> dict:
         """The routed-row counters per pool program, each stacked over the
@@ -1264,7 +1305,8 @@ class SlotEngine:
         slots, summed over steps: ``state_bytes`` (whole state, read and
         written) and ``kv_bytes`` (the K/V columns held, the new one
         included).  ``"decode_need"``: :meth:`_decode_need_stats`.
-        ``"residual"``: :meth:`_residual_stats`.
+        ``"residual"``: :meth:`_residual_stats`.  ``"prefill_attn"``:
+        :meth:`_count_prefill_attn`.
         ``"params"``: what :func:`place_params` did at construction;
         ``reset_stats()`` leaves it.  ``"loop"``: the loop thread's clock
         (:meth:`tpu_dist.obs.spans.LoopClock.stats`): every iteration the
@@ -1278,6 +1320,7 @@ class SlotEngine:
             "decode_attn": self._decode_attn_stats(),
             "decode_need": self._decode_need_stats(since),
             "residual": self._residual_stats(),
+            "prefill_attn": dict(self._prefill_attn),
             "state": {"state_bytes": int(self._state_bytes),
                       "kv_bytes": int(self._kv_bytes)},
             "pipeline": {k: dict(v) if isinstance(v, dict) else v
