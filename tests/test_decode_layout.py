@@ -92,23 +92,25 @@ def _compile(program, cache_dtype, sharding):
     return _lower(model, program, params, cache, {}, sharding).compile()
 
 
-def _lower(model, program, params, pool, counters, sharding):
+def _lower(model, program, params, pool, counters, sharding, slots=SLOTS,
+           bucket=MAX_LEN):
     """``serve.engine.pool_programs(model)`` — the program AS SERVED, the
     slot state and the live mask beside the donated pool (ISSUE 29) —
-    lowered for the described chip."""
+    lowered for the described chip; ``slots`` of the pool, a prompt of
+    ``bucket`` positions."""
     def arr(dtype, *shape):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
     ints = lambda *shape: arr(jnp.int32, *shape)
-    slots = {"tokens": ints(SLOTS), "lengths": ints(SLOTS),
-             "steps": ints(SLOTS), "temps": arr(jnp.float32, SLOTS),
-             "keys": arr(jnp.uint32, SLOTS, 2)}
+    state = {"tokens": ints(slots), "lengths": ints(slots),
+             "steps": ints(slots), "temps": arr(jnp.float32, slots),
+             "keys": arr(jnp.uint32, slots, 2)}
     decode, prefill = pool_programs(model)
     if program == "decode_step":
         return jax.jit(decode, donate_argnums=1, static_argnums=5).lower(
-            params, pool, counters, slots, arr(jnp.bool_, SLOTS), False)
+            params, pool, counters, state, arr(jnp.bool_, slots), False)
     return jax.jit(prefill, donate_argnums=1, static_argnums=9).lower(
-        params, pool, counters, slots, ints(MAX_LEN), ints(), ints(),
+        params, pool, counters, state, ints(bucket), ints(), ints(),
         arr(jnp.float32), arr(jnp.uint32, 2), False)
 
 
@@ -464,3 +466,48 @@ def test_kimi_k2_prefill_on_the_kernel_holds_no_score_tensor(
     calls, score_shapes, dense_temp = seen["dense"]
     assert calls == 0 and "bf16[64,1024,1024]" in score_shapes
     assert dense_temp - temp >= 48 << 20, (dense_temp, temp)
+
+
+# -- a slot of whole state beside a headless latent (ISSUE 40) -----------------
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_into_slot"])
+def test_kimi_linear_pool_programs_compile_at_the_cells_shapes(
+        one_chip, no_compile_cache, mosaic_gmm, mosaic_decode_attention,
+        program):
+    """Kimi Linear's block at the published widths, two layers (a Kimi
+    Delta Attention layer over the dense MLP, a latent layer without rank
+    or rope over 32 held of 256 experts), vocabulary cut, at the cell's 120
+    slots x 1,024 and its 256 bucket: the chip's compiler takes both pool
+    programs, the decode step holds one ``latent_decode_attention`` call
+    and the grouped matmuls as Mosaic calls, and the 252 MB float32 state
+    is never copied (its update is in place in the donated pool).  The
+    cell names 120 slots because THIS compile is refused at 128 (a
+    ``bf16[1024,2304]`` gather of the expert layer's combine runs out of
+    scoped vmem: PERF.md section 7)."""
+    from tpu_dist.models import KimiLinearLM
+    slots = 120
+    model = KimiLinearLM(
+        VOCAB, dim=2304, depth=2, num_heads=32, kv_lora_rank=512,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        dense_hidden=9216, kda_layers=[1], full_attn_layers=[2],
+        num_experts=256, moe_top_k=8, moe_hidden=1024,
+        routed_scaling_factor=2.446, experts_held=32, max_seq_len=MAX_LEN)
+    pool = _shapes(jax.eval_shape(
+        lambda: model.init_slot_cache(slots, MAX_LEN, jnp.bfloat16)),
+        one_chip)
+    assert pool["block0.attn"]["state"].shape == (slots, 32, 128, 128)
+    counters = _shapes(jax.eval_shape(model.init_moe_counters), one_chip)
+    with nn.attention_impl("flash"):
+        compiled = _lower(model, program, _param_shapes(model, one_chip),
+                          pool, counters, one_chip, slots=slots,
+                          bucket=256).compile()
+    text = compiled.as_text()
+    calls = re.findall(r"%(latent_decode_attention|gmm_r\d+)[.\d]* = [^\n]*"
+                       r"custom_call_target=\"tpu_custom_call\"", text)
+    picks = (slots if program == "decode_step" else 256) * 8
+    assert f"gmm_r{picks}" in calls, calls
+    assert ("latent_decode_attention" in calls) == (program == "decode_step")
+    state = re.escape(f"f32[{slots},32,128,128]")
+    assert not [line for line in text.splitlines()
+                if re.search(r"= [^=]*%s[^=]* copy\(" % state, line)]
+    assert compiled.memory_analysis().temp_size_in_bytes < 512 << 20

@@ -5,7 +5,7 @@ from . import cache, functional, init
 from .attention import (MultiheadSelfAttention, attention_impl, rotary_embed,
                         scaled_dot_product_attention, yarn_inv_freq,
                         yarn_mscale)
-from .deltanet import GatedDeltaNet
+from .deltanet import GatedDeltaNet, KimiDeltaAttention
 from .hyper import HyperConnection, close_streams, open_streams
 from .layers import (AdaptiveAvgPool2d, AvgPool2d, BatchNorm2d, Conv2d,
                      Dropout, Embedding, Flatten, GELU, GatedMLP, Identity,
@@ -25,6 +25,7 @@ __all__ = [
     "Embedding", "LayerNorm", "RMSNorm", "GELU", "GatedMLP",
     "MultiheadSelfAttention", "MultiheadLatentAttention",
     "scaled_dot_product_attention", "attention_impl", "GatedDeltaNet",
+    "KimiDeltaAttention",
     "HyperConnection", "open_streams", "close_streams",
     "MoELayer", "rotary_embed", "yarn_inv_freq", "yarn_mscale",
     "CrossEntropyLoss",

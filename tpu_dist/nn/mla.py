@@ -3,12 +3,14 @@ keys and values are rebuilt from ONE low-rank latent a position, and the
 cache holds that latent, not K and V by head.
 
     c_q = N(x W_qa);            [q_nope | q_pe] = c_q W_qb        per head
+    (``q_lora_rank`` None: [q_nope | q_pe] = x W_q, one projection, no rank)
     [c_kv | k_pe] = x W_kva;    c_kv <- N(c_kv)                   no heads
-    q_pe, k_pe <- rope          (k_pe is ONE head, shared by all)
+    q_pe, k_pe <- rope          (k_pe is ONE head, shared by all;
+                                 ``use_nope``: no rotation anywhere)
     [k_nope | v] = c_kv W_kvb                                     per head
     scores = s (q_nope . k_nope + q_pe . k_pe);  causal softmax;  . v;  W_o
 
-Per position the layer keeps the normalised ``c_kv`` and the roped ``k_pe``
+Per position the layer keeps the normalised ``c_kv`` and the (roped) ``k_pe``
 side by side: ``kv_lora_rank + qk_rope_head_dim`` numbers (576 for Kimi K2,
 against 64 x (192 + 128) by head), the ``latent`` leaf of nn/cache.py,
 time-indexed and headless.
@@ -71,13 +73,25 @@ class MultiheadLatentAttention(Module):
     ``softmax_scale`` replaces ``(nope + rope) ** -0.5`` (YaRN's
     ``mscale ** 2`` folded in by the caller).  The parameters follow the
     published layout: ``q_b_weight``'s columns are per head ``[nope |
-    rope]``, ``kv_b_weight``'s per head ``[k_nope | v]``."""
+    rope]``, ``kv_b_weight``'s per head ``[k_nope | v]``.
 
-    def __init__(self, embed_dim: int, num_heads: int, q_lora_rank: int,
+    Two forms a published configuration may ask for, under its own keys:
+    ``q_lora_rank=None`` (``q_lora_rank: null``) projects the queries by
+    ONE matrix ``q_weight`` ``(embed_dim, H (nope + rope))``, columns per
+    head ``[nope | rope]`` as ``q_b_weight``'s, and has no ``q_a_weight`` /
+    ``q_a_norm_weight`` / ``q_b_weight``; ``use_nope=True`` (``mla_use_nope:
+    true``) rotates neither ``q_pe`` nor ``k_pe``: the layer has no
+    positional encoding, its ``rope`` columns are ``qk_rope_head_dim`` more
+    numbers of a key that all heads share, and ``rope_theta`` /
+    ``rope_inv_freq`` go unread.  The cache, both paths and both kernels are
+    the same in every form."""
+
+    def __init__(self, embed_dim: int, num_heads: int, q_lora_rank,
                  kv_lora_rank: int, qk_nope_head_dim: int,
                  qk_rope_head_dim: int, v_head_dim: int,
                  rope_theta: float = 10000.0, rope_inv_freq=None,
-                 softmax_scale=None, norm_eps: float = 1e-6):
+                 softmax_scale=None, norm_eps: float = 1e-6,
+                 use_nope: bool = False):
         super().__init__()
         if qk_rope_head_dim % 2:
             raise ValueError(f"rotary embeddings need an even "
@@ -92,6 +106,7 @@ class MultiheadLatentAttention(Module):
         self.latent_dim = kv_lora_rank + qk_rope_head_dim
         self.rope_theta = rope_theta
         self.rope_inv_freq = rope_inv_freq
+        self.use_nope = use_nope
         self.softmax_scale = (softmax_scale if softmax_scale is not None
                               else (self.nope + self.rope) ** -0.5)
         self.norm_eps = norm_eps
@@ -109,11 +124,14 @@ class MultiheadLatentAttention(Module):
         d, h = self.embed_dim, self.num_heads
         lin = lambda k, fan_in, fan_out: I.torch_default_uniform(
             k, (fan_in, fan_out), fan_in)
+        queries = ({"q_weight": lin(ks[0], d, h * (self.nope + self.rope))}
+                   if self.q_lora_rank is None else
+                   {"q_a_weight": lin(ks[0], d, self.q_lora_rank),
+                    "q_a_norm_weight": jnp.ones((self.q_lora_rank,)),
+                    "q_b_weight": lin(ks[1], self.q_lora_rank,
+                                      h * (self.nope + self.rope))})
         return {
-            "q_a_weight": lin(ks[0], d, self.q_lora_rank),
-            "q_a_norm_weight": jnp.ones((self.q_lora_rank,)),
-            "q_b_weight": lin(ks[1], self.q_lora_rank,
-                              h * (self.nope + self.rope)),
+            **queries,
             "kv_a_weight": lin(ks[2], d, self.latent_dim),
             "kv_a_norm_weight": jnp.ones((self.kv_lora_rank,)),
             "kv_b_weight": lin(ks[3], self.kv_lora_rank,
@@ -285,20 +303,25 @@ class MultiheadLatentAttention(Module):
         vector = getattr(index, "ndim", 0) == 1
         steps = jnp.arange(t)
         pos = index[:, None] + steps if vector else index + steps
-        with jax.named_scope("q_lora"):
-            c_q = F.rms_norm(F.linear(x, p["q_a_weight"]),
-                             p["q_a_norm_weight"], self.norm_eps)
-            q = F.linear(c_q, p["q_b_weight"]).reshape(
-                b, t, h, self.nope + self.rope)
+        rope = ((lambda a: a) if self.use_nope else
+                (lambda a: rotary_embed(a, pos, self.rope_theta,
+                                        inv_freq=self.rope_inv_freq)))
+        with jax.named_scope("q_proj" if self.q_lora_rank is None
+                             else "q_lora"):
+            if self.q_lora_rank is None:
+                q = F.linear(x, p["q_weight"])
+            else:
+                c_q = F.rms_norm(F.linear(x, p["q_a_weight"]),
+                                 p["q_a_norm_weight"], self.norm_eps)
+                q = F.linear(c_q, p["q_b_weight"])
+            q = q.reshape(b, t, h, self.nope + self.rope)
             q_nope = q[..., :self.nope]
-            q_pe = rotary_embed(q[..., self.nope:], pos, self.rope_theta,
-                                inv_freq=self.rope_inv_freq)
+            q_pe = rope(q[..., self.nope:])
         with jax.named_scope("kv_latent"):
             kv = F.linear(x, p["kv_a_weight"])
             c_kv = F.rms_norm(kv[..., :r], p["kv_a_norm_weight"],
                               self.norm_eps)
-            k_pe = rotary_embed(kv[..., None, r:], pos, self.rope_theta,
-                                inv_freq=self.rope_inv_freq)[..., 0, :]
+            k_pe = rope(kv[..., None, r:])[..., 0, :]
             # (B, t, C) -> (B, C, t): the stored order, time last
             new = jnp.swapaxes(jnp.concatenate([c_kv, k_pe], axis=-1), 1, 2)
         heads_first = False

@@ -71,6 +71,16 @@ def _fit_blocks(block_rows: int, block_h: int, dp: int, itemsize: int,
     return block_rows, block_h
 
 
+def _dividing_tile(width: int, tile: int) -> int:
+    """``tile`` where it divides ``width`` (whole lanes), else the widest
+    multiple of ``_LANE`` under it that does; a tile under a lane (a test's)
+    has none and stays."""
+    if width % tile == 0:
+        return tile
+    return max((t for t in range(_LANE, tile, _LANE) if width % t == 0),
+               default=tile)
+
+
 def gmm(x, w, block_groups, n_live_blocks, *, bias=None, block_rows: int = 512,
         block_h: int = 512, out_dtype=None, activation=None,
         name: str = "gmm"):
@@ -111,6 +121,12 @@ def gmm(x, w, block_groups, n_live_blocks, *, bias=None, block_rows: int = 512,
     br = block_rows
     block_rows, block_h = _fit_blocks(block_rows, block_h, dp,
                                       jnp.dtype(x.dtype).itemsize)
+    # H is padded up to whole tiles, and padding it copies the WHOLE weight
+    # stack at every call (a width of 2,304 under tiles of 512: 32 experts'
+    # 151 MB read and 168 written a layer a decode step, a fifth of the
+    # step; PERF.md, PR 40): take the widest tile of whole lanes that
+    # divides the lane-padded width.  A width of whole tiles keeps its tile.
+    block_h = _dividing_tile(_ceil_to(h, _LANE), block_h)
     if block_rows != br:
         # each caller row-block split into equal sub-blocks: expand the
         # block->group map and live count to the finer granularity

@@ -440,15 +440,19 @@ def decode_need_facts(model, params) -> dict:
     ``{MoELayer path: (bytes, parameters) of ONE expert}``, read only where
     a row reaches it; ``attend_flops``, the operations one resident
     position costs the attention layers, summed over them (each layer's
-    ``attend_flops_per_position``; a layer of whole state has none).  Host
-    facts for ``stats()["decode_need"]``, fixed at construction."""
+    ``attend_flops_per_position``; a layer of whole state has none);
+    ``state_flops``, the operations ONE row's update of its whole state
+    costs the recurrent layers (each layer's ``state_flops_per_row``; an
+    attention layer has none).  Host facts for ``stats()["decode_need"]``,
+    fixed at construction."""
     from ..nn import MoELayer
 
     tables = set(gathered_tables(model))
     experts, routed = {}, set()
-    attend_flops = 0
+    attend_flops = state_flops = 0
     for path, module in model.named_modules():
         attend_flops += getattr(module, "attend_flops_per_position", 0)
+        state_flops += getattr(module, "state_flops_per_row", 0)
         if isinstance(module, MoELayer) and path in params:
             own = {n: a for n, a in params[path].items()
                    if n in ("w1", "w2", "w3", "b1", "b2")}
@@ -461,7 +465,8 @@ def decode_need_facts(model, params) -> dict:
              if (path, n) not in tables and (path, n) not in routed]
     return {"fixed_bytes": sum(int(a.nbytes) for a in fixed),
             "fixed_params": sum(int(a.size) for a in fixed),
-            "experts": experts, "attend_flops": int(attend_flops)}
+            "experts": experts, "attend_flops": int(attend_flops),
+            "state_flops": int(state_flops)}
 
 
 def served_dtype(model, params):
@@ -1261,7 +1266,8 @@ class SlotEngine:
         busy slots, the one written included, and twice their whole state;
         ``flops``: 2 x the parameters a row uses (the fixed ones, and an
         expert's for each pick on a held expert) + the attention's
-        operations a resident position.  Rows of free slots, the kernels'
+        operations a resident position + the recurrent layers' a row's
+        state update.  Rows of free slots, the kernels'
         padding and a dense branch's reading of the whole pool are nobody's
         need.  ``steps`` are those launched, ``rows`` their busy slots and
         ``positions`` the columns those held, the one written included;
@@ -1281,7 +1287,8 @@ class SlotEngine:
                 "cache_bytes": int(self._kv_bytes + self._state_bytes),
                 "flops": 2 * (need["fixed_params"] * int(self._need_rows)
                               + picks)
-                + need["attend_flops"] * int(self._need_positions)}
+                + need["attend_flops"] * int(self._need_positions)
+                + need["state_flops"] * int(self._need_rows)}
 
     def _decode_attn_stats(self) -> dict:
         """``stats()["decode_attn"]``: how far the decode step's K/V traffic
