@@ -7,8 +7,9 @@ vocab 32768, T = 2048), every phase fatal:
   device    platform must be ``tpu``; versions, compile cache, store backend
   kernels   the Pallas kernels (flash attention, fused CE and the grouped
             matmuls forward and backward, slot-decode attention by head
-            and over a latent), lowered by
-            Mosaic at their full-width users' shapes, against plain ``jnp``
+            and over a latent, the recurrent state's one-token update),
+            lowered by Mosaic at their full-width users' shapes, against
+            plain ``jnp``
   convnet   the source paper's ConvNet through ``init_process_group`` +
             ``DistributedDataParallel.train_step``
   trainer   the LM through the same DDP over ALL local devices, bf16, fused
@@ -41,6 +42,8 @@ import time
 # within a few ulp of the largest element; 3e-2 (~8 ulp) is also the bound
 # tests/test_flash_attention.py holds the bf16 forward to.
 BF16_TOL = 3e-2
+# float32 sums of 128 products in another order
+F32_TOL = 2e-6
 
 # Serving check (see phase_server): a served greedy token may trail the
 # position's max logit in a plain forward by at most this.  The two paths
@@ -379,14 +382,45 @@ def check_latent_decode_attention(slots: int, heads: int, latent: int,
          f"touched ({int(busy.sum())} busy of {slots} slots)")
 
 
+def check_delta_step(slots: int, heads: int, k_dim: int, v_dim: int) -> None:
+    """The recurrent state's one-token update on a float32 state ``(slots,
+    heads, k_dim, v_dim)``, a decay a channel and a decay a head, slot 1 a
+    no-op row, against ``nn.deltanet.gated_delta_step``, the state donated."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from tpu_dist.nn.deltanet import gated_delta_step
+    from tpu_dist.ops.delta_step import delta_step
+
+    keys = jax.random.split(jax.random.key(9), 7)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    state = jax.random.normal(keys[0], (slots, heads, k_dim, v_dim))
+    q = unit(jax.random.normal(keys[1], (slots, heads, k_dim))) * k_dim ** -.5
+    k = unit(jax.random.normal(keys[2], (slots, heads, k_dim)))
+    v = jax.random.normal(keys[3], (slots, heads, v_dim))
+    beta = jax.random.uniform(keys[4], (slots, heads)).at[1].set(0.0)
+    for rank, shape in (("channel", k.shape), ("head", beta.shape)):
+        g = (-2.0 * jax.random.uniform(keys[5], shape)).at[1].set(0.0)
+        want_o, want = jax.jit(gated_delta_step)(state, q, k, v, g, beta)
+        out, got = jax.jit(delta_step, donate_argnums=0)(
+            state + 0.0, q, k, v, g, beta)
+        _check_close(f"delta step out, a decay a {rank}", out, want_o, F32_TOL)
+        _check_close(f"delta step state, a decay a {rank}", got, want,
+                     F32_TOL)
+        if not np.array_equal(np.asarray(got)[1], np.asarray(state)[1]):
+            raise AssertionError("delta step: a no-op row's state moved")
+
+
 def phase_kernels(flash: dict, ce: dict, moe: dict, decode: dict,
-                  latent: dict) -> dict:
+                  latent: dict, state: dict) -> dict:
     t0 = time.perf_counter()
     check_flash(**flash)
     check_fused_ce(**ce)
     check_dropless_moe(**moe)
     check_decode_attention(**decode)
     check_latent_decode_attention(**latent)
+    check_delta_step(**state)
     return {"seconds": time.perf_counter() - t0}
 
 
@@ -728,7 +762,8 @@ def main() -> int:
         moe=dict(tokens=8 * 2048, dim=768, experts=8, top_k=2),
         decode=dict(slots=32, heads=25, head_dim=64, max_len=1024),
         latent=dict(slots=32, heads=64, latent=576, values=512,
-                    max_len=1024))
+                    max_len=1024),
+        state=dict(slots=32, heads=32, k_dim=128, v_dim=128))
     _say(f"phase kernels passed ({r['seconds']:.1f} s with compilation)")
 
     _say("phase convnet")

@@ -377,9 +377,11 @@ def test_the_engine_mirrors_a_hybrid_pool_without_a_branch():
     state, per_pos = nn.cache.slot_bytes(engine.cache)
     # two decode steps over one busy slot holding 7 then 8 positions
     assert st == {"state_bytes": 2 * 2 * state,
-                  "kv_bytes": per_pos * (8 + 9)}
+                  "kv_bytes": per_pos * (8 + 9),
+                  "steps": 2, "kernel_steps": 0}            # a CPU run
     engine.reset_stats()
-    assert engine.stats()["state"] == {"state_bytes": 0, "kv_bytes": 0}
+    assert engine.stats()["state"] == {"state_bytes": 0, "kv_bytes": 0,
+                                       "steps": 0, "kernel_steps": 0}
 
 
 def _raises_naming_a_state_leaf():

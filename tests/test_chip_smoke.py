@@ -112,7 +112,8 @@ def test_kernel_convnet_and_multichip_phases_tiny():
         ce=dict(rows=64, vocab=1000),
         moe=dict(tokens=256, dim=128, experts=4, top_k=2),
         decode=dict(slots=12, heads=2, head_dim=64, max_len=512),
-        latent=dict(slots=12, heads=4, latent=48, values=32, max_len=512))
+        latent=dict(slots=12, heads=4, latent=48, values=32, max_len=512),
+        state=dict(slots=3, heads=2, k_dim=64, v_dim=128))
     chip_smoke.phase_convnet("cpu", per_chip_batch=8, steps=12)
     tr = chip_smoke.phase_trainer("cpu", steps=2, **TINY_RUN)
     chip_smoke.phase_multichip("cpu", dp_first_loss=tr["losses"][0],

@@ -470,17 +470,30 @@ def test_kimi_k2_prefill_on_the_kernel_holds_no_score_tensor(
 
 # -- a slot of whole state beside a headless latent (ISSUE 40) -----------------
 
+@pytest.fixture
+def mosaic_delta_step(monkeypatch):
+    """As ``mosaic_gmm``: steer ``ops/delta_step.py`` to the Mosaic lowering
+    although ``jax.default_backend()`` says CPU here."""
+    import sys
+    import tpu_dist.ops.delta_step  # noqa: F401
+    module = sys.modules["tpu_dist.ops.delta_step"]
+    monkeypatch.setattr(module, "_use_interpret", lambda: False)
+    yield
+    module._call.clear_cache()      # leave no Mosaic-lowered trace behind
+
+
 @pytest.mark.parametrize("program", ["decode_step", "prefill_into_slot"])
 def test_kimi_linear_pool_programs_compile_at_the_cells_shapes(
         one_chip, no_compile_cache, mosaic_gmm, mosaic_decode_attention,
-        program):
+        mosaic_delta_step, program):
     """Kimi Linear's block at the published widths, two layers (a Kimi
     Delta Attention layer over the dense MLP, a latent layer without rank
     or rope over 32 held of 256 experts), vocabulary cut, at the cell's 120
     slots x 1,024 and its 256 bucket: the chip's compiler takes both pool
-    programs, the decode step holds one ``latent_decode_attention`` call
-    and the grouped matmuls as Mosaic calls, and the 252 MB float32 state
-    is never copied (its update is in place in the donated pool).  The
+    programs, the decode step holds one ``latent_decode_attention`` call,
+    one ``delta_step`` call and the grouped matmuls as Mosaic calls, and the
+    252 MB float32 state is never copied (its update is in place in the
+    donated pool).  The
     cell names 120 slots because THIS compile is refused at 128 (a
     ``bf16[1024,2304]`` gather of the expert layer's combine runs out of
     scoped vmem: PERF.md section 7)."""
@@ -511,3 +524,16 @@ def test_kimi_linear_pool_programs_compile_at_the_cells_shapes(
     assert not [line for line in text.splitlines()
                 if re.search(r"= [^=]*%s[^=]* copy\(" % state, line)]
     assert compiled.memory_analysis().temp_size_in_bytes < 512 << 20
+    if program == "decode_step":
+        # ISSUE 41: the one-token update is ONE ``delta_step`` call fed the
+        # donated pool's leaf itself, and nothing else in the program yields
+        # an array of the state's shape (the ``jax.numpy`` form's
+        # multiply-add fusion did): read once, written once, in place
+        makers = re.findall(r"%([\w.-]+) = [^=\n]*?" + state
+                            + r"[^=\n]*? ([\w-]+)\(", text)
+        assert [(n.split(".")[0], op) for n, op in makers
+                if op not in ("parameter", "get-tuple-element", "tuple",
+                              "bitcast")] == [("delta_step", "custom-call")]
+        assert re.search(r"%delta_step[.\d]* = [^\n]*custom-call\("
+                         r"%cache__block0_attn____state__", text)
+        assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20

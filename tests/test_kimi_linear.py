@@ -565,7 +565,8 @@ def test_slot_engine_serves_the_reference_tokens_and_counts_by_hand(program):
     # 8 decode steps over two busy slots, 21 + i and 50 + i resident
     positions = sum((21 + i + 1) + (50 + i + 1) for i in range(8))
     assert st["state"] == {"state_bytes": 2 * state_bytes * 16,
-                           "kv_bytes": 24 * 4 * positions}
+                           "kv_bytes": 24 * 4 * positions,
+                           "steps": 8, "kernel_steps": 0}      # a CPU run
     dn, moe = st["decode_need"], st["moe"]["by_phase"]["decode"]
     assert (dn["steps"], dn["rows"], dn["positions"]) == (8, 16, positions)
     assert dn["cache_bytes"] == (st["state"]["state_bytes"]

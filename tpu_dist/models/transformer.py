@@ -319,6 +319,17 @@ class TransformerLM(nn.Module):
                    for mixer in self._mixers()
                    if nn.cache.pool_leaf(cache[mixer._path]) is not None)
 
+    def slot_state_kernel(self, cache) -> bool:
+        """Whether a decode step over the pool ``cache`` computes EVERY
+        recurrent layer's one-token update with the Pallas kernel
+        (tpu_dist.ops.delta_step; each layer's own answer, its
+        ``takes_step_kernel``, asked under the ``attention_impl`` the
+        program is traced under).  False for a model that keeps no whole
+        state.  A host fact for ``SlotEngine.stats()["state"]``."""
+        layers = [m for m in self._mixers() if hasattr(m, "takes_step_kernel")]
+        return bool(layers) and all(m.takes_step_kernel(cache[m._path])
+                                    for m in layers)
+
     def prefill_attention_facts(self, bucket: int, dtype=jnp.bfloat16) -> dict:
         """What the attention of a whole-prompt prefill of ``bucket``
         positions is built on, in the layers that say (a latent layer's

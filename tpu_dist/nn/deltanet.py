@@ -33,7 +33,10 @@ argument's shape (``g`` one axis shorter than ``k``: a scalar a head; of
 - :func:`gated_delta_step`, ONE token a row (a decode step over the slot
   pool): two passes over the state, one that reads it (``S^T [k, q]`` in one
   contraction; ``o_t`` follows from it without the updated state) and one
-  that reads and rewrites it;
+  that reads and rewrites it.  It is the definition, the differentiable
+  form and every CPU run's; a served decode step on a TPU takes
+  tpu_dist.ops.delta_step instead, one Pallas call that reads a slot's
+  tile once and writes it once in place (:func:`takes_step_kernel`);
 - :func:`gated_delta_chunked`, a whole prompt in chunks of 64 positions
   (the WY form of HF's ``torch_chunk_gated_delta_rule``): everything inside
   a chunk is matrix products over all chunks at once, and only the state's
@@ -67,7 +70,7 @@ from . import init as I
 from .module import Module
 
 __all__ = ["GatedDeltaNet", "KimiDeltaAttention", "gated_delta_step",
-           "gated_delta_chunked"]
+           "gated_delta_chunked", "takes_step_kernel"]
 
 CHUNK = 64
 # positions a sub-block of a chunk holds where the decay is per channel
@@ -275,13 +278,38 @@ def _causal_conv(x, tail, weight, valid):
         win, n, taps, axis=0))(window, n_real)
 
 
-def _recur(state, q, k, v, g, beta):
+def takes_step_kernel(entry, t: int = 1) -> bool:
+    """Whether a call of ``t`` positions of a recurrent layer served from
+    the cache ``entry`` (None: a plain forward) computes its update with the
+    Pallas kernel (tpu_dist.ops.delta_step: a slot's ``(Dk, Dv)`` tile read
+    once and written once, in place) or with :func:`gated_delta_step`.
+    Chosen as ``MultiheadLatentAttention.takes_slot_kernel`` chooses, by
+    what the call shows: one token a row; a cache entry (a plain
+    differentiable forward keeps the ``jax.numpy`` form: the kernel has no
+    backward); a float32 state whose ``Dv`` fills whole lanes and whose
+    ``Dk`` whole sublane tiles; and :func:`slot_kernel_wanted` (a TPU
+    backend; ``attention_impl`` overrides).  Both layers answer with it
+    (``layer.takes_step_kernel(entry)``); the engine asks the model, which
+    asks here, which form its decode program was built on."""
+    from ..ops.delta_step import delta_step_ok
+    from .attention import slot_kernel_wanted
+    return (t == 1 and entry is not None and delta_step_ok(entry["state"])
+            and slot_kernel_wanted())
+
+
+def _recur(state, q, k, v, g, beta, kernel: bool = False):
     """The recurrence over a call's ``t`` positions, operands (B, t, H, .):
-    the one-token update for a decode step, the chunked scan for anything
-    longer, each under its scope.  Returns ``(o (B, t, H, Dv), state)``."""
+    the one-token update for a decode step (``kernel``: through
+    tpu_dist.ops.delta_step, :func:`takes_step_kernel`'s answer), the
+    chunked scan for anything longer, each under its scope.  Returns ``(o
+    (B, t, H, Dv), state)``."""
     if q.shape[1] == 1:
         with jax.named_scope("state_update"):
-            out, state = gated_delta_step(
+            if kernel:
+                from ..ops.delta_step import delta_step as step
+            else:
+                step = gated_delta_step
+            out, state = step(
                 state, q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0])
             return out[:, None], state
     with jax.named_scope("scan"):
@@ -344,6 +372,7 @@ class GatedDeltaNet(Module):
 
     #: a layer of whole state reads no resident position
     attend_flops_per_position = 0
+    takes_step_kernel = staticmethod(takes_step_kernel)
 
     @property
     def state_flops_per_row(self) -> int:
@@ -418,7 +447,8 @@ class GatedDeltaNet(Module):
                           ba[..., hv:] + f32(p["dt_bias"])), 0.0)
         state = (jnp.zeros((b, hv, self.k_dim, self.v_dim), jnp.float32)
                  if st is None else st["state"])
-        out, state = _recur(state, q, k, v, g, beta)
+        out, state = _recur(state, q, k, v, g, beta,
+                            kernel=takes_step_kernel(st, t))
         if st is not None:
             ctx.put_state(self._path, dict(
                 st, state=state, index=jnp.asarray(st["index"]) + t,
@@ -481,6 +511,7 @@ class KimiDeltaAttention(Module):
 
     #: a layer of whole state reads no resident position
     attend_flops_per_position = 0
+    takes_step_kernel = staticmethod(takes_step_kernel)
 
     @property
     def state_flops_per_row(self) -> int:
@@ -566,7 +597,8 @@ class KimiDeltaAttention(Module):
             z = low_rank("g").reshape(b, t, h, d)
         state = (jnp.zeros((b, h, d, d), jnp.float32)
                  if st is None else st["state"])
-        out, state = _recur(state, q, k, v, g, beta)
+        out, state = _recur(state, q, k, v, g, beta,
+                            kernel=takes_step_kernel(st, t))
         if st is not None:
             ctx.put_state(self._path, dict(
                 st, state=state, index=jnp.asarray(st["index"]) + t,

@@ -651,6 +651,12 @@ class SlotEngine:
         # over decode iterations, host arithmetic like the blocks above
         self._slot_bytes = kvcache.slot_bytes(self.cache)
         self._state_bytes = self._kv_bytes = 0
+        # decode steps launched, and those whose program computes every
+        # recurrent layer's one-token update with the Pallas kernel (the
+        # model's answer, asked when the decode program is first launched,
+        # under whatever attention_impl it is traced under)
+        self._state_kernel = None
+        self._state_steps = 0
         # what a decode step needs of the parameters (host facts), and the
         # busy rows and resident positions summed over decode iterations
         self._need = decode_need_facts(model, self.params)
@@ -883,6 +889,9 @@ class SlotEngine:
                 np.where(live, self.lengths, 0), self.max_len)[0]
             state, per_pos = self._slot_bytes
             self._state_bytes += 2 * state * len(rows)
+            if self._state_kernel is None:
+                self._state_kernel = self.model.slot_state_kernel(self.cache)
+            self._state_steps += 1
             positions = int(self.lengths[rows].sum()) + len(rows)
             self._kv_bytes += per_pos * positions
             self._need_rows += len(rows)
@@ -1143,6 +1152,7 @@ class SlotEngine:
         self._decode_steps = 0
         self._kv_blocks_read = 0
         self._state_bytes = self._kv_bytes = 0
+        self._state_steps = 0
         self._need_rows = self._need_positions = 0
         self._residual_rows = self._fresh_residual_rows()
         self._prefill_attn = self._fresh_prefill_attn()
@@ -1311,7 +1321,11 @@ class SlotEngine:
         two kinds of cache the decode steps had to touch for their busy
         slots, summed over steps: ``state_bytes`` (whole state, read and
         written) and ``kv_bytes`` (the K/V columns held, the new one
-        included).  ``"decode_need"``: :meth:`_decode_need_stats`.
+        included); ``steps``, the decode steps launched, and
+        ``kernel_steps``, those whose program computes every recurrent
+        layer's one-token update with tpu_dist.ops.delta_step (the
+        model's ``slot_state_kernel``; 0 for a model without such a
+        layer).  ``"decode_need"``: :meth:`_decode_need_stats`.
         ``"residual"``: :meth:`_residual_stats`.  ``"prefill_attn"``:
         :meth:`_count_prefill_attn`.
         ``"params"``: what :func:`place_params` did at construction;
@@ -1329,7 +1343,10 @@ class SlotEngine:
             "residual": self._residual_stats(),
             "prefill_attn": dict(self._prefill_attn),
             "state": {"state_bytes": int(self._state_bytes),
-                      "kv_bytes": int(self._kv_bytes)},
+                      "kv_bytes": int(self._kv_bytes),
+                      "steps": self._state_steps,
+                      "kernel_steps": (self._state_steps
+                                       if self._state_kernel else 0)},
             "pipeline": {k: dict(v) if isinstance(v, dict) else v
                          for k, v in self._pipeline.items()},
             "params": dict(self._placed),
