@@ -44,6 +44,9 @@ import time
 BF16_TOL = 3e-2
 # float32 sums of 128 products in another order
 F32_TOL = 2e-6
+# three bfloat16 passes a product (2^-17 of its scale) on both sides, chained
+# through the chunks of a prompt
+SCAN_TOL = 1e-4
 
 # Serving check (see phase_server): a served greedy token may trail the
 # position's max logit in a plain forward by at most this.  The two paths
@@ -412,8 +415,53 @@ def check_delta_step(slots: int, heads: int, k_dim: int, v_dim: int) -> None:
             raise AssertionError("delta step: a no-op row's state moved")
 
 
+def check_delta_scan(seq: int, key_heads: int, value_heads: int, k_dim: int,
+                     v_dim: int) -> None:
+    """The recurrent state's chunked scan (a decay a head) over one prompt
+    of ``seq`` positions whose last tenth is padding (``g = 0``, ``beta =
+    0``), from a drawn float32 state, against
+    ``nn.deltanet.gated_delta_chunked`` on the same operands repeated and
+    heads-first, the state donated; and the state after the padded prompt
+    against the state after its real positions alone."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpu_dist.nn.deltanet import gated_delta_chunked
+    from tpu_dist.ops.delta_scan import delta_scan
+
+    keys = jax.random.split(jax.random.key(10), 6)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    real = seq - seq // 10
+    valid = (jnp.arange(seq) < real)[None, :, None]
+    state = jax.random.normal(keys[0], (1, value_heads, k_dim, v_dim))
+    q = unit(jax.random.normal(keys[1], (1, seq, key_heads, k_dim))) \
+        * k_dim ** -.5
+    k = unit(jax.random.normal(keys[2], (1, seq, key_heads, k_dim)))
+    v = jax.random.normal(keys[3], (1, seq, value_heads, v_dim))
+    beta = jnp.where(valid, jax.random.uniform(
+        keys[4], (1, seq, value_heads)), 0.0)
+    g = jnp.where(valid, -jnp.exp(jax.random.uniform(
+        keys[5], (1, seq, value_heads), minval=-7.0, maxval=1.0)), 0.0)
+
+    def chunked(state, q, k, v, g, beta):
+        rep = value_heads // key_heads
+        q, k = (jnp.repeat(a, rep, axis=2) for a in (q, k))
+        out, state = gated_delta_chunked(state, *(
+            jnp.moveaxis(a, 2, 1) for a in (q, k, v, g, beta)))
+        return jnp.moveaxis(out, 1, 2), state
+
+    want_o, want = jax.jit(chunked)(state, q, k, v, g, beta)
+    scan = jax.jit(delta_scan, donate_argnums=0)
+    out, got = scan(state + 0.0, q, k, v, g, beta)
+    _check_close("delta scan out", out, want_o, SCAN_TOL)
+    _check_close("delta scan state", got, want, SCAN_TOL)
+    _, alone = scan(state + 0.0, *(a[:, :real] for a in (q, k, v, g, beta)))
+    _check_close("delta scan state, padded against alone", got, alone,
+                 F32_TOL)
+
+
 def phase_kernels(flash: dict, ce: dict, moe: dict, decode: dict,
-                  latent: dict, state: dict) -> dict:
+                  latent: dict, state: dict, scan: dict) -> dict:
     t0 = time.perf_counter()
     check_flash(**flash)
     check_fused_ce(**ce)
@@ -421,6 +469,7 @@ def phase_kernels(flash: dict, ce: dict, moe: dict, decode: dict,
     check_decode_attention(**decode)
     check_latent_decode_attention(**latent)
     check_delta_step(**state)
+    check_delta_scan(**scan)
     return {"seconds": time.perf_counter() - t0}
 
 
@@ -763,7 +812,9 @@ def main() -> int:
         decode=dict(slots=32, heads=25, head_dim=64, max_len=1024),
         latent=dict(slots=32, heads=64, latent=576, values=512,
                     max_len=1024),
-        state=dict(slots=32, heads=32, k_dim=128, v_dim=128))
+        state=dict(slots=32, heads=32, k_dim=128, v_dim=128),
+        scan=dict(seq=4096, key_heads=16, value_heads=32, k_dim=128,
+                  v_dim=128))
     _say(f"phase kernels passed ({r['seconds']:.1f} s with compilation)")
 
     _say("phase convnet")

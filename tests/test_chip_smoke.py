@@ -105,6 +105,14 @@ def test_phase_fails_loudly():
         chip_smoke.phase_server(TINY_LM, slots=4, requests=[(5, 4)])
 
 
+@pytest.mark.parametrize("heads", [(1, 2), (2, 4)], ids=["rep2", "two-pairs"])
+def test_the_scan_kernels_check_tiny(heads):
+    """``check_delta_scan`` as the chip runs it (a padded tail, a drawn
+    state, the state donated), interpreted here at 200 positions."""
+    chip_smoke.check_delta_scan(seq=200, key_heads=heads[0],
+                                value_heads=heads[1], k_dim=128, v_dim=128)
+
+
 @pytest.mark.slow
 def test_kernel_convnet_and_multichip_phases_tiny():
     chip_smoke.phase_kernels(
@@ -113,7 +121,8 @@ def test_kernel_convnet_and_multichip_phases_tiny():
         moe=dict(tokens=256, dim=128, experts=4, top_k=2),
         decode=dict(slots=12, heads=2, head_dim=64, max_len=512),
         latent=dict(slots=12, heads=4, latent=48, values=32, max_len=512),
-        state=dict(slots=3, heads=2, k_dim=64, v_dim=128))
+        state=dict(slots=3, heads=2, k_dim=64, v_dim=128),
+        scan=dict(seq=150, key_heads=1, value_heads=2, k_dim=128, v_dim=128))
     chip_smoke.phase_convnet("cpu", per_chip_batch=8, steps=12)
     tr = chip_smoke.phase_trainer("cpu", steps=2, **TINY_RUN)
     chip_smoke.phase_multichip("cpu", dp_first_loss=tr["losses"][0],

@@ -537,3 +537,66 @@ def test_kimi_linear_pool_programs_compile_at_the_cells_shapes(
         assert re.search(r"%delta_step[.\d]* = [^\n]*custom-call\("
                          r"%cache__block0_attn____state__", text)
         assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+# -- a Gated DeltaNet prefill on the scan kernel (ISSUE 42) --------------------
+
+@pytest.fixture
+def mosaic_delta_scan(monkeypatch):
+    """As ``mosaic_delta_step``, for ``ops/delta_scan.py``."""
+    import sys
+    import tpu_dist.ops.delta_scan  # noqa: F401
+    module = sys.modules["tpu_dist.ops.delta_scan"]
+    monkeypatch.setattr(module, "_use_interpret", lambda: False)
+    module._call.clear_cache()
+    yield
+    module._call.clear_cache()      # leave no Mosaic-lowered trace behind
+
+
+def test_qwen3_next_prefill_holds_one_delta_scan_call_a_recurrent_layer(
+        one_chip, no_compile_cache, mosaic_gmm, mosaic_flash,
+        mosaic_decode_attention, mosaic_delta_step, mosaic_delta_scan):
+    """Qwen3-Next's block at the published widths, one period of the layer
+    pattern (three Gated DeltaNet layers of 16 key / 32 value heads of 128 x
+    128, one gated full-attention layer; 64 held of 512 experts, vocabulary
+    cut), at the cell's 96 slots x 4,096 and its 4,096 bucket: the chip's
+    compiler takes the prefill program with ONE ``delta_scan`` call a
+    recurrent layer (its state operand the zeros a request starts from, its
+    result written into the slot's row of the donated pool), and on that
+    branch nothing of the ``jax.numpy`` scan's shapes is left in the
+    program: no ``(1, 32, 64, 64, 64)`` decay or power, no ``(1, 32, 64,
+    64, 128)`` operand of a doubling step, no carry of 64 steps (the dense
+    branch holds all three), and the program's temporaries are smaller."""
+    from tpu_dist.models import Qwen3NextLM
+    slots, max_len = 96, 4096
+    model = Qwen3NextLM(
+        VOCAB, dim=2048, depth=4, num_heads=16, num_kv_heads=2, head_dim=256,
+        num_experts=512, experts_held=64, moe_top_k=10, moe_hidden=512,
+        shared_hidden=512, max_seq_len=max_len)
+    pool = _shapes(jax.eval_shape(
+        lambda: model.init_slot_cache(slots, max_len, jnp.bfloat16)),
+        one_chip)
+    assert pool["block0.attn"]["state"].shape == (slots, 32, 128, 128)
+    counters = _shapes(jax.eval_shape(model.init_moe_counters), one_chip)
+    params = _param_shapes(model, one_chip)
+    scan_shapes = re.compile(r"f32\[1,32,64,64,(?:64|128)\]")
+    seen = {}
+    for impl in ("flash", "dense"):
+        with nn.attention_impl(impl):
+            assert model.prefill_scan_kernel(pool, max_len) is (
+                impl == "flash")
+            compiled = _lower(model, "prefill_into_slot", params, pool,
+                              counters, one_chip, slots=slots,
+                              bucket=max_len).compile()
+        text = compiled.as_text()
+        seen[impl] = (
+            len(re.findall(r"%delta_scan[.\d]* = [^\n]*"
+                           r"custom_call_target=\"tpu_custom_call\"", text)),
+            sorted(set(scan_shapes.findall(text))),
+            compiled.memory_analysis().temp_size_in_bytes)
+    calls, shapes, temp = seen["flash"]
+    assert calls == 3 and not shapes, (calls, shapes)
+    calls, shapes, dense_temp = seen["dense"]
+    assert calls == 0 and shapes == ["f32[1,32,64,64,128]",
+                                    "f32[1,32,64,64,64]"]
+    assert temp < dense_temp, (temp, dense_temp)

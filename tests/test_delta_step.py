@@ -256,6 +256,15 @@ def test_a_model_without_recurrent_layers_counts_no_kernel_step():
         engine.step()
     state = engine.stats()["state"]
     assert (state["steps"], state["kernel_steps"]) == (2, 0)
+    # nor a prefill on the scan kernel (ISSUE 42), and the count is reset
+    with nn.attention_impl("flash"):
+        assert model.prefill_scan_kernel(model.init_slot_cache(2, 32),
+                                         8) is False
+    assert engine.stats()["prefill_scan"] == {"prefills": 1,
+                                              "kernel_prefills": 0}
+    engine.reset_stats()
+    assert engine.stats()["prefill_scan"] == {"prefills": 0,
+                                              "kernel_prefills": 0}
 
 
 def test_the_wire_stats_frame_carries_the_two_counters():

@@ -586,6 +586,61 @@ def test_slot_engine_serves_the_reference_tokens_and_counts_by_hand(program):
     assert whole["absent_rows"] == 0 and whole["held_rows"] == whole["rows"]
 
 
+def _pallas_call_names(jaxpr):
+    """The ``name`` of every ``pallas_call`` of a jaxpr, sub-jaxprs too."""
+    names = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            names.append(eqn.params["name"])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names.extend(_pallas_call_names(sub))
+    return names
+
+
+def test_a_prefill_with_a_decay_a_channel_never_takes_the_scan_kernel():
+    """ISSUE 42: tpu_dist.ops.delta_scan computes the scalar-decay form;
+    Kimi Delta Attention's pairwise decays do not factor out of a chunk's
+    products, so with heads the kernel WOULD take (128 x 128, float32) and
+    the kernels forced, the model answers false, its prefill program holds
+    no ``delta_scan`` call (its decode step does hold ``delta_step``), the
+    engine counts no kernel prefill, and the tokens are the ``jax.numpy``
+    form's."""
+    model = KimiLinearLM(
+        97, dim=64, depth=2, num_heads=4, kv_lora_rank=16,
+        qk_nope_head_dim=8, qk_rope_head_dim=8, v_head_dim=8,
+        dense_hidden=96, kda_layers=[1], full_attn_layers=[2],
+        linear_num_heads=2, linear_head_dim=128, num_experts=8,
+        moe_top_k=2, moe_hidden=32, max_seq_len=64)
+    params = model.init(jax.random.key(0))
+    pool, counters = model.init_slot_cache(2, 64), model.init_moe_counters()
+    with nn.attention_impl("flash"):
+        assert model.prefill_scan_kernel(pool, 32) is False
+        assert model.slot_state_kernel(pool) is True
+        prefill = jax.make_jaxpr(model.prefill_into_slot)(
+            params, np.zeros(32, np.int32), 20, 0, pool, counters)
+        decode = jax.make_jaxpr(model.decode_step)(
+            params, np.zeros(2, np.int32), np.zeros(2, np.int32), pool,
+            counters)
+    assert "delta_scan" not in _pallas_call_names(prefill.jaxpr)
+    assert "delta_step" in _pallas_call_names(decode.jaxpr)
+
+    def served(impl):
+        got = []
+        with nn.attention_impl(impl):
+            engine = serve.SlotEngine(model, params, num_slots=2, max_len=64,
+                                      min_bucket=16)
+            engine.admit(serve.Request(
+                np.arange(1, 21), 4, on_token=lambda _, tok: got.append(tok)))
+            while not engine.idle():
+                engine.step()
+        return got, engine.stats()["prefill_scan"]
+
+    got, scan = served("flash")
+    want, _ = served("dense")
+    assert got == want and len(got) == 4
+    assert scan == {"prefills": 1, "kernel_prefills": 0}
+
+
 def test_gated_deltanet_gets_the_state_operations_too():
     model = Qwen3NextLM(97, dim=32, depth=1, num_heads=2, num_kv_heads=1,
                         head_dim=16, linear_key_heads=1, linear_value_heads=2,

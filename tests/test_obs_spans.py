@@ -702,6 +702,31 @@ class TestServingLoopClock:
             fe.close()
             sched.close()
 
+    def test_prefill_scan_counts_reset_and_ride_the_wire(self, lm):
+        """``stats()["prefill_scan"]`` (ISSUE 42) for a model without a
+        recurrent layer: its prefills counted, none on the scan kernel (the
+        counts of a hybrid model on either branch: tests/test_qwen3_next.py,
+        tests/test_kimi_linear.py)."""
+        model, params = lm
+        engine = serve.SlotEngine(model, params, num_slots=4)
+        sched = serve.Scheduler(engine, batch_window=0.002)
+        fe = serve.Frontend(sched, port=0)
+        cli = serve.ServeClient("127.0.0.1", fe.port, connect_retry=10)
+        zero = {"prefills": 0, "kernel_prefills": 0}
+        try:
+            assert cli.stats()["prefill_scan"] == zero
+            for n in (6, 30):
+                cli.generate(list(range(1, n)), max_new_tokens=2,
+                             timeout=120.0)
+            assert cli.stats()["prefill_scan"] == dict(zero, prefills=2)
+            assert engine.stats()["prefill_scan"] == dict(zero, prefills=2)
+            engine.reset_stats()
+            assert cli.stats()["prefill_scan"] == zero
+        finally:
+            cli.close()
+            fe.close()
+            sched.close()
+
     def test_the_wire_stats_frame_carries_the_loop_as_json(self, lm):
         import json
         model, params = lm

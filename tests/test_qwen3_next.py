@@ -298,6 +298,111 @@ def test_a_request_does_not_depend_on_its_bucket_or_its_neighbours(program):
     np.testing.assert_allclose(busy, base, rtol=0, atol=SAME)
 
 
+# -- the prefill's scan on the Pallas kernel (ISSUE 42) -------------------------
+# ``attention_impl("flash")`` makes a CPU run take tpu_dist.ops.delta_scan
+# (interpreted) for a prefill and tpu_dist.ops.delta_step for a decode step;
+# heads of 128 x 128 are the least either takes: a key head, two value heads.
+
+CFG128 = dict(CFG, linear_num_key_heads=1, linear_num_value_heads=2,
+              linear_key_head_dim=128, linear_value_head_dim=128)
+
+
+@pytest.fixture(scope="module")
+def program128():
+    model = _model(CFG128)
+    return model, _perturbed(model.init(jax.random.key(11)))
+
+
+@pytest.mark.parametrize("bucket", [64, 128])
+def test_prefill_on_the_scan_kernel_then_decode_match_the_reference(
+        program128, bucket):
+    """The prefill's scan through ``delta_scan`` (three bfloat16 passes a
+    product, interpreted) and the decode steps through ``delta_step``:
+    against the reference position by position, against the ``jax.numpy``
+    form, and the same in two buckets."""
+    model, params = program128
+    prompt = np.random.default_rng(5).integers(0, CFG["vocab_size"], 45)
+    with jax.default_matmul_precision("highest"):
+        with nn.attention_impl("flash"):
+            rows, toks, _ = _serve_one(model, params, prompt, 6, 2,
+                                       _pool(model), bucket=bucket)
+        with nn.attention_impl("dense"):
+            want, toks_dense, _ = _serve_one(model, params, prompt, 6, 2,
+                                             _pool(model), bucket=bucket)
+    full = np.concatenate([prompt, toks])
+    ref = _ref_logits(params, full, CFG128)[len(prompt) - 1:-1]
+    np.testing.assert_allclose(rows, ref, rtol=0, atol=ATOL)
+    assert toks == toks_dense
+    np.testing.assert_allclose(rows, want, rtol=0, atol=SAME)
+
+
+def _through_engine(model, params, impl, prompts, new=5):
+    """``prompts`` through a ``SlotEngine`` built and run under ``impl``:
+    tokens, ``stats()["prefill_scan"]`` before and after ``reset_stats()``."""
+    got = {i: [] for i in range(len(prompts))}
+    with nn.attention_impl(impl):
+        engine = serve.SlotEngine(model, params, num_slots=3, max_len=128,
+                                  min_bucket=32)
+        for i, prompt in enumerate(prompts):
+            engine.launch_admit(serve.Request(
+                prompt, new, on_token=lambda _, tok, i=i: got[i].append(tok)))
+            engine.settle()
+        while not engine.idle():
+            if engine.launch_step():
+                engine.settle()
+            else:
+                engine.collect_all()
+    scan = engine.stats()["prefill_scan"]
+    engine.reset_stats()
+    return got, scan, engine.stats()["prefill_scan"]
+
+
+def test_slot_engine_on_the_scan_kernel_serves_the_reference_tokens(
+        program128):
+    """Two buckets (32 and 64) through ``SlotEngine`` with the kernels
+    forced: every served token is the reference's largest logit at its
+    position, the tokens are the ``jax.numpy`` form's, and
+    ``stats()["prefill_scan"]`` counts every prefill on the kernel."""
+    model, params = program128
+    rng = np.random.default_rng(6)
+    prompts = [rng.integers(0, CFG["vocab_size"], n) for n in (21, 50)]
+    got, scan, zeroed = _through_engine(model, params, "flash", prompts)
+    want, dense, _ = _through_engine(model, params, "dense", prompts)
+    assert got == want
+    for i, prompt in enumerate(prompts):
+        ref = _ref_logits(params, np.concatenate([prompt, got[i]]), CFG128)
+        ref = ref[len(prompt) - 1:-1]
+        margin = ref.max(-1) - ref[np.arange(5), got[i]]
+        assert margin.max() <= ATOL, margin
+    assert scan == {"prefills": 2, "kernel_prefills": 2}
+    assert dense == {"prefills": 2, "kernel_prefills": 0}
+    assert zeroed == {"prefills": 0, "kernel_prefills": 0}
+
+
+def test_heads_the_kernel_does_not_take_keep_the_jax_numpy_scan(program):
+    """Heads of 16 x 16 (this file's usual size) fill no lane: the model
+    answers false for every bucket whatever ``attention_impl`` says, and the
+    engine counts no kernel prefill."""
+    model, params = program
+    with nn.attention_impl("flash"):
+        assert model.prefill_scan_kernel(model.init_slot_cache(2, 64),
+                                         64) is False
+    prompts = [np.arange(1, 20)]
+    _, scan, _ = _through_engine(model, params, "flash", prompts, new=2)
+    assert scan == {"prefills": 1, "kernel_prefills": 0}
+
+
+def test_the_model_answers_for_its_recurrent_layers(program128):
+    model, _ = program128
+    cache = model.init_slot_cache(2, 64)
+    with nn.attention_impl("flash"):
+        assert model.prefill_scan_kernel(cache, 64) is True
+        assert model.prefill_scan_kernel(cache, 1) is False
+    with nn.attention_impl("dense"):
+        assert model.prefill_scan_kernel(cache, 64) is False
+    assert model.prefill_scan_kernel(cache, 64) is False     # a CPU backend
+
+
 def test_a_state_advanced_over_padding_would_show(program):
     """What the tolerance above is measured against: ONE more real position
     (the padding token read as the request's) moves the first logits by far

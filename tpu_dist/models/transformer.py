@@ -330,6 +330,17 @@ class TransformerLM(nn.Module):
         return bool(layers) and all(m.takes_step_kernel(cache[m._path])
                                     for m in layers)
 
+    def prefill_scan_kernel(self, cache, bucket: int) -> bool:
+        """Whether a whole-prompt prefill of ``bucket`` positions into the
+        pool ``cache`` computes EVERY recurrent layer's scan with the Pallas
+        kernel (tpu_dist.ops.delta_scan; each layer's own answer, its
+        ``takes_scan_kernel``, asked under the ``attention_impl`` the
+        program is traced under).  False for a model that keeps no whole
+        state.  A host fact for ``SlotEngine.stats()["prefill_scan"]``."""
+        layers = [m for m in self._mixers() if hasattr(m, "takes_scan_kernel")]
+        return bool(layers) and all(
+            m.takes_scan_kernel(cache[m._path], bucket) for m in layers)
+
     def prefill_attention_facts(self, bucket: int, dtype=jnp.bfloat16) -> dict:
         """What the attention of a whole-prompt prefill of ``bucket``
         positions is built on, in the layers that say (a latent layer's

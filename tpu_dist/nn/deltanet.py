@@ -47,7 +47,11 @@ argument's shape (``g`` one axis shorter than ``k``: a scalar a head; of
   where forward substitution has 63 dependent row updates.  With a decay a
   channel the chunk's pairwise decays ``exp(G_i - G_j)`` no longer factor
   out of the products ``q_i . k_j``: :func:`_pairwise_decayed` computes them
-  in sub-blocks of 16 positions.
+  in sub-blocks of 16 positions.  It is the definition, the differentiable
+  form, every CPU run's and the per-channel form's; a served prefill on a
+  TPU whose decay is a number a head takes tpu_dist.ops.delta_scan instead,
+  one Pallas call that keeps a chunk's 64 x 64 system, its products and the
+  carried state in VMEM (:func:`takes_scan_kernel`).
 
 **Positions that are nobody's** (bucket padding in a prefill, a free slot's
 row in a decode step; ``valid`` false in the layer's cache entry,
@@ -70,7 +74,7 @@ from . import init as I
 from .module import Module
 
 __all__ = ["GatedDeltaNet", "KimiDeltaAttention", "gated_delta_step",
-           "gated_delta_chunked", "takes_step_kernel"]
+           "gated_delta_chunked", "takes_step_kernel", "takes_scan_kernel"]
 
 CHUNK = 64
 # positions a sub-block of a chunk holds where the decay is per channel
@@ -297,12 +301,44 @@ def takes_step_kernel(entry, t: int = 1) -> bool:
             and slot_kernel_wanted())
 
 
+def takes_scan_kernel(entry, t: int, g) -> bool:
+    """Whether a call of ``t`` positions of a recurrent layer served from
+    the cache ``entry`` (None: a plain forward), its log decay ``g`` (an
+    array or a shape, ``(B, t, H)`` or ``(B, t, H, Dk)``), computes its scan
+    with the Pallas kernel (tpu_dist.ops.delta_scan: a chunk's 64 x 64
+    system, its products and the carried state kept in VMEM) or with
+    :func:`gated_delta_chunked`.  Chosen as :func:`takes_step_kernel`
+    chooses, by what the call shows: more than one position; a cache entry
+    (the kernel has no backward); a float32 state whose ``Dk`` and ``Dv``
+    fill whole lanes; ``g`` one axis shorter than ``k`` (a decay a HEAD: a
+    decay a channel does not factor out of a chunk's products and keeps the
+    ``jax.numpy`` form); and :func:`slot_kernel_wanted`.  Each layer answers
+    with its own ``g``'s shape (``layer.takes_scan_kernel(entry, t)``); the
+    engine asks the model, which asks there, which form a bucket's prefill
+    program was built on."""
+    from ..ops.delta_scan import delta_scan_ok
+    from .attention import slot_kernel_wanted
+    return (t > 1 and entry is not None and len(g.shape) == 3
+            and delta_scan_ok(entry["state"]) and slot_kernel_wanted())
+
+
 def _recur(state, q, k, v, g, beta, kernel: bool = False):
-    """The recurrence over a call's ``t`` positions, operands (B, t, H, .):
-    the one-token update for a decode step (``kernel``: through
+    """The recurrence over a call's ``t`` positions, operands (B, t, H, .),
+    ``q`` and ``k`` by KEY head (each serves ``Hv // Hk`` consecutive value
+    heads): the one-token update for a decode step (``kernel``: through
     tpu_dist.ops.delta_step, :func:`takes_step_kernel`'s answer), the
-    chunked scan for anything longer, each under its scope.  Returns ``(o
-    (B, t, H, Dv), state)``."""
+    chunked scan for anything longer (``kernel``: through
+    tpu_dist.ops.delta_scan, :func:`takes_scan_kernel`'s answer, which
+    takes the operands as they are; the ``jax.numpy`` forms take them
+    repeated and heads-first), each under its scope.  Returns ``(o (B, t,
+    H, Dv), state)``."""
+    if q.shape[1] > 1 and kernel:
+        with jax.named_scope("scan"):
+            from ..ops.delta_scan import delta_scan
+            return delta_scan(state, q, k, v, g, beta)
+    if v.shape[2] != q.shape[2]:
+        q, k = (jnp.repeat(a, v.shape[2] // q.shape[2], axis=2)
+                for a in (q, k))
     if q.shape[1] == 1:
         with jax.named_scope("state_update"):
             if kernel:
@@ -374,6 +410,12 @@ class GatedDeltaNet(Module):
     attend_flops_per_position = 0
     takes_step_kernel = staticmethod(takes_step_kernel)
 
+    def takes_scan_kernel(self, entry, t: int) -> bool:
+        """:func:`takes_scan_kernel` of a call of ``t`` positions: this
+        layer's decay is a number a head."""
+        return takes_scan_kernel(entry, t, jax.ShapeDtypeStruct(
+            (1, t, self.num_v_heads), jnp.float32))
+
     @property
     def state_flops_per_row(self) -> int:
         """Operations one row's one-token update costs this layer
@@ -438,7 +480,6 @@ class GatedDeltaNet(Module):
         q, k, v = jnp.split(mixed, [self.key_dim, 2 * self.key_dim], axis=-1)
         q = _l2norm(f32(q.reshape(b, t, hk, self.k_dim))) * self.k_dim ** -0.5
         k = _l2norm(f32(k.reshape(b, t, hk, self.k_dim)))
-        q, k = (jnp.repeat(a, hv // hk, axis=2) for a in (q, k))
         v = f32(v.reshape(b, t, hv, self.v_dim))
         # nobody's positions: beta = 0 and g = 0, the recurrence's no-op
         beta = jnp.where(valid[..., None], jax.nn.sigmoid(ba[..., :hv]), 0.0)
@@ -448,7 +489,8 @@ class GatedDeltaNet(Module):
         state = (jnp.zeros((b, hv, self.k_dim, self.v_dim), jnp.float32)
                  if st is None else st["state"])
         out, state = _recur(state, q, k, v, g, beta,
-                            kernel=takes_step_kernel(st, t))
+                            kernel=(takes_step_kernel(st, t)
+                                    or takes_scan_kernel(st, t, g)))
         if st is not None:
             ctx.put_state(self._path, dict(
                 st, state=state, index=jnp.asarray(st["index"]) + t,
@@ -512,6 +554,12 @@ class KimiDeltaAttention(Module):
     #: a layer of whole state reads no resident position
     attend_flops_per_position = 0
     takes_step_kernel = staticmethod(takes_step_kernel)
+
+    def takes_scan_kernel(self, entry, t: int) -> bool:
+        """:func:`takes_scan_kernel` of a call of ``t`` positions: this
+        layer's decay is a number a CHANNEL, so never."""
+        return takes_scan_kernel(entry, t, jax.ShapeDtypeStruct(
+            (1, t, self.num_heads, self.head_dim), jnp.float32))
 
     @property
     def state_flops_per_row(self) -> int:
@@ -598,7 +646,8 @@ class KimiDeltaAttention(Module):
         state = (jnp.zeros((b, h, d, d), jnp.float32)
                  if st is None else st["state"])
         out, state = _recur(state, q, k, v, g, beta,
-                            kernel=takes_step_kernel(st, t))
+                            kernel=(takes_step_kernel(st, t)
+                                    or takes_scan_kernel(st, t, g)))
         if st is not None:
             ctx.put_state(self._path, dict(
                 st, state=state, index=jnp.asarray(st["index"]) + t,

@@ -671,6 +671,11 @@ class SlotEngine:
         # the prefills counted by it
         self._prefill_attn_facts: dict = {}
         self._prefill_attn = self._fresh_prefill_attn()
+        # and whether a bucket's program computes every recurrent layer's
+        # scan with the Pallas kernel (asked the same way), with the
+        # prefills counted by it
+        self._prefill_scan_kernel: dict = {}
+        self._prefill_scan = self._fresh_prefill_scan()
 
         self._build_programs()
 
@@ -846,6 +851,7 @@ class SlotEngine:
             key = seed_key(req.seed)
         with span("prefill.dispatch", bucket=int(staged.shape[0]), **ids):
             self._count_prefill_attn(int(staged.shape[0]), len(req.prompt))
+            self._count_prefill_scan(int(staged.shape[0]))
             tok_dev, self.cache, self._moe["prefill"], self._slots = \
                 self._prefill(
                     self.params, self.cache, self._moe["prefill"],
@@ -1156,6 +1162,7 @@ class SlotEngine:
         self._need_rows = self._need_positions = 0
         self._residual_rows = self._fresh_residual_rows()
         self._prefill_attn = self._fresh_prefill_attn()
+        self._prefill_scan = self._fresh_prefill_scan()
         self._pipeline = self._fresh_pipeline()
         reset_phases(SERVE_PHASES)
         self._loop.reset()
@@ -1217,6 +1224,24 @@ class SlotEngine:
         count["kernel_prefills"] += int(facts["kernel"])
         count["pairs_needed"] += facts["heads"] * (n * (n + 1) // 2)
         count["pairs_executed"] += facts["pairs_executed"]
+
+    @staticmethod
+    def _fresh_prefill_scan() -> dict:
+        return {"prefills": 0, "kernel_prefills": 0}
+
+    def _count_prefill_scan(self, bucket: int) -> None:
+        """``stats()["prefill_scan"]``: one whole-prompt prefill in a
+        program of ``bucket`` positions.  ``prefills``; ``kernel_prefills``,
+        those whose program computes every recurrent layer's scan with
+        tpu_dist.ops.delta_scan (``model.prefill_scan_kernel``, asked once
+        a bucket: a model without such a layer reports 0).  Host arithmetic
+        inside ``prefill.dispatch``."""
+        kernel = self._prefill_scan_kernel.get(bucket)
+        if kernel is None:
+            kernel = self._prefill_scan_kernel[bucket] = \
+                self.model.prefill_scan_kernel(self.cache, bucket)
+        self._prefill_scan["prefills"] += 1
+        self._prefill_scan["kernel_prefills"] += int(kernel)
 
     def _moe_read(self) -> dict:
         """The routed-row counters per pool program, each stacked over the
@@ -1327,7 +1352,8 @@ class SlotEngine:
         model's ``slot_state_kernel``; 0 for a model without such a
         layer).  ``"decode_need"``: :meth:`_decode_need_stats`.
         ``"residual"``: :meth:`_residual_stats`.  ``"prefill_attn"``:
-        :meth:`_count_prefill_attn`.
+        :meth:`_count_prefill_attn`.  ``"prefill_scan"``:
+        :meth:`_count_prefill_scan`.
         ``"params"``: what :func:`place_params` did at construction;
         ``reset_stats()`` leaves it.  ``"loop"``: the loop thread's clock
         (:meth:`tpu_dist.obs.spans.LoopClock.stats`): every iteration the
@@ -1342,6 +1368,7 @@ class SlotEngine:
             "decode_need": self._decode_need_stats(since),
             "residual": self._residual_stats(),
             "prefill_attn": dict(self._prefill_attn),
+            "prefill_scan": dict(self._prefill_scan),
             "state": {"state_bytes": int(self._state_bytes),
                       "kv_bytes": int(self._kv_bytes),
                       "steps": self._state_steps,
