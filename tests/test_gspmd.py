@@ -10,9 +10,12 @@ from tpu_dist import nn, optim
 from tpu_dist.models import TransformerLM
 from tpu_dist.parallel.gspmd import (PartitionRules, TRANSFORMER_TP_RULES,
                                      make_gspmd_train_step, shard_pytree)
+from tpu_dist.parallel.rules import DEFAULT_RULES, partition_pairs
 
-# compile-heavy file: excluded from the fast tier (`pytest -m "not slow"`)
-pytestmark = pytest.mark.slow
+# a table that binds every logical axis to None: the same program on the
+# same mesh, every parameter whole on every device
+REPLICATE_RULES = PartitionRules(
+    partition_pairs({axis: None for axis in DEFAULT_RULES}))
 
 
 @pytest.fixture(scope="module")
@@ -59,10 +62,17 @@ class TestPartitionRules:
 
 
 class TestGspmdStep:
-    def test_tp_dp_matches_single_device(self, mesh2d):
+    @pytest.mark.parametrize("rules", [TRANSFORMER_TP_RULES, REPLICATE_RULES],
+                             ids=["tp_rules", "all_none_table"])
+    def test_tp_dp_matches_single_device(self, mesh2d, rules):
         vocab = 64
         model, x, y = _lm_and_batch(vocab=vocab)
         params = model.init(jax.random.key(0))
+        # the attention biases start at zero: give the row-parallel one a
+        # value, so that adding it once a shard would show in the loss
+        bias = params["block0.attn"]["out_bias"]
+        params["block0.attn"]["out_bias"] = bias + jnp.linspace(
+            -0.5, 0.5, bias.size)
         opt = optim.SGD(lr=0.1, momentum=0.9)
         opt_state = opt.init(params)
         loss_fn = _lm_loss(vocab)
@@ -72,9 +82,15 @@ class TestGspmdStep:
         rp, ro, rm = ref_step(params, opt_state, x, y)
 
         # sharded: params per TP rules, momentum mirrors params, batch on data
-        sp = shard_pytree(params, mesh2d, TRANSFORMER_TP_RULES)
-        so = {"momentum": shard_pytree(opt_state["momentum"], mesh2d,
-                                       TRANSFORMER_TP_RULES)}
+        sp = shard_pytree(params, mesh2d, rules)
+        so = {"momentum": shard_pytree(opt_state["momentum"], mesh2d, rules)}
+        sharded = {jax.tree_util.keystr(path) for path, leaf
+                   in jax.tree_util.tree_leaves_with_path(sp)
+                   if leaf.sharding.spec != P()}
+        if rules is REPLICATE_RULES:
+            assert not sharded
+        else:
+            assert "['block0.attn']['qkv_weight']" in sharded
         bsh = NamedSharding(mesh2d, P("data", None))
         sx, sy = jax.device_put(x, bsh), jax.device_put(y, bsh)
         step = make_gspmd_train_step(model, loss_fn, opt, donate=False)

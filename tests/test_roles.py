@@ -647,9 +647,14 @@ class TestSpawnGraphPolicy:
         g = RoleGraph([Role("lead", 1), Role("w", 2, restart="solo")])
         rc, runs = self._run(tmp_path, "gang-crash", g, max_restarts=1)
         assert rc == 0
-        # every rank ran in BOTH generations (fresh channel keyspace)
-        assert {r for r in runs if r.endswith("_i0")} == {
-            f"r{i}_g{gen}_i0" for i in range(3) for gen in (0, 1)}
+        # the lead's death in generation 0 restarted EVERY rank into
+        # generation 1 (fresh channel keyspace).  A worker the teardown
+        # killed before its first line left no generation-0 file: the
+        # launcher owes it none
+        ran = {r for r in runs if r.endswith("_i0")}
+        gen1 = {f"r{i}_g1_i0" for i in range(3)}
+        assert gen1 | {"r0_g0_i0"} <= ran \
+            <= gen1 | {f"r{i}_g0_i0" for i in range(3)}
 
     def test_budget_exhausted_returns_failing_rc(self, tmp_path):
         g = RoleGraph([Role("lead", 1)])

@@ -114,6 +114,38 @@ def test_spec_for_literals():
     assert _norm(spec_for_key("not-a-keystr")) == ()
 
 
+def test_row_parallel_biases_replicate_in_compiled_specs():
+    """The compiled half of ``LeafLayout.partial_axis``: where the weight
+    is cut along its rows (each device's matmul gives a partial sum), the
+    bias that follows is whole on every device, so XLA adds it once, after
+    the psum.  ``spans_for`` gives the same leaves to shard 0 alone."""
+    import jax
+    from jax.sharding import PartitionSpec as P
+    from tpu_dist.parallel.gspmd import TRANSFORMER_TP_RULES
+    model = _lm()
+    params = model.init(jax.random.PRNGKey(0))
+    specs = TRANSFORMER_TP_RULES.tree_specs(params)
+    axes = model_axes(model)
+    seen = set()
+    for path, leaf in params.items():
+        for name, arr in leaf.items():
+            lay = R.layout_for(path, name)
+            if lay is None or lay.partial_axis is None:
+                continue
+            seen.add((path, name))
+            weight = name.replace("bias", "weight")
+            assert DEFAULT_RULES[lay.partial_axis] == "model"
+            assert _norm(specs[path][weight]) == ("model",), (path, weight)
+            assert specs[path][name] == P(), (path, name)
+            assert spec_for(path, name) == P(), (path, name)
+            plans = [spans_for(path, name, arr.shape, axes, rank, 2)
+                     for rank in range(2)]
+            assert plans == [([(0, arr.size)], arr.shape), None]
+    assert seen == {leaf for i in range(2) for leaf in
+                    ((f"block{i}.attn", "out_bias"),
+                     (f"block{i}.mlp.2", "bias"))}
+
+
 def test_conflicting_dim_factors_raise():
     bad = dict(DEFAULT_RULES, qkv3="model", heads="model")
     # qkv3 and heads factor the SAME tensor dim of qkv_weight: one dim
@@ -212,8 +244,7 @@ def test_serving_spans_match_legacy(world):
                     _legacy_leaf_tag(path, name), arr.shape, dims,
                     rank, world)
                 plan = spans_for(path, name, arr.shape, axes, rank, world,
-                                 rules=SERVING_RULES, mesh_axis="shard",
-                                 partial="first")
+                                 rules=SERVING_RULES, mesh_axis="shard")
                 key = (world, rank, path, name)
                 if legacy is None:
                     assert plan is None, key
@@ -229,20 +260,6 @@ def test_serving_spans_match_legacy(world):
                 np.testing.assert_array_equal(shard_leaf(arr, plan), want)
 
 
-def test_training_spans_replicate_partial_biases():
-    """dp x tp training's partial="replicate" policy: every rank holds the
-    row-parallel output biases in full (added once, post-all-reduce)."""
-    model = _lm()
-    axes = model_axes(model)
-    for rank in range(2):
-        for path, name, shape in [("block0.attn", "out_bias", (32,)),
-                                  ("block0.mlp.2", "bias", (32,))]:
-            plan = spans_for(path, name, shape, axes, rank, 2,
-                             rules=DEFAULT_RULES, mesh_axis="model",
-                             partial="replicate")
-            assert plan == ([(0, 32)], (32,))
-
-
 def test_spans_world1_are_identity():
     model = _lm()
     axes = model_axes(model)
@@ -251,8 +268,7 @@ def test_spans_world1_are_identity():
     for path, leaf in params.items():
         for name, arr in leaf.items():
             plan = spans_for(path, name, arr.shape, axes, 0, 1,
-                             rules=DEFAULT_RULES, mesh_axis="model",
-                             partial="replicate")
+                             rules=DEFAULT_RULES, mesh_axis="model")
             np.testing.assert_array_equal(shard_leaf(arr, plan), arr)
 
 

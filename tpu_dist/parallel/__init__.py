@@ -17,8 +17,6 @@ from .rules import (DEFAULT_RULES, SERVING_RULES, LeafLayout,
                     chunk_span, layout_for, mapped_axes, model_axes,
                     partition_pairs, shard_leaf, spans_for, spec_for,
                     spec_for_key)
-from .tensor import (SerialTPRunner, TPConfigError, TPTrainer,
-                     build_tp_stage_fns, tp_shard_params)
 from .zero import ZeroOptimizer, ZeroParams, ZeroStateError
 
 # torch-style alias (the reference imports nn.parallel.DistributedDataParallel)
@@ -36,7 +34,5 @@ __all__ = ["DistributedDataParallel", "DDP", "TrainState",
            "chunk_span", "layout_for", "mapped_axes", "model_axes",
            "partition_pairs", "shard_leaf", "spans_for", "spec_for",
            "spec_for_key",
-           "TPTrainer", "SerialTPRunner", "TPConfigError",
-           "tp_shard_params", "build_tp_stage_fns",
            "ring_self_attention", "ulysses_self_attention",
            "ZeroOptimizer", "ZeroParams", "ZeroStateError"]

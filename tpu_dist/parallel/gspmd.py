@@ -65,9 +65,8 @@ class PartitionRules:
 # - embeddings and LM head sharded on the vocab/feature dimension.
 # Derived from the unified rule plane (parallel/rules.py): the same
 # DEFAULT_RULES + layout table that drives ZeRO shards, reshard
-# manifests, serving spans, and host dp×tp training produces these
-# specs, so the compiled mesh program and the eager host twin cannot
-# drift (golden-pinned to the pre-refactor literals in tests/test_rules).
+# manifests and serving spans produces these specs (golden-pinned to
+# the pre-refactor literals in tests/test_rules).
 TRANSFORMER_TP_RULES = PartitionRules(_rules.partition_pairs())
 
 # Expert parallelism over an 'expert' mesh axis: every stacked MoE leaf

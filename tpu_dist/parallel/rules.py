@@ -16,15 +16,13 @@ redistribution formulation of arXiv 2112.01075:
 * consumers bind the two:
   - :func:`spec_for` / :func:`partition_pairs` → ``PartitionSpec`` trees
     for pjit (``parallel/gspmd.py``, ``parallel/fsdp.py``);
-  - :func:`spans_for` → contiguous flat element spans for host-path
-    sharding (``serve/sharded.py`` shard slicing and checkpoint
-    range-reads, ``parallel/tensor.py`` dp×tp training);
+  - :func:`spans_for` → contiguous flat element spans for the serving
+    shards (``serve/sharded.py`` shard slicing and checkpoint
+    range-reads);
   - :func:`chunk_bounds` / :func:`chunk_span` → the flat ZeRO/reshard
     chunk contract (``parallel/zero.py``, ``resilience/reshard.py``).
 
-Changing only the rule table re-partitions every consumer coherently; the
-eager host collectives are the debuggable twin of the compiled mesh
-program (verified bitwise in benchmarks/bench_mesh_rules.py --smoke).
+Changing only the rule table re-partitions every consumer coherently.
 
 Everything here is pure layout arithmetic over numpy/ints — jax is
 imported lazily and only when PartitionSpecs are requested, so the host
@@ -108,11 +106,9 @@ class LeafLayout:
     ``partial_axis``: set on row-parallel output biases (attn out_bias,
     mlp down bias).  When the named axis is sharded, the matmul feeding
     this bias produces rank-partial sums; the bias must be added exactly
-    once after the combine.  Consumers choose the policy via
-    ``spans_for(..., partial=...)``: serving keeps the shard-0-owns-it
-    convention, dp×tp training replicates it and adds it post-all-reduce
-    (the order XLA's psum+bias takes, which is what keeps the eager twin
-    bitwise against pjit)."""
+    once.  :func:`spans_for` gives it to shard 0 alone (serving's
+    convention: the combine's sum then holds it once); the pjit specs
+    replicate it and XLA adds it after the psum."""
 
     dims: Tuple[Tuple[str, ...], ...]
     partial_axis: Optional[str] = None
@@ -229,7 +225,7 @@ def partition_pairs(rules: Dict[str, Optional[str]] = None,
 
 
 # ---------------------------------------------------------------------------
-# host-path spans (eager twin of the specs above)
+# host-path spans (the serving shards' slices)
 # ---------------------------------------------------------------------------
 
 def _find_sharded(lay: LeafLayout, rules: Dict[str, Optional[str]],
@@ -254,23 +250,18 @@ def _full(shape: Tuple[int, ...]):
 def spans_for(path: str, name: str, shape: Tuple[int, ...],
               axes: Dict[str, int], rank: int, world: int,
               rules: Dict[str, Optional[str]] = None,
-              mesh_axis: str = "model", partial: str = "first",
+              mesh_axis: str = "model",
               table: Sequence[Tuple[str, str, LeafLayout]] = None
               ) -> Optional[Tuple[List[Tuple[int, int]], Tuple[int, ...]]]:
     """``(contiguous flat element spans, local shape)`` of shard ``rank``'s
     slice of a parameter, or None when this rank holds nothing (a
-    partial-sum bias under the ``partial="first"`` policy on rank > 0).
+    partial-sum bias whose controlling axis is sharded, on rank > 0:
+    rank 0 owns the full bias, so the combine's sum holds it once).
 
     ``axes`` gives the logical axis sizes (:func:`model_axes`).  Every
     span is contiguous in the flat row-major layout — what lets both
     in-memory slicing and checkpoint range-reads assemble identical
-    shards (serve/sharded.py's contract, now generalized).
-
-    ``partial``: policy for partial-sum biases when their controlling
-    axis is sharded — ``"first"`` = rank 0 owns the full bias (serving's
-    pre-reduce convention), ``"replicate"`` = every rank holds it and the
-    consumer adds it once after the combine (training's post-reduce
-    order, bitwise-matching XLA's psum+bias)."""
+    shards (serve/sharded.py's contract, now generalized)."""
     if rules is None:
         rules = DEFAULT_RULES
     lay = layout_for(path, name, table)
@@ -278,8 +269,6 @@ def spans_for(path: str, name: str, shape: Tuple[int, ...],
         return _full(shape)
     if lay.partial_axis is not None and rules.get(lay.partial_axis) \
             == mesh_axis and world > 1:
-        if partial == "replicate":
-            return _full(shape)
         return _full(shape) if rank == 0 else None
     sh = _find_sharded(lay, rules, mesh_axis)
     if sh is None:

@@ -168,8 +168,7 @@ def _leaf_plan(path: str, name: str, shape: Tuple[int, ...], dims: dict,
     try:
         return _shard_rules.spans_for(
             path, name, shape, axes, rank, world,
-            rules=_shard_rules.SERVING_RULES, mesh_axis="shard",
-            partial="first")
+            rules=_shard_rules.SERVING_RULES, mesh_axis="shard")
     except _shard_rules.ShardLayoutError as e:
         raise ShardConfigError(str(e)) from e
 
