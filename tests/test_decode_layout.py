@@ -600,3 +600,66 @@ def test_qwen3_next_prefill_holds_one_delta_scan_call_a_recurrent_layer(
     assert calls == 0 and shapes == ["f32[1,32,64,64,128]",
                                     "f32[1,32,64,64,64]"]
     assert temp < dense_temp, (temp, dense_temp)
+
+
+# -- K/V columns and a whole state in EVERY layer (ISSUE 44) --------------------
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_into_slot"])
+def test_falcon_h1_pool_programs_compile_at_the_cells_shapes(
+        one_chip, no_compile_cache, program, capsys):
+    """Falcon-H1's layer at the published widths, two layers (each a
+    grouped-query attention of 20 heads over 4 K/V heads of 128 AND a Mamba-2
+    mixer of 32 heads of 128 over a state of 256, then the MLP of 21,504;
+    every multiplier as published; vocabulary cut), at the cell's 80 slots x
+    1,024 and its 256 bucket: the chip's compiler takes both pool programs
+    and ``memory_analysis()`` is reported.  Neither holds a Mosaic call (no
+    kernel is this model's yet: grouped queries decode dense, the state's
+    update and scan are ``jax.numpy``), and the decode step's update of the
+    336 MB float32 state of a layer is ONE fusion that reads the donated
+    pool's leaf and yields the new state and the output together: read
+    once, written once, in place, never copied."""
+    from tpu_dist.models import FalconH1LM
+    slots = 80
+    model = FalconH1LM(
+        VOCAB, dim=5120, depth=2, num_heads=20, num_kv_heads=4, head_dim=128,
+        mlp_hidden=21504, mamba_heads=32, mamba_head_dim=128,
+        mamba_state_dim=256, mamba_groups=2, embedding_multiplier=5.656854,
+        lm_head_multiplier=0.0078125, attention_out_multiplier=0.0375,
+        key_multiplier=0.0110485, ssm_in_multiplier=0.25,
+        ssm_multipliers=(0.3535534, 0.25, 0.1767767, 0.5, 0.3535534),
+        ssm_out_multiplier=0.0883883, mlp_multipliers=(0.1767767, 0.0111607),
+        max_seq_len=MAX_LEN)
+    pool = _shapes(jax.eval_shape(
+        lambda: model.init_slot_cache(slots, MAX_LEN, jnp.bfloat16)),
+        one_chip)
+    assert pool["block1.attn.ssm"]["state"].shape == (slots, 32, 128, 256)
+    assert pool["block1.attn.attention"]["k"].shape == (slots, 4, 128,
+                                                        MAX_LEN)
+    with nn.attention_impl("flash"):        # as a TPU backend would choose
+        assert model.slot_decode_kernel(pool) is False
+        assert model.slot_state_kernel(pool) is False
+        assert model.prefill_scan_kernel(pool, 256) is False
+        compiled = _lower(model, program, _param_shapes(model, one_chip),
+                          pool, {}, one_chip, slots=slots,
+                          bucket=256).compile()
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\n[falcon-h1, 2 of 9 layers, {slots} slots] {program}: "
+              f"arguments {memory.argument_size_in_bytes / 2**30:.3f} GiB, "
+              f"temporaries {memory.temp_size_in_bytes / 2**20:.1f} MiB")
+    assert "tpu_custom_call" not in text
+    state = re.escape(f"f32[{slots},32,128,256]")
+    assert not [line for line in text.splitlines()
+                if re.search(r"= [^=]*%s[^=]* copy\(" % state, line)]
+    assert not _pool_sized_results(text, ("copy",),
+                                   elements=slots * MAX_LEN * 4 * 128)
+    assert memory.temp_size_in_bytes < 128 << 20
+    if program == "decode_step":
+        # whatever yields an array of the state's shape is a fusion that
+        # yields the layer's output with it: one a layer
+        updates = re.findall(r"= ([^=\n]*?%s[^=\n]*?) fusion\(([^\n]*?)\), "
+                             r"kind=" % state, text)
+        assert len(updates) == 2, updates
+        for layer, (result, operands) in enumerate(updates):
+            assert re.search(r"f32\[%d,32,128\]\{" % slots, result)
+            assert f"%cache__block{layer}_attn_ssm____state__" in operands

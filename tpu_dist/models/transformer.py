@@ -48,7 +48,10 @@ class TransformerBlock(nn.Module):
         with a module built by the caller, as ``mlp`` overrides the MLP:
         e.g. an :class:`nn.GatedDeltaNet`, or an attention layer spelled
         beyond this constructor's flags.  A mixer that serves from a slot
-        cache has ``init_cache(batch, max_len, dtype)``.
+        cache has ``init_cache(batch, max_len, dtype)``; a composite of
+        several such (:class:`nn.ParallelMixer`: one norm, mixers side by
+        side, outputs summed into the residual) has ``mixers()``, the parts
+        that each own a cache entry.
 
         ``residual`` is how a sublayer's output joins the residual: None is
         ``x + f(x)``; a callable builds the joining module of ONE sublayer
@@ -164,13 +167,20 @@ class TransformerLM(nn.Module):
 
     def _assemble(self, vocab_size: int, dim: int, max_seq_len: int, blocks,
                   ln_f, head, learned_pos: bool, causal: bool = True,
-                  sequence_axis: Optional[str] = None, remat: bool = False):
+                  sequence_axis: Optional[str] = None, remat: bool = False,
+                  embedding_multiplier: float = 1.0,
+                  head_multiplier: float = 1.0):
         """Register the model's parts.  A model spelled beyond
         ``__init__``'s flags (models/qwen3_next.py) builds its own blocks,
         each a :class:`TransformerBlock` whose ``attn`` is any token mixer,
         and shares everything below: embedding, forward, the slot cache,
-        the pool programs' two methods and :meth:`generate`."""
+        the pool programs' two methods and :meth:`generate`.
+        ``embedding_multiplier`` scales the token embeddings and
+        ``head_multiplier`` the logits, constants of the program
+        (Falcon-H1's; 1 is no operation)."""
         self.vocab_size = vocab_size
+        self.embedding_multiplier = float(embedding_multiplier)
+        self.head_multiplier = float(head_multiplier)
         self.max_seq_len = max_seq_len
         self.tok = nn.Embedding(vocab_size, dim)
         self.pos = nn.Embedding(max_seq_len, dim) if learned_pos else None
@@ -193,8 +203,15 @@ class TransformerLM(nn.Module):
         self.head = head
 
     def _mixers(self):
-        """Each block's token mixer, in order."""
-        return [getattr(self, f"block{i}").attn for i in range(self.depth)]
+        """The token mixers that own a cache entry, in order: each block's
+        ``attn``, or, where that is a composite of several
+        (:class:`nn.ParallelMixer`), its ``mixers()`` in its order.  So a
+        layer of two mixers side by side is two entries here, and a method
+        that walks this list sees both."""
+        mixers = (getattr(self, f"block{i}").attn for i in range(self.depth))
+        return [part for mixer in mixers
+                for part in (mixer.mixers() if hasattr(mixer, "mixers")
+                             else [mixer])]
 
     def embed_tokens(self, idx, pos_offset=None):
         """Token (+ learned positional) embeddings for ``idx`` (B, T) —
@@ -215,9 +232,10 @@ class TransformerLM(nn.Module):
             # (B,) offsets index a (B, t) position table row per sequence
             pos_idx = (off[..., None] + jnp.arange(t) if off.ndim
                        else pos_offset + jnp.arange(t))
-            return self.tok(idx) + self.pos(pos_idx)
+            return nn.functional.scaled(self.tok(idx) + self.pos(pos_idx),
+                                        self.embedding_multiplier)
         # rope: positions enter through the attention rotations
-        return self.tok(idx)
+        return nn.functional.scaled(self.tok(idx), self.embedding_multiplier)
 
     def forward(self, idx, pos_offset=None):
         x = self.embed_tokens(idx, pos_offset)
@@ -247,7 +265,8 @@ class TransformerLM(nn.Module):
                 x = block(x)
         if self.streams > 1:
             x = nn.close_streams(x)
-        return self.head(self.ln_f(x))
+        return nn.functional.scaled(self.head(self.ln_f(x)),
+                                    self.head_multiplier)
 
     def residual_numbers_per_row(self) -> int:
         """Numbers of the compute type ONE row (a prompt token, a busy slot)
@@ -262,8 +281,9 @@ class TransformerLM(nn.Module):
                    if hc is not None)
 
     def _decoding(self) -> bool:
-        """True when the current apply() carries a KV cache for this model's
-        attention layers (i.e. we are inside prefill/decode)."""
+        """True when the current apply() carries a cache entry for any of
+        this model's cache-owning mixers (:meth:`_mixers`: either part of a
+        layer of two), i.e. we are inside prefill/decode."""
         from ..nn.module import current_context
         ctx = current_context()
         if ctx is None or not ctx.state:
@@ -287,8 +307,11 @@ class TransformerLM(nn.Module):
     def init_slot_cache(self, slots: int, max_len: Optional[int] = None,
                         dtype=jnp.float32):
         """Cache pool for slot-based continuous-batching decode, in the
-        format nn/cache.py owns: per token mixer, keyed by module path,
-        what the layer keeps per slot: an attention layer's ``k``/``v``
+        format nn/cache.py owns: per cache-owning mixer (:meth:`_mixers`;
+        a layer of two mixers side by side has two entries, one a part,
+        say ``block3.attn.attention`` with ``k``/``v`` and
+        ``block3.attn.ssm`` with ``state``/``conv``), keyed by module path,
+        what the mixer keeps per slot: an attention layer's ``k``/``v``
         ``(slots, Hkv, D, max_len)``, time last (the layout the TPU
         compiler keeps the pool in, written and read in place;
         :meth:`nn.MultiheadSelfAttention.init_cache`), a recurrent layer's
@@ -312,9 +335,10 @@ class TransformerLM(nn.Module):
 
     def slot_decode_kernel(self, cache) -> bool:
         """Whether a decode step over the pool ``cache`` takes a Pallas
-        decode-attention kernel in EVERY layer that keeps a time-indexed
-        pool, by head or latent (``nn.cache.pool_leaf``; each layer's own
-        answer, its ``takes_slot_kernel``)."""
+        decode-attention kernel in EVERY mixer that keeps a time-indexed
+        pool, by head or latent (``nn.cache.pool_leaf``; each mixer's own
+        answer, its ``takes_slot_kernel``; of a layer of two mixers, the
+        part that keeps the pool)."""
         return all(mixer.takes_slot_kernel(cache[mixer._path])
                    for mixer in self._mixers()
                    if nn.cache.pool_leaf(cache[mixer._path]) is not None)
@@ -324,8 +348,9 @@ class TransformerLM(nn.Module):
         recurrent layer's one-token update with the Pallas kernel
         (tpu_dist.ops.delta_step; each layer's own answer, its
         ``takes_step_kernel``, asked under the ``attention_impl`` the
-        program is traced under).  False for a model that keeps no whole
-        state.  A host fact for ``SlotEngine.stats()["state"]``."""
+        program is traced under; of a layer of two mixers, the part that
+        keeps the state).  False for a model that keeps no whole state.  A
+        host fact for ``SlotEngine.stats()["state"]``."""
         layers = [m for m in self._mixers() if hasattr(m, "takes_step_kernel")]
         return bool(layers) and all(m.takes_step_kernel(cache[m._path])
                                     for m in layers)
@@ -335,8 +360,9 @@ class TransformerLM(nn.Module):
         pool ``cache`` computes EVERY recurrent layer's scan with the Pallas
         kernel (tpu_dist.ops.delta_scan; each layer's own answer, its
         ``takes_scan_kernel``, asked under the ``attention_impl`` the
-        program is traced under).  False for a model that keeps no whole
-        state.  A host fact for ``SlotEngine.stats()["prefill_scan"]``."""
+        program is traced under; of a layer of two mixers, the part that
+        keeps the state).  False for a model that keeps no whole state.  A
+        host fact for ``SlotEngine.stats()["prefill_scan"]``."""
         layers = [m for m in self._mixers() if hasattr(m, "takes_scan_kernel")]
         return bool(layers) and all(
             m.takes_scan_kernel(cache[m._path], bucket) for m in layers)

@@ -6,11 +6,13 @@ from .attention import (MultiheadSelfAttention, attention_impl, rotary_embed,
                         scaled_dot_product_attention, yarn_inv_freq,
                         yarn_mscale)
 from .deltanet import GatedDeltaNet, KimiDeltaAttention
+from .hybrid import ParallelMixer
 from .hyper import HyperConnection, close_streams, open_streams
 from .layers import (AdaptiveAvgPool2d, AvgPool2d, BatchNorm2d, Conv2d,
                      Dropout, Embedding, Flatten, GELU, GatedMLP, Identity,
                      LayerNorm, Linear, MaxPool2d, ReLU, RMSNorm)
 from .loss import CrossEntropyLoss
+from .mamba import Mamba2
 from .mla import MultiheadLatentAttention
 from .moe import MoELayer
 from .module import Module, Remat, Sequential, run_capturing_state
@@ -25,7 +27,7 @@ __all__ = [
     "Embedding", "LayerNorm", "RMSNorm", "GELU", "GatedMLP",
     "MultiheadSelfAttention", "MultiheadLatentAttention",
     "scaled_dot_product_attention", "attention_impl", "GatedDeltaNet",
-    "KimiDeltaAttention",
+    "KimiDeltaAttention", "Mamba2", "ParallelMixer",
     "HyperConnection", "open_streams", "close_streams",
     "MoELayer", "rotary_embed", "yarn_inv_freq", "yarn_mscale",
     "CrossEntropyLoss",

@@ -230,6 +230,8 @@ class MultiheadSelfAttention(Module):
     projection, OLMoE's), and ``gated`` multiplies the attention's output
     by the sigmoid of a second, query-sized projection of the input before
     the output projection (Qwen3-Next's full-attention layer).
+    ``key_multiplier`` scales the keys' projection, a constant of the
+    program (Falcon-H1's; 1 is no operation).
     """
 
     def __init__(self, embed_dim: int, num_heads: int, bias: bool = True,
@@ -239,7 +241,8 @@ class MultiheadSelfAttention(Module):
                  qk_norm=False, qk_norm_eps: float = 1e-6,
                  num_kv_heads: Optional[int] = None,
                  head_dim: Optional[int] = None,
-                 rotary_dim: Optional[int] = None, gated: bool = False):
+                 rotary_dim: Optional[int] = None, gated: bool = False,
+                 key_multiplier: float = 1.0):
         super().__init__()
         if head_dim is None and embed_dim % num_heads:
             raise ValueError(f"embed_dim {embed_dim} not divisible by "
@@ -268,6 +271,7 @@ class MultiheadSelfAttention(Module):
         self.kv_dim = num_kv_heads * head_dim
         self.rotary_dim = rotary_dim
         self.gated = gated
+        self.key_multiplier = float(key_multiplier)
         self.bias = bias
         self.causal = causal
         self.sequence_axis = sequence_axis
@@ -334,6 +338,7 @@ class MultiheadSelfAttention(Module):
         else:
             qkv = self._qkv_proj(p, x).reshape(b, t, 3, self.embed_dim)
             q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+        k = F.scaled(k, self.key_multiplier)
         if self.qk_norm is True:
             q = F.rms_norm(q, p["q_norm_weight"], self.qk_norm_eps)
             k = F.rms_norm(k, p["k_norm_weight"], self.qk_norm_eps)

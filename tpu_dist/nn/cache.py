@@ -21,6 +21,13 @@ apart by name (:func:`is_timed`, :func:`pool_leaf`):
   joined along time, and the functions below that do so pass it through
   or refuse it, each as its docstring says.
 
+The tree is keyed by the path of the MIXER that owns the entry, not by
+layer: one layer may own entries of both kinds, where two mixers run side
+by side on one input (:class:`ParallelMixer`: ``block3.attn.attention`` with
+``k``/``v`` and ``block3.attn.ssm`` with ``state``/``conv``).  Nothing below
+reads a layer, so such a slot is served as one whose layers alternate the
+kinds.
+
 What a single call adds travels beside it and is put in and taken out HERE
 and nowhere else:
 

@@ -265,21 +265,40 @@ def _conv_tail(st, name: str, like):
     return st[name].reshape(like.shape).astype(like.dtype)
 
 
-def _causal_conv(x, tail, weight, valid):
+def _advanced(st, t: int, **leaves):
+    """The cache entry ``st`` after a call of ``t`` positions: ``leaves``
+    replaced entire and the write index moved on."""
+    return dict(st, index=jnp.asarray(st["index"]) + t, **leaves)
+
+
+def _causal_conv(x, tail, weight, valid, bias=None):
     """A causal depthwise convolution and SiLU over ``x`` (B, t, C), whose
     last ``K - 1`` inputs before this call are ``tail`` (B, K - 1, C);
-    ``weight`` (C, K), tap ``K - 1`` the current position's.  Returns the
+    ``weight`` (C, K), tap ``K - 1`` the current position's; ``bias`` (C,)
+    joins the taps' sum before the SiLU where the layer has one
+    (:class:`~tpu_dist.nn.Mamba2`).  Returns the
     activations and the tail after the call's LAST REAL position: rows ``[n,
     n + K - 1)`` of the window, ``n`` the call's count of real positions
     (``valid`` (B, t); they lead)."""
     taps, t = tail.shape[1], x.shape[1]
     window = jnp.concatenate([tail, x], axis=1)          # (B, K-1+t, C)
     w = weight.astype(x.dtype)
-    out = jax.nn.silu(sum(
-        window[:, j:j + t] * w[:, j] for j in range(taps + 1)))
+    mixed = sum(window[:, j:j + t] * w[:, j] for j in range(taps + 1))
+    if bias is not None:
+        mixed = mixed + bias.astype(x.dtype)
+    out = jax.nn.silu(mixed)
     n_real = valid.sum(-1).astype(jnp.int32)
     return out, jax.vmap(lambda win, n: lax.dynamic_slice_in_dim(
         win, n, taps, axis=0))(window, n_real)
+
+
+def _dt_bias(key, shape):
+    """The inverse softplus of a log-uniform step in [1e-3, 0.1]
+    (``softplus(dt_bias) = dt``): the published initialiser of a recurrent
+    layer's step, so that the decay spans short and long memories."""
+    dt = jnp.exp(jax.random.uniform(key, shape)
+                 * (jnp.log(0.1) - jnp.log(1e-3)) + jnp.log(1e-3))
+    return dt + jnp.log(-jnp.expm1(-dt))
 
 
 def takes_step_kernel(entry, t: int = 1) -> bool:
@@ -425,8 +444,6 @@ class GatedDeltaNet(Module):
     def create_params(self, key):
         ks = jax.random.split(key, 6)
         hv = self.num_v_heads
-        dt = jnp.exp(jax.random.uniform(ks[4], (hv,))
-                     * (jnp.log(0.1) - jnp.log(1e-3)) + jnp.log(1e-3))
         return {
             "qkvz_weight": I.torch_default_uniform(
                 ks[0], (self.dim, 2 * self.key_dim + 2 * self.value_dim),
@@ -437,8 +454,7 @@ class GatedDeltaNet(Module):
                 ks[2], (self.conv_dim, self.conv_kernel), self.conv_kernel),
             "A_log": jnp.log(jax.random.uniform(ks[3], (hv,), minval=1e-3,
                                                 maxval=16.0)),
-            # softplus(dt_bias) = dt
-            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+            "dt_bias": _dt_bias(ks[4], (hv,)),
             "norm_weight": jnp.ones((self.v_dim,)),
             "out_weight": I.torch_default_uniform(
                 ks[5], (self.value_dim, self.dim), self.value_dim),
@@ -492,8 +508,8 @@ class GatedDeltaNet(Module):
                             kernel=(takes_step_kernel(st, t)
                                     or takes_scan_kernel(st, t, g)))
         if st is not None:
-            ctx.put_state(self._path, dict(
-                st, state=state, index=jnp.asarray(st["index"]) + t,
+            ctx.put_state(self._path, _advanced(
+                st, t, state=state,
                 conv=new_tail.reshape(b, -1).astype(st["conv"].dtype)))
         with jax.named_scope("gate_norm"):
             z = z.reshape(b, t, hv, self.v_dim)
@@ -575,8 +591,6 @@ class KimiDeltaAttention(Module):
             k, (fan_in, fan_out), fan_in)
         conv = lambda k: I.torch_default_uniform(
             k, (width, self.conv_kernel), self.conv_kernel)
-        dt = jnp.exp(jax.random.uniform(ks[11], (width,))
-                     * (jnp.log(0.1) - jnp.log(1e-3)) + jnp.log(1e-3))
         return {
             "q_weight": lin(ks[0], self.dim, width),
             "k_weight": lin(ks[1], self.dim, width),
@@ -591,8 +605,7 @@ class KimiDeltaAttention(Module):
             "g_b_weight": lin(ks[10], d, width),
             "A_log": jnp.log(jax.random.uniform(ks[12], (h,), minval=1.0,
                                                 maxval=16.0)),
-            # softplus(dt_bias) = dt
-            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+            "dt_bias": _dt_bias(ks[11], (width,)),
             "norm_weight": jnp.ones((d,)),
             "out_weight": lin(ks[13], width, self.dim),
         }
@@ -649,8 +662,8 @@ class KimiDeltaAttention(Module):
                             kernel=(takes_step_kernel(st, t)
                                     or takes_scan_kernel(st, t, g)))
         if st is not None:
-            ctx.put_state(self._path, dict(
-                st, state=state, index=jnp.asarray(st["index"]) + t,
+            ctx.put_state(self._path, _advanced(
+                st, t, state=state,
                 **{name: tail.reshape(b, -1).astype(st[name].dtype)
                    for name, tail in tails.items()}))
         with jax.named_scope("gate_norm"):

@@ -17,7 +17,7 @@ from jax import lax
 __all__ = [
     "conv2d", "max_pool2d", "avg_pool2d", "relu", "linear", "dropout",
     "log_softmax", "softmax", "cross_entropy", "one_hot", "flatten",
-    "batch_norm", "rms_norm",
+    "batch_norm", "rms_norm", "scaled",
 ]
 
 _IntOr2 = Union[int, Tuple[int, int]]
@@ -105,6 +105,12 @@ def relu(x):
 def linear(x, w, b=None):
     """``x @ w + b`` with ``w`` shaped (in_features, out_features)."""
     return _bias_add(jnp.dot(x, w), b)
+
+
+def scaled(x, multiplier: float):
+    """``x`` times a constant of the program (a muP multiplier); 1 is no
+    operation, so a model without one traces as it did."""
+    return x if multiplier == 1.0 else x * multiplier
 
 
 def dropout(x, rate: float, key, training: bool = True):

@@ -361,13 +361,21 @@ class GELU(Module):
 
 class GatedMLP(Module):
     """SiLU-gated feed-forward without biases, ``down(silu(gate(x)) *
-    up(x))`` (SwiGLU; the LLaMA-family MLP), ``hidden`` wide."""
+    up(x))`` (SwiGLU; the LLaMA-family MLP), ``hidden`` wide.
+    ``gate_multiplier`` scales the gate's projection before the SiLU and
+    ``down_multiplier`` the output (Falcon-H1's ``mlp_multipliers``); 1 is
+    no operation."""
 
-    def __init__(self, dim: int, hidden: int):
+    def __init__(self, dim: int, hidden: int, gate_multiplier: float = 1.0,
+                 down_multiplier: float = 1.0):
         super().__init__()
         self.gate = Linear(dim, hidden, bias=False)
         self.up = Linear(dim, hidden, bias=False)
         self.down = Linear(hidden, dim, bias=False)
+        self.gate_multiplier = float(gate_multiplier)
+        self.down_multiplier = float(down_multiplier)
 
     def forward(self, x):
-        return self.down(jax.nn.silu(self.gate(x)) * self.up(x))
+        gate = F.scaled(self.gate(x), self.gate_multiplier)
+        return F.scaled(self.down(jax.nn.silu(gate) * self.up(x)),
+                        self.down_multiplier)

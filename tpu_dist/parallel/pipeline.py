@@ -147,6 +147,12 @@ class PipelineParallel:
             raise ValueError("pipeline parallelism microbatches over the "
                              "batch dim; build the model without "
                              "sequence_axis (pp x sp needs a 3-D mesh recipe)")
+        if (getattr(model, "embedding_multiplier", 1.0) != 1.0
+                or getattr(model, "head_multiplier", 1.0) != 1.0):
+            raise NotImplementedError(
+                "the pipeline's embedding and head stages apply no "
+                "embedding_multiplier / head_multiplier, and this model "
+                "has them")
         if schedule not in ("gpipe", "1f1b"):
             raise ValueError(f"schedule must be 'gpipe' or '1f1b', "
                              f"got {schedule!r}")

@@ -178,6 +178,13 @@ def _model_dims(model) -> dict:
     raising :class:`ShardConfigError` for shapes this layout cannot
     split."""
     import jax
+    attn = model.block0.attn
+    if hasattr(attn, "mixers"):
+        raise NotImplementedError(
+            f"sharded serving divides ONE attention mixer a block by head, "
+            f"and block0.attn is a {type(attn).__name__} of "
+            f"{[type(m).__name__ for m in attn.mixers()]}, side by side; a "
+            f"head-divided layer of several mixers is not built")
     # the per-block segments address one K/V pool entry a block
     pool = jax.eval_shape(lambda: model.init_slot_cache(1, 8))
     kvcache.require_timed(pool, "sharded serving")
@@ -193,7 +200,6 @@ def _model_dims(model) -> dict:
         raise ShardConfigError(
             "build the model without sequence_axis for serving (KV-cache "
             "decode runs on gathered sequences)")
-    attn = model.block0.attn
     up = model.block0.mlp[0]
     return {"dim": attn.embed_dim, "num_heads": attn.num_heads,
             "head_dim": attn.head_dim, "depth": model.depth,
