@@ -6,8 +6,9 @@ vocab 32768, T = 2048), every phase fatal:
 
   device    platform must be ``tpu``; versions, compile cache, store backend
   kernels   the Pallas kernels (flash attention, fused CE and the grouped
-            matmuls forward and backward, slot-decode attention by head
-            and over a latent, the recurrent state's one-token update),
+            matmuls forward and backward, slot-decode attention by head,
+            by groups of query heads and over a latent, the recurrent
+            state's one-token update),
             lowered by Mosaic at their full-width users' shapes, against
             plain ``jnp``
   convnet   the source paper's ConvNet through ``init_process_group`` +
@@ -283,12 +284,13 @@ def check_dropless_moe(tokens: int, dim: int, experts: int,
 
 
 def check_decode_attention(slots: int, heads: int, head_dim: int,
-                           max_len: int) -> None:
+                           max_len: int, group: int = 1) -> None:
     """The slot-decode kernel on a bf16 K/V pool ``(slots, heads, head_dim,
     max_len)`` with ragged lengths (free slots, lane and block edges, a
-    full row), against the float32 jnp composition of the same step: the
-    output of every busy slot, and the pools bit for bit (the new column
-    in, nothing else touched)."""
+    full row), ``group`` query heads a K/V head (1: the one-row form, more:
+    the grouped one), against the float32 jnp composition of the same step:
+    the output of every busy slot, and the pools bit for bit (the new
+    column in, nothing else touched)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -296,8 +298,10 @@ def check_decode_attention(slots: int, heads: int, head_dim: int,
     from tpu_dist.ops.decode_attention import decode_attention
 
     keys = jax.random.split(jax.random.key(6), 5)
-    q, kn, vn = (jax.random.normal(k, (slots, heads, head_dim), jnp.bfloat16)
-                 for k in keys[:3])
+    q = jax.random.normal(keys[0], (slots, heads * group, head_dim),
+                          jnp.bfloat16)
+    kn, vn = (jax.random.normal(k, (slots, heads, head_dim), jnp.bfloat16)
+              for k in keys[1:3])
     kp, vp = (jax.random.normal(k, (slots, heads, head_dim, max_len),
                                 jnp.bfloat16) for k in keys[3:])
     edges = [0, 1, 127, 128, 129, 255, 256, 257, max_len - 1, max_len, 0,
@@ -310,13 +314,14 @@ def check_decode_attention(slots: int, heads: int, head_dim: int,
         at = jnp.arange(max_len) == lens[:, None, None, None]
         kp = jnp.where(at, kn[..., None], kp)
         vp = jnp.where(at, vn[..., None], vp)
-        s = jnp.einsum("bhd,bhdt->bht", q.astype(jnp.float32),
-                       kp.astype(jnp.float32), precision=hi
-                       ) / math.sqrt(head_dim)
-        seen = jnp.arange(max_len) <= lens[:, None, None]
+        qg = q.astype(jnp.float32).reshape(slots, heads, group, head_dim)
+        s = jnp.einsum("bhgd,bhdt->bhgt", qg, kp.astype(jnp.float32),
+                       precision=hi) / math.sqrt(head_dim)
+        seen = jnp.arange(max_len) <= lens[:, None, None, None]
         w = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
-        return jnp.einsum("bht,bhdt->bhd", w, vp.astype(jnp.float32),
-                          precision=hi), kp, vp
+        out = jnp.einsum("bhgt,bhdt->bhgd", w, vp.astype(jnp.float32),
+                         precision=hi)
+        return out.reshape(q.shape), kp, vp
 
     out, k2, v2 = jax.jit(decode_attention)(q, kn, vn, kp, vp, lens)
     want, k_ref, v_ref = jax.jit(reference)(q, kn, vn, kp, vp, lens)
@@ -461,12 +466,14 @@ def check_delta_scan(seq: int, key_heads: int, value_heads: int, k_dim: int,
 
 
 def phase_kernels(flash: dict, ce: dict, moe: dict, decode: dict,
-                  latent: dict, state: dict, scan: dict) -> dict:
+                  grouped: dict, latent: dict, state: dict,
+                  scan: dict) -> dict:
     t0 = time.perf_counter()
     check_flash(**flash)
     check_fused_ce(**ce)
     check_dropless_moe(**moe)
     check_decode_attention(**decode)
+    check_decode_attention(**grouped)
     check_latent_decode_attention(**latent)
     check_delta_step(**state)
     check_delta_scan(**scan)
@@ -810,6 +817,7 @@ def main() -> int:
         ce=dict(rows=8 * 2048, vocab=32768),
         moe=dict(tokens=8 * 2048, dim=768, experts=8, top_k=2),
         decode=dict(slots=32, heads=25, head_dim=64, max_len=1024),
+        grouped=dict(slots=32, heads=4, head_dim=128, max_len=1024, group=5),
         latent=dict(slots=32, heads=64, latent=576, values=512,
                     max_len=1024),
         state=dict(slots=32, heads=32, k_dim=128, v_dim=128),

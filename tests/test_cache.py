@@ -361,7 +361,7 @@ def test_prefill_into_slot_of_a_hybrid_is_prefill_rows_then_the_write():
 def test_the_engine_mirrors_a_hybrid_pool_without_a_branch():
     """``SlotEngine`` asks nn/cache.py and the model, never a leaf's name:
     the state counters come from ``slot_bytes``, the kernel flag from the
-    model (grouped queries stay dense)."""
+    model (a CPU run stays dense)."""
     model = _hybrid()
     engine = serve.SlotEngine(model, model.init(jax.random.key(0)),
                               num_slots=2, max_len=64)

@@ -113,6 +113,14 @@ def test_the_scan_kernels_check_tiny(heads):
                                 value_heads=heads[1], k_dim=128, v_dim=128)
 
 
+@pytest.mark.parametrize("group", [1, 5], ids=["by-head", "grouped"])
+def test_the_slot_decode_kernels_check_tiny(group):
+    """``check_decode_attention`` as the chip runs it, both forms
+    (ISSUE 45), interpreted here at twelve slots of 512."""
+    chip_smoke.check_decode_attention(slots=12, heads=2, head_dim=64,
+                                      max_len=512, group=group)
+
+
 @pytest.mark.slow
 def test_kernel_convnet_and_multichip_phases_tiny():
     chip_smoke.phase_kernels(
@@ -120,6 +128,7 @@ def test_kernel_convnet_and_multichip_phases_tiny():
         ce=dict(rows=64, vocab=1000),
         moe=dict(tokens=256, dim=128, experts=4, top_k=2),
         decode=dict(slots=12, heads=2, head_dim=64, max_len=512),
+        grouped=dict(slots=12, heads=2, head_dim=64, max_len=512, group=5),
         latent=dict(slots=12, heads=4, latent=48, values=32, max_len=512),
         state=dict(slots=3, heads=2, k_dim=64, v_dim=128),
         scan=dict(seq=150, key_heads=1, value_heads=2, k_dim=128, v_dim=128))

@@ -6,6 +6,12 @@ with ``attention_impl("flash")``; the comparison is with the dense branch of
 layer, parameters and K/V pool.  What the chip's compiler makes of the
 kernel at the serving cells' shapes is ``tests/test_decode_layout.py``'s.
 
+Grouped queries (ISSUE 45): ``G`` query heads a K/V head take the kernel's
+grouped form, one copy of a K/V block for all ``G`` rows and two MXU products
+a head; ``G`` = 1 keeps the one-row VPU form.  Both stand against the same
+dense branch, whose grouped einsums have K/V head ``j`` serve query heads
+``[j * G, (j + 1) * G)``.
+
 Tolerances.  float32: the kernel and the dense branch both accumulate in
 float32 and differ only in summation order: 1e-5.  bfloat16: the kernel
 keeps float32 scores and statistics and rounds its output ONCE to bf16; the
@@ -28,10 +34,19 @@ TMAX = 512
 # free, first column, the 128-lane edges, the block (256) edges, the row's
 # last column, and a full row (the new column is dropped)
 LENGTHS = [0, 1, 127, 128, 129, 255, 256, 257, TMAX - 1, TMAX]
-SHAPES = {"5x64-float32": (5, 64, jnp.float32, 1e-5),
-          "4x128-float32": (4, 128, jnp.float32, 1e-5),
-          "5x64-bfloat16": (5, 64, jnp.bfloat16, 3e-2),
-          "4x128-bfloat16": (4, 128, jnp.bfloat16, 3e-2)}
+# query heads, K/V heads, head dim, the pool's (and the layer's) type, tolerance
+SHAPES = {"5x64-float32": (5, 5, 64, jnp.float32, 1e-5),
+          "4x128-float32": (4, 4, 128, jnp.float32, 1e-5),
+          "5x64-bfloat16": (5, 5, 64, jnp.bfloat16, 3e-2),
+          "4x128-bfloat16": (4, 4, 128, jnp.bfloat16, 3e-2),
+          "2x256-bfloat16": (2, 2, 256, jnp.bfloat16, 3e-2),
+          # G = 5 (Falcon-H1's 20 over 4) and G = 8 (Qwen3-Next's 16 over 2)
+          "10over2x128-float32": (10, 2, 128, jnp.float32, 1e-5),
+          "10over2x128-bfloat16": (10, 2, 128, jnp.bfloat16, 3e-2),
+          "8over1x256-float32": (8, 1, 256, jnp.float32, 1e-5),
+          "16over2x256-bfloat16": (16, 2, 256, jnp.bfloat16, 3e-2),
+          "5over1x256-bfloat16": (5, 1, 256, jnp.bfloat16, 3e-2),
+          "8over1x128-bfloat16": (8, 1, 128, jnp.bfloat16, 3e-2)}
 
 
 @pytest.fixture(scope="module", params=list(SHAPES))
@@ -39,8 +54,9 @@ def case(request):
     """One decode step of one attention layer over a random pool, through
     both branches: ``(lengths, pool before, dense (out, state), kernel
     (out, state), tolerance)``."""
-    heads, dim, dtype, tol = SHAPES[request.param]
-    attn = nn.MultiheadSelfAttention(heads * dim, heads, causal=True)
+    heads, kv_heads, dim, dtype, tol = SHAPES[request.param]
+    attn = nn.MultiheadSelfAttention(heads * dim, heads, causal=True,
+                                     num_kv_heads=kv_heads)
     keys = jax.random.split(jax.random.key(7), 4)
     params = jax.tree_util.tree_map(lambda a: a.astype(dtype),
                                     attn.init(keys[0]))
@@ -92,6 +108,9 @@ def test_a_column_at_tmax_is_dropped(case):
 
 
 def test_a_free_slot_is_untouched_and_its_output_finite(case):
+    """The kernel's own row for a free slot is zero
+    (``test_free_slots_anywhere_in_the_pool``); here, after the output
+    projection, it is the projection's bias: finite."""
     _, pool, _, (out, kernel), _ = case
     b = LENGTHS.index(0)
     assert np.isfinite(out).all()
@@ -106,15 +125,18 @@ def test_index_advances_by_one(case):
     np.testing.assert_array_equal(kernel["index"], dense["index"])
 
 
+@pytest.mark.parametrize("group", [1, 5], ids=["G1", "G5"])
 @pytest.mark.parametrize("lengths", [[0, 0, 0, 0], [0, 0, 9, 0],
                                      [300, 0, 0, 1]],
                          ids=["all-free", "leading-free", "trailing-free"])
-def test_free_slots_anywhere_in_the_pool(lengths):
+def test_free_slots_anywhere_in_the_pool(lengths, group):
     """The work list holds busy slots only: a pool with none, with free
-    slots before the first busy one and after the last."""
+    slots before the first busy one and after the last.  A free slot's
+    pool row is untouched and its output row zero, in both forms."""
     h, d, t = 2, 64, 512
     keys = jax.random.split(jax.random.key(3), 5)
-    q, kn, vn = (jax.random.normal(k, (4, h, d)) for k in keys[:3])
+    q = jax.random.normal(keys[0], (4, h * group, d))
+    kn, vn = (jax.random.normal(k, (4, h, d)) for k in keys[1:3])
     kp, vp = (jax.random.normal(k, (4, h, d, t)) for k in keys[3:])
     lens = jnp.asarray(lengths, jnp.int32)
     with nn.attention_impl("flash"):
@@ -124,11 +146,13 @@ def test_free_slots_anywhere_in_the_pool(lengths):
         want_k, want_v = np.array(kp[b]), np.array(vp[b])
         if n:
             want_k[..., n], want_v[..., n] = kn[b], vn[b]
-            s = np.einsum("hd,hdt->ht", q[b], want_k[..., :n + 1]) / 8.0
+            s = np.einsum("hgd,hdt->hgt", np.reshape(q[b], (h, group, d)),
+                          want_k[..., :n + 1]) / 8.0
             w = np.exp(s - s.max(-1, keepdims=True))
-            want = np.einsum("ht,hdt->hd", w / w.sum(-1, keepdims=True),
+            want = np.einsum("hgt,hdt->hgd", w / w.sum(-1, keepdims=True),
                              want_v[..., :n + 1])
-            np.testing.assert_allclose(out[b], want, atol=1e-5)
+            np.testing.assert_allclose(out[b], want.reshape(h * group, d),
+                                       atol=1e-5)
         else:
             assert not np.asarray(out[b]).any()
         np.testing.assert_array_equal(k2[b], want_k)
@@ -159,15 +183,25 @@ def test_kv_blocks_counts_what_the_work_list_holds(lengths, want):
     ("int8 cache", (4,), 1, jnp.int8, 64, 256, "flash", False),
     ("D off the sublane tile", (4,), 1, jnp.bfloat16, 8, 256, "flash", False),
     ("Tmax off the lanes", (4,), 1, jnp.float32, 64, 192, "flash", False),
+    ("grouped queries, forced", (4,), 1, jnp.bfloat16, 64, 256, "flash",
+     True),
+    ("grouped queries on a CPU run", (4,), 1, jnp.bfloat16, 64, 256, None,
+     False),
+    ("grouped queries forced dense", (4,), 1, jnp.bfloat16, 64, 256, "dense",
+     False),
+    ("grouped queries prefill", (), 8, jnp.bfloat16, 64, 256, "flash", False),
 ], ids=lambda v: v.replace(" ", "-") if isinstance(v, str) and " " in v
     else None)
 def test_which_branch_decode_takes(why, index, t, dtype, dim, tmax, impl,
                                    taken):
     """What ``_decode`` observes picks the branch; the traced program holds
-    the kernel's call or does not."""
+    the kernel's call or does not.  Six query heads over two K/V heads where
+    the row says grouped, two over two elsewhere."""
     import contextlib
-    heads, b = 2, 4
-    attn = nn.MultiheadSelfAttention(heads * dim, heads, causal=True)
+    kv_heads, b = 2, 4
+    heads = 6 if why.startswith("grouped") else kv_heads
+    attn = nn.MultiheadSelfAttention(heads * dim, heads, causal=True,
+                                     num_kv_heads=kv_heads)
     params = attn.init(jax.random.key(0))
     state = {attn._path: dict(attn.init_cache(b, tmax, dtype),
                               index=jnp.zeros(index, jnp.int32) + 3)}

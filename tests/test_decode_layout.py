@@ -606,18 +606,22 @@ def test_qwen3_next_prefill_holds_one_delta_scan_call_a_recurrent_layer(
 
 @pytest.mark.parametrize("program", ["decode_step", "prefill_into_slot"])
 def test_falcon_h1_pool_programs_compile_at_the_cells_shapes(
-        one_chip, no_compile_cache, program, capsys):
+        one_chip, no_compile_cache, mosaic_decode_attention, program, capsys):
     """Falcon-H1's layer at the published widths, two layers (each a
     grouped-query attention of 20 heads over 4 K/V heads of 128 AND a Mamba-2
     mixer of 32 heads of 128 over a state of 256, then the MLP of 21,504;
     every multiplier as published; vocabulary cut), at the cell's 80 slots x
     1,024 and its 256 bucket: the chip's compiler takes both pool programs
-    and ``memory_analysis()`` is reported.  Neither holds a Mosaic call (no
-    kernel is this model's yet: grouped queries decode dense, the state's
-    update and scan are ``jax.numpy``), and the decode step's update of the
-    336 MB float32 state of a layer is ONE fusion that reads the donated
-    pool's leaf and yields the new state and the output together: read
-    once, written once, in place, never copied."""
+    and ``memory_analysis()`` is reported.  Since ISSUE 45 the decode step
+    holds ONE ``decode_attention`` Mosaic call a layer (the grouped form:
+    ``G`` = 5 read from the shapes), whose aliased pools are the only
+    pool-sized results (no ``copy``, no ``select`` over ``bf16[80,4,128,
+    1024]``: the dense branch rewrote the pool through one); the prefill
+    holds none, and no other kernel is this model's (the state's update and
+    scan are ``jax.numpy``).  The decode step's update of the 336 MB float32
+    state of a layer is ONE fusion that reads the donated pool's leaf and
+    yields the new state and the output together: read once, written once,
+    in place, never copied."""
     from tpu_dist.models import FalconH1LM
     slots = 80
     model = FalconH1LM(
@@ -636,7 +640,7 @@ def test_falcon_h1_pool_programs_compile_at_the_cells_shapes(
     assert pool["block1.attn.attention"]["k"].shape == (slots, 4, 128,
                                                         MAX_LEN)
     with nn.attention_impl("flash"):        # as a TPU backend would choose
-        assert model.slot_decode_kernel(pool) is False
+        assert model.slot_decode_kernel(pool) is True
         assert model.slot_state_kernel(pool) is False
         assert model.prefill_scan_kernel(pool, 256) is False
         compiled = _lower(model, program, _param_shapes(model, one_chip),
@@ -647,11 +651,14 @@ def test_falcon_h1_pool_programs_compile_at_the_cells_shapes(
         print(f"\n[falcon-h1, 2 of 9 layers, {slots} slots] {program}: "
               f"arguments {memory.argument_size_in_bytes / 2**30:.3f} GiB, "
               f"temporaries {memory.temp_size_in_bytes / 2**20:.1f} MiB")
-    assert "tpu_custom_call" not in text
+    calls = re.findall(r"%([\w-]+?)[.\d]* = [^\n]*"
+                       r"custom_call_target=\"tpu_custom_call\"", text)
+    assert calls == (["decode_attention"] * 2 if program == "decode_step"
+                     else []), calls
     state = re.escape(f"f32[{slots},32,128,256]")
     assert not [line for line in text.splitlines()
                 if re.search(r"= [^=]*%s[^=]* copy\(" % state, line)]
-    assert not _pool_sized_results(text, ("copy",),
+    assert not _pool_sized_results(text, ("copy", "select"),
                                    elements=slots * MAX_LEN * 4 * 128)
     assert memory.temp_size_in_bytes < 128 << 20
     if program == "decode_step":
@@ -663,3 +670,43 @@ def test_falcon_h1_pool_programs_compile_at_the_cells_shapes(
         for layer, (result, operands) in enumerate(updates):
             assert re.search(r"f32\[%d,32,128\]\{" % slots, result)
             assert f"%cache__block{layer}_attn_ssm____state__" in operands
+
+
+def test_qwen3_next_decode_step_holds_one_grouped_call_a_full_attention_layer(
+        one_chip, no_compile_cache, mosaic_gmm, mosaic_decode_attention,
+        mosaic_delta_step, capsys):
+    """The twin (ISSUE 45): Qwen3-Next's block at the published widths, one
+    period of the layer pattern (three Gated DeltaNet layers, one gated
+    full-attention layer of 16 query heads over 2 K/V heads of 256: ``G`` =
+    8, ``D`` = 256; 64 held of 512 experts, vocabulary cut), at the cell's
+    96 slots x 4,096: the chip's compiler takes the decode program with one
+    ``decode_attention`` call for the full-attention layer beside the three
+    ``delta_step`` calls, no pool-sized ``copy`` or ``select`` over the 2.4
+    GB pool's leaves, and ``memory_analysis()`` is reported."""
+    from tpu_dist.models import Qwen3NextLM
+    slots, max_len = 96, 4096
+    model = Qwen3NextLM(
+        VOCAB, dim=2048, depth=4, num_heads=16, num_kv_heads=2, head_dim=256,
+        num_experts=512, experts_held=64, moe_top_k=10, moe_hidden=512,
+        shared_hidden=512, max_seq_len=max_len)
+    pool = _shapes(jax.eval_shape(
+        lambda: model.init_slot_cache(slots, max_len, jnp.bfloat16)),
+        one_chip)
+    assert pool["block3.attn"]["k"].shape == (slots, 2, 256, max_len)
+    counters = _shapes(jax.eval_shape(model.init_moe_counters), one_chip)
+    with nn.attention_impl("flash"):
+        assert model.slot_decode_kernel(pool) is True
+        compiled = _lower(model, "decode_step",
+                          _param_shapes(model, one_chip), pool, counters,
+                          one_chip, slots=slots, bucket=max_len).compile()
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\n[qwen3-next, 4 of 12 layers, {slots} slots] decode_step: "
+              f"arguments {memory.argument_size_in_bytes / 2**30:.3f} GiB, "
+              f"temporaries {memory.temp_size_in_bytes / 2**20:.1f} MiB")
+    calls = re.findall(r"%(decode_attention|delta_step)[.\d]* = [^\n]*"
+                       r"custom_call_target=\"tpu_custom_call\"", text)
+    assert sorted(calls) == ["decode_attention"] + ["delta_step"] * 3, calls
+    assert not _pool_sized_results(text, ("copy", "select"),
+                                   elements=slots * max_len * 2 * 256)
+    assert memory.temp_size_in_bytes < 128 << 20

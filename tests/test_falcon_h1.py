@@ -382,7 +382,7 @@ def test_a_slot_holds_columns_and_a_whole_state_for_each_layer(program):
     template = nn.cache.token_template(pool)
     assert template["block0.attn.attention"]["k"] == ((2, 8), np.float32)
     assert template["block0.attn.ssm"]["state"] == ((4, 8, 16), np.float32)
-    assert model.slot_decode_kernel(pool) is False      # grouped queries
+    assert model.slot_decode_kernel(pool) is False      # a CPU run
     assert model.slot_state_kernel(pool) is False       # no such kernel
     assert model.prefill_scan_kernel(pool, 64) is False
     assert model.prefill_attention_facts(64) == {
@@ -555,13 +555,81 @@ def test_slot_engine_serves_the_reference_tokens_and_counts_by_hand(program):
     assert dn["weight_bytes"] == 8 * 4 * fixed
     assert dn["flops"] == (2 * fixed * 16 + 3 * 2 * 10 * 2 * 8 * positions
                            + 3 * 5 * 4 * 8 * 16 * 16)
-    # the dense grouped-query branch reads the whole pool: one block of 128
+    # a CPU run's dense branch reads the whole pool: one block of 128
     # columns a slot a step, where the two busy slots held one each
     assert st["decode_attn"] == {"kv_blocks_read": 16, "kv_blocks_pool": 24,
                                  "steps": 8, "block": 128, "kernel": False}
     assert st["prefill_scan"] == {"prefills": 2, "kernel_prefills": 0}
     assert st["prefill_attn"]["kernel_prefills"] == 0
     assert "moe" not in st
+
+
+def _steps_on(model, params, impl, prompts, steps=4):
+    """Under ``impl``: ``prompts`` prefilled into slots 0 and 2 of a pool of
+    three (slot 1 stays FREE), then ``steps`` greedy decode steps through a
+    program traced afresh (a trace is cached by the function traced).
+    Returns the busy rows' logits a step and the pool before and after."""
+    with nn.attention_impl(impl):
+        pool = _pool(model, slots=3, max_len=128)
+        prefill = jax.jit(lambda *a: model.prefill_into_slot(*a))
+        decode = jax.jit(lambda *a: model.decode_step(*a))
+        tokens, lengths = np.zeros(3, np.int32), np.zeros(3, np.int32)
+        for slot, prompt in zip((0, 2), prompts):
+            padded = np.full(64, 5, np.int32)
+            padded[:len(prompt)] = prompt
+            row, pool, _ = prefill(params, padded, len(prompt), slot, pool)
+            tokens[slot], lengths[slot] = int(np.argmax(row)), len(prompt)
+        before, rows = jax.tree.map(np.asarray, pool), []
+        for _ in range(steps):
+            logits, pool, _ = decode(params, tokens, lengths, pool)
+            rows.append(np.asarray(logits)[[0, 2]])
+            tokens[[0, 2]] = np.argmax(rows[-1], axis=-1)
+            lengths[[0, 2]] += 1
+    return np.stack(rows), before, jax.tree.map(np.asarray, pool)
+
+
+def test_the_grouped_kernel_serves_the_dense_branchs_tokens_and_logits(
+        program):
+    """ISSUE 45: ten query heads over two K/V heads through the grouped
+    slot-decode kernel (``attention_impl("flash")``, interpreted), one slot
+    free: the dense branch's logits step by step, the engine's tokens, the
+    free slot's K/V rows untouched, and ``stats()["decode_attn"]`` saying
+    which branch the decode program was built on."""
+    model, params = program
+    rng = np.random.default_rng(8)
+    prompts = [rng.integers(0, CFG["vocab_size"], n) for n in (21, 50)]
+    with jax.default_matmul_precision("highest"):
+        want, _, _ = _steps_on(model, params, "dense", prompts)
+        got, before, after = _steps_on(model, params, "flash", prompts)
+    np.testing.assert_allclose(got, want, rtol=0, atol=ATOL)
+    np.testing.assert_array_equal(got.argmax(-1), want.argmax(-1))
+    for i in range(3):
+        entry = f"block{i}.attn.attention"
+        for name in ("k", "v"):
+            np.testing.assert_array_equal(after[entry][name][1],
+                                          before[entry][name][1])
+            assert not np.array_equal(after[entry][name][0],
+                                      before[entry][name][0])
+    served = {}
+    for impl in ("dense", "flash"):
+        toks = {0: [], 1: []}
+        with nn.attention_impl(impl):
+            engine = serve.SlotEngine(model, params, num_slots=3,
+                                      max_len=128, min_bucket=32)
+            for i, prompt in enumerate(prompts):
+                engine.launch_admit(serve.Request(
+                    prompt, 5,
+                    on_token=lambda _, tok, i=i: toks[i].append(tok)))
+                engine.settle()
+            while not engine.idle():
+                if engine.launch_step():
+                    engine.settle()
+                else:
+                    engine.collect_all()
+        served[impl] = toks, engine.stats()["decode_attn"]
+    assert served["flash"][0] == served["dense"][0]
+    assert served["flash"][1] == dict(served["dense"][1], kernel=True)
+    assert served["dense"][1]["kernel"] is False
 
 
 def _rows(model, length=8):

@@ -338,7 +338,7 @@ def test_prefill_on_the_scan_kernel_then_decode_match_the_reference(
 
 def _through_engine(model, params, impl, prompts, new=5):
     """``prompts`` through a ``SlotEngine`` built and run under ``impl``:
-    tokens, ``stats()["prefill_scan"]`` before and after ``reset_stats()``."""
+    tokens, ``stats()`` before and after ``reset_stats()``."""
     got = {i: [] for i in range(len(prompts))}
     with nn.attention_impl(impl):
         engine = serve.SlotEngine(model, params, num_slots=3, max_len=128,
@@ -352,9 +352,9 @@ def _through_engine(model, params, impl, prompts, new=5):
                 engine.settle()
             else:
                 engine.collect_all()
-    scan = engine.stats()["prefill_scan"]
+    stats = engine.stats()
     engine.reset_stats()
-    return got, scan, engine.stats()["prefill_scan"]
+    return got, stats, engine.stats()
 
 
 def test_slot_engine_on_the_scan_kernel_serves_the_reference_tokens(
@@ -368,6 +368,7 @@ def test_slot_engine_on_the_scan_kernel_serves_the_reference_tokens(
     prompts = [rng.integers(0, CFG["vocab_size"], n) for n in (21, 50)]
     got, scan, zeroed = _through_engine(model, params, "flash", prompts)
     want, dense, _ = _through_engine(model, params, "dense", prompts)
+    scan, dense, zeroed = (a["prefill_scan"] for a in (scan, dense, zeroed))
     assert got == want
     for i, prompt in enumerate(prompts):
         ref = _ref_logits(params, np.concatenate([prompt, got[i]]), CFG128)
@@ -379,6 +380,56 @@ def test_slot_engine_on_the_scan_kernel_serves_the_reference_tokens(
     assert zeroed == {"prefills": 0, "kernel_prefills": 0}
 
 
+def test_the_grouped_kernel_serves_the_dense_branchs_tokens_and_logits(
+        program):
+    """ISSUE 45: the full-attention layer's four query heads over one K/V
+    head through the grouped slot-decode kernel (``attention_impl("flash")``,
+    interpreted; heads of 16 keep the recurrent layers on ``jax.numpy``
+    either way), slot 1 free: the dense branch's logits step by step and its
+    tokens, the free slot's K/V rows untouched, and the engine's tokens with
+    ``stats()["decode_attn"]["kernel"]`` saying which branch it built."""
+    model, params = program
+    rng = np.random.default_rng(9)
+    prompts = [rng.integers(0, CFG["vocab_size"], n) for n in (21, 50)]
+    seen = {}
+    for impl in ("dense", "flash"):
+        with nn.attention_impl(impl), \
+                jax.default_matmul_precision("highest"):
+            pool = _pool(model, slots=3, max_len=128)
+            assert model.slot_decode_kernel(pool[0]) is (impl == "flash")
+            assert model.slot_state_kernel(pool[0]) is False
+            prefill = jax.jit(lambda *a: model.prefill_into_slot(*a))
+            decode = jax.jit(lambda *a: model.decode_step(*a))
+            tokens, lengths = np.zeros(3, np.int32), np.zeros(3, np.int32)
+            for slot, prompt in zip((0, 2), prompts):
+                padded = np.full(64, 5, np.int32)
+                padded[:len(prompt)] = prompt
+                row, *pool = prefill(params, padded, len(prompt), slot, *pool)
+                tokens[slot], lengths[slot] = int(np.argmax(row)), len(prompt)
+            before, rows = jax.tree.map(np.asarray, pool[0]), []
+            for _ in range(4):
+                logits, *pool = decode(params, tokens, lengths, *pool)
+                rows.append(np.asarray(logits)[[0, 2]])
+                tokens[[0, 2]] = np.argmax(rows[-1], axis=-1)
+                lengths[[0, 2]] += 1
+        seen[impl] = np.stack(rows), before, jax.tree.map(np.asarray, pool[0])
+    np.testing.assert_allclose(seen["flash"][0], seen["dense"][0], rtol=0,
+                               atol=SAME)
+    np.testing.assert_array_equal(seen["flash"][0].argmax(-1),
+                                  seen["dense"][0].argmax(-1))
+    _, before, after = seen["flash"]
+    for name in ("k", "v"):
+        np.testing.assert_array_equal(after["block3.attn"][name][1],
+                                      before["block3.attn"][name][1])
+        assert not np.array_equal(after["block3.attn"][name][0],
+                                  before["block3.attn"][name][0])
+    toks, stats, _ = _through_engine(model, params, "flash", prompts)
+    want, dense, _ = _through_engine(model, params, "dense", prompts)
+    assert toks == want
+    assert stats["decode_attn"] == dict(dense["decode_attn"], kernel=True)
+    assert dense["decode_attn"]["kernel"] is False
+
+
 def test_heads_the_kernel_does_not_take_keep_the_jax_numpy_scan(program):
     """Heads of 16 x 16 (this file's usual size) fill no lane: the model
     answers false for every bucket whatever ``attention_impl`` says, and the
@@ -388,8 +439,8 @@ def test_heads_the_kernel_does_not_take_keep_the_jax_numpy_scan(program):
         assert model.prefill_scan_kernel(model.init_slot_cache(2, 64),
                                          64) is False
     prompts = [np.arange(1, 20)]
-    _, scan, _ = _through_engine(model, params, "flash", prompts, new=2)
-    assert scan == {"prefills": 1, "kernel_prefills": 0}
+    _, stats, _ = _through_engine(model, params, "flash", prompts, new=2)
+    assert stats["prefill_scan"] == {"prefills": 1, "kernel_prefills": 0}
 
 
 def test_the_model_answers_for_its_recurrent_layers(program128):

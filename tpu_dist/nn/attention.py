@@ -421,7 +421,8 @@ class MultiheadSelfAttention(Module):
         pool takes ONE Pallas call where :meth:`takes_slot_kernel` says so
         (tpu_dist.ops.decode_attention: it reads only each slot's resident
         blocks and writes only the block of the new column; a slot of
-        length 0 is free there: untouched, output zero).  Prefill, a
+        length 0 is free there: untouched, output zero; grouped queries
+        take its grouped form, ``G`` read from the shapes).  Prefill, a
         multi-token append, every CPU run and the int8 cache (its hoisted
         scales would be a different kernel; no benchmark cell runs it) stay
         on the dense branch below."""
@@ -508,17 +509,16 @@ class MultiheadSelfAttention(Module):
         a slot) of THIS layer over its pool ``entry`` takes the Pallas
         kernel (tpu_dist.ops.decode_attention) or the dense branch of
         :meth:`_decode`.  Chosen as :func:`scaled_dot_product_attention`
-        chooses flash: by what can be observed — one query row a K/V head,
-        which is the kernel's form (grouped queries stay dense: PERF.md,
-        PR 30), a float pool whose ``D`` fills whole sublane tiles and
-        whose ``Tmax`` fills whole lanes, on a TPU backend (interpreted,
-        the kernel would make every served token cost seconds) — with the
-        trace-scoped :func:`attention_impl` (``"flash"`` / ``"dense"``) as
-        the override.  The engine asks the model, which asks here, which
-        branch its decode program was built on."""
+        chooses flash: by what can be observed — a float pool whose ``D``
+        fills whole sublane tiles and whose ``Tmax`` fills whole lanes, on
+        a TPU backend (interpreted, the kernel would make every served
+        token cost seconds) — with the trace-scoped :func:`attention_impl`
+        (``"flash"`` / ``"dense"``) as the override.  One query row a K/V
+        head or several: the kernel reads ``G`` off its operands' shapes.
+        The engine asks the model, which asks here, which branch its decode
+        program was built on."""
         from ..ops.decode_attention import decode_attention_ok
-        return (self.num_kv_heads == self.num_heads and slot_kernel_wanted()
-                and decode_attention_ok(entry["k"]))
+        return slot_kernel_wanted() and decode_attention_ok(entry["k"])
 
     def init_cache(self, batch: int, max_len: int, dtype=jnp.float32):
         """What this layer keeps per slot (one entry of a nn/cache.py tree,
