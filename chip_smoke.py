@@ -8,7 +8,8 @@ vocab 32768, T = 2048), every phase fatal:
   kernels   the Pallas kernels (flash attention, fused CE and the grouped
             matmuls forward and backward, slot-decode attention by head,
             by groups of query heads and over a latent, the recurrent
-            state's one-token update),
+            state's one-token update and chunked scan, the expert
+            combine by its buffer's rows),
             lowered by Mosaic at their full-width users' shapes, against
             plain ``jnp``
   convnet   the source paper's ConvNet through ``init_process_group`` +
@@ -465,9 +466,39 @@ def check_delta_scan(seq: int, key_heads: int, value_heads: int, k_dim: int,
                  F32_TOL)
 
 
+def check_moe_combine(tokens: int, top_k: int, rows: int, dim: int,
+                      share: float) -> None:
+    """The combine of a share of the experts by its buffer's rows: ``tokens``
+    x ``top_k`` picks of which ``share`` hold a row of a ``(rows, dim)``
+    bfloat16 buffer, the last tenth of the tokens alike (bucket padding)
+    with every pick held, against the float32 sum of each token's rows."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from tpu_dist.ops.moe_combine import combine_by_token
+
+    rng = np.random.default_rng(12)
+    held = rng.random((top_k, tokens)) < share
+    held[:, tokens - tokens // 10:] = True
+    picks = np.flatnonzero(held.reshape(-1))
+    if len(picks) > rows:
+        raise AssertionError(f"{len(picks)} held picks pass {rows} rows")
+    slot = np.full(top_k * tokens, rows, np.int32)
+    slot[picks] = rng.permutation(rows)[:len(picks)]
+    slot = jnp.asarray(slot.reshape(top_k, tokens))
+    out = jnp.asarray(rng.standard_normal((rows, dim)), jnp.bfloat16)
+    w = jnp.asarray(rng.random((top_k, tokens)), jnp.bfloat16)
+    padded = jnp.concatenate([out, jnp.zeros_like(out[:1])])
+    want = (padded[slot].astype(jnp.float32)
+            * w.astype(jnp.float32)[:, :, None]).sum(0)
+    got = jax.jit(combine_by_token)(out, w, slot)
+    _check_close("expert combine by the buffer's rows", got, want, BF16_TOL)
+
+
 def phase_kernels(flash: dict, ce: dict, moe: dict, decode: dict,
                   grouped: dict, latent: dict, state: dict,
-                  scan: dict) -> dict:
+                  scan: dict, combine: dict) -> dict:
     t0 = time.perf_counter()
     check_flash(**flash)
     check_fused_ce(**ce)
@@ -477,6 +508,7 @@ def phase_kernels(flash: dict, ce: dict, moe: dict, decode: dict,
     check_latent_decode_attention(**latent)
     check_delta_step(**state)
     check_delta_scan(**scan)
+    check_moe_combine(**combine)
     return {"seconds": time.perf_counter() - t0}
 
 
@@ -822,7 +854,9 @@ def main() -> int:
                     max_len=1024),
         state=dict(slots=32, heads=32, k_dim=128, v_dim=128),
         scan=dict(seq=4096, key_heads=16, value_heads=32, k_dim=128,
-                  v_dim=128))
+                  v_dim=128),
+        combine=dict(tokens=4096, top_k=10, rows=15360, dim=2048,
+                     share=1 / 8))
     _say(f"phase kernels passed ({r['seconds']:.1f} s with compilation)")
 
     _say("phase convnet")

@@ -121,6 +121,13 @@ def test_the_slot_decode_kernels_check_tiny(group):
                                       max_len=512, group=group)
 
 
+def test_the_expert_combine_checks_tiny():
+    """``check_moe_combine`` as the chip runs it (ISSUE 46), interpreted
+    here at 200 tokens of which the last 20 are alike."""
+    chip_smoke.check_moe_combine(tokens=200, top_k=4, rows=256, dim=128,
+                                 share=1 / 8)
+
+
 @pytest.mark.slow
 def test_kernel_convnet_and_multichip_phases_tiny():
     chip_smoke.phase_kernels(
@@ -131,7 +138,8 @@ def test_kernel_convnet_and_multichip_phases_tiny():
         grouped=dict(slots=12, heads=2, head_dim=64, max_len=512, group=5),
         latent=dict(slots=12, heads=4, latent=48, values=32, max_len=512),
         state=dict(slots=3, heads=2, k_dim=64, v_dim=128),
-        scan=dict(seq=150, key_heads=1, value_heads=2, k_dim=128, v_dim=128))
+        scan=dict(seq=150, key_heads=1, value_heads=2, k_dim=128, v_dim=128),
+        combine=dict(tokens=200, top_k=4, rows=256, dim=128, share=1 / 8))
     chip_smoke.phase_convnet("cpu", per_chip_batch=8, steps=12)
     tr = chip_smoke.phase_trainer("cpu", steps=2, **TINY_RUN)
     chip_smoke.phase_multichip("cpu", dp_first_loss=tr["losses"][0],

@@ -191,6 +191,19 @@ def mosaic_gmm(monkeypatch):
                         lambda: False)
 
 
+@pytest.fixture
+def mosaic_moe_combine(monkeypatch):
+    """``ops/moe_combine.py`` the same (``mosaic_gmm``): the combine of a
+    layer that holds a share of its experts, by its buffer's rows."""
+    import sys
+    import tpu_dist.ops.moe_combine  # noqa: F401
+    module = sys.modules["tpu_dist.ops.moe_combine"]
+    monkeypatch.setattr(module, "_use_interpret", lambda: False)
+    module._call.clear_cache()
+    yield
+    module._call.clear_cache()      # leave no Mosaic-lowered trace behind
+
+
 @pytest.mark.parametrize("program", ["decode_step", "prefill_into_slot"])
 def test_olmoe_pool_program_compiles_with_its_grouped_matmuls(
         one_chip, no_compile_cache, mosaic_gmm, program):
@@ -441,7 +454,8 @@ def mosaic_flash(monkeypatch):
 
 
 def test_kimi_k2_prefill_on_the_kernel_holds_no_score_tensor(
-        one_chip, no_compile_cache, mosaic_gmm, mosaic_flash):
+        one_chip, no_compile_cache, mosaic_gmm, mosaic_flash,
+        mosaic_moe_combine):
     """The prefill program of the same two layers at a 1,024 bucket: on the
     kernel branch the chip's compiler takes one ``flash_fwd`` call a layer
     at heads of 192 / 128 and the optimized program produces no array of
@@ -485,7 +499,7 @@ def mosaic_delta_step(monkeypatch):
 @pytest.mark.parametrize("program", ["decode_step", "prefill_into_slot"])
 def test_kimi_linear_pool_programs_compile_at_the_cells_shapes(
         one_chip, no_compile_cache, mosaic_gmm, mosaic_decode_attention,
-        mosaic_delta_step, program):
+        mosaic_delta_step, mosaic_moe_combine, program):
     """Kimi Linear's block at the published widths, two layers (a Kimi
     Delta Attention layer over the dense MLP, a latent layer without rank
     or rope over 32 held of 256 experts), vocabulary cut, at the cell's 120
@@ -555,7 +569,8 @@ def mosaic_delta_scan(monkeypatch):
 
 def test_qwen3_next_prefill_holds_one_delta_scan_call_a_recurrent_layer(
         one_chip, no_compile_cache, mosaic_gmm, mosaic_flash,
-        mosaic_decode_attention, mosaic_delta_step, mosaic_delta_scan):
+        mosaic_decode_attention, mosaic_delta_step, mosaic_delta_scan,
+        mosaic_moe_combine):
     """Qwen3-Next's block at the published widths, one period of the layer
     pattern (three Gated DeltaNet layers of 16 key / 32 value heads of 128 x
     128, one gated full-attention layer; 64 held of 512 experts, vocabulary
@@ -594,6 +609,14 @@ def test_qwen3_next_prefill_holds_one_delta_scan_call_a_recurrent_layer(
                            r"custom_call_target=\"tpu_custom_call\"", text)),
             sorted(set(scan_shapes.findall(text))),
             compiled.memory_analysis().temp_size_in_bytes)
+        # ISSUE 46: each of the four expert layers combines its usual-size
+        # buffer by its rows, a ``moe_combine`` call the chip's compiler
+        # takes over the half of the rows and one over all of them, beside
+        # the grouped matmuls of both buffers
+        kernels = re.findall(r"%(moe_combine|gmm_r\d+)[.\d]* = [^\n]*"
+                             r"custom_call_target=\"tpu_custom_call\"", text)
+        assert kernels.count("moe_combine") == 4 * 2, kernels
+        assert kernels.count("gmm_r40960") == 4 * 2 * 3, kernels
     calls, shapes, temp = seen["flash"]
     assert calls == 3 and not shapes, (calls, shapes)
     calls, shapes, dense_temp = seen["dense"]
@@ -710,3 +733,108 @@ def test_qwen3_next_decode_step_holds_one_grouped_call_a_full_attention_layer(
     assert not _pool_sized_results(text, ("copy", "select"),
                                    elements=slots * max_len * 2 * 256)
     assert memory.temp_size_in_bytes < 128 << 20
+
+
+# -- the combine of a share of the experts, by its buffer's rows (ISSUE 46) ----
+
+def _without_the_combine_by_rows(monkeypatch):
+    """The expert layer as it was before ISSUE 46: every call combined by a
+    row gather for every pick."""
+    from tpu_dist.nn import moe
+    monkeypatch.setattr(moe, "_combines_by_token", lambda m_rows, kn: False)
+
+
+def test_qwen3_next_prefill_combines_its_usual_buffer_by_its_rows(
+        one_chip, monkeypatch, mosaic_gmm, mosaic_flash, mosaic_delta_scan,
+        mosaic_moe_combine):
+    """The hybrid's ``prefill_into_slot`` lowered at the cell's shapes (96
+    slots x 4,096, a 4,096 bucket: 40,960 picks a layer over 64 held of 512
+    experts).  Each expert layer is a ``cond`` over two row buffers: the
+    usual one (15,360 rows) is combined by its rows through the
+    ``moe_combine`` kernel and holds no ``(10, 4096, 2048)`` array of every
+    pick's row any more; the buffer of every pick keeps the row-gather form
+    (tests/test_qwen3_next.py sends every pick and takes it).  So the
+    program holds half the per-pick arrays it held, and every ``gmm_r40960``
+    call it had."""
+    from tpu_dist.models import Qwen3NextLM
+    slots, max_len = 96, 4096
+    model = Qwen3NextLM(
+        VOCAB, dim=2048, depth=4, num_heads=16, num_kv_heads=2, head_dim=256,
+        num_experts=512, experts_held=64, moe_top_k=10, moe_hidden=512,
+        shared_hidden=512, max_seq_len=max_len)
+    layer = model.block0.mlp
+    assert layer._buffer_sizes(40960, layer._block_rows(
+        40960, jnp.bfloat16)) == [15360, 46080]
+    pool = _shapes(jax.eval_shape(
+        lambda: model.init_slot_cache(slots, max_len, jnp.bfloat16)),
+        one_chip)
+    counters = _shapes(jax.eval_shape(model.init_moe_counters), one_chip)
+    params = _param_shapes(model, one_chip)
+
+    def facts():
+        with nn.attention_impl("flash"):
+            text = _lower(model, "prefill_into_slot", params, pool, counters,
+                          one_chip, slots=slots, bucket=max_len).as_text()
+        return (len(re.findall(r"tensor<10x4096x2048xbf16>", text)),
+                len(re.findall(r'kernel_name = "gmm_r40960"', text)),
+                len(re.findall(r'kernel_name = "moe_combine"', text)))
+
+    per_pick, gmm, kernel = facts()
+    _without_the_combine_by_rows(monkeypatch)
+    was_per_pick, was_gmm, was_kernel = facts()
+    # four layers x two buffers x three grouped matmuls, on both sides
+    assert gmm == was_gmm == 24
+    # jitted: one body for the four layers' calls over half the buffer's
+    # rows (the held picks of most calls fit those) and one over all
+    assert (kernel, was_kernel) == (2, 0)
+    # the same arrays fewer in each of the four layers (what is left is the
+    # other buffer's branch and the router's own (k, N) bookkeeping)
+    gone = was_per_pick - per_pick
+    assert gone > 0 and gone % 4 == 0, (per_pick, was_per_pick)
+
+
+@pytest.mark.parametrize("cell", ["olmoe", "xing4"])
+@pytest.mark.parametrize("program", ["decode_step", "prefill_into_slot"])
+def test_a_model_that_holds_every_expert_lowers_as_it_did(
+        one_chip, monkeypatch, mosaic_gmm, mosaic_flash,
+        mosaic_decode_attention, mosaic_moe_combine, program, cell):
+    """OLMoE's block (64 of 64 experts, 8 a token, 32 slots x 1,024) and
+    Xing4.0's (64 of 64, 4 a token, four residual streams; one dense and
+    one expert layer of the cell's configuration, 96 slots x 4,096): a
+    buffer that holds every pick is never at most half the picks, so both
+    pool programs lower to the text they had before ISSUE 46, the counters
+    beside the pool too, and hold no ``moe_combine``."""
+    import json
+    import os
+    if cell == "olmoe":
+        slots, max_len = SLOTS, MAX_LEN
+        model = TransformerLM(VOCAB, dim=2048, depth=1, num_heads=16,
+                              max_seq_len=MAX_LEN, norm="rmsnorm", rope=True,
+                              norm_eps=1e-5, attn_bias=False, qk_norm=True,
+                              num_experts=64, moe_top_k=8, moe_hidden=1024,
+                              moe_gated=True, moe_normalize_gates=False,
+                              moe_dispatch="dropless")
+    else:
+        from tpu_dist.models import Xing4LM
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        with open(os.path.join(root, "chipbench", "configs",
+                               "xing4-29b-a4b-serve.json")) as f:
+            cfg = json.load(f)
+        slots, max_len = cfg["serve"]["slots"], cfg["serve"]["max_len"]
+        kw = {k: cfg[key] for k, key in cfg["model"]["kwargs_from"].items()}
+        model = Xing4LM(**dict(kw, depth=2, vocab_size=VOCAB))
+    pool = _shapes(jax.eval_shape(
+        lambda: model.init_slot_cache(slots, max_len, jnp.bfloat16)),
+        one_chip)
+    counters = _shapes(jax.eval_shape(model.init_moe_counters), one_chip)
+    params = _param_shapes(model, one_chip)
+
+    def text():
+        with nn.attention_impl("flash"):
+            return _lower(model, program, params, pool, counters, one_chip,
+                          slots=slots, bucket=max_len).as_text()
+
+    now = text()
+    _without_the_combine_by_rows(monkeypatch)
+    assert "moe_combine" not in now and "combined_rows" in str(counters)
+    assert now == text()

@@ -562,6 +562,41 @@ def test_a_share_counts_held_and_absent_picks_and_computes_only_held(
     assert c["computed_rows"] < 4 * (64 + 3 * 2)      # far from every pick
 
 
+def test_a_share_at_a_prefills_shape_is_combined_by_its_buffers_rows():
+    """A model that holds 2 of 32 experts, 8 a token, over a 512 bucket:
+    4,096 picks against a usual buffer of 768 rows (twice the expected share
+    and a block of 128 an expert), so each layer's prefill is combined by
+    the buffer's rows in token order (ops/moe_combine.py; the counter says
+    which combine ran), the bucket's 92 padding rows among them, and serves
+    the reference's logits and tokens.  The decode steps after it (16 picks)
+    and the test below, which sends every pick, keep the row-gather form."""
+    from tpu_dist.nn import moe
+    cfg = dict(CFG, router_num_experts=32, num_experts=2, expert_offset=6,
+               num_experts_per_tok=8, max_position_embeddings=512)
+    model = _model(cfg)
+    layer = model.block0.mlp
+    assert layer._buffer_sizes(4096, layer._block_rows(
+        4096, jnp.float32)) == [768, 4352]
+    assert moe._combines_by_token(768, 4096)
+    params = _perturbed(model.init(jax.random.key(5)))
+    prompt = np.random.default_rng(6).integers(0, cfg["vocab_size"], 420)
+    with jax.default_matmul_precision("highest"):
+        rows, toks, pool = _serve_one(
+            model, params, prompt, 3, 1, _pool(model, slots=2, max_len=512),
+            bucket=512)
+    ref = _ref_logits(params, np.concatenate([prompt, toks]), cfg)
+    np.testing.assert_allclose(rows, ref[len(prompt) - 1:-1], rtol=0,
+                               atol=ATOL)
+    assert toks == [int(t) for t in ref[len(prompt) - 1:-1].argmax(-1)]
+    for path, c in jax.tree.map(np.asarray, pool[1]).items():
+        # one prefill by the buffer's rows (the half of the 768 that its
+        # ~256 held picks fit), two decode steps over two slots by their 16
+        # picks
+        assert c["calls"] == 3, path
+        assert c["combined_rows"] == 384 + 2 * 16, (path, c["combined_rows"])
+        assert 0 < c["held_rows"] <= c["computed_rows"] <= 768 + 2 * 16
+
+
 @pytest.mark.parametrize("sent", ["every_pick_held", "a_usual_share"])
 def test_a_share_sent_every_pick_drops_none(sent):
     """A layer that holds 4 of 16 experts sizes its row buffer for twice
@@ -596,6 +631,7 @@ def test_a_share_sent_every_pick_drops_none(sent):
     per_expert = c["rows"][8:12]
     want_rows = int((-(-per_expert // 16) * 16).sum())
     assert c["computed_rows"] == want_rows
+    assert c["combined_rows"] == 256        # every pick's row gathered
     if sent == "every_pick_held":
         assert c["held_rows"] == 256 == c["rows"].sum() and want_rows > usual
     else:

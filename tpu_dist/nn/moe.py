@@ -188,6 +188,42 @@ def _combine_rows_bwd(res, gy):
 _combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
 
 
+# A row buffer much smaller than the picks of the call (a layer that holds a
+# share of its experts) is combined by its ROWS, not by the picks: at most
+# ``len(out_flat)`` rows carry anything, and ops/moe_combine.py walks them in
+# token order.  Timed on the chip at the shapes of the cells that hold a
+# share (PERF.md, PR 46): a buffer of at most half the picks gains 1.5 ms a
+# layer at 40,960 picks (64 of 512 experts, a 4,096 prefill: 1.68 -> 0.34 ms)
+# and 0.6 ms at 16,384 (12 of 384, a 2,048 prefill).  At 2,048 picks (Kimi
+# Linear's 256 prefill) and at a 128-slot decode step's 1,024 the forms alone
+# stand 0.02-0.04 ms a layer apart, which no cell's window shows: those calls
+# keep the row gather, and the floor stands between 2,048 and 16,384.
+_BY_TOKEN_MIN_PICKS = 4096
+
+
+def _combines_by_token(m_rows: int, kn: int) -> bool:
+    """Whether a call of ``kn`` picks over a buffer of ``m_rows`` takes
+    :func:`_combine_held_rows` (both static)."""
+    return 2 * m_rows <= kn and kn >= _BY_TOKEN_MIN_PICKS
+
+
+@jax.custom_vjp
+def _combine_held_rows(out_flat, w, choice_for_slot, slot):
+    """:func:`_combine_rows`, the same function of ``out_flat`` and ``w``,
+    by the buffer's rows in token order (ops/moe_combine.py): no ``(k, N,
+    d)`` array, the sum accumulated in float32 and rounded once."""
+    from ..ops.moe_combine import combine_by_token
+    return combine_by_token(out_flat, w, slot)
+
+
+def _combine_held_rows_fwd(out_flat, w, choice_for_slot, slot):
+    return (_combine_held_rows(out_flat, w, choice_for_slot, slot),
+            (out_flat, w, choice_for_slot, slot))
+
+
+_combine_held_rows.defvjp(_combine_held_rows_fwd, _combine_rows_bwd)
+
+
 class MoELayer(Module):
     """Top-k routed mixture of expert FFNs (drop-in for a transformer MLP).
 
@@ -422,11 +458,24 @@ class MoELayer(Module):
             if self.dispatch == "dropless":
                 b = self._block_rows(k * n, xt.dtype)
                 computed = (((counts + b - 1) // b) * b).sum()
+                # the smallest buffer the rows fit takes the call
+                # (_forward_dropless), and its size says which combine:
+                # by its rows, all or the half the held picks fit
+                from ..ops.moe_combine import gather_sizes
+                combined = k * n
+                for m_rows in reversed(self._buffer_sizes(k * n, b)[:-1]):
+                    if _combines_by_token(m_rows, k * n):
+                        half, whole = gather_sizes(m_rows)
+                        combined = jnp.where(
+                            computed <= m_rows,
+                            jnp.where(counts.sum() <= half, half, whole),
+                            combined)
             else:
                 computed = e * c
+                combined = k * n
             # serving counts its routed rows; training publishes the
             # load-balancing loss (the state entry holds one or the other)
-            if not self._count_rows(ctx, gate_idx, oh_i, computed):
+            if not self._count_rows(ctx, gate_idx, oh_i, computed, combined):
                 self._put_switch_aux(xt, probs.astype(xt.dtype), gate_idx)
 
         if self.dispatch == "dropless":
@@ -500,15 +549,19 @@ class MoELayer(Module):
         belong to no request (free slots in a decode step, bucket padding in
         a prefill), ``computed_rows`` the rows the expert matmuls ran over
         (every held pick, a request's or not, with each expert's segment
-        rounded up to the row block), ``calls`` of the layer, and
+        rounded up to the row block), ``combined_rows`` the rows the combine
+        gathered (every pick of the call by the picks; where the call is
+        combined by its buffer's rows, :func:`_combines_by_token`, those in
+        whole chunks, or half of them where the held picks fit the half),
+        ``calls`` of the layer, and
         ``experts_hit``, the held experts with a request's row summed over
         calls.  int32: a reader takes differences modulo 2**32."""
         z = lambda *shape: jnp.zeros(shape, jnp.int32)
         return {"rows": z(self.num_experts), "held_rows": z(),
-                "pad_rows": z(), "computed_rows": z(), "calls": z(),
-                "experts_hit": z()}
+                "pad_rows": z(), "computed_rows": z(), "combined_rows": z(),
+                "calls": z(), "experts_hit": z()}
 
-    def _count_rows(self, ctx, gate_idx, oh_i, computed) -> bool:
+    def _count_rows(self, ctx, gate_idx, oh_i, computed, combined) -> bool:
         """When this layer's state entry carries the counters
         (:meth:`init_counters`), add this call's routed rows to it, on the
         device.  ``valid`` (the rows that belong to a request; put into the
@@ -536,6 +589,8 @@ class MoELayer(Module):
             "pad_rows": st["pad_rows"] + k * (n - n_valid),
             "computed_rows": st["computed_rows"]
             + jnp.asarray(computed, jnp.int32),
+            "combined_rows": st["combined_rows"]
+            + jnp.asarray(combined, jnp.int32),
             "calls": st["calls"] + 1,
             "experts_hit": st["experts_hit"] + (held > 0).sum().astype(
                 jnp.int32)})
@@ -579,6 +634,10 @@ class MoELayer(Module):
         passes are gathers, never a data scatter); the per-expert FFN
         matmuls and all three of their backward passes are grouped
         matmuls over the same block→expert map (ops.gmm.grouped_linear).
+        A buffer much smaller than the picks of the call (a share's usual
+        and middle buffers in a prefill) is combined by ITS rows in token
+        order (:func:`_combine_held_rows`, the same VJP), not by a row for
+        every pick: :func:`_combines_by_token` chooses from the two sizes.
 
         A layer that holds a share of the experts expects a share of the
         picks, but MAY be sent all of them.  The row buffer is sized for
@@ -596,27 +655,34 @@ class MoELayer(Module):
         times the rows stand between the two, so that one expert most of a
         prompt picks costs a layer 1.4 ms and not 4.5 (PERF.md, PR 32).
         """
-        e, k = self.experts_held, self.top_k
-        n, d = xt.shape
-        kn = k * n
+        k, n = self.top_k, xt.shape[0]
         padded = ((counts + b - 1) // b) * b
         cum_padded = jnp.cumsum(padded)
-        worst = (-(-kn // b) + e) * b            # every pick on a held expert
-        usual = (-(-2 * kn * e // (self.num_experts * b)) + e) * b
         rows = functools.partial(
             self._dropless_rows, p, xt, gate_vals, gate_idx, held, rank,
             counts, padded, cum_padded, b)
-        sizes = [usual]
-        while 8 * sizes[-1] <= worst:
-            sizes.append(4 * sizes[-1])
 
         def smallest(sizes):
-            if not sizes or sizes[0] >= worst:
-                return rows(worst)
+            if len(sizes) == 1:
+                return rows(sizes[0])
             return lax.cond(cum_padded[-1] <= sizes[0],
                             lambda: rows(sizes[0]),
                             lambda: smallest(sizes[1:]))
-        return smallest(sizes)
+        return smallest(self._buffer_sizes(k * n, b))
+
+    def _buffer_sizes(self, kn: int, b: int) -> list:
+        """The row buffers a dropless call of ``kn`` picks may take, rising
+        (:meth:`_forward_dropless`): twice the expected share, four times
+        that while eight times still fits, and last the one that holds
+        every pick on a held expert, which alone a layer that holds every
+        expert has."""
+        e = self.experts_held
+        worst = (-(-kn // b) + e) * b
+        usual = (-(-2 * kn * e // (self.num_experts * b)) + e) * b
+        sizes = [usual]
+        while 8 * sizes[-1] <= worst:
+            sizes.append(4 * sizes[-1])
+        return [m for m in sizes if m < worst] + [worst]
 
     def _dropless_rows(self, p, xt, gate_vals, gate_idx, held, rank, counts,
                        padded, cum_padded, b, m_rows):
@@ -668,7 +734,9 @@ class MoELayer(Module):
 
         out = self._experts(p, xs, linear)
         with jax.named_scope("combine"):
-            return _combine_rows(out, gate_vals.T, choice_for_row, slot)
+            combine = (_combine_held_rows if _combines_by_token(m_rows, kn)
+                       else _combine_rows)
+            return combine(out, gate_vals.T, choice_for_row, slot)
 
     def _put_aux(self, aux) -> None:
         from .module import current_context

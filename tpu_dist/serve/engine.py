@@ -1274,8 +1274,10 @@ class SlotEngine:
         ``held_rows`` those on an expert the model holds and ``absent_rows``
         the others (a model that holds a share of its experts), ``pad_rows``
         the picks of free slots (decode) and bucket padding (prefill),
-        ``computed_rows`` the rows the expert matmuls ran over, ``calls`` of
-        an expert layer, ``experts_hit`` summed over calls
+        ``computed_rows`` the rows the expert matmuls ran over,
+        ``combined_rows`` the rows the combine gathered (every pick of a
+        call, or the row buffer's where a call is combined by those),
+        ``calls`` of an expert layer, ``experts_hit`` summed over calls
         (``MoELayer.init_counters``)."""
         if not since:
             return None
@@ -1288,7 +1290,7 @@ class SlotEngine:
         return {"rows_per_expert": [int(r) for r in per_expert],
                 **{k: total(k) for k in ("rows", "held_rows", "absent_rows",
                                          "pad_rows", "computed_rows",
-                                         "calls")},
+                                         "combined_rows", "calls")},
                 "by_phase": by_phase}
 
     def _decode_need_stats(self, since: dict) -> dict:
