@@ -427,7 +427,7 @@ def test_the_formats_names_stay_in_nn():
     module builds or filters the call's state by hand again."""
     owners = {os.path.join("tpu_dist", "nn", f)
               for f in ("cache.py", "attention.py", "deltanet.py",
-                        "mla.py", "moe.py")}
+                        "mla.py", "moe.py", "shortconv.py")}
     pattern = re.compile(r'"index"|"valid"|"k" (not )?in ')
     found = []
     for folder, _, files in os.walk(os.path.join(ROOT, "tpu_dist")):

@@ -838,3 +838,59 @@ def test_a_model_that_holds_every_expert_lowers_as_it_did(
     _without_the_combine_by_rows(monkeypatch)
     assert "moe_combine" not in now and "combined_rows" in str(counters)
     assert now == text()
+
+
+# -- a slot entry that is a convolution tail alone (ISSUE 47) ------------------
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_into_slot"])
+def test_lfm2_moe_pool_programs_compile_at_the_cells_shapes(
+        one_chip, no_compile_cache, mosaic_gmm, mosaic_decode_attention,
+        program, capsys):
+    """LFM2-MoE's layers at the published widths, three layers (a gated
+    short convolution over the dense MLP of 11,776, a grouped-query
+    attention of 32 heads over 8 K/V heads of 64 and a convolution layer
+    each over 64 held experts of 1,536; vocabulary cut), at the cell's 320
+    slots x 1,024 and its 256 bucket: the chip's compiler takes both pool
+    programs; the decode step holds ONE grouped ``decode_attention`` call
+    (``G`` = 4, ``D`` = 64) for the attention layer and the grouped matmuls
+    named by a step's 1,280 picks, which are no prefill bucket's
+    (``chipbench/gmm_ep_need.py`` tells the two apart by them); neither
+    program copies the K/V pool, and a convolution layer's tail (2.6 MB for
+    320 slots) is made by a fusion, never by a copy."""
+    from tpu_dist.models import Lfm2MoeLM
+    slots = 320
+    model = Lfm2MoeLM(
+        VOCAB, dim=2048, depth=3, num_heads=32, num_kv_heads=8,
+        layer_types="conv,full_attention,conv", dense_hidden=11776,
+        num_dense_layers=1, max_seq_len=MAX_LEN)
+    pool = _shapes(jax.eval_shape(
+        lambda: model.init_slot_cache(slots, MAX_LEN, jnp.bfloat16)),
+        one_chip)
+    assert {n: a.shape for n, a in pool["block0.attn"].items()} == {
+        "conv": (slots, 4096)}
+    assert pool["block1.attn"]["k"].shape == (slots, 8, 64, MAX_LEN)
+    counters = _shapes(jax.eval_shape(model.init_moe_counters), one_chip)
+    with nn.attention_impl("flash"):        # as a TPU backend would choose
+        assert model.slot_decode_kernel(pool) is True
+        assert model.slot_state_kernel(pool) is False
+        assert model.prefill_scan_kernel(pool, 256) is False
+        compiled = _lower(model, program, _param_shapes(model, one_chip),
+                          pool, counters, one_chip, slots=slots,
+                          bucket=256).compile()
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\n[lfm2-moe, 3 of 10 layers, {slots} slots] {program}: "
+              f"arguments {memory.argument_size_in_bytes / 2**30:.3f} GiB, "
+              f"temporaries {memory.temp_size_in_bytes / 2**20:.1f} MiB")
+    calls = re.findall(r"%(decode_attention|gmm_r\d+)[.\d]* = [^\n]*"
+                       r"custom_call_target=\"tpu_custom_call\"", text)
+    picks = (slots if program == "decode_step" else 256) * 4
+    assert calls.count(f"gmm_r{picks}") == 6, calls          # two layers
+    assert calls.count("decode_attention") == (program == "decode_step")
+    assert len(calls) == 6 + (program == "decode_step")
+    assert not _pool_sized_results(text, ("copy",),
+                                   elements=slots * MAX_LEN * 8 * 64)
+    tail = re.escape(f"bf16[{slots},4096]")
+    assert not [line for line in text.splitlines()
+                if re.search(r"= [^=]*%s[^=]* copy\(" % tail, line)]
+    assert memory.temp_size_in_bytes < 128 << 20

@@ -26,10 +26,8 @@ other than one token a row is ROADMAP Reach 10).
 
 from __future__ import annotations
 
-import jax
-
 from .. import nn
-from ..nn import init as I
+from ..nn.moe import experts_around_a_common_one
 from .kimi_k2 import KimiK2LM
 
 __all__ = ["Xing4LM"]
@@ -74,13 +72,8 @@ class Xing4LM(KimiK2LM):
         params = super().init(key)
         params["tok"]["weight"] = EMBEDDING_STD * params["tok"]["weight"]
         for i, kind in enumerate(self.layer_kinds):
-            if kind != "moe":
-                continue
-            experts = params[f"block{i}.mlp"]
-            for j, name in enumerate(("w1", "w3", "w2")):
-                w = experts[name]                       # (held, in, out)
-                common = I.torch_default_uniform(
-                    jax.random.fold_in(key, 1000 * (i + 1) + j), w.shape[1:],
-                    w.shape[1])
-                experts[name] = common + EXPERT_DEVIATION * w
+            if kind == "moe":
+                params[f"block{i}.mlp"] = experts_around_a_common_one(
+                    params[f"block{i}.mlp"], key, 1000 * (i + 1),
+                    EXPERT_DEVIATION)
         return params

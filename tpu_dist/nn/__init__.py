@@ -18,6 +18,7 @@ from .moe import MoELayer
 from .module import Module, Remat, Sequential, run_capturing_state
 from .quant import (QuantEmbedding, QuantLinear,
                     QuantMultiheadSelfAttention, quantize_linear_weights)
+from .shortconv import GatedShortConv
 
 __all__ = [
     "Module", "Remat", "Sequential", "run_capturing_state",
@@ -27,7 +28,7 @@ __all__ = [
     "Embedding", "LayerNorm", "RMSNorm", "GELU", "GatedMLP",
     "MultiheadSelfAttention", "MultiheadLatentAttention",
     "scaled_dot_product_attention", "attention_impl", "GatedDeltaNet",
-    "KimiDeltaAttention", "Mamba2", "ParallelMixer",
+    "KimiDeltaAttention", "Mamba2", "GatedShortConv", "ParallelMixer",
     "HyperConnection", "open_streams", "close_streams",
     "MoELayer", "rotary_embed", "yarn_inv_freq", "yarn_mscale",
     "CrossEntropyLoss",

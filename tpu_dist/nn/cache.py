@@ -16,7 +16,11 @@ apart by name (:func:`is_timed`, :func:`pool_leaf`):
   joins or describes a tree along time serves it as it serves ``k``;
 - a slot's WHOLE STATE, with no time axis: any other name, such as
   :class:`GatedDeltaNet`'s ``state`` ``(B, Hv, Dk, Dv)`` float32 and its
-  convolution tail ``conv`` (:meth:`GatedDeltaNet.init_cache`).  A layer
+  convolution tail ``conv`` (:meth:`GatedDeltaNet.init_cache`).  A
+  convolution may be a layer's WHOLE mixer and may have no activation
+  (:class:`GatedShortConv`): its entry is the tail ``conv`` ALONE, with no
+  ``state`` and no pool (:func:`pool_leaf` None), and everything below
+  serves it as it serves any other leaf of whole state.  A layer
   replaces such a leaf entire at every call; it cannot be cut, padded or
   joined along time, and the functions below that do so pass it through
   or refuse it, each as its docstring says.

@@ -72,6 +72,7 @@ from jax import lax
 from . import functional as F
 from . import init as I
 from .module import Module
+from .shortconv import advanced, causal_conv, conv_tail, valid_positions
 
 __all__ = ["GatedDeltaNet", "KimiDeltaAttention", "gated_delta_step",
            "gated_delta_chunked", "takes_step_kernel", "takes_scan_kernel"]
@@ -245,51 +246,6 @@ def gated_delta_chunked(state, q, k, v, g, beta, chunk: int = CHUNK):
         value, k_cumdecay, within, q_decayed, k_carry, chunk_decay))))
     out = jnp.moveaxis(out, 0, 2).reshape(b, h, n * chunk, -1)
     return out[:, :, :t], state
-
-
-def _valid_positions(st, b: int, t: int):
-    """The call's mask of positions that are a request's, (B, t): the cache
-    entry's ``valid``, all of them for a plain forward."""
-    valid = None if st is None else st.get("valid")
-    if valid is None:
-        valid = jnp.ones((b, t), bool)
-    return jnp.broadcast_to(valid, (b, t))
-
-
-def _conv_tail(st, name: str, like):
-    """A convolution's last ``K - 1`` inputs before this call, (B, K - 1,
-    C) in ``like``'s type: the cache entry's flattened leaf ``name``, zeros
-    for a plain forward (``like`` (B, K - 1, C) gives the shape)."""
-    if st is None:
-        return jnp.zeros(like.shape, like.dtype)
-    return st[name].reshape(like.shape).astype(like.dtype)
-
-
-def _advanced(st, t: int, **leaves):
-    """The cache entry ``st`` after a call of ``t`` positions: ``leaves``
-    replaced entire and the write index moved on."""
-    return dict(st, index=jnp.asarray(st["index"]) + t, **leaves)
-
-
-def _causal_conv(x, tail, weight, valid, bias=None):
-    """A causal depthwise convolution and SiLU over ``x`` (B, t, C), whose
-    last ``K - 1`` inputs before this call are ``tail`` (B, K - 1, C);
-    ``weight`` (C, K), tap ``K - 1`` the current position's; ``bias`` (C,)
-    joins the taps' sum before the SiLU where the layer has one
-    (:class:`~tpu_dist.nn.Mamba2`).  Returns the
-    activations and the tail after the call's LAST REAL position: rows ``[n,
-    n + K - 1)`` of the window, ``n`` the call's count of real positions
-    (``valid`` (B, t); they lead)."""
-    taps, t = tail.shape[1], x.shape[1]
-    window = jnp.concatenate([tail, x], axis=1)          # (B, K-1+t, C)
-    w = weight.astype(x.dtype)
-    mixed = sum(window[:, j:j + t] * w[:, j] for j in range(taps + 1))
-    if bias is not None:
-        mixed = mixed + bias.astype(x.dtype)
-    out = jax.nn.silu(mixed)
-    n_real = valid.sum(-1).astype(jnp.int32)
-    return out, jax.vmap(lambda win, n: lax.dynamic_slice_in_dim(
-        win, n, taps, axis=0))(window, n_real)
 
 
 def _dt_bias(key, shape):
@@ -483,13 +439,13 @@ class GatedDeltaNet(Module):
         qkvz = F.linear(x, p["qkvz_weight"])
         mixed, z = qkvz[..., :self.conv_dim], qkvz[..., self.conv_dim:]
         ba = F.linear(x, p["ba_weight"]).astype(jnp.float32)
-        valid = _valid_positions(st, b, t)
+        valid = valid_positions(st, b, t)
 
         with jax.named_scope("conv"):
-            tail = _conv_tail(st, "conv", jax.ShapeDtypeStruct(
+            tail = conv_tail(st, "conv", jax.ShapeDtypeStruct(
                 (b, taps, self.conv_dim), mixed.dtype))
-            mixed, new_tail = _causal_conv(mixed, tail, p["conv_weight"],
-                                           valid)
+            mixed, new_tail = causal_conv(mixed, tail, p["conv_weight"],
+                                          valid)
 
         hk, hv = self.num_k_heads, self.num_v_heads
         f32 = lambda a: a.astype(jnp.float32)
@@ -508,7 +464,7 @@ class GatedDeltaNet(Module):
                             kernel=(takes_step_kernel(st, t)
                                     or takes_scan_kernel(st, t, g)))
         if st is not None:
-            ctx.put_state(self._path, _advanced(
+            ctx.put_state(self._path, advanced(
                 st, t, state=state,
                 conv=new_tail.reshape(b, -1).astype(st["conv"].dtype)))
         with jax.named_scope("gate_norm"):
@@ -630,16 +586,16 @@ class KimiDeltaAttention(Module):
               if ctx.state is not None and self._path in ctx.state else None)
         b, t, _ = x.shape
         h, d, taps = self.num_heads, self.head_dim, self.conv_kernel - 1
-        valid = _valid_positions(st, b, t)
+        valid = valid_positions(st, b, t)
         f32 = lambda a: a.astype(jnp.float32)
 
         qkv, tails = [], {}
         for c in self._CONVS:
             mixed = F.linear(x, p[f"{c}_weight"])
             with jax.named_scope("conv"):
-                tail = _conv_tail(st, f"conv_{c}", jax.ShapeDtypeStruct(
+                tail = conv_tail(st, f"conv_{c}", jax.ShapeDtypeStruct(
                     (b, taps, self.proj_dim), mixed.dtype))
-                mixed, tails[f"conv_{c}"] = _causal_conv(
+                mixed, tails[f"conv_{c}"] = causal_conv(
                     mixed, tail, p[f"{c}_conv_weight"], valid)
             qkv.append(f32(mixed.reshape(b, t, h, d)))
         q, k, v = qkv
@@ -662,7 +618,7 @@ class KimiDeltaAttention(Module):
                             kernel=(takes_step_kernel(st, t)
                                     or takes_scan_kernel(st, t, g)))
         if st is not None:
-            ctx.put_state(self._path, _advanced(
+            ctx.put_state(self._path, advanced(
                 st, t, state=state,
                 **{name: tail.reshape(b, -1).astype(st[name].dtype)
                    for name, tail in tails.items()}))

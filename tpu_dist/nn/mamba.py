@@ -48,9 +48,9 @@ from jax import lax
 
 from . import functional as F
 from . import init as I
-from .deltanet import (_CHUNK_PRECISION, _advanced, _causal_conv,
-                       _conv_tail, _dt_bias, _valid_positions)
+from .deltanet import _CHUNK_PRECISION, _dt_bias
 from .module import Module
+from .shortconv import advanced, causal_conv, conv_tail, valid_positions
 
 __all__ = ["Mamba2", "ssd_step", "ssd_chunked"]
 
@@ -267,13 +267,13 @@ class Mamba2(Module):
                 proj = proj * jnp.asarray(self._column_scale, proj.dtype)
         z, mixed, dt = jnp.split(
             proj, [self.inner_dim, self.inner_dim + self.conv_dim], axis=-1)
-        valid = _valid_positions(st, b, t)
+        valid = valid_positions(st, b, t)
 
         with jax.named_scope("conv"):
-            tail = _conv_tail(st, "conv", jax.ShapeDtypeStruct(
+            tail = conv_tail(st, "conv", jax.ShapeDtypeStruct(
                 (b, taps, self.conv_dim), mixed.dtype))
-            mixed, new_tail = _causal_conv(mixed, tail, p["conv_weight"],
-                                           valid, p["conv_bias"])
+            mixed, new_tail = causal_conv(mixed, tail, p["conv_weight"],
+                                          valid, p["conv_bias"])
 
         xs, bm, cm = jnp.split(
             mixed, [self.inner_dim, self.inner_dim + self.bc_dim], axis=-1)
@@ -295,7 +295,7 @@ class Mamba2(Module):
                 y, state = ssd_chunked(state, xs, dt, a, bm, cm, d,
                                        chunk=self.chunk_size)
         if st is not None:
-            ctx.put_state(self._path, _advanced(
+            ctx.put_state(self._path, advanced(
                 st, t, state=state,
                 conv=new_tail.reshape(b, -1).astype(st["conv"].dtype)))
         with jax.named_scope("gate_norm"):

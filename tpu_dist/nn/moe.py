@@ -102,7 +102,7 @@ from . import init as init_lib
 
 from ..ops._pallas import ceil_to as _ceil_to, sublane_tile
 
-__all__ = ["MoELayer"]
+__all__ = ["MoELayer", "experts_around_a_common_one"]
 
 
 # -- gather dispatch: permutation as index maps, not one-hot einsums --------
@@ -222,6 +222,27 @@ def _combine_held_rows_fwd(out_flat, w, choice_for_slot, slot):
 
 
 _combine_held_rows.defvjp(_combine_held_rows_fwd, _combine_rows_bwd)
+
+
+def experts_around_a_common_one(experts: dict, key, fold: int,
+                                deviation: float) -> dict:
+    """Seeded weights for a benchmark, not a model's: a gated layer's routed
+    experts (``w1``, ``w3``, ``w2`` of its parameters ``experts``, each ``(E,
+    in, out)``) redrawn as ONE expert, U(+-1/sqrt(fan_in)) a matrix from
+    ``key`` folded with ``fold``, ``fold + 1``, ``fold + 2``, plus
+    ``deviation`` times the draw each came with.  Where every
+    expert is held, bfloat16 and float32 decide a near-tie in a router's top
+    k differently for about one token in a hundred a layer, and a swap of
+    two INDEPENDENT experts moves that token's logits as far as a precision
+    moves every token's; near-copies keep the comparison that decides
+    ``correct`` on the arithmetic (models/xing4.py, models/lfm2_moe.py)."""
+    out = dict(experts)
+    for j, name in enumerate(("w1", "w3", "w2")):
+        w = experts[name]
+        common = init_lib.torch_default_uniform(
+            jax.random.fold_in(key, fold + j), w.shape[1:], w.shape[1])
+        out[name] = common + deviation * w
+    return out
 
 
 class MoELayer(Module):

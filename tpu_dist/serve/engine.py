@@ -491,6 +491,18 @@ def residual_facts(model, params) -> dict:
                           * served_dtype(model, params).itemsize)}
 
 
+def short_conv_facts(model) -> dict:
+    """The token mixers of ``model`` that are a short convolution and
+    nothing else (each module's ``short_conv_params``;
+    :class:`tpu_dist.nn.GatedShortConv`): ``layers``, how many, and
+    ``params``, their matrices' and taps' parameters summed, of which one
+    row costs twice that in operations.  Host facts for
+    ``stats()["conv"]``, fixed at construction."""
+    sizes = [module.short_conv_params for _, module in model.named_modules()
+             if hasattr(module, "short_conv_params")]
+    return {"layers": len(sizes), "params": int(sum(sizes))}
+
+
 def beside(params, state):
     """``state`` committed where the committed leaves of ``params`` are, if
     they share one sharding; as it came if none is committed.  A program's
@@ -664,7 +676,9 @@ class SlotEngine:
         # what the residual mixes must move a row (the model's answer: 0
         # for x + f(x)), and the rows each pool program carried
         self._residual = residual_facts(model, self.params)
-        self._residual_rows = self._fresh_residual_rows()
+        self._program_rows = self._fresh_program_rows()
+        # the layers whose whole mixer is a short convolution (host facts)
+        self._short_conv = short_conv_facts(model)
         # what each bucket's prefill program builds its attention on (the
         # model's answer, asked when the bucket's program is first
         # launched, under whatever attention_impl it is traced under), and
@@ -859,7 +873,7 @@ class SlotEngine:
                     np.int32(slot), np.float32(req.temperature), key,
                     req.temperature > 0)
             self._occupy(req, slot, key)
-            self._residual_rows["prefill"] += len(req.prompt)
+            self._program_rows["prefill"] += len(req.prompt)
         self._launched(_Flight("prefill", tok_dev, [slot], [req],
                                req.t_admit, ids))
         return slot
@@ -902,7 +916,7 @@ class SlotEngine:
             self._kv_bytes += per_pos * positions
             self._need_rows += len(rows)
             self._need_positions += positions
-            self._residual_rows["decode"] += len(rows)
+            self._program_rows["decode"] += len(rows)
             if not np.array_equal(live, self._live[0]):
                 self._live = (live, jax.device_put(live))
             nxt_dev, self.cache, self._moe["decode"], self._slots = \
@@ -1160,7 +1174,7 @@ class SlotEngine:
         self._state_bytes = self._kv_bytes = 0
         self._state_steps = 0
         self._need_rows = self._need_positions = 0
-        self._residual_rows = self._fresh_residual_rows()
+        self._program_rows = self._fresh_program_rows()
         self._prefill_attn = self._fresh_prefill_attn()
         self._prefill_scan = self._fresh_prefill_scan()
         self._pipeline = self._fresh_pipeline()
@@ -1181,7 +1195,7 @@ class SlotEngine:
                 "wasted_rows": 0}
 
     @staticmethod
-    def _fresh_residual_rows() -> dict:
+    def _fresh_program_rows() -> dict:
         return {"prefill": 0, "decode": 0}
 
     def _residual_stats(self) -> dict:
@@ -1197,7 +1211,20 @@ class SlotEngine:
         facts = self._residual
         return {"streams": facts["streams"], "sublayers": facts["sublayers"],
                 **{kind: {"rows": rows, "bytes": rows * facts["row_bytes"]}
-                   for kind, rows in self._residual_rows.items()}}
+                   for kind, rows in self._program_rows.items()}}
+
+    def _conv_stats(self) -> dict:
+        """``stats()["conv"]``: the ``layers`` whose whole mixer is a short
+        convolution and their ``params`` (:func:`short_conv_facts`), and by
+        pool program since ``reset_stats()`` the ``rows`` it carried for
+        requests through every one of them (a prefill's true prompt tokens,
+        a decode step's busy slots) and the ``calls`` of the program (each
+        reads every such layer's matrices once).  Host arithmetic inside
+        ``prefill.dispatch`` / ``decode.dispatch``."""
+        launches = self._pipeline["launches"]
+        return {**self._short_conv,
+                **{kind: {"rows": rows, "calls": launches[kind]}
+                   for kind, rows in self._program_rows.items()}}
 
     @staticmethod
     def _fresh_prefill_attn() -> dict:
@@ -1353,7 +1380,8 @@ class SlotEngine:
         layer's one-token update with tpu_dist.ops.delta_step (the
         model's ``slot_state_kernel``; 0 for a model without such a
         layer).  ``"decode_need"``: :meth:`_decode_need_stats`.
-        ``"residual"``: :meth:`_residual_stats`.  ``"prefill_attn"``:
+        ``"residual"``: :meth:`_residual_stats`.  ``"conv"``:
+        :meth:`_conv_stats`, for a model with such layers.  ``"prefill_attn"``:
         :meth:`_count_prefill_attn`.  ``"prefill_scan"``:
         :meth:`_count_prefill_scan`.
         ``"params"``: what :func:`place_params` did at construction;
@@ -1369,6 +1397,8 @@ class SlotEngine:
             "decode_attn": self._decode_attn_stats(),
             "decode_need": self._decode_need_stats(since),
             "residual": self._residual_stats(),
+            **({"conv": self._conv_stats()}
+               if self._short_conv["layers"] else {}),
             "prefill_attn": dict(self._prefill_attn),
             "prefill_scan": dict(self._prefill_scan),
             "state": {"state_bytes": int(self._state_bytes),
