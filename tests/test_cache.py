@@ -208,13 +208,15 @@ def test_prefill_into_slot_is_prefill_rows_then_the_slot_write(kind):
 
     logits, new_pool, new_counters = jax.jit(model.prefill_into_slot)(
         params, prompt, 11, 2, pool, counters)
+    # the rows hold the prompt's padded columns, not the pool's 64: they
+    # land at column 0 of the slot and the rest of it stays (PR 48)
     row, rows, counted = jax.jit(
-        lambda p, t, n, c: model.prefill_rows(p, t, n, 64, counters=c))(
+        lambda p, t, n, c: model.prefill_rows(p, t, n, 16, counters=c))(
             params, prompt, 11, counters)
     np.testing.assert_array_equal(np.asarray(logits), np.asarray(row))
     _assert_trees_equal(new_pool, nn.cache.write_slot_rows(pool, rows, 2))
     _assert_trees_equal(new_counters, counted)
-    assert nn.cache.extent(rows) == nn.cache.extent(pool)
+    assert nn.cache.extent(rows) == (16, nn.cache.extent(pool)[1])
     if kind == "routed":
         c = new_counters["block1.mlp"]
         base = counters["block1.mlp"]
@@ -352,7 +354,7 @@ def test_prefill_into_slot_of_a_hybrid_is_prefill_rows_then_the_write():
     logits, new_pool, _ = jax.jit(model.prefill_into_slot)(
         params, prompt, 11, 2, pool, counters)
     row, rows, _ = jax.jit(
-        lambda p, t, n, c: model.prefill_rows(p, t, n, 64, counters=c))(
+        lambda p, t, n, c: model.prefill_rows(p, t, n, 16, counters=c))(
             params, prompt, 11, counters)
     np.testing.assert_array_equal(np.asarray(logits), np.asarray(row))
     _assert_trees_equal(new_pool, nn.cache.write_slot_rows(pool, rows, 2))

@@ -25,7 +25,8 @@ import pytest
 
 from tpu_dist import nn
 from tpu_dist.models import TransformerLM
-from tpu_dist.serve.engine import gathered_tables, pool_programs, row_major
+from tpu_dist.serve.engine import (gathered_tables, pool_programs,
+                                   prefill_width, row_major)
 
 SLOTS, MAX_LEN, DIM, HEADS, DEPTH = 32, 1024, 1600, 25, 2
 # The cell's vocabulary is 50257; the K/V path does not see it, and the older
@@ -96,8 +97,9 @@ def _lower(model, program, params, pool, counters, sharding, slots=SLOTS,
            bucket=MAX_LEN):
     """``serve.engine.pool_programs(model)`` — the program AS SERVED, the
     slot state and the live mask beside the donated pool (ISSUE 29) —
-    lowered for the described chip; ``slots`` of the pool, a prompt of
-    ``bucket`` positions."""
+    lowered for the described chip; ``slots`` of the pool, prompts of
+    ``bucket`` positions, as many as the engine gives a program of that
+    bucket over this pool (ISSUE 48: ``SlotEngine.prefill_width``)."""
     def arr(dtype, *shape):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
@@ -109,9 +111,11 @@ def _lower(model, program, params, pool, counters, sharding, slots=SLOTS,
     if program == "decode_step":
         return jax.jit(decode, donate_argnums=1, static_argnums=5).lower(
             params, pool, counters, state, arr(jnp.bool_, slots), False)
+    width = prefill_width(bucket, nn.cache.extent(pool)[0])
     return jax.jit(prefill, donate_argnums=1, static_argnums=9).lower(
-        params, pool, counters, state, ints(bucket), ints(), ints(),
-        arr(jnp.float32), arr(jnp.uint32, 2), False)
+        params, pool, counters, state, ints(width, bucket), ints(width),
+        ints(width), arr(jnp.float32, width), arr(jnp.uint32, width, 2),
+        False)
 
 
 def _pool_sized_results(hlo_text, ops, elements=POOL_ELEMENTS):
@@ -499,7 +503,7 @@ def mosaic_delta_step(monkeypatch):
 @pytest.mark.parametrize("program", ["decode_step", "prefill_into_slot"])
 def test_kimi_linear_pool_programs_compile_at_the_cells_shapes(
         one_chip, no_compile_cache, mosaic_gmm, mosaic_decode_attention,
-        mosaic_delta_step, mosaic_moe_combine, program):
+        mosaic_delta_step, mosaic_moe_combine, program, capsys):
     """Kimi Linear's block at the published widths, two layers (a Kimi
     Delta Attention layer over the dense MLP, a latent layer without rank
     or rope over 32 held of 256 experts), vocabulary cut, at the cell's 120
@@ -531,13 +535,22 @@ def test_kimi_linear_pool_programs_compile_at_the_cells_shapes(
     text = compiled.as_text()
     calls = re.findall(r"%(latent_decode_attention|gmm_r\d+)[.\d]* = [^\n]*"
                        r"custom_call_target=\"tpu_custom_call\"", text)
-    picks = (slots if program == "decode_step" else 256) * 8
+    # a prefill program of the 256 bucket takes four prompts (ISSUE 48):
+    # 8,192 picks, from which the combine goes by the buffer's rows
+    prompts = prefill_width(256, MAX_LEN)
+    assert prompts == 4
+    picks = (slots if program == "decode_step" else prompts * 256) * 8
     assert f"gmm_r{picks}" in calls, calls
     assert ("latent_decode_attention" in calls) == (program == "decode_step")
     state = re.escape(f"f32[{slots},32,128,128]")
     assert not [line for line in text.splitlines()
                 if re.search(r"= [^=]*%s[^=]* copy\(" % state, line)]
-    assert compiled.memory_analysis().temp_size_in_bytes < 512 << 20
+    memory = compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\n[kimi-linear, 2 of 14 layers, {slots} slots] {program}: "
+              f"arguments {memory.argument_size_in_bytes / 2**30:.3f} GiB, "
+              f"temporaries {memory.temp_size_in_bytes / 2**20:.1f} MiB")
+    assert memory.temp_size_in_bytes < 512 << 20
     if program == "decode_step":
         # ISSUE 41: the one-token update is ONE ``delta_step`` call fed the
         # donated pool's leaf itself, and nothing else in the program yields
@@ -884,7 +897,10 @@ def test_lfm2_moe_pool_programs_compile_at_the_cells_shapes(
               f"temporaries {memory.temp_size_in_bytes / 2**20:.1f} MiB")
     calls = re.findall(r"%(decode_attention|gmm_r\d+)[.\d]* = [^\n]*"
                        r"custom_call_target=\"tpu_custom_call\"", text)
-    picks = (slots if program == "decode_step" else 256) * 4
+    # a step's 1,280 picks; a prefill program's four prompts of 256 (ISSUE
+    # 48): 4,096
+    picks = (slots if program == "decode_step"
+             else prefill_width(256, MAX_LEN) * 256) * 4
     assert calls.count(f"gmm_r{picks}") == 6, calls          # two layers
     assert calls.count("decode_attention") == (program == "decode_step")
     assert len(calls) == 6 + (program == "decode_step")

@@ -929,10 +929,21 @@ def test_the_engine_serves_the_model_without_a_branch(served):
 
 def _served_logits(model, seed=0):
     """Two prompts prefilled into slots 2 and 0 (buckets 32 and 16), then
-    three decode steps: the float32 logits' bytes, hashed."""
+    three decode steps: the float32 logits' bytes, hashed.  The prefill's
+    rows are built at the POOL's extent, as ``prefill_into_slot`` built
+    them when the digests were taken (since PR 48 it builds them at the
+    bucket's, which sums a softmax over fewer masked columns: the last
+    bits of float32): the digests hold the models' forward, not that
+    choice."""
     params = model.init(jax.random.key(seed))
     pool, moe = model.init_slot_cache(3, 64), model.init_moe_counters()
-    pre, step = jax.jit(model.prefill_into_slot), jax.jit(model.decode_step)
+
+    def prefill(params, prompt, n, slot, pool, moe):
+        logits, rows, moe = model.prefill_rows(
+            params, prompt, n, *nn.cache.extent(pool), counters=moe)
+        return logits, nn.cache.write_slot_rows(pool, rows, slot), moe
+
+    pre, step = jax.jit(prefill), jax.jit(model.decode_step)
     rng = np.random.default_rng(seed)
     lengths, out = np.zeros(3, np.int32), []
     for slot, n, bucket in ((2, 21, 32), (0, 9, 16)):

@@ -577,7 +577,8 @@ def test_slot_engine_serves_the_reference_tokens_and_counts_by_hand(program):
                            + 2 * 4 * 40 * positions
                            + 3 * 7 * 4 * 16 * 16 * 16)
     # two prefills (32 and 64 buckets) of one latent layer, dense: the
-    # whole square a head
+    # whole square a head (a request's row of its program: the absent
+    # prompts beside it are prefill_absent_rows', ISSUE 48)
     assert st["prefill_attn"] == {
         "prefills": 2, "kernel_prefills": 0,
         "pairs_needed": 4 * (21 * 22 // 2 + 50 * 51 // 2),

@@ -236,7 +236,14 @@ class TestServingPhases:
         steps = stats["decode_steps"]
         assert steps > 0 and stats["completed"] == 10
         assert {phases[n]["count"] for n in DECODE} == {steps}
-        assert {phases[n]["count"] for n in PREFILL} == {10}
+        # a prefill program may carry several of the ten (ISSUE 48): the
+        # counters that say how many ride the wire's stats frame
+        pipeline = stats["pipeline"]
+        programs = pipeline["launches"]["prefill"]
+        assert 1 <= programs <= pipeline["prefill_prompts"] == 10
+        assert pipeline["prefill_absent_rows"] >= 0
+        assert pipeline["deferred_slot_steps"] >= 0
+        assert {phases[n]["count"] for n in PREFILL} == {programs}
         assert phases["stage.put"]["count"] == 10
         assert phases["sched.wait"]["count"] > 0
         assert phases["sweep"]["count"] >= steps
@@ -255,8 +262,22 @@ class TestServingPhases:
         assert served and served <= set(ids)
         for name in PREFILL + ("stage.put",):
             assert all({"req"} <= set(f) for f, *_ in by_name[name]), name
-        assert all({"req", "slot", "bucket"} <= set(f)
-                   for f, *_ in by_name["prefill.dispatch"])
+        # the program's spans carry its group's size beside its bucket
+        for name in ("prefill.prepare", "prefill.dispatch"):
+            assert all({"req", "slot", "bucket", "prompts"} <= set(f)
+                       for f, *_ in by_name[name]), name
+        assert sum(f["prompts"] for f, *_ in
+                   by_name["prefill.dispatch"]) == 10
+        # and every member's id and slot, the first of them under req / slot
+        members = [str(f["reqs"]).split("/")
+                   for f, *_ in by_name["prefill.dispatch"]]
+        assert sorted(int(r) for m in members for r in m) == sorted(ids)
+        for f, *_ in by_name["prefill.dispatch"]:
+            reqs, slots = (str(f[k]).split("/") for k in ("reqs", "slots"))
+            assert len(reqs) == len(slots) == int(f["prompts"])
+            assert int(reqs[0]) == int(f["req"])
+            assert int(slots[0]) == int(f["slot"])
+            assert len(set(slots)) == len(slots)
         # step on the spans of one decode iteration: the three phases of an
         # iteration share it and follow one another on the loop thread
         for name in DECODE:

@@ -310,6 +310,7 @@ def test_slot_engine_serves_the_reference_argmax_and_counts_rows(program):
                    for p in prompts]
         outs = [h.wait_done(300.0) for h in handles]
         moe = eng.stats()["moe"]
+        programs = eng.stats()["pipeline"]["launches"]["prefill"]
         eng.reset_stats()
         zero = eng.stats()["moe"]
     finally:
@@ -322,9 +323,13 @@ def test_slot_engine_serves_the_reference_argmax_and_counts_rows(program):
     layers, k = CFG["num_hidden_layers"], CFG["num_experts_per_tok"]
     pre, dec = moe["by_phase"]["prefill"], moe["by_phase"]["decode"]
     assert pre["rows"] == sum(map(len, prompts)) * k * layers
-    assert pre["pad_rows"] == sum(eng.bucket_for(len(p)) - len(p)
-                                  for p in prompts) * k * layers
-    assert pre["calls"] == 4 * layers
+    # a program of a bucket takes as many prompts as the pool's 128
+    # positions hold of it (ISSUE 48), so each runs 128 rows, whoever shared
+    # it: what is no request's is padding, an absent prompt's rows too
+    assert all(eng.prefill_width(b) * b == 128 for b in eng.buckets)
+    assert 1 <= programs <= 4
+    assert pre["rows"] + pre["pad_rows"] == programs * 128 * k * layers
+    assert pre["calls"] == programs * layers
     # 4 tokens a request come from decode steps (the first from prefill)
     assert dec["rows"] == 4 * 4 * k * layers
     assert (dec["rows"] + dec["pad_rows"]) == dec["calls"] * 4 * k
@@ -334,6 +339,31 @@ def test_slot_engine_serves_the_reference_argmax_and_counts_rows(program):
     assert 0 < pre["experts_hit"] <= pre["calls"] * CFG["num_experts"]
     assert zero["rows"] == zero["pad_rows"] == zero["calls"] == 0
     assert not any(zero["rows_per_expert"])
+
+
+def test_a_fixed_grouping_counts_its_rows_and_padding_exactly(program):
+    """The same four prompts through ``launch_group`` by hand, so that who
+    shares a program is the test's and not the loop's timing: three
+    programs (the 16 bucket's one, the 32 bucket's two together, the 64
+    bucket's one), each of 128 rows whatever it carries; a request's
+    positions are ``rows``, everything else of the program ``pad_rows``."""
+    model, params = program
+    eng = serve.SlotEngine(model, params, num_slots=4, max_len=128,
+                           min_bucket=16)
+    rng = np.random.default_rng(4)
+    lengths = (9, 23, 40, 17)
+    reqs = [serve.Request(rng.integers(1, CFG["vocab_size"], n).astype(
+        np.int32), 1, req_id=i + 1) for i, n in enumerate(lengths)]
+    groups = [[reqs[0]], [reqs[1], reqs[3]], [reqs[2]]]
+    assert [eng.launch_group(g) for g in groups] == [[0], [1, 2], [3]]
+    eng.collect_all()
+    layers, k = CFG["num_hidden_layers"], CFG["num_experts_per_tok"]
+    pre = eng.stats()["moe"]["by_phase"]["prefill"]
+    assert pre["calls"] == 3 * layers
+    assert pre["rows"] == sum(lengths) * k * layers
+    assert pre["pad_rows"] == (3 * 128 - sum(lengths)) * k * layers
+    assert eng.stats()["pipeline"]["prefill_absent_rows"] == 7 + 2 + 1
+    assert eng.stats()["prefill_attn"]["prefills"] == 4
 
 
 def test_a_dense_model_keeps_no_moe_counters():

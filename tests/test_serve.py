@@ -109,7 +109,9 @@ class TestSlotParity:
         padded = np.zeros(16, np.int32)
         padded[:5] = prompt
         logits, *_ = model.prefill_into_slot(params, padded, 5, 1, cache)
-        ref_cache = model.init_cache(1, 64)
+        # the prefill's rows hold the bucket's 16 columns (PR 48), so the
+        # unpadded reference attends over a cache of as many
+        ref_cache = model.init_cache(1, 16)
         ref_logits, _ = model.apply(params, jnp.asarray(prompt)[None, :],
                                     state=ref_cache)
         np.testing.assert_array_equal(np.asarray(logits),

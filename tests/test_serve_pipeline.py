@@ -215,7 +215,14 @@ def test_launch_ahead_loop_gives_the_serial_loops_tokens(dense, name):
     assert s["launched_ahead"] == {"decode": 0, "prefill": 0}
     assert s["wasted_rows"] == 0
     p = engine.stats()["pipeline"]
-    assert p["launches"]["prefill"] == n
+    # every request went through a prefill program, the first wave sharing
+    # theirs (a 16 bucket of a 64 pool takes four prompts a program)
+    assert p["prefill_prompts"] == n == s["prefill_prompts"]
+    assert s["launches"]["prefill"] == n
+    assert p["launches"]["prefill"] < n
+    width = engine.prefill_width(16)
+    assert p["prefill_absent_rows"] == \
+        p["launches"]["prefill"] * width - n
     assert p["launches"]["decode"] == engine.stats()["decode_steps"]
     assert p["launched_ahead"]["decode"] > 0
     if name == "slot_reuse":
@@ -284,9 +291,12 @@ def test_halves_by_hand_count_what_was_launched_ahead(dense):
     assert engine.collect_all() == 2 and engine.idle()
     assert engine.collect() == 0                    # nothing in flight
     assert steps == 3
+    width = engine.prefill_width(16)
     assert engine.stats()["pipeline"] == {
         "launches": {"decode": 3, "prefill": 2},
-        "launched_ahead": {"decode": 3, "prefill": 1}, "wasted_rows": 0}
+        "launched_ahead": {"decode": 3, "prefill": 1}, "wasted_rows": 0,
+        "prefill_prompts": 2, "prefill_absent_rows": 2 * (width - 1),
+        "deferred_slot_steps": 0}
     for req, prompt in zip(reqs, prompts):
         ref = model.generate(params, jnp.asarray(prompt)[None, :], 4)
         assert outs[req.id] == np.asarray(ref)[0, len(prompt):].tolist()
@@ -295,7 +305,9 @@ def test_halves_by_hand_count_what_was_launched_ahead(dense):
     engine.reset_stats()
     assert engine.stats()["pipeline"] == {
         "launches": {"decode": 0, "prefill": 0},
-        "launched_ahead": {"decode": 0, "prefill": 0}, "wasted_rows": 0}
+        "launched_ahead": {"decode": 0, "prefill": 0}, "wasted_rows": 0,
+        "prefill_prompts": 0, "prefill_absent_rows": 0,
+        "deferred_slot_steps": 0}
     assert engine.steps_done == 3
 
 

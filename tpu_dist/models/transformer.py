@@ -427,21 +427,34 @@ class TransformerLM(nn.Module):
                           counters=None):
         """Prefill ONE request into slot ``slot`` of a slot-cache pool
         while other slots' rows are untouched — the admission half of
-        continuous batching: :meth:`prefill_rows` at the pool's own extent,
-        then ``nn.cache.write_slot_rows``.
+        continuous batching: :meth:`prefill_rows` at the PROMPT's padded
+        extent and in the pool's type, then ``nn.cache.write_slot_rows``
+        (the rows' columns land at column 0 of the slot; the slot's columns
+        past them keep what its last request left, which no decode step
+        attends before it has overwritten it).
 
-        ``prompt``: (P,) int tokens, padded past ``length`` with any valid
+        ``prompt``: (S,) int tokens, padded past ``length`` with any valid
         token id (padding K/V lands at positions ``>= length``, which
         every later decode step either masks out or overwrites before
         attending).  ``length``: true token count (traced OK).  Returns
         ``(last-real-token logits (vocab,), new_cache, new_counters)`` —
         sample the request's first generated token from those logits.  One
         padded prompt length = one compiled program; bucket prompt lengths
-        to bound retraces."""
+        to bound retraces.
+
+        SEVERAL requests in one forward: ``prompt`` (P, S), ``length`` and
+        ``slot`` (P,); row ``i`` lands in ``slot[i]`` and the logits are
+        (P, vocab).  Every layer's weights are read once for the P prompts.
+        A row of ``length`` 0 is an ABSENT prompt: all its positions are
+        padding (the counters take them as such), it leaves nothing in
+        the pool and its logits are nobody's."""
         logits, rows, counters = self.prefill_rows(
-            params, prompt, length, *nn.cache.extent(cache),
-            counters=counters)
-        return logits, nn.cache.write_slot_rows(cache, rows, slot), counters
+            params, prompt, length, jnp.shape(prompt)[-1],
+            nn.cache.extent(cache)[1], counters=counters)
+        # a program of one prompt has no absent row
+        present = (jnp.asarray(length) > 0 if jnp.size(slot) > 1 else None)
+        return (logits, nn.cache.write_slot_rows(cache, rows, slot, present),
+                counters)
 
     def prefill_rows(self, params, prompt, length, max_len,
                      dtype=jnp.float32, prefix_rows=None, prefix_len=0,
@@ -465,26 +478,41 @@ class TransformerLM(nn.Module):
         new_counters)`` where ``rows`` are full-width per-layer entries,
         ``k``/``v`` of shape ``(1, H, D, max_len)`` — the pool's own order,
         time last, so the slot write lands them without a transpose.  One
-        padded suffix length = one compiled program."""
+        padded suffix length = one compiled program.
+
+        ``prompt`` (P, S) with ``length`` (P,) is a GROUP of whole prompts
+        in one forward at batch P: logits (P, vocab), each row's at its own
+        last real position, and batch-P ``rows``.  A prefix hit's suffix
+        goes alone."""
         prompt = jnp.asarray(prompt)
-        real = jnp.asarray(length, jnp.int32)   # the prompt's true tokens
+        group = prompt.ndim == 2
+        prompts = prompt if group else prompt[None, :]
+        # each prompt's true tokens
+        real = jnp.asarray(length, jnp.int32).reshape(-1)
         if prefix_rows is None:
-            rows = self.init_slot_cache(1, max_len, dtype)
-            # a whole prompt from position 0, known while tracing: a layer
+            rows = self.init_slot_cache(prompts.shape[0], max_len, dtype)
+            # whole prompts from position 0, known while tracing: a layer
             # may stop at the columns such a call can see
             # (nn.MultiheadLatentAttention.forward)
             start, offset = 0, None
         else:
+            if group:
+                raise ValueError(
+                    "a prefix hit's suffix is prefilled alone: its rows "
+                    "start at the hit's own position, which a group of "
+                    f"{prompts.shape[0]} prompts does not share")
             rows = prefix_rows
             start = offset = jnp.asarray(prefix_len, jnp.int32)
             real = real - start
         state = nn.cache.call_state(
             rows, start, counters,
-            valid=(jnp.arange(prompt.shape[0]) < real)[None, :])
-        logits, state = self.apply(params, prompt[None, :],
-                                   pos_offset=offset, state=state)
-        return (jax.lax.dynamic_index_in_dim(logits[0], real - 1, axis=0,
-                                             keepdims=False),
+            valid=jnp.arange(prompts.shape[1])[None, :] < real[:, None])
+        logits, state = self.apply(params, prompts, pos_offset=offset,
+                                   state=state)
+        last = [jax.lax.dynamic_index_in_dim(row, n - 1, axis=0,
+                                             keepdims=False)
+                for row, n in zip(logits, real)]
+        return (jnp.stack(last) if group else last[0],
                 *nn.cache.split_state(state, counters))
 
     def generate(self, params, prompt, max_new_tokens: int,

@@ -244,18 +244,44 @@ def split_state(state, counters=None):
 
 # -- the slot write -----------------------------------------------------------
 
-def write_slot_rows(cache, rows, slot):
+def write_slot_rows(cache, rows, slot, present=None):
     """ONE request's batch-1 ``rows`` into slot ``slot`` of the pool
     ``cache``, every other slot untouched: the one way rows land in a pool,
     whether the engine prefilled them or a prefill rank sent them
     (serve/disagg.py).  ``rows`` may hold fewer columns than the pool (a
     bucket's worth lands at column 0); a state leaf lands entire, so
     nothing of the slot's last request is left.  The update is on the slot
-    axis alone, so it is in place in a donated pool."""
+    axis alone, so it is in place in a donated pool.
+
+    A vector ``slot`` (P,) lands row ``i`` of batch-P ``rows`` in
+    ``slot[i]`` (a prefill program of several prompts).  ``present`` (P,)
+    bool marks the rows that are a request's (None: all); an absent row is
+    never written.  The rows land in a loop a LAYER's entry over the
+    present rows: the program's text does not grow with P x leaves (16
+    rows of 48 layers written out tripled it and the set-up's lowering with
+    it), and a layer's rows are dead once they have landed, where one loop
+    at the end kept every layer's alive beside the pool (PERF.md section
+    6, PR 48)."""
     slot = jnp.asarray(slot, jnp.int32)
+    update = lambda leaf, new, at: jax.lax.dynamic_update_slice(
+        leaf, new.astype(leaf.dtype), (at,) + (0,) * (leaf.ndim - 1))
+
+    def land(entry, new):
+        if slot.size == 1:
+            return {name: update(leaf, new[name], slot.reshape(()))
+                    for name, leaf in entry.items()}
+
+        def body(k, entry):
+            i = order[k]
+            return {name: update(leaf, jax.lax.dynamic_slice_in_dim(
+                new[name], i, 1, axis=0), slot[i])
+                for name, leaf in entry.items()}
+        return jax.lax.fori_loop(0, count, body, entry)
+
+    if slot.size > 1:
+        if present is None:
+            present = jnp.ones(slot.shape, bool)
+        order = jnp.argsort(~present, stable=True)  # a request's rows lead
+        count = present.sum()
     with jax.named_scope("cache_write"):
-        return {path: {
-            name: jax.lax.dynamic_update_slice(
-                leaf, rows[path][name].astype(leaf.dtype),
-                (slot,) + (0,) * (leaf.ndim - 1))
-            for name, leaf in entry.items()} for path, entry in cache.items()}
+        return {path: land(entry, rows[path]) for path, entry in cache.items()}

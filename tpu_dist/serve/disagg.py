@@ -47,7 +47,7 @@ import os
 import queue
 import threading
 import time
-from typing import Callable, Dict, Optional
+from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
@@ -352,7 +352,12 @@ class DisaggSlotEngine(SlotEngine):
 
     # -- admission: inject instead of prefill ---------------------------------
 
-    def _admit(self, req: Request, slot: int) -> int:
+    def prefill_width(self, bucket: int) -> int:
+        """One: each request's rows arrive prefilled, by themselves."""
+        return 1
+
+    def _admit(self, group: List[tuple]) -> None:
+        (req, slot), = group
         arrival = req.staged
         if not isinstance(arrival, dict) or "rows" not in arrival:
             raise DisaggError(f"request {req.id} reached disagg admission "
@@ -392,7 +397,6 @@ class DisaggSlotEngine(SlotEngine):
         self.generated_tokens += 1
         self._maybe_finish(slot, tok)
         self._flush()
-        return slot
 
     def _obs_transfer(self, req: Request, arrival: dict,
                       xfer: float) -> None:

@@ -12,12 +12,17 @@ run-to-completion barrier that leaves most of a static batch idle
 
 Two compiled programs drive the pool (tpu_dist/models/transformer.py):
 
-- ``prefill_into_slot``: one request's (bucket-padded) prompt fills ONE
+- ``prefill_into_slot``: a request's (bucket-padded) prompt fills ONE
   cache slot in a single forward — the other slots' rows are untouched,
   so admission never disturbs in-flight decodes.  One padded length = one
   XLA program; prompt lengths are padded to power-of-two buckets to bound
   retraces (padding K/V is masked or overwritten before it is ever
-  attended — token-identical to the unpadded prefill, tested).
+  attended — token-identical to the unpadded prefill, tested).  The
+  program of a bucket takes SEVERAL prompts, each into its own slot, as
+  many as the pool's largest bucket holds of that bucket
+  (:func:`prefill_width`, from the two shapes alone): a layer's weights
+  are read once for all of them.  A program with fewer requests than its
+  width carries absent prompts (length 0), which touch no slot.
 - ``decode_step``: ONE batched iteration over the whole pool — each slot
   appends at its own length and samples its next token on device.  This
   is the same method ``generate``'s scan runs, so serving output is
@@ -337,6 +342,15 @@ def _bucket_lengths(max_prompt: int, min_bucket: int = 16) -> List[int]:
     return out
 
 
+def prefill_width(bucket: int, max_len: int) -> int:
+    """Prompts ONE prefill program of ``bucket`` positions takes over a pool
+    of ``max_len``: as many whole buckets as the pool's largest bucket
+    (``max_len``) holds rows, rounded down to a power of two, so a prefill
+    never holds more prompt rows than the one a legal request may need
+    anyway.  From the two shapes alone."""
+    return 1 << (max(1, int(max_len) // int(bucket)).bit_length() - 1)
+
+
 def seed_key(seed: int) -> np.ndarray:
     """``jax.random.key_data(jax.random.key(seed))`` of the default
     (threefry) generator, computed on the host: the words of the seed, the
@@ -376,8 +390,10 @@ def advance_rows(slots: dict, live, nxt) -> dict:
 
 def set_row(slots: dict, slot, token, length, temp, key) -> dict:
     """One slot's row as an admission leaves it: first token sampled,
-    ``length`` positions resident, step 1 of the sampling schedule."""
-    put = lambda name, value: slots[name].at[slot].set(value)
+    ``length`` positions resident, step 1 of the sampling schedule.  With
+    vectors, row ``i`` of an admission of several; a ``slot`` past the pool
+    (an absent prompt's) sets no row."""
+    put = lambda name, value: slots[name].at[slot].set(value, mode="drop")
     return {"tokens": put("tokens", token), "lengths": put("lengths", length),
             "steps": put("steps", 1), "temps": put("temps", temp),
             "keys": put("keys", key)}
@@ -521,9 +537,11 @@ def beside(params, state):
 def pool_programs(model):
     """The two pool programs of ``model``, unjitted: ``decode(params, cache,
     moe, slots, live, sampling)`` and ``prefill(params, cache, moe, slots,
-    prompt, length, slot, temp, key, sampling)``.  Each returns its sampled
-    token(s), then the cache, the counters and the slot state it was
-    given, updated."""
+    prompts (P, bucket), lengths (P,), into (P,), temps (P,), keys (P, 2),
+    sampling)``, ``P`` prompts in one forward, row ``i`` into slot
+    ``into[i]``; a row of length 0 is an absent prompt and touches no slot.
+    Each returns its sampled tokens, then the cache, the counters and the
+    slot state it was given, updated."""
     import jax
     import jax.numpy as jnp
 
@@ -537,16 +555,18 @@ def pool_programs(model):
                                 sampling)
             return nxt, cache, moe, advance_rows(slots, live, nxt)
 
-    def prefill(params, cache, moe, slots, prompt, length, slot, temp, key,
-                sampling):
+    def prefill(params, cache, moe, slots, prompts, lengths, into, temps,
+                keys, sampling):
         with jax.named_scope("prefill"):
             logits, cache, moe = model.prefill_into_slot(
-                params, prompt, length, slot, cache, moe)
+                params, prompts, lengths, into, cache, moe)
         with jax.named_scope("sample"):
-            tok = sample_tokens(logits[None], temp[None], key[None],
-                                jnp.zeros((1,), jnp.int32), sampling)[0]
-            return tok, cache, moe, set_row(slots, slot, tok, length, temp,
-                                            key)
+            toks = sample_tokens(logits, temps, keys,
+                                 jnp.zeros(lengths.shape, jnp.int32),
+                                 sampling)
+            rows = jnp.where(lengths > 0, into, slots["tokens"].shape[0])
+            return toks, cache, moe, set_row(slots, rows, toks, lengths,
+                                             temps, keys)
 
     return decode, prefill
 
@@ -785,16 +805,25 @@ class SlotEngine:
                 f"({max_new_tokens}) exceeds the slot capacity "
                 f"({self.max_len})")
 
-    def stage(self, req: Request):
-        """Bucket-pad (and device-stage) a request's prompt — the work the
-        scheduler's background staging thread runs off the decode loop."""
-        import jax
+    def prefill_width(self, bucket: int) -> int:
+        """Prompts ONE prefill program of ``bucket`` positions takes
+        (:func:`prefill_width` over this pool's ``max_len``): the scheduler
+        asks here how many held requests of a bucket may share a program
+        (:meth:`launch_group`).  An engine whose admission is not a program
+        of its own (a plan on the wire for each, rows that arrive
+        prefilled) answers 1."""
+        return prefill_width(bucket, self.max_len)
 
+    def stage(self, req: Request):
+        """Bucket-pad a request's prompt — the work the scheduler's
+        background staging thread runs off the decode loop.  The padded row
+        stays on the host: a prefill program takes its group's rows as one
+        array (:meth:`_admit`)."""
         bucket = self.bucket_for(len(req.prompt))
         with span("stage.put", req=req.id, bucket=bucket):
             padded = np.zeros(bucket, np.int32)
             padded[:len(req.prompt)] = req.prompt
-            req.staged = jax.device_put(padded)
+            req.staged = padded
         return req.staged
 
     # -- the two pool operations --------------------------------------------
@@ -818,22 +847,48 @@ class SlotEngine:
 
     def launch_admit(self, req: Request) -> int:
         """The launch half of :meth:`admit`: the refusals, the slot choice
-        and the prefill's launch.  The slot is occupied from here on; its
-        first token is emitted by the :meth:`collect` of this program."""
-        slot = self._admission_slot(req)
+        and the prefill's launch — :meth:`launch_group` of one.  The slot
+        is occupied from here on; its first token is emitted by the
+        :meth:`collect` of this program."""
+        return self.launch_group([req])[0]
+
+    def launch_group(self, reqs: List[Request],
+                     refuse: Optional[Callable] = None) -> List[int]:
+        """Launch ONE prefill program for ``reqs``: requests of one bucket,
+        at most :meth:`prefill_width` of it and no more than the free
+        slots, in order.  A member that is cancelled, past its deadline or
+        does not fit is refused by name BEFORE the program:
+        ``refuse(req, error)`` is told and the others go on (with no
+        ``refuse`` the error is raised and nothing is launched).  Returns
+        the slots of the members launched, in order; an error of the
+        program itself is raised with none of them occupied."""
+        group = []
+        for req in reqs:
+            try:
+                group.append((req, self._admission_slot(req, len(group))))
+            except Exception as e:
+                if refuse is None:
+                    raise
+                refuse(req, e)
+        if not group:
+            return []
         if self._loop_kind == "prefill":
             # the scheduler admits up to a pool's worth between two sweeps
             # (a pool's first filling is ONE pass of seconds): an iteration
             # of the loop clock holds one prefill
             self._tick()
-        self._pre_admit(req, slot)
-        return self._admit(req, slot)
+        for req, slot in group:
+            self._pre_admit(req, slot)
+        self._admit(group)
+        return [slot for _, slot in group]
 
-    def _admission_slot(self, req: Request) -> int:
-        """All admission pre-checks + the deterministic slot choice (lowest
-        free index).  Split from :meth:`_admit` so the sharded engine can
-        broadcast its admission plan AFTER every refusal path has passed —
-        a follower must never prefill a slot the leader then refuses."""
+    def _admission_slot(self, req: Request, taken: int = 0) -> int:
+        """All admission pre-checks + the deterministic slot choice (the
+        lowest free index past the ``taken`` lowest, which earlier members
+        of the group have).  Split from :meth:`_admit` so the sharded
+        engine can broadcast its admission plan AFTER every refusal path
+        has passed — a follower must never prefill a slot the leader then
+        refuses."""
         if req.cancelled:
             raise RequestCancelledError(
                 f"request {req.id} was cancelled before admission")
@@ -842,41 +897,65 @@ class SlotEngine:
                 f"request {req.id} missed its deadline before admission "
                 f"(deadline_ms elapsed in the queue) — shed")
         free = np.flatnonzero(~self.active)
-        if len(free) == 0:
+        if len(free) <= taken:
             raise RuntimeError("no free slot (check free_slots() first)")
         self.validate(len(req.prompt), req.max_new_tokens)
-        return int(free[0])
+        return int(free[taken])
 
     def _pre_admit(self, req: Request, slot: int) -> None:
         """Hook between the (passed) admission checks and the prefill —
         the sharded engine's plan broadcast point."""
 
-    def _admit(self, req: Request, slot: int) -> int:
-        """The unconditional admission half: the prefill's launch and the
-        slot's occupation (every refusal already ruled out by
-        :meth:`_admission_slot`).  Uploads the request's own scalars and
-        nothing of the other slots."""
-        ids = {"req": req.id, "slot": slot}
-        req.t_admit = _now()
-        with span("prefill.prepare", **ids):
-            self.hist_queue.observe(req.t_admit - req.t_submit)
-            staged = (req.staged if req.staged is not None
-                      else self.stage(req))
-            key = seed_key(req.seed)
-        with span("prefill.dispatch", bucket=int(staged.shape[0]), **ids):
-            self._count_prefill_attn(int(staged.shape[0]), len(req.prompt))
-            self._count_prefill_scan(int(staged.shape[0]))
-            tok_dev, self.cache, self._moe["prefill"], self._slots = \
+    def _admit(self, group: List[tuple]) -> None:
+        """The unconditional admission half: ONE prefill program for the
+        ``(request, slot)`` pairs of ``group`` and the slots' occupation
+        (every refusal already ruled out by :meth:`_admission_slot`).  The
+        program is as wide as the bucket's :meth:`prefill_width`; the rows
+        past the group are absent prompts (length 0).  Uploads the group's
+        own rows and scalars and nothing of the other slots."""
+        reqs = [req for req, _ in group]
+        bucket = self.bucket_for(len(reqs[0].prompt))
+        width = self.prefill_width(bucket)
+        if len(group) > width or any(
+                self.bucket_for(len(r.prompt)) != bucket for r in reqs):
+            raise ValueError(
+                f"a prefill program of bucket {bucket} takes {width} "
+                f"prompt(s) of that bucket, not these {len(group)}")
+        # req / slot: the group's first, what a program of one always
+        # carried; reqs / slots: every member's, in the rows' order (no
+        # comma: the profiler's annotation separates its fields by them)
+        ids = {"req": reqs[0].id, "slot": group[0][1],
+               "prompts": len(group),
+               "reqs": "/".join(str(r.id) for r in reqs),
+               "slots": "/".join(str(slot) for _, slot in group)}
+        now = _now()
+        with span("prefill.prepare", bucket=bucket, **ids):
+            prompts = np.zeros((width, bucket), np.int32)
+            lengths, into = (np.zeros(width, np.int32) for _ in range(2))
+            temps = np.zeros(width, np.float32)
+            keys = np.zeros((width, 2), np.uint32)
+            for i, (req, slot) in enumerate(group):
+                req.t_admit = now
+                self.hist_queue.observe(now - req.t_submit)
+                prompts[i] = (req.staged if req.staged is not None
+                              else self.stage(req))
+                lengths[i], into[i] = len(req.prompt), slot
+                temps[i], keys[i] = req.temperature, seed_key(req.seed)
+        with span("prefill.dispatch", bucket=bucket, **ids):
+            self._count_prefill_attn(bucket, lengths)
+            self._count_prefill_scan(bucket, len(group))
+            toks_dev, self.cache, self._moe["prefill"], self._slots = \
                 self._prefill(
                     self.params, self.cache, self._moe["prefill"],
-                    self._slots, staged, np.int32(len(req.prompt)),
-                    np.int32(slot), np.float32(req.temperature), key,
-                    req.temperature > 0)
-            self._occupy(req, slot, key)
-            self._program_rows["prefill"] += len(req.prompt)
-        self._launched(_Flight("prefill", tok_dev, [slot], [req],
-                               req.t_admit, ids))
-        return slot
+                    self._slots, prompts, lengths, into, temps, keys,
+                    bool(np.any(temps > 0)))
+            for i, (req, slot) in enumerate(group):
+                self._occupy(req, slot, keys[i])
+            self._program_rows["prefill"] += int(lengths.sum())
+            self._pipeline["prefill_prompts"] += len(group)
+            self._pipeline["prefill_absent_rows"] += width - len(group)
+        self._launched(_Flight("prefill", toks_dev,
+                               [slot for _, slot in group], reqs, now, ids))
 
     def _occupy(self, req: Request, slot: int, key) -> None:
         """The host's rows of a slot whose admission was launched; its
@@ -1188,11 +1267,22 @@ class SlotEngine:
     def _fresh_pipeline() -> dict:
         """``stats()["pipeline"]``: programs launched, those launched while
         an earlier one's result was still uncollected, and the rows decode
-        steps carried for requests that had already ended.  Host
-        arithmetic, no device read."""
+        steps carried for requests that had already ended;
+        ``prefill_prompts``, the requests launched in prefill programs
+        (over ``launches["prefill"]``: prompts a program),
+        ``prefill_absent_rows``, the absent prompts those programs carried
+        to their width, and ``deferred_slot_steps``, the idle slot-steps
+        free slots spent waiting for company (:meth:`count_deferred`).
+        Host arithmetic, no device read."""
         kinds = lambda: {"decode": 0, "prefill": 0}
         return {"launches": kinds(), "launched_ahead": kinds(),
-                "wasted_rows": 0}
+                "wasted_rows": 0, "prefill_prompts": 0,
+                "prefill_absent_rows": 0, "deferred_slot_steps": 0}
+
+    def count_deferred(self, slot_steps: int) -> None:
+        """The scheduler's report, at a decode launch, of the free slots it
+        kept waiting for a fuller prefill group over that step."""
+        self._pipeline["deferred_slot_steps"] += int(slot_steps)
 
     @staticmethod
     def _fresh_program_rows() -> dict:
@@ -1231,14 +1321,16 @@ class SlotEngine:
         return {"prefills": 0, "kernel_prefills": 0, "pairs_needed": 0,
                 "pairs_executed": 0}
 
-    def _count_prefill_attn(self, bucket: int, n: int) -> None:
-        """``stats()["prefill_attn"]``: one whole-prompt prefill of ``n``
-        true tokens in a program of ``bucket`` positions.  ``prefills``;
+    def _count_prefill_attn(self, bucket: int, lengths) -> None:
+        """``stats()["prefill_attn"]``: one prefill program of ``bucket``
+        positions a row, ``lengths`` its rows' true tokens (0: an absent
+        prompt).  ``prefills``, the whole prompts prefilled;
         ``kernel_prefills``, those whose program's attention is the causal
         flash forward kernel (``model.prefill_attention_facts``: a model
         without a layer that says reports 0); ``pairs_needed``, ``n (n +
         1) / 2`` (query, key) pairs a head a layer of those; and
-        ``pairs_executed``, what the program executed for them:
+        ``pairs_executed``, what the program executed for them (an absent
+        prompt's row is ``prefill_absent_rows``' to count, not this one's):
         ``tile_plan``'s sub-tiles on the kernel, ``bucket ** 2`` dense.
         Host arithmetic inside ``prefill.dispatch``."""
         facts = self._prefill_attn_facts.get(bucket)
@@ -1247,17 +1339,19 @@ class SlotEngine:
                 self.model.prefill_attention_facts(
                     bucket, served_dtype(self.model, self.params))
         count = self._prefill_attn
-        count["prefills"] += 1
-        count["kernel_prefills"] += int(facts["kernel"])
-        count["pairs_needed"] += facts["heads"] * (n * (n + 1) // 2)
-        count["pairs_executed"] += facts["pairs_executed"]
+        real = [int(n) for n in lengths if n]
+        count["prefills"] += len(real)
+        count["kernel_prefills"] += len(real) * int(facts["kernel"])
+        count["pairs_needed"] += facts["heads"] * sum(
+            n * (n + 1) // 2 for n in real)
+        count["pairs_executed"] += len(real) * facts["pairs_executed"]
 
     @staticmethod
     def _fresh_prefill_scan() -> dict:
         return {"prefills": 0, "kernel_prefills": 0}
 
-    def _count_prefill_scan(self, bucket: int) -> None:
-        """``stats()["prefill_scan"]``: one whole-prompt prefill in a
+    def _count_prefill_scan(self, bucket: int, prompts: int) -> None:
+        """``stats()["prefill_scan"]``: ``prompts`` whole prompts in one
         program of ``bucket`` positions.  ``prefills``; ``kernel_prefills``,
         those whose program computes every recurrent layer's scan with
         tpu_dist.ops.delta_scan (``model.prefill_scan_kernel``, asked once
@@ -1267,8 +1361,8 @@ class SlotEngine:
         if kernel is None:
             kernel = self._prefill_scan_kernel[bucket] = \
                 self.model.prefill_scan_kernel(self.cache, bucket)
-        self._prefill_scan["prefills"] += 1
-        self._prefill_scan["kernel_prefills"] += int(kernel)
+        self._prefill_scan["prefills"] += prompts
+        self._prefill_scan["kernel_prefills"] += prompts * int(kernel)
 
     def _moe_read(self) -> dict:
         """The routed-row counters per pool program, each stacked over the

@@ -145,8 +145,7 @@ class Scheduler:
             # a queue nobody reads.  Fail it by name (idempotent if the
             # close-side drain already did) and refuse the submit.
             exc = self._closed_error()
-            self.engine._obs_end(req, error_outcome(exc))
-            req.fail(exc)
+            self._fail(req, exc)
             raise exc
         return handle
 
@@ -243,14 +242,12 @@ class Scheduler:
             try:
                 shed = self._shed_stale(req)
                 if shed is not None:
-                    self.engine._obs_end(req, error_outcome(shed))
-                    req.fail(shed)
+                    self._fail(req, shed)
                     continue
                 try:
                     self.engine.stage(req)
                 except Exception as e:   # bad request: not a stage killer
-                    self.engine._obs_end(req, error_outcome(e))
-                    req.fail(e)
+                    self._fail(req, e)
                     continue
                 placed = False
                 while not self._stop.is_set():
@@ -265,8 +262,7 @@ class Scheduler:
                     # it still terminates with the named error, never
                     # silently
                     exc = self._closed_error()
-                    self.engine._obs_end(req, error_outcome(exc))
-                    req.fail(exc)
+                    self._fail(req, exc)
             finally:
                 # the pending pop is fully handled (staged OR failed) —
                 # this is what lets drain()'s quiesced predicate see a
@@ -294,8 +290,7 @@ class Scheduler:
     def _drain_failed(self, req: Request) -> None:
         exc = SchedulerDrainingError("request rejected: scheduler started "
                                      "draining before it was admitted")
-        self.engine._obs_end(req, error_outcome(exc))
-        req.fail(exc)
+        self._fail(req, exc)
 
     def _reject_queued(self) -> None:
         """Drain mode: everything accepted but not yet admitted fails with
@@ -319,8 +314,7 @@ class Scheduler:
                     req = q.get_nowait()
                 except queue.Empty:
                     break
-                self.engine._obs_end(req, error_outcome(exc))
-                req.fail(exc)
+                self._fail(req, exc)
                 if count:
                     q.task_done()
 
@@ -335,14 +329,47 @@ class Scheduler:
         self._stop.set()
         return False
 
-    def _admit(self, req: Request) -> bool:
-        """Launch one admission ahead of the program in flight, then collect
-        what came before it; False = fatal engine death (stop set)."""
+    def _fail(self, req: Request, exc: BaseException) -> None:
+        """One request that will not be served fails by name."""
+        self.engine._obs_end(req, error_outcome(exc))
+        req.fail(exc)
+
+    def _next_group(self, held: list) -> tuple:
+        """``(group, wanted)``: the oldest held request and the held
+        requests of ITS bucket behind it, in order, up to the width of that
+        bucket's prefill program (``engine.prefill_width``); and how many
+        the program would carry if the free slots waited for company: the
+        width, or as many requests as wait for a prefill of THAT bucket
+        (the held ones, and those staged behind them: a request of another
+        bucket is no company, however long the slots wait)."""
+        of = lambda req: self.engine.bucket_for(len(req.prompt))
+        bucket = of(held[0])
+        width = self.engine.prefill_width(bucket)
+        mates = [req for req in held if of(req) == bucket]
+        if len(mates) >= width:
+            return mates[:width], width
+        with self._staged.mutex:
+            staged = list(self._staged.queue)
+        return mates, min(width, len(mates) + sum(
+            of(req) == bucket for req in staged))
+
+    def _admit(self, group: list) -> bool:
+        """Launch one prefill program for ``group`` ahead of the program in
+        flight, then collect what came before it; False = fatal engine
+        death (stop set).  A member the engine refuses fails by its own
+        named error and the others are served; a program that fails fails
+        the members it carried, each by name."""
+        left = list(group)
+
+        def refuse(req, exc):
+            left.remove(req)
+            self._fail(req, exc)
+
         try:
-            self.engine.launch_admit(req)
+            self.engine.launch_group(group, refuse)
         except Exception as e:   # a bad request must not kill the loop
-            self.engine._obs_end(req, error_outcome(e))
-            req.fail(e)
+            for req in left:
+                self._fail(req, e)
             fatal = getattr(self.engine, "fatal_error", None)
             if fatal is not None:
                 # the failure poisoned the ENGINE, not just the request
@@ -353,7 +380,8 @@ class Scheduler:
                 return self._fail_fatal(fatal)
             return True
         finally:
-            self._staged.task_done()
+            for _ in group:
+                self._staged.task_done()
         return self._collect(self.engine.settle)
 
     def _sweep_once(self) -> bool:
@@ -410,6 +438,10 @@ class Scheduler:
         # whose token is dropped at collection.
         held = []            # staged requests inside the coalescing window
         window_start = None
+        # free slots wait for a fuller prefill group only so long: the idle
+        # slot-steps spent since the waiting began (free slots x decode
+        # steps launched), against one step's worth of the pool
+        deferred = 0
         while not self._stop.is_set():
             if self._draining.is_set():
                 # drain mode: NOTHING new reaches the engine — reject the
@@ -442,12 +474,18 @@ class Scheduler:
             if not self._sweep_once():
                 break
             # -- admission, between decode iterations ------------------------
-            # a busy pool admits immediately (the iteration boundary IS the
-            # batching point); an idle pool holds the first prefill for up
-            # to batch_window so closely-spaced arrivals group up.  Each
+            # a busy pool admits at the iteration boundary; an idle pool
+            # holds the first prefill for up to batch_window so
+            # closely-spaced arrivals group up.  One prefill program takes
+            # the oldest held request and those of its bucket behind it
+            # (_next_group).  It goes at once when it is as full as it can
+            # get (the program's width, or every request that waits), and
+            # when no slot is active; otherwise the free slots wait for
+            # company, for at most num_slots idle slot-steps.  Each
             # admission's collection may free slots: staged arrivals are
             # pulled again for them, a pool's worth at most per iteration
             admitted = 0
+            company = False     # free slots are waiting for company
             while not self._stop.is_set():
                 # pull staged arrivals (never beyond the free slots)
                 while len(held) < self.engine.free_slots():
@@ -464,15 +502,27 @@ class Scheduler:
                       or self.batch_window <= 0)
                 if not (held and go) or admitted >= self.engine.num_slots:
                     break
-                admitted += 1
-                if not self._admit(held.pop(0)):
+                group, wanted = self._next_group(held)
+                if (len(group) < wanted and self.engine.active_count()
+                        and deferred < self.engine.num_slots):
+                    company = True
+                    break
+                deferred = 0
+                admitted += len(group)
+                held = [req for req in held if req not in group]
+                if not self._admit(group):
                     break
             if self._stop.is_set():
                 break
             if not held:
                 window_start = None
+                deferred = 0
             # -- one decode iteration over the pool --------------------------
             if not self.engine.idle():
+                if company:
+                    waiting = self.engine.free_slots()
+                    deferred += waiting
+                    self.engine.count_deferred(waiting)
                 if not self._step_once():
                     break
             elif held:
@@ -492,8 +542,7 @@ class Scheduler:
         # loop exit: requests still held in the window are not dropped
         exc = self._closed_error()
         for req in held:
-            self.engine._obs_end(req, error_outcome(exc))
-            req.fail(exc)
+            self._fail(req, exc)
             self._staged.task_done()
         if self._fatal is not None:
             # fatal engine death: close() early-returns once _stop is set,

@@ -662,12 +662,16 @@ class ShardedSlotEngine(SlotEngine):
                                          slots["steps"], sampling)
             return nxt, cache, counters, advance_rows(slots, live, nxt)
 
-        def _prefill(params, cache, counters, slots, prompt, length, slot,
-                     temp, key, sampling):
-            tok, cache = dec.prefill_pool(params, cache, prompt, length,
-                                          slot, temp, key, sampling)
-            return tok, cache, counters, set_row(slots, slot, tok, length,
-                                                 temp, key)
+        # a program of ONE prompt (prefill_width): the group's arrays hold
+        # one row
+        def _prefill(params, cache, counters, slots, prompts, lengths, into,
+                     temps, keys, sampling):
+            tok, cache = dec.prefill_pool(params, cache, prompts[0],
+                                          lengths[0], into[0], temps[0],
+                                          keys[0], sampling)
+            return tok, cache, counters, set_row(slots, into[0], tok,
+                                                 lengths[0], temps[0],
+                                                 keys[0])
 
         self._decode = _decode
         self._prefill = _prefill
@@ -732,9 +736,13 @@ class ShardedSlotEngine(SlotEngine):
         if err is not None:
             raise err
 
-    def _admit(self, req: Request, slot: int) -> int:
+    def prefill_width(self, bucket: int) -> int:
+        """One: every admission is a plan on the wire of its own."""
+        return 1
+
+    def _admit(self, group: List[tuple]) -> None:
         try:
-            return super()._admit(req, slot)
+            super()._admit(group)
         except Exception as e:
             # the admit plan is already on the wire (the followers have
             # prefilled this slot and advanced their tag counters): a
