@@ -1,7 +1,9 @@
 """What the loop clock costs (ISSUE 34): a ``span`` on a thread with no
 clock, a ``span`` on the thread that owns one, and a ``tick``, each in a loop
 of 10^5 on the host's CPU (best of 15; the empty loop subtracted); then the
-system calls a tick makes, each alone, and the step of the thread's CPU clock.
+system calls a tick makes, each alone, and the step of the thread's CPU clock;
+and (ISSUE 49) what the compile ledger's listeners do for one program and
+what ``ensure_compile_cache()`` costs when called again.
 
     JAX_PLATFORMS=cpu python3 benchmarks/bench_loop_clock.py
 
@@ -10,6 +12,7 @@ Host numbers only: nothing here touches a device.
 
 from __future__ import annotations
 
+import importlib
 import json
 import os
 import resource
@@ -82,5 +85,46 @@ def measure(n: int = N) -> dict:
     return out
 
 
+def measure_ledger(n: int = 2000) -> dict:
+    """What the compile ledger costs (ISSUE 49), nanoseconds each: the
+    listeners' work for ONE program (three starts, three durations and the
+    cache's two events, as JAX fires them once a compilation and never in a
+    warmed loop) and ``ensure_compile_cache()``'s second call.  None on a
+    tree from before the ledger.  The ``n`` records land in this process's
+    ledger under the name ``bench.cost``."""
+    from tpu_dist.utils import ensure_compile_cache
+    ensure_compile_cache()
+
+    def again():
+        for i in range(n):
+            ensure_compile_cache()
+
+    def empty():
+        for i in range(n):
+            pass
+
+    base = _best(empty, 5)
+    out = {"n": n, "ensure_compile_cache_again_ns":
+           (_best(again, 5) - base) / n * 1e9, "program_ns": None}
+    try:    # by name: ``tpu_dist.obs.compiles`` the attribute is a function
+        ledger = importlib.import_module("tpu_dist.obs.compiles")
+    except ImportError:
+        return out
+    stages = ((ledger._TRACE, "bench.cost"), (ledger._LOWER, "jit(bench.cost)"),
+              (ledger._BACKEND, "jit(bench.cost)"))
+
+    def programs():
+        for i in range(n):
+            for event, name in stages:
+                if event == ledger._BACKEND:
+                    ledger._on_event(ledger._ASKED)
+                    ledger._on_event(ledger._KEPT)
+                ledger._on_scalar(event, 0.0, fun_name=name)
+                ledger._on_duration(event, 1e-9, fun_name=name)
+
+    out["program_ns"] = (_best(programs, 5) - base) / n * 1e9
+    return out
+
+
 if __name__ == "__main__":
-    print(json.dumps(measure()))
+    print(json.dumps(dict(measure(), ledger=measure_ledger())))

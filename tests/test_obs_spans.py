@@ -613,12 +613,15 @@ class TestServingLoopClock:
         assert set(loop) == {
             "iterations", "wall_s", "covered_s", "unnamed_s", "wait_s",
             "cpu_s", "cpu_others_s", "offcpu_s", "gc_s", "gc_collections",
-            "iteration", "longest"}
+            "compile_s", "compiles", "iteration", "longest"}
         pre, = loop["longest"]["prefill"]
         assert {"prefill.dispatch", "prefill.readback", "decode.dispatch",
                 "sweep", "stage.put"} <= set(pre["by_phase"])
-        # stage.put ran inline, inside prefill.prepare: counted once
-        top = sum(v for k, v in pre["by_phase"].items() if k != "stage.put")
+        # stage.put ran inline, inside prefill.prepare: counted once; and
+        # the first dispatches compiled, inside their spans (ISSUE 49)
+        top = sum(v for k, v in pre["by_phase"].items()
+                  if k not in ("stage.put", "compile"))
+        assert pre["compiled"] == ["prefill", "decode"]
         assert pre["wall"] - pre["unnamed"] == pytest.approx(top)
         assert [r["step"] for r in loop["longest"]["decode"]] in ([2, 3],
                                                                   [3, 2])

@@ -287,11 +287,15 @@ def init_process_group(backend: Optional[str] = None,
         _rdzv.rendezvous(init_method, world_size=world_size, rank=rank,
                          timeout=timeout)
 
-        resolve_backend(backend)
+        from ..obs.spans import span
         from ..utils.compile_cache import ensure_compile_cache
         ensure_compile_cache()
         import jax
-        group = ProcessGroup(jax.devices(), axis_names=axis_names,
+        # the runtime's start, where this is the first touch of the backend
+        with span("setup.devices"):
+            resolve_backend(backend)
+            devices = jax.devices()
+        group = ProcessGroup(devices, axis_names=axis_names,
                              mesh_shape=mesh_shape)
         _DEFAULT_GROUP = group
         return group

@@ -32,7 +32,12 @@ the scheduler's wait, the training step's dispatch) on the profiler's
 clock and into :func:`phase_times` — always on, for time on the chip
 rather than hangs of the host collectives.  A :class:`LoopClock` closes the
 books on every iteration of one loop thread over those spans: by phase,
-CPU, garbage collection (``td/gc``) and time off the CPU.
+CPU, garbage collection (``td/gc``) and time off the CPU.  And
+:mod:`.compiles` keeps the compile ledger: one record a program JAX traced,
+lowered and compiled or loaded from the persistent cache, with the span and
+the loop iteration it fell in (:func:`compiles` reads it; the function
+shadows the module's name here, so reach the module's other names with
+``from tpu_dist.obs.compiles import ...``).
 """
 
 from . import hooks, recorder, spans, trace
@@ -42,11 +47,12 @@ from .recorder import (FlightRecorder, default_dump_dir, dump_now, dump_path,
                        enabled, get_recorder, obs_key, record_transport,
                        reset, reset_transport_counters, transport_counters)
 from .spans import LoopClock, phase_times, reset_phases, span
+from .compiles import compiles
 from .trace import diagnose, merge_trace, read_dumps, render_diagnosis
 
 __all__ = [
     "recorder", "hooks", "trace", "spans",
-    "span", "phase_times", "reset_phases", "LoopClock",
+    "span", "phase_times", "reset_phases", "LoopClock", "compiles",
     "FlightRecorder", "enabled", "get_recorder", "reset", "dump_now",
     "record_transport", "transport_counters", "reset_transport_counters",
     "obs_key", "default_dump_dir", "dump_path",

@@ -58,6 +58,11 @@ def _hist(name: str) -> LatencyHistogram:
 class _Local(threading.local):
     clock = None    # the LoopClock this thread owns (a class default: a
     #                 thread that owns none reads it without an exception)
+    joining = None  # the compile ledger's (.compiles) stages not yet
+    #                 joined, of this thread
+    # ``open``: the names of the spans open on this thread, outermost
+    # first (what the ledger files a record under); made by the thread's
+    # first span, so it has no class default
 
 
 _local = _Local()
@@ -70,7 +75,8 @@ class span:
     to the clock's open iteration; any other thread pays one thread-local
     lookup for that."""
 
-    __slots__ = ("name", "_ann", "_hist", "_t0", "_clock", "_covered")
+    __slots__ = ("name", "_ann", "_hist", "_t0", "_clock", "_covered",
+                 "_open")
 
     def __init__(self, name: str, **fields):
         import jax
@@ -79,6 +85,10 @@ class span:
 
     def __enter__(self) -> "span":
         self._hist = _hist(self.name)
+        try:
+            names = self._open = _local.open
+        except AttributeError:
+            names = self._open = _local.open = []
         clock = self._clock = _local.clock
         if clock is not None:
             if clock._thread != threading.get_ident():  # taken over since
@@ -86,6 +96,7 @@ class span:
             else:
                 self._covered = clock._covered
         self._ann.__enter__()
+        names.append(self.name)     # open from here: ``__exit__`` will run
         self._t0 = time.perf_counter()
         return self
 
@@ -93,6 +104,7 @@ class span:
         dt = time.perf_counter() - self._t0
         self._ann.__exit__(*exc)
         self._hist.observe(dt)
+        self._open.pop()
         clock = self._clock
         if clock is not None:
             by_phase = clock._by_phase
@@ -193,14 +205,15 @@ class _Books:
     """What a clock has closed since its last reset."""
 
     __slots__ = ("t0", "n", "wall", "covered", "wait", "cpu", "proc", "gc",
-                 "gc_n", "hist", "longest", "seq")
+                 "gc_n", "compile", "compiles", "hist", "longest", "seq")
 
     def __init__(self, kinds: Sequence[str]):
         self.t0 = time.perf_counter()
         self.n = dict.fromkeys(kinds, 0)
         self.wall = self.covered = self.wait = self.cpu = 0.0
-        self.proc = self.gc = 0.0
+        self.proc = self.gc = self.compile = 0.0
         self.gc_n = [0, 0, 0]
+        self.compiles = 0
         self.hist = {k: LatencyHistogram() for k in kinds}
         self.longest = {k: [] for k in kinds}   # heaps of (busy, seq, record)
         self.seq = 0
@@ -229,7 +242,11 @@ class LoopClock:
       lower bound: CPU spent inside the waits is not subtracted.  The sum
       ``offcpu_s`` is taken over the sums, not over the iterations;
     - ``gc``: seconds of the collections that ENDED in the iteration, on any
-      thread (a collection holds the interpreter lock whoever set it off).
+      thread (a collection holds the interpreter lock whoever set it off);
+    - ``by_phase["compile"]``: seconds of the programs this thread traced,
+      lowered and compiled or loaded in the iteration (the compile ledger,
+      :mod:`.compiles`), with their names under ``compiled``.  Never added
+      to ``covered``: the span they fell in already holds those seconds.
 
     Sums go to counters, ``wall`` less the ``sleep`` span to one
     ``LatencyHistogram`` a kind, and the :data:`KEPT` longest iterations of
@@ -242,7 +259,7 @@ class LoopClock:
 
     __slots__ = ("name", "_kinds", "_waits", "_sleep", "_books", "_open",
                  "_by_phase", "_covered", "_thread", "_last", "_gc_n",
-                 "_logged", "_switches")
+                 "_logged", "_switches", "_compiled", "_step")
 
     def __init__(self, name: str, kinds: Sequence[str],
                  waits: Sequence[str], sleep: str):
@@ -255,6 +272,10 @@ class LoopClock:
         self._open = None           # the books the open iteration began under
         self._by_phase: Dict[str, float] = {}
         self._covered = 0.0
+        # the compile ledger's records of the open iteration (seconds,
+        # name, what the cache said) and the cell they read its step from
+        self._compiled: list = []
+        self._step: list = [None]
         self._thread = None
         self._switches = _counts_switches()
         # the last tick's readings: wall, this thread's CPU, the
@@ -285,6 +306,10 @@ class LoopClock:
         books = self._books
         by_phase, covered = self._by_phase, self._covered
         self._by_phase, self._covered = {}, 0.0
+        compiled = self._compiled
+        if compiled:
+            cell = self._step
+            self._compiled, self._step = [], [None]
         if self._open is books:
             t0, cpu0, proc0, gc0, ru0 = self._last
             wall = now - t0
@@ -307,6 +332,10 @@ class LoopClock:
                 self._gc_n = counts
                 for g in range(3):
                     books.gc_n[g] += counts[g] - before[g]
+            if compiled:
+                books.compile += by_phase["compile"]
+                books.compiles += len(compiled)
+                cell[0] = int(step)     # the ledger's records read it
             busy = wall - sleep
             books.hist[kind].observe(busy)
             heap = books.longest[kind]
@@ -325,6 +354,9 @@ class LoopClock:
                         major_faults=ru.ru_majflt - ru0.ru_majflt)
                 named = dict(by_phase, unnamed=record["unnamed"])
                 named.pop(self._sleep, None)
+                if compiled:
+                    named.pop("compile")    # inside the span it fell in
+                    record["compiled"] = [c[1] for c in compiled]
                 record["phase"] = max(named, key=named.get)
                 entry = (busy, books.seq, record)
                 if len(heap) < KEPT:
@@ -333,7 +365,7 @@ class LoopClock:
                     heapq.heapreplace(heap, entry)
                 if busy > STALL_S and now - self._logged >= STALL_LOG_EVERY_S:
                     self._logged = now
-                    self._log_stall(busy, record)
+                    self._log_stall(busy, record, compiled)
         else:
             self._gc_n = list(_gc_counts)
         self._open = books
@@ -346,15 +378,32 @@ class LoopClock:
         self._thread = threading.get_ident()
         self._open = None
         self._by_phase, self._covered = {}, 0.0
+        self._compiled, self._step = [], [None]
 
-    def _log_stall(self, busy: float, r: dict) -> None:
+    def _compiled_one(self, name: str, seconds: float, cache: str) -> list:
+        """The compile ledger closed a record on this, the owning, thread:
+        its ``seconds`` go to the open iteration under ``compile``.
+        Returns the cell the iteration's step is written to when it is
+        ticked (None until then, and for ever if it is dropped)."""
+        by_phase = self._by_phase
+        by_phase["compile"] = by_phase.get("compile", 0.0) + seconds
+        self._compiled.append((seconds, name, cache))
+        return self._step
+
+    def _log_stall(self, busy: float, r: dict, compiled: list) -> None:
         from ..utils.logging import log_event
+        why = ""
+        if compiled:
+            seconds, name, cache = max(compiled)
+            why = (f", compiling {name} {seconds:.3g} s (cache {cache})"
+                   + (f" and {len(compiled) - 1} more"
+                      if len(compiled) > 1 else ""))
         log_event(
             f"{self.name} stalled {busy:.2f} s at step {r['step']} in "
             f"{r['phase']}: cpu {r['cpu']:.3g} s, off-cpu {r['offcpu']:.3g} "
             f"s, gc {r['gc']:.3g} s, others' cpu {r['cpu_others']:.3g} s"
             + (f", {r['switches']} involuntary switches"
-               if "switches" in r else ""))
+               if "switches" in r else "") + why)
 
     def stats(self) -> dict:
         """Everything closed since :meth:`reset`, plain ints, floats and
@@ -375,6 +424,7 @@ class LoopClock:
             "cpu_s": b.cpu, "cpu_others_s": max(0.0, b.proc - b.cpu),
             "offcpu_s": max(0.0, b.wall - b.wait - b.cpu), "gc_s": b.gc,
             "gc_collections": list(b.gc_n),
+            "compile_s": b.compile, "compiles": b.compiles,
             "iteration": {k: h.summary() for k, h in b.hist.items()},
             "longest": {k: [dict(r, by_phase=dict(r["by_phase"]))
                             for _, _, r in sorted(list(heap), reverse=True,

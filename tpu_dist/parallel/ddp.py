@@ -229,18 +229,21 @@ class DistributedDataParallel:
         is unnecessary when init is deterministic, but available as
         ``collectives.broadcast_host`` for externally-loaded params.)
         """
-        key = rng if rng is not None else jax.random.key(seed)
-        params = self.module.init(key)
-        model_state = self.module.init_state()
-        opt_state = ({} if self.optimizer is None
-                     else self.optimizer.init(params))
-        state = TrainState(params, model_state, opt_state,
-                           jnp.zeros((), jnp.int32),
-                           jax.random.key_data(jax.random.fold_in(key, 0x5eed)))
-        # commit onto the mesh so donation reuses buffers; the layout policy
-        # (replicated, but params and opt_state per leaf by shard_axis) lives
-        # in state_shardings so checkpoints restore to exactly this placement
-        return jax.tree.map(jax.device_put, state, self.state_shardings(state))
+        with span("setup.init_state"):
+            key = rng if rng is not None else jax.random.key(seed)
+            params = self.module.init(key)
+            model_state = self.module.init_state()
+            opt_state = ({} if self.optimizer is None
+                         else self.optimizer.init(params))
+            state = TrainState(
+                params, model_state, opt_state, jnp.zeros((), jnp.int32),
+                jax.random.key_data(jax.random.fold_in(key, 0x5eed)))
+            # commit onto the mesh so donation reuses buffers; the layout
+            # policy (replicated, but params and opt_state per leaf by
+            # shard_axis) lives in state_shardings so checkpoints restore to
+            # exactly this placement
+            return jax.tree.map(jax.device_put, state,
+                                self.state_shardings(state))
 
     def state_shardings(self, state: TrainState) -> TrainState:
         """Pytree of :class:`NamedSharding` mirroring ``state``'s layout:

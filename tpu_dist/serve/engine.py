@@ -58,7 +58,10 @@ from typing import Callable, List, Optional
 import numpy as np
 
 from ..nn import cache as kvcache
-from ..obs.spans import LoopClock, phase_times, reset_phases, span
+from ..obs.compiles import (longest as longest_compiles,
+                            totals as compile_totals,
+                            totals_since as compile_totals_since)
+from ..obs.spans import KEPT, LoopClock, phase_times, reset_phases, span
 from ..ops.decode_attention import kv_blocks
 from ..utils.metrics import LatencyHistogram
 
@@ -606,7 +609,8 @@ class SlotEngine:
         self.model = model
         # placed once, in the format the pool programs read (host facts for
         # stats(), fixed here)
-        self.params, self._placed = place_params(model, params)
+        with span("setup.place_params"):
+            self.params, self._placed = place_params(model, params)
         self.num_slots = int(num_slots)
         self.max_len = int(max_len if max_len is not None
                            else model.max_seq_len)
@@ -616,8 +620,9 @@ class SlotEngine:
         self.cache_dtype = cache_dtype or jnp.float32
         self.buckets = _bucket_lengths(self.max_len, min_bucket)
         self._jnp = jnp
-        self.cache = beside(self.params, model.init_slot_cache(
-            self.num_slots, self.max_len, self.cache_dtype))
+        with span("setup.init_cache"):
+            self.cache = beside(self.params, model.init_slot_cache(
+                self.num_slots, self.max_len, self.cache_dtype))
 
         # host-side slot table — THE source of truth for occupancy, and the
         # host's mirror of the device's slot state: lengths / steps / temps /
@@ -710,8 +715,12 @@ class SlotEngine:
         # prefills counted by it
         self._prefill_scan_kernel: dict = {}
         self._prefill_scan = self._fresh_prefill_scan()
+        # the compile ledger's totals and the instant of the last
+        # reset_stats() (stats()["compiles"])
+        self._compiles_base = (compile_totals(), 0.0)
 
-        self._build_programs()
+        with span("setup.build_programs"):
+            self._build_programs()
 
     def _build_programs(self) -> None:
         """Compile the two pool programs (``self._decode`` /
@@ -1259,6 +1268,7 @@ class SlotEngine:
         self._pipeline = self._fresh_pipeline()
         reset_phases(SERVE_PHASES)
         self._loop.reset()
+        self._compiles_base = (compile_totals(), time.monotonic())
         # the device counters are never zeroed (a step in flight would
         # carry the old count on): stats() reports them past this reading
         self._moe_base = self._moe_read()
@@ -1514,4 +1524,19 @@ class SlotEngine:
             "e2e": self.hist_e2e.summary(),
             "phases": phase_times(SERVE_PHASES),
             "loop": self._loop.stats(),
+            "compiles": self._compile_stats(),
         }
+
+    def _compile_stats(self) -> dict:
+        """``stats()["compiles"]``: the compile ledger
+        (:mod:`tpu_dist.obs.compiles`) of the whole PROCESS, which
+        ``reset_stats()`` leaves — programs by what the persistent cache
+        said and seconds by stage — with the :data:`~tpu_dist.obs.spans.KEPT`
+        longest records, and under ``since_reset`` the same over what was
+        built since the last ``reset_stats()``: 0 programs in a warmed
+        server; 1 is a shape no bucket was warmed for."""
+        base, at = self._compiles_base
+        return {**compile_totals(),
+                "longest": longest_compiles(KEPT),
+                "since_reset": {**compile_totals_since(base),
+                                "longest": longest_compiles(KEPT, since=at)}}

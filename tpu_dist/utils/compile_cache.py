@@ -23,7 +23,12 @@ def ensure_compile_cache() -> str:
     call before the first compilation — ``init_process_group``,
     ``SlotEngine``, ``examples/serve_lm.py`` and ``chip_smoke.py`` do.
     Launcher children inherit the environment and so share the directory.
+
+    Either way the compile ledger (:mod:`tpu_dist.obs.compiles`) starts
+    listening here, once a process: what compiles after this call is on it.
     """
+    from ..obs.compiles import install
+    install()
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if placed:
         return placed
