@@ -62,7 +62,7 @@ LM = dict(vocab_size=32768, dim=768, depth=12, num_heads=12, max_seq_len=2048)
 # the names ops/*.py give their pallas_calls; the compiled trainer step must
 # hold a Mosaic custom call for each (neither dense attention, nor plain CE,
 # nor the interpreter was taken)
-TRAINER_KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
+TRAINER_KERNELS = ("flash_fwd", "flash_bwd_dq_dkv",
                    "fused_ce_fwd", "fused_ce_bwd")
 
 

@@ -453,8 +453,10 @@ def mosaic_flash(monkeypatch):
     fa = sys.modules["tpu_dist.ops.flash_attention"]
     monkeypatch.setattr(fa, "_use_interpret", lambda: False)
     fa._fwd_call.clear_cache()
-    yield
+    fa._bwd_call.clear_cache()
+    yield fa
     fa._fwd_call.clear_cache()      # leave no Mosaic-lowered trace behind
+    fa._bwd_call.clear_cache()
 
 
 def test_kimi_k2_prefill_on_the_kernel_holds_no_score_tensor(
@@ -910,3 +912,33 @@ def test_lfm2_moe_pool_programs_compile_at_the_cells_shapes(
     assert not [line for line in text.splitlines()
                 if re.search(r"= [^=]*%s[^=]* copy\(" % tail, line)]
     assert memory.temp_size_in_bytes < 128 << 20
+
+
+# -- one backward kernel for flash attention (ISSUE 50) ------------------------
+
+@pytest.mark.parametrize("bh, t, d, dtype, names", [
+    # the training cells' call, chip_smoke.py's trainer and float32 operands
+    # (2 x 2 tiles of 512): a head's dQ stays in VMEM across the sweep
+    (128, 1024, 64, jnp.bfloat16, ["flash_bwd_dq_dkv"]),
+    (128, 2048, 64, jnp.bfloat16, ["flash_bwd_dq_dkv"]),
+    (128, 1024, 64, jnp.float32, ["flash_bwd_dq_dkv"]),
+    # a head's dQ past the estimate: the pair, each making the scores
+    (16, 16384, 128, jnp.bfloat16, ["flash_bwd_dkv", "flash_bwd_dq"]),
+], ids=["cell", "chip_smoke", "float32", "pair"])
+def test_flash_backward_compiles_as_backward_plan_says(
+        one_chip, no_compile_cache, mosaic_flash, bh, t, d, dtype, names):
+    """The causal backward pass compiled for the described chip (interpret
+    mode has missed Mosaic's refusals before: a slice off the tiling, more
+    VMEM than a kernel may take): ONE ``flash_bwd_dq_dkv`` call where
+    ``backward_plan`` says one kernel, ``flash_bwd_dq`` and ``flash_bwd_dkv``
+    where it says two."""
+    fa = mosaic_flash
+    assert fa.backward_plan(t, t, d, True, dtype=dtype)["kernels"] == len(names)
+    x = jax.ShapeDtypeStruct((bh, t, d), dtype, sharding=one_chip)
+    stat = jax.ShapeDtypeStruct((bh, t, 1), jnp.float32, sharding=one_chip)
+    text = jax.jit(lambda *a: fa._bwd_call(
+        *a, True, d ** -0.5, 1024, 1024)).lower(
+            x, x, x, x, stat, x).compile().as_text()
+    calls = re.findall(r"%(flash_bwd\w+?)[.\d]* = [^\n]*"
+                       r"custom_call_target=\"tpu_custom_call\"", text)
+    assert sorted(calls) == names, calls

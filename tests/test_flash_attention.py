@@ -370,16 +370,21 @@ def test_tile_plan_counts_what_the_mask_leaves(case):
     (1024, 1024, True, jnp.float32),    # 2 x 2 grid tiles of two kinds
     (1000, 1000, False, jnp.bfloat16),
     (640, 384, True, jnp.bfloat16),
+    (16384, 128, False, jnp.float32),   # a head's dQ past the VMEM estimate
 ], ids=_case_id)
 def test_kernels_execute_the_plan(rng, monkeypatch, case):
     """Count, in interpret mode, the sub-tiles whose scores each pass really
     computes: every piece of scores goes through ``_scores_t`` (a run of
     adjacent sub-tiles as one matmul: counted by its area), every piece that
-    masks through ``_visible``.  Forward, dQ and dK/dV each run
-    ``tile_plan``'s ``executed`` a head, ``masked`` of them with mask math."""
+    masks through ``_visible``.  The forward and each backward kernel run
+    ``tile_plan``'s ``executed`` a head, ``masked`` of them with mask math:
+    twice in all where ``backward_plan`` says one backward kernel, three
+    times where it says the pair."""
     tq, tk, causal, dtype = case
     heads = 2
     plan = fa.tile_plan(tq, tk, causal, dtype=dtype)
+    kernels = fa.backward_plan(tq, tk, 64, causal, dtype=dtype)["kernels"]
+    assert kernels == (2 if tq == 16384 else 1)
     area = plan["sub_q"] * plan["sub_k"]
     counts = {"scores": 0, "masked": 0}
 
@@ -413,11 +418,97 @@ def test_kernels_execute_the_plan(rng, monkeypatch, case):
                           "masked": heads * plan["masked"]}
         jax.block_until_ready(vjp(jnp.ones_like(out)))
         jax.effects_barrier()
-        # forward once, then dQ and dK/dV
-        assert counts == {"scores": 3 * heads * plan["executed"],
-                          "masked": 3 * heads * plan["masked"]}
+        # forward once, then the backward kernel, or dQ and dK/dV
+        assert counts == {"scores": (1 + kernels) * heads * plan["executed"],
+                          "masked": (1 + kernels) * heads * plan["masked"]}
+        assert kernels * plan["executed"] == fa.backward_plan(
+            tq, tk, 64, causal, dtype=dtype)["score_passes"]
     finally:
         drop_traces()
+
+
+# -- one backward kernel (ISSUE 50) --------------------------------------------
+
+def test_backward_plan_at_the_training_shape():
+    """One backward kernel at the training cells' call (a head of 64 over
+    1,024 positions in bfloat16): the 10 sub-tiles a causal pass executes
+    are computed once for dQ, dK and dV, and a head's dQ takes 512 KB of
+    VMEM (256 KB of float32 accumulator, a double-buffered block of 128 KB).
+    Two kernels, each making the scores, where a head's dQ does not fit
+    beside the dK/dV kernel's blocks."""
+    plan = fa.backward_plan(1024, 1024, 64, True)
+    assert plan == {"kernels": 1, "score_passes": 10,
+                    "dq_resident_bytes": 512 << 10}
+    assert plan["score_passes"] == fa.tile_plan(1024, 1024, True)["executed"]
+    # chip_smoke.py's trainer, float32 operands, a head of 128
+    assert fa.backward_plan(2048, 2048, 64, True)["kernels"] == 1
+    assert fa.backward_plan(1024, 1024, 64, True,
+                            dtype=jnp.float32)["kernels"] == 1
+    assert fa.backward_plan(2048, 2048, 128, False)["kernels"] == 1
+    # a very long sequence at a wide head
+    long = fa.backward_plan(16384, 16384, 128, True)
+    assert long["kernels"] == 2
+    assert long["score_passes"] == 2 * fa.tile_plan(16384, 16384,
+                                                    True)["executed"]
+    assert long["dq_resident_bytes"] == 16384 * 128 * 8 > fa._VMEM_BUDGET
+
+
+_BOTH_WAYS = [
+    # tq, tk, d, causal, dtype, block, a cotangent for lse
+    (1024, 1024, 64, True, jnp.bfloat16, 1024, False),   # the training call
+    (1024, 1024, 64, False, jnp.bfloat16, 1024, True),
+    (1024, 1024, 64, True, jnp.float32, 1024, False),    # 2 x 2 tiles of 512
+    (1024, 1024, 64, "offdiag", jnp.float32, 512, True),
+    (640, 384, 64, True, jnp.bfloat16, 1024, False),     # padded keys
+    (640, 384, 64, False, jnp.float32, 1024, True),
+    (1024, 1024, 128, True, jnp.bfloat16, 1024, True),   # a head of 128
+    (2048, 2048, 64, True, jnp.bfloat16, 512, False),    # 4 x 4 tiles
+    (2048, 2048, 64, "offdiag", jnp.bfloat16, 512, True),
+]
+
+
+@pytest.mark.parametrize("case", _BOTH_WAYS, ids=_case_id)
+def test_one_backward_kernel_equals_the_pair(rng, monkeypatch, case):
+    """The backward pass built BOTH ways on the same operands: the one
+    kernel, which the shapes choose here, and the pair, which a call takes
+    whose dQ does not fit VMEM.  dq, dk and dv are equal bit for bit: the
+    same matmuls on the same operands, and where a head is several tiles
+    each row of dq is summed over the k tiles in the same order, the pair's
+    over its inner sweep, the one kernel's over its outer.  Both against the
+    dense composition's gradients, with ``lse``'s cotangent live in half the
+    cases."""
+    tq, tk, d, causal, dtype, block, with_lse = case
+    q, k, v = _rand_qkv(rng, 1, tq, tk, 1, d, dtype)
+    cot = jnp.asarray(rng.standard_normal((1, tq, 1, d)), jnp.float32)
+    kw = dict(causal=causal, block_q=block, block_k=block)
+    assert fa.backward_plan(tq, tk, d, causal, block, block,
+                            dtype)["kernels"] == 1
+
+    def loss(o, lse):
+        o = o.astype(jnp.float32)
+        return jnp.vdot(o, cot) + (_lse_loss(o, lse) if with_lse else 0.0)
+
+    def grads():
+        fa._bwd_call.clear_cache()
+        return jax.grad(lambda *a: loss(*flash_attention_with_lse(*a, **kw)),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    try:
+        one = grads()
+        monkeypatch.setattr(fa, "_one_kernel_fits", lambda *a: False)
+        pair = grads()
+    finally:
+        fa._bwd_call.clear_cache()
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    block = fa._clamp_blocks(dtype, tq, tk, block, block)[0]
+    dense = jax.grad(lambda *a: loss(*_dense_with_lse(*a, causal, block)),
+                     argnums=(0, 1, 2))(*f32)
+    _, tol = _tol(dtype)
+    for a, b, ref, name in zip(one, pair, dense, "qkv"):
+        assert a.dtype == dtype
+        np.testing.assert_array_equal(a, b, err_msg=f"d{name}")
+        np.testing.assert_allclose(a.astype(np.float32), ref, **tol,
+                                   err_msg=f"d{name}")
 
 
 # -- a value width of its own (ISSUE 39) --------------------------------------
@@ -482,8 +573,8 @@ def test_differentiating_a_value_width_of_its_own_says_why_not(rng):
         flash_attention(q, v, v)
 
 
-def _mosaic_kernel(monkeypatch, *operands):
-    """The Mosaic module ``_fwd_call`` lowers to for a TPU (no chip, no
+def _mosaic_kernels(monkeypatch, call, *args):
+    """The Mosaic modules a jitted ``call`` lowers to for a TPU (no chip, no
     compile), as text without source locations."""
     import jax._src.tpu_custom_call as tcc
     seen, inner = [], tcc._lower_mosaic_module_to_asm
@@ -492,9 +583,14 @@ def _mosaic_kernel(monkeypatch, *operands):
         tcc, "_lower_mosaic_module_to_asm", lambda module, **kw: (
             seen.append(module.operation.get_asm(enable_debug_info=False)),
             inner(module, **kw))[1])
-    fa._fwd_call.trace(*operands, True, 0.125, 1024, 1024).lower(
-        lowering_platforms=("tpu",))
-    (text,) = seen
+    call.trace(*args).lower(lowering_platforms=("tpu",))
+    return seen
+
+
+def _mosaic_kernel(monkeypatch, *operands):
+    """The Mosaic module ``_fwd_call`` lowers to."""
+    (text,) = _mosaic_kernels(monkeypatch, fa._fwd_call, *operands, True,
+                              0.125, 1024, 1024)
     return text
 
 
@@ -527,3 +623,23 @@ def test_the_training_call_lowers_to_the_blocks_it_always_had(monkeypatch):
     assert main.count(_vmem((1024, 128), "f32")) == 1
     assert "iteration_bounds = array<i64: 32, 4, 4>" in main
     fa._fwd_call.clear_cache()      # leave no TPU-lowered trace behind
+
+
+def test_the_training_backward_lowers_to_one_kernel(monkeypatch):
+    """The backward pass at the training cells' shape is ONE Mosaic kernel,
+    one grid step a head: q, k, v and dO come in and dq, dk and dv go out in
+    blocks of (1, 1024, 64), lse and delta as rows, and three (1024, 64)
+    float32 accumulators stay in VMEM."""
+    sds = lambda *shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype)
+    x, stat = sds(128, 1024, 64), sds(128, 1024, 1, dtype=jnp.float32)
+    fa._bwd_call.clear_cache()
+    try:
+        (text,) = _mosaic_kernels(monkeypatch, fa._bwd_call, x, x, x, x, stat,
+                                  x, True, 0.125, 1024, 1024)
+    finally:
+        fa._bwd_call.clear_cache()      # leave no TPU-lowered trace behind
+    main = next(l for l in text.splitlines() if "func.func @main" in l)
+    assert "iteration_bounds = array<i64: 128, 1, 1>" in main
+    assert main.count(_vmem((1, 1024, 64), "bf16")) == 4 + 3
+    assert main.count(_vmem((1, 1, 1024), "f32")) == 2
+    assert main.count(_vmem((1024, 64), "f32")) == 3

@@ -23,7 +23,7 @@ or skipped:
 - only a sub-tile that the diagonal or the padding's edge crosses masks.
 
 So causal attention at T = 1024 is one grid step a head and executes 10
-of its 16 sub-tiles of 256 (``tile_plan``).  All three kernels walk a tile
+of its 16 sub-tiles of 256 (``tile_plan``).  Every kernel walks a tile
 the same way: by ROW of sub-tiles (``_SUB`` queries), each row against the
 keys ``_k_range`` leaves it — its unmasked sub-tiles first, as one piece
 of scores, then those that mask — and on TRANSPOSED scores (keys x
@@ -45,16 +45,25 @@ Layout (kernel-internal): (BH, T, D) with a (BH, nq, nk) grid; the KV index
 is innermost so the f32 accumulators (m, l, acc) persist in VMEM scratch
 across a Q row's KV sweep and the output tile is written back to HBM once.
 Forward saves per-row logsumexp; backward recomputes scores from
-(q, k, lse) flash-style — two kernels, one accumulating dQ over the KV
-sweep, one accumulating dK/dV over the Q sweep (grid transposed so the
-accumulators stay resident).  Residuals are just (q, k, v, o, lse): no
-(Tq, Tk) tensor is ever materialized, forward or backward.
+(q, k, lse) flash-style, in ONE kernel (``flash_bwd_dq_dkv``): a
+sub-tile's scores and probabilities are made once, and dV, dK and dQ are
+three matmuls from the same p^T and ds^T (5 matmuls a sub-tile).  Its grid
+is transposed, (BH, nk, nq), so dK and dV accumulate in VMEM over the Q
+sweep; dQ accumulates in a float32 scratch of the head's WHOLE padded
+sequence, under an output block whose index does not change while the
+head's tiles are swept, and is written back once a head.  Whether that
+fits is read off the shapes (``_bwd_vmem``, ``backward_plan``): a very long
+sequence at a wide head takes two kernels instead, one accumulating dQ
+over the KV sweep, one accumulating dK/dV over the Q sweep, each making the
+scores and probabilities for itself (7 matmuls a sub-tile).  Residuals are
+just (q, k, v, o, lse): no (Tq, Tk) tensor is ever materialized, forward or
+backward.
 
 The FORWARD kernel takes a value width of its own: q and k share one head
 size D, v may have another (a latent layer's expanded heads are 192 for q
 and k, 128 for v), and only V's block, the accumulator and the output are
 Dv wide; the sub-tiles, the statistics and ``tile_plan`` do not see it, and
-with Dv == D the call lowers to the program it always was.  The two
+with Dv == D the call lowers to the program it always was.  The
 backward kernels take ONE head size: differentiating a call with Dv != D
 raises ``NotImplementedError``.  Its caller is a latent layer's
 whole-prompt prefill (tpu_dist.nn.mla, through
@@ -83,7 +92,7 @@ from ._pallas import (ceil_to as _ceil_to, out_struct as _out_struct,
                       use_interpret as _use_interpret)
 
 __all__ = ["flash_attention", "flash_attention_with_lse",
-           "flash_attention_heads_first", "tile_plan"]
+           "flash_attention_heads_first", "tile_plan", "backward_plan"]
 
 _LANE = 128
 _D_ALIGN = 64  # head_dim alignment: 64 halves K/V DMA for d=64 vs padding to 128
@@ -91,6 +100,7 @@ _NEG_INF = -1e30  # finite: keeps max/correction arithmetic NaN-free when a
                   # whole tile is masked (same sentinel as ring_attention)
 _SUB = 256  # edge of a sub-tile; timed on the chip against 128 and 512
             # (PERF.md section 6, PR 33)
+_VMEM_BUDGET = 12 * 1024 * 1024  # of the 16 MB a kernel may take; ops/gmm.py's
 
 
 def _clamp_blocks(dtype, tq, tk, block_q, block_k):
@@ -103,6 +113,33 @@ def _clamp_blocks(dtype, tq, tk, block_q, block_k):
         block_k = min(block_k, 512)
     return (min(block_q, _ceil_to(tq, _LANE)),
             min(block_k, _ceil_to(tk, _LANE)))
+
+
+def _bwd_vmem(dtype, tq, tk, d, block_q, block_k):
+    """``(bytes, dq bytes)``: an estimate of the VMEM the ONE backward
+    kernel takes at these shapes, and the part of it that holds a head's
+    whole dQ (its float32 accumulator and its double-buffered output
+    block).  Counted the way ops/gmm.py's ``_fit_blocks`` counts: the
+    blocks double-buffered, the accumulators, and the intermediates of a
+    tile, for which Mosaic was seen to want half of a whole tile's float32
+    scores and ``dp`` and the ``p`` and ``ds`` cast from them (compiled for a
+    v5e under a falling limit, the estimate is within 0.3 MB of the least
+    the compiler takes where every sub-tile is live and above it elsewhere:
+    PERF.md section 6, PR 50)."""
+    item = jnp.dtype(dtype).itemsize
+    block_q, block_k = _clamp_blocks(dtype, tq, tk, block_q, block_k)
+    tqp, dp = _ceil_to(tq, block_q), _ceil_to(d, _D_ALIGN)
+    dq = tqp * dp * (4 + 2 * item)
+    blocks = 2 * (2 * block_q + 4 * block_k) * dp * item  # q dO k v, dk dv
+    acc = 2 * block_k * dp * 4
+    tile = block_q * block_k * (4 + item)
+    return dq + blocks + acc + tile, dq
+
+
+def _one_kernel_fits(dtype, tq, tk, d, block_q, block_k):
+    """Does a head's whole dQ fit VMEM beside what the dK/dV kernel holds:
+    is the backward pass one kernel (_make_bwd_kernel) or two."""
+    return _bwd_vmem(dtype, tq, tk, d, block_q, block_k)[0] <= _VMEM_BUDGET
 
 
 def _sub_edge(block):
@@ -196,8 +233,28 @@ def tile_plan(tq, tk, causal, block_q: int = 1024, block_k: int = 1024,
             "needed": pairs / (sq * sk), "sub_q": sq, "sub_k": sk}
 
 
+def backward_plan(tq, tk, d, causal, block_q: int = 1024,
+                  block_k: int = 1024, dtype=jnp.bfloat16) -> dict:
+    """How one head's backward pass is made, from shapes alone, by the
+    function ``_bwd_call`` decides with.
+
+    ``kernels``: 1 where a head's whole dQ fits VMEM beside the dK/dV
+    kernel's blocks (``flash_bwd_dq_dkv``: the scores and probabilities of
+    a sub-tile made once for dQ, dK and dV), else 2 (``flash_bwd_dq`` and
+    ``flash_bwd_dkv``, each making them); ``score_passes``: sub-tiles of
+    scores the backward pass computes, ``kernels`` times
+    :func:`tile_plan`'s ``executed``; ``dq_resident_bytes``: the VMEM a
+    head's dQ takes in the one kernel (its float32 accumulator and its
+    double-buffered output block), whichever is chosen."""
+    _, dq = _bwd_vmem(dtype, tq, tk, d, block_q, block_k)
+    kernels = 1 if _one_kernel_fits(dtype, tq, tk, d, block_q, block_k) else 2
+    executed = tile_plan(tq, tk, causal, block_q, block_k, dtype)["executed"]
+    return {"kernels": kernels, "score_passes": kernels * executed,
+            "dq_resident_bytes": dq}
+
+
 # ---------------------------------------------------------------------------
-# what the three kernels share
+# what the kernels share
 # ---------------------------------------------------------------------------
 
 def _scores_t(k, q, sm_scale):
@@ -211,7 +268,7 @@ def _scores_t(k, q, sm_scale):
 def _visible(shape, causal, tk, q_lo, k_lo):
     """Mask of a (keys, queries) piece of scores whose first query is
     ``q_lo`` and first key ``k_lo`` (scalars of the kernel).  The single
-    source of the mask convention shared by the forward and both backward
+    source of the mask convention shared by the forward and the backward
     kernels.
 
     ``causal`` is three-valued: ``True`` masks above the diagonal,
@@ -474,6 +531,18 @@ def _make_dq_kernel(sm_scale, tk, block_q, block_k, causal, nq, nk):
     return kernel
 
 
+def _add_dkv(dk_scr, dv_scr, q_ref, do_ref, rows, live, p, ds, sm_scale):
+    """A row of sub-tiles' part of dV and dK, from its p^T and ds^T, into the
+    accumulators of the ``live`` keys it ran.  Padded q rows contribute
+    nothing: their do and delta are zero."""
+    dv_scr[:live, :] = dv_scr[:live, :] + jax.lax.dot_general(
+        p, do_ref[0, rows, :], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    dk_scr[:live, :] = dk_scr[:live, :] + sm_scale * jax.lax.dot_general(
+        ds, q_ref[0, rows, :], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+
 def _make_dkv_kernel(sm_scale, tk, block_q, block_k, causal, nq, nk):
     from jax.experimental import pallas as pl
 
@@ -496,15 +565,8 @@ def _make_dkv_kernel(sm_scale, tk, block_q, block_k, causal, nq, nk):
                 (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), tiles,
                 sm_scale, causal, tk, q_lo, k_lo)
             for (rows, _, _, live), (p, ds) in zip(tiles, pieces):
-                # padded q rows contribute nothing: their do and delta are
-                # zero
-                dv_scr[:live, :] = dv_scr[:live, :] + jax.lax.dot_general(
-                    p, do_ref[0, rows, :], (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-                dk_scr[:live, :] = dk_scr[:live, :] + (
-                    sm_scale * jax.lax.dot_general(
-                        ds, q_ref[0, rows, :], (((1,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32))
+                _add_dkv(dk_scr, dv_scr, q_ref, do_ref, rows, live, p, ds,
+                         sm_scale)
 
         run(q_lo, k_lo, body)
 
@@ -516,17 +578,165 @@ def _make_dkv_kernel(sm_scale, tk, block_q, block_k, causal, nq, nk):
     return kernel
 
 
-@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9))
-def _bwd_call(q, k, v, o, lse, do, causal, sm_scale, block_q, block_k,
-              dlse=None):
+def _make_bwd_kernel(sm_scale, tk, block_q, block_k, causal, nq, nk):
+    """dQ, dK and dV of a head in one kernel, on the dK/dV kernel's grid
+    (KV tile outer, Q sweep inner): ``_bwd_pieces`` once a tile, and all
+    three output matmuls from its p^T and ds^T.  dk and dv accumulate over
+    the Q sweep as in _make_dkv_kernel; dq accumulates in a float32 scratch
+    of the head's WHOLE padded sequence, and its output block does not move
+    while the head's tiles are swept, so it is written back once a head."""
+    from jax.experimental import pallas as pl
+
+    sq, sk, run = _by_kind(causal, tk, block_q, block_k, nq, nk)
+
+    def kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+               dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr):
+        ki, qi = pl.program_id(1), pl.program_id(2)
+        k_lo = ki * block_k
+        q_lo = qi * block_q
+
+        def of_head(rows):
+            """A tile's rows in the head's dq accumulator."""
+            if nq == 1:
+                return rows
+            return pl.ds(pl.multiple_of(q_lo + rows.start, sq), sq)
+
+        @pl.when((ki == 0) & (qi == 0))
+        def _init_dq():
+            dq_scr[:] = jnp.zeros(dq_scr.shape, jnp.float32)
+
+        @pl.when(qi == 0)
+        def _init():
+            dk_scr[:] = jnp.zeros(dk_scr.shape, jnp.float32)
+            dv_scr[:] = jnp.zeros(dv_scr.shape, jnp.float32)
+
+        def body(kind):
+            tiles = _sub_tiles(kind, sq, sk)
+            pieces = _bwd_pieces(
+                (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), tiles,
+                sm_scale, causal, tk, q_lo, k_lo)
+            for (rows, _, _, live), (p, ds) in zip(tiles, pieces):
+                _add_dkv(dk_scr, dv_scr, q_ref, do_ref, rows, live, p, ds,
+                         sm_scale)
+                at = of_head(rows)
+                dq_scr[at, :] = dq_scr[at, :] + sm_scale * jax.lax.dot_general(
+                    ds, k_ref[0, :live, :], (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+
+        run(q_lo, k_lo, body)
+
+        @pl.when(qi == nq - 1)
+        def _fin():
+            dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
+            dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+
+        @pl.when((ki == nk - 1) & (qi == nq - 1))
+        def _fin_dq():
+            dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
+
+    return kernel
+
+
+def _specs_kv_outer(block_q, block_k, dp):
+    """``(q, kv, row)`` block specs of a grid (bh, nk, nq): KV tile outer,
+    Q sweep inner."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    return (pl.BlockSpec((1, block_q, dp), lambda b, j, i: (b, i, 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, block_k, dp), lambda b, j, i: (b, j, 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, i),
+                         memory_space=pltpu.VMEM))
+
+
+def _bwd_two_kernels(operands, tk, block_q, block_k, causal, sm_scale):
+    """The backward pass as two kernels, each making the scores and
+    probabilities for itself: dQ accumulated over the KV sweep, dK/dV over
+    the Q sweep on the grid transposed.  What a call takes whose dQ does
+    not fit VMEM whole (``backward_plan``)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    qp, kp, vp = operands[:3]
+    (bh, tqp, dp), tkp = qp.shape, kp.shape[1]
+    nq, nk = tqp // block_q, tkp // block_k
+    q_spec = pl.BlockSpec((1, block_q, dp), lambda b, i, j: (b, i, 0),
+                          memory_space=pltpu.VMEM)
+    kv_spec_dq = pl.BlockSpec((1, block_k, dp), lambda b, i, j: (b, j, 0),
+                              memory_space=pltpu.VMEM)
+    row_spec = pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i),
+                            memory_space=pltpu.VMEM)
+
+    dq = pl.pallas_call(
+        _make_dq_kernel(sm_scale, tk, block_q, block_k, causal, nq, nk),
+        grid=(bh, nq, nk),
+        in_specs=[q_spec, kv_spec_dq, kv_spec_dq, q_spec, row_spec, row_spec],
+        out_specs=q_spec,
+        out_shape=_out_struct((bh, tqp, dp), qp.dtype, *operands[:4]),
+        scratch_shapes=[pltpu.VMEM((block_q, dp), jnp.float32)],
+        interpret=_use_interpret(),
+        name="flash_bwd_dq",
+    )(*operands)
+
+    # grid transposed: KV tile outer, Q sweep inner, so dk/dv accumulate
+    q_spec_t, kv_spec_t, row_spec_t = _specs_kv_outer(block_q, block_k, dp)
+    dk, dv = pl.pallas_call(
+        _make_dkv_kernel(sm_scale, tk, block_q, block_k, causal, nq, nk),
+        grid=(bh, nk, nq),
+        in_specs=[q_spec_t, kv_spec_t, kv_spec_t, q_spec_t, row_spec_t,
+                  row_spec_t],
+        out_specs=[kv_spec_t, kv_spec_t],
+        out_shape=[_out_struct((bh, tkp, dp), kp.dtype, *operands[:4]),
+                   _out_struct((bh, tkp, dp), vp.dtype, *operands[:4])],
+        scratch_shapes=[pltpu.VMEM((block_k, dp), jnp.float32),
+                        pltpu.VMEM((block_k, dp), jnp.float32)],
+        interpret=_use_interpret(),
+        name="flash_bwd_dkv",
+    )(*operands)
+    return dq, dk, dv
+
+
+def _bwd_one_kernel(operands, tk, block_q, block_k, causal, sm_scale):
+    """The backward pass as ONE kernel (_make_bwd_kernel).  Its name holds
+    ``flash_bwd_dq``, which is what a device trace's readers look for, and
+    does not hold ``flash_bwd_dkv``."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    qp, kp, vp = operands[:3]
+    (bh, tqp, dp), tkp = qp.shape, kp.shape[1]
+    nq, nk = tqp // block_q, tkp // block_k
+    q_spec, kv_spec, row_spec = _specs_kv_outer(block_q, block_k, dp)
+    # the head's whole dq: the block's index does not change over a head's
+    # tiles, so it stays in VMEM and is written back once
+    dq_spec = pl.BlockSpec((1, tqp, dp), lambda b, j, i: (b, 0, 0),
+                           memory_space=pltpu.VMEM)
+    return pl.pallas_call(
+        _make_bwd_kernel(sm_scale, tk, block_q, block_k, causal, nq, nk),
+        grid=(bh, nk, nq),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        out_specs=[dq_spec, kv_spec, kv_spec],
+        out_shape=[_out_struct((bh, tqp, dp), qp.dtype, *operands[:4]),
+                   _out_struct((bh, tkp, dp), kp.dtype, *operands[:4]),
+                   _out_struct((bh, tkp, dp), vp.dtype, *operands[:4])],
+        scratch_shapes=[pltpu.VMEM((tqp, dp), jnp.float32),
+                        pltpu.VMEM((block_k, dp), jnp.float32),
+                        pltpu.VMEM((block_k, dp), jnp.float32)],
+        interpret=_use_interpret(),
+        name="flash_bwd_dq_dkv",
+    )(*operands)
+
+
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9))
+def _bwd_call(q, k, v, o, lse, do, causal, sm_scale, block_q, block_k,
+              dlse=None):
     bh, tq, d = q.shape
     tk = k.shape[1]
+    one = _one_kernel_fits(q.dtype, tq, tk, d, block_q, block_k)
     block_q, block_k = _clamp_blocks(q.dtype, tq, tk, block_q, block_k)
     tqp, tkp, dp = _ceil_to(tq, block_q), _ceil_to(tk, block_k), _ceil_to(d, _D_ALIGN)
-    nq, nk = tqp // block_q, tkp // block_k
 
     # delta_i = rowsum(dO_i * O_i) — the softmax-jacobian correction term;
     # cheap elementwise jnp, fused by XLA around the kernels.  When the
@@ -547,44 +757,9 @@ def _bwd_call(q, k, v, o, lse, do, causal, sm_scale, block_q, block_k,
     lsep = jnp.pad(lse.reshape(bh, 1, tq), pad_row)
     deltap = jnp.pad(delta.reshape(bh, 1, tq), pad_row)
 
-    q_spec = pl.BlockSpec((1, block_q, dp), lambda b, i, j: (b, i, 0),
-                          memory_space=pltpu.VMEM)
-    kv_spec_dq = pl.BlockSpec((1, block_k, dp), lambda b, i, j: (b, j, 0),
-                              memory_space=pltpu.VMEM)
-    row_spec = pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i),
-                            memory_space=pltpu.VMEM)
-
-    dq = pl.pallas_call(
-        _make_dq_kernel(sm_scale, tk, block_q, block_k, causal, nq, nk),
-        grid=(bh, nq, nk),
-        in_specs=[q_spec, kv_spec_dq, kv_spec_dq, q_spec, row_spec, row_spec],
-        out_specs=q_spec,
-        out_shape=_out_struct((bh, tqp, dp), q.dtype, qp, kp, vp, dop),
-        scratch_shapes=[pltpu.VMEM((block_q, dp), jnp.float32)],
-        interpret=_use_interpret(),
-        name="flash_bwd_dq",
-    )(qp, kp, vp, dop, lsep, deltap)
-
-    # grid transposed: KV tile outer, Q sweep inner, so dk/dv accumulate
-    q_spec_t = pl.BlockSpec((1, block_q, dp), lambda b, j, i: (b, i, 0),
-                            memory_space=pltpu.VMEM)
-    kv_spec_t = pl.BlockSpec((1, block_k, dp), lambda b, j, i: (b, j, 0),
-                             memory_space=pltpu.VMEM)
-    row_spec_t = pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, i),
-                              memory_space=pltpu.VMEM)
-    dk, dv = pl.pallas_call(
-        _make_dkv_kernel(sm_scale, tk, block_q, block_k, causal, nq, nk),
-        grid=(bh, nk, nq),
-        in_specs=[q_spec_t, kv_spec_t, kv_spec_t, q_spec_t, row_spec_t,
-                  row_spec_t],
-        out_specs=[kv_spec_t, kv_spec_t],
-        out_shape=[_out_struct((bh, tkp, dp), k.dtype, qp, kp, vp, dop),
-                   _out_struct((bh, tkp, dp), v.dtype, qp, kp, vp, dop)],
-        scratch_shapes=[pltpu.VMEM((block_k, dp), jnp.float32),
-                        pltpu.VMEM((block_k, dp), jnp.float32)],
-        interpret=_use_interpret(),
-        name="flash_bwd_dkv",
-    )(qp, kp, vp, dop, lsep, deltap)
+    make = _bwd_one_kernel if one else _bwd_two_kernels
+    dq, dk, dv = make((qp, kp, vp, dop, lsep, deltap), tk, block_q, block_k,
+                      causal, sm_scale)
     return dq[:, :tq, :d], dk[:, :tk, :d], dv[:, :tk, :d]
 
 
@@ -604,7 +779,7 @@ def _flash_lse_fwd(q, k, v, causal, sm_scale, block_q, block_k):
         raise NotImplementedError(
             f"flash attention with a value width of its own (q/k heads of "
             f"{q.shape[-1]}, v heads of {v.shape[-1]}) has a forward kernel "
-            f"only: the two backward kernels take one head size.  "
+            f"only: the backward kernels take one head size.  "
             f"Differentiate the dense composition (impl='dense'), or pad v "
             f"to the q/k width")
     o, lse = _fwd_call(q, k, v, causal, sm_scale, block_q, block_k)
@@ -726,8 +901,9 @@ def flash_attention(q, k, v, causal: bool = False, sm_scale=None,
     computed is decided a level below, by SUB-TILE (``_SUB`` squared scores,
     a constant timed on the chip): a causal call never computes a sub-tile
     above the diagonal, takes no mask math in one wholly below it, and
-    masks only those the diagonal crosses, forward and in both backward
-    kernels; :func:`tile_plan` counts them from shapes.
+    masks only those the diagonal crosses, forward and backward;
+    :func:`tile_plan` counts them from shapes, :func:`backward_plan` how
+    many times the backward pass computes them.
 
     Same computation as :func:`flash_attention_with_lse` with the lse
     discarded (its cotangent is then zero, so the backward is identical).
